@@ -1,0 +1,599 @@
+"""Chip smoke: the system's main path, once, on the TPU.
+
+One process, run from the root of the checkout, through the entry
+points a user calls; data is made from ``--seed`` and nothing is
+fetched. It fails before any phase unless JAX's first device is a TPU
+— it never sets a platform itself.
+
+With no arguments (one chip), four phases:
+
+- ``trainer_sync``   the README flow at BERT-base width:
+  ``serialize_torch_obj(bert_base())`` -> ``SparkTorch(mode=
+  "synchronous").fit(df)`` (-> ``train_distributed``), seq 128,
+  mini-batch 128; loss finite and lower at the end than at the start.
+- ``predictor``      ``model.transform(df)`` (-> ``BatchPredictor``):
+  served parameters live on a TPU device and the predictions equal a
+  plain ``module.apply`` on the same parameters.
+- ``kernels``        ``CausalLM(attn_impl="flash")`` + the
+  ``cross_entropy`` loss at seq 8192 through ``make_train_epoch`` as
+  ``train_distributed`` drives it; the COMPILED step's text must hold
+  each of the five Pallas kernels as a ``tpu_custom_call``; at seq
+  2048 flash-vs-dense and fused-vs-dense losses and gradients agree.
+- ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
+  ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
+  server's version advances, loss finite.
+
+With ``--chips 4`` only the multi-chip path and what it is compared
+with: BERT-base ``train_distributed`` on dp=4 against the same run on
+one chip, one ``make_sharded_train_step`` step on dp=2 x fsdp=2, and
+one MoE step at ep=4 whose compiled text contains ``all-to-all``.
+
+Every phase is a function that raises on failure; a failure stops the
+run (later phases print as "not run"), the exit code is non-zero and
+no result line is printed. The last line of a passing run is the one
+JSON object the driver reads.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import re
+import sys
+import time
+import traceback
+
+import numpy as np
+
+# The five Pallas kernels, by the ``name=`` of their ``pallas_call``.
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+           "fused_ce_fwd", "fused_ce_bwd")
+
+# Stated tolerances. The models compute in bf16 (eps 7.8e-3), so two
+# programs that order their sums differently agree to a few eps; a
+# wrong mask, scale or block index is off by O(1).
+TOL_PREDICT_ABS = 2e-2       # predictor logits vs plain apply (logits O(1))
+TOL_PARITY_LOSS_REL = 1e-3   # kernel-vs-dense loss at seq 2048
+TOL_PARITY_GRAD_REL = 2e-2   # ||g_kernel - g_dense|| / ||g_dense||
+TOL_DP_LOSS_REL = 5e-3       # dp=4 vs one chip, per-step loss
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Shapes of one run. The defaults are the real ones; a rehearsal
+    on the CPU passes small ones (it imports this module — there is no
+    option of the script that shrinks a phase)."""
+
+    # trainer_sync / predictor / dp4: bert_base() as published
+    bert_overrides: tuple = ()
+    bert_vocab: int = 30522
+    bert_seq: int = 128
+    bert_rows: int = 512
+    bert_mini_batch: int = 128
+    bert_iters: int = 64
+    predict_rows: int = 300
+    # kernels: bench long_context_lm
+    lm_vocab: int = 32768
+    lm_d_model: int = 512
+    lm_heads: int = 8
+    lm_layers: int = 4
+    lm_d_ff: int = 2048
+    lm_seq: int = 8192
+    lm_batch: int = 2
+    lm_steps: int = 4
+    parity_seq: int = 2048
+    # trainer_hogwild: bench resnet18_hogwild
+    hog_rows: int = 1024
+    hog_mini_batch: int = 256
+    hog_iters: int = 16
+    hog_push_every: int = 4
+    hog_workers: int = 2
+    # four chips
+    dp_rows: int = 128
+    dp_iters: int = 4
+    moe_experts: int = 8
+    moe_seq: int = 1024
+    moe_batch: int = 8
+
+
+def kernel_call_counts(hlo_text: str) -> dict:
+    """``{kernel name: count}`` of the ``tpu_custom_call``s in a
+    compiled program's text, by the ``pallas_call`` name that jax puts
+    in the call's ``op_name`` metadata."""
+    counts = {k: 0 for k in KERNELS}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.search(r'op_name="([^"]*)"', line)
+        for k in KERNELS:
+            if m and re.search(rf"\b{k}\b", m.group(1)):
+                counts[k] += 1
+    return counts
+
+
+def _bert_rows(rng, n, seq, vocab):
+    """Token-id rows with a learnable label: class 1 draws its tokens
+    from the upper half of the vocabulary, class 0 from the lower."""
+    y = rng.integers(0, 2, (n,))
+    lo = np.where(y == 1, vocab // 2, 0)[:, None]
+    ids = lo + rng.integers(0, vocab // 2, (n, seq))
+    return ids.astype(np.float32), y.astype(np.float32)
+
+
+def _bert_obj(sz: Sizes, lr: float = 1e-4):
+    from sparktorch_tpu import serialize_torch_obj
+    from sparktorch_tpu.models.transformer import bert_base
+
+    return serialize_torch_obj(
+        bert_base(**dict(sz.bert_overrides)), criterion="cross_entropy",
+        optimizer="adam", optimizer_params={"lr": lr},
+        input_shape=(sz.bert_seq,),
+    )
+
+
+def _peak_bytes():
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("peak_bytes_in_use")
+
+
+def _all_on_platform(tree, platform: str) -> bool:
+    import jax
+
+    leaves = jax.tree.leaves(tree)
+    return bool(leaves) and all(
+        isinstance(a, jax.Array)
+        and all(d.platform == platform for d in a.devices())
+        for a in leaves
+    )
+
+
+# ---------------------------------------------------------------------------
+# One chip
+# ---------------------------------------------------------------------------
+
+
+def phase_trainer_sync(sz: Sizes, seed: int, ctx: dict) -> str:
+    from sparktorch_tpu import SparkTorch
+
+    rng = np.random.default_rng(seed)
+    x, y = _bert_rows(rng, sz.bert_rows, sz.bert_seq, sz.bert_vocab)
+    df = {"features": list(x), "label": y}
+    est = SparkTorch(
+        inputCol="features", labelCol="label", predictionCol="predictions",
+        torchObj=_bert_obj(sz), iters=sz.bert_iters,
+        miniBatch=sz.bert_mini_batch, mode="synchronous",
+        useVectorOut=True,
+    )
+    t0 = time.perf_counter()
+    model = est.fit(df)
+    wall = time.perf_counter() - t0
+    recs = est._last_metrics
+    losses = [r["loss"] for r in recs]
+    if len(losses) != sz.bert_iters:
+        raise AssertionError(f"{len(losses)} records, wanted {sz.bert_iters}")
+    if not np.all(np.isfinite(losses)):
+        raise AssertionError(f"non-finite loss: {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"loss did not fall: {losses[0]} -> {losses[-1]}")
+    # The first fused chunk holds the compile; later chunks are steady.
+    first = recs[0]["step_time_s"]
+    steady = sorted(r["step_time_s"] for r in recs
+                    if r["step_time_s"] != first)
+    ctx["model"], ctx["bert_x"] = model, x
+    return (f"fit_s={wall:.2f} iters={len(losses)} "
+            f"first_chunk_step_s={first:.4f} "
+            f"steady_step_s={steady[len(steady) // 2] if steady else None} "
+            f"loss_first={losses[0]:.4f} loss_last={losses[-1]:.4f}")
+
+
+def phase_predictor(sz: Sizes, seed: int, ctx: dict) -> str:
+    import jax
+
+    model = ctx["model"]
+    x = ctx["bert_x"][: sz.predict_rows]
+    t0 = time.perf_counter()
+    out = model.transform({"features": list(x)})
+    wall = time.perf_counter() - t0
+    preds = np.stack([np.asarray(v) for v in out["predictions"]])
+    served = model._predictor()._params
+    if not _all_on_platform(served, jax.devices()[0].platform):
+        raise AssertionError("served parameters are not on the device")
+    bundle = model.getModel()
+    ref = np.asarray(jax.jit(bundle.module.apply)(
+        {"params": bundle.params, **(bundle.model_state or {})}, x))
+    if preds.shape != ref.shape or not np.all(np.isfinite(preds)):
+        raise AssertionError(f"predictions {preds.shape} vs {ref.shape}")
+    err = float(np.max(np.abs(preds - ref)))
+    if err > TOL_PREDICT_ABS:
+        raise AssertionError(f"predictor differs from module.apply by {err}")
+    agree = float(np.mean(preds.argmax(-1) == ref.argmax(-1)))
+    return (f"transform_s={wall:.2f} rows={len(preds)} "
+            f"max_abs_err={err:.2e} (tol {TOL_PREDICT_ABS}) "
+            f"argmax_agree={agree:.4f} params_on={jax.devices()[0].platform}")
+
+
+def _lm_spec(sz: Sizes, attn: str, seq: int, loss: str = "cross_entropy"):
+    from sparktorch_tpu.models import CausalLM
+    from sparktorch_tpu.models.transformer import TransformerConfig
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    cfg = TransformerConfig(
+        vocab_size=sz.lm_vocab, d_model=sz.lm_d_model, n_heads=sz.lm_heads,
+        n_layers=sz.lm_layers, d_ff=sz.lm_d_ff, max_len=seq,
+        attn_impl=attn, remat=True,
+    )
+    return ModelSpec(module=CausalLM(cfg), loss=loss, optimizer="adamw",
+                     optimizer_params={"lr": 3e-4}, input_shape=(seq,))
+
+
+def _kernel_parity(sz: Sizes, seed: int) -> str:
+    """Loss and gradients of the same parameters at ``parity_seq``:
+    flash attention vs dense attention (dense loss on both), and the
+    fused loss vs the dense loss (dense attention on both)."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed + 1)
+    ids = rng.integers(0, sz.lm_vocab,
+                       (sz.lm_batch, sz.parity_seq + 1)).astype(np.int32)
+    x, y = jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+    base = _lm_spec(sz, "dense", sz.parity_seq, "cross_entropy_dense")
+    params = jax.jit(base.make_module().init)(jax.random.key(seed), x)["params"]
+
+    def loss_and_grad(attn, loss):
+        spec = _lm_spec(sz, attn, sz.parity_seq, loss)
+        module, loss_fn = spec.make_module(), spec.loss_fn()
+        fn = jax.jit(jax.value_and_grad(
+            lambda p: jnp.mean(loss_fn(module.apply({"params": p}, x), y))))
+        val, grads = fn(params)
+        return float(val), grads
+
+    def rel(ga, gb):
+        num = jnp.sqrt(sum(jnp.sum(jnp.square(a.astype(jnp.float32)
+                                              - b.astype(jnp.float32)))
+                           for a, b in zip(jax.tree.leaves(ga),
+                                           jax.tree.leaves(gb))))
+        den = jnp.sqrt(sum(jnp.sum(jnp.square(b.astype(jnp.float32)))
+                           for b in jax.tree.leaves(gb)))
+        return float(num / den)
+
+    l_ref, g_ref = loss_and_grad("dense", "cross_entropy_dense")
+    out = []
+    for tag, attn, loss in (("flash_vs_dense", "flash", "cross_entropy_dense"),
+                            ("fused_vs_dense", "dense", "cross_entropy_fused")):
+        l, g = loss_and_grad(attn, loss)
+        dl, dg = abs(l - l_ref) / abs(l_ref), rel(g, g_ref)
+        if not (np.isfinite(l) and dl <= TOL_PARITY_LOSS_REL
+                and dg <= TOL_PARITY_GRAD_REL):
+            raise AssertionError(
+                f"{tag} at seq {sz.parity_seq}: loss {l} vs {l_ref} "
+                f"(rel {dl}, tol {TOL_PARITY_LOSS_REL}), grad rel {dg} "
+                f"(tol {TOL_PARITY_GRAD_REL})")
+        out.append(f"{tag}: loss_rel={dl:.2e} grad_rel={dg:.2e}")
+    return (f"parity@seq{sz.parity_seq} loss={l_ref:.4f} " + " ".join(out)
+            + f" (tol loss {TOL_PARITY_LOSS_REL} grad {TOL_PARITY_GRAD_REL})")
+
+
+def phase_kernels(sz: Sizes, seed: int, ctx: dict) -> str:
+    import jax
+
+    from sparktorch_tpu.parallel.mesh import build_mesh, replicated
+    from sparktorch_tpu.train.step import create_train_state, make_train_epoch
+    from sparktorch_tpu.train.sync import prepare_sharded_batch
+    from sparktorch_tpu.utils.data import handle_features
+
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, sz.lm_vocab,
+                       (sz.lm_batch, sz.lm_seq + 1)).astype(np.int32)
+    spec = _lm_spec(sz, "flash", sz.lm_seq)
+    mesh = build_mesh()
+    batch, _ = handle_features(ids[:, :-1], ids[:, 1:])
+    batch = prepare_sharded_batch(batch, mesh)
+    tx = spec.make_optimizer()
+    with mesh:
+        state = jax.jit(
+            lambda: create_train_state(spec, jax.random.key(seed),
+                                       sample_x=batch.x[:1], tx=tx),
+            out_shardings=replicated(mesh),
+        )()
+    step = make_train_epoch(spec.make_module().apply, spec.loss_fn(), tx,
+                            mesh, sz.lm_steps)
+    t0 = time.perf_counter()
+    compiled = step.lower(state, batch).compile()
+    compile_s = time.perf_counter() - t0
+    # From the compiled text, not from the config that was asked for.
+    counts = kernel_call_counts(compiled.as_text())
+    missing = [k for k, n in counts.items() if n == 0]
+    if missing:
+        raise AssertionError(
+            f"compiled step holds no tpu_custom_call for {missing}: {counts}")
+    t0 = time.perf_counter()
+    state, metrics = compiled(state, batch)
+    losses = np.asarray(metrics.loss)
+    run_s = time.perf_counter() - t0
+    if losses.shape != (sz.lm_steps,) or not np.all(np.isfinite(losses)):
+        raise AssertionError(f"kernel step losses: {losses}")
+    del state, compiled
+    parity = _kernel_parity(sz, seed)
+    return (f"compile_s={compile_s:.2f} steps={sz.lm_steps} run_s={run_s:.3f} "
+            f"seq={sz.lm_seq} losses={[round(float(v), 4) for v in losses]} "
+            f"tpu_custom_calls={json.dumps(counts)} | {parity}")
+
+
+def phase_trainer_hogwild(sz: Sizes, seed: int, ctx: dict) -> str:
+    from sparktorch_tpu import SparkTorch, serialize_torch_obj
+    from sparktorch_tpu.models.resnet import resnet18
+
+    rng = np.random.default_rng(seed)
+    x = rng.normal(0, 1, (sz.hog_rows, 32, 32, 3)).astype(np.float32)
+    y = rng.integers(0, 10, (sz.hog_rows,)).astype(np.float32)
+    obj = serialize_torch_obj(
+        resnet18(num_classes=10), criterion="cross_entropy",
+        optimizer="sgd", optimizer_params={"lr": 1e-2},
+        input_shape=(32, 32, 3),
+    )
+    est = SparkTorch(
+        inputCol="features", labelCol="label", torchObj=obj,
+        iters=sz.hog_iters, miniBatch=sz.hog_mini_batch, mode="hogwild",
+        partitions=sz.hog_workers, pushEvery=sz.hog_push_every,
+    )
+    t0 = time.perf_counter()
+    est.fit({"features": list(x), "label": y})
+    wall = time.perf_counter() - t0
+    recs = est._last_metrics
+    losses = [r["loss"] for r in recs]
+    versions = sorted({r["version"] for r in recs})
+    workers = sorted({r["worker"] for r in recs})
+    if not losses or not np.all(np.isfinite(losses)):
+        raise AssertionError(f"hogwild losses: {losses}")
+    if len(workers) != sz.hog_workers:
+        raise AssertionError(f"workers seen: {workers}")
+    if not versions[-1] > versions[0]:
+        raise AssertionError(f"server version never advanced: {versions}")
+    return (f"fit_s={wall:.2f} workers={len(workers)} records={len(recs)} "
+            f"pulled_versions={versions[0]}..{versions[-1]} "
+            f"loss_first={losses[0]:.4f} loss_last={losses[-1]:.4f}")
+
+
+# ---------------------------------------------------------------------------
+# Four chips
+# ---------------------------------------------------------------------------
+
+
+def _spread(tree) -> set:
+    import jax
+
+    return {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+
+
+def phase_dp4_vs_one_chip(sz: Sizes, seed: int, ctx: dict) -> str:
+    """``train_distributed`` BERT-base, same global batch and seed, on
+    a dp=4 mesh and on one chip: per-step losses agree; on dp=4 the
+    parameters and the batch are really on four devices."""
+    import jax
+
+    from sparktorch_tpu.parallel.mesh import MeshConfig, build_mesh
+    from sparktorch_tpu.train.sync import train_distributed
+
+    rng = np.random.default_rng(seed)
+    x, y = _bert_rows(rng, sz.dp_rows, sz.bert_seq, sz.bert_vocab)
+    devices = jax.devices()
+    seen = {}
+
+    def witness(tag, n_dev):
+        # The trainer keeps its state to itself; what is live on the
+        # devices while a step's record is handed out is the witness.
+        def hook(_record):
+            if tag in seen:
+                return
+            big = [a for a in jax.live_arrays()
+                   if a.ndim == 2 and a.shape[0] == sz.bert_vocab]
+            rows = [a for a in jax.live_arrays()
+                    if a.shape == x.shape and len(a.devices()) == n_dev]
+            seen[tag] = (
+                sorted(len(a.devices()) for a in big),
+                sorted({a.addressable_shards[0].data.shape[0] for a in rows}),
+            )
+        return hook
+
+    out = {}
+    for tag, mesh in (
+        ("dp4", build_mesh(MeshConfig(dp=4), devices[:4])),
+        ("one", build_mesh(MeshConfig(dp=1), devices[:1])),
+    ):
+        t0 = time.perf_counter()
+        res = train_distributed(
+            _bert_obj(sz), x, labels=y, mesh=mesh, iters=sz.dp_iters,
+            seed=seed, metrics_hook=witness(tag, mesh.size),
+        )
+        out[tag] = ([r["loss"] for r in res.metrics],
+                    time.perf_counter() - t0)
+    l4, l1 = np.asarray(out["dp4"][0]), np.asarray(out["one"][0])
+    if l4.shape != (sz.dp_iters,) or not np.all(np.isfinite(l4)):
+        raise AssertionError(f"dp=4 losses: {l4}")
+    rel = float(np.max(np.abs(l4 - l1) / np.abs(l1)))
+    if rel > TOL_DP_LOSS_REL:
+        raise AssertionError(f"dp=4 {l4} vs one chip {l1}: rel {rel}")
+    embed_devs, shard_rows = seen["dp4"]
+    if not embed_devs or set(embed_devs) != {4}:
+        raise AssertionError(f"dp=4 parameters on {embed_devs} devices")
+    if shard_rows != [sz.dp_rows // 4]:
+        raise AssertionError(f"dp=4 batch shards hold {shard_rows} rows")
+    return (f"dp4_s={out['dp4'][1]:.2f} one_chip_s={out['one'][1]:.2f} "
+            f"losses_dp4={[round(float(v), 5) for v in l4]} "
+            f"losses_one={[round(float(v), 5) for v in l1]} "
+            f"max_rel={rel:.2e} (tol {TOL_DP_LOSS_REL}) "
+            f"vocab_leaves_on={embed_devs} devices, "
+            f"batch_rows_per_device={shard_rows}")
+
+
+def _sharded_step(spec, mesh_cfg, x, y, seed, want_text=False):
+    """One ``make_sharded_train_step`` step; returns (loss, state,
+    compiled text or None, seconds)."""
+    import jax
+
+    from sparktorch_tpu.parallel.mesh import build_mesh
+    from sparktorch_tpu.train.sharded import (
+        create_sharded_state,
+        make_sharded_train_step,
+        shard_batch,
+    )
+    from sparktorch_tpu.utils.data import DataBatch
+
+    mesh = build_mesh(mesh_cfg, jax.devices()[:4])
+    tx = spec.make_optimizer()
+    state, shardings = create_sharded_state(
+        spec, mesh, jax.random.key(seed), sample_x=x[:1], tx=tx)
+    step = make_sharded_train_step(
+        spec.make_module().apply, spec.loss_fn(), tx, mesh, shardings)
+    batch = shard_batch(
+        DataBatch(x=x, y=y, w=np.ones((x.shape[0],), np.float32)), mesh)
+    t0 = time.perf_counter()
+    text = None
+    if want_text:
+        with jax.set_mesh(mesh):
+            text = step.jitted.lower(state, batch).compile().as_text()
+    state, metrics = step(state, batch)
+    loss = float(metrics.loss)
+    return loss, state, text, time.perf_counter() - t0
+
+
+def phase_sharded_dp2_fsdp2(sz: Sizes, seed: int, ctx: dict) -> str:
+    import jax
+
+    from sparktorch_tpu.models.transformer import bert_base
+    from sparktorch_tpu.parallel.mesh import MeshConfig
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    rng = np.random.default_rng(seed)
+    x, y = _bert_rows(rng, sz.dp_rows, sz.bert_seq, sz.bert_vocab)
+    spec = ModelSpec(module=bert_base(**dict(sz.bert_overrides)),
+                     loss="cross_entropy", optimizer="adam",
+                     optimizer_params={"lr": 1e-4})
+    loss, state, _text, wall = _sharded_step(
+        spec, MeshConfig(dp=2, fsdp=2), x.astype(np.int32),
+        y.astype(np.int32), seed)
+    if not np.isfinite(loss):
+        raise AssertionError(f"dp2 x fsdp2 loss {loss}")
+    n_dev = len(_spread(state.params))
+    split = sum(
+        1 for a in jax.tree.leaves(state.params)
+        if a.addressable_shards[0].data.shape != a.shape)
+    if n_dev != 4 or split == 0:
+        raise AssertionError(
+            f"dp2 x fsdp2: params on {n_dev} devices, {split} leaves split")
+    return (f"step_s={wall:.2f} loss={loss:.4f} param_devices={n_dev} "
+            f"leaves_split_over_fsdp={split}/{len(jax.tree.leaves(state.params))}")
+
+
+def phase_moe_ep4(sz: Sizes, seed: int, ctx: dict) -> str:
+    from sparktorch_tpu.models import CausalLM
+    from sparktorch_tpu.models.transformer import TransformerConfig
+    from sparktorch_tpu.parallel.mesh import MeshConfig
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, sz.lm_vocab,
+                       (sz.moe_batch, sz.moe_seq + 1)).astype(np.int32)
+    cfg = TransformerConfig(
+        vocab_size=sz.lm_vocab, d_model=sz.lm_d_model, n_heads=sz.lm_heads,
+        n_layers=sz.lm_layers, d_ff=sz.lm_d_ff, max_len=sz.moe_seq,
+        n_experts=sz.moe_experts, moe_every=2,
+    )
+    spec = ModelSpec(module=CausalLM(cfg), loss="cross_entropy",
+                     optimizer="adamw", optimizer_params={"lr": 3e-4})
+    loss, state, text, wall = _sharded_step(
+        spec, MeshConfig(ep=4), ids[:, :-1], ids[:, 1:], seed,
+        want_text=True)
+    n_a2a = len(re.findall(r"\ball-to-all(?:-start)?\(", text))
+    if n_a2a == 0:
+        raise AssertionError("no all-to-all in the ep=4 MoE step's "
+                             "compiled text")
+    if not np.isfinite(loss):
+        raise AssertionError(f"ep=4 MoE loss {loss}")
+    n_dev = len(_spread(state.params))
+    if n_dev != 4:
+        raise AssertionError(f"ep=4: params on {n_dev} devices")
+    return (f"step_s={wall:.2f} loss={loss:.4f} all_to_all_ops={n_a2a} "
+            f"param_devices={n_dev}")
+
+
+ONE_CHIP = (("trainer_sync", phase_trainer_sync),
+            ("predictor", phase_predictor),
+            ("kernels", phase_kernels),
+            ("trainer_hogwild", phase_trainer_hogwild))
+FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
+              ("sharded_dp2_fsdp2", phase_sharded_dp2_fsdp2),
+              ("moe_ep4", phase_moe_ep4))
+
+
+def run_phases(phases, sz: Sizes, seed: int) -> bool:
+    """Run in order; the first failure stops the run. Every phase
+    prints its own line — also the ones that did not run."""
+    ctx: dict = {}
+    failed = None
+    for name, fn in phases:
+        if failed is not None:
+            print(f"phase {name}: not run ({failed} failed)", flush=True)
+            continue
+        t0 = time.perf_counter()
+        try:
+            line = fn(sz, seed, ctx)
+        except Exception:
+            traceback.print_exc(file=sys.stderr)
+            print(f"phase {name}: FAILED after "
+                  f"{time.perf_counter() - t0:.1f}s (reason on stderr)",
+                  flush=True)
+            failed = name
+            continue
+        print(f"phase {name}: ok {time.perf_counter() - t0:.1f}s "
+              f"peak_bytes={_peak_bytes()} | {line}", flush=True)
+    return failed is None
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the four-chip path and what it is "
+                    "compared with")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        print(f"chip_smoke: no accelerator: jax.devices()[0] is "
+              f"{dev.platform!r} ({dev.device_kind}); this script runs "
+              "on a TPU only", file=sys.stderr)
+        return 2
+    if len(devices) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} but JAX sees "
+              f"{len(devices)} device(s)", file=sys.stderr)
+        return 2
+
+    from sparktorch_tpu.utils.checkpoint import arm_compile_cache
+
+    arm_compile_cache()
+    print(f"chip_smoke: platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(devices)} jax={jax.__version__} seed={args.seed} "
+          f"compile_cache={jax.config.jax_compilation_cache_dir}",
+          flush=True)
+    t0 = time.perf_counter()
+    ok = run_phases(ONE_CHIP if args.chips == 1 else FOUR_CHIPS,
+                    Sizes(), args.seed)
+    print(f"chip_smoke: total_s={time.perf_counter() - t0:.1f}", flush=True)
+    if not ok:
+        return 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

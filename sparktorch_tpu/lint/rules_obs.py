@@ -106,7 +106,7 @@ class UrllibScrapeRule(Rule):
     summary = "ad-hoc urllib scraping outside obs/"
     why = ("readers of /metrics, /telemetry, /heartbeats, /gang go "
            "through obs.collector.scrape_json/scrape_text (shared "
-           "timeout, error taxonomy, degradation discipline)")
+           "timeout, error classes, degradation discipline)")
 
     def applies(self, rel: Optional[str]) -> bool:
         return _outside_obs(rel)

@@ -27,6 +27,7 @@ from sparktorch_tpu.obs.blackbox import (
 from sparktorch_tpu.obs.goodput import (
     GoodputLedger,
     LedgerSpan,
+    device_peaks,
     mfu_honest,
 )
 from sparktorch_tpu.obs.health import (
@@ -92,6 +93,7 @@ __all__ = [
     "read_postmortem",
     "GoodputLedger",
     "LedgerSpan",
+    "device_peaks",
     "mfu_honest",
     "HealthConfig",
     "TrainHealthLedger",

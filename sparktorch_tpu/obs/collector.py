@@ -127,7 +127,7 @@ def post_json(url: str, payload: Mapping[str, Any],
               headers: Optional[Mapping[str, str]] = None) -> Any:
     """POST a JSON document to a control route (``/ctl``) and parse
     the JSON reply — the write-side twin of :func:`scrape_json`, kept
-    in obs/ so control traffic shares the same timeout/error taxonomy
+    in obs/ so control traffic shares the same timeout/error classes
     the lint-obs scrape discipline enforces on readers. Raises
     :class:`ScrapeError` on network failure or a non-JSON reply;
     non-2xx statuses raise with the server's body in the message (a

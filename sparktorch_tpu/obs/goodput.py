@@ -100,18 +100,39 @@ _DIRECT_BUCKETS = ("compute", "exposed_comm", "compile", "checkpoint",
 
 PRODUCTIVE_BUCKETS = ("compute",)
 
-# v5e peak (bf16). Single source of truth for MFU math — bench.py's
-# mfu_honest reporting imports these, and the ledger's /goodput MFU
-# uses the identical formula (mfu_honest below).
-V5E_BF16_PEAK_TFLOPS = 197.0
+# Published per-chip peaks, keyed by jax's ``device_kind`` — the one
+# table every MFU and roofline field reads (the ledger's /goodput MFU
+# and bench.py's records alike). A device that is not in it gets no
+# such field: never another device's number.
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "bf16_tflops": 197.0,
+        "hbm_gb_per_s": 819.0,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+}
+
+
+def device_peaks(device_kind: Optional[str] = None
+                 ) -> Optional[Mapping[str, Any]]:
+    """The table row for ``device_kind`` (default: the device JAX
+    runs on), or None when that device is not in the table."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
 
 
 def mfu_honest(achieved_tflops_per_chip: float,
-               peak_tflops: float = V5E_BF16_PEAK_TFLOPS) -> float:
+               peak_tflops: Optional[float]) -> Optional[float]:
     """Model-FLOPs utilization from honest achieved TFLOPs/chip — the
     exact division bench.py's headline configs report, shared so the
     ledger's /goodput MFU and the bench can never disagree on the
-    formula."""
+    formula. No peak (a device outside :data:`DEVICE_PEAKS`) means no
+    MFU: None."""
+    if not peak_tflops:
+        return None
     return achieved_tflops_per_chip / peak_tflops
 
 
@@ -269,14 +290,18 @@ class GoodputLedger:
                  publish_interval_s: float = 0.25,
                  flops_per_step: Optional[float] = None,
                  n_chips: int = 1,
-                 peak_tflops: float = V5E_BF16_PEAK_TFLOPS,
+                 peak_tflops: Optional[float] = None,
                  skew_capacity: int = 512):
         self.telemetry = telemetry
         self.rank = rank
         self.publish_interval_s = float(publish_interval_s)
         self.flops_per_step = flops_per_step
         self.n_chips = int(n_chips)
-        self.peak_tflops = float(peak_tflops)
+        # None = read the running device's row of DEVICE_PEAKS when
+        # the first flops-declared snapshot needs it; a device outside
+        # the table leaves the doc without peak/MFU fields.
+        self.peak_tflops = (float(peak_tflops) if peak_tflops is not None
+                            else None)
         # Concurrent execution LANES attributing into this ledger
         # (e.g. train_async's N local worker threads — each thread is
         # a lane of real work, so the MECE budget is lanes x clock
@@ -459,9 +484,13 @@ class GoodputLedger:
             # the default peak — /goodput must agree with the per-rank
             # docs it embeds.
             doc["n_chips"] = self.n_chips
-            doc["peak_tflops"] = self.peak_tflops
             doc["achieved_tflops_per_chip"] = round(achieved, 4)
-            doc["mfu"] = round(mfu_honest(achieved, self.peak_tflops), 6)
+            if self.peak_tflops is None:
+                self.peak_tflops = (device_peaks() or {}).get("bf16_tflops")
+            if self.peak_tflops:
+                doc["peak_tflops"] = self.peak_tflops
+                doc["mfu"] = round(
+                    mfu_honest(achieved, self.peak_tflops), 6)
         return doc
 
     # -- publication ---------------------------------------------------------
@@ -651,6 +680,7 @@ def merge_sections(rank_docs: Mapping[Any, Mapping[str, Any]],
     flops_total = 0.0
     chip_seconds = 0.0
     peak_flop_seconds = 0.0  # aggregate capacity of the flops ranks
+    peak_missing = False
     for rank, doc in sorted(rank_docs.items(), key=lambda kv: str(kv[0])):
         if not isinstance(doc, Mapping) or "buckets" not in doc:
             continue
@@ -666,13 +696,17 @@ def merge_sections(rank_docs: Mapping[Any, Mapping[str, Any]],
         sources.add(str(doc.get("comm_source") or "none"))
         if doc.get("flops_per_step"):
             rank_chips = int(doc.get("n_chips") or 1)
-            rank_peak = float(doc.get("peak_tflops")
-                              or V5E_BF16_PEAK_TFLOPS)
             rank_wall = float(doc.get("wall_s") or 0.0)
             flops_total += (float(doc["flops_per_step"])
                             * int(doc.get("n_steps") or 0))
             chip_seconds += rank_wall * rank_chips
-            peak_flop_seconds += rank_wall * rank_chips * rank_peak * 1e12
+            # A rank whose device is not in the peak table declared
+            # no peak: the run then has a rate but no MFU.
+            if doc.get("peak_tflops"):
+                peak_flop_seconds += (rank_wall * rank_chips
+                                      * float(doc["peak_tflops"]) * 1e12)
+            else:
+                peak_missing = True
     denom = max(wall, 1e-9)
     productive = sum(buckets[b] for b in PRODUCTIVE_BUCKETS)
     run: Dict[str, Any] = {
@@ -717,7 +751,8 @@ def merge_sections(rank_docs: Mapping[Any, Mapping[str, Any]],
         # the per-rank docs it embeds.
         achieved = achieved_tflops_per_chip(flops_total, chip_seconds)
         run["achieved_tflops_per_chip"] = round(achieved, 4)
-        run["mfu"] = round(flops_total / peak_flop_seconds, 6)
+        if not peak_missing:
+            run["mfu"] = round(flops_total / peak_flop_seconds, 6)
     return run
 
 

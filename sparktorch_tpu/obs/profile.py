@@ -53,6 +53,7 @@ in their shutdown path.
 
 from __future__ import annotations
 
+import atexit
 import os
 import sys
 import threading
@@ -503,6 +504,11 @@ def ensure(telemetry: Optional[Telemetry] = None,
             prof = _ACTIVE = StackProfiler(telemetry=telemetry,
                                            rank=rank, hz=hz)
             prof.start()
+            # Joined before the interpreter finalizes: a daemon thread
+            # that wakes during finalization is ended by pthread_exit,
+            # which on this Python (3.12) can abort the process —
+            # seen on the chip as exit 134 after a run that passed.
+            atexit.register(prof.stop)
         else:
             if telemetry is not None:
                 prof.telemetry = telemetry

@@ -23,8 +23,15 @@ round-1 version recomputed the backward through the dense path;
 this closes that gap.)
 
 On non-TPU backends (tests run on the CPU mesh) the kernels run in
-Pallas interpret mode; shapes that don't tile onto (8, 128) TPU
-blocks fall back to the XLA dense path in both directions.
+Pallas interpret mode, and shapes that don't tile onto (8, 128) TPU
+blocks fall back to the XLA dense path in both directions. On a TPU
+backend an untileable shape is an error that names the shape and the
+rule: a caller who asked for the kernel never gets a dense program.
+
+Each ``pallas_call`` carries a stable ``name`` (``flash_fwd``,
+``flash_bwd_dq``, ``flash_bwd_dkv``) — that is how a compiled step's
+text is checked for the kernels (``chip_smoke.py``,
+``tests/test_chip_compile.py``).
 """
 
 from __future__ import annotations
@@ -36,10 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from sparktorch_tpu.ops.attention import dense_attention
 
@@ -151,6 +155,7 @@ def _flash_fwd(q3, k3, v3, *, scale: float, causal: bool, block_q: int,
             out_specs=[o_spec, lse_spec],
             scratch_shapes=scratch,
             interpret=interpret,
+            name="flash_fwd",
         )(q3, k3, v3)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, **kw),
@@ -160,6 +165,7 @@ def _flash_fwd(q3, k3, v3, *, scale: float, causal: bool, block_q: int,
         out_specs=o_spec,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
 
 
@@ -327,10 +333,18 @@ def _flash_impl(q, k, v, causal, block_q, block_k, with_lse):
     d_pad = d if d % _LANES == 0 else d + (_LANES - d % _LANES)
     block_q = _auto_block(s_q, d_pad) if block_q is None else min(block_q, s_q)
     block_k = _auto_block(s_k, d_pad) if block_k is None else min(block_k, s_k)
-    if not _tileable(s_q, s_k, block_q, block_k) or pltpu is None:
+    interpret = jax.default_backend() != "tpu"
+    if not _tileable(s_q, s_k, block_q, block_k):
+        if not interpret:
+            raise ValueError(
+                f"flash_attention: q seq {s_q} / k seq {s_k} cannot be "
+                f"tiled (blocks {block_q} x {block_k}): each sequence "
+                "length must be a multiple of its block, block_q a "
+                f"multiple of 8 and block_k of {_LANES}. Pad the "
+                "sequence or use attn_impl='dense'."
+            )
         return dense_attention(q, k, v, causal=causal), None
 
-    interpret = jax.default_backend() != "tpu"
     # Softmax scale from the TRUE head_dim; zero-padding the lane dim
     # does not change QK^T, so no rescaling trick is needed.
     scale = d ** -0.5
@@ -388,6 +402,7 @@ def _flash_bwd_impl(q, k, v, out, lse3, g, causal, block_q, block_k):
         out_specs=pl.BlockSpec((1, block_q, d_pad), lambda bb, qi, ki: (bb, qi, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, lse3, di3)
 
     row_spec_kv = pl.BlockSpec((1, block_q, _LANES), lambda bb, ki, qi: (bb, qi, 0))
@@ -416,6 +431,7 @@ def _flash_bwd_impl(q, k, v, out, lse3, g, causal, block_q, block_k):
             pltpu.VMEM((block_k, d_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, lse3, di3)
 
     return (

@@ -23,18 +23,34 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 
 
+def can_tile(t: int, v: int, block_t: int = 256, block_v: int = 512) -> bool:
+    """Whether (tokens, vocab) logits land on the kernel's blocks:
+    tokens a multiple of ``min(block_t, t)`` and vocab a multiple of
+    ``min(block_v, v)``. The ``cross_entropy`` auto loss asks this
+    before it picks the kernel."""
+    return t % min(block_t, t) == 0 and v % min(block_v, v) == 0
+
+
 def _use_kernel(t: int, v: int, block_t: int, block_v: int) -> bool:
     """One predicate for BOTH directions — forward and backward must
-    always pick the same path (kernel vs dense fallback)."""
-    return pltpu is not None and t % block_t == 0 and v % block_v == 0
+    always pick the same path. Off the TPU an untileable shape takes
+    the dense path; on a TPU backend the caller asked for the kernel
+    by name, so it is an error that names the shape and the rule."""
+    if can_tile(t, v, block_t, block_v):
+        return True
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            f"fused_cross_entropy: logits ({t}, {v}) cannot be tiled: "
+            f"tokens must be a multiple of {block_t} and vocab of "
+            f"{block_v}. Pad the vocabulary or use the dense "
+            "'cross_entropy_dense' loss."
+        )
+    return False
 
 
 def _ce_kernel(logits_ref, labels_ref, loss_ref, m_ref, l_ref, p_ref,
@@ -111,6 +127,7 @@ def _ce_impl(logits, labels, block_t, block_v):
             pltpu.VMEM((block_t, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(logits, labels2)
     return out[:, 0]
 
@@ -168,6 +185,7 @@ def _ce_bwd(block_t, block_v, res, g):
         ],
         out_specs=pl.BlockSpec((bt, bv), lambda ti, vi: (ti, vi)),
         interpret=interpret,
+        name="fused_ce_bwd",
     )(logits, labels2, lse2, g2)
     return grad, None
 

@@ -1,68 +1,30 @@
-"""JAX version compatibility shims.
+"""The ambient-mesh probe, on the installed JAX's public API.
 
-The codebase targets current JAX, but deployment images pin older
-releases (this container ships 0.4.x). Two APIs the hot paths use
-landed after 0.4.37; both have exact equivalents there:
-
-- ``jax.lax.axis_size(name)`` — the static size of a mapped axis.
-  Equivalent: ``jax.lax.psum(1, name)``, which JAX constant-folds to
-  the axis size from the static axis env (no collective is emitted).
-- ``jax.set_mesh(mesh)`` as a context manager — the ambient mesh.
-  Equivalent: ``with mesh:`` (``Mesh.__enter__``), which is what
-  resolves shard_map/with_sharding_constraint axis names here.
-
-Call sites import from this module so the same wheel runs on both
-sides of the API change.
+One installation is supported (see ``pyproject.toml``); ``axis_size``
+and ``set_mesh`` are plain aliases kept so call sites read the same.
 """
 
 from __future__ import annotations
 
 import jax
 
-
-def axis_size(name) -> jax.Array:
-    """Static size of the mapped axis ``name`` (int under tracing)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+axis_size = jax.lax.axis_size
+set_mesh = jax.set_mesh
 
 
 def ambient_gspmd_mesh():
-    """The ambient concrete :class:`~jax.sharding.Mesh` when we are in
-    GSPMD context, else None.
+    """The ambient mesh (``jax.set_mesh``) when we are in GSPMD
+    context, else None.
 
-    "GSPMD context" means a mesh is installed (``set_mesh`` / ``with
-    mesh:``) and NONE of its axis names is bound as a manual mapped
-    axis — inside a ``shard_map`` (or pmap) body every mesh axis is
-    Manual, sharding constraints are meaningless-to-wrong there, and
-    collective islands must not nest. The 0.4.x runtime has no
-    ``get_abstract_mesh``/axis-types API, so this is the one
-    version-portable detection point: the physical mesh comes off the
-    thread-local resource env that ``Mesh.__enter__`` installs, and
-    Manual-ness is probed through the trace-state axis env (a bound
-    axis name resolves; an unbound one raises NameError). Fails CLOSED:
-    any API drift returns None, which callers treat as "no mesh" — the
-    plain single-device code path, never a wrong collective."""
-    try:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        if mesh is None or mesh.empty:
-            return None
-        frame = jax.core.axis_frame  # AttributeError on newer jax -> closed
-        for name in mesh.axis_names:
-            try:
-                frame(name)
-                return None  # bound => Manual (shard_map/pmap body)
-            except NameError:
-                continue
-        return mesh
-    except Exception:  # noqa: BLE001 - fail closed across jax versions
+    "GSPMD context" means a mesh is installed and NONE of its axes is
+    Manual — inside a ``shard_map`` body sharding constraints are
+    meaningless-to-wrong and collective islands must not nest. The
+    mesh returned is the :class:`~jax.sharding.AbstractMesh` (the only
+    handle available under ``jit`` tracing): it carries ``shape`` and
+    ``axis_names``, and ``jax.shard_map`` / ``with_sharding_constraint``
+    resolve specs against it. None means exactly "no mesh set" or "an
+    axis is Manual"; an API that moved raises."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
         return None
+    return mesh

@@ -324,15 +324,21 @@ _ALPHA_PROBE_CACHE: Dict[Tuple[str, int], float] = {}
 
 
 def alpha_bytes_for_backend(backend: Optional[str] = None) -> float:
+    """The table's alpha for ``backend`` (default: the running one).
+    A backend the table does not know is an error — it never inherits
+    another backend's guess; set ``SPARKTORCH_TPU_TUNE_ALPHA_BYTES``
+    or calibrate."""
     if backend is None:
-        try:
-            import jax
+        import jax
 
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    return float(DEFAULT_ALPHA_BYTES.get(backend,
-                                         DEFAULT_ALPHA_BYTES["tpu"]))
+        backend = jax.default_backend()
+    if backend not in DEFAULT_ALPHA_BYTES:
+        raise ValueError(
+            f"no default collective alpha for backend {backend!r} "
+            f"(known: {sorted(DEFAULT_ALPHA_BYTES)}); set {ALPHA_ENV} "
+            "or use calibrate_alpha_bytes()"
+        )
+    return float(DEFAULT_ALPHA_BYTES[backend])
 
 
 def calibrate_alpha_bytes(devices: Optional[Sequence[Any]] = None,

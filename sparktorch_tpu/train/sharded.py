@@ -123,12 +123,15 @@ def create_sharded_state(
             )(rng)
     finally:
         jax.config.update("jax_threefry_partitionable", _old_threefry)
+    # step/rng are placed on the mesh too: an off-mesh leaf gives the
+    # first call a different abstract type than the step's own output,
+    # and the second call would compile the whole step again.
     state = TrainState(
-        step=jnp.zeros((), jnp.int32),
+        step=jax.device_put(jnp.zeros((), jnp.int32), replicated(mesh)),
         params=params,
         model_state=mstate,
         opt_state=opt_state,
-        rng=rng,
+        rng=jax.device_put(rng, replicated(mesh)),
     )
     shardings = TrainState(
         step=replicated(mesh),
@@ -156,8 +159,9 @@ def _opt_state_shardings(a_opt, a_params, param_sh, mesh: Mesh):
 
 
 # Env knob for the auto path's persistent-compile-cache arming:
-# unset/1 arms (default ~/.cache/sparktorch_tpu/xla), 0/off disables,
-# any other value is the cache directory.
+# unset/1 arms (utils.checkpoint.arm_compile_cache: the directory
+# JAX_COMPILATION_CACHE_DIR names, else the one fixed path inside the
+# checkout), 0/off disables, any other value is the cache directory.
 XLA_CACHE_ENV = "SPARKTORCH_TPU_XLA_CACHE"
 
 
@@ -191,14 +195,14 @@ def _maybe_arm_xla_cache() -> bool:
     env = (os.environ.get(XLA_CACHE_ENV) or "").strip()
     if env in ("0", "off", "false"):
         return False
-    if env in ("", "1", "true", "on"):
-        cache_dir = os.path.join(os.path.expanduser("~"), ".cache",
-                                 "sparktorch_tpu", "xla")
-    else:
-        cache_dir = env
-    from sparktorch_tpu.utils.checkpoint import arm_persistent_cache
+    from sparktorch_tpu.utils.checkpoint import (
+        arm_compile_cache,
+        arm_persistent_cache,
+    )
 
-    return arm_persistent_cache(cache_dir)
+    if env in ("", "1", "true", "on"):
+        return arm_compile_cache()
+    return arm_persistent_cache(env)
 
 
 def _make_auto_pipeline_step(spec, tx, mesh, tune_result, rng,

@@ -32,29 +32,11 @@ from sparktorch_tpu.parallel.compat import axis_size as _axis_size
 from sparktorch_tpu.parallel.mesh import BATCH_AXES, replicated
 from sparktorch_tpu.utils.data import DataBatch, sample_minibatch
 
-try:  # jax>=0.6 top-level export; fall back for older trees
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the API rename
-    (new keyword ``check_vma``; the legacy API spells it
-    ``check_rep``). ``mesh=None`` means the ambient (set_mesh) mesh:
-    new jax resolves that natively, but 0.4.x requires the concrete
-    handle — resolve it here so island call sites (ring attention, the
-    MoE dispatch relayout) stay version-portable."""
-    if mesh is None:
-        from sparktorch_tpu.parallel.compat import ambient_gspmd_mesh
-
-        mesh = ambient_gspmd_mesh()
-    try:
-        return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover - legacy jax
-        return _shard_map_raw(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off. ``mesh=None``
+    means the ambient (``jax.set_mesh``) mesh."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class TrainState(NamedTuple):

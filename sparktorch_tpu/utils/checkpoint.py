@@ -88,10 +88,7 @@ def _disarm_persistent_cache_after_restore() -> None:
 def persistent_cache_armed() -> bool:
     """Whether the jax persistent compilation cache is currently
     armed (a cache dir is configured)."""
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except AttributeError:
-        return False
+    return bool(jax.config.jax_compilation_cache_dir)
 
 
 def arm_persistent_cache(cache_dir: str,
@@ -116,26 +113,37 @@ def arm_persistent_cache(cache_dir: str,
         return False
     if persistent_cache_armed():
         return True
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_s))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except AttributeError:
-        # A knob renamed on this build: never leave the cache HALF
-        # armed (dir set, thresholds defaulted, latch not reset).
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except AttributeError:
-            pass
-        return False
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 - private API; the latch may bite
-        pass
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_time_s))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _cc.reset_cache()
     return True
+
+
+# The one place the program's compile cache lives when nobody placed
+# it from outside: a fixed path inside the checkout (git-ignored). The
+# path is part of the cache key, so it is never built from a
+# temporary name, a pid or a time.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
+
+def arm_compile_cache(min_compile_time_s: float = 0.3) -> bool:
+    """The program's one rule for where compiled code is kept. With
+    ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses that directory
+    and no directory is set in code; otherwise the cache is armed at
+    :data:`DEFAULT_COMPILE_CACHE_DIR`. Every entry point that wants a
+    warm second run (``bench.main``, ``chip_smoke.py``, the
+    ``mesh='auto'`` path, the benchmark scripts) calls this and names
+    no path of its own."""
+    return arm_persistent_cache(DEFAULT_COMPILE_CACHE_DIR,
+                                min_compile_time_s)
 
 
 _ORBAX_TMP_MARKER = ".orbax-checkpoint-tmp"

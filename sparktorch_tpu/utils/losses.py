@@ -99,27 +99,34 @@ def cross_entropy_auto(preds: jax.Array, targets: jax.Array) -> jax.Array:
     workload it was built for (CausalLM training) — at trace time;
     everything else takes the dense path.
 
-    GSPMD-aware fallback: under a GSPMD mesh on a non-TPU backend the
-    Pallas kernel runs in INTERPRET mode and lowers to a while loop
-    the partitioner can only handle by all-gathering the logits into
-    every shard — a spurious all-gather that pollutes collective
-    profiles and, now that the goodput ledger attributes exposed comm,
-    the ``exposed_comm`` bucket (ROADMAP item-1 follow-up; the
-    bench_moe_a2a docstring documents the same artifact). Real TPU
-    keeps the kernel: the compiled Pallas call partitions cleanly and
-    the streaming-CE memory win is the whole point there. Both trace-
-    time probes fail CLOSED (``ambient_gspmd_mesh`` returns None on
-    any API drift, and inside shard_map bodies — where the fused
-    kernel is the right choice — every mesh axis is Manual, so the
-    mesh probe reads None and the kernel stays)."""
+    Two trace-time reasons to stay dense, both read from what the
+    trace can see:
+
+    - the (tokens, vocab) shape does not land on the kernel's blocks
+      (:func:`sparktorch_tpu.ops.fused_ce.can_tile` — e.g. BERT's
+      30,522 vocabulary). "auto" may choose; a caller who names
+      ``cross_entropy_fused`` gets an error on the TPU instead.
+    - a GSPMD (non-Manual) ambient mesh: a Pallas call cannot be
+      partitioned automatically. The TPU compiler refuses it ("Mosaic
+      kernels cannot be automatically partitioned. Please wrap the
+      call in a shard_map" — found compiling the ep=4 MoE step for a
+      described v5e:2x2), and off the TPU the interpret-mode kernel
+      lowers to a while loop the partitioner can only handle by
+      all-gathering the logits into every shard. Inside shard_map
+      bodies — the DP and pipeline trainers, where the fused kernel is
+      the right choice — every mesh axis is Manual, so the mesh probe
+      reads None and the kernel stays."""
     lm_shaped = preds.ndim == 3 and not (
         jnp.issubdtype(targets.dtype, jnp.floating) and targets.shape == preds.shape
     )
     if lm_shaped:
+        from sparktorch_tpu.ops.fused_ce import can_tile
         from sparktorch_tpu.parallel.compat import ambient_gspmd_mesh
 
-        if jax.default_backend() != "tpu" \
-                and ambient_gspmd_mesh() is not None:
+        b, s, v = preds.shape
+        if not can_tile(b * s, v):
+            return cross_entropy_loss(preds, targets)
+        if ambient_gspmd_mesh() is not None:
             return cross_entropy_loss(preds, targets)
         return fused_cross_entropy_loss(preds, targets)
     return cross_entropy_loss(preds, targets)

@@ -68,8 +68,10 @@ if os.environ.get("SPARKTORCH_TPU_TEST_FASTCOMPILE"):
 #   checkpoint test re-runs itself in a SUBPROCESS (fresh process =
 #   no prior restore = cache armed all the way through it); the
 #   escape hatch for rigs where the in-process disarm is not enough.
+# - JAX_COMPILATION_CACHE_DIR set -> jax already uses that directory;
+#   the suite sets none of its own
 _CACHE_DIR = os.environ.get("SPARKTORCH_TPU_TEST_CACHE")
-if _CACHE_DIR in ("0", "off"):
+if _CACHE_DIR in ("0", "off") or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     _CACHE_DIR = None
 elif not _CACHE_DIR:
     import atexit
@@ -88,7 +90,7 @@ if _CACHE_DIR:
 # The mesh="auto" builder's own persistent-cache arming
 # (SPARKTORCH_TPU_XLA_CACHE) is OFF by default for the suite: the
 # session cache above already covers the suite, and a test must never
-# write into the user's ~/.cache. Cache tests opt in explicitly.
+# write into the checkout's cache directory. Cache tests opt in explicitly.
 os.environ.setdefault("SPARKTORCH_TPU_XLA_CACHE", "0")
 
 # The tune-result cache is OFF by default for the suite: tests must

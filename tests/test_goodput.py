@@ -205,7 +205,7 @@ def test_publish_gauges_and_sink_event():
 def test_merge_sections_run_level():
     a = {"rank": 0, "wall_s": 10.0, "n_steps": 10, "compiles": 1,
          "comm_source": "measured", "overattributed_s": 0.0,
-         "flops_per_step": 1e12,
+         "flops_per_step": 1e12, "peak_tflops": 197.0,
          "counts": {"compile": 1},
          "buckets": {"compute": 6.0, "exposed_comm": 1.0, "compile": 2.0,
                      "checkpoint": 0.0, "data_wait": 0.0,
@@ -227,7 +227,14 @@ def test_merge_sections_run_level():
     assert run["biggest_thief"]["bucket"] == "idle"
     # MFU aggregates over the flops-declaring rank's chip-seconds.
     assert run["mfu"] == pytest.approx(
-        gp.mfu_honest(10 * 1e12 / 10.0 / 1e12), abs=1e-6)
+        gp.mfu_honest(10 * 1e12 / 10.0 / 1e12, 197.0), abs=1e-6)
+    # A flops-declaring rank whose device is not in the peak table
+    # carries no peak: the run keeps its rate and gets NO mfu — never
+    # another device's number.
+    no_peak = {k: v for k, v in a.items() if k != "peak_tflops"}
+    run_np = gp.merge_sections({0: no_peak})
+    assert "mfu" not in run_np
+    assert run_np["achieved_tflops_per_chip"] == pytest.approx(1.0)
     # Docs without buckets (a rank that never published) are skipped.
     assert gp.merge_sections({0: a, 1: {"rank": 1}})["n_ranks"] == 1
     # A multi-chip rank's declared capacity (n_chips, peak) divides
@@ -533,10 +540,11 @@ def test_postmortem_bundle_carries_goodput(tmp_path):
 
 
 def test_cross_entropy_auto_gspmd_dense_fallback():
-    """Under a GSPMD mesh on CPU the LM-shaped CE must lower to the
-    dense path (no interpret-mode Pallas while loop for the
-    partitioner to all-gather logits into); without a mesh the fused
-    kernel stays (the while loop is its interpret lowering)."""
+    """Under a GSPMD mesh the LM-shaped CE must lower to the dense
+    path (a Pallas call cannot be partitioned automatically: the TPU
+    compiler refuses it, and on CPU the interpret-mode while loop
+    makes the partitioner all-gather the logits); without a mesh the
+    fused kernel stays (the while loop is its interpret lowering)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -544,8 +552,6 @@ def test_cross_entropy_auto_gspmd_dense_fallback():
     from sparktorch_tpu.parallel.compat import set_mesh
     from sparktorch_tpu.utils.losses import cross_entropy_auto
 
-    if jax.default_backend() == "tpu":
-        pytest.skip("CPU-interpret-mode artifact; TPU keeps the kernel")
     devs = np.array(jax.devices()).reshape(-1, 1)
     mesh = Mesh(devs, ("dp", "tp"))
     x = jnp.zeros((8, 16, 512), jnp.float32)

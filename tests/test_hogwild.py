@@ -230,7 +230,7 @@ def test_hogwild_push_every_accumulates(payload, monkeypatch):
 
 
 def test_hogwild_phase_budget_sums_to_whole(payload):
-    """The per-phase budget (VERDICT r04 item 3): every worker's loop
+    """The per-phase budget: every worker's loop
     wall decomposes into pull / placement / dispatch / materialize /
     wire / poll / other, summing to the whole; the http transport also
     counts wire bytes; shuffle rounds don't double-count."""

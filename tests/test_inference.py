@@ -68,7 +68,7 @@ def test_fitted_model_set_mesh(data):
 
 
 def test_parquet_streaming_matches_direct(tmp_path, trained):
-    """VERDICT r2 item 2: the columnar-ingest->device streaming path.
+    """The columnar-ingest->device streaming path.
     Rows written as raw fixed-size binary Parquet must stream through
     the reader thread + double-buffered predictor and match the direct
     in-memory predict, with uint8 ingest decoded ON DEVICE via the

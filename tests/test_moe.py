@@ -132,10 +132,16 @@ def test_moe_top2_trains_and_ep_parity():
     the explicit a2a dispatch keeps ep=2 a pure layout choice even at
     k=2 (choice-priority capacity assignment is per-group, and every
     group routes on exactly one device)."""
-    l1 = _run_steps(MeshConfig(ep=1), n_steps=8, moe_top_k=2)
+    # float32 compute: at bf16 the two layouts are BITWISE equal for
+    # five steps on this jax, then one reduction-order ulp flips a
+    # bf16 rounding and adamw amplifies it past 1e-5 (3e-4 by step 8)
+    # — chaos, not layout. f32 holds 2e-7 over ten steps.
+    l1 = _run_steps(MeshConfig(ep=1), n_steps=8, moe_top_k=2,
+                    dtype="float32")
     assert all(np.isfinite(l1))
     assert l1[-1] < l1[0], l1
-    l2 = _run_steps(MeshConfig(ep=2), n_steps=8, moe_top_k=2)
+    l2 = _run_steps(MeshConfig(ep=2), n_steps=8, moe_top_k=2,
+                    dtype="float32")
     np.testing.assert_allclose(l1, l2, rtol=1e-5)
 
 
@@ -222,7 +228,7 @@ def test_moe_gspmd_ep_lowers_to_all_to_all():
     """The explicit shard_map dispatch (transformer.py MoEFFN /
     _ep_relayout) must land REAL dispatch/combine all-to-alls in the
     compiled ep=2 train step — the GShard scaling property, not token
-    replication (VERDICT r04 item 2). Asserted on the compiled HLO of
+    replication. Asserted on the compiled HLO of
     the actual train step."""
     hlo = _compiled_ep2_hlo(moe_group_size=16)
     assert "all-to-all" in hlo, "no all-to-all in the ep=2 MoE step HLO"

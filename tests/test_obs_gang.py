@@ -445,7 +445,7 @@ def test_gang_coordinator_rejects_line_unsafe_run_id():
         pass
 
 
-def test_scrape_helpers_error_taxonomy(tmp_path):
+def test_scrape_helpers_error_classes(tmp_path):
     with pytest.raises(ScrapeError):
         scrape_text("http://127.0.0.1:9/metrics")
     with pytest.raises(ScrapeError):

@@ -166,7 +166,7 @@ def test_pipeline_layer_math_matches_encoder_layer():
 
 
 def test_pipeline_via_modelspec_and_estimator():
-    """VERDICT r2 item 3: pp is a MESH choice on the ordinary surface —
+    """Pp is a MESH choice on the ordinary surface —
     a CausalLM ModelSpec fit through the Estimator with a pp=2 mesh
     trains pipelined and the fitted model transforms normally."""
     from sparktorch_tpu.ml.estimator import SparkTorch
@@ -569,7 +569,7 @@ def test_pp_mini_batch_validation():
 def test_pp_trainer_knobs_end_to_end(tmp_path):
     """The estimator-level contract: train_distributed on a pp mesh
     accepts mini_batch + steps_per_call + profile_dir together and
-    trains (VERDICT r03 item 4 — the full Param surface on pp)."""
+    trains (the full Param surface on pp)."""
     from sparktorch_tpu.models import CausalLM
     from sparktorch_tpu.train.sync import train_distributed
 
@@ -599,8 +599,8 @@ def test_pp_trainer_knobs_end_to_end(tmp_path):
 
 
 def test_pp_ep_composition_parity():
-    """Experts shard ACROSS chips within a pipeline stage (VERDICT r03
-    item 5): pp=2 x ep=2 must reproduce pp=2 x ep=1 — and transitively
+    """Experts shard ACROSS chips within a pipeline stage: pp=2 x ep=2
+    must reproduce pp=2 x ep=1 — and transitively
     the GSPMD trainer, whose parity vs ep=1 the MoE suite pins — to
     summation-order tolerance. SGD at lr=1 would expose any mis-scaled
     router/aux gradient immediately; Adam loss parity covers the rest."""
@@ -868,7 +868,7 @@ def _a2a_cfg(**over):
 
 
 def test_pp_ep_a2a_parity():
-    """The all-to-all expert dispatch (VERDICT r04 item 2) must be a
+    """The all-to-all expert dispatch must be a
     LAYOUT choice: on matched init, 'a2a' must reproduce 'replicate'
     (and ep=1) — Adam loss curves plus one SGD lr=1 step at parameter
     level, which catches any mis-scaled router/aux/expert gradient the
@@ -971,7 +971,7 @@ def test_pp_ep_a2a_memory_delta():
 
 
 def test_pp_sp_ring_exactness():
-    """pp x sp composition (VERDICT r04 item 4): ring attention rides
+    """pp x sp composition: ring attention rides
     the pp schedule's own shard_map, so a pp=2 x sp=2 run with
     attn_impl='ring' must reproduce the pp=2 dense run on matched init
     — the ring IS dense attention, computed blockwise. Adam loss

@@ -74,7 +74,7 @@ def test_validation_split_and_early_stop():
 
 
 def test_early_stop_fused_matches_per_step():
-    """VERDICT r2 item 6: an EXPLICIT steps_per_call > 1 with early
+    """An EXPLICIT steps_per_call > 1 with early
     stopping must stop within one step of the per-step path — the stop
     decision rides the fused scan (EsState), masking post-stop steps."""
     x, y = _blob_data()
