@@ -11,8 +11,13 @@ Prometheus text for the param server's ``/metrics`` route.
 Design constraints:
 
 - **Hot-path cheap.** A counter bump is a dict add under one lock; a
-  span is two ``perf_counter`` calls. Nothing here touches the device
-  unless the caller explicitly asks (``Span.sync``).
+  span is two ``perf_counter`` calls and a ``TraceAnnotation`` (one
+  flag check while no profiler session runs). Nothing here touches the
+  device unless the caller explicitly asks (``Span.sync``).
+- **On the profiler's clock.** Every span is a host event of its path
+  in any ``jax.profiler`` trace, so a gap on the device's lines is
+  named by the program span the host was in. ``jax`` is imported at
+  the first span, not with this module.
 - **Bounded memory.** Histograms keep streaming count/sum/min/max plus
   a fixed-size ring of recent samples for the percentile roll-ups — a
   million-step run holds O(ring), not O(steps).
@@ -131,6 +136,73 @@ def rollup_from_state(state: Tuple[int, float, float, float,
     }
 
 
+# ---------------------------------------------------------------------------
+# The profiler and the compile events, shared by every bus of the process
+# ---------------------------------------------------------------------------
+
+# jax.monitoring duration event -> histogram it is filed under, with the
+# label ``span=<path of the innermost span open on the calling thread>``.
+# These events nest (tracing a function traces the jitted functions it
+# calls, each with an event of its own; ``backend_compile_duration``
+# wraps the persistent cache's lookup), so a sample is the event's OWN
+# time, its duration less the events that ended inside it on the same
+# thread: the four histograms of one span add up to wall time.
+JIT_EVENT_HISTOGRAMS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower_s",
+    "/jax/core/compile/backend_compile_duration": "jit.compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jit.cache_load_s",
+}
+
+# Per thread: ``stack``, the spans open on it over ALL buses, innermost
+# last, as ``(bus, span)`` (the compile listener routes by it; each bus
+# keeps its own stack beside it to build paths), and ``jit_ended``, the
+# ``(arrival, duration)`` of the compile events no later one contained.
+_OPEN = threading.local()
+_JIT_ENDED_KEPT = 1 << 16
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation once imported
+_JAX_HOOKS_LOCK = threading.Lock()
+
+
+def _on_jax_duration(event: str, duration_s: float, **_kw: Any) -> None:
+    name = JIT_EVENT_HISTOGRAMS.get(event)
+    if name is None:
+        return
+    now = time.perf_counter()
+    ended = getattr(_OPEN, "jit_ended", None)
+    if ended is None:
+        ended = _OPEN.jit_ended = []
+    own = duration_s
+    while ended and ended[-1][0] >= now - duration_s:
+        own -= ended.pop()[1]
+    ended.append((now, duration_s))
+    # One entry stays per outermost event; tracing one large program
+    # leaves thousands waiting for it (5,952 for a BERT-base step).
+    if len(ended) > _JIT_ENDED_KEPT:
+        del ended[:_JIT_ENDED_KEPT // 2]
+    stack = getattr(_OPEN, "stack", None)
+    if stack:
+        bus, span = stack[-1]
+        bus.observe(name, max(own, 0.0), {"span": span.path})
+
+
+def _jax_hooks():
+    """``jax.profiler.TraceAnnotation``, imported at the first span of
+    the process; the same moment registers the one compile listener
+    (``jax.monitoring`` listeners cannot be unregistered, so it is one
+    per process and routes to the bus that owns the open span)."""
+    global _TRACE_ANNOTATION
+    with _JAX_HOOKS_LOCK:
+        if _TRACE_ANNOTATION is None:
+            import jax.monitoring
+            import jax.profiler
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
 class Span:
     """One timed region, yielded by :meth:`Telemetry.span`.
 
@@ -177,6 +249,9 @@ class Telemetry:
         self._gauges: Dict[MetricKey, float] = {}
         self._hists: Dict[MetricKey, _Hist] = {}
         self._spans: Dict[MetricKey, _Hist] = {}
+        # each span sample's start (perf_counter), beside its duration
+        # in ``_spans[key].ring`` and bounded like it
+        self._span_starts: Dict[MetricKey, "collections.deque[float]"] = {}
         self._info: Dict[MetricKey, str] = {}
         self._sections: Dict[str, Any] = {}
         self._sinks: List[Callable[[Dict[str, Any]], None]] = []
@@ -258,25 +333,38 @@ class Telemetry:
         """Nestable timed region. The span records under its full
         slash-joined path (``train/step`` inside ``train``), so nested
         timings stay attributable; completion emits one event to the
-        sinks and one histogram sample."""
+        sinks and one sample (start and duration). For its life the
+        span is a ``TraceAnnotation`` of that path, and the compile
+        events of the thread are filed under it (``jit.*_s``)."""
         stack: List[Span] = getattr(self._tls, "stack", None)
         if stack is None:
             stack = self._tls.stack = []
+        opened = getattr(_OPEN, "stack", None)
+        if opened is None:
+            opened = _OPEN.stack = []
         parent = stack[-1] if stack else None
         path = f"{parent.path}/{name}" if parent is not None else name
         span = Span(name, path, dict(labels or {}), depth=len(stack))
         stack.append(span)
+        opened.append((self, span))
         try:
-            yield span
+            with (_TRACE_ANNOTATION or _jax_hooks())(path):
+                yield span
         finally:
             span.duration_s = time.perf_counter() - span.t0
+            opened.pop()
             stack.pop()
             k = _key(path, labels)
             with self._lock:
                 hist = self._spans.get(k)
                 if hist is None:
                     hist = self._spans[k] = _Hist(self._ring_size)
+                starts = self._span_starts.get(k)
+                if starts is None:
+                    starts = self._span_starts[k] = collections.deque(
+                        maxlen=self._ring_size)
                 hist.observe(span.duration_s)
+                starts.append(span.t0)
             self.event("span", name=path, dur_s=span.duration_s,
                        depth=span.depth, synced=span.synced,
                        **span.labels)
@@ -346,6 +434,22 @@ class Telemetry:
         return (rollup_from_state(state) if state is not None
                 else rollup_from_state((0, 0.0, 0.0, 0.0, ())))
 
+    def span_samples(self, path: str,
+                     labels: Optional[Dict[str, Any]] = None
+                     ) -> List[Tuple[float, float]]:
+        """``[(t0, dur_s)]`` of one span's retained samples, oldest
+        first: ``t0`` on ``time.perf_counter``'s clock, at most the
+        ring's size of them."""
+        k = _key(path, labels)
+        with self._lock:
+            hist = self._spans.get(k)
+            starts = self._span_starts.get(k)
+            if hist is None or not starts:
+                return []
+            # a bus restored from an older pickle has durations whose
+            # starts were never kept: the newest samples have both
+            return list(zip(starts, list(hist.ring)[-len(starts):]))
+
     def snapshot(self) -> Dict[str, Any]:
         """One coherent view of every metric: counters and gauges as
         flat ``name{labels}`` -> value dicts, histograms and spans as
@@ -396,6 +500,7 @@ class Telemetry:
             self._gauges.clear()
             self._hists.clear()
             self._spans.clear()
+            self._span_starts.clear()
             self._info.clear()
             self._sections.clear()
 
@@ -416,6 +521,7 @@ class Telemetry:
                 "_gauges": dict(self._gauges),
                 "_hists": dict(self._hists),
                 "_spans": dict(self._spans),
+                "_span_starts": dict(self._span_starts),
                 "_info": dict(self._info),
                 "_sections": dict(self._sections),
             }
@@ -424,6 +530,7 @@ class Telemetry:
         self.__dict__.update(state)
         self.__dict__.setdefault("_info", {})  # pre-info pickles
         self.__dict__.setdefault("_sections", {})  # pre-section pickles
+        self.__dict__.setdefault("_span_starts", {})  # pre-start pickles
         self._lock = threading.Lock()
         self._sinks = []
         self._tls = threading.local()

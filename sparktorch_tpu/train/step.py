@@ -279,15 +279,23 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
     Per-shard sampling key: replicated rng folded with the shard index —
     data selection differs per shard, carried rng stays replicated so
     the output state is provably identical on all shards.
+
+    The phases carry ``jax.named_scope``s (``sample``, ``forward_loss``
+    with its backward ``transpose(jvp(forward_loss))``,
+    ``grad_allreduce``, ``optimizer``, ``step_stats``): metadata of the
+    compiled operations only, by which a device trace gives the step's
+    time by phase.
     """
-    rng, next_rng = jax.random.split(state.rng)
-    sample_key = jax.random.fold_in(rng, _shard_index(axis_names))
+    with jax.named_scope("sample"):
+        rng, next_rng = jax.random.split(state.rng)
+        sample_key = jax.random.fold_in(rng, _shard_index(axis_names))
 
-    if per_shard_mb is not None and per_shard_mb < batch.x.shape[0]:
-        mb = sample_minibatch(batch, sample_key, per_shard_mb)
-    else:
-        mb = batch
+        if per_shard_mb is not None and per_shard_mb < batch.x.shape[0]:
+            mb = sample_minibatch(batch, sample_key, per_shard_mb)
+        else:
+            mb = batch
 
+    @jax.named_scope("forward_loss")
     def weighted_sums(params):
         preds, new_model_state, sown, sown_metrics = _forward(
             apply_fn, params, state.model_state, mb.x, train=True,
@@ -307,44 +315,49 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
     )(state.params)
 
     # ONE fused collective for everything the step needs globally.
-    num_g = jax.lax.psum(num, axis_names)
-    den_g = jax.lax.psum(den, axis_names)
-    grads_g = jax.lax.psum(grads_num, axis_names)
-    safe_den = jnp.maximum(den_g, 1.0)
-    grads = jax.tree.map(lambda g: g / safe_den, grads_g)
-    loss = num_g / safe_den
-    drop_fraction = None
-    if drop_counts is not None:
-        dropped_g = jax.lax.psum(drop_counts[0], axis_names)
-        routed_g = jax.lax.psum(drop_counts[1], axis_names)
-        drop_fraction = dropped_g / jnp.maximum(routed_g, 1.0)
+    with jax.named_scope("grad_allreduce"):
+        num_g = jax.lax.psum(num, axis_names)
+        den_g = jax.lax.psum(den, axis_names)
+        grads_g = jax.lax.psum(grads_num, axis_names)
+        safe_den = jnp.maximum(den_g, 1.0)
+        grads = jax.tree.map(lambda g: g / safe_den, grads_g)
+        loss = num_g / safe_den
+        drop_fraction = None
+        if drop_counts is not None:
+            dropped_g = jax.lax.psum(drop_counts[0], axis_names)
+            routed_g = jax.lax.psum(drop_counts[1], axis_names)
+            drop_fraction = dropped_g / jnp.maximum(routed_g, 1.0)
 
-    # Non-trainable collections (batch_stats) sync by global mean.
-    if state.model_state:
-        new_model_state = jax.tree.map(
-            lambda a: jax.lax.pmean(a, axis_names)
-            if jnp.issubdtype(a.dtype, jnp.floating)
-            else a,
-            new_model_state,
-        )
-    updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
-    gnorm = optax.global_norm(grads)
+        # Non-trainable collections (batch_stats) sync by global mean.
+        if state.model_state:
+            new_model_state = jax.tree.map(
+                lambda a: jax.lax.pmean(a, axis_names)
+                if jnp.issubdtype(a.dtype, jnp.floating)
+                else a,
+                new_model_state,
+            )
+    with jax.named_scope("optimizer"):
+        updates, new_opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+        new_params = optax.apply_updates(state.params, updates)
 
     # Model-health vector (obs/health.py): tiny fused reductions, no
     # extra collectives — grads are already globally psum'd above.
-    grad_leaves = jax.tree.leaves(grads)
-    leaf_norms = (
-        jnp.stack([jnp.sqrt(jnp.sum(jnp.square(g))).astype(jnp.float32)
-                   for g in grad_leaves])
-        if grad_leaves else jnp.zeros((0,), jnp.float32)
-    )
-    health = HealthVec(
-        finite=(jnp.isfinite(loss) & jnp.isfinite(gnorm)).astype(jnp.float32),
-        update_ratio=optax.global_norm(updates)
-        / jnp.maximum(optax.global_norm(new_params), 1e-12),
-        leaf_norms=leaf_norms,
-    )
+    with jax.named_scope("step_stats"):
+        gnorm = optax.global_norm(grads)
+        grad_leaves = jax.tree.leaves(grads)
+        leaf_norms = (
+            jnp.stack([jnp.sqrt(jnp.sum(jnp.square(g))).astype(jnp.float32)
+                       for g in grad_leaves])
+            if grad_leaves else jnp.zeros((0,), jnp.float32)
+        )
+        health = HealthVec(
+            finite=(jnp.isfinite(loss)
+                    & jnp.isfinite(gnorm)).astype(jnp.float32),
+            update_ratio=optax.global_norm(updates)
+            / jnp.maximum(optax.global_norm(new_params), 1e-12),
+            leaf_norms=leaf_norms,
+        )
 
     new_state = TrainState(
         step=state.step + 1,
@@ -382,14 +395,18 @@ def make_train_step(
     if mini_batch is not None and mini_batch > 0:
         per_shard_mb = mini_batch
 
-    def shard_step(state: TrainState, batch: DataBatch):
+    # The function's name is the compiled program's (``jit_train_step``
+    # on a trace's module line) and part of the persistent cache's key,
+    # which leaves locations out: under the name it had before the
+    # scopes, a cache could hand back a program compiled without them.
+    def train_step(state: TrainState, batch: DataBatch):
         return _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
                         state, batch)
 
     data_spec = P(axis_names)
     batch_specs = DataBatch(x=data_spec, y=data_spec, w=data_spec)
     mapped = shard_map_compat(
-        shard_step,
+        train_step,
         mesh,
         in_specs=(P(), batch_specs),
         out_specs=(P(), P()),
@@ -417,7 +434,7 @@ def make_train_epoch(
     if mini_batch is not None and mini_batch > 0:
         per_shard_mb = mini_batch
 
-    def shard_epoch(state: TrainState, batch: DataBatch):
+    def train_epoch(state: TrainState, batch: DataBatch):  # see train_step
         def one_step(state: TrainState, _):
             return _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
                             state, batch)
@@ -427,7 +444,7 @@ def make_train_epoch(
     data_spec = P(axis_names)
     batch_specs = DataBatch(x=data_spec, y=data_spec, w=data_spec)
     mapped = shard_map_compat(
-        shard_epoch,
+        train_epoch,
         mesh,
         in_specs=(P(), batch_specs),
         out_specs=(P(), P()),
@@ -496,7 +513,8 @@ def make_train_epoch_fused(
         den = jax.lax.psum(jnp.sum(vb.w), axis_names)
         return num / jnp.maximum(den, 1.0)
 
-    def shard_epoch(carry, batch: DataBatch, val_batch: Optional[DataBatch]):
+    def train_epoch_fused(carry, batch: DataBatch,
+                          val_batch: Optional[DataBatch]):
         def one_step(carry, _):
             state, es = carry
             active = ~es.stopped
@@ -535,15 +553,17 @@ def make_train_epoch_fused(
     carry_specs = (P(), P())
     if with_val:
         mapped = shard_map_compat(
-            shard_epoch,
+            train_epoch_fused,
             mesh,
             in_specs=(carry_specs, batch_specs, batch_specs),
             out_specs=((P(), P()), P()),
         )
     else:
-        fn = lambda carry, batch: shard_epoch(carry, batch, None)
+        def train_epoch_fused_noval(carry, batch):
+            return train_epoch_fused(carry, batch, None)
+
         mapped = shard_map_compat(
-            fn,
+            train_epoch_fused_noval,
             mesh,
             in_specs=(carry_specs, batch_specs),
             out_specs=((P(), P()), P()),

@@ -15,6 +15,7 @@ weight-zero padding (see utils/data.py).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, NamedTuple, Optional, Union
 
 import jax
@@ -195,8 +196,13 @@ def train_distributed(
     O(V*pp) activation memory).
     """
     del device
-    spec = deserialize_model(torch_obj)
-    mesh = mesh or build_mesh()
+    tele = telemetry or get_telemetry()
+    # The span's start is the call's entry stamp: what lies between
+    # the process's start and it is the caller's (imports, the PJRT
+    # client, the rows).
+    with tele.span("train/enter"):
+        spec = deserialize_model(torch_obj)
+        mesh = mesh or build_mesh()
 
     from sparktorch_tpu.parallel.mesh import AXIS_PP
 
@@ -228,7 +234,6 @@ def train_distributed(
             telemetry=telemetry,
         )
 
-    tele = telemetry or get_telemetry()
     # The continuous stack sampler lives wherever ledgers live: the
     # ambient ledger names the thieving bucket, the sampler names the
     # function inside it. Env-gated; idempotent per process.
@@ -263,7 +268,8 @@ def train_distributed(
                 val_batch = prepare_sharded_batch(val_batch, mesh)
 
     rng = jax.random.key(seed)
-    tx = spec.make_optimizer()
+    with tele.span("train/build_step"):
+        tx = spec.make_optimizer()
     if pre_sharded:
         # Slicing a non-fully-addressable global array is not allowed;
         # init from an abstract sample of the right shape instead.
@@ -289,60 +295,61 @@ def train_distributed(
     if _hl is not None and _hl.leaf_keys is None:
         _hl.leaf_keys = _health.health_leaf_keys(state.params)
 
-    loss_fn = spec.loss_fn()
-    module = spec.make_module()
+    with tele.span("train/build_step"):
+        loss_fn = spec.loss_fn()
+        module = spec.make_module()
 
-    stopper = (
-        EarlyStopping(patience=early_stop_patience)
-        if early_stop_patience is not None and early_stop_patience > 0
-        else None
-    )
-    # Fast path: fuse many steps into one compiled call (lax.scan).
-    # Early stopping / the val forward no longer force 1 step/call:
-    # the stop decision and per-iter val forward ride INSIDE the fused
-    # scan (make_train_epoch_fused) with exact per-step semantics —
-    # post-stop steps are masked to no-ops, so the only fusion cost is
-    # the masked tail of the chunk where the stop fires (hence the
-    # smaller default chunk there).
-    steps_per_call = _resolve_steps_per_call(
-        steps_per_call,
-        default=(
-            min(iters, 8)
-            if (stopper is not None or val_batch is not None)
-            else min(iters, 32)
-        ),
-        iters=iters,
-        checkpoint_every=checkpoint_every,
-        ckpt_active=ckpt is not None,
-    )
-
-    fused_signals = steps_per_call > 1 and (
-        stopper is not None or val_batch is not None
-    )
-    es_state = init_es_state() if fused_signals else None
-    if fused_signals:
-        train_step = make_train_epoch_fused(
-            module.apply, loss_fn, tx, mesh, steps_per_call,
-            es_config=(
-                EsConfig(patience=early_stop_patience)
-                if stopper is not None else None
+        stopper = (
+            EarlyStopping(patience=early_stop_patience)
+            if early_stop_patience is not None and early_stop_patience > 0
+            else None
+        )
+        # Fast path: fuse many steps into one compiled call (lax.scan).
+        # Early stopping / the val forward no longer force 1 step/call:
+        # the stop decision and per-iter val forward ride INSIDE the fused
+        # scan (make_train_epoch_fused) with exact per-step semantics —
+        # post-stop steps are masked to no-ops, so the only fusion cost is
+        # the masked tail of the chunk where the stop fires (hence the
+        # smaller default chunk there).
+        steps_per_call = _resolve_steps_per_call(
+            steps_per_call,
+            default=(
+                min(iters, 8)
+                if (stopper is not None or val_batch is not None)
+                else min(iters, 32)
             ),
-            with_val=val_batch is not None,
-            mini_batch=mini_batch,
+            iters=iters,
+            checkpoint_every=checkpoint_every,
+            ckpt_active=ckpt is not None,
         )
-    elif steps_per_call > 1:
-        train_step = make_train_epoch(
-            module.apply, loss_fn, tx, mesh, steps_per_call, mini_batch=mini_batch
+
+        fused_signals = steps_per_call > 1 and (
+            stopper is not None or val_batch is not None
         )
-    else:
-        train_step = make_train_step(
-            module.apply, loss_fn, tx, mesh, mini_batch=mini_batch
+        es_state = init_es_state() if fused_signals else None
+        if fused_signals:
+            train_step = make_train_epoch_fused(
+                module.apply, loss_fn, tx, mesh, steps_per_call,
+                es_config=(
+                    EsConfig(patience=early_stop_patience)
+                    if stopper is not None else None
+                ),
+                with_val=val_batch is not None,
+                mini_batch=mini_batch,
+            )
+        elif steps_per_call > 1:
+            train_step = make_train_epoch(
+                module.apply, loss_fn, tx, mesh, steps_per_call, mini_batch=mini_batch
+            )
+        else:
+            train_step = make_train_step(
+                module.apply, loss_fn, tx, mesh, mini_batch=mini_batch
+            )
+        eval_step = (
+            make_eval_step(module.apply, loss_fn, mesh)
+            if val_batch is not None and not fused_signals
+            else None
         )
-    eval_step = (
-        make_eval_step(module.apply, loss_fn, mesh)
-        if val_batch is not None and not fused_signals
-        else None
-    )
 
     from sparktorch_tpu.utils.metrics import MetricsRecorder
     from sparktorch_tpu.utils.tracing import profile_run, step_annotation
@@ -353,6 +360,15 @@ def train_distributed(
     # lint-obs: ok (pre-loop scalar — nothing queued yet)
     last_ckpt_step = int(jax.device_get(state.step)) if ckpt is not None else 0
     shuffle_key = jax.random.key(seed + 1)
+    # What the hook gets beside the recorder's record: set per chunk.
+    leaf_rows = None
+    leaf_keys = (_health.health_leaf_keys(state.params)
+                 if metrics_hook else None)
+    # On the fused path three host spans tile an iteration together
+    # with train/step_chunk (prepare, readback, records): a few a
+    # chunk. The per-step path keeps its one train/step span a step.
+    chunk_span = (tele.span if steps_per_call > 1
+                  else lambda _name: contextlib.nullcontext())
     profiler = profile_run(profile_dir, telemetry=tele)
     profiler.__enter__()
     completed = False
@@ -370,48 +386,49 @@ def train_distributed(
             stop = False
             i = 0
             while i < iters:
-                # Fail fast if a peer host died (multi-host runs only; the
-                # gang's heartbeat marks survivors dead within one
-                # interval). Checking here — before dispatching the next
-                # compiled chunk — means we raise GangFailure instead of
-                # wedging in the chunk's collectives. The same spot
-                # publishes this rank's progress on its heartbeat so
-                # the driver can read cross-rank step skew, and hosts
-                # the chaos kill point (a seeded injection dies here,
-                # between compiled dispatches — where a real preempt
-                # lands; ft.supervisor.supervise_run then restarts the
-                # attempt resuming from the latest checkpoint).
-                check_gang()
-                notify_gang_step(i)
-                # `i` (the round-local iteration), not state.step: the
-                # latter would cost a device sync per chunk on the hot
-                # path; one-shot kill configs make the distinction
-                # irrelevant across resumes.
-                _chaos.fire("worker.step", worker=jax.process_index(),
-                            step=i)
-                # Seeded poison-batch injection (bench-health drill):
-                # the site returns an action dict instead of raising,
-                # and the poisoned copy REPLACES the resident batch so
-                # the health ledger's replay anchor records exactly
-                # what dispatches.
-                _act = _chaos.fire("data.batch",
-                                   worker=jax.process_index(), step=i)
-                if _act and _act.get("poison"):
-                    train_batch = _chaos.poison_batch(train_batch)
-                if _hl is not None:
-                    _hl.note_replay_anchor(state, train_batch)
-                # Seeded straggler injection: sleep BEFORE the step
-                # span so the skew referee sees a late fence arrival
-                # on this rank, not a longer step.
-                _chaos.straggle(jax.process_index(), i)
-                # The step clock is a goodput LedgerSpan: it times the
-                # dispatch+sync region whether or not a ledger is
-                # active (step_time_s comes off its duration), and when
-                # one is, the seconds land in the step bucket — or in
-                # ``compile`` when the jit dispatch cache grew under
-                # the call (first call / new shape).
-                cache0 = (_goodput.jit_cache_size(train_step)
-                          if _goodput.active() is not None else None)
+                with chunk_span("train/chunk_prepare"):
+                    # Fail fast if a peer host died (multi-host runs only; the
+                    # gang's heartbeat marks survivors dead within one
+                    # interval). Checking here — before dispatching the next
+                    # compiled chunk — means we raise GangFailure instead of
+                    # wedging in the chunk's collectives. The same spot
+                    # publishes this rank's progress on its heartbeat so
+                    # the driver can read cross-rank step skew, and hosts
+                    # the chaos kill point (a seeded injection dies here,
+                    # between compiled dispatches — where a real preempt
+                    # lands; ft.supervisor.supervise_run then restarts the
+                    # attempt resuming from the latest checkpoint).
+                    check_gang()
+                    notify_gang_step(i)
+                    # `i` (the round-local iteration), not state.step: the
+                    # latter would cost a device sync per chunk on the hot
+                    # path; one-shot kill configs make the distinction
+                    # irrelevant across resumes.
+                    _chaos.fire("worker.step", worker=jax.process_index(),
+                                step=i)
+                    # Seeded poison-batch injection (bench-health drill):
+                    # the site returns an action dict instead of raising,
+                    # and the poisoned copy REPLACES the resident batch so
+                    # the health ledger's replay anchor records exactly
+                    # what dispatches.
+                    _act = _chaos.fire("data.batch",
+                                       worker=jax.process_index(), step=i)
+                    if _act and _act.get("poison"):
+                        train_batch = _chaos.poison_batch(train_batch)
+                    if _hl is not None:
+                        _hl.note_replay_anchor(state, train_batch)
+                    # Seeded straggler injection: sleep BEFORE the step
+                    # span so the skew referee sees a late fence arrival
+                    # on this rank, not a longer step.
+                    _chaos.straggle(jax.process_index(), i)
+                    # The step clock is a goodput LedgerSpan: it times the
+                    # dispatch+sync region whether or not a ledger is
+                    # active (step_time_s comes off its duration), and when
+                    # one is, the seconds land in the step bucket — or in
+                    # ``compile`` when the jit dispatch cache grew under
+                    # the call (first call / new shape).
+                    cache0 = (_goodput.jit_cache_size(train_step)
+                              if _goodput.active() is not None else None)
                 if steps_per_call > 1:
                     n = min(steps_per_call, iters - i)
                     with _goodput.step_span(step=i) as _led:
@@ -429,45 +446,30 @@ def train_distributed(
                             else:
                                 state, stacked = train_step(state, train_batch)
                             _chunk_span.sync(stacked.loss)
-                        losses = np.asarray(stacked.loss)[:n]
-                        examples = np.asarray(stacked.examples)[:n]
-                        gnorms = np.asarray(stacked.grad_norm)[:n]
-                        if fused_signals:
-                            vals = np.asarray(stacked.val_loss)[:n]
-                            actives = np.asarray(stacked.active)[:n]
-                        else:
-                            vals = [None] * n
-                            actives = [True] * n
-                        drops = (
-                            np.asarray(stacked.drop_fraction)[:n]
-                            if stacked.drop_fraction is not None
-                            else [None] * n
-                        )
+                        with tele.span("train/chunk_readback"):
+                            losses = np.asarray(stacked.loss)[:n]
+                            examples = np.asarray(stacked.examples)[:n]
+                            gnorms = np.asarray(stacked.grad_norm)[:n]
+                            if fused_signals:
+                                vals = np.asarray(stacked.val_loss)[:n]
+                                actives = np.asarray(stacked.active)[:n]
+                            else:
+                                vals = [None] * n
+                                actives = [True] * n
+                            drops = (
+                                np.asarray(stacked.drop_fraction)[:n]
+                                if stacked.drop_fraction is not None
+                                else [None] * n
+                            )
+                            if metrics_hook and stacked.health is not None:
+                                leaf_rows = np.asarray(
+                                    stacked.health.leaf_norms)[:n]
                         n_active = int(np.sum(np.asarray(actives)))
                         _led.count = max(1, n_active)
                         if cache0 is not None and (
                                 _goodput.jit_cache_size(train_step)
                                 or cache0) > cache0:
                             _led.rebucket("compile")
-                    dt = _led.duration_s / max(1, n_active)
-                    if _hl is not None and n_active > 0:
-                        _h = stacked.health
-                        _hl.note_step(
-                            count=n_active,
-                            device=None if _h is None else {
-                                "finite": _h.finite,
-                                "update_ratio": _h.update_ratio,
-                                "leaf_norms": _h.leaf_norms,
-                            },
-                            host={"loss": losses, "grad_norm": gnorms},
-                        )
-                    chunk = [
-                        (float(l), float(e), float(g),
-                         None if v is None or np.isnan(v) else float(v),
-                         bool(a), None if dr is None else float(dr))
-                        for l, e, g, v, a, dr in zip(losses, examples, gnorms,
-                                                     vals, actives, drops)
-                    ]
                 else:
                     with _goodput.step_span(step=i) as _led:
                         with tele.span("train/step") as _step_span, \
@@ -496,6 +498,9 @@ def train_distributed(
                         if step_metrics.drop_fraction is not None else None,
                     )]
                     dt = _led.duration_s
+                    if metrics_hook and step_metrics.health is not None:
+                        leaf_rows = [np.asarray(
+                            step_metrics.health.leaf_norms)]
                     if _hl is not None:
                         _h = step_metrics.health
                         _hl.note_step(
@@ -508,48 +513,81 @@ def train_distributed(
                                   "grad_norm": chunk[0][2]},
                         )
 
-                for loss, examples_n, gnorm, val_loss, active, drop_f in chunk:
-                    if not active:
-                        # Step masked out inside the fused chunk: the
-                        # stop had already fired — nothing trained.
-                        break
-                    record = {
-                        "round": shuffle_round,
-                        "iter": i,
-                        "loss": loss,
-                        "val_loss": val_loss,
-                        "examples": examples_n,
-                        "grad_norm": gnorm,
-                        "step_time_s": dt,
-                    }
-                    if drop_f is not None:
-                        record["moe_drop_fraction"] = drop_f
-                    recorder.record(record)
-                    if metrics_hook:
-                        metrics_hook(record)
-                    if verbose:
-                        # Reference prints per-partition loss lines
-                        # (distributed.py:201-204); here one global
-                        # line through the obs logger (lint-obs bans
-                        # raw prints in library code).
-                        msg = f"[sparktorch_tpu] round {shuffle_round} iter {i} loss {loss:.6f}"
-                        if val_loss is not None:
-                            msg += f" val_loss {val_loss:.6f}"
-                        log.info(msg)
-                    # Early stop needs no collective: `loss` is already the
-                    # global mean, identical on every host (vs the
-                    # reference's two extra all_reduces,
-                    # distributed.py:186-197). On the fused path the
-                    # decision already happened on-device (EsState).
-                    if stopper is not None and not fused_signals:
-                        signal = val_loss if val_loss is not None else loss
-                        if stopper.step(signal):
-                            stop = True
+                with chunk_span("train/chunk_records"):
+                    if steps_per_call > 1:
+                        dt = _led.duration_s / max(1, n_active)
+                        if _hl is not None and n_active > 0:
+                            _h = stacked.health
+                            _hl.note_step(
+                                count=n_active,
+                                device=None if _h is None else {
+                                    "finite": _h.finite,
+                                    "update_ratio": _h.update_ratio,
+                                    "leaf_norms": _h.leaf_norms,
+                                },
+                                host={"loss": losses, "grad_norm": gnorms},
+                            )
+                        chunk = [
+                            (float(l), float(e), float(g),
+                             None if v is None or np.isnan(v) else float(v),
+                             bool(a), None if dr is None else float(dr))
+                            for l, e, g, v, a, dr in zip(
+                                losses, examples, gnorms, vals, actives,
+                                drops)
+                        ]
+                    for j, (loss, examples_n, gnorm, val_loss, active,
+                            drop_f) in enumerate(chunk):
+                        if not active:
+                            # Step masked out inside the fused chunk: the
+                            # stop had already fired — nothing trained.
                             break
-                    i += 1
-                # lint-obs: ok (one early-stop scalar per drained chunk)
-                if fused_signals and bool(jax.device_get(es_state.stopped)):
-                    stop = True
+                        record = {
+                            "round": shuffle_round,
+                            "iter": i,
+                            "loss": loss,
+                            "val_loss": val_loss,
+                            "examples": examples_n,
+                            "grad_norm": gnorm,
+                            "step_time_s": dt,
+                        }
+                        if drop_f is not None:
+                            record["moe_drop_fraction"] = drop_f
+                        recorder.record(record)
+                        if metrics_hook:
+                            # The hook's copy also carries the step's
+                            # per-leaf gradient norms (a row of the
+                            # chunk's readback) and, once a call, their
+                            # keys; the recorder keeps neither.
+                            if leaf_rows is not None:
+                                record = {**record,
+                                          "leaf_grad_norms": leaf_rows[j]}
+                                if leaf_keys is not None:
+                                    record["leaf_grad_norm_keys"] = leaf_keys
+                                    leaf_keys = None
+                            metrics_hook(record)
+                        if verbose:
+                            # Reference prints per-partition loss lines
+                            # (distributed.py:201-204); here one global
+                            # line through the obs logger (lint-obs bans
+                            # raw prints in library code).
+                            msg = f"[sparktorch_tpu] round {shuffle_round} iter {i} loss {loss:.6f}"
+                            if val_loss is not None:
+                                msg += f" val_loss {val_loss:.6f}"
+                            log.info(msg)
+                        # Early stop needs no collective: `loss` is already the
+                        # global mean, identical on every host (vs the
+                        # reference's two extra all_reduces,
+                        # distributed.py:186-197). On the fused path the
+                        # decision already happened on-device (EsState).
+                        if stopper is not None and not fused_signals:
+                            signal = val_loss if val_loss is not None else loss
+                            if stopper.step(signal):
+                                stop = True
+                                break
+                        i += 1
+                    # lint-obs: ok (one early-stop scalar per drained chunk)
+                    if fused_signals and bool(jax.device_get(es_state.stopped)):
+                        stop = True
                 if ckpt is not None:
                     with tele.span("train/checkpoint"):
                         last_ckpt_step = _save_if_due(
