@@ -32,6 +32,7 @@ from sparktorch_tpu.train.step import (
     EsConfig,
     TrainState,
     create_train_state,
+    grad_allreduce_plan,
     init_es_state,
     make_eval_step,
     make_train_epoch,
@@ -155,6 +156,15 @@ def _finalize_checkpoint(ckpt, state, completed: bool) -> None:
             ckpt.save(final_step, state, force=True)
     ckpt.wait()
     ckpt.close()
+
+
+def _note_grad_allreduce(tele, params, mesh: Mesh) -> None:
+    """What the step's gradient all-reduce sums, on the bus: the
+    all-reduces a step issues, one a parameter array (0 where the batch
+    axes have one member and nothing is reduced), and their bytes."""
+    buckets, nbytes = grad_allreduce_plan(params, mesh)
+    tele.gauge("train.grad_allreduce.buckets", buckets)
+    tele.gauge("train.grad_allreduce.bytes", nbytes)
 
 
 def train_distributed(
@@ -350,6 +360,7 @@ def train_distributed(
             if val_batch is not None and not fused_signals
             else None
         )
+        _note_grad_allreduce(tele, state.params, mesh)
 
     from sparktorch_tpu.utils.metrics import MetricsRecorder
     from sparktorch_tpu.utils.tracing import profile_run, step_annotation
@@ -881,6 +892,7 @@ def train_distributed_streaming(
     last_ckpt_step = int(jax.device_get(state.step)) if ckpt is not None else 0
 
     tele = telemetry or get_telemetry()
+    _note_grad_allreduce(tele, state.params, mesh)
     log = get_logger("sparktorch_tpu.train")
     # Stack sampler beside the ambient ledger (see train_distributed).
     from sparktorch_tpu.obs import health as _health

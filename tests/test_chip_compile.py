@@ -18,6 +18,7 @@ read back). The programme reads ``jax.default_backend()``, which is
 """
 
 import os
+import re
 import sys
 
 import pytest
@@ -127,3 +128,89 @@ def test_untileable_shape_stays_dense_off_the_tpu():
         jnp.zeros((2, 4, 30522), jnp.float32),
         jnp.zeros((2, 4), jnp.int32)).as_text()
     assert "while" not in text  # no interpret-mode kernel loop: dense
+
+
+# -- the sync step's gradient all-reduce on a 2x2 host ------------------------
+
+
+def _computations(text):
+    """``{name: [instruction lines]}`` of a compiled module's text."""
+    out, cur = {}, None
+    for line in text.split("\n"):
+        if line.endswith("{") and not line.startswith(" "):
+            cur = out.setdefault(line.split(" (")[0].split()[-1], [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return out
+
+
+@pytest.mark.parametrize("builder", ["step", "epoch", "fused"])
+def test_dp4_step_runs_its_allreduces_under_the_backward_pass(
+        topo, as_tpu, builder):
+    """The compiled dp=4 step of a small transformer, from each of the
+    three builders (the loops' body is read): the all-reduce of
+    every gradient of 1 MiB or more is an asynchronous collective
+    fusion, the first of them starts before the backward pass's last
+    matrix product, and one synchronous all-reduce is left (the small
+    gradients and the loss's sums, merged). Without the compiler
+    options of ``train/step.py`` the same lowered program compiles to
+    no asynchronous all-reduce at all. Over one chip the step is a
+    plain ``jax.jit``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sparktorch_tpu.models import SequenceClassifier, tiny_transformer
+    from sparktorch_tpu.train import step as step_mod
+    from sparktorch_tpu.utils.data import DataBatch
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    cfg = tiny_transformer(vocab_size=8192, d_model=512, n_heads=8,
+                           n_layers=2, d_ff=2048, max_len=64, n_classes=2)
+    spec = ModelSpec(module=SequenceClassifier(cfg), loss="cross_entropy",
+                     optimizer="adam", optimizer_params={"lr": 1e-3},
+                     input_shape=(64,))
+    tx = spec.make_optimizer()
+    apply_fn, loss_fn = spec.make_module().apply, spec.loss_fn()
+    one = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "fsdp"))
+    assert not isinstance(step_mod.make_train_step(apply_fn, loss_fn, tx, one),
+                          step_mod._CompiledWithOptions)
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("dp", "fsdp"))
+    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(("dp", "fsdp")))
+    shapes = jax.eval_shape(lambda: step_mod.create_train_state(
+        spec, jax.random.key(0), sample_x=jnp.zeros((1, 64), jnp.float32),
+        tx=tx))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), shapes)
+    batch = DataBatch(
+        x=jax.ShapeDtypeStruct((32, 64), jnp.float32, sharding=rows),
+        y=jax.ShapeDtypeStruct((32,), jnp.float32, sharding=rows),
+        w=jax.ShapeDtypeStruct((32,), jnp.float32, sharding=rows))
+    step = step_mod.make_train_step(apply_fn, loss_fn, tx, mesh)
+    def is_start(line):
+        return line.lstrip().startswith("%async-collective-start")
+
+    plain = step._jitted.lower(state, batch).compile().as_text()
+    assert not any(is_start(line) for line in plain.split("\n"))
+    comps = _computations(step.lower(state, batch).compile().as_text())
+    body = next(lines for lines in comps.values()
+                if any(is_start(line) for line in lines))
+    has_matmul = {name for name, lines in comps.items()
+                  if any(" convolution(" in line for line in lines)}
+    starts = [i for i, line in enumerate(body) if is_start(line)]
+    def is_matmul(line):
+        called = re.search(r"calls=(%[\w.\-]+)", line)
+        return " convolution(" in line or (
+            called is not None and called.group(1) in has_matmul)
+
+    backward_matmuls = [i for i, line in enumerate(body)
+                        if "transpose(jvp(forward_loss))" in line
+                        and is_matmul(line)]
+    n_large = sum(1 for leaf in jax.tree.leaves(shapes.params)
+                  if leaf.size * leaf.dtype.itemsize >= 2**20)
+    assert len(starts) == n_large > 1
+    assert sum(1 for line in body if " all-reduce(" in line) == 1
+    assert backward_matmuls and starts[0] < backward_matmuls[-1]
