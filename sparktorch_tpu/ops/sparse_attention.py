@@ -1,0 +1,332 @@
+"""Attention over a per-query selected key set, in Pallas for TPU —
+forward and backward, grouped-query heads.
+
+Learned sparse attention (an indexer scores the keys of each query and
+the query attends only its ``topk`` best) hands the attention a set
+``S_t`` per query, one for all heads. Here the set is a mask
+``[batch, T, T]`` of int8 (nonzero: query ``t`` attends key ``s``),
+which whoever selected the keys has made. The kernels are the flash
+kernels of ``ops/flash_attention.py`` with two changes:
+
+- grouped-query heads: the grid runs over key/value heads, and one grid
+  step serves the ``G`` query heads of its group from one K tile, one V
+  tile and one mask tile in VMEM (``q`` is laid out ``[b, kv_heads, G,
+  T, d]``), so the mask is read once a group, not once a head;
+- a masked score is set to a large negative FINITE number and a masked
+  probability to exactly 0, so a tile in which some query selects
+  nothing (or every query: ``S_t`` may skip whole tiles) leaves the
+  running maximum and sum as they were. The mask is zero above the
+  diagonal (a query attends no later key): the tiles strictly above it
+  are neither computed nor fetched.
+
+These kernels compute every pair of a tile they visit and throw the
+masked ones away: at 8,192 tokens and 2,048 selected keys that is about
+2.3x the selected pairs. Skipping tiles no query selects from, or
+gathering the selected keys, is a later optimisation; the roofline
+share the benchmark reports counts selected pairs only and says so.
+
+``pallas_call`` names: ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``,
+``sparse_attn_bwd_dkv``. Off the TPU they run in interpret mode. A
+shape that does not tile is an error everywhere: there is no dense
+path.
+"""
+
+from __future__ import annotations
+
+import functools
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
+_NEG = -1e30         # a masked score: finite, so NEG - NEG is 0, not NaN
+
+
+def _last_k(qi, block_q: int, block_k: int):
+    """Index of the last K tile at or below the diagonal of Q tile ``qi``."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _first_q(ki, block_q: int, block_k: int):
+    """Index of the first Q tile at or below the diagonal of K tile ``ki``."""
+    return (ki * block_k) // block_q
+
+
+def _keep(mask_ref):
+    return mask_ref[...].astype(jnp.int32) != 0
+
+
+def _scores(q, k, keep, scale):
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where(keep, s, _NEG)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
+                m_ref, l_ref, *, scale, block_q, block_k, n_k, groups):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(ki <= _last_k(qi, block_q, block_k))
+    def _body():
+        k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
+        for g in range(groups):
+            s = _scores(q_ref[g], k, keep, scale)
+            m_prev, l_prev = m_ref[g][:, :1], l_ref[g][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[g] = acc_ref[g] * alpha + pv
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    @pl.when(ki == n_k - 1)
+    def _finalize():
+        for g in range(groups):
+            l = jnp.maximum(l_ref[g][:, :1], 1e-20)
+            o_ref[g] = (acc_ref[g] / l).astype(o_ref.dtype)
+            lse_ref[g] = jnp.broadcast_to(m_ref[g][:, :1] + jnp.log(l),
+                                          lse_ref.shape[1:])
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, d_ref,
+                   dq_ref, dq_acc, *, scale, block_q, block_k, n_k, groups):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(ki <= _last_k(qi, block_q, block_k))
+    def _body():
+        k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
+        for g in range(groups):
+            s = _scores(q_ref[g], k, keep, scale)
+            p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
+            dp = jax.lax.dot_general(
+                do_ref[g], v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - d_ref[g][:, :1])
+            dq_acc[g] = dq_acc[g] + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(ki == n_k - 1)
+    def _finalize():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, d_ref,
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, block_q,
+                    block_k, n_q, groups):
+    ki, qi = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(qi >= _first_q(ki, block_q, block_k))
+    def _body():
+        k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
+        for g in range(groups):
+            q, do = q_ref[g], do_ref[g]
+            s = _scores(q, k, keep, scale)
+            p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
+            dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - d_ref[g][:, :1])
+            dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(qi == n_q - 1)
+    def _finalize():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _blocks(seq: int) -> tuple:
+    """``(block_q, block_k)``: the largest powers of two dividing
+    ``seq`` (a multiple of 128) up to 256 x 512. Eight query heads
+    share a step, so a Q tile of 256 is 2,048 rows of scores against
+    each K tile; an int8 mask tile wants 32 rows or more."""
+    def largest(cap):
+        b = 1
+        while b * 2 <= min(cap, seq) and seq % (b * 2) == 0:
+            b *= 2
+        return b
+    return largest(256), largest(512)
+
+
+def _check(q, k, v, mask):
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    if k.shape != v.shape or k.shape[:2] != (b, t) or k.shape[3] != d:
+        raise ValueError(f"sparse_attention: q {q.shape}, k {k.shape}, "
+                         f"v {v.shape} do not go together")
+    if hq % hkv:
+        raise ValueError(f"sparse_attention: {hq} query heads are not a "
+                         f"multiple of {hkv} key/value heads")
+    if mask.shape != (b, t, t) or mask.dtype != jnp.int8:
+        raise ValueError(f"sparse_attention: the mask is {mask.dtype}"
+                         f"{mask.shape}, not int8{(b, t, t)}")
+    if d % _LANES or t % _LANES:
+        raise ValueError(
+            f"sparse_attention: seq {t} x head_dim {d} cannot be tiled: "
+            f"both must be multiples of {_LANES}")
+
+
+def _heads_first(x, hkv):
+    """``[b, T, h, d]`` -> ``[b, hkv, h // hkv, T, d]``."""
+    b, t, h, d = x.shape
+    return jnp.transpose(x.reshape(b, t, hkv, h // hkv, d), (0, 2, 3, 1, 4))
+
+
+def _heads_last(x5):
+    b, hkv, g, t, d = x5.shape
+    return jnp.transpose(x5, (0, 3, 1, 2, 4)).reshape(b, t, hkv * g, d)
+
+
+def _specs(groups, d, block_q, block_k, q_major: bool):
+    """Block specs of the operands every kernel shares. ``q_major``:
+    the grid is ``(b, h, qi, ki)``, else ``(b, h, ki, qi)``. A tile
+    above the diagonal, which is skipped, maps to the last tile fetched,
+    so nothing is copied for it."""
+    def order(f):
+        return f if q_major else (lambda b, h, ki, qi: f(b, h, qi, ki))
+
+    def kk(qi, ki):
+        return jnp.minimum(
+            ki, _last_k(qi, block_q, block_k)) if q_major else ki
+
+    def qq(qi, ki):
+        return qi if q_major else jnp.maximum(
+            qi, _first_q(ki, block_q, block_k))
+
+    q_spec = pl.BlockSpec((None, None, groups, block_q, d), order(
+        lambda b, h, qi, ki: (b, h, 0, qq(qi, ki), 0)))
+    kv_spec = pl.BlockSpec((None, None, block_k, d), order(
+        lambda b, h, qi, ki: (b, h, kk(qi, ki), 0)))
+    mask_spec = pl.BlockSpec((None, block_q, block_k), order(
+        lambda b, h, qi, ki: (b, qq(qi, ki), kk(qi, ki))))
+    row_spec = pl.BlockSpec((None, None, groups, block_q, _LANES), order(
+        lambda b, h, qi, ki: (b, h, 0, qq(qi, ki), 0)))
+    return q_spec, kv_spec, mask_spec, row_spec
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _fwd(q5, k4, v4, mask):
+    b, hkv, groups, t, d = q5.shape
+    block_q, block_k = _blocks(t)
+    n_q, n_k = t // block_q, t // block_k
+    q_spec, kv_spec, mask_spec, row_spec = _specs(
+        groups, d, block_q, block_k, q_major=True)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=d ** -0.5, block_q=block_q,
+                          block_k=block_k, n_k=n_k, groups=groups),
+        out_shape=[jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+                   jax.ShapeDtypeStruct((b, hkv, groups, t, _LANES),
+                                        jnp.float32)],
+        grid=(b, hkv, n_q, n_k),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=[q_spec, row_spec],
+        scratch_shapes=[pltpu.VMEM((groups, block_q, d), jnp.float32),
+                        pltpu.VMEM((groups, block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((groups, block_q, _LANES), jnp.float32)],
+        interpret=_interpret(),
+        name="sparse_attn_fwd",
+    )(q5, k4, v4, mask)
+
+
+def _bwd(q5, k4, v4, mask, o5, lse1, do5):
+    b, hkv, groups, t, d = q5.shape
+    block_q, block_k = _blocks(t)
+    n_q, n_k = t // block_q, t // block_k
+    kw = dict(scale=d ** -0.5, block_q=block_q, block_k=block_k,
+              groups=groups)
+    rows = (b, hkv, groups, t, _LANES)
+    di = jnp.sum(o5.astype(jnp.float32) * do5.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+    di, lse = jnp.broadcast_to(di, rows), jnp.broadcast_to(lse1, rows)
+
+    q_spec, kv_spec, mask_spec, row_spec = _specs(
+        groups, d, block_q, block_k, q_major=True)
+    dq5 = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, n_k=n_k, **kw),
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        grid=(b, hkv, n_q, n_k),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, row_spec,
+                  row_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((groups, block_q, d), jnp.float32)],
+        interpret=_interpret(),
+        name="sparse_attn_bwd_dq",
+    )(q5, k4, v4, mask, do5, lse, di)
+
+    q_spec, kv_spec, mask_spec, row_spec = _specs(
+        groups, d, block_q, block_k, q_major=False)
+    dk4, dv4 = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, n_q=n_q, **kw),
+        out_shape=[jax.ShapeDtypeStruct(k4.shape, k4.dtype),
+                   jax.ShapeDtypeStruct(v4.shape, v4.dtype)],
+        grid=(b, hkv, n_k, n_q),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, row_spec,
+                  row_spec],
+        out_specs=[kv_spec, kv_spec],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        interpret=_interpret(),
+        name="sparse_attn_bwd_dkv",
+    )(q5, k4, v4, mask, do5, lse, di)
+    return dq5, dk4, dv4
+
+
+@jax.custom_vjp
+def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     mask: jax.Array) -> jax.Array:
+    """``softmax`` attention of each query over the keys its mask row
+    selects. ``q`` is ``[b, T, heads, d]``, ``k`` and ``v`` are ``[b,
+    T, kv_heads, d]`` (query head ``i`` reads key/value head ``i //
+    (heads // kv_heads)``), ``mask`` is int8 ``[b, T, T]``, shared by
+    all heads and zero above the diagonal. A query that selects nothing
+    gets zeros. No gradient reaches the mask."""
+    return _forward(q, k, v, mask)[0]
+
+
+def _forward(q, k, v, mask):
+    _check(q, k, v, mask)
+    hkv = k.shape[2]
+    q5 = _heads_first(q, hkv)
+    k4, v4 = (jnp.swapaxes(x, 1, 2) for x in (k, v))
+    o5, lse = _fwd(q5, k4, v4, mask)
+    # one lane of the row statistics is kept for the backward pass
+    return _heads_last(o5), (q5, k4, v4, mask, o5, lse[..., :1])
+
+
+def _bwd_rule(res, g):
+    q5, k4, v4, mask, o5, lse1 = res
+    dq5, dk4, dv4 = _bwd(q5, k4, v4, mask, o5, lse1,
+                         _heads_first(g.astype(q5.dtype), k4.shape[1]))
+    return (_heads_last(dq5), jnp.swapaxes(dk4, 1, 2),
+            jnp.swapaxes(dv4, 1, 2), None)
+
+
+sparse_attention.defvjp(_forward, _bwd_rule)

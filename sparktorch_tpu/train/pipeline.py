@@ -77,7 +77,7 @@ from sparktorch_tpu.parallel.mesh import (
     AXIS_SP,
     AXIS_TP,
 )
-from sparktorch_tpu.train.step import shard_map_compat
+from sparktorch_tpu.train.step import refuse_sync_dp_only, shard_map_compat
 from sparktorch_tpu.utils.data import DataBatch
 
 
@@ -2564,6 +2564,7 @@ def train_distributed_pipeline(
         _hl.reset()
 
     module = spec.make_module()
+    refuse_sync_dp_only(module, "the pipeline trainer (mesh pp>1)")
     if isinstance(module, CausalLM):
         head = "lm"
     elif isinstance(module, SequenceClassifier):

@@ -34,6 +34,7 @@ from sparktorch_tpu.train.step import (
     _accepts_example_w,
     _moe_drop_counts,
     _split_variables,
+    refuse_sync_dp_only,
 )
 from sparktorch_tpu.utils.data import DataBatch
 
@@ -66,6 +67,7 @@ def create_sharded_state(
     level)."""
     tx = tx or spec.make_optimizer()
     module = spec.make_module()
+    refuse_sync_dp_only(module, "the GSPMD trainer")
     rules = rules or transformer_rules(mesh)
 
     # The init trace runs the full forward (incl. any shard_map
@@ -437,6 +439,7 @@ def make_sharded_train_step(
     if state_shardings is None:
         raise ValueError("state_shardings is required unless mesh='auto'")
 
+    refuse_sync_dp_only(apply_fn, "the GSPMD trainer")
     pass_w = _accepts_example_w(apply_fn)
 
     def step(state: TrainState, batch: DataBatch):

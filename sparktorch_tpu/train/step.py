@@ -76,6 +76,9 @@ class StepMetrics(NamedTuple):
     # (global); None (empty pytree leaf) for models without MoE.
     drop_fraction: Optional[jax.Array] = None
     health: Optional[HealthVec] = None
+    # Rows each held expert computed, [MoE layers, experts held]
+    # (global); None for models that sow no ``expert_rows``.
+    expert_rows: Optional[jax.Array] = None
 
 
 class EpochMetrics(NamedTuple):
@@ -91,6 +94,7 @@ class EpochMetrics(NamedTuple):
     active: jax.Array
     drop_fraction: Optional[jax.Array] = None
     health: Optional[HealthVec] = None
+    expert_rows: Optional[jax.Array] = None
 
 
 class EsConfig(NamedTuple):
@@ -185,6 +189,18 @@ def _accepts_example_w(apply_fn) -> bool:
         return False
 
 
+def refuse_sync_dp_only(module_or_apply, trainer: str) -> None:
+    """Raise if the module says it trains on the sync DP trainer alone
+    (a ``sync_dp_only`` attribute giving the reason): a trainer that
+    was never taught a model refuses it and does not run it wrong."""
+    module = getattr(module_or_apply, "__self__", module_or_apply)
+    reason = getattr(module, "sync_dp_only", None)
+    if reason:
+        raise NotImplementedError(
+            f"{type(module).__name__} does not train under {trainer}: "
+            f"{reason}. Use train_distributed on a mesh of batch axes.")
+
+
 def create_train_state(
     spec,
     rng: jax.Array,
@@ -262,6 +278,18 @@ def _moe_drop_counts(sown_metrics) -> Optional[Tuple[jax.Array, jax.Array]]:
             routed = routed + jnp.sum(leaf)
             found = True
     return (dropped, routed) if found else None
+
+
+def _moe_expert_rows(sown_metrics) -> Optional[jax.Array]:
+    """The sown ``expert_rows`` vectors (rows each held expert computed
+    in this pass) stacked by layer, or None when the model sowed none."""
+    if not sown_metrics:
+        return None
+    from jax.tree_util import tree_flatten_with_path
+
+    rows = [leaf for path, leaf in tree_flatten_with_path(sown_metrics)[0]
+            if any(getattr(p, "key", None) == "expert_rows" for p in path)]
+    return jnp.stack(rows).astype(jnp.float32) if rows else None
 
 
 def _shard_index(axis_names: Tuple[str, ...]) -> jax.Array:
@@ -425,9 +453,10 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
         # is the task mean plus the example-weighted mean aux —
         # matching the sharded trainer's objective.
         num = jnp.sum(per * mb.w) + _sown_total(sown, per.dtype) * den
-        return num, (den, new_model_state, _moe_drop_counts(sown_metrics))
+        return num, (den, new_model_state, _moe_drop_counts(sown_metrics),
+                     _moe_expert_rows(sown_metrics))
 
-    (num, (den, new_model_state, drop_counts)), grads_num = jax.value_and_grad(
+    (num, (den, new_model_state, drop_counts, expert_rows)), grads_num = jax.value_and_grad(
         weighted_sums, has_aux=True
     )(state.params)
 
@@ -444,6 +473,8 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
             dropped_g = jax.lax.psum(drop_counts[0], axis_names)
             routed_g = jax.lax.psum(drop_counts[1], axis_names)
             drop_fraction = dropped_g / jnp.maximum(routed_g, 1.0)
+        if expert_rows is not None:
+            expert_rows = jax.lax.psum(expert_rows, axis_names)
 
         # Non-trainable collections (batch_stats) sync by global mean.
         if state.model_state:
@@ -484,7 +515,8 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
         rng=next_rng,
     )
     return new_state, StepMetrics(loss=loss, examples=den_g, grad_norm=gnorm,
-                                  drop_fraction=drop_fraction, health=health)
+                                  drop_fraction=drop_fraction, health=health,
+                                  expert_rows=expert_rows)
 
 
 def make_train_step(
@@ -660,6 +692,7 @@ def make_train_epoch_fused(
                 active=active,
                 drop_fraction=metrics.drop_fraction,
                 health=metrics.health,
+                expert_rows=metrics.expert_rows,
             )
             return (new_state, new_es), out
 

@@ -167,6 +167,25 @@ def _note_grad_allreduce(tele, params, mesh: Mesh) -> None:
     tele.gauge("train.grad_allreduce.bytes", nbytes)
 
 
+def _note_model_gauges(tele, module) -> None:
+    """What a model says of its own structure (``train_gauges``: the
+    sparse attention's top-k, the experts held and routed), on the bus."""
+    for name, value in getattr(module, "train_gauges", dict)().items():
+        tele.gauge(name, value)
+
+
+def _moe_row_fields(expert_rows, drop_fraction) -> dict:
+    """One step's ``[MoE layers, experts held]`` rows as the record's
+    fields: the rows computed, the most loaded expert's and the mean,
+    and the routed pairs that were not computed (``drop_fraction`` is
+    dropped over routed, and routed is computed plus dropped)."""
+    rows, f = float(expert_rows.sum()), float(drop_fraction or 0.0)
+    return {"moe_rows": rows,
+            "moe_rows_max": float(expert_rows.max()),
+            "moe_rows_mean": rows / expert_rows.size,
+            "moe_pairs_dropped": rows * f / (1.0 - f) if f < 1.0 else rows}
+
+
 def train_distributed(
     torch_obj: Union[str, ModelSpec],
     data: Any,
@@ -361,6 +380,7 @@ def train_distributed(
             else None
         )
         _note_grad_allreduce(tele, state.params, mesh)
+        _note_model_gauges(tele, module)
 
     from sparktorch_tpu.utils.metrics import MetricsRecorder
     from sparktorch_tpu.utils.tracing import profile_run, step_annotation
@@ -373,6 +393,7 @@ def train_distributed(
     shuffle_key = jax.random.key(seed + 1)
     # What the hook gets beside the recorder's record: set per chunk.
     leaf_rows = None
+    expert_rows = None  # [steps, MoE layers, experts held] of a chunk
     leaf_keys = (_health.health_leaf_keys(state.params)
                  if metrics_hook else None)
     # On the fused path three host spans tile an iteration together
@@ -475,6 +496,9 @@ def train_distributed(
                             if metrics_hook and stacked.health is not None:
                                 leaf_rows = np.asarray(
                                     stacked.health.leaf_norms)[:n]
+                            if stacked.expert_rows is not None:
+                                expert_rows = np.asarray(
+                                    stacked.expert_rows)[:n]
                         n_active = int(np.sum(np.asarray(actives)))
                         _led.count = max(1, n_active)
                         if cache0 is not None and (
@@ -512,6 +536,8 @@ def train_distributed(
                     if metrics_hook and step_metrics.health is not None:
                         leaf_rows = [np.asarray(
                             step_metrics.health.leaf_norms)]
+                    if step_metrics.expert_rows is not None:
+                        expert_rows = [np.asarray(step_metrics.expert_rows)]
                     if _hl is not None:
                         _h = step_metrics.health
                         _hl.note_step(
@@ -563,6 +589,15 @@ def train_distributed(
                         }
                         if drop_f is not None:
                             record["moe_drop_fraction"] = drop_f
+                        if expert_rows is not None:
+                            record.update(
+                                _moe_row_fields(expert_rows[j], drop_f))
+                            tele.counter("train.moe.rows",
+                                         record["moe_rows"])
+                            tele.counter("train.moe.pairs_dropped",
+                                         record["moe_pairs_dropped"])
+                            tele.gauge("train.moe.rows_max",
+                                       record["moe_rows_max"])
                         recorder.record(record)
                         if metrics_hook:
                             # The hook's copy also carries the step's

@@ -100,6 +100,54 @@ def test_fused_ce_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+def _pallas_calls(text, kernel):
+    return len(re.findall(
+        rf'custom_call_target="tpu_custom_call".*op_name="[^"]*\b{kernel}\b',
+        text))
+
+
+def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
+    """The selected-key kernels at Keye-VL-2.0's heads (32 query heads
+    on 4 key/value heads of 128), one row of 4,096 tokens."""
+    from sparktorch_tpu.ops.sparse_attention import sparse_attention
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q, kv = S((1, 4096, 32, 128), jnp.bfloat16), S((1, 4096, 4, 128),
+                                                   jnp.bfloat16)
+    mask = S((1, 4096, 4096), jnp.int8)
+    text = jax.jit(jax.grad(
+        lambda q, k, v, m: sparse_attention(q, k, v, m).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))).lower(
+                q, kv, kv, mask).compile().as_text()
+    for kernel in ("sparse_attn_fwd", "sparse_attn_bwd_dq",
+                   "sparse_attn_bwd_dkv"):
+        assert _pallas_calls(text, kernel) == 1, kernel
+
+
+def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
+    """One layer of the sparse-attention MoE LM at the published widths
+    (4,096 tokens, above the top-k of 2,048, so the selection is in the
+    program; 16 of 128 experts held; the padded head into the fused
+    cross entropy): the whole gradient through the TPU compiler, which
+    has refused scatters in it that the CPU's took."""
+    from sparktorch_tpu.models.sparse_moe_lm import keye_vl2_lm
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    module = keye_vl2_lm(n_layers=1, vocab_size=4000,
+                         experts_held=tuple(range(16)))
+    ids = jnp.zeros((1, 4096), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy")
+    text = jax.jit(jax.grad(lambda p, x, y: loss_fn(
+        module.apply({"params": p}, x), y).sum())).lower(
+            jax.tree.map(S, shapes), S(ids), S(ids)).compile().as_text()
+    assert _pallas_calls(text, "sparse_attn_fwd") == 2  # and recomputed
+    assert _pallas_calls(text, "sparse_attn_bwd_dq") == 1
+    assert _pallas_calls(text, "fused_ce_fwd") == 1  # 4,000 padded to 4,096
+
+
 def test_untileable_shape_raises_on_tpu_backend(one_chip, as_tpu):
     """A caller who asked for the kernel by name gets an error naming
     the shape and the rule on a TPU backend — never a dense program

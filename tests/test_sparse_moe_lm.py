@@ -1,0 +1,397 @@
+"""``models/sparse_moe_lm.py`` (learned sparse attention over GQA, a
+dropless MoE that is told which experts it holds) against its plain
+reference (``chipbench/reference/keye-vl-2.0-30b-a3b-ep8.py``) at tiny
+widths on the CPU, seeded weights, float32: same arithmetic in another
+order, so 1e-5 relative."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from chipbench import harness
+from sparktorch_tpu.models import sparse_moe_lm as M
+from sparktorch_tpu.utils.losses import resolve_loss
+
+REF = harness.load_module("reference", "keye-vl-2.0-30b-a3b-ep8")
+ROWS, T, VOCAB = 2, 128, 96
+
+
+def sizes(topk=32, held=(2, 3), layers=2):
+    """The reference's configuration (the source's keys) and the
+    program's module for the same tiny model."""
+    cfg = dict(
+        hidden_size=64, num_hidden_layers=layers, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=128, vocab_size=VOCAB,
+        num_local_experts=16, num_experts_per_tok=4,
+        moe_intermediate_size=32, experts_held=list(held), rms_norm_eps=1e-6,
+        rope_theta=1e7, rope_scaling={"mrope_section": [16, 24, 24]},
+        sa_config={"indexer_num_heads": 2, "indexer_head_dim": 16,
+                   "topk": topk}, indexer_rope_dims=8,
+        embedding_init_std=1.0)
+    module = M.keye_vl2_lm(
+        vocab_size=VOCAB, d_model=64, n_layers=layers, n_heads=4,
+        n_kv_heads=2, idx_heads=2, idx_dim=16, idx_rope_dims=8, topk=topk,
+        n_routed_experts=16, experts_held=held, experts_per_token=4,
+        expert_width=32, compute_dtype="float32")
+    return cfg, module
+
+
+def rows(seed=1):
+    k1, k2 = jax.random.split(jax.random.key(seed))
+    return (jax.random.randint(k1, (ROWS, T), 0, VOCAB),
+            jax.random.randint(k2, (ROWS, T), 0, VOCAB))
+
+
+def rel(a, b):
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+# T = 128 above topk = 32 (selection at work) and below topk = 256
+# (every causal key attended)
+CASES = {"above_topk": 32, "below_topk": 256}
+
+
+@pytest.fixture(scope="module", params=list(CASES))
+def both(request):
+    """Program and reference on the same weights and rows: logits, the
+    selected sets, the loss and every gradient leaf."""
+    cfg, module = sizes(topk=CASES[request.param])
+    # index scores in three blocks of queries, the last one short
+    patch = pytest.MonkeyPatch()
+    patch.setattr(M, "_IDX_Q_CHUNK", 48)
+    request.addfinalizer(patch.undo)
+    variables = REF.init(jax.random.key(0), cfg)
+    ids, labels = rows()
+    loss_fn = resolve_loss("cross_entropy")
+
+    def prog_loss(p):
+        logits, state = module.apply(
+            {"params": p}, ids.astype(jnp.float32), mutable=["intermediates"])
+        return jnp.sum(loss_fn(logits, labels)), (logits, state)
+
+    def ref_loss(p):
+        return REF.loss_sum({"params": p}, ids, labels, jnp.ones(ROWS), cfg)
+
+    (p_loss, (p_logits, state)), p_grads = jax.value_and_grad(
+        prog_loss, has_aux=True)(variables["params"])
+    r_loss, r_grads = jax.value_and_grad(ref_loss)(variables["params"])
+    r_logits, r_sets = REF.forward(variables, ids, cfg, with_selected=True)
+    p_sets = jnp.stack([
+        state["intermediates"][f"layer_{i}"]["attn"]["selected"][0]
+        for i in range(cfg["num_hidden_layers"])])
+    return dict(p_logits=p_logits, r_logits=r_logits, p_loss=p_loss,
+                r_loss=r_loss, p_grads=p_grads, r_grads=r_grads,
+                p_sets=p_sets, r_sets=r_sets, case=request.param)
+
+
+def test_logits_match_the_reference(both):
+    assert rel(both["p_logits"], both["r_logits"]) < 1e-5
+
+
+def test_a_vocabulary_the_fused_loss_cannot_tile_is_padded_inside():
+    """600 columns come out as 1,024: the columns past the vocabulary
+    are no parameters and never enter a softmax, so the loss over the
+    padded width is the loss over the vocabulary."""
+    module = M.keye_vl2_lm(
+        vocab_size=600, d_model=64, n_layers=1, n_heads=4, n_kv_heads=2,
+        idx_heads=2, idx_dim=16, idx_rope_dims=8, topk=32,
+        n_routed_experts=16, experts_held=(2, 3), experts_per_token=4,
+        expert_width=32, compute_dtype="float32")
+    ids, labels = rows()
+    variables = module.init(jax.random.key(0), ids)
+    assert variables["params"]["head"].shape == (64, 600)
+    logits = module.apply(variables, ids)
+    assert logits.shape == (ROWS, T, 1024)
+    assert np.all(np.asarray(logits[..., 600:]) <= -1e29)
+    loss_fn = resolve_loss("cross_entropy")
+    np.testing.assert_allclose(loss_fn(logits, labels),
+                               loss_fn(logits[..., :600], labels), rtol=1e-6)
+
+
+def test_loss_matches_the_reference(both):
+    assert abs(float(both["p_loss"] - both["r_loss"])) \
+        < 1e-5 * abs(float(both["r_loss"]))
+
+
+def test_every_gradient_leaf_matches_the_reference(both):
+    errs = jax.tree.map(rel, both["p_grads"], both["r_grads"])
+    worst = max(jax.tree.leaves(errs))
+    assert worst < 1e-5, errs
+
+
+def test_selected_sets_equal_the_references_exactly(both):
+    p_sets, r_sets = np.asarray(both["p_sets"]), np.asarray(both["r_sets"])
+    assert p_sets.shape == r_sets.shape == (2, ROWS, T, T)
+    assert np.array_equal(p_sets != 0, r_sets)
+    per_query = (p_sets != 0).sum(-1)
+    topk = CASES[both["case"]]
+    assert np.array_equal(per_query[0, 0], np.minimum(np.arange(T) + 1, topk))
+
+
+def test_indexer_leaves_read_gradient_exactly_zero(both):
+    for grads in (both["p_grads"], both["r_grads"]):
+        for i in range(2):
+            attn = grads[f"layer_{i}"]["attn"]
+            for name in ("idx_wq", "idx_wk", "idx_ww", "idx_k_norm"):
+                for leaf in jax.tree.leaves(attn[name]):
+                    assert np.all(np.asarray(leaf) == 0.0), name
+            assert float(jnp.linalg.norm(attn["wq"])) > 0
+
+
+@pytest.mark.parametrize("levels", [2, 5, 1000])
+@pytest.mark.parametrize("topk", [8, 100])
+def test_select_topk_is_exact_under_ties(topk, levels):
+    """Scores quantised to a few levels (and exact zeros of both signs)
+    tie at the threshold; the lower index wins, as in the reference."""
+    rng = np.random.default_rng(levels * 1000 + topk)
+    scores = rng.integers(-levels, levels + 1, (2, T, T)).astype(np.float32)
+    scores = np.where(scores == 0, rng.choice([0.0, -0.0], scores.shape),
+                      scores * np.float32(0.37))
+    got = np.asarray(M.select_topk(jnp.asarray(scores), topk)) != 0
+    want = np.stack([np.asarray(REF.selected(jnp.asarray(s), 0, topk))
+                     for s in scores])
+    assert np.array_equal(got, want)
+    assert np.array_equal(got.sum(-1)[0], np.minimum(np.arange(T) + 1, topk))
+    # a block of queries against the keys up to its last query
+    block = np.asarray(M.select_topk(jnp.asarray(scores[:, 40:72, :72]),
+                                     topk, 40)) != 0
+    assert np.array_equal(block, want[:, 40:72, :72])
+    assert not want[:, 40:72, 72:].any()
+
+
+def test_unequal_mrope_ids_match_the_reference():
+    cfg, module = sizes()
+    variables = REF.init(jax.random.key(0), cfg)
+    ids, _ = rows()
+    keys = jax.random.split(jax.random.key(9), 2)
+    pos = jnp.stack([jnp.broadcast_to(jnp.arange(T), (ROWS, T))]
+                    + [jax.random.randint(k, (ROWS, T), 0, 40) for k in keys])
+    got = module.apply(variables, ids, position_ids=pos)[..., :VOCAB]
+    want = REF.forward(variables, ids, cfg, position_ids=pos)
+    assert rel(got, want) < 1e-5
+    plain = REF.forward(variables, ids, cfg)
+    assert rel(plain, want) > 1e-3  # the ids matter
+
+
+def _expert_layer(held):
+    _, module = sizes(held=held, layers=1)
+    return M.HeldExperts(module.config)
+
+
+@pytest.mark.parametrize("n_shares", [8, 4])
+def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(n_shares):
+    """Each share routes over all 16 experts and computes its own; the
+    shares' outputs summed are the reference's layer with every expert."""
+    cfg, _ = sizes(held=tuple(range(16)), layers=1)
+    whole = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    g = jax.random.normal(jax.random.key(5), (ROWS, T, 64), jnp.float32)
+    ein = lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
+    want = jnp.stack([REF._experts_row(whole, row, REF._sizes(cfg), ein, None)
+                      for row in g])
+    per = 16 // n_shares
+    total = 0.0
+    for share in range(n_shares):
+        held = tuple(range(share * per, (share + 1) * per))
+        params = {"router": whole["router"],
+                  **{k: whole[k][jnp.asarray(held)]
+                     for k in ("w_gate", "w_up", "w_down")}}
+        out = _expert_layer(held).apply({"params": params}, g)
+        total = total + out
+        assert rel(out, want) > 1e-2  # one share alone is not the layer
+    assert rel(total, want) < 1e-5
+
+
+@pytest.mark.parametrize("forced", [(2,), (2, 3)])
+def test_no_pair_is_dropped_when_the_router_forces_held_experts(forced):
+    """Every token chooses the forced held experts (and experts held
+    elsewhere): all tokens' rows land on them, 4 (one expert) and 8
+    times (two) the rows an even router sends here, the second past
+    what the smaller row buffer holds; none is dropped, and the output
+    still is the reference's."""
+    cfg, _ = sizes(held=(2, 3), layers=1)
+    params = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    g = jnp.abs(jax.random.normal(jax.random.key(5), (ROWS, T, 64))) + 0.1
+    router = jnp.zeros((64, 16)).at[:, 3].set(-1.0)
+    router = router.at[:, jnp.asarray(forced)].set(1.0)
+    params = {**params, "router": router}
+    layer = _expert_layer((2, 3))
+    out, state = layer.apply({"params": params}, g, mutable=["moe_metrics"])
+    sown = state["moe_metrics"]
+    assert np.array_equal(np.asarray(sown["expert_rows"][0]),
+                          [ROWS * T, ROWS * T * (3 in forced)])
+    assert float(sown["routed"][0]) == ROWS * T * len(forced)
+    assert float(sown["dropped"][0]) == 0.0
+    ein = lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
+    want = jnp.stack([REF._experts_row(params, row, REF._sizes(cfg), ein, None)
+                      for row in g])
+    assert rel(out, want) < 1e-5
+
+
+def test_a_pair_outside_its_experts_group_counts_as_dropped():
+    """``dropped`` is counted from the sorted pairs against the group
+    sizes the grouped product is given: sizes that are right cover every
+    held pair, a group one short loses the pairs that slide into the
+    next expert's group, and rows past the last group are not covered."""
+    sorted_expert = jnp.asarray([0, 0, 0, 1, 1, 2, 2, 2])  # 2: held elsewhere
+    assert float(M.pairs_covered(sorted_expert, jnp.asarray([3, 2]))) == 5.0
+    assert float(M.pairs_covered(sorted_expert, jnp.asarray([2, 3]))) == 4.0
+    assert float(M.pairs_covered(sorted_expert, jnp.asarray([3, 1]))) == 4.0
+    assert float(M.pairs_covered(sorted_expert, jnp.asarray([3, 5]))) == 5.0
+
+
+@pytest.mark.parametrize("fault", ["no_selection", "shifted_share",
+                                   "no_renorm"])
+def test_a_planted_fault_changes_the_references_logits(fault):
+    cfg, _ = sizes()
+    variables = REF.init(jax.random.key(0), cfg)
+    ids, _ = rows()
+    sound = REF.forward(variables, ids, cfg)
+    assert rel(REF.forward(variables, ids, {**cfg, "fault": fault}),
+               sound) > 1e-4
+
+
+def test_a_bad_configuration_is_refused():
+    with pytest.raises(ValueError, match="experts_held"):
+        M.keye_vl2_lm(experts_held=(0, 0))
+    with pytest.raises(ValueError, match="mrope_section"):
+        M.keye_vl2_lm(mrope_section=(16, 16, 16))
+
+
+# -- through the trainers ------------------------------------------------
+
+
+def _train(n_devices, iters=2, **kwargs):
+    """``train_distributed`` on the first ``n_devices`` CPU devices over
+    the same four rows; the records the hook got, the result, the bus."""
+    from sparktorch_tpu.obs.telemetry import Telemetry
+    from sparktorch_tpu.parallel.mesh import build_mesh
+    from sparktorch_tpu.train.sync import train_distributed
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    _, module = sizes()
+    spec = ModelSpec(module=module, loss="cross_entropy", optimizer="adam",
+                     optimizer_params={"lr": 1e-3}, input_shape=(T,))
+    k1, k2 = jax.random.split(jax.random.key(3))
+    ids = np.asarray(jax.random.randint(k1, (4, T), 0, VOCAB), np.float32)
+    labels = np.asarray(jax.random.randint(k2, (4, T), 0, VOCAB), np.float32)
+    tele, records = Telemetry(run_id="test"), []
+    result = train_distributed(
+        spec, ids, labels=labels, iters=iters, seed=0,
+        mesh=build_mesh(devices=jax.devices()[:n_devices]),
+        metrics_hook=records.append, telemetry=tele, **kwargs)
+    return records, result, tele
+
+
+@pytest.fixture(scope="module")
+def one_and_two_shards():
+    return _train(1, steps_per_call=1), _train(2, steps_per_call=1)
+
+
+@pytest.mark.parametrize("field", ["loss", "grad_norm", "examples",
+                                   "moe_rows"])
+def test_dp2_on_the_cpu_mesh_equals_one_shard_on_the_same_rows(
+        one_and_two_shards, field):
+    (one, _, _), (two, _, _) = one_and_two_shards
+    assert len(one) == len(two) == 2
+    for a, b in zip(one, two):
+        assert a[field] == pytest.approx(b[field], rel=2e-5)
+
+
+def test_dp2_ends_on_the_parameters_of_one_shard(one_and_two_shards):
+    (_, one, _), (_, two, _) = one_and_two_shards
+    # Adam's first steps move every weight by lr whatever the gradient's
+    # size, so a leaf whose gradient is noise about zero may differ by a
+    # step; the loss of the second step (above) is the tight comparison
+    errs = jax.tree.map(rel, one.params, two.params)
+    assert max(jax.tree.leaves(errs)) < 2e-2, errs
+
+
+def test_counters_and_gauges_reach_the_records_and_the_bus():
+    records, _, tele = _train(1, iters=4, steps_per_call=2)
+    assert len(records) == 4
+    for r in records:
+        assert r["moe_pairs_dropped"] == 0.0 and r["moe_drop_fraction"] == 0.0
+        # 4 rows x 128 tokens x 4 choices, 2 of 16 experts held, 2 layers
+        assert 0 < r["moe_rows"] < 2 * 4 * T * 4
+        assert r["moe_rows_max"] >= r["moe_rows_mean"] > 0
+    keys = records[0]["leaf_grad_norm_keys"]
+    norms = dict(zip(keys, records[0]["leaf_grad_norms"]))
+    assert all(v == 0.0 for k, v in norms.items() if ".idx_" in k)
+    assert norms["layer_0.attn.wq"] > 0
+    assert tele.gauge_value("train.sparse_attn.topk") == 32
+    assert tele.gauge_value("train.moe.experts_held") == 2
+    assert tele.gauge_value("train.moe.experts_routed") == 16
+    assert tele.counter_value("train.moe.pairs_dropped") == 0.0
+    assert tele.counter_value("train.moe.rows") == pytest.approx(
+        sum(r["moe_rows"] for r in records))
+
+
+def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
+    import optax
+
+    from sparktorch_tpu.parallel.mesh import MeshConfig, build_mesh
+    from sparktorch_tpu.train.sharded import (create_sharded_state,
+                                              make_sharded_train_step)
+    from sparktorch_tpu.train.sync import train_distributed
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    _, module = sizes()
+    spec = ModelSpec(module=module, loss="cross_entropy", optimizer="adam",
+                     optimizer_params={"lr": 1e-3}, input_shape=(T,))
+    mesh = build_mesh(devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="GSPMD.*Pallas kernel"):
+        create_sharded_state(spec, mesh, jax.random.key(0),
+                             jnp.zeros((2, T), jnp.float32))
+    with pytest.raises(NotImplementedError, match="GSPMD"):
+        make_sharded_train_step(module.apply, resolve_loss("cross_entropy"),
+                                optax.adam(1e-3), mesh, state_shardings=())
+    pp_mesh = build_mesh(MeshConfig(dp=1, pp=2), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="pipeline"):
+        train_distributed(spec, np.zeros((4, T), np.float32),
+                          labels=np.zeros((4, T), np.float32), mesh=pp_mesh,
+                          iters=1)
+
+
+def test_rows_ragged_dot_leaves_undefined_never_reach_a_sum(monkeypatch):
+    """On the TPU ``ragged_dot`` writes neither the rows of its result
+    past the groups nor those of the cotangent of its left operand (the
+    CPU's zero-fills both, which hid it: the chip's gradients read 25x
+    the reference's, PR 27). Poison both here: the layer's output and
+    every gradient must be what they are without the poison."""
+    real = jax.lax.ragged_dot
+
+    @jax.custom_vjp
+    def poisoned(a, m, sizes):
+        return _poison(real(a, m, sizes), sizes)
+
+    def _poison(x, sizes):
+        past = jnp.arange(x.shape[0])[:, None] >= jnp.sum(sizes)
+        return jnp.where(past, jnp.nan, x)
+
+    def fwd(a, m, sizes):
+        return poisoned(a, m, sizes), (a, m, sizes)
+
+    def bwd(res, ct):
+        a, m, sizes = res
+        _, vjp = jax.vjp(lambda a, m: real(a, m, sizes), a, m)
+        da, dm = vjp(ct)
+        return _poison(da, sizes), dm, None
+
+    poisoned.defvjp(fwd, bwd)
+
+    cfg, _ = sizes(held=(2, 3), layers=1)
+    params = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    g = jax.random.normal(jax.random.key(5), (ROWS, T, 64), jnp.float32)
+    layer = _expert_layer((2, 3))
+    loss = lambda p, g: jnp.sum(jnp.sin(layer.apply({"params": p}, g)))
+    want = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
+    monkeypatch.setattr(
+        jax.lax, "ragged_dot",
+        lambda a, m, sizes, preferred_element_type=None: poisoned(
+            a, m, sizes).astype(preferred_element_type or a.dtype))
+    got = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
