@@ -30,9 +30,13 @@ One layer, for the tokens ``x`` of a row, in the published order:
   out (they live on other chips, whose exchange is not here). Every
   chosen (token, held expert) pair is computed: pairs are sorted by
   expert (those of experts held elsewhere last), their tokens' rows
-  gathered, and the rows go through ``jax.lax.ragged_dot``, whose work
-  follows the rows present; the gather and the sum back run over every
-  chosen pair. There is no capacity and nothing is dropped.
+  gathered, multiplied (``jax.lax.ragged_dot``), gated and summed back
+  a chunk of ``_ROW_CHUNK`` sorted pairs at a time, by a loop that runs
+  as many chunks as hold a pair of a held expert
+  (:func:`held_experts_sum`, with a backward pass of its own: the same
+  loop again). So the rows moved are the rows held, rounded up to a
+  chunk, at any load: a holder of every expert runs every chunk. There
+  is no capacity and nothing is dropped.
 
 After the last layer RMSNorm and an untied head over ``vocab_size``
 rows (a slice of the published vocabulary, when the configuration says
@@ -43,9 +47,11 @@ over the padded width is the loss over ``vocab_size``. Every layer is
 rematerialised in the backward pass, but for its selected sets.
 
 Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
-(rows computed by each held expert), ``routed`` (chosen pairs whose
-expert is held) and ``dropped`` (those of them that the grouped product
-did not multiply by their own expert's weights: 0).
+(rows computed by each held expert), ``row_chunks`` (the chunks the
+loop ran, and the chunks that all chosen pairs would take), ``routed``
+(chosen pairs whose expert is held) and ``dropped`` (those of them that
+the grouped products, chunk by chunk, did not multiply by their own
+expert's weights: 0).
 """
 
 from __future__ import annotations
@@ -65,6 +71,12 @@ _MASK_NAME = "sparse_attn_mask"
 # Queries a block of index scores. The source's q_chunk_size is 512;
 # the selected sets do not depend on the block (tests shrink it).
 _IDX_Q_CHUNK = 1_024
+# Sorted pairs a trip of the expert layer's loop (fewer where a layer
+# sees fewer pairs; tests shrink it). A trip costs what 7,000 rows cost
+# (its scatter-adds pass over all tokens' sums, its weight gradients
+# are added to all experts'), so the chunk is near the rows a layer
+# holds: PERF.md section 6, PR 28.
+_ROW_CHUNK = 16_384
 # The vocabulary tile of ``ops/fused_ce.py``, which the head pads to.
 _CE_BLOCK_V = 512
 
@@ -326,35 +338,170 @@ class HeldExperts(nn.Module):
                 pair_local[:, None] == jnp.arange(n_held)[None, :], 0,
                 dtype=jnp.int32)
             token = order // k
-            xs = x[token]
             gate = gates.reshape(n * k)[order]
 
-        with jax.named_scope("moe_experts"):
-            w = lambda name, shape: self.param(
-                name, _normal(), (n_held, *shape)).astype(dt)
-            # Rows past the held pairs belong to no group (their experts
-            # are held elsewhere). ragged_dot leaves such rows of its
-            # result undefined, and of the cotangent it hands back to
-            # its left operand too (the TPU's does not write them: found
-            # on the chip, PR 27), so both sides of every product are
-            # masked: nothing undefined reaches a sum in either pass.
-            computed = (jnp.arange(n * k) < jnp.sum(rows))[:, None]
-            held_rows = lambda a: jnp.where(computed, a, 0.0).astype(a.dtype)
-            rdot = lambda a, m: held_rows(jax.lax.ragged_dot(
-                held_rows(a), m, rows, preferred_element_type=jnp.float32))
-            hidden = (jax.nn.silu(rdot(xs, w("w_gate", (d, f))))
-                      * rdot(xs, w("w_up", (d, f))))
-            ys = rdot(hidden.astype(dt), w("w_down", (f, d)))
-        with jax.named_scope("moe_route"):
-            out = jnp.zeros((n, d), jnp.float32).at[token].add(
-                ys * gate[:, None])
+        w = lambda name, shape: self.param(name, _normal(), (n_held, *shape))
+        out = held_experts_sum(x, token, gate, rows, w("w_gate", (d, f)),
+                               w("w_up", (d, f)), w("w_down", (f, d)))
 
+        chunk, n_chunks = _row_chunks(n * k)
         n_pairs = jnp.sum(pair_local < n_held).astype(jnp.float32)
+        # counted chunk by chunk against the group sizes the loop gives
+        # its products (chunks it does not run hold no held pair)
+        covered = jax.vmap(pairs_covered)(
+            jnp.pad(pair_local[order], (0, n_chunks * chunk - n * k),
+                    constant_values=n_held).reshape(n_chunks, chunk),
+            jax.vmap(lambda c: chunk_rows(rows, c * chunk, chunk))(
+                jnp.arange(n_chunks)))
         self.sow("moe_metrics", "expert_rows", rows)
+        self.sow("moe_metrics", "row_chunks", jnp.stack(
+            [_trips(rows, chunk), jnp.int32(n_chunks)]))
         self.sow("moe_metrics", "routed", n_pairs)
-        self.sow("moe_metrics", "dropped",
-                 n_pairs - pairs_covered(pair_local[order], rows))
+        self.sow("moe_metrics", "dropped", n_pairs - jnp.sum(covered))
         return out.reshape(b, t, d)
+
+
+# -- the held experts' rows, a chunk of the sorted pairs at a time ------------
+
+# [m, a] x [m, b] over groups of the rows -> [groups, a, b]: the weight
+# gradient of a grouped product
+_BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _row_chunks(n_pairs: int):
+    """``(pairs a chunk, chunks that n_pairs take)``."""
+    chunk = min(_ROW_CHUNK, n_pairs)
+    return chunk, -(-n_pairs // chunk)
+
+
+def _trips(rows, chunk: int):
+    """The chunks that hold a pair of a held expert: the loops' bound."""
+    return -(-jnp.sum(rows) // chunk)
+
+
+def chunk_rows(rows, start, chunk: int):
+    """The group sizes of the sorted pairs ``[start, start + chunk)``:
+    the overlap of each expert's range of pairs with the chunk."""
+    ends = jnp.cumsum(rows)
+    return jnp.maximum(jnp.minimum(ends, start + chunk)
+                       - jnp.maximum(ends - rows, start), 0)
+
+
+class _Chunk:
+    """Chunk ``c`` of the sorted pairs: its tokens, gates, group sizes
+    and gathered rows of ``x``, and the grouped product on them.
+
+    Rows past the held pairs belong to no group (their experts are held
+    elsewhere). ``ragged_dot`` leaves such rows of its result undefined
+    (the TPU's does not write them: found on the chip, PR 27), so both
+    sides of every product are zero there: nothing undefined reaches a
+    sum in either pass."""
+
+    def __init__(self, c, x, token, gate, rows, chunk):
+        self.start = c * chunk
+        self.token = jax.lax.dynamic_slice(token, (self.start,), (chunk,))
+        self.gate = jax.lax.dynamic_slice(gate, (self.start,), (chunk,))[:, None]
+        self.sizes = chunk_rows(rows, self.start, chunk)
+        self.live = (self.start + jnp.arange(chunk) < jnp.sum(rows))[:, None]
+        self.xs = self.held(x[self.token])
+
+    def held(self, a):
+        return jnp.where(self.live, a, 0.0).astype(a.dtype)
+
+    def rdot(self, a, m):
+        """``a`` (zero past the held pairs) by each row's expert's ``m``."""
+        return self.held(jax.lax.ragged_dot(
+            a, m, self.sizes, preferred_element_type=jnp.float32))
+
+    def by_group(self, a, b):
+        """``a^T b`` over each expert's rows, float32. A group with no
+        row in this chunk reads 0 whatever the product wrote there."""
+        prod = jax.lax.ragged_dot_general(
+            a, b, self.sizes, _BY_GROUP, preferred_element_type=jnp.float32)
+        return jnp.where((self.sizes > 0)[:, None, None], prod, 0.0)
+
+
+def _padded_pairs(token, gate):
+    """The sorted pairs padded to whole chunks, and the chunk."""
+    chunk, n_chunks = _row_chunks(token.size)
+    pad = (0, n_chunks * chunk - token.size)
+    return jnp.pad(token, pad), jnp.pad(gate, pad), chunk
+
+
+@jax.custom_vjp
+def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down):
+    """``out[t] = sum over the pairs p of token t on held experts of
+    gate[p] * w_down_e (silu(w_gate_e x[t]) * w_up_e x[t])``, float32
+    ``[tokens, d]``. ``token`` and ``gate`` are the chosen pairs' in
+    sorted order (held pairs first, by expert), ``rows`` the pairs of
+    each held expert. A loop over chunks of the sorted pairs, as many
+    as hold a held pair: products in ``x``'s dtype, sums in float32."""
+    dt = x.dtype
+    with jax.named_scope("moe_experts"):
+        token, gate, chunk = _padded_pairs(token, gate)
+        w_in = jnp.concatenate([w_gate, w_up], -1).astype(dt)
+        w_out = w_down.astype(dt)
+
+        def one_chunk(c, out):
+            ck = _Chunk(c, x, token, gate, rows, chunk)
+            a, b = jnp.split(ck.rdot(ck.xs, w_in), 2, -1)
+            ys = ck.rdot((jax.nn.silu(a) * b).astype(dt), w_out)
+            return out.at[ck.token].add(ys * ck.gate)
+
+        return jax.lax.fori_loop(0, _trips(rows, chunk), one_chunk,
+                                 jnp.zeros(x.shape, jnp.float32))
+
+
+def _held_experts_fwd(*args):
+    return held_experts_sum(*args), args
+
+
+def _held_experts_bwd(args, d_out):
+    """The same loop again, a chunk's hidden rows recomputed: what is
+    kept between the passes is the function's arguments, and what the
+    loop carries is the gradients' sums in float32."""
+    x, token, gate, rows, w_gate, w_up, w_down = args
+    dt, n_pairs = x.dtype, token.size
+    # a custom_vjp's backward rule carries no scope of the forward's
+    with jax.named_scope("moe_experts"):
+        token, gate, chunk = _padded_pairs(token, gate)
+        w_in = jnp.concatenate([w_gate, w_up], -1).astype(dt)
+        w_in_t, w_out_t = (jnp.swapaxes(w_in, 1, 2),
+                           jnp.swapaxes(w_down.astype(dt), 1, 2))
+
+        def one_chunk(c, sums):
+            dx, d_gate, dw_in, dw_out = sums
+            ck = _Chunk(c, x, token, gate, rows, chunk)
+            a, b = jnp.split(ck.rdot(ck.xs, w_in), 2, -1)
+            sig = jax.nn.sigmoid(a)
+            hidden = a * sig * b
+            dy = ck.held(d_out[ck.token]).astype(dt)
+            # ys = hidden w_down, out += gate ys
+            d_hidden = ck.rdot(dy, w_out_t)  # before the gate
+            d_gate = jax.lax.dynamic_update_slice(
+                d_gate, jnp.sum(hidden.astype(dt) * d_hidden, -1),
+                (ck.start,))
+            d_hidden = d_hidden * ck.gate
+            d_ab = jnp.concatenate(
+                [d_hidden * b * sig * (1.0 + a * (1.0 - sig)),
+                 d_hidden * a * sig], -1).astype(dt)
+            return (dx.at[ck.token].add(ck.rdot(d_ab, w_in_t)), d_gate,
+                    dw_in + ck.by_group(ck.xs, d_ab),
+                    dw_out + ck.by_group((hidden * ck.gate).astype(dt), dy))
+
+        f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
+        dx, d_gate, dw_in, dw_out = jax.lax.fori_loop(
+            0, _trips(rows, chunk), one_chunk,
+            (f32(x), f32(gate), f32(w_in), f32(w_down)))
+        dw_gate, dw_up = jnp.split(dw_in, 2, -1)
+    return (dx.astype(dt), None, d_gate[:n_pairs].astype(gate.dtype), None,
+            dw_gate.astype(w_gate.dtype), dw_up.astype(w_up.dtype),
+            dw_out.astype(w_down.dtype))
+
+
+held_experts_sum.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def pairs_covered(sorted_expert, group_sizes):

@@ -79,6 +79,10 @@ class StepMetrics(NamedTuple):
     # Rows each held expert computed, [MoE layers, experts held]
     # (global); None for models that sow no ``expert_rows``.
     expert_rows: Optional[jax.Array] = None
+    # Chunks of rows the expert layers' loops ran, and the chunks all
+    # chosen pairs would take, [MoE layers, 2] (global); None for
+    # models that sow no ``row_chunks``.
+    row_chunks: Optional[jax.Array] = None
 
 
 class EpochMetrics(NamedTuple):
@@ -95,6 +99,7 @@ class EpochMetrics(NamedTuple):
     drop_fraction: Optional[jax.Array] = None
     health: Optional[HealthVec] = None
     expert_rows: Optional[jax.Array] = None
+    row_chunks: Optional[jax.Array] = None
 
 
 class EsConfig(NamedTuple):
@@ -280,15 +285,16 @@ def _moe_drop_counts(sown_metrics) -> Optional[Tuple[jax.Array, jax.Array]]:
     return (dropped, routed) if found else None
 
 
-def _moe_expert_rows(sown_metrics) -> Optional[jax.Array]:
-    """The sown ``expert_rows`` vectors (rows each held expert computed
-    in this pass) stacked by layer, or None when the model sowed none."""
+def _moe_sown_by_layer(sown_metrics, name: str) -> Optional[jax.Array]:
+    """The vectors sown under ``name`` (``expert_rows``: rows each held
+    expert computed in this pass; ``row_chunks``: chunks of rows run
+    and possible) stacked by layer, or None when the model sowed none."""
     if not sown_metrics:
         return None
     from jax.tree_util import tree_flatten_with_path
 
     rows = [leaf for path, leaf in tree_flatten_with_path(sown_metrics)[0]
-            if any(getattr(p, "key", None) == "expert_rows" for p in path)]
+            if any(getattr(p, "key", None) == name for p in path)]
     return jnp.stack(rows).astype(jnp.float32) if rows else None
 
 
@@ -454,9 +460,11 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
         # matching the sharded trainer's objective.
         num = jnp.sum(per * mb.w) + _sown_total(sown, per.dtype) * den
         return num, (den, new_model_state, _moe_drop_counts(sown_metrics),
-                     _moe_expert_rows(sown_metrics))
+                     _moe_sown_by_layer(sown_metrics, "expert_rows"),
+                     _moe_sown_by_layer(sown_metrics, "row_chunks"))
 
-    (num, (den, new_model_state, drop_counts, expert_rows)), grads_num = jax.value_and_grad(
+    (num, (den, new_model_state, drop_counts, expert_rows,
+           row_chunks)), grads_num = jax.value_and_grad(
         weighted_sums, has_aux=True
     )(state.params)
 
@@ -475,6 +483,8 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
             drop_fraction = dropped_g / jnp.maximum(routed_g, 1.0)
         if expert_rows is not None:
             expert_rows = jax.lax.psum(expert_rows, axis_names)
+        if row_chunks is not None:
+            row_chunks = jax.lax.psum(row_chunks, axis_names)
 
         # Non-trainable collections (batch_stats) sync by global mean.
         if state.model_state:
@@ -516,7 +526,8 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
     )
     return new_state, StepMetrics(loss=loss, examples=den_g, grad_norm=gnorm,
                                   drop_fraction=drop_fraction, health=health,
-                                  expert_rows=expert_rows)
+                                  expert_rows=expert_rows,
+                                  row_chunks=row_chunks)
 
 
 def make_train_step(
@@ -693,6 +704,7 @@ def make_train_epoch_fused(
                 drop_fraction=metrics.drop_fraction,
                 health=metrics.health,
                 expert_rows=metrics.expert_rows,
+                row_chunks=metrics.row_chunks,
             )
             return (new_state, new_es), out
 

@@ -174,16 +174,29 @@ def _note_model_gauges(tele, module) -> None:
         tele.gauge(name, value)
 
 
-def _moe_row_fields(expert_rows, drop_fraction) -> dict:
-    """One step's ``[MoE layers, experts held]`` rows as the record's
-    fields: the rows computed, the most loaded expert's and the mean,
-    and the routed pairs that were not computed (``drop_fraction`` is
-    dropped over routed, and routed is computed plus dropped)."""
-    rows, f = float(expert_rows.sum()), float(drop_fraction or 0.0)
-    return {"moe_rows": rows,
-            "moe_rows_max": float(expert_rows.max()),
-            "moe_rows_mean": rows / expert_rows.size,
-            "moe_pairs_dropped": rows * f / (1.0 - f) if f < 1.0 else rows}
+def _note_moe_rows(tele, record, expert_rows, row_chunks, drop_fraction):
+    """What an expert layer that counts its rows sowed in one step, into
+    the step's record and onto the bus. From ``[MoE layers, experts
+    held]`` rows: the rows computed, the most loaded expert's and the
+    mean, and the routed pairs that were not computed (``drop_fraction``
+    is dropped over routed, and routed is computed plus dropped). From
+    ``[MoE layers, 2]`` chunks (where the layer moves its rows by
+    chunks): the chunks its loops ran, whose ratio to the chunks that
+    all chosen pairs would take is the share of them moved."""
+    if expert_rows is not None:
+        rows, f = float(expert_rows.sum()), float(drop_fraction or 0.0)
+        record.update(
+            moe_rows=rows, moe_rows_max=float(expert_rows.max()),
+            moe_rows_mean=rows / expert_rows.size,
+            moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows)
+        tele.counter("train.moe.rows", rows)
+        tele.counter("train.moe.pairs_dropped", record["moe_pairs_dropped"])
+        tele.gauge("train.moe.rows_max", record["moe_rows_max"])
+    if row_chunks is not None:
+        record["moe_row_chunks"] = float(row_chunks[:, 0].sum())
+        tele.counter("train.moe.row_chunks", record["moe_row_chunks"])
+        tele.gauge("train.moe.row_chunks_possible",
+                   float(row_chunks[:, 1].sum()))
 
 
 def train_distributed(
@@ -394,6 +407,7 @@ def train_distributed(
     # What the hook gets beside the recorder's record: set per chunk.
     leaf_rows = None
     expert_rows = None  # [steps, MoE layers, experts held] of a chunk
+    row_chunks = None  # [steps, MoE layers, 2 (run, possible)]
     leaf_keys = (_health.health_leaf_keys(state.params)
                  if metrics_hook else None)
     # On the fused path three host spans tile an iteration together
@@ -499,6 +513,9 @@ def train_distributed(
                             if stacked.expert_rows is not None:
                                 expert_rows = np.asarray(
                                     stacked.expert_rows)[:n]
+                            if stacked.row_chunks is not None:
+                                row_chunks = np.asarray(
+                                    stacked.row_chunks)[:n]
                         n_active = int(np.sum(np.asarray(actives)))
                         _led.count = max(1, n_active)
                         if cache0 is not None and (
@@ -538,6 +555,8 @@ def train_distributed(
                             step_metrics.health.leaf_norms)]
                     if step_metrics.expert_rows is not None:
                         expert_rows = [np.asarray(step_metrics.expert_rows)]
+                    if step_metrics.row_chunks is not None:
+                        row_chunks = [np.asarray(step_metrics.row_chunks)]
                     if _hl is not None:
                         _h = step_metrics.health
                         _hl.note_step(
@@ -589,15 +608,11 @@ def train_distributed(
                         }
                         if drop_f is not None:
                             record["moe_drop_fraction"] = drop_f
-                        if expert_rows is not None:
-                            record.update(
-                                _moe_row_fields(expert_rows[j], drop_f))
-                            tele.counter("train.moe.rows",
-                                         record["moe_rows"])
-                            tele.counter("train.moe.pairs_dropped",
-                                         record["moe_pairs_dropped"])
-                            tele.gauge("train.moe.rows_max",
-                                       record["moe_rows_max"])
+                        _note_moe_rows(
+                            tele, record,
+                            None if expert_rows is None else expert_rows[j],
+                            None if row_chunks is None else row_chunks[j],
+                            drop_f)
                         recorder.record(record)
                         if metrics_hook:
                             # The hook's copy also carries the step's

@@ -126,26 +126,46 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
 
 def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     """One layer of the sparse-attention MoE LM at the published widths
-    (4,096 tokens, above the top-k of 2,048, so the selection is in the
-    program; 16 of 128 experts held; the padded head into the fused
-    cross entropy): the whole gradient through the TPU compiler, which
-    has refused scatters in it that the CPU's took."""
+    and the benchmark cell's rows (2 x 8,192 tokens, above the top-k of
+    2,048, so the selection is in the program; 16 of 128 experts held;
+    the padded head into the fused cross entropy): the whole gradient
+    through the TPU compiler, which has refused scatters in it that the
+    CPU's took. The grouped products of the expert layer sit in the
+    bodies of two loops over chunks of rows (forward and backward: the
+    remat's second forward needs no result of the loop and is gone), and
+    the gradient's temporaries are under half of what they were with all
+    131,072 chosen pairs' rows held at once (6,009,584,128 bytes at this
+    shape, compiled so on PR 27's tree; 2,555,206,144 with the loop)."""
     from sparktorch_tpu.models.sparse_moe_lm import keye_vl2_lm
     from sparktorch_tpu.utils.losses import resolve_loss
 
     module = keye_vl2_lm(n_layers=1, vocab_size=4000,
                          experts_held=tuple(range(16)))
-    ids = jnp.zeros((1, 4096), jnp.float32)
+    ids = jnp.zeros((2, 8192), jnp.float32)
     shapes = jax.eval_shape(
         lambda: module.init(jax.random.key(0), ids))["params"]
     S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
     loss_fn = resolve_loss("cross_entropy")
-    text = jax.jit(jax.grad(lambda p, x, y: loss_fn(
+    compiled = jax.jit(jax.grad(lambda p, x, y: loss_fn(
         module.apply({"params": p}, x), y).sum())).lower(
-            jax.tree.map(S, shapes), S(ids), S(ids)).compile().as_text()
+            jax.tree.map(S, shapes), S(ids), S(ids)).compile()
+    text = compiled.as_text()
     assert _pallas_calls(text, "sparse_attn_fwd") == 2  # and recomputed
     assert _pallas_calls(text, "sparse_attn_bwd_dq") == 1
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # 4,000 padded to 4,096
+
+    grouped = re.compile(r"%ragged-dot-none[.\d]* = ")
+    comps = _computations(text)
+    bodies = {m.group(1) for lines in comps.values() for line in lines
+              if " while(" in line
+              for m in [re.search(r"body=(%[\w.\-]+)", line)]}
+    calls = {name: sum(bool(grouped.search(line)) for line in lines)
+             for name, lines in comps.items()}
+    # gate|up and down; the same again recomputed, the cotangents of
+    # both left operands and the two weight gradients
+    assert sorted(calls[b] for b in bodies if calls[b]) == [2, 5]
+    assert sum(calls.values()) == 7  # none outside the loops
+    assert compiled.memory_analysis().temp_size_in_bytes < 6_009_584_128 // 2
 
 
 def test_untileable_shape_raises_on_tpu_backend(one_chip, as_tpu):

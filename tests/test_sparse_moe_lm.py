@@ -58,9 +58,11 @@ def both(request):
     """Program and reference on the same weights and rows: logits, the
     selected sets, the loss and every gradient leaf."""
     cfg, module = sizes(topk=CASES[request.param])
-    # index scores in three blocks of queries, the last one short
+    # index scores in three blocks of queries, the last one short; the
+    # expert layers' 1,024 sorted pairs in chunks of 96
     patch = pytest.MonkeyPatch()
     patch.setattr(M, "_IDX_Q_CHUNK", 48)
+    patch.setattr(M, "_ROW_CHUNK", 96)
     request.addfinalizer(patch.undo)
     variables = REF.init(jax.random.key(0), cfg)
     ids, labels = rows()
@@ -180,6 +182,15 @@ def _expert_layer(held):
     return M.HeldExperts(module.config)
 
 
+def _seeded_layer(held=(2, 3)):
+    """The expert layer holding ``held``, seeded weights for it and a
+    seeded input."""
+    cfg, _ = sizes(held=held, layers=1)
+    params = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    g = jax.random.normal(jax.random.key(5), (ROWS, T, 64), jnp.float32)
+    return _expert_layer(held), params, g
+
+
 @pytest.mark.parametrize("n_shares", [8, 4])
 def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(n_shares):
     """Each share routes over all 16 experts and computes its own; the
@@ -229,6 +240,122 @@ def test_no_pair_is_dropped_when_the_router_forces_held_experts(forced):
     assert rel(out, want) < 1e-5
 
 
+# -- the loop over chunks of the sorted pairs, under planted loads --------
+
+HELD4, ELSEWHERE, LOAD_CHUNK = (2, 3, 5, 7), (8, 9, 10, 11), 64
+# case -> [(tokens, the four experts each of them chooses)]; the other
+# tokens of the 256 choose four experts held elsewhere. 1,024 pairs in
+# chunks of 64.
+LOADS = {
+    "no_held_pair": [],
+    "under_one_chunk": [(40, (2, 8, 9, 10))],
+    "a_multiple_of_the_chunk": [(64, (2, 3, 8, 9))],
+    "one_row_over_a_multiple": [(64, (2, 3, 8, 9)), (1, (5, 8, 9, 10))],
+    "an_expert_without_rows_between": [(50, (2, 5, 8, 9))],
+    "every_pair_held": [(ROWS * T, HELD4)],
+}
+
+
+def _planted(load):
+    """Input, norm gain and expert layer weights under which each token
+    chooses the experts the load plants for it: the token's class is a
+    large entry of its input, and the router's row of that entry lifts
+    the class's four experts far above the rest."""
+    cfg, _ = sizes(held=HELD4, layers=1)
+    params = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    classes = [ELSEWHERE] + [experts for _, experts in load]
+    of_token = np.repeat(np.arange(len(classes)),
+                         [ROWS * T - sum(n for n, _ in load)]
+                         + [n for n, _ in load])
+    of_token = np.random.default_rng(0).permutation(of_token)
+    x = 0.3 * jax.random.normal(jax.random.key(5), (ROWS * T, 64))
+    x = x.at[jnp.arange(ROWS * T), of_token].set(6.0)
+    router = np.array(params["router"])
+    for c, experts in enumerate(classes):
+        router[c, list(experts)] = 4.0
+    gain = 1.0 + 0.1 * jax.random.normal(jax.random.key(6), (64,))
+    want_rows = [sum(n for n, experts in load if e in experts)
+                 for e in HELD4]
+    return (cfg, x.reshape(ROWS, T, 64), gain,
+            {**params, "router": jnp.asarray(router)}, want_rows)
+
+
+@pytest.mark.parametrize("case", list(LOADS))
+def test_the_chunked_layer_is_the_reference_at_every_load(case, monkeypatch):
+    """Value and every gradient (the input's, the gain's of the norm in
+    front, the router's, the three expert weights') of the layer whose
+    loop runs 0, 1, 2, 3 and all 16 chunks, against the plain reference.
+    With no held pair the layer adds exactly 0 and every gradient is
+    exactly 0."""
+    monkeypatch.setattr(M, "_ROW_CHUNK", LOAD_CHUNK)
+    cfg, x, gain, params, want_rows = _planted(LOADS[case])
+    layer = _expert_layer(HELD4)
+    ein = lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
+
+    def prog(x, gain, p):
+        out, state = layer.apply({"params": p}, M.rms_norm(x, gain, 1e-6),
+                                 mutable=["moe_metrics"])
+        return jnp.sum(jnp.sin(out) + out), (out, state["moe_metrics"])
+
+    def ref(x, gain, p):
+        out = jnp.stack([REF._experts_row(
+            p, REF._rms_norm(row, gain, 1e-6), REF._sizes(cfg), ein, None)
+            for row in x])
+        return jnp.sum(jnp.sin(out) + out), out
+
+    (_, (got, sown)), got_grads = jax.value_and_grad(
+        prog, argnums=(0, 1, 2), has_aux=True)(x, gain, params)
+    (_, want), want_grads = jax.value_and_grad(
+        ref, argnums=(0, 1, 2), has_aux=True)(x, gain, params)
+
+    assert np.array_equal(np.asarray(sown["expert_rows"][0]), want_rows)
+    assert np.array_equal(
+        np.asarray(sown["row_chunks"][0]),
+        [-(-sum(want_rows) // LOAD_CHUNK), ROWS * T * 4 // LOAD_CHUNK])
+    assert float(sown["routed"][0]) == sum(want_rows)
+    assert float(sown["dropped"][0]) == 0.0
+    if not sum(want_rows):
+        for leaf in jax.tree.leaves((got, got_grads, want, want_grads)):
+            assert np.all(np.asarray(leaf) == 0.0)
+        return
+    assert rel(got, want) < 1e-5
+    errs = jax.tree.map(rel, got_grads, want_grads)
+    assert max(jax.tree.leaves(errs)) < 1e-5, errs
+    for leaf in jax.tree.leaves(want_grads):
+        assert float(jnp.linalg.norm(leaf)) > 0  # a comparison of something
+
+
+def _float_arrays_by_pair(jaxpr, n_pairs):
+    """Every float array with a row for each chosen pair (rank 2 or
+    more, ``n_pairs`` or more rows) that an equation of the jaxpr, or of
+    a jaxpr inside it, produces."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            a = v.aval
+            if (jnp.issubdtype(a.dtype, jnp.floating) and a.ndim >= 2
+                    and a.shape[0] >= n_pairs):
+                found.append((eqn.primitive.name, a.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _float_arrays_by_pair(sub, n_pairs)
+    return found
+
+
+def test_no_pass_of_the_layer_holds_a_row_for_every_chosen_pair(monkeypatch):
+    """Neither the layer nor its gradient makes a float array of
+    ``[tokens x k, ...]``: the rows exist a chunk at a time."""
+    monkeypatch.setattr(M, "_ROW_CHUNK", LOAD_CHUNK)
+    cfg, x, gain, params, _ = _planted(LOADS["under_one_chunk"])
+    layer = _expert_layer(HELD4)
+    loss = lambda x, p: jnp.sum(jnp.sin(layer.apply({"params": p}, x)))
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, params)
+    assert _float_arrays_by_pair(jaxpr.jaxpr, ROWS * T * 4) == []
+    # the probe sees such an array where there is one
+    gathered = jax.make_jaxpr(lambda x: x.reshape(-1, 64)[
+        jnp.arange(ROWS * T * 4) // 4] * 2.0)(x)
+    assert _float_arrays_by_pair(gathered.jaxpr, ROWS * T * 4)
+
+
 def test_a_pair_outside_its_experts_group_counts_as_dropped():
     """``dropped`` is counted from the sorted pairs against the group
     sizes the grouped product is given: sizes that are right cover every
@@ -239,6 +366,30 @@ def test_a_pair_outside_its_experts_group_counts_as_dropped():
     assert float(M.pairs_covered(sorted_expert, jnp.asarray([2, 3]))) == 4.0
     assert float(M.pairs_covered(sorted_expert, jnp.asarray([3, 1]))) == 4.0
     assert float(M.pairs_covered(sorted_expert, jnp.asarray([3, 5]))) == 5.0
+    # chunk by chunk, as the layer counts it: each chunk's pairs against
+    # the overlap of the experts' ranges with the chunk
+    rows, halves = jnp.asarray([3, 2]), sorted_expert.reshape(2, 4)
+    by_chunk = lambda sizes_of: sum(
+        float(M.pairs_covered(halves[c], sizes_of(c))) for c in range(2))
+    assert np.array_equal(M.chunk_rows(rows, 4, 4), [0, 1])
+    assert by_chunk(lambda c: M.chunk_rows(rows, 4 * c, 4)) == 5.0
+    # sizes that forget where the chunk starts put the second chunk's
+    # pairs in the first expert's group
+    assert by_chunk(lambda c: jnp.minimum(rows, 4)) == 4.0
+
+
+def test_chunk_sizes_derived_wrongly_show_as_dropped_pairs(monkeypatch):
+    """The layer's ``dropped`` is counted against the group sizes its
+    loop gives the products, chunk by chunk."""
+    monkeypatch.setattr(M, "_ROW_CHUNK", 64)
+    layer, params, g = _seeded_layer()
+    sown = lambda: layer.apply({"params": params}, g,
+                               mutable=["moe_metrics"])[1]["moe_metrics"]
+    right = sown()
+    assert float(right["routed"][0]) > 64 and float(right["dropped"][0]) == 0
+    monkeypatch.setattr(M, "chunk_rows",
+                        lambda rows, start, chunk: jnp.minimum(rows, chunk))
+    assert float(sown()["dropped"][0]) > 0
 
 
 @pytest.mark.parametrize("fault", ["no_selection", "shifted_share",
@@ -262,7 +413,7 @@ def test_a_bad_configuration_is_refused():
 # -- through the trainers ------------------------------------------------
 
 
-def _train(n_devices, iters=2, **kwargs):
+def _train(n_devices, iters=2, layers=2, **kwargs):
     """``train_distributed`` on the first ``n_devices`` CPU devices over
     the same four rows; the records the hook got, the result, the bus."""
     from sparktorch_tpu.obs.telemetry import Telemetry
@@ -270,7 +421,7 @@ def _train(n_devices, iters=2, **kwargs):
     from sparktorch_tpu.train.sync import train_distributed
     from sparktorch_tpu.utils.serde import ModelSpec
 
-    _, module = sizes()
+    _, module = sizes(layers=layers)
     spec = ModelSpec(module=module, loss="cross_entropy", optimizer="adam",
                      optimizer_params={"lr": 1e-3}, input_shape=(T,))
     k1, k2 = jax.random.split(jax.random.key(3))
@@ -308,7 +459,8 @@ def test_dp2_ends_on_the_parameters_of_one_shard(one_and_two_shards):
     assert max(jax.tree.leaves(errs)) < 2e-2, errs
 
 
-def test_counters_and_gauges_reach_the_records_and_the_bus():
+def test_counters_and_gauges_reach_the_records_and_the_bus(monkeypatch):
+    monkeypatch.setattr(M, "_ROW_CHUNK", 96)
     records, _, tele = _train(1, iters=4, steps_per_call=2)
     assert len(records) == 4
     for r in records:
@@ -316,6 +468,8 @@ def test_counters_and_gauges_reach_the_records_and_the_bus():
         # 4 rows x 128 tokens x 4 choices, 2 of 16 experts held, 2 layers
         assert 0 < r["moe_rows"] < 2 * 4 * T * 4
         assert r["moe_rows_max"] >= r["moe_rows_mean"] > 0
+        # each layer's rows rounded up to a chunk of 96
+        assert 0 <= r["moe_row_chunks"] - r["moe_rows"] / 96 < 2
     keys = records[0]["leaf_grad_norm_keys"]
     norms = dict(zip(keys, records[0]["leaf_grad_norms"]))
     assert all(v == 0.0 for k, v in norms.items() if ".idx_" in k)
@@ -326,6 +480,32 @@ def test_counters_and_gauges_reach_the_records_and_the_bus():
     assert tele.counter_value("train.moe.pairs_dropped") == 0.0
     assert tele.counter_value("train.moe.rows") == pytest.approx(
         sum(r["moe_rows"] for r in records))
+    assert tele.counter_value("train.moe.row_chunks") == sum(
+        r["moe_row_chunks"] for r in records)
+    assert tele.gauge_value("train.moe.row_chunks_possible") \
+        == 2 * -(-4 * T * 4 // 96)
+
+
+@pytest.mark.parametrize("n_devices", [1, 2])
+def test_row_chunks_in_the_records_are_the_chunks_the_rows_take(
+        monkeypatch, n_devices):
+    """One expert layer, so a step's ``moe_rows`` are that layer's: the
+    chunks its loop ran are those rows rounded up to a chunk (on two
+    shards each rounds up its own), of the chunks that all chosen pairs
+    would take."""
+    monkeypatch.setattr(M, "_ROW_CHUNK", 96)
+    records, _, tele = _train(n_devices, iters=3, layers=1, steps_per_call=1)
+    assert len(records) == 3
+    for r in records:
+        whole = -(-r["moe_rows"] // 96)
+        assert whole <= r["moe_row_chunks"] <= whole + n_devices - 1
+    assert tele.counter_value("train.moe.row_chunks") == sum(
+        r["moe_row_chunks"] for r in records)
+    assert tele.gauge_value("train.moe.row_chunks_possible") \
+        == n_devices * -(-(4 // n_devices) * T * 4 // 96)
+    share = (tele.counter_value("train.moe.row_chunks")
+             / (3 * tele.gauge_value("train.moe.row_chunks_possible")))
+    assert 0 < share < 0.5  # 2 of 16 experts held
 
 
 def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
@@ -355,43 +535,36 @@ def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
 
 
 def test_rows_ragged_dot_leaves_undefined_never_reach_a_sum(monkeypatch):
-    """On the TPU ``ragged_dot`` writes neither the rows of its result
-    past the groups nor those of the cotangent of its left operand (the
-    CPU's zero-fills both, which hid it: the chip's gradients read 25x
-    the reference's, PR 27). Poison both here: the layer's output and
-    every gradient must be what they are without the poison."""
-    real = jax.lax.ragged_dot
+    """On the TPU ``ragged_dot`` does not write the rows of its result
+    past the groups (the CPU's zero-fills them, which hid it: the chip's
+    gradients read 25x the reference's, PR 27), and the layer's backward
+    pass, its own, takes the cotangent of a product's left operand by
+    such a product too. Poison those rows of every grouped product, on
+    the tail of the last chunk (150 or so held rows in chunks of 64),
+    and the groups without a row of every weight gradient: the layer's
+    output and every gradient must be what they are without the poison."""
+    real, real_general = jax.lax.ragged_dot, jax.lax.ragged_dot_general
 
-    @jax.custom_vjp
-    def poisoned(a, m, sizes):
-        return _poison(real(a, m, sizes), sizes)
+    def poisoned(a, m, sizes, preferred_element_type=None):
+        out = real(a, m, sizes, preferred_element_type=preferred_element_type)
+        past = jnp.arange(out.shape[0])[:, None] >= jnp.sum(sizes)
+        return jnp.where(past, jnp.nan, out)
 
-    def _poison(x, sizes):
-        past = jnp.arange(x.shape[0])[:, None] >= jnp.sum(sizes)
-        return jnp.where(past, jnp.nan, x)
+    def poisoned_general(a, b, sizes, dims, preferred_element_type=None):
+        out = real_general(a, b, sizes, dims,
+                           preferred_element_type=preferred_element_type)
+        return jnp.where((sizes == 0)[:, None, None], jnp.nan, out)
 
-    def fwd(a, m, sizes):
-        return poisoned(a, m, sizes), (a, m, sizes)
-
-    def bwd(res, ct):
-        a, m, sizes = res
-        _, vjp = jax.vjp(lambda a, m: real(a, m, sizes), a, m)
-        da, dm = vjp(ct)
-        return _poison(da, sizes), dm, None
-
-    poisoned.defvjp(fwd, bwd)
-
-    cfg, _ = sizes(held=(2, 3), layers=1)
-    params = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
-    g = jax.random.normal(jax.random.key(5), (ROWS, T, 64), jnp.float32)
-    layer = _expert_layer((2, 3))
+    monkeypatch.setattr(M, "_ROW_CHUNK", 64)
+    layer, params, g = _seeded_layer()
     loss = lambda p, g: jnp.sum(jnp.sin(layer.apply({"params": p}, g)))
     want = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
-    monkeypatch.setattr(
-        jax.lax, "ragged_dot",
-        lambda a, m, sizes, preferred_element_type=None: poisoned(
-            a, m, sizes).astype(preferred_element_type or a.dtype))
+    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+    monkeypatch.setattr(jax.lax, "ragged_dot_general", poisoned_general)
     got = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+    # and the poison is there to be met: a product alone shows it
+    assert np.isnan(np.asarray(jax.lax.ragged_dot(
+        jnp.ones((4, 2)), jnp.ones((1, 2, 2)), jnp.asarray([3])))[3]).all()
