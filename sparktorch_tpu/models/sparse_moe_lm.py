@@ -554,6 +554,31 @@ class SparseMoELM(nn.Module):
                 "train.moe.experts_held": len(cfg.experts_held),
                 "train.moe.experts_routed": cfg.n_routed_experts}
 
+    def train_counters(self, sown: dict, drop_fraction) -> tuple:
+        """What the counters sown in one step mean, to the trainer that
+        read them back (``{name: [MoE layers, ...]}``, summed over the
+        shards; ``drop_fraction`` is the step's dropped over routed):
+        ``(the record's fields, the bus's counters, the bus's gauges)``.
+        From ``expert_rows``, ``[layers, experts held]``: the rows
+        computed, the most loaded expert's and the mean, and the routed
+        pairs that were not computed (routed is computed plus dropped).
+        From ``row_chunks``, ``[layers, 2]``: the chunks the layers'
+        loops ran, whose ratio to the chunks that all chosen pairs would
+        take is the share of them moved."""
+        by_expert, chunks = sown["expert_rows"], sown["row_chunks"]
+        rows, f = float(by_expert.sum()), float(drop_fraction or 0.0)
+        fields = dict(
+            moe_rows=rows, moe_rows_max=float(by_expert.max()),
+            moe_rows_mean=rows / by_expert.size,
+            moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows,
+            moe_row_chunks=float(chunks[:, 0].sum()))
+        return (fields,
+                {"train.moe.rows": rows,
+                 "train.moe.pairs_dropped": fields["moe_pairs_dropped"],
+                 "train.moe.row_chunks": fields["moe_row_chunks"]},
+                {"train.moe.rows_max": fields["moe_rows_max"],
+                 "train.moe.row_chunks_possible": float(chunks[:, 1].sum())})
+
     @nn.compact
     def __call__(self, ids, example_w=None, position_ids=None):
         cfg, dt = self.config, self.config.compute_dtype

@@ -22,7 +22,7 @@ already the global mean, replicated on every host
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,37 +69,23 @@ class HealthVec(NamedTuple):
 
 
 class StepMetrics(NamedTuple):
+    """One step's metrics, or a chunk's stacked by step. A field its
+    builder does not fill is None (no pytree leaf)."""
+
     loss: jax.Array        # global weighted-mean train loss
     examples: jax.Array    # real (weight>0) examples this step, global
     grad_norm: jax.Array
     # Fraction of routed MoE token-choices dropped at expert capacity
-    # (global); None (empty pytree leaf) for models without MoE.
+    # (global); None for models without MoE.
     drop_fraction: Optional[jax.Array] = None
     health: Optional[HealthVec] = None
-    # Rows each held expert computed, [MoE layers, experts held]
-    # (global); None for models that sow no ``expert_rows``.
-    expert_rows: Optional[jax.Array] = None
-    # Chunks of rows the expert layers' loops ran, and the chunks all
-    # chosen pairs would take, [MoE layers, 2] (global); None for
-    # models that sow no ``row_chunks``.
-    row_chunks: Optional[jax.Array] = None
-
-
-class EpochMetrics(NamedTuple):
-    """Stacked per-step metrics from a fused chunk with early-stop /
-    validation support. ``val_loss`` is NaN when no val batch was given;
-    ``active`` is False for steps masked out after the stop fired (the
-    host must ignore those rows)."""
-
-    loss: jax.Array
-    examples: jax.Array
-    grad_norm: jax.Array
-    val_loss: jax.Array
-    active: jax.Array
-    drop_fraction: Optional[jax.Array] = None
-    health: Optional[HealthVec] = None
-    expert_rows: Optional[jax.Array] = None
-    row_chunks: Optional[jax.Array] = None
+    # What else the model sowed (global), see ``_moe_sown_by_layer``.
+    sown: Optional[Dict[str, jax.Array]] = None
+    # Of ``make_train_epoch_fused`` alone: NaN when no val batch was
+    # given; False for steps masked out after the stop fired (the host
+    # must ignore those rows).
+    val_loss: Optional[jax.Array] = None
+    active: Optional[jax.Array] = None
 
 
 class EsConfig(NamedTuple):
@@ -285,17 +271,21 @@ def _moe_drop_counts(sown_metrics) -> Optional[Tuple[jax.Array, jax.Array]]:
     return (dropped, routed) if found else None
 
 
-def _moe_sown_by_layer(sown_metrics, name: str) -> Optional[jax.Array]:
-    """The vectors sown under ``name`` (``expert_rows``: rows each held
-    expert computed in this pass; ``row_chunks``: chunks of rows run
-    and possible) stacked by layer, or None when the model sowed none."""
-    if not sown_metrics:
-        return None
+def _moe_sown_by_layer(sown_metrics) -> Dict[str, jax.Array]:
+    """Everything else the model sowed into ``moe_metrics``, ``{name:
+    the leaves sown under it, stacked by layer}``: empty when it sowed
+    nothing else, so such programs carry no extra values. What the
+    names mean is the model's to say (``train_counters``)."""
     from jax.tree_util import tree_flatten_with_path
 
-    rows = [leaf for path, leaf in tree_flatten_with_path(sown_metrics)[0]
-            if any(getattr(p, "key", None) == name for p in path)]
-    return jnp.stack(rows).astype(jnp.float32) if rows else None
+    by_name: Dict[str, list] = {}
+    for path, leaf in tree_flatten_with_path(sown_metrics or {})[0]:
+        # sow() keeps a tuple under the name: the last key of the path
+        name = [p.key for p in path if hasattr(p, "key")][-1]
+        if name not in ("dropped", "routed"):  # _moe_drop_counts' two
+            by_name.setdefault(name, []).append(leaf)
+    return {name: jnp.stack(leaves).astype(jnp.float32)
+            for name, leaves in by_name.items()}
 
 
 def _shard_index(axis_names: Tuple[str, ...]) -> jax.Array:
@@ -460,13 +450,10 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
         # matching the sharded trainer's objective.
         num = jnp.sum(per * mb.w) + _sown_total(sown, per.dtype) * den
         return num, (den, new_model_state, _moe_drop_counts(sown_metrics),
-                     _moe_sown_by_layer(sown_metrics, "expert_rows"),
-                     _moe_sown_by_layer(sown_metrics, "row_chunks"))
+                     _moe_sown_by_layer(sown_metrics))
 
-    (num, (den, new_model_state, drop_counts, expert_rows,
-           row_chunks)), grads_num = jax.value_and_grad(
-        weighted_sums, has_aux=True
-    )(state.params)
+    (num, (den, new_model_state, drop_counts, sown)), grads_num = (
+        jax.value_and_grad(weighted_sums, has_aux=True)(state.params))
 
     # ONE fused collective for everything the step needs globally.
     with jax.named_scope("grad_allreduce"):
@@ -481,10 +468,7 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
             dropped_g = jax.lax.psum(drop_counts[0], axis_names)
             routed_g = jax.lax.psum(drop_counts[1], axis_names)
             drop_fraction = dropped_g / jnp.maximum(routed_g, 1.0)
-        if expert_rows is not None:
-            expert_rows = jax.lax.psum(expert_rows, axis_names)
-        if row_chunks is not None:
-            row_chunks = jax.lax.psum(row_chunks, axis_names)
+        sown = jax.tree.map(lambda a: jax.lax.psum(a, axis_names), sown)
 
         # Non-trainable collections (batch_stats) sync by global mean.
         if state.model_state:
@@ -526,8 +510,32 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
     )
     return new_state, StepMetrics(loss=loss, examples=den_g, grad_norm=gnorm,
                                   drop_fraction=drop_fraction, health=health,
-                                  expert_rows=expert_rows,
-                                  row_chunks=row_chunks)
+                                  sown=sown)
+
+
+def _shard_step(apply_fn, loss_fn, tx, axis_names, mini_batch):
+    """One shard's ``(state, batch) -> (state, StepMetrics)``, sampling
+    ``mini_batch`` rows of the shard a step (all of them when None or
+    not positive, the torch-parity "disabled" sentinels)."""
+    per_shard_mb = (mini_batch if mini_batch is not None and mini_batch > 0
+                    else None)
+
+    def one_step(state: TrainState, batch: DataBatch):
+        return _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
+                        state, batch)
+
+    return one_step
+
+
+def _over_mesh(fn, mesh: Mesh, axis_names: Tuple[str, ...],
+               n_batches: int = 1):
+    """The jitted step of ``fn(carry, *batches) -> (carry, metrics)``:
+    the carry and the metrics replicated, each batch's rows split over
+    ``axis_names``."""
+    rows = DataBatch(*(P(axis_names),) * 3)
+    mapped = shard_map_compat(fn, mesh, in_specs=(P(),) + (rows,) * n_batches,
+                              out_specs=(P(), P()))
+    return _jit_step(mapped, mesh, axis_names)
 
 
 def make_train_step(
@@ -551,27 +559,16 @@ def make_train_step(
     data, so world-total examples per step = mini_batch * n_shards and
     ported configs keep their training dynamics.
     """
-    per_shard_mb = None
-    if mini_batch is not None and mini_batch > 0:
-        per_shard_mb = mini_batch
+    one_step = _shard_step(apply_fn, loss_fn, tx, axis_names, mini_batch)
 
     # The function's name is the compiled program's (``jit_train_step``
     # on a trace's module line) and part of the persistent cache's key,
     # which leaves locations out: under the name it had before the
     # scopes, a cache could hand back a program compiled without them.
     def train_step(state: TrainState, batch: DataBatch):
-        return _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
-                        state, batch)
+        return one_step(state, batch)
 
-    data_spec = P(axis_names)
-    batch_specs = DataBatch(x=data_spec, y=data_spec, w=data_spec)
-    mapped = shard_map_compat(
-        train_step,
-        mesh,
-        in_specs=(P(), batch_specs),
-        out_specs=(P(), P()),
-    )
-    return _jit_step(mapped, mesh, axis_names)
+    return _over_mesh(train_step, mesh, axis_names)
 
 
 def make_train_epoch(
@@ -590,26 +587,13 @@ def make_train_epoch(
     a single XLA program. Returns stacked per-step metrics.
     ``mini_batch`` is per batch-shard (see ``make_train_step``).
     """
-    per_shard_mb = None
-    if mini_batch is not None and mini_batch > 0:
-        per_shard_mb = mini_batch
+    one_step = _shard_step(apply_fn, loss_fn, tx, axis_names, mini_batch)
 
     def train_epoch(state: TrainState, batch: DataBatch):  # see train_step
-        def one_step(state: TrainState, _):
-            return _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
-                            state, batch)
+        return jax.lax.scan(lambda state, _: one_step(state, batch), state,
+                            None, length=steps_per_call)
 
-        return jax.lax.scan(one_step, state, None, length=steps_per_call)
-
-    data_spec = P(axis_names)
-    batch_specs = DataBatch(x=data_spec, y=data_spec, w=data_spec)
-    mapped = shard_map_compat(
-        train_epoch,
-        mesh,
-        in_specs=(P(), batch_specs),
-        out_specs=(P(), P()),
-    )
-    return _jit_step(mapped, mesh, axis_names)
+    return _over_mesh(train_epoch, mesh, axis_names)
 
 
 def _mask_state(active: jax.Array, new: TrainState, old: TrainState) -> TrainState:
@@ -654,36 +638,24 @@ def make_train_epoch_fused(
 
     Returns a jitted fn. With ``with_val``::
 
-        ((state, es), EpochMetrics) = fn((state, es), batch, val_batch)
+        ((state, es), StepMetrics) = fn((state, es), batch, val_batch)
 
-    otherwise ``fn((state, es), batch)``. ``EpochMetrics.active`` tells
+    otherwise ``fn((state, es), batch)``. ``StepMetrics.active`` tells
     the host how many steps actually trained.
     """
-    per_shard_mb = None
-    if mini_batch is not None and mini_batch > 0:
-        per_shard_mb = mini_batch
+    shard_step = _shard_step(apply_fn, loss_fn, tx, axis_names, mini_batch)
 
-    def _val_loss(state: TrainState, vb: DataBatch) -> jax.Array:
-        preds, _, _, _ = _forward(
-            apply_fn, state.params, state.model_state, vb.x, train=False,
-            example_w=vb.w,
-        )
-        per = loss_fn(preds, vb.y)
-        num = jax.lax.psum(jnp.sum(per * vb.w), axis_names)
-        den = jax.lax.psum(jnp.sum(vb.w), axis_names)
-        return num / jnp.maximum(den, 1.0)
+    val_loss = _shard_eval(apply_fn, loss_fn, axis_names)
 
     def train_epoch_fused(carry, batch: DataBatch,
-                          val_batch: Optional[DataBatch]):
+                          val_batch: Optional[DataBatch] = None):
         def one_step(carry, _):
             state, es = carry
             active = ~es.stopped
-            stepped, metrics = _dp_body(
-                apply_fn, loss_fn, tx, axis_names, per_shard_mb, state, batch
-            )
+            stepped, metrics = shard_step(state, batch)
             new_state = _mask_state(active, stepped, state)
             if with_val:
-                val = _val_loss(new_state, val_batch)
+                val = val_loss(new_state, val_batch)
                 signal = val
             else:
                 val = jnp.float32(jnp.nan)
@@ -695,42 +667,29 @@ def make_train_epoch_fused(
                 )
             else:
                 new_es = es
-            out = EpochMetrics(
-                loss=metrics.loss,
-                examples=metrics.examples,
-                grad_norm=metrics.grad_norm,
-                val_loss=val,
-                active=active,
-                drop_fraction=metrics.drop_fraction,
-                health=metrics.health,
-                expert_rows=metrics.expert_rows,
-                row_chunks=metrics.row_chunks,
-            )
-            return (new_state, new_es), out
+            return (new_state, new_es), metrics._replace(val_loss=val,
+                                                         active=active)
 
         return jax.lax.scan(one_step, carry, None, length=steps_per_call)
 
-    data_spec = P(axis_names)
-    batch_specs = DataBatch(x=data_spec, y=data_spec, w=data_spec)
-    carry_specs = (P(), P())
-    if with_val:
-        mapped = shard_map_compat(
-            train_epoch_fused,
-            mesh,
-            in_specs=(carry_specs, batch_specs, batch_specs),
-            out_specs=((P(), P()), P()),
-        )
-    else:
-        def train_epoch_fused_noval(carry, batch):
-            return train_epoch_fused(carry, batch, None)
+    return _over_mesh(train_epoch_fused, mesh, axis_names,
+                      n_batches=2 if with_val else 1)
 
-        mapped = shard_map_compat(
-            train_epoch_fused_noval,
-            mesh,
-            in_specs=(carry_specs, batch_specs),
-            out_specs=((P(), P()), P()),
+
+def _shard_eval(apply_fn, loss_fn, axis_names):
+    """One shard's ``(state, batch) -> global weighted-mean loss``."""
+
+    def shard_eval(state: TrainState, batch: DataBatch) -> jax.Array:
+        preds, _, _, _ = _forward(
+            apply_fn, state.params, state.model_state, batch.x, train=False,
+            example_w=batch.w,
         )
-    return _jit_step(mapped, mesh, axis_names)
+        per = loss_fn(preds, batch.y)
+        num = jax.lax.psum(jnp.sum(per * batch.w), axis_names)
+        den = jax.lax.psum(jnp.sum(batch.w), axis_names)
+        return num / jnp.maximum(den, 1.0)
+
+    return shard_eval
 
 
 def make_eval_step(
@@ -741,23 +700,6 @@ def make_eval_step(
 ) -> Callable[[TrainState, DataBatch], jax.Array]:
     """Global weighted-mean validation loss — the per-iteration val
     forward of ``distributed.py:166-176``, compiled and collective."""
-
-    def shard_eval(state: TrainState, batch: DataBatch):
-        preds, _, _, _ = _forward(
-            apply_fn, state.params, state.model_state, batch.x, train=False,
-            example_w=batch.w,
-        )
-        per = loss_fn(preds, batch.y)
-        num = jax.lax.psum(jnp.sum(per * batch.w), axis_names)
-        den = jax.lax.psum(jnp.sum(batch.w), axis_names)
-        return num / jnp.maximum(den, 1.0)
-
-    data_spec = P(axis_names)
-    batch_specs = DataBatch(x=data_spec, y=data_spec, w=data_spec)
-    mapped = shard_map_compat(
-        shard_eval,
-        mesh,
-        in_specs=(P(), batch_specs),
-        out_specs=P(),
-    )
-    return jax.jit(mapped)
+    return jax.jit(shard_map_compat(
+        _shard_eval(apply_fn, loss_fn, axis_names), mesh,
+        in_specs=(P(), DataBatch(*(P(axis_names),) * 3)), out_specs=P()))

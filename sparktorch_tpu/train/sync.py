@@ -16,6 +16,7 @@ weight-zero padding (see utils/data.py).
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Any, Callable, NamedTuple, Optional, Union
 
 import jax
@@ -27,7 +28,13 @@ from sparktorch_tpu.ft import chaos as _chaos
 from sparktorch_tpu.obs import get_logger, get_telemetry
 from sparktorch_tpu.obs import goodput as _goodput
 from sparktorch_tpu.parallel.launch import check_gang, notify_gang_step
-from sparktorch_tpu.parallel.mesh import BATCH_AXES, batch_sharding, build_mesh, replicated
+from sparktorch_tpu.parallel.mesh import (
+    AXIS_PP,
+    BATCH_AXES,
+    batch_sharding,
+    build_mesh,
+    replicated,
+)
 from sparktorch_tpu.train.step import (
     EsConfig,
     TrainState,
@@ -60,6 +67,11 @@ def _as_batch(data, labels=None, validation_pct=0.0, seed=0):
     return handle_features(data, labels, validation_pct, seed)
 
 
+def _n_shards(mesh: Mesh) -> int:
+    """How many shards the batch axes cut the rows into."""
+    return math.prod(mesh.shape[ax] for ax in BATCH_AXES)
+
+
 def prepare_sharded_batch(batch: DataBatch, mesh: Mesh) -> DataBatch:
     """Pad to a multiple of the batch-axis size and place shards.
 
@@ -67,12 +79,16 @@ def prepare_sharded_batch(batch: DataBatch, mesh: Mesh) -> DataBatch:
     protocol (``distributed.py:46-63,131-133``) done with math instead
     of phantom collective participants.
     """
-    n_shards = 1
-    for ax in BATCH_AXES:
-        n_shards *= mesh.shape[ax]
-    padded = pad_to_multiple(batch, n_shards)
+    padded = pad_to_multiple(batch, _n_shards(mesh))
     sharding = batch_sharding(mesh)
     return DataBatch(*(jax.device_put(a, sharding) for a in padded))
+
+
+def _pad_rows(arr: np.ndarray, n: int) -> np.ndarray:
+    """``arr`` with zero rows (weight 0) added up to ``n``."""
+    if arr.shape[0] == n:
+        return arr
+    return np.pad(arr, [(0, n - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1))
 
 
 def _shuffle_batch(batch: DataBatch, key: jax.Array, mesh: Mesh) -> DataBatch:
@@ -174,29 +190,319 @@ def _note_model_gauges(tele, module) -> None:
         tele.gauge(name, value)
 
 
-def _note_moe_rows(tele, record, expert_rows, row_chunks, drop_fraction):
-    """What an expert layer that counts its rows sowed in one step, into
-    the step's record and onto the bus. From ``[MoE layers, experts
-    held]`` rows: the rows computed, the most loaded expert's and the
-    mean, and the routed pairs that were not computed (``drop_fraction``
-    is dropped over routed, and routed is computed plus dropped). From
-    ``[MoE layers, 2]`` chunks (where the layer moves its rows by
-    chunks): the chunks its loops ran, whose ratio to the chunks that
-    all chosen pairs would take is the share of them moved."""
-    if expert_rows is not None:
-        rows, f = float(expert_rows.sum()), float(drop_fraction or 0.0)
-        record.update(
-            moe_rows=rows, moe_rows_max=float(expert_rows.max()),
-            moe_rows_mean=rows / expert_rows.size,
-            moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows)
-        tele.counter("train.moe.rows", rows)
-        tele.counter("train.moe.pairs_dropped", record["moe_pairs_dropped"])
-        tele.gauge("train.moe.rows_max", record["moe_rows_max"])
-    if row_chunks is not None:
-        record["moe_row_chunks"] = float(row_chunks[:, 0].sum())
-        tele.counter("train.moe.row_chunks", record["moe_row_chunks"])
-        tele.gauge("train.moe.row_chunks_possible",
-                   float(row_chunks[:, 1].sum()))
+def _note_model_counters(tele, module, record, sown, drop_fraction) -> None:
+    """What the model sowed in one step (``{name: that step's values
+    by layer}``), into the step's record and onto the bus, as the model
+    reads it (``train_counters``: the record's fields, the bus's
+    counters and gauges). The trainer knows no counter by name."""
+    explain = getattr(module, "train_counters", None)
+    if not sown or explain is None:
+        return
+    fields, counters, gauges = explain(sown, drop_fraction)
+    record.update(fields)
+    for name, value in counters.items():
+        tele.counter(name, value)
+    for name, value in gauges.items():
+        tele.gauge(name, value)
+
+
+def _make_step(module, loss_fn, tx, mesh: Mesh, steps: int, mini_batch,
+               in_scan: Optional[dict] = None):
+    """The compiled step of ``steps`` steps a dispatch: one step, a scan
+    of them, or (``in_scan``: ``es_config``, ``with_val``) a scan that
+    decides the early stop and runs the val forward itself."""
+    if in_scan is not None:
+        return make_train_epoch_fused(module.apply, loss_fn, tx, mesh, steps,
+                                      mini_batch=mini_batch, **in_scan)
+    if steps > 1:
+        return make_train_epoch(module.apply, loss_fn, tx, mesh, steps,
+                                mini_batch=mini_batch)
+    return make_train_step(module.apply, loss_fn, tx, mesh,
+                           mini_batch=mini_batch)
+
+
+def _result(spec, loop: "_ChunkLoop", obs: "_RunObservers") -> TrainResult:
+    """What a fit hands back, the final parameters on the host."""
+    # lint-obs: ok (end-of-run gather after the loop drained)
+    params, model_state = jax.device_get(
+        (loop.state.params, loop.state.model_state))
+    return TrainResult(params=params, model_state=model_state,
+                       metrics=obs.recorder.records, spec=spec,
+                       summary=obs.recorder.summary())
+
+
+def _init_state(tele, spec, mesh: Mesh, rng, sample_x, tx) -> TrainState:
+    """Initialize UNDER jit with replicated out_shardings: every process
+    runs the same compiled init, so this works on multi-process
+    (non-fully-addressable) meshes where a host-side device_put of
+    replicated state cannot (the reference replicates the model onto
+    every executor, distributed.py:112-115). The jitted init is a
+    compile-dominated call (one trace+compile, negligible device work):
+    the goodput ledger's compile bucket takes it."""
+    with tele.span("train/init"), _goodput.span(
+            "compile", {"site": "train_init"}), mesh:
+        return jax.jit(
+            lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx),
+            out_shardings=replicated(mesh),
+        )()
+
+
+class _RunObservers:
+    """Everything that watches a fit without being part of it, wired
+    once a call: the stack sampler and the model-health ledger
+    (``obs.profile``, ``obs.health``), the goodput ledger's step clock
+    (``obs.goodput``), the gang's liveness check, the seeded chaos
+    sites (``ft.chaos``), the trace's step annotation and the
+    recorder of the step records. The loop calls it at four points and
+    sees none of them by name."""
+
+    def __init__(self, tele, mesh: Mesh, prefix: str = "train"):
+        from sparktorch_tpu.obs import health, profile
+        from sparktorch_tpu.utils.metrics import MetricsRecorder
+
+        self._tele = tele
+        self._rank = jax.process_index()
+        # The stack sampler lives wherever ledgers live: the ambient
+        # ledger names the thieving bucket, the sampler the function
+        # inside it. Env-gated; idempotent per process.
+        profile.ensure(tele)
+        # reset() re-bases the EWMAs, so a restarted attempt on the same
+        # bus is not judged against the previous attempt's losses.
+        self._health = health.ensure(tele, rank=self._rank)
+        if self._health is not None:
+            self._health.reset()
+        self.recorder = MetricsRecorder(n_chips=mesh.size, telemetry=tele,
+                                        prefix=prefix)
+
+    def before_dispatch(self, i: int, state: TrainState,
+                        batch: DataBatch) -> DataBatch:
+        """Between two compiled dispatches, where a real preempt lands:
+        GangFailure if a peer host died (its heartbeat marks it dead
+        within one interval), not a wedge in the chunk's collectives;
+        this rank's progress onto its heartbeat, for the driver's step
+        skew; the seeded chaos sites. Returns the batch to dispatch on."""
+        check_gang()
+        notify_gang_step(i)
+        # The kill point (ft.supervisor.supervise_run restarts the
+        # attempt from the latest checkpoint). `i`, not state.step: that
+        # would cost a device sync a chunk, and one-shot kill configs
+        # make the distinction irrelevant across resumes.
+        _chaos.fire("worker.step", worker=self._rank, step=i)
+        # Poison-batch injection (bench-health drill): the poisoned copy
+        # REPLACES the batch, so the health ledger's replay anchor
+        # records exactly what dispatches.
+        act = _chaos.fire("data.batch", worker=self._rank, step=i)
+        if act and act.get("poison"):
+            batch = _chaos.poison_batch(batch)
+        if self._health is not None:
+            if self._health.leaf_keys is None:
+                from sparktorch_tpu.obs.health import health_leaf_keys
+
+                self._health.leaf_keys = health_leaf_keys(state.params)
+            self._health.note_replay_anchor(state, batch)
+        # Straggler injection: the sleep comes BEFORE the step span, so
+        # the skew referee sees a late arrival, not a longer step.
+        _chaos.straggle(self._rank, i)
+        return batch
+
+    @contextlib.contextmanager
+    def step_span(self, i: int, step_fn):
+        """The step clock, a goodput LedgerSpan: it times the
+        dispatch+sync region whether or not a ledger is active
+        (step_time_s comes off its duration), and when one is, the
+        seconds land in the step bucket, or in ``compile`` when the jit
+        dispatch cache grew under the call (first call / new shape).
+        Set ``count`` on it to the steps the dispatch trained."""
+        cache0 = (_goodput.jit_cache_size(step_fn)
+                  if _goodput.active() is not None else None)
+        with _goodput.step_span(step=i) as led:
+            yield led
+            if cache0 is not None and (
+                    _goodput.jit_cache_size(step_fn) or cache0) > cache0:
+                led.rebucket("compile")
+
+    def annotation(self):
+        """The trace's step boundary, numbered by the steps recorded."""
+        from sparktorch_tpu.utils.tracing import step_annotation
+
+        return step_annotation(len(self.recorder.records),
+                               telemetry=self._tele)
+
+    def after_chunk(self, count: int, health, loss, grad_norm) -> None:
+        """The chunk's ``count`` trained steps to the health ledger:
+        ``health`` stays on the device (it is fetched K notes late),
+        ``loss`` and ``grad_norm`` are the rows just read back."""
+        if self._health is None or count < 1:
+            return
+        self._health.note_step(
+            count=count,
+            device=None if health is None else {
+                "finite": health.finite,
+                "update_ratio": health.update_ratio,
+                "leaf_norms": health.leaf_norms,
+            },
+            host={"loss": loss, "grad_norm": grad_norm},
+        )
+
+    def record(self, record: dict) -> None:
+        self.recorder.record(record)
+
+    def close(self) -> None:
+        if self._health is not None:
+            # Drain the delayed-fetch tail so the published section
+            # (and any postmortem) reflects the final steps.
+            self._health.flush()
+
+
+class _BatchSource:
+    """The rows a dispatch trains on: ``batch`` is what the next one
+    takes (a poisoned copy is put back here); ``advance`` is called once
+    it is dispatched, inside its step span (resident rows: nothing; the
+    streaming fit enqueues the next chunk's copy)."""
+
+    def __init__(self, batch: Optional[DataBatch] = None,
+                 advance: Callable[[], None] = lambda: None):
+        self.batch, self.advance = batch, advance
+
+
+class _ChunkLoop:
+    """The host's side of one dispatch of the compiled step, written
+    once for the resident fit a step a dispatch (``steps`` 1), the
+    resident fit by fused chunks and the streaming fit: observers,
+    dispatch, readback, the steps' records, the checkpoint. Owns the
+    carried ``state`` (the step donates it) and, where the stop is
+    decided inside the scan, the early-stop carry ``es_state``.
+
+    ``span`` names the dispatch (``train/step_chunk``, ``train/step``,
+    ``train_streaming/chunk``). With ``tiled``, three more host spans
+    tile an iteration together with it, a few a chunk; a step a
+    dispatch keeps its one span a step."""
+
+    def __init__(self, tele, obs: _RunObservers, module, state: TrainState,
+                 step_fn, steps: int, span: str, tiled: bool = False,
+                 metrics_hook=None, verbose: int = 0, ckpt=None,
+                 checkpoint_every: int = 0, val_batch=None, eval_step=None,
+                 es_state=None, stopper=None):
+        self.state, self.es_state = state, es_state
+        self._tele, self._obs, self._module = tele, obs, module
+        self._step_fn, self._steps, self._span = step_fn, steps, span
+        self._tile = (tele.span if tiled
+                      else lambda _name: contextlib.nullcontext())
+        self._checkpoint_span = span.split("/")[0] + "/checkpoint"
+        self._hook, self._verbose = metrics_hook, verbose
+        self._log = get_logger("sparktorch_tpu.train")
+        self._val_batch, self._eval_step = val_batch, eval_step
+        self._stopper = stopper
+        self._ckpt, self._every = ckpt, checkpoint_every
+        # lint-obs: ok (pre-loop scalar — nothing queued yet)
+        self.last_ckpt_step = (int(jax.device_get(state.step))
+                               if ckpt is not None else 0)
+        # The hook's copy of a record also carries the step's per-leaf
+        # gradient norms and, once a call, their keys.
+        self._leaf_keys = None
+        if metrics_hook:
+            from sparktorch_tpu.obs.health import health_leaf_keys
+
+            self._leaf_keys = health_leaf_keys(state.params)
+
+    def _dispatch(self, batch: DataBatch):
+        if self.es_state is None:
+            self.state, stacked = self._step_fn(self.state, batch)
+        else:
+            batches = ((batch,) if self._val_batch is None
+                       else (batch, self._val_batch))
+            (self.state, self.es_state), stacked = self._step_fn(
+                (self.state, self.es_state), *batches)
+        return stacked
+
+    def run(self, source: _BatchSource, i: int, round_: int):
+        """One dispatch on ``source.batch`` at iteration ``i`` of round
+        ``round_``. Returns ``(steps recorded, whether to stop)``."""
+        tele, obs, steps = self._tele, self._obs, self._steps
+        with self._tile("train/chunk_prepare"):
+            source.batch = obs.before_dispatch(i, self.state, source.batch)
+        with obs.step_span(i, self._step_fn) as led:
+            with tele.span(self._span) as span, obs.annotation():
+                stacked = self._dispatch(source.batch)
+                source.advance()
+                span.sync(stacked.loss)
+            with self._tile("train/chunk_readback"):
+                # A scan stacks its steps; one step is a chunk of one. The
+                # health vector stays on the device, but for the hook's norms.
+                rows = (np.asarray if steps > 1
+                        else lambda a: np.asarray(a)[None])
+                host = jax.tree.map(rows, stacked._replace(health=None))
+                leaf_rows = (rows(stacked.health.leaf_norms)
+                             if self._hook and stacked.health is not None
+                             else None)
+            n_active = (steps if host.active is None
+                        else int(np.sum(host.active)))
+            led.count = max(1, n_active)
+        vals = [None] * steps
+        if host.val_loss is not None:
+            vals = [None if np.isnan(v) else float(v) for v in host.val_loss]
+        elif self._eval_step is not None:
+            # The per-iteration val forward is productive device work,
+            # just not a train step.
+            with _goodput.span("compute", {"site": "eval"}):
+                vals = [float(self._eval_step(self.state, self._val_batch))]
+        with self._tile("train/chunk_records"):
+            obs.after_chunk(n_active, stacked.health, host.loss,
+                            host.grad_norm)
+            done, stop = self._record(
+                host, vals, leaf_rows, i, round_, n_active,
+                led.duration_s / max(1, n_active))
+            if self.es_state is not None:
+                # lint-obs: ok (one early-stop scalar per drained chunk)
+                stop = bool(jax.device_get(self.es_state.stopped))
+        if self._ckpt is not None:
+            with tele.span(self._checkpoint_span):
+                self.last_ckpt_step = _save_if_due(
+                    self._ckpt, self.state, self.last_ckpt_step, self._every)
+        return done, stop
+
+    def _record(self, host, vals, leaf_rows, i, round_, n_active, dt):
+        """The chunk's trained steps (the first ``n_active``: the stop had
+        fired and the scan masked the rest out) to the recorder, the hook,
+        the log and the host's stopper. Returns ``(recorded, stopped)``."""
+        for j in range(n_active):
+            loss, val_loss = float(host.loss[j]), vals[j]
+            record = {
+                "round": round_, "iter": i + j, "loss": loss,
+                "val_loss": val_loss, "examples": float(host.examples[j]),
+                "grad_norm": float(host.grad_norm[j]), "step_time_s": dt,
+            }
+            drop_f = (None if host.drop_fraction is None
+                      else float(host.drop_fraction[j]))
+            if drop_f is not None:
+                record["moe_drop_fraction"] = drop_f
+            _note_model_counters(
+                self._tele, self._module, record,
+                {name: v[j] for name, v in (host.sown or {}).items()}, drop_f)
+            self._obs.record(record)
+            if self._hook:
+                # the recorder keeps neither the norms nor their keys
+                if leaf_rows is not None:
+                    record = {**record, "leaf_grad_norms": leaf_rows[j]}
+                    if self._leaf_keys is not None:
+                        record["leaf_grad_norm_keys"] = self._leaf_keys
+                        self._leaf_keys = None
+                self._hook(record)
+            if self._verbose:
+                # The reference prints a loss line a partition
+                # (distributed.py:201-204); here one global line.
+                msg = (f"[sparktorch_tpu] round {round_} iter {i + j} "
+                       f"loss {loss:.6f}")
+                if val_loss is not None:
+                    msg += f" val_loss {val_loss:.6f}"
+                self._log.info(msg)
+            # Early stop needs no collective: `loss` is already the
+            # global mean, identical on every host (vs the reference's
+            # two extra all_reduces, distributed.py:186-197).
+            if self._stopper is not None and self._stopper.step(
+                    val_loss if val_loss is not None else loss):
+                return j + 1, True
+        return n_active, False
 
 
 def train_distributed(
@@ -246,8 +552,6 @@ def train_distributed(
         spec = deserialize_model(torch_obj)
         mesh = mesh or build_mesh()
 
-    from sparktorch_tpu.parallel.mesh import AXIS_PP
-
     if dict(mesh.shape).get(AXIS_PP, 1) > 1:
         # pp is a MESH choice on this same entry point: a mesh with
         # pp>1 routes to the GPipe trainer (pipeline.py), which trains
@@ -276,20 +580,7 @@ def train_distributed(
             telemetry=telemetry,
         )
 
-    # The continuous stack sampler lives wherever ledgers live: the
-    # ambient ledger names the thieving bucket, the sampler names the
-    # function inside it. Env-gated; idempotent per process.
-    from sparktorch_tpu.obs import health as _health
-    from sparktorch_tpu.obs import profile as _profile
-
-    _profile.ensure(tele)
-    # Model-health lane (obs/health.py): per-rank ledger fed each step
-    # with device values fetched K steps late. reset() re-bases the
-    # EWMAs so a restarted attempt on the same bus is not judged
-    # against the previous attempt's loss baseline.
-    _hl = _health.ensure(tele, rank=jax.process_index())
-    if _hl is not None:
-        _hl.reset()
+    obs = _RunObservers(tele, mesh)
     if pre_sharded:
         # ``data`` is already a globally-sharded DataBatch (multi-host
         # path, train_distributed_multihost) — do not re-place it.
@@ -319,362 +610,86 @@ def train_distributed(
                              train_batch.x.dtype)
     else:
         sample_x = train_batch.x[:1]
-    # Initialize UNDER jit with replicated out_shardings: every process
-    # runs the same compiled init, so this works on multi-process
-    # (non-fully-addressable) meshes where a host-side device_put of
-    # replicated state cannot (the reference replicates the model onto
-    # every executor, distributed.py:112-115).
-    # The jitted init is a compile-dominated call (one trace+compile,
-    # negligible device work) — the ledger's compile bucket takes it.
-    with tele.span("train/init"), _goodput.span(
-            "compile", {"site": "train_init"}), mesh:
-        state = jax.jit(
-            lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx),
-            out_shardings=replicated(mesh),
-        )()
-
+    state = _init_state(tele, spec, mesh, rng, sample_x, tx)
     ckpt, state = _open_checkpoint(checkpoint_dir, resume, state)
-    if _hl is not None and _hl.leaf_keys is None:
-        _hl.leaf_keys = _health.health_leaf_keys(state.params)
 
     with tele.span("train/build_step"):
         loss_fn = spec.loss_fn()
         module = spec.make_module()
 
-        stopper = (
-            EarlyStopping(patience=early_stop_patience)
-            if early_stop_patience is not None and early_stop_patience > 0
-            else None
-        )
+        stopper = (EarlyStopping(patience=early_stop_patience)
+                   if early_stop_patience is not None
+                   and early_stop_patience > 0 else None)
         # Fast path: fuse many steps into one compiled call (lax.scan).
-        # Early stopping / the val forward no longer force 1 step/call:
-        # the stop decision and per-iter val forward ride INSIDE the fused
-        # scan (make_train_epoch_fused) with exact per-step semantics —
-        # post-stop steps are masked to no-ops, so the only fusion cost is
-        # the masked tail of the chunk where the stop fires (hence the
-        # smaller default chunk there).
+        # Early stopping / the val forward do not force 1 step/call: the
+        # stop decision and per-iter val forward ride INSIDE the fused
+        # scan (make_train_epoch_fused) with exact per-step semantics.
+        # Post-stop steps are masked to no-ops, so the only fusion cost
+        # is the masked tail of the chunk where the stop fires (hence
+        # the smaller default chunk there).
         steps_per_call = _resolve_steps_per_call(
             steps_per_call,
-            default=(
-                min(iters, 8)
-                if (stopper is not None or val_batch is not None)
-                else min(iters, 32)
-            ),
-            iters=iters,
-            checkpoint_every=checkpoint_every,
-            ckpt_active=ckpt is not None,
-        )
-
+            default=min(iters, 32 if stopper is None and val_batch is None
+                        else 8),
+            iters=iters, checkpoint_every=checkpoint_every,
+            ckpt_active=ckpt is not None)
+        # At one step a dispatch the host's stopper and a separate val
+        # forward decide (ROADMAP D15); inside a scan, the scan's own.
         fused_signals = steps_per_call > 1 and (
-            stopper is not None or val_batch is not None
-        )
-        es_state = init_es_state() if fused_signals else None
-        if fused_signals:
-            train_step = make_train_epoch_fused(
-                module.apply, loss_fn, tx, mesh, steps_per_call,
-                es_config=(
-                    EsConfig(patience=early_stop_patience)
-                    if stopper is not None else None
-                ),
-                with_val=val_batch is not None,
-                mini_batch=mini_batch,
-            )
-        elif steps_per_call > 1:
-            train_step = make_train_epoch(
-                module.apply, loss_fn, tx, mesh, steps_per_call, mini_batch=mini_batch
-            )
-        else:
-            train_step = make_train_step(
-                module.apply, loss_fn, tx, mesh, mini_batch=mini_batch
-            )
-        eval_step = (
-            make_eval_step(module.apply, loss_fn, mesh)
-            if val_batch is not None and not fused_signals
-            else None
-        )
+            stopper is not None or val_batch is not None)
+        train_step = _make_step(
+            module, loss_fn, tx, mesh, steps_per_call, mini_batch,
+            in_scan=dict(es_config=(EsConfig(patience=early_stop_patience)
+                                    if stopper is not None else None),
+                         with_val=val_batch is not None)
+            if fused_signals else None)
+        eval_step = (make_eval_step(module.apply, loss_fn, mesh)
+                     if val_batch is not None and not fused_signals else None)
         _note_grad_allreduce(tele, state.params, mesh)
         _note_model_gauges(tele, module)
 
-    from sparktorch_tpu.utils.metrics import MetricsRecorder
-    from sparktorch_tpu.utils.tracing import profile_run, step_annotation
+    from sparktorch_tpu.utils.tracing import profile_run
 
-    recorder = MetricsRecorder(n_chips=mesh.size, telemetry=tele)
-    metrics = recorder.records
-    log = get_logger("sparktorch_tpu.train")
-    # lint-obs: ok (pre-loop scalar — nothing queued yet)
-    last_ckpt_step = int(jax.device_get(state.step)) if ckpt is not None else 0
+    loop = _ChunkLoop(
+        tele, obs, module, state, train_step, steps_per_call,
+        span="train/step_chunk" if steps_per_call > 1 else "train/step",
+        tiled=steps_per_call > 1, metrics_hook=metrics_hook, verbose=verbose,
+        ckpt=ckpt, checkpoint_every=checkpoint_every, val_batch=val_batch,
+        eval_step=eval_step,
+        es_state=init_es_state() if fused_signals else None,
+        stopper=None if fused_signals else stopper)
+    source = _BatchSource(train_batch)
+    del train_batch  # or a shuffle could not free the rows it read
     shuffle_key = jax.random.key(seed + 1)
-    # What the hook gets beside the recorder's record: set per chunk.
-    leaf_rows = None
-    expert_rows = None  # [steps, MoE layers, experts held] of a chunk
-    row_chunks = None  # [steps, MoE layers, 2 (run, possible)]
-    leaf_keys = (_health.health_leaf_keys(state.params)
-                 if metrics_hook else None)
-    # On the fused path three host spans tile an iteration together
-    # with train/step_chunk (prepare, readback, records): a few a
-    # chunk. The per-step path keeps its one train/step span a step.
-    chunk_span = (tele.span if steps_per_call > 1
-                  else lambda _name: contextlib.nullcontext())
-    profiler = profile_run(profile_dir, telemetry=tele)
-    profiler.__enter__()
     completed = False
     try:
-        for shuffle_round in range(max(1, partition_shuffles)):
-            # Round 0 must ALSO shuffle when minibatch sampling is on:
-            # sample_minibatch takes contiguous blocks, whose
-            # uniformity argument requires random resident order — an
-            # input sorted by label (common from Spark groupBy) would
-            # otherwise feed near-single-class blocks all run.
-            if shuffle_round > 0 or (mini_batch is not None and mini_batch > 0):
-                shuffle_key, sub = jax.random.split(shuffle_key)
-                with tele.span("train/shuffle"):
-                    train_batch = _shuffle_batch(train_batch, sub, mesh)
-            stop = False
-            i = 0
-            while i < iters:
-                with chunk_span("train/chunk_prepare"):
-                    # Fail fast if a peer host died (multi-host runs only; the
-                    # gang's heartbeat marks survivors dead within one
-                    # interval). Checking here — before dispatching the next
-                    # compiled chunk — means we raise GangFailure instead of
-                    # wedging in the chunk's collectives. The same spot
-                    # publishes this rank's progress on its heartbeat so
-                    # the driver can read cross-rank step skew, and hosts
-                    # the chaos kill point (a seeded injection dies here,
-                    # between compiled dispatches — where a real preempt
-                    # lands; ft.supervisor.supervise_run then restarts the
-                    # attempt resuming from the latest checkpoint).
-                    check_gang()
-                    notify_gang_step(i)
-                    # `i` (the round-local iteration), not state.step: the
-                    # latter would cost a device sync per chunk on the hot
-                    # path; one-shot kill configs make the distinction
-                    # irrelevant across resumes.
-                    _chaos.fire("worker.step", worker=jax.process_index(),
-                                step=i)
-                    # Seeded poison-batch injection (bench-health drill):
-                    # the site returns an action dict instead of raising,
-                    # and the poisoned copy REPLACES the resident batch so
-                    # the health ledger's replay anchor records exactly
-                    # what dispatches.
-                    _act = _chaos.fire("data.batch",
-                                       worker=jax.process_index(), step=i)
-                    if _act and _act.get("poison"):
-                        train_batch = _chaos.poison_batch(train_batch)
-                    if _hl is not None:
-                        _hl.note_replay_anchor(state, train_batch)
-                    # Seeded straggler injection: sleep BEFORE the step
-                    # span so the skew referee sees a late fence arrival
-                    # on this rank, not a longer step.
-                    _chaos.straggle(jax.process_index(), i)
-                    # The step clock is a goodput LedgerSpan: it times the
-                    # dispatch+sync region whether or not a ledger is
-                    # active (step_time_s comes off its duration), and when
-                    # one is, the seconds land in the step bucket — or in
-                    # ``compile`` when the jit dispatch cache grew under
-                    # the call (first call / new shape).
-                    cache0 = (_goodput.jit_cache_size(train_step)
-                              if _goodput.active() is not None else None)
-                if steps_per_call > 1:
-                    n = min(steps_per_call, iters - i)
-                    with _goodput.step_span(step=i) as _led:
-                        with tele.span("train/step_chunk") as _chunk_span, \
-                                step_annotation(
-                                    int(metrics[-1]["iter"]) + 1
-                                    if metrics else 0,
-                                    telemetry=tele):
-                            if fused_signals:
-                                args = (((state, es_state), train_batch,
-                                         val_batch)
-                                        if val_batch is not None
-                                        else ((state, es_state), train_batch))
-                                (state, es_state), stacked = train_step(*args)
-                            else:
-                                state, stacked = train_step(state, train_batch)
-                            _chunk_span.sync(stacked.loss)
-                        with tele.span("train/chunk_readback"):
-                            losses = np.asarray(stacked.loss)[:n]
-                            examples = np.asarray(stacked.examples)[:n]
-                            gnorms = np.asarray(stacked.grad_norm)[:n]
-                            if fused_signals:
-                                vals = np.asarray(stacked.val_loss)[:n]
-                                actives = np.asarray(stacked.active)[:n]
-                            else:
-                                vals = [None] * n
-                                actives = [True] * n
-                            drops = (
-                                np.asarray(stacked.drop_fraction)[:n]
-                                if stacked.drop_fraction is not None
-                                else [None] * n
-                            )
-                            if metrics_hook and stacked.health is not None:
-                                leaf_rows = np.asarray(
-                                    stacked.health.leaf_norms)[:n]
-                            if stacked.expert_rows is not None:
-                                expert_rows = np.asarray(
-                                    stacked.expert_rows)[:n]
-                            if stacked.row_chunks is not None:
-                                row_chunks = np.asarray(
-                                    stacked.row_chunks)[:n]
-                        n_active = int(np.sum(np.asarray(actives)))
-                        _led.count = max(1, n_active)
-                        if cache0 is not None and (
-                                _goodput.jit_cache_size(train_step)
-                                or cache0) > cache0:
-                            _led.rebucket("compile")
-                else:
-                    with _goodput.step_span(step=i) as _led:
-                        with tele.span("train/step") as _step_span, \
-                                step_annotation(i, telemetry=tele):
-                            state, step_metrics = train_step(state,
-                                                             train_batch)
-                            _step_span.sync(step_metrics.loss)
-                        if cache0 is not None and (
-                                _goodput.jit_cache_size(train_step)
-                                or cache0) > cache0:
-                            _led.rebucket("compile")
-                    if eval_step is not None:
-                        # The per-iteration val forward is productive
-                        # device work, just not a train step.
-                        with _goodput.span("compute", {"site": "eval"}):
-                            val_now = float(eval_step(state, val_batch))
-                    else:
-                        val_now = None
-                    chunk = [(
-                        float(step_metrics.loss),
-                        float(step_metrics.examples),
-                        float(step_metrics.grad_norm),
-                        val_now,
-                        True,
-                        float(step_metrics.drop_fraction)
-                        if step_metrics.drop_fraction is not None else None,
-                    )]
-                    dt = _led.duration_s
-                    if metrics_hook and step_metrics.health is not None:
-                        leaf_rows = [np.asarray(
-                            step_metrics.health.leaf_norms)]
-                    if step_metrics.expert_rows is not None:
-                        expert_rows = [np.asarray(step_metrics.expert_rows)]
-                    if step_metrics.row_chunks is not None:
-                        row_chunks = [np.asarray(step_metrics.row_chunks)]
-                    if _hl is not None:
-                        _h = step_metrics.health
-                        _hl.note_step(
-                            device=None if _h is None else {
-                                "finite": _h.finite,
-                                "update_ratio": _h.update_ratio,
-                                "leaf_norms": _h.leaf_norms,
-                            },
-                            host={"loss": chunk[0][0],
-                                  "grad_norm": chunk[0][2]},
-                        )
-
-                with chunk_span("train/chunk_records"):
-                    if steps_per_call > 1:
-                        dt = _led.duration_s / max(1, n_active)
-                        if _hl is not None and n_active > 0:
-                            _h = stacked.health
-                            _hl.note_step(
-                                count=n_active,
-                                device=None if _h is None else {
-                                    "finite": _h.finite,
-                                    "update_ratio": _h.update_ratio,
-                                    "leaf_norms": _h.leaf_norms,
-                                },
-                                host={"loss": losses, "grad_norm": gnorms},
-                            )
-                        chunk = [
-                            (float(l), float(e), float(g),
-                             None if v is None or np.isnan(v) else float(v),
-                             bool(a), None if dr is None else float(dr))
-                            for l, e, g, v, a, dr in zip(
-                                losses, examples, gnorms, vals, actives,
-                                drops)
-                        ]
-                    for j, (loss, examples_n, gnorm, val_loss, active,
-                            drop_f) in enumerate(chunk):
-                        if not active:
-                            # Step masked out inside the fused chunk: the
-                            # stop had already fired — nothing trained.
-                            break
-                        record = {
-                            "round": shuffle_round,
-                            "iter": i,
-                            "loss": loss,
-                            "val_loss": val_loss,
-                            "examples": examples_n,
-                            "grad_norm": gnorm,
-                            "step_time_s": dt,
-                        }
-                        if drop_f is not None:
-                            record["moe_drop_fraction"] = drop_f
-                        _note_moe_rows(
-                            tele, record,
-                            None if expert_rows is None else expert_rows[j],
-                            None if row_chunks is None else row_chunks[j],
-                            drop_f)
-                        recorder.record(record)
-                        if metrics_hook:
-                            # The hook's copy also carries the step's
-                            # per-leaf gradient norms (a row of the
-                            # chunk's readback) and, once a call, their
-                            # keys; the recorder keeps neither.
-                            if leaf_rows is not None:
-                                record = {**record,
-                                          "leaf_grad_norms": leaf_rows[j]}
-                                if leaf_keys is not None:
-                                    record["leaf_grad_norm_keys"] = leaf_keys
-                                    leaf_keys = None
-                            metrics_hook(record)
-                        if verbose:
-                            # Reference prints per-partition loss lines
-                            # (distributed.py:201-204); here one global
-                            # line through the obs logger (lint-obs bans
-                            # raw prints in library code).
-                            msg = f"[sparktorch_tpu] round {shuffle_round} iter {i} loss {loss:.6f}"
-                            if val_loss is not None:
-                                msg += f" val_loss {val_loss:.6f}"
-                            log.info(msg)
-                        # Early stop needs no collective: `loss` is already the
-                        # global mean, identical on every host (vs the
-                        # reference's two extra all_reduces,
-                        # distributed.py:186-197). On the fused path the
-                        # decision already happened on-device (EsState).
-                        if stopper is not None and not fused_signals:
-                            signal = val_loss if val_loss is not None else loss
-                            if stopper.step(signal):
-                                stop = True
-                                break
-                        i += 1
-                    # lint-obs: ok (one early-stop scalar per drained chunk)
-                    if fused_signals and bool(jax.device_get(es_state.stopped)):
-                        stop = True
-                if ckpt is not None:
-                    with tele.span("train/checkpoint"):
-                        last_ckpt_step = _save_if_due(
-                            ckpt, state, last_ckpt_step, checkpoint_every
-                        )
+        with profile_run(profile_dir, telemetry=tele):
+            for shuffle_round in range(max(1, partition_shuffles)):
+                # Round 0 must ALSO shuffle when minibatch sampling is
+                # on: sample_minibatch takes contiguous blocks, whose
+                # uniformity argument requires random resident order (an
+                # input sorted by label, common from Spark groupBy,
+                # would otherwise feed near-single-class blocks all run).
+                if shuffle_round > 0 or (mini_batch is not None
+                                         and mini_batch > 0):
+                    shuffle_key, sub = jax.random.split(shuffle_key)
+                    with tele.span("train/shuffle"):
+                        source.batch = _shuffle_batch(source.batch, sub, mesh)
+                i, stop = 0, False
+                while i < iters and not stop:
+                    done, stop = loop.run(source, i, shuffle_round)
+                    i += done
                 if stop:
                     break
-            if stop:
-                break
         completed = True
     finally:
         # Cleanup must run on the failure paths too (GangFailure from
-        # check_gang, a raising metrics_hook): close the profiler
-        # trace and flush async checkpoint writes already in flight.
-        profiler.__exit__(None, None, None)
-        if _hl is not None:
-            # Drain the delayed-fetch tail so the published section
-            # (and any postmortem) reflects the final steps.
-            _hl.flush()
-        _finalize_checkpoint(ckpt, state, completed)
-
-    # lint-obs: ok (end-of-run gather after the loop drained)
-    params = jax.device_get(state.params)
-    model_state = jax.device_get(state.model_state)  # lint-obs: ok (end-of-run)
-    return TrainResult(params=params, model_state=model_state, metrics=metrics,
-                       spec=spec, summary=recorder.summary())
+        # check_gang, a raising metrics_hook): the profiler's trace is
+        # closed by now; flush the health ledger and the async
+        # checkpoint writes already in flight.
+        obs.close()
+        _finalize_checkpoint(ckpt, loop.state, completed)
+    return _result(spec, loop, obs)
 
 
 def train_distributed_multihost(
@@ -785,7 +800,7 @@ def train_distributed_multihost(
     # must never see the alias: its heads are an LM (targets are the
     # NEXT token — alias the raw matrix and it trains an identity
     # copier) or a classifier (needs real labels).
-    if local_y is None and dict(mesh.shape).get("pp", 1) > 1:
+    if local_y is None and dict(mesh.shape).get(AXIS_PP, 1) > 1:
         from sparktorch_tpu.models.transformer import CausalLM as _CLM
 
         probe = deserialize_model(torch_obj)
@@ -802,39 +817,26 @@ def train_distributed_multihost(
     local_w = np.ones((local_x.shape[0],), np.float32)
     per_host = int(counts.max())
     # The global batch must divide the mesh's batch shards.
-    n_shards = 1
-    for ax in BATCH_AXES:
-        n_shards *= mesh.shape[ax]
+    n_shards = _n_shards(mesh)
     shards_per_host = max(1, n_shards // n_proc)
     per_host = max(
         shards_per_host,
         -(-per_host // shards_per_host) * shards_per_host,
     )
-    from sparktorch_tpu.parallel.mesh import AXIS_PP as _PP
-
-    if dict(mesh.shape).get(_PP, 1) > 1:
+    if dict(mesh.shape).get(AXIS_PP, 1) > 1:
         # The pp route needs global rows divisible by dp * n_micro
         # (each dp shard splits into n_micro microbatches). Round
         # per_host up so per_host * n_proc satisfies that.
-        import math as _math
-
         dp_sz = mesh.shape[BATCH_AXES[0]]
         need = dp_sz * int(kwargs.get("n_micro", 4))
-        unit = need // _math.gcd(n_proc, need)
+        unit = need // math.gcd(n_proc, need)
         per_host = -(-per_host // unit) * unit
 
-    def pad_to(arr, n):
-        if arr.shape[0] == n:
-            return arr
-        widths = [(0, n - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
-        return np.pad(arr, widths)
-
     sharding = batch_sharding(mesh)
-    global_batch = DataBatch(
-        jax.make_array_from_process_local_data(sharding, pad_to(local_x, per_host)),
-        jax.make_array_from_process_local_data(sharding, pad_to(local_y, per_host)),
-        jax.make_array_from_process_local_data(sharding, pad_to(local_w, per_host)),
-    )
+    global_batch = DataBatch(*(
+        jax.make_array_from_process_local_data(sharding,
+                                               _pad_rows(a, per_host))
+        for a in (local_x, local_y, local_w)))
     return train_distributed(torch_obj, global_batch, mesh=mesh,
                              pre_sharded=True, **kwargs)
 
@@ -879,6 +881,8 @@ def train_distributed_streaming(
     """
     spec = deserialize_model(torch_obj)
     mesh = mesh or build_mesh()
+    tele = telemetry or get_telemetry()
+    obs = _RunObservers(tele, mesh, prefix="train_streaming")
 
     train_all, _ = _as_batch(data, labels, 0.0, seed)
     x = np.asarray(train_all.x, np.float32)
@@ -889,9 +893,7 @@ def train_distributed_streaming(
         spec.input_shape = tuple(x.shape[1:])
     chunk_rows = min(chunk_rows, n)
 
-    n_shards = 1
-    for ax in BATCH_AXES:
-        n_shards *= mesh.shape[ax]
+    n_shards = _n_shards(mesh)
     chunk_rows = -(-chunk_rows // n_shards) * n_shards  # pad up to shards
     if mini_batch is not None and mini_batch > 0:
         per_shard_rows = chunk_rows // n_shards
@@ -901,158 +903,64 @@ def train_distributed_streaming(
     steps = steps_per_chunk or default_steps
 
     tx = spec.make_optimizer()
-    rng = jax.random.key(seed)
-    sample_x = jnp.zeros((1,) + tuple(x.shape[1:]), jnp.float32)
-    # Compile-dominated (same attribution as the DP trainer's init).
-    with _goodput.span("compile", {"site": "train_init"}), mesh:
-        state = jax.jit(
-            lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx),
-            out_shardings=replicated(mesh),
-        )()
+    state = _init_state(
+        tele, spec, mesh, jax.random.key(seed),
+        jnp.zeros((1,) + tuple(x.shape[1:]), jnp.float32), tx)
+    ckpt, state = _open_checkpoint(checkpoint_dir, resume, state)
 
     module = spec.make_module()
     loss_fn = spec.loss_fn()
-    if steps > 1:
-        step_fn = make_train_epoch(module.apply, loss_fn, tx, mesh, steps,
-                                   mini_batch=mini_batch)
-    else:
-        step_fn = make_train_step(module.apply, loss_fn, tx, mesh,
-                                  mini_batch=mini_batch)
+    step_fn = _make_step(module, loss_fn, tx, mesh, steps, mini_batch)
+    _note_grad_allreduce(tele, state.params, mesh)
+    _note_model_gauges(tele, module)
 
     sharding = batch_sharding(mesh)
 
     def put_chunk(lo: int, order: np.ndarray) -> DataBatch:
         idx = order[lo : lo + chunk_rows]
-        cx, cy, cw = x[idx], y[idx], w[idx]
-        pad = chunk_rows - cx.shape[0]
-        if pad:
-            cx = np.concatenate([cx, np.zeros((pad, *cx.shape[1:]), cx.dtype)])
-            cy = np.concatenate([cy, np.zeros((pad, *cy.shape[1:]), cy.dtype)])
-            cw = np.concatenate([cw, np.zeros((pad,), cw.dtype)])
-        return DataBatch(
-            jax.device_put(cx, sharding),
-            jax.device_put(cy, sharding),
-            jax.device_put(cw, sharding),
-        )
+        return DataBatch(*(
+            jax.device_put(_pad_rows(a[idx], chunk_rows), sharding)
+            for a in (x, y, w)))
 
-    from sparktorch_tpu.utils.metrics import MetricsRecorder
-
-    ckpt, state = _open_checkpoint(checkpoint_dir, resume, state)
-    # lint-obs: ok (pre-loop scalar — nothing queued yet)
-    last_ckpt_step = int(jax.device_get(state.step)) if ckpt is not None else 0
-
-    tele = telemetry or get_telemetry()
-    _note_grad_allreduce(tele, state.params, mesh)
+    # Chunk boundaries are the save points.
+    loop = _ChunkLoop(tele, obs, module, state, step_fn, steps,
+                      span="train_streaming/chunk", metrics_hook=metrics_hook,
+                      ckpt=ckpt, checkpoint_every=checkpoint_every)
     log = get_logger("sparktorch_tpu.train")
-    # Stack sampler beside the ambient ledger (see train_distributed).
-    from sparktorch_tpu.obs import health as _health
-    from sparktorch_tpu.obs import profile as _profile
-
-    _profile.ensure(tele)
-    _hl = _health.ensure(tele, rank=jax.process_index())
-    if _hl is not None:
-        _hl.reset()
-        if _hl.leaf_keys is None:
-            _hl.leaf_keys = _health.health_leaf_keys(state.params)
-    recorder = MetricsRecorder(n_chips=mesh.size, telemetry=tele,
-                               prefix="train_streaming")
     # Fold the restored step into the shuffle seed: a resumed run must
     # draw FRESH permutations, not replay the epochs the interrupted
     # run already consumed.
-    shuffle_rng = np.random.default_rng(seed + 1 + last_ckpt_step)
-    it_counter = 0
+    shuffle_rng = np.random.default_rng(seed + 1 + loop.last_ckpt_step)
     completed = False
     try:
         for epoch in range(max(1, epochs)):
-            check_gang()
             order = shuffle_rng.permutation(n)
-            starts = list(range(0, n, chunk_rows))
-            # The epoch's first chunk has nothing to hide under: a
-            # pure data wait.
-            with _goodput.span("data_wait", {"site": "streaming_chunk"}):
-                resident = put_chunk(starts[0], order)
-            for ci, lo in enumerate(starts):
-                # Per-chunk liveness, matching train_distributed: a
-                # peer host dying mid-epoch must abort before the next
-                # compiled dispatch, not at the epoch boundary.
-                check_gang()
-                notify_gang_step(it_counter)
-                _act = _chaos.fire("data.batch",
-                                   worker=jax.process_index(),
-                                   step=it_counter)
-                if _act and _act.get("poison"):
-                    resident = _chaos.poison_batch(resident)
-                if _hl is not None:
-                    _hl.note_replay_anchor(state, resident)
-                # Straggler injection before the step span: a late
-                # fence arrival, visible to the skew referee.
-                _chaos.straggle(jax.process_index(), it_counter)
-                cache0 = (_goodput.jit_cache_size(step_fn)
-                          if _goodput.active() is not None else None)
-                with _goodput.step_span(step=it_counter) as _led, \
-                        tele.span("train_streaming/chunk"):
-                    state, metrics = step_fn(state, resident)
-                    # Enqueue the NEXT chunk's host->device copy while
-                    # the current chunk's (already dispatched) steps
-                    # compute. The placement is a nested data_wait
-                    # span: its seconds subtract from this chunk's
-                    # step attribution (one second, one bucket) —
-                    # though being deliberately overlapped under the
-                    # in-flight compute, it is usually small.
-                    if ci + 1 < len(starts):
-                        with _goodput.span("data_wait",
-                                           {"site": "streaming_chunk"}):
-                            resident = put_chunk(starts[ci + 1], order)
-                    losses = np.asarray(metrics.loss).reshape(-1)
-                    _led.count = len(losses)
-                    if cache0 is not None and (
-                            _goodput.jit_cache_size(step_fn)
-                            or cache0) > cache0:
-                        _led.rebucket("compile")
-                examples = np.asarray(metrics.examples).reshape(-1)
-                dt = _led.duration_s / len(losses)
-                if _hl is not None:
-                    _h = metrics.health
-                    _hl.note_step(
-                        count=len(losses),
-                        device=None if _h is None else {
-                            "finite": _h.finite,
-                            "update_ratio": _h.update_ratio,
-                            "leaf_norms": _h.leaf_norms,
-                        },
-                        host={"loss": losses,
-                              "grad_norm": np.asarray(
-                                  metrics.grad_norm).reshape(
-                                      losses.shape[0], -1)[:, 0]},
-                    )
-                for j in range(len(losses)):
-                    record = {
-                        "round": epoch, "iter": it_counter,
-                        "loss": float(losses[j]),
-                        "val_loss": None,
-                        "examples": float(examples[j]),
-                        "grad_norm": None,
-                        "step_time_s": dt,
-                    }
-                    recorder.record(record)
-                    if metrics_hook:
-                        metrics_hook(record)
-                    it_counter += 1
-                # Chunk boundaries are the save points.
-                last_ckpt_step = _save_if_due(
-                    ckpt, state, last_ckpt_step, checkpoint_every
-                )
+            starts = iter(range(0, n, chunk_rows))
+
+            def put_next():
+                lo = next(starts, None)
+                if lo is not None:
+                    with _goodput.span("data_wait",
+                                       {"site": "streaming_chunk"}):
+                        source.batch = put_chunk(lo, order)
+
+            # Double-buffered: the NEXT chunk's host->device copy is
+            # enqueued while the current chunk's (already dispatched)
+            # steps compute. The placement is a nested data_wait span:
+            # its seconds subtract from that chunk's step attribution
+            # (one second, one bucket), though being deliberately
+            # overlapped under the in-flight compute, it is usually
+            # small. The epoch's first chunk has nothing to hide under:
+            # a pure data wait.
+            source = _BatchSource(advance=put_next)
+            put_next()
+            for ci in range(-(-n // chunk_rows)):
+                loop.run(source, len(obs.recorder.records), epoch)
                 if verbose:
                     log.info(f"[sparktorch_tpu] epoch {epoch} chunk {ci} "
-                             f"loss {losses[-1]:.6f}")
+                             f"loss {obs.recorder.records[-1]['loss']:.6f}")
         completed = True
     finally:
-        if _hl is not None:
-            _hl.flush()
-        _finalize_checkpoint(ckpt, state, completed)
-    # lint-obs: ok (end-of-run gather after the loop drained)
-    params = jax.device_get(state.params)
-    model_state = jax.device_get(state.model_state)  # lint-obs: ok (end-of-run)
-    return TrainResult(params=params, model_state=model_state,
-                       metrics=recorder.records, spec=spec,
-                       summary=recorder.summary())
+        obs.close()
+        _finalize_checkpoint(ckpt, loop.state, completed)
+    return _result(spec, loop, obs)
