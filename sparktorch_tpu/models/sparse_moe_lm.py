@@ -44,7 +44,12 @@ so). The logits come out at a width the fused cross-entropy kernel
 tiles (18,992 does not): the columns past ``vocab_size`` are no
 parameters, they read -1e30 and so never enter a softmax, and the loss
 over the padded width is the loss over ``vocab_size``. Every layer is
-rematerialised in the backward pass, but for its selected sets.
+rematerialised in the backward pass, but for three arrays the forward
+pass keeps: its selected sets, and the attention kernel's output and
+row statistics (``ops.sparse_attention.SAVED_NAMES``: one activation of
+``[rows, T, heads, head_dim]`` in the compute dtype and 4 bytes a row a
+head, for each layer), so the selection and the attention's forward
+kernel run once a layer a step.
 
 Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
 (rows computed by each held expert), ``row_chunks`` (the chunks the
@@ -65,7 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from sparktorch_tpu.ops.sparse_attention import sparse_attention
+from sparktorch_tpu.ops.sparse_attention import SAVED_NAMES, sparse_attention
 
 _MASK_NAME = "sparse_attn_mask"
 # Queries a block of index scores. The source's q_chunk_size is 512;
@@ -593,7 +598,8 @@ class SparseMoELM(nn.Module):
                        (cfg.vocab_size, cfg.d_model))[ids]
         layer = nn.remat(
             DecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(_MASK_NAME))
+            policy=jax.checkpoint_policies.save_only_these_names(
+                _MASK_NAME, *SAVED_NAMES))
         for i in range(cfg.n_layers):
             x = layer(cfg, name=f"layer_{i}")(x, angles, temporal)
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,

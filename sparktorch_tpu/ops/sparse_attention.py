@@ -25,6 +25,18 @@ masked ones away: at 8,192 tokens and 2,048 selected keys that is about
 gathering the selected keys, is a later optimisation; the roofline
 share the benchmark reports counts selected pairs only and says so.
 
+The backward kernels need two arrays only the forward kernel can make:
+its output in its own layout (``[b, kv_heads, G, T, d]``, the inputs'
+dtype) and the row statistics (log-sum-exp, ``[b, kv_heads, G, T]``
+float32). The forward rule names both (``checkpoint_name``,
+:data:`SAVED_NAMES`), so a caller who rematerialises around the
+attention can list them in its policy
+(``save_only_these_names(*SAVED_NAMES)``) and the backward pass reads
+what the forward pass left, for one more activation of ``q``'s size a
+call, and does not launch ``sparse_attn_fwd`` a second time. Outside a
+``jax.checkpoint``, or under a policy that lists neither, the names are
+identities.
+
 ``pallas_call`` names: ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``,
 ``sparse_attn_bwd_dkv``. Off the TPU they run in interpret mode. A
 shape that does not tile is an error everywhere: there is no dense
@@ -36,11 +48,16 @@ from __future__ import annotations
 import functools
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _NEG = -1e30         # a masked score: finite, so NEG - NEG is 0, not NaN
+
+# what the forward rule names for a caller's remat policy: the kernel's
+# output in its own layout, and the row statistics
+SAVED_NAMES = ("sparse_attn_out", "sparse_attn_lse")
 
 
 def _last_k(qi, block_q: int, block_k: int):
@@ -256,7 +273,7 @@ def _fwd(q5, k4, v4, mask):
     )(q5, k4, v4, mask)
 
 
-def _bwd(q5, k4, v4, mask, o5, lse1, do5):
+def _bwd(q5, k4, v4, mask, o5, lse, do5):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     n_q, n_k = t // block_q, t // block_k
@@ -265,7 +282,8 @@ def _bwd(q5, k4, v4, mask, o5, lse1, do5):
     rows = (b, hkv, groups, t, _LANES)
     di = jnp.sum(o5.astype(jnp.float32) * do5.astype(jnp.float32), axis=-1,
                  keepdims=True)
-    di, lse = jnp.broadcast_to(di, rows), jnp.broadcast_to(lse1, rows)
+    di = jnp.broadcast_to(di, rows)
+    lse = jnp.broadcast_to(lse[..., None], rows)
 
     q_spec, kv_spec, mask_spec, row_spec = _specs(
         groups, d, block_q, block_k, q_major=True)
@@ -317,13 +335,16 @@ def _forward(q, k, v, mask):
     q5 = _heads_first(q, hkv)
     k4, v4 = (jnp.swapaxes(x, 1, 2) for x in (k, v))
     o5, lse = _fwd(q5, k4, v4, mask)
-    # one lane of the row statistics is kept for the backward pass
-    return _heads_last(o5), (q5, k4, v4, mask, o5, lse[..., :1])
+    o5 = checkpoint_name(o5, SAVED_NAMES[0])
+    # One lane of the row statistics is kept for the backward pass, with
+    # no trailing axis of one: the TPU would pad that axis to 128 lanes.
+    lse = checkpoint_name(lse[..., 0], SAVED_NAMES[1])
+    return _heads_last(o5), (q5, k4, v4, mask, o5, lse)
 
 
 def _bwd_rule(res, g):
-    q5, k4, v4, mask, o5, lse1 = res
-    dq5, dk4, dv4 = _bwd(q5, k4, v4, mask, o5, lse1,
+    q5, k4, v4, mask, o5, lse = res
+    dq5, dk4, dv4 = _bwd(q5, k4, v4, mask, o5, lse,
                          _heads_first(g.astype(q5.dtype), k4.shape[1]))
     return (_heads_last(dq5), jnp.swapaxes(dk4, 1, 2),
             jnp.swapaxes(dv4, 1, 2), None)

@@ -130,12 +130,17 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     2,048, so the selection is in the program; 16 of 128 experts held;
     the padded head into the fused cross entropy): the whole gradient
     through the TPU compiler, which has refused scatters in it that the
-    CPU's took. The grouped products of the expert layer sit in the
-    bodies of two loops over chunks of rows (forward and backward: the
-    remat's second forward needs no result of the loop and is gone), and
-    the gradient's temporaries are under half of what they were with all
-    131,072 chosen pairs' rows held at once (6,009,584,128 bytes at this
-    shape, compiled so on PR 27's tree; 2,555,206,144 with the loop)."""
+    CPU's took. The layer is rematerialised under the model's own
+    policy, which keeps the attention kernel's output and row
+    statistics: the compiled program holds one ``sparse_attn_fwd`` a
+    layer and none for the remat's second forward pass, so a compiler
+    that put the call back would show here. The grouped products of the
+    expert layer sit in the bodies of two loops over chunks of rows
+    (forward and backward: the remat's second forward needs no result of
+    the loop either and is gone), and the gradient's temporaries are
+    under half of what they were with all 131,072 chosen pairs' rows
+    held at once (6,009,584,128 bytes at this shape, compiled so on PR
+    27's tree; 2,555,206,144 with the loop)."""
     from sparktorch_tpu.models.sparse_moe_lm import keye_vl2_lm
     from sparktorch_tpu.utils.losses import resolve_loss
 
@@ -150,8 +155,9 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
         module.apply({"params": p}, x), y).sum())).lower(
             jax.tree.map(S, shapes), S(ids), S(ids)).compile()
     text = compiled.as_text()
-    assert _pallas_calls(text, "sparse_attn_fwd") == 2  # and recomputed
+    assert _pallas_calls(text, "sparse_attn_fwd") == 1  # kept, not recomputed
     assert _pallas_calls(text, "sparse_attn_bwd_dq") == 1
+    assert _pallas_calls(text, "sparse_attn_bwd_dkv") == 1
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # 4,000 padded to 4,096
 
     grouped = re.compile(r"%ragged-dot-none[.\d]* = ")

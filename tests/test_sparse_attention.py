@@ -1,6 +1,7 @@
 """``ops/sparse_attention.py`` in interpret mode against dense masked
 attention: forward and both gradients, grouped-query heads, selected
-sets that leave whole tiles empty."""
+sets that leave whole tiles empty; and what a caller's remat keeps of
+the forward pass."""
 
 import numpy as np
 import pytest
@@ -8,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from sparktorch_tpu.ops.sparse_attention import sparse_attention
+from sparktorch_tpu.ops.sparse_attention import SAVED_NAMES, sparse_attention
 
 B, HQ, HKV, D = 2, 4, 2, 128
 # sequence lengths and the tiles the kernels cut them into
@@ -107,3 +108,61 @@ def test_a_shape_that_cannot_be_tiled_is_an_error(qkv, bad):
         q, k, v, mask = (x[:, :200] for x in (q, k, v, mask[:, :, :200]))
     with pytest.raises(ValueError, match="sparse_attention"):
         sparse_attention(q, k, v, mask)
+
+
+def pallas_calls(jaxpr, name: str) -> int:
+    """``pallas_call``s of that name in the jaxpr and in every jaxpr
+    inside it."""
+    return sum(
+        (eqn.primitive.name == "pallas_call"
+         and eqn.params["name"] == name)
+        + sum(pallas_calls(sub, name)
+              for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+POLICIES = {
+    # policy of the caller's remat, forward kernels in the gradient
+    "nothing_saveable": (jax.checkpoint_policies.nothing_saveable, 2),
+    "exported_names": (
+        jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES), 1),
+    "everything_saveable": (jax.checkpoint_policies.everything_saveable, 1),
+}
+
+
+@pytest.mark.parametrize("policy", list(POLICIES))
+def test_under_a_remat_the_forward_kernel_runs_again_only_if_nothing_is_kept(
+        qkv, policy):
+    """A caller whose policy lists the module's names (or keeps
+    everything) gets the backward pass on the arrays the forward pass
+    left; one that keeps nothing pays a second forward kernel. The
+    numbers are the bare call's under every policy."""
+    mask = make_mask("empty_tiles", 384)
+    bare = lambda q, k, v: sparse_attention(q, k, v, mask)
+    keep, n_fwd = POLICIES[policy]
+    remat = jax.checkpoint(bare, policy=keep)
+    np.testing.assert_array_equal(remat(*qkv), bare(*qkv))
+    for got, want, name in zip(_grads(remat, qkv), _grads(bare, qkv), "qkv"):
+        np.testing.assert_array_equal(got, want, err_msg=f"d{name}")
+    jaxpr = jax.make_jaxpr(lambda *a: _grads(remat, a))(*qkv).jaxpr
+    assert pallas_calls(jaxpr, "sparse_attn_fwd") == n_fwd
+    assert pallas_calls(jaxpr, "sparse_attn_bwd_dq") == 1
+    assert pallas_calls(jaxpr, "sparse_attn_bwd_dkv") == 1
+    # outside a remat the names keep nothing and cost nothing
+    bare_jaxpr = jax.make_jaxpr(lambda *a: _grads(bare, a))(*qkv).jaxpr
+    assert pallas_calls(bare_jaxpr, "sparse_attn_fwd") == 1
+
+
+def test_the_row_statistics_are_kept_without_an_axis_of_one():
+    """``[b, kv_heads, G, T]``: a trailing axis of one would be padded
+    to 128 lanes on the TPU, 128 times the array."""
+    qkv, mask = make_qkv(384, jnp.bfloat16), make_mask("causal", 384)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda *a: sparse_attention(*a, mask), q, k, v)[0])(*qkv).jaxpr
+    named = {eqn.params["name"]: eqn.outvars[0].aval for eqn in jaxpr.eqns
+             if eqn.primitive.name == "name"}
+    assert set(named) == set(SAVED_NAMES)
+    out, lse = (named[n] for n in SAVED_NAMES)
+    assert (out.shape, out.dtype) == ((B, HKV, HQ // HKV, 384, D),
+                                      jnp.bfloat16)
+    assert (lse.shape, lse.dtype) == ((B, HKV, HQ // HKV, 384), jnp.float32)

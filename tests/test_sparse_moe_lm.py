@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import harness
+from test_sparse_attention import pallas_calls
 from sparktorch_tpu.models import sparse_moe_lm as M
 from sparktorch_tpu.utils.losses import resolve_loss
 
@@ -323,6 +324,67 @@ def test_the_chunked_layer_is_the_reference_at_every_load(case, monkeypatch):
     assert max(jax.tree.leaves(errs)) < 1e-5, errs
     for leaf in jax.tree.leaves(want_grads):
         assert float(jnp.linalg.norm(leaf)) > 0  # a comparison of something
+
+
+ATTN_KERNELS = ("sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv")
+
+
+@pytest.fixture(scope="module")
+def gradient_by_policy():
+    """``{policy: ({kernel: calls in the gradient's jaxpr}, the
+    gradient)}`` of the tiny two-layer model under the policy its layers
+    are rematerialised with, and with ``nothing_saveable`` in its place;
+    and under ``"names"`` the names the model's policy lists."""
+    cfg, module = sizes()
+    params = REF.init(jax.random.key(0), cfg)["params"]
+    ids, labels = rows()
+    loss_fn = resolve_loss("cross_entropy")
+    policies, listed = jax.checkpoint_policies, set()
+    by_names = policies.save_only_these_names
+
+    def the_models_own(*names):
+        listed.update(names)
+        return by_names(*names)
+
+    stand_ins = {"model": the_models_own,
+                 "nothing_saveable": lambda *names: policies.nothing_saveable}
+    patch, out = pytest.MonkeyPatch(), {"names": listed}
+    for policy, stand_in in stand_ins.items():
+        patch.setattr(policies, "save_only_these_names", stand_in)
+        # a function of its own each time: a trace is cached by function
+        grad = jax.grad(lambda p: jnp.sum(loss_fn(
+            module.apply({"params": p}, ids), labels)))
+        jaxpr = jax.make_jaxpr(grad)(params).jaxpr
+        out[policy] = ({k: pallas_calls(jaxpr, k) for k in ATTN_KERNELS},
+                       grad(params))
+    patch.undo()
+    return out
+
+
+def test_each_attention_kernel_runs_once_a_layer_in_the_gradient(
+        gradient_by_policy):
+    """The remat's second forward pass launches no ``sparse_attn_fwd``:
+    the backward kernels read the output and row statistics the first
+    one left."""
+    calls, grads = gradient_by_policy["model"]
+    n = sum(name.startswith("layer_") for name in grads)
+    assert n == 2 and calls == dict.fromkeys(ATTN_KERNELS, n)
+    # the count sees a second forward where a policy keeps neither array
+    assert gradient_by_policy["nothing_saveable"][0] == {
+        **calls, "sparse_attn_fwd": 2 * n}
+
+
+def test_keeping_the_attentions_results_changes_no_gradient_leaf(
+        gradient_by_policy):
+    assert gradient_by_policy["names"] == {M._MASK_NAME, *M.SAVED_NAMES}
+    grads, recomputed = (gradient_by_policy[k][1]
+                         for k in ("model", "nothing_saveable"))
+    same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)),
+                        grads, recomputed)
+    assert all(jax.tree.leaves(same)), same
+    # what the attention's backward kernels hand on is something
+    assert all(float(jnp.linalg.norm(grads["layer_0"]["attn"][w])) > 0
+               for w in ("wq", "wk", "wv"))
 
 
 def _float_arrays_by_pair(jaxpr, n_pairs):
