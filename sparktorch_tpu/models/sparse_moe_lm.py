@@ -1,7 +1,22 @@
-"""Decoder LM with learned sparse attention over grouped-query heads
-and a dropless mixture of experts that is told which experts it holds
-(the language model of Keye-VL-2.0-30B-A3B: Qwen3-MoE's block with
-DeepSeek-V3.2's lightning indexer in front of the attention).
+"""Decoder LM over grouped-query heads with a dropless mixture of
+experts that is told which experts it holds (Qwen3-MoE's block), under
+one of two attentions, by configuration:
+
+- ``attention="learned_sparse"``: DeepSeek-V3.2's lightning indexer in
+  front of a causal attention over the keys it selects (the language
+  model of Keye-VL-2.0-30B-A3B, :func:`keye_vl2_lm`);
+- ``attention="block_diffusion"``: every key a static block mask allows,
+  for a model trained by masked block diffusion (SDAR-30B-A3B-Chat,
+  :func:`sdar_moe_lm`; "Masked block diffusion" below).
+
+Shared by both, written once: the projections, q/k norm and rotary step
+around the attention kernel (``_GroupedQueryProjections``), the expert
+layer (``HeldExperts``, ``held_experts_sum``), ``rms_norm``, the layers'
+remat with the attention kernel's output and row statistics kept, the
+head's padding to the fused cross entropy's tile, the counters and
+gauges. What differs is the attention module (``_ATTENTION``) and, under
+block diffusion, what the model does before its first layer and hands
+back after its last.
 
 One layer, for the tokens ``x`` of a row, in the published order:
 
@@ -31,12 +46,12 @@ One layer, for the tokens ``x`` of a row, in the published order:
   chosen (token, held expert) pair is computed: pairs are sorted by
   expert (those of experts held elsewhere last), their tokens' rows
   gathered, multiplied (``jax.lax.ragged_dot``), gated and summed back
-  a chunk of ``_ROW_CHUNK`` sorted pairs at a time, by a loop that runs
-  as many chunks as hold a pair of a held expert
-  (:func:`held_experts_sum`, with a backward pass of its own: the same
-  loop again). So the rows moved are the rows held, rounded up to a
-  chunk, at any load: a holder of every expert runs every chunk. There
-  is no capacity and nothing is dropped.
+  a chunk of the sorted pairs at a time (:func:`_row_chunks`: twice the
+  pairs the layer expects to hold), by a loop that runs as many chunks
+  as hold a pair of a held expert (:func:`held_experts_sum`, with a
+  backward pass of its own: the same loop again). So the rows moved are
+  the rows held, rounded up to a chunk, at any load: a holder of every
+  expert runs every chunk. There is no capacity and nothing is dropped.
 
 After the last layer RMSNorm and an untied head over ``vocab_size``
 rows (a slice of the published vocabulary, when the configuration says
@@ -51,17 +66,44 @@ row statistics (``ops.sparse_attention.SAVED_NAMES``: one activation of
 head, for each layer), so the selection and the attention's forward
 kernel run once a layer a step.
 
+Masked block diffusion. The forward pass of the second model is its
+TRAINING forward: a row ``x_0`` of ``L`` ids is noised (one level a row,
+``t = noise_eps + (1 - noise_eps) u``, ``u ~ U[0, 1)``; each token
+masked independently with probability ``t``: ``x_t[i] = mask_token_id if
+m_i else x_0[i]``; :func:`diffusion_noise`), and the layers run on ``[x_0
+; x_t]``, ``2L`` tokens whose halves share position ids, under
+``ops/block_diffusion_attention.py``'s mask in blocks of
+``block_length``. The head runs on the noised half only (the clean
+half's last output enters nothing), the logit at noised position ``L +
+i`` predicts ``x_0[i]`` (no shift), and the model returns
+``utils.losses.TokenWeighted(logits, m / t)``: the loss
+``cross_entropy_weighted`` with the row as labels is then ``(1 / L)
+sum_{m_i} (1 / t) CE_i``. The noise comes from the flax stream
+:data:`NOISE_STREAM`, which the sync DP step hands a module that
+declares it (``train_rngs``; ``train/step.py`` ``_forward_rngs``); with
+no stream, ``init`` and a forward that collects no counters (a
+validation forward) draw from a fixed key, the same at every call, and a
+forward that collects the step's counters raises. Assumed, as the benchmark's configuration file
+lists with reasons: the block length 4, the schedule and ``noise_eps``
+1e-3 (the LLaDA / SDAR fine-tune convention), q/k norm (Qwen3-MoE's
+convention), no shift, the loss normalised by the row's length. All
+``[MASK]`` tokens share one embedding row, so a layer's router sends
+them alike. Generation by denoising is not here.
+
 Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
 (rows computed by each held expert), ``row_chunks`` (the chunks the
 loop ran, and the chunks that all chosen pairs would take), ``routed``
 (chosen pairs whose expert is held) and ``dropped`` (those of them that
 the grouped products, chunk by chunk, did not multiply by their own
-expert's weights: 0).
+expert's weights: 0); under block diffusion also ``masked_tokens`` and
+``tokens`` of the step and, by layer, ``attn_tiles`` (the tiles the
+attention's forward kernel visits, of the whole square's).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import flax.linen as nn
@@ -70,18 +112,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from sparktorch_tpu.ops.block_diffusion_attention import (
+    SAVED_NAMES as BLOCKDIFF_SAVED_NAMES, BlockDiffusionMask,
+    block_diffusion_attention, tiles_visited)
 from sparktorch_tpu.ops.sparse_attention import SAVED_NAMES, sparse_attention
+from sparktorch_tpu.utils.losses import TokenWeighted
 
 _MASK_NAME = "sparse_attn_mask"
 # Queries a block of index scores. The source's q_chunk_size is 512;
 # the selected sets do not depend on the block (tests shrink it).
 _IDX_Q_CHUNK = 1_024
-# Sorted pairs a trip of the expert layer's loop (fewer where a layer
-# sees fewer pairs; tests shrink it). A trip costs what 7,000 rows cost
-# (its scatter-adds pass over all tokens' sums, its weight gradients
-# are added to all experts'), so the chunk is near the rows a layer
-# holds: PERF.md section 6, PR 28.
-_ROW_CHUNK = 16_384
+# A trip of the expert layer's loop takes the sorted pairs this many
+# times the layer's expected share of them (:func:`_row_chunks`). A trip
+# costs its chunk's rows whatever rows are live, and 4 ms before its
+# first row (its scatter-adds pass over all tokens' sums, its weight
+# gradients are added to all experts'), so a layer's usual load should
+# take ONE trip: a chunk of exactly the share ran one trip or two by the
+# step's rows, 13 ms apart (PERF.md section 6, PR 28 and PR 32).
+_CHUNK_OVER_SHARE = 2
 # The vocabulary tile of ``ops/fused_ce.py``, which the head pads to.
 _CE_BLOCK_V = 512
 
@@ -106,8 +154,21 @@ class SparseMoEConfig:
     experts_per_token: int = 8
     expert_width: int = 768
     compute_dtype: jnp.dtype = jnp.bfloat16
+    # "learned_sparse" (the indexer's selected keys, causal) or
+    # "block_diffusion" (the module docstring's second model)
+    attention: str = "learned_sparse"
+    block_length: int = 4
+    mask_token_id: int = 151_669
+    noise_eps: float = 1e-3
 
     def __post_init__(self):
+        if self.attention not in _ATTENTION:
+            raise ValueError(f"attention {self.attention!r} is none of "
+                             f"{sorted(_ATTENTION)}")
+        if (self.attention == "block_diffusion"
+                and not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(f"mask_token_id {self.mask_token_id} is no row "
+                             f"of a vocabulary of {self.vocab_size}")
         if sum(self.mrope_section) * 2 != self.head_dim:
             raise ValueError(
                 f"mrope_section {self.mrope_section} does not cut the "
@@ -253,27 +314,48 @@ def selected_keys(q_idx, k_idx, w, topk: int, chunk: int):
 # -- modules -----------------------------------------------------------------
 
 
-class SparseAttention(nn.Module):
+class _GroupedQueryProjections(nn.Module):
+    """What both attentions do around their kernels: ``q``, ``k``, ``v``
+    without biases, RMSNorm over each head of ``q`` and ``k``, the rotary
+    step; and the output projection."""
+
     config: SparseMoEConfig
 
-    @nn.compact
-    def __call__(self, h, angles, temporal):
-        cfg, dt = self.config, self.config.compute_dtype
-        b, t, d = h.shape
-        hd = cfg.head_dim
-        dense = lambda name, shape: self.param(name, _normal(), shape)
-        proj = lambda x, w: jnp.einsum(
-            "btd,d...->bt...", x.astype(dt), w.astype(dt),
-            preferred_element_type=jnp.float32)
+    def _dense(self, name, shape):
+        return self.param(name, _normal(), shape)
 
-        q = proj(h, dense("wq", (d, cfg.n_heads, hd)))
-        k = proj(h, dense("wk", (d, cfg.n_kv_heads, hd)))
-        v = proj(h, dense("wv", (d, cfg.n_kv_heads, hd)))
+    def _proj(self, x, w):
+        dt = self.config.compute_dtype
+        return jnp.einsum("btd,d...->bt...", x.astype(dt), w.astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    def _qkv(self, h, angles):
+        cfg, d, hd = self.config, h.shape[-1], self.config.head_dim
+        q = self._proj(h, self._dense("wq", (d, cfg.n_heads, hd)))
+        k = self._proj(h, self._dense("wk", (d, cfg.n_kv_heads, hd)))
+        v = self._proj(h, self._dense("wv", (d, cfg.n_kv_heads, hd)))
         ones = nn.initializers.ones
         q = rms_norm(q, self.param("q_norm", ones, (hd,)), cfg.rms_eps)
         k = rms_norm(k, self.param("k_norm", ones, (hd,)), cfg.rms_eps)
         cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
-        q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+        return _rotate(q, cos, sin), _rotate(k, cos, sin), v
+
+    def _out(self, o, d):
+        cfg, dt = self.config, self.config.compute_dtype
+        return jnp.einsum(
+            "bthk,hkd->btd", o,
+            self._dense("wo", (cfg.n_heads, cfg.head_dim, d)).astype(dt),
+            preferred_element_type=jnp.float32)
+
+
+class SparseAttention(_GroupedQueryProjections):
+
+    @nn.compact
+    def __call__(self, h, angles, temporal):
+        cfg, dt = self.config, self.config.compute_dtype
+        d = h.shape[-1]
+        dense, proj = self._dense, self._proj
+        q, k, v = self._qkv(h, angles)
 
         with jax.named_scope("indexer"):
             hi = jax.lax.stop_gradient(h)
@@ -299,9 +381,35 @@ class SparseAttention(nn.Module):
         with jax.named_scope("sparse_attention"):
             o = sparse_attention(q.astype(dt), k.astype(dt), v.astype(dt),
                                  mask)
-        return jnp.einsum("bthk,hkd->btd", o,
-                          dense("wo", (cfg.n_heads, hd, d)).astype(dt),
-                          preferred_element_type=jnp.float32)
+        return self._out(o, d)
+
+
+class BlockDiffusionAttention(_GroupedQueryProjections):
+    """Every key the block-diffusion mask allows, for the ``2L`` tokens
+    of a clean row and its noised copy: no indexer, nothing selected."""
+
+    @nn.compact
+    def __call__(self, h, angles, temporal):
+        cfg, dt = self.config, self.config.compute_dtype
+        b, t, d = h.shape
+        del temporal
+        q, k, v = self._qkv(h, angles)
+        rule = BlockDiffusionMask(t // 2, cfg.block_length)
+        visited, total = tiles_visited(rule, t)
+        self.sow("moe_metrics", "attn_tiles", b * cfg.n_kv_heads
+                 * jnp.asarray([visited, total], jnp.float32))
+        with jax.named_scope("block_diffusion_attention"):
+            o = block_diffusion_attention(q.astype(dt), k.astype(dt),
+                                          v.astype(dt), rule)
+        return self._out(o, d)
+
+
+# attention by configuration: the module, and what its forward pass
+# names for the layers' remat policy to keep
+_ATTENTION = {
+    "learned_sparse": (SparseAttention, (_MASK_NAME, *SAVED_NAMES)),
+    "block_diffusion": (BlockDiffusionAttention, BLOCKDIFF_SAVED_NAMES),
+}
 
 
 class HeldExperts(nn.Module):
@@ -345,11 +453,11 @@ class HeldExperts(nn.Module):
             token = order // k
             gate = gates.reshape(n * k)[order]
 
+        chunk, n_chunks = _row_chunks(n * k, n_held, cfg.n_routed_experts)
         w = lambda name, shape: self.param(name, _normal(), (n_held, *shape))
         out = held_experts_sum(x, token, gate, rows, w("w_gate", (d, f)),
-                               w("w_up", (d, f)), w("w_down", (f, d)))
+                               w("w_up", (d, f)), w("w_down", (f, d)), chunk)
 
-        chunk, n_chunks = _row_chunks(n * k)
         n_pairs = jnp.sum(pair_local < n_held).astype(jnp.float32)
         # counted chunk by chunk against the group sizes the loop gives
         # its products (chunks it does not run hold no held pair)
@@ -375,9 +483,15 @@ _BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _row_chunks(n_pairs: int):
-    """``(pairs a chunk, chunks that n_pairs take)``."""
-    chunk = min(_ROW_CHUNK, n_pairs)
+def _row_chunks(n_pairs: int, n_held: int, n_routed: int):
+    """``(pairs a chunk, chunks that n_pairs take)`` for a layer that
+    holds ``n_held`` of ``n_routed`` experts: a chunk is
+    ``_CHUNK_OVER_SHARE`` times the pairs it expects to hold, its share
+    of the ``n_pairs`` chosen (16,384 of 131,072 at an eighth: 32,768),
+    so the load takes one trip until it doubles, whatever the step's
+    rows; the whole of ``n_pairs`` where that is less."""
+    share = -(-n_pairs * n_held // n_routed)
+    chunk = min(_CHUNK_OVER_SHARE * share, n_pairs)
     return chunk, -(-n_pairs // chunk)
 
 
@@ -428,24 +542,24 @@ class _Chunk:
         return jnp.where((self.sizes > 0)[:, None, None], prod, 0.0)
 
 
-def _padded_pairs(token, gate):
-    """The sorted pairs padded to whole chunks, and the chunk."""
-    chunk, n_chunks = _row_chunks(token.size)
-    pad = (0, n_chunks * chunk - token.size)
-    return jnp.pad(token, pad), jnp.pad(gate, pad), chunk
+def _padded_pairs(token, gate, chunk: int):
+    """The sorted pairs padded to whole chunks."""
+    pad = (0, -token.size % chunk)
+    return jnp.pad(token, pad), jnp.pad(gate, pad)
 
 
-@jax.custom_vjp
-def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down, chunk):
     """``out[t] = sum over the pairs p of token t on held experts of
     gate[p] * w_down_e (silu(w_gate_e x[t]) * w_up_e x[t])``, float32
     ``[tokens, d]``. ``token`` and ``gate`` are the chosen pairs' in
     sorted order (held pairs first, by expert), ``rows`` the pairs of
-    each held expert. A loop over chunks of the sorted pairs, as many
-    as hold a held pair: products in ``x``'s dtype, sums in float32."""
+    each held expert. A loop over chunks of ``chunk`` sorted pairs (a
+    Python int: :func:`_row_chunks`), as many as hold a held pair:
+    products in ``x``'s dtype, sums in float32."""
     dt = x.dtype
     with jax.named_scope("moe_experts"):
-        token, gate, chunk = _padded_pairs(token, gate)
+        token, gate = _padded_pairs(token, gate, chunk)
         w_in = jnp.concatenate([w_gate, w_up], -1).astype(dt)
         w_out = w_down.astype(dt)
 
@@ -460,10 +574,10 @@ def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down):
 
 
 def _held_experts_fwd(*args):
-    return held_experts_sum(*args), args
+    return held_experts_sum(*args), args[:7]
 
 
-def _held_experts_bwd(args, d_out):
+def _held_experts_bwd(chunk, args, d_out):
     """The same loop again, a chunk's hidden rows recomputed: what is
     kept between the passes is the function's arguments, and what the
     loop carries is the gradients' sums in float32."""
@@ -471,7 +585,7 @@ def _held_experts_bwd(args, d_out):
     dt, n_pairs = x.dtype, token.size
     # a custom_vjp's backward rule carries no scope of the forward's
     with jax.named_scope("moe_experts"):
-        token, gate, chunk = _padded_pairs(token, gate)
+        token, gate = _padded_pairs(token, gate, chunk)
         w_in = jnp.concatenate([w_gate, w_up], -1).astype(dt)
         w_in_t, w_out_t = (jnp.swapaxes(w_in, 1, 2),
                            jnp.swapaxes(w_down.astype(dt), 1, 2))
@@ -515,10 +629,14 @@ def pairs_covered(sorted_expert, group_sizes):
     in the group ``g`` with ``sum(group_sizes[:g]) <= i <
     sum(group_sizes[:g + 1])`` (past the last group in none), and is
     covered when ``g`` is the local id of its expert. Counted from the
-    sorted ids against the group sizes, which the layer derives apart."""
+    sorted ids against the group sizes, which the layer derives apart.
+    ``g`` is the number of groups that end at or before ``i``, counted
+    by comparison: ``jnp.searchsorted`` found the same ``g`` in five
+    passes of scalar gathers over all chosen pairs, 15 to 28 ms a step
+    of the 8k cells on the chip (PERF.md section 6, PR 32)."""
     ends = jnp.cumsum(group_sizes)
-    group = jnp.searchsorted(ends, jnp.arange(sorted_expert.size),
-                             side="right")
+    group = jnp.sum(jnp.arange(sorted_expert.size)[:, None] >= ends[None, :],
+                    -1, dtype=jnp.int32)
     return jnp.sum((group == sorted_expert) & (group < group_sizes.size),
                    dtype=jnp.float32)
 
@@ -532,7 +650,8 @@ class DecoderLayer(nn.Module):
         ones = nn.initializers.ones
         d = x.shape[-1]
         h = rms_norm(x, self.param("attn_norm", ones, (d,)), cfg.rms_eps)
-        x = x + SparseAttention(cfg, name="attn")(h, angles, temporal)
+        attention, _ = _ATTENTION[cfg.attention]
+        x = x + attention(cfg, name="attn")(h, angles, temporal)
         g = rms_norm(x, self.param("moe_norm", ones, (d,)), cfg.rms_eps)
         return x + HeldExperts(cfg, name="moe")(g)
 
@@ -550,14 +669,27 @@ class SparseMoELM(nn.Module):
     sync_dp_only = ("its attention is a Pallas kernel that GSPMD cannot "
                     "partition, and its expert layer computes one chip's "
                     "share of the experts with no exchange, so no mesh axis "
-                    "may divide the model")
+                    "may divide the model; and where its training forward "
+                    "draws (block diffusion's noise) only the sync DP step "
+                    "hands a forward pass a random stream")
+
+    @property
+    def train_rngs(self) -> tuple:
+        """The flax streams the training forward draws from, for the
+        step that hands them over (``train/step.py``): block diffusion's
+        noise, and nothing for the deterministic model."""
+        return ((NOISE_STREAM,) if self.config.attention == "block_diffusion"
+                else ())
 
     def train_gauges(self) -> dict:
         """What the trainers put on the bus when they build a step."""
         cfg = self.config
-        return {"train.sparse_attn.topk": cfg.topk,
-                "train.moe.experts_held": len(cfg.experts_held),
-                "train.moe.experts_routed": cfg.n_routed_experts}
+        gauges = {"train.moe.experts_held": len(cfg.experts_held),
+                  "train.moe.experts_routed": cfg.n_routed_experts}
+        if cfg.attention == "block_diffusion":
+            return {"train.diffusion.block_length": cfg.block_length,
+                    **gauges}
+        return {"train.sparse_attn.topk": cfg.topk, **gauges}
 
     def train_counters(self, sown: dict, drop_fraction) -> tuple:
         """What the counters sown in one step mean, to the trainer that
@@ -569,7 +701,11 @@ class SparseMoELM(nn.Module):
         pairs that were not computed (routed is computed plus dropped).
         From ``row_chunks``, ``[layers, 2]``: the chunks the layers'
         loops ran, whose ratio to the chunks that all chosen pairs would
-        take is the share of them moved."""
+        take is the share of them moved. Under block diffusion also the
+        step's ``masked_tokens`` of its ``tokens`` (the rows' own, not
+        the doubled sequence's), and from ``attn_tiles``, ``[layers,
+        2]``: the tiles the attention's forward kernel visited of the
+        tiles of the whole square, over rows and key/value heads."""
         by_expert, chunks = sown["expert_rows"], sown["row_chunks"]
         rows, f = float(by_expert.sum()), float(drop_fraction or 0.0)
         fields = dict(
@@ -577,12 +713,42 @@ class SparseMoELM(nn.Module):
             moe_rows_mean=rows / by_expert.size,
             moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows,
             moe_row_chunks=float(chunks[:, 0].sum()))
-        return (fields,
-                {"train.moe.rows": rows,
-                 "train.moe.pairs_dropped": fields["moe_pairs_dropped"],
-                 "train.moe.row_chunks": fields["moe_row_chunks"]},
-                {"train.moe.rows_max": fields["moe_rows_max"],
-                 "train.moe.row_chunks_possible": float(chunks[:, 1].sum())})
+        counters = {"train.moe.rows": rows,
+                    "train.moe.pairs_dropped": fields["moe_pairs_dropped"],
+                    "train.moe.row_chunks": fields["moe_row_chunks"]}
+        gauges = {"train.moe.rows_max": fields["moe_rows_max"],
+                  "train.moe.row_chunks_possible": float(chunks[:, 1].sum())}
+        if "masked_tokens" in sown:
+            fields.update(
+                diffusion_masked_tokens=float(sown["masked_tokens"].sum()),
+                diffusion_tokens=float(sown["tokens"].sum()))
+            counters.update({
+                "train.diffusion.masked_tokens":
+                    fields["diffusion_masked_tokens"],
+                "train.diffusion.tokens": fields["diffusion_tokens"]})
+            tiles = sown["attn_tiles"].sum(0)
+            gauges.update({
+                "train.diffusion.attn_tiles_visited": float(tiles[0]),
+                "train.diffusion.attn_tiles_total": float(tiles[1])})
+        return fields, counters, gauges
+
+    def _noise_key(self):
+        """The step's key from :data:`NOISE_STREAM`. Without the stream,
+        a fixed key for ``init`` and for a forward that collects no
+        counters (a validation loss is then comparable from call to
+        call); a forward that collects the step's counters is a
+        training forward, and training on one fixed mask is an error."""
+        if self.has_rng(NOISE_STREAM):
+            return self.make_rng(NOISE_STREAM)
+        if (self.is_mutable_collection("moe_metrics")
+                and not self.is_initializing()):
+            raise ValueError(
+                f"a training forward under block diffusion draws its noise "
+                f"from the stream {NOISE_STREAM!r} and got none: the sync DP "
+                f"step finds the stream's name in the bound module's "
+                f"`train_rngs` (train/step.py _forward_rngs), which a "
+                f"wrapped `apply` hides")
+        return jax.random.key(0)
 
     @nn.compact
     def __call__(self, ids, example_w=None, position_ids=None):
@@ -593,15 +759,28 @@ class SparseMoELM(nn.Module):
         b, t = ids.shape
         if position_ids is None:
             position_ids = jnp.broadcast_to(jnp.arange(t), (3, b, t))
+        diffusion = cfg.attention == "block_diffusion"
+        if diffusion:
+            with jax.named_scope("diffusion_noise"):
+                level, masked = diffusion_noise(
+                    self._noise_key(), b, t, cfg.noise_eps)
+                ids = jnp.concatenate(
+                    [ids, jnp.where(masked, cfg.mask_token_id, ids)], 1)
+                position_ids = jnp.concatenate([position_ids] * 2, -1)
+            self.sow("moe_metrics", "masked_tokens",
+                     jnp.sum(masked, dtype=jnp.float32))
+            self.sow("moe_metrics", "tokens", jnp.float32(b * t))
         angles, temporal = mrope_angles(position_ids, cfg), position_ids[0]
         x = self.param("embed", _normal(),
                        (cfg.vocab_size, cfg.d_model))[ids]
         layer = nn.remat(
             DecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(
-                _MASK_NAME, *SAVED_NAMES))
+                *_ATTENTION[cfg.attention][1]))
         for i in range(cfg.n_layers):
             x = layer(cfg, name=f"layer_{i}")(x, angles, temporal)
+        if diffusion:
+            x = x[:, t:]  # the clean half's last output enters nothing
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
                                    (cfg.d_model,)), cfg.rms_eps)
         with jax.named_scope("lm_head"):
@@ -611,8 +790,35 @@ class SparseMoELM(nn.Module):
             logits = jnp.einsum("btd,dv->btv", x.astype(dt),
                                 jnp.pad(head, ((0, 0), (0, pad))),
                                 preferred_element_type=jnp.float32)
-            return logits + jnp.where(
+            logits = logits + jnp.where(
                 jnp.arange(cfg.vocab_size + pad) < cfg.vocab_size, 0.0, -1e30)
+        if diffusion:
+            return TokenWeighted(logits, masked / level)
+        return logits
+
+
+# the flax stream block diffusion's noise is drawn from
+NOISE_STREAM = "diffusion"
+
+
+def diffusion_noise(key, rows: int, seq_len: int, eps: float):
+    """``(t [rows, 1], m [rows, seq_len])``: one noise level a row, ``t
+    = eps + (1 - eps) u`` with ``u ~ U[0, 1)`` from the first half of
+    ``split(key)``, and each token masked independently with probability
+    ``t`` (a uniform from the second half below ``t``)."""
+    k_level, k_mask = jax.random.split(key)
+    level = eps + (1.0 - eps) * jax.random.uniform(k_level, (rows, 1))
+    return level, jax.random.uniform(k_mask, (rows, seq_len)) < level
+
+
+def _coerced(overrides: dict) -> dict:
+    """A configuration file's lists and dtype names as the fields' types."""
+    for key in ("mrope_section", "experts_held"):
+        if key in overrides:
+            overrides[key] = tuple(overrides[key])
+    if isinstance(overrides.get("compute_dtype"), str):
+        overrides["compute_dtype"] = jnp.dtype(overrides["compute_dtype"])
+    return overrides
 
 
 def keye_vl2_lm(**overrides) -> SparseMoELM:
@@ -620,9 +826,14 @@ def keye_vl2_lm(**overrides) -> SparseMoELM:
     ``overrides`` are :class:`SparseMoEConfig` fields (the benchmark's
     configuration file gives its cut: layers, the experts held, the
     vocabulary's slice)."""
-    for key in ("mrope_section", "experts_held"):
-        if key in overrides:
-            overrides[key] = tuple(overrides[key])
-    if isinstance(overrides.get("compute_dtype"), str):
-        overrides["compute_dtype"] = jnp.dtype(overrides["compute_dtype"])
-    return SparseMoELM(SparseMoEConfig(**overrides))
+    return SparseMoELM(SparseMoEConfig(**_coerced(overrides)))
+
+
+def sdar_moe_lm(**overrides) -> SparseMoELM:
+    """SDAR-30B-A3B-Chat at its published sizes (the same 30B-A3B block:
+    48 layers, 32 / 4 heads of 128, 128 experts of 768, 8 a token), plain
+    rotary of theta 1e6, trained by masked block diffusion in blocks of
+    4; ``overrides`` as for :func:`keye_vl2_lm`."""
+    return SparseMoELM(SparseMoEConfig(**{
+        "attention": "block_diffusion", "rope_theta": 1e6,
+        "mrope_section": (64,), **_coerced(overrides)}))

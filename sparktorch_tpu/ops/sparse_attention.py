@@ -80,40 +80,88 @@ def _scores(q, k, keep, scale):
     return jnp.where(keep, s, _NEG)
 
 
+def fwd_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def fwd_tile(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale, groups):
+    """One K/V tile into the running maximum, sum and output of the
+    ``groups`` query heads of a Q tile. ``keep`` is the tile's mask,
+    from wherever the kernel has it: this body and the two below are
+    shared with ``ops/block_diffusion_attention.py``."""
+    for g in range(groups):
+        s = _scores(q_ref[g], k, keep, scale)
+        m_prev, l_prev = m_ref[g][:, :1], l_ref[g][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_ref[g] = acc_ref[g] * alpha + pv
+        m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def fwd_finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups):
+    for g in range(groups):
+        l = jnp.maximum(l_ref[g][:, :1], 1e-20)
+        o_ref[g] = (acc_ref[g] / l).astype(o_ref.dtype)
+        lse_ref[g] = jnp.broadcast_to(m_ref[g][:, :1] + jnp.log(l),
+                                      lse_ref.shape[1:])
+
+
+def dq_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dq_acc, scale,
+            groups):
+    for g in range(groups):
+        s = _scores(q_ref[g], k, keep, scale)
+        p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
+        dp = jax.lax.dot_general(
+            do_ref[g], v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - d_ref[g][:, :1])
+        dq_acc[g] = dq_acc[g] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def dkv_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dk_acc, dv_acc,
+             scale, groups):
+    for g in range(groups):
+        q, do = q_ref[g], do_ref[g]
+        s = _scores(q, k, keep, scale)
+        p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
+        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - d_ref[g][:, :1])
+        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, scale, block_q, block_k, n_k, groups):
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        fwd_init(acc_ref, m_ref, l_ref)
 
     @pl.when(ki <= _last_k(qi, block_q, block_k))
     def _body():
         k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
-        for g in range(groups):
-            s = _scores(q_ref[g], k, keep, scale)
-            m_prev, l_prev = m_ref[g][:, :1], l_ref[g][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            acc_ref[g] = acc_ref[g] * alpha + pv
-            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        fwd_tile(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale, groups)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        for g in range(groups):
-            l = jnp.maximum(l_ref[g][:, :1], 1e-20)
-            o_ref[g] = (acc_ref[g] / l).astype(o_ref.dtype)
-            lse_ref[g] = jnp.broadcast_to(m_ref[g][:, :1] + jnp.log(l),
-                                          lse_ref.shape[1:])
+        fwd_finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, d_ref,
@@ -127,16 +175,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, d_ref,
     @pl.when(ki <= _last_k(qi, block_q, block_k))
     def _body():
         k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
-        for g in range(groups):
-            s = _scores(q_ref[g], k, keep, scale)
-            p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
-            dp = jax.lax.dot_general(
-                do_ref[g], v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - d_ref[g][:, :1])
-            dq_acc[g] = dq_acc[g] + jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        dq_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dq_acc, scale,
+                groups)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -156,20 +196,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, d_ref,
     @pl.when(qi >= _first_q(ki, block_q, block_k))
     def _body():
         k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
-        for g in range(groups):
-            q, do = q_ref[g], do_ref[g]
-            s = _scores(q, k, keep, scale)
-            p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
-            dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - d_ref[g][:, :1])
-            dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        dkv_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dk_acc, dv_acc,
+                 scale, groups)
 
     @pl.when(qi == n_q - 1)
     def _finalize():
@@ -273,17 +301,23 @@ def _fwd(q5, k4, v4, mask):
     )(q5, k4, v4, mask)
 
 
+def row_statistics(o5, lse, do5):
+    """What the backward kernels read a row: the log-sum-exp and ``sum(o
+    * do)``, each spread over a tile's lanes."""
+    rows = (*o5.shape[:-1], _LANES)
+    di = jnp.sum(o5.astype(jnp.float32) * do5.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+    di = jnp.broadcast_to(di, rows)
+    return jnp.broadcast_to(lse[..., None], rows), di
+
+
 def _bwd(q5, k4, v4, mask, o5, lse, do5):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     n_q, n_k = t // block_q, t // block_k
     kw = dict(scale=d ** -0.5, block_q=block_q, block_k=block_k,
               groups=groups)
-    rows = (b, hkv, groups, t, _LANES)
-    di = jnp.sum(o5.astype(jnp.float32) * do5.astype(jnp.float32), axis=-1,
-                 keepdims=True)
-    di = jnp.broadcast_to(di, rows)
-    lse = jnp.broadcast_to(lse[..., None], rows)
+    lse, di = row_statistics(o5, lse, do5)
 
     q_spec, kv_spec, mask_spec, row_spec = _specs(
         groups, d, block_q, block_k, q_major=True)

@@ -180,6 +180,21 @@ def _accepts_example_w(apply_fn) -> bool:
         return False
 
 
+def _forward_rngs(apply_fn, sample_key) -> dict:
+    """The random streams of a training forward that draws: ``{name:
+    fold_in(sample_key, i + 1)}`` for the ``i``-th name of the module's
+    ``train_rngs`` (flax stream names: a diffusion LM's noise, a
+    dropout), empty for a module that declares none, which gets what it
+    always got. ``sample_key`` is the step's own for this shard
+    (``fold_in(split(state.rng)[0], shard)``, see ``_dp_body``), so a
+    stream differs by step and by shard, never repeats the key the rows
+    were sampled with, and anyone who knows the run's seed can restate
+    it."""
+    names = getattr(getattr(apply_fn, "__self__", None), "train_rngs", ())
+    return {name: jax.random.fold_in(sample_key, i + 1)
+            for i, name in enumerate(names)}
+
+
 def refuse_sync_dp_only(module_or_apply, trainer: str) -> None:
     """Raise if the module says it trains on the sync DP trainer alone
     (a ``sync_dp_only`` attribute giving the reason): a trainer that
@@ -211,7 +226,8 @@ def create_train_state(
     )
 
 
-def _forward(apply_fn, params, model_state, x, train: bool, example_w=None):
+def _forward(apply_fn, params, model_state, x, train: bool, example_w=None,
+             rngs=None):
     """Apply with mutable non-trainable collections when present.
 
     Training forwards also request the write-only ``losses`` and
@@ -220,7 +236,8 @@ def _forward(apply_fn, params, model_state, x, train: bool, example_w=None):
     caller; they are popped — never carried — because ``sow`` appends
     to carried-in collections. ``example_w`` (per-example weights) is
     forwarded to modules that accept it, letting MoE routing mask
-    weight-0 padding rows. Returns ``(preds, new_model_state,
+    weight-0 padding rows; ``rngs`` (:func:`_forward_rngs`) to a
+    training forward that draws. Returns ``(preds, new_model_state,
     sown_losses_or_None, sown_metrics_or_None)``.
     """
     variables = {"params": params, **model_state}
@@ -229,6 +246,8 @@ def _forward(apply_fn, params, model_state, x, train: bool, example_w=None):
         kwargs["example_w"] = example_w
     if train:
         mutable = [*model_state.keys(), "losses", "moe_metrics"]
+        if rngs:
+            kwargs["rngs"] = rngs
         preds, new_state = apply_fn(variables, x, mutable=mutable, **kwargs)
         new_state = dict(new_state)
         sown = new_state.pop("losses", None)
@@ -440,7 +459,7 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
     def weighted_sums(params):
         preds, new_model_state, sown, sown_metrics = _forward(
             apply_fn, params, state.model_state, mb.x, train=True,
-            example_w=mb.w,
+            example_w=mb.w, rngs=_forward_rngs(apply_fn, sample_key),
         )
         per = loss_fn(preds, mb.y)
         den = jnp.sum(mb.w)

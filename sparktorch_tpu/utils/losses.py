@@ -20,12 +20,21 @@ zero-gradient all_reduce participant.
 
 from __future__ import annotations
 
-from typing import Callable, Union
+from typing import Callable, NamedTuple, Union
 
 import jax
 import jax.numpy as jnp
 
 LossFn = Callable[[jax.Array, jax.Array], jax.Array]
+
+
+class TokenWeighted(NamedTuple):
+    """What a training forward hands :func:`weighted_cross_entropy_loss`
+    in place of bare logits: ``logits [batch, seq, vocab]`` and a weight
+    for each token's cross entropy, ``weights [batch, seq]``."""
+
+    logits: jax.Array
+    weights: jax.Array
 
 
 def _flatten_per_example(x: jax.Array) -> jax.Array:
@@ -93,6 +102,16 @@ def fused_cross_entropy_loss(preds: jax.Array, targets: jax.Array) -> jax.Array:
     return _fce(preds, targets)
 
 
+def _fused_tiles(preds: jax.Array) -> bool:
+    """Whether LM-shaped logits go to the fused kernel: see
+    :func:`cross_entropy_auto`'s two reasons to stay dense."""
+    from sparktorch_tpu.ops.fused_ce import can_tile
+    from sparktorch_tpu.parallel.compat import ambient_gspmd_mesh
+
+    b, s, v = preds.shape
+    return can_tile(b * s, v) and ambient_gspmd_mesh() is None
+
+
 def cross_entropy_auto(preds: jax.Array, targets: jax.Array) -> jax.Array:
     """``cross_entropy`` registry entry. LM-shaped integer-label logits
     (batch, seq, vocab) dispatch to the fused Pallas kernel — the
@@ -119,17 +138,39 @@ def cross_entropy_auto(preds: jax.Array, targets: jax.Array) -> jax.Array:
     lm_shaped = preds.ndim == 3 and not (
         jnp.issubdtype(targets.dtype, jnp.floating) and targets.shape == preds.shape
     )
-    if lm_shaped:
-        from sparktorch_tpu.ops.fused_ce import can_tile
-        from sparktorch_tpu.parallel.compat import ambient_gspmd_mesh
-
-        b, s, v = preds.shape
-        if not can_tile(b * s, v):
-            return cross_entropy_loss(preds, targets)
-        if ambient_gspmd_mesh() is not None:
-            return cross_entropy_loss(preds, targets)
+    if lm_shaped and _fused_tiles(preds):
         return fused_cross_entropy_loss(preds, targets)
     return cross_entropy_loss(preds, targets)
+
+
+def weighted_cross_entropy_loss(preds: TokenWeighted,
+                                targets: jax.Array) -> jax.Array:
+    """``cross_entropy_weighted`` registry entry: the row's mean over
+    its positions of ``weight x cross entropy``, integer targets. A
+    masked-diffusion LM's loss is this with weight ``m_i / t`` (1 / the
+    row's noise level on the positions it masked, 0 elsewhere), so it
+    sums over masked positions only and is normalised by the row's
+    length.
+
+    How the weights get here: the trainers call ``loss_fn(preds,
+    targets)`` and know no weight by token, and the weights are drawn
+    inside the training forward. So the forward returns them WITH its
+    logits, as a :class:`TokenWeighted`, and that pair is the ``preds``
+    this loss takes: the step has no second branch. The token's cross
+    entropy goes through the fused Pallas kernel where
+    :func:`cross_entropy_auto` would pick it, else the dense path."""
+    logits, weights = preds
+    b, s, v = logits.shape
+    labels = targets.astype(jnp.int32).reshape(b * s)
+    flat = logits.reshape(b * s, v)
+    if _fused_tiles(logits):
+        from sparktorch_tpu.ops.fused_ce import fused_cross_entropy
+
+        per_token = fused_cross_entropy(flat, labels)
+    else:
+        per_token = jax.nn.logsumexp(flat, axis=-1) - jnp.take_along_axis(
+            flat, labels[:, None], axis=-1)[:, 0]
+    return (per_token.reshape(b, s) * weights).mean(-1)
 
 
 def nll_loss(preds: jax.Array, targets: jax.Array) -> jax.Array:
@@ -157,6 +198,7 @@ LOSS_REGISTRY: dict[str, LossFn] = {
     "cross_entropy": cross_entropy_auto,
     "cross_entropy_dense": cross_entropy_loss,
     "cross_entropy_fused": fused_cross_entropy_loss,
+    "cross_entropy_weighted": weighted_cross_entropy_loss,
     "nll": nll_loss,
     "bce_with_logits": bce_with_logits_loss,
     # torch.nn criterion-class spellings, so reference users can pass the

@@ -174,6 +174,38 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 6_009_584_128 // 2
 
 
+def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
+    """One layer of the block-diffusion MoE LM at the published widths
+    and the benchmark cell's rows (1 row of 8,192 tokens, 16,384 through
+    the layer as the clean row and its noised copy; 16 of 128 experts
+    held; the head and the weighted loss on the noised half, through the
+    fused cross entropy): the mask is a rule the kernels evaluate on
+    their tiles' indices, which Mosaic has to lower (integer division
+    and remainder on a column and a row of indices), and their grids run
+    over tables that reach them by scalar prefetch. One kernel of each
+    kind a layer: the remat keeps the forward kernel's output and row
+    statistics."""
+    from sparktorch_tpu.models.sparse_moe_lm import NOISE_STREAM, sdar_moe_lm
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    module = sdar_moe_lm(n_layers=1, vocab_size=4000, mask_token_id=3999,
+                         experts_held=tuple(range(16)))
+    ids = jnp.zeros((1, 8192), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy_weighted")
+    text = jax.jit(jax.grad(lambda p, x: loss_fn(
+        module.apply({"params": p}, x,
+                     rngs={NOISE_STREAM: jax.random.key(1)}), x).sum())).lower(
+            jax.tree.map(S, shapes), S(ids)).compile().as_text()
+    assert _pallas_calls(text, "blockdiff_attn_fwd") == 1
+    assert _pallas_calls(text, "blockdiff_attn_bwd_dq") == 1
+    assert _pallas_calls(text, "blockdiff_attn_bwd_dkv") == 1
+    assert _pallas_calls(text, "sparse_attn_fwd") == 0
+    assert _pallas_calls(text, "fused_ce_fwd") == 1  # on 8,192 rows
+
+
 def test_untileable_shape_raises_on_tpu_backend(one_chip, as_tpu):
     """A caller who asked for the kernel by name gets an error naming
     the shape and the rule on a TPU backend — never a dense program

@@ -49,6 +49,18 @@ def rel(a, b):
     return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
 
 
+# the module's own rule, kept from before any test patches it
+ROW_CHUNKS_RULE = M._row_chunks
+
+
+def chunks_of(patch, chunk):
+    """The expert layers' loops over chunks of ``chunk`` sorted pairs,
+    whatever share is held: the module's own rule (twice the expected
+    share of the pairs) gives these sizes one trip or two."""
+    patch.setattr(M, "_row_chunks", lambda n_pairs, *_: (
+        min(chunk, n_pairs), -(-n_pairs // min(chunk, n_pairs))))
+
+
 # T = 128 above topk = 32 (selection at work) and below topk = 256
 # (every causal key attended)
 CASES = {"above_topk": 32, "below_topk": 256}
@@ -63,7 +75,7 @@ def both(request):
     # expert layers' 1,024 sorted pairs in chunks of 96
     patch = pytest.MonkeyPatch()
     patch.setattr(M, "_IDX_Q_CHUNK", 48)
-    patch.setattr(M, "_ROW_CHUNK", 96)
+    chunks_of(patch, 96)
     request.addfinalizer(patch.undo)
     variables = REF.init(jax.random.key(0), cfg)
     ids, labels = rows()
@@ -192,15 +204,31 @@ def _seeded_layer(held=(2, 3)):
     return _expert_layer(held), params, g
 
 
+def _uncut_layer(model):
+    """The reference, its configuration holding all 16 experts, and the
+    program's expert layer by the experts held, for either model built
+    on the block."""
+    if model == "keye":
+        cfg, _ = sizes(held=tuple(range(16)), layers=1)
+        return REF, cfg, _expert_layer
+    import test_block_diffusion_lm as D
+
+    cfg, _ = D.sizes(held=tuple(range(16)), layers=1)
+    return D.REF, cfg, lambda held: M.HeldExperts(
+        D.sizes(held=held, layers=1)[1].config)
+
+
 @pytest.mark.parametrize("n_shares", [8, 4])
-def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(n_shares):
+@pytest.mark.parametrize("model", ["keye", "sdar"])
+def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(model,
+                                                               n_shares):
     """Each share routes over all 16 experts and computes its own; the
     shares' outputs summed are the reference's layer with every expert."""
-    cfg, _ = sizes(held=tuple(range(16)), layers=1)
-    whole = REF.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    ref, cfg, layer_holding = _uncut_layer(model)
+    whole = ref.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
     g = jax.random.normal(jax.random.key(5), (ROWS, T, 64), jnp.float32)
     ein = lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
-    want = jnp.stack([REF._experts_row(whole, row, REF._sizes(cfg), ein, None)
+    want = jnp.stack([ref._experts_row(whole, row, ref._sizes(cfg), ein, None)
                       for row in g])
     per = 16 // n_shares
     total = 0.0
@@ -209,7 +237,7 @@ def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(n_shares):
         params = {"router": whole["router"],
                   **{k: whole[k][jnp.asarray(held)]
                      for k in ("w_gate", "w_up", "w_down")}}
-        out = _expert_layer(held).apply({"params": params}, g)
+        out = layer_holding(held).apply({"params": params}, g)
         total = total + out
         assert rel(out, want) > 1e-2  # one share alone is not the layer
     assert rel(total, want) < 1e-5
@@ -242,6 +270,18 @@ def test_no_pair_is_dropped_when_the_router_forces_held_experts(forced):
 
 
 # -- the loop over chunks of the sorted pairs, under planted loads --------
+
+
+@pytest.mark.parametrize("n_pairs, n_held, n_routed, want", [
+    (131_072, 16, 128, (32_768, 4)),   # the 8k cells: 16,384 tokens x 8
+    (131_072, 128, 128, (131_072, 1)),  # a holder of every expert
+    (1_024, 3, 16, (384, 3)),          # 192 expected, the last chunk short
+    (10, 1, 128, (2, 5)),              # an expected share under one pair
+])
+def test_a_chunk_is_twice_the_pairs_the_layer_expects_to_hold(
+        n_pairs, n_held, n_routed, want):
+    assert ROW_CHUNKS_RULE(n_pairs, n_held, n_routed) == want
+
 
 HELD4, ELSEWHERE, LOAD_CHUNK = (2, 3, 5, 7), (8, 9, 10, 11), 64
 # case -> [(tokens, the four experts each of them chooses)]; the other
@@ -288,7 +328,7 @@ def test_the_chunked_layer_is_the_reference_at_every_load(case, monkeypatch):
     loop runs 0, 1, 2, 3 and all 16 chunks, against the plain reference.
     With no held pair the layer adds exactly 0 and every gradient is
     exactly 0."""
-    monkeypatch.setattr(M, "_ROW_CHUNK", LOAD_CHUNK)
+    chunks_of(monkeypatch, LOAD_CHUNK)
     cfg, x, gain, params, want_rows = _planted(LOADS[case])
     layer = _expert_layer(HELD4)
     ein = lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
@@ -406,7 +446,7 @@ def _float_arrays_by_pair(jaxpr, n_pairs):
 def test_no_pass_of_the_layer_holds_a_row_for_every_chosen_pair(monkeypatch):
     """Neither the layer nor its gradient makes a float array of
     ``[tokens x k, ...]``: the rows exist a chunk at a time."""
-    monkeypatch.setattr(M, "_ROW_CHUNK", LOAD_CHUNK)
+    chunks_of(monkeypatch, LOAD_CHUNK)
     cfg, x, gain, params, _ = _planted(LOADS["under_one_chunk"])
     layer = _expert_layer(HELD4)
     loss = lambda x, p: jnp.sum(jnp.sin(layer.apply({"params": p}, x)))
@@ -443,7 +483,7 @@ def test_a_pair_outside_its_experts_group_counts_as_dropped():
 def test_chunk_sizes_derived_wrongly_show_as_dropped_pairs(monkeypatch):
     """The layer's ``dropped`` is counted against the group sizes its
     loop gives the products, chunk by chunk."""
-    monkeypatch.setattr(M, "_ROW_CHUNK", 64)
+    chunks_of(monkeypatch, 64)
     layer, params, g = _seeded_layer()
     sown = lambda: layer.apply({"params": params}, g,
                                mutable=["moe_metrics"])[1]["moe_metrics"]
@@ -522,7 +562,7 @@ def test_dp2_ends_on_the_parameters_of_one_shard(one_and_two_shards):
 
 
 def test_counters_and_gauges_reach_the_records_and_the_bus(monkeypatch):
-    monkeypatch.setattr(M, "_ROW_CHUNK", 96)
+    chunks_of(monkeypatch, 96)
     records, _, tele = _train(1, iters=4, steps_per_call=2)
     assert len(records) == 4
     for r in records:
@@ -555,7 +595,7 @@ def test_row_chunks_in_the_records_are_the_chunks_the_rows_take(
     chunks its loop ran are those rows rounded up to a chunk (on two
     shards each rounds up its own), of the chunks that all chosen pairs
     would take."""
-    monkeypatch.setattr(M, "_ROW_CHUNK", 96)
+    chunks_of(monkeypatch, 96)
     records, _, tele = _train(n_devices, iters=3, layers=1, steps_per_call=1)
     assert len(records) == 3
     for r in records:
@@ -617,7 +657,7 @@ def test_rows_ragged_dot_leaves_undefined_never_reach_a_sum(monkeypatch):
                            preferred_element_type=preferred_element_type)
         return jnp.where((sizes == 0)[:, None, None], jnp.nan, out)
 
-    monkeypatch.setattr(M, "_ROW_CHUNK", 64)
+    chunks_of(monkeypatch, 64)
     layer, params, g = _seeded_layer()
     loss = lambda p, g: jnp.sum(jnp.sin(layer.apply({"params": p}, g)))
     want = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
