@@ -25,8 +25,11 @@ With no arguments (one chip), four phases:
 
 With ``--chips 4`` only the multi-chip path and what it is compared
 with: BERT-base ``train_distributed`` on dp=4 against the same run on
-one chip, one ``make_sharded_train_step`` step on dp=2 x fsdp=2, and
-one MoE step at ep=4 whose compiled text contains ``all-to-all``.
+one chip, the same at rows of 512 tokens (the default attention is the
+fused kernels there) against ``attn_impl="dense"`` and through the
+predictor over the mesh, one ``make_sharded_train_step`` step on
+dp=2 x fsdp=2, and one MoE step at ep=4 whose compiled text contains
+``all-to-all``.
 
 Every phase is a function that raises on failure; a failure stops the
 run (later phases print as "not run"), the exit code is non-zero and
@@ -92,6 +95,8 @@ class Sizes:
     # four chips
     dp_rows: int = 128
     dp_iters: int = 4
+    long_seq: int = 512
+    long_rows: int = 32
     moe_experts: int = 8
     moe_seq: int = 1024
     moe_batch: int = 8
@@ -430,6 +435,68 @@ def phase_dp4_vs_one_chip(sz: Sizes, seed: int, ctx: dict) -> str:
             f"batch_rows_per_device={shard_rows}")
 
 
+def phase_dp4_long_rows(sz: Sizes, seed: int, ctx: dict) -> str:
+    """BERT-base on rows of ``long_seq`` tokens over dp=4, where the
+    default attention is the fused kernels, each chip's own inside the
+    step's ``shard_map``: the bus says they were picked, the per-step
+    losses agree with ``attn_impl="dense"`` on the same mesh, and the
+    predictor over the same mesh (a program the partitioner splits, so
+    dense by the rule) compiles and equals a plain ``module.apply``."""
+    import jax
+
+    from sparktorch_tpu import serialize_torch_obj
+    from sparktorch_tpu.inference import BatchPredictor
+    from sparktorch_tpu.models.transformer import bert_base
+    from sparktorch_tpu.obs import Telemetry
+    from sparktorch_tpu.parallel.mesh import MeshConfig, build_mesh
+    from sparktorch_tpu.train.sync import train_distributed
+
+    rng = np.random.default_rng(seed)
+    x, y = _bert_rows(rng, sz.long_rows, sz.long_seq, sz.bert_vocab)
+    mesh = build_mesh(MeshConfig(dp=4), jax.devices()[:4])
+    losses, picked, wall = {}, {}, {}
+    for attn in ("dense", "auto"):
+        module = bert_base(attn_impl=attn, **dict(sz.bert_overrides))
+        tele = Telemetry(run_id=f"smoke-long-{attn}")
+        t0 = time.perf_counter()
+        res = train_distributed(
+            serialize_torch_obj(
+                module, criterion="cross_entropy", optimizer="adam",
+                optimizer_params={"lr": 1e-4}, input_shape=(sz.long_seq,)),
+            x, labels=y, mesh=mesh, iters=sz.dp_iters, seed=seed,
+            telemetry=tele)
+        wall[attn] = time.perf_counter() - t0
+        losses[attn] = np.asarray([r["loss"] for r in res.metrics])
+        picked[attn] = tele.gauge_value("train.attention.kernel_layers")
+    la, ld = losses["auto"], losses["dense"]
+    if la.shape != (sz.dp_iters,) or not np.all(np.isfinite(la)):
+        raise AssertionError(f"losses under 'auto': {la}")
+    if (picked["auto"], picked["dense"]) != (module.config.n_layers, 0):
+        raise AssertionError(
+            f"train.attention.kernel_layers reads {picked['auto']} under "
+            f"'auto' and {picked['dense']} under 'dense', wanted "
+            f"{module.config.n_layers} and 0")
+    rel = float(np.max(np.abs(la - ld) / np.abs(ld)))
+    if rel > TOL_DP_LOSS_REL:
+        raise AssertionError(f"kernels {la} vs dense {ld}: rel {rel}")
+    # the model 'auto' trained, served over the same mesh
+    preds = BatchPredictor(module, res.params, res.model_state, mesh=mesh,
+                           chunk=sz.long_rows).predict(x)
+    ref = np.asarray(jax.jit(module.apply)(
+        {"params": res.params, **(res.model_state or {})}, x))
+    err = float(np.max(np.abs(preds - ref)))
+    if preds.shape != ref.shape or not err <= TOL_PREDICT_ABS:
+        raise AssertionError(f"predictor over dp=4 differs from "
+                             f"module.apply by {err}")
+    return (f"seq={sz.long_seq} rows={sz.long_rows} "
+            f"auto_s={wall['auto']:.2f} dense_s={wall['dense']:.2f} "
+            f"kernel_layers={picked['auto']:.0f}/{picked['dense']:.0f} "
+            f"losses_auto={[round(float(v), 5) for v in la]} "
+            f"losses_dense={[round(float(v), 5) for v in ld]} "
+            f"max_rel={rel:.2e} (tol {TOL_DP_LOSS_REL}) "
+            f"predictor_max_abs_err={err:.2e} (tol {TOL_PREDICT_ABS})")
+
+
 def _sharded_step(spec, mesh_cfg, x, y, seed, want_text=False):
     """One ``make_sharded_train_step`` step; returns (loss, state,
     compiled text or None, seconds)."""
@@ -526,6 +593,7 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("kernels", phase_kernels),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
+              ("dp4_long_rows", phase_dp4_long_rows),
               ("sharded_dp2_fsdp2", phase_sharded_dp2_fsdp2),
               ("moe_ep4", phase_moe_ep4))
 

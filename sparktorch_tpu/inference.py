@@ -24,6 +24,30 @@ from sparktorch_tpu.parallel.mesh import batch_sharding, replicated
 from sparktorch_tpu.utils.serde import ModelSpec
 
 
+def _jit_forward(module, mesh: Optional[Mesh], params, model_state,
+                 preprocess=None, postprocess=None):
+    """The predictor's compiled forward ``(params, model_state, x)``.
+    Over a mesh the parameters are replicated and the rows split over
+    its batch axes by the ``jit``'s own shardings: the partitioner
+    splits the program, and no mesh is in sight of the trace (a module
+    that picks a path by the mesh, :func:`sparktorch_tpu.models.
+    transformer.pick_attention`, sees the process's device count)."""
+
+    def fwd(params, model_state, x):
+        if preprocess is not None:
+            x = preprocess(x)
+        out = module.apply({"params": params, **model_state}, x)
+        if postprocess is not None:
+            out = postprocess(out)
+        return out
+
+    if mesh is None:
+        return jax.jit(fwd)
+    whole = lambda tree: jax.tree.map(lambda _: replicated(mesh), tree)
+    return jax.jit(fwd, in_shardings=(whole(params), whole(model_state),
+                                      batch_sharding(mesh)))
+
+
 class BatchPredictor:
     """Mesh-parallel batch inference engine.
 
@@ -68,26 +92,11 @@ class BatchPredictor:
         self.chunk = ((c + n_shards - 1) // n_shards) * n_shards
         self._n_shards = n_shards
 
-        def fwd(params, model_state, x):
-            if preprocess is not None:
-                x = preprocess(x)
-            variables = {"params": params, **(model_state or {})}
-            out = self.module.apply(variables, x)
-            if postprocess is not None:
-                out = postprocess(out)
-            return out
-
+        self._fwd = _jit_forward(module, mesh, params, model_state or {},
+                                 preprocess, postprocess)
         if mesh is not None:
             self._params = jax.device_put(params, replicated(mesh))
             self._model_state = jax.device_put(model_state or {}, replicated(mesh))
-            self._fwd = jax.jit(
-                fwd,
-                in_shardings=(
-                    jax.tree.map(lambda _: replicated(mesh), params),
-                    jax.tree.map(lambda _: replicated(mesh), model_state or {}),
-                    batch_sharding(mesh),
-                ),
-            )
             self._x_sharding = batch_sharding(mesh)
         else:
             # Pin params/state to ONE device ONCE. Leaving them as
@@ -99,7 +108,6 @@ class BatchPredictor:
             self._params = jax.device_put(params, self._device)
             self._model_state = jax.device_put(model_state or {},
                                                self._device)
-            self._fwd = jax.jit(fwd)
             self._x_sharding = None
 
     @property

@@ -681,8 +681,9 @@ class SparseMoELM(nn.Module):
         return ((NOISE_STREAM,) if self.config.attention == "block_diffusion"
                 else ())
 
-    def train_gauges(self) -> dict:
-        """What the trainers put on the bus when they build a step."""
+    def train_gauges(self, row_shape) -> dict:
+        """What the trainers put on the bus when they build a step (for
+        rows of ``row_shape``, which changes nothing here)."""
         cfg = self.config
         gauges = {"train.moe.experts_held": len(cfg.experts_held),
                   "train.moe.experts_routed": cfg.n_routed_experts}

@@ -6,7 +6,18 @@ the reference itself contains no transformer or attention code —
 SURVEY §5 "Long-context": *entirely absent*). Long context is
 first-class here:
 
-- ``attn_impl='dense'``: fused-by-XLA softmax attention.
+- ``attn_impl='auto'`` (the default): the code picks, layer by layer at
+  trace time, from what it can see (:func:`pick_attention`): the fused
+  Pallas kernels of :mod:`sparktorch_tpu.ops.flash_attention` on a TPU
+  at rows of :data:`KERNEL_MIN_SEQ` tokens or more (by head width, as
+  read on the chip) whose shape the kernels take as it stands, where
+  the trace is one device's own program (a ``shard_map`` body, or no
+  mesh in a process of one device) and the sequence is whole; else
+  dense.
+- ``attn_impl='dense'``: softmax attention as XLA fuses it; the
+  ``[rows, heads, T, T]`` scores and probabilities cross HBM.
+- ``attn_impl='flash'``: the fused kernels, asked for by name (an
+  untileable sequence is an error on a TPU).
 - ``attn_impl='ring'``: sequence-parallel ring attention
   (:mod:`sparktorch_tpu.ops.attention`) — the sequence axis is
   sharded over the mesh's ``sp`` axis and K/V blocks rotate over ICI,
@@ -25,6 +36,7 @@ GSPMD inserts the tp collectives. Heads must divide the tp size.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional, Tuple
 
@@ -49,7 +61,7 @@ class TransformerConfig:
     max_len: int = 512
     n_classes: int = 2
     dtype: str = "bfloat16"
-    attn_impl: str = "dense"  # 'dense' | 'ring'
+    attn_impl: str = "auto"  # 'auto' | 'dense' | 'flash' | 'ring'
     causal: bool = False
     remat: bool = False
     # Mixture-of-experts (0 = dense FFN everywhere). Expert weights
@@ -116,6 +128,52 @@ class TransformerConfig:
         return jnp.dtype(self.dtype)
 
 
+class HeadsDense(nn.Module):
+    """A dense layer from the trailing axes ``in_shape`` onto the axes
+    ``features``, with ``nn.DenseGeneral``'s parameters (``kernel``
+    ``in_shape + features`` drawn flat, ``bias`` ``features``) and, as
+    the default, its product: the tree, the initial values and the
+    program are the ones it gives.
+
+    ``flat``: the same product written as one 2-D matmul, from rows
+    ``[..., prod(in_shape)]`` onto ``[..., prod(features)]``, for the
+    fused attention kernels, which take and give rows ``[b, T, heads *
+    head_dim]``. A product ``[b, T, 3, heads, 64]`` XLA lays out with
+    the sequence in the lanes (64 would fill half of them), and each
+    operand of a kernel then costs a transposing copy: 8 copies of 25 MB
+    and a slicing pass a layer at BERT-base on 32 rows of 512 (compiled
+    for a v5e, PR 33; a 2-D product reshaped to five axes before the
+    bias is added is folded back into that one). Flat, q, k and v leave
+    the projection's fusion as the kernels read them, and the three
+    gradients enter its transpose as the kernels wrote them."""
+
+    in_shape: Tuple[int, ...]
+    features: Tuple[int, ...]
+    dtype: Optional[jnp.dtype] = None
+    flat: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        n_in, n_out = math.prod(self.in_shape), math.prod(self.features)
+
+        def drawn_flat(rng, shape):
+            return nn.initializers.lecun_normal()(rng, (n_in, n_out)
+                                                  ).reshape(shape)
+
+        kernel = self.param("kernel", drawn_flat,
+                            self.in_shape + self.features)
+        bias = self.param("bias", nn.initializers.zeros, self.features)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        if self.flat:
+            return x @ kernel.reshape(n_in, n_out) + bias.reshape(n_out)
+        n, lead = len(self.in_shape), x.ndim - len(self.in_shape)
+        out = jax.lax.dot_general(
+            x, kernel,
+            ((tuple(range(lead, x.ndim)), tuple(range(n))), ((), ())))
+        return out + bias.reshape((1,) * lead + self.features)
+
+
 class MultiHeadAttention(nn.Module):
     config: TransformerConfig
 
@@ -124,16 +182,22 @@ class MultiHeadAttention(nn.Module):
         cfg = self.config
         b, s, _ = x.shape
         dt = cfg.compute_dtype
-        qkv = nn.DenseGeneral(
-            (3, cfg.n_heads, cfg.head_dim), axis=-1, dtype=dt, name="qkv"
-        )(x)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (b,s,h,hd)
-
-        if cfg.attn_impl == "flash":
+        heads = (cfg.n_heads, cfg.head_dim)
+        kernels = pick_attention(cfg, s) == "flash"
+        qkv = HeadsDense((cfg.d_model,), (3, *heads), dt, flat=kernels,
+                         name="qkv")(x)
+        out_proj = HeadsDense(heads, (cfg.d_model,), dt, flat=kernels,
+                              name="proj")
+        if kernels:
             from sparktorch_tpu.ops.flash_attention import flash_attention
 
+            q, k, v = (t.reshape(b, s, *heads)
+                       for t in jnp.split(qkv, 3, axis=-1))
             out = flash_attention(q, k, v, cfg.causal)
-        elif cfg.attn_impl == "ring" and _ring_island_enabled() \
+            return out_proj(out.reshape(b, s, cfg.d_model))
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # (b,s,h,hd)
+
+        if cfg.attn_impl == "ring" and _ring_island_enabled() \
                 and _sp_mesh_available(q.shape):
             from sparktorch_tpu.train.step import shard_map_compat
 
@@ -162,9 +226,61 @@ class MultiHeadAttention(nn.Module):
             # load-bearing — is unaffected: it rides the pp shard_map,
             # not this island.)
             out = dense_attention(q, k, v, causal=cfg.causal)
-        return nn.DenseGeneral(
-            cfg.d_model, axis=(-2, -1), dtype=dt, name="proj"
-        )(out)
+        return out_proj(out)
+
+
+# Head width -> the shortest rows that go to the fused kernels under
+# ``attn_impl='auto'``; a width that is no key stays dense. Placed by
+# measurement on a v5e chip, whole steps at BERT-base's widths and
+# 16,384 tokens, dense / kernels (PERF.md section 6, PR 33): heads of 64
+# 68.9 / 76.9 ms at 128, 83.5 / 76.3 at 256, 111.8 / 75.9 at 512; heads
+# of 128 70.4 / 70.2 at 256, 85.0 / 69.3 at 512.
+KERNEL_MIN_SEQ = {64: 256, 128: 512}
+
+
+def pick_attention(cfg: TransformerConfig, seq: int) -> str:
+    """Which attention a layer of ``cfg`` runs on rows of ``seq``
+    tokens: ``cfg.attn_impl`` as named, and for ``'auto'`` ``'flash'``
+    or ``'dense'`` from what the trace can see. The kernels when
+
+    - the backend is a TPU (off it they run in interpret mode: a test's
+      tool, not a path);
+    - the shape is theirs as it stands
+      (:func:`sparktorch_tpu.ops.flash_attention.can_tile`: the
+      sequence splits into blocks of whole lane widths and the heads
+      fill 128-lane groups with no padding);
+    - the trace is one device's own program, which no partitioner will
+      split: a Mosaic kernel cannot be partitioned automatically (the
+      rule ``cross_entropy_auto`` follows), and the TPU compiler
+      refuses a program that asks. Inside a ``shard_map`` body every
+      mesh axis is Manual and the kernel sees its shard's rows. A
+      GSPMD (non-Manual) ambient mesh says the opposite. No mesh in
+      sight says nothing: a ``jit`` may carry shardings of its own (the
+      predictor over a mesh, the trainers' init), which a trace cannot
+      see, so without a mesh only a process that drives one device in
+      all gets the kernel;
+    - the sequence is not sharded over ``sp``: a shard's rows hold part
+      of the keys;
+    - the rows are as long as :data:`KERNEL_MIN_SEQ` asks at this head
+      width, which was read on the chip at the widths it holds: under
+      that the scores are small, XLA's fusions hold them well, and the
+      kernel would run one small tile a grid step.
+    """
+    if cfg.attn_impl != "auto":
+        return cfg.attn_impl
+    from sparktorch_tpu.ops.flash_attention import can_tile
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        own_program = jax.device_count() == 1
+    else:
+        own_program = (set(mesh.manual_axes) == set(mesh.axis_names)
+                       and dict(mesh.shape).get("sp", 1) == 1)
+    if (jax.default_backend() == "tpu" and own_program
+            and seq >= KERNEL_MIN_SEQ.get(cfg.head_dim, math.inf)
+            and can_tile(seq, seq, cfg.n_heads, cfg.head_dim)):
+        return "flash"
+    return "dense"
 
 
 def _ring_island_enabled() -> bool:
@@ -666,10 +782,22 @@ class Transformer(nn.Module):
         return nn.LayerNorm(dtype=cfg.compute_dtype, name="ln_final")(x)
 
 
+def _attention_gauges(cfg: TransformerConfig, row_shape) -> dict:
+    """What the trainers put on the bus when they build a step for rows
+    of ``row_shape`` (``(seq,)``): the layers whose attention is the
+    fused kernels, by :func:`pick_attention` where it is called (the
+    trainers call from inside their step's ``shard_map``)."""
+    kernel = pick_attention(cfg, row_shape[0]) == "flash"
+    return {"train.attention.kernel_layers": cfg.n_layers if kernel else 0}
+
+
 class SequenceClassifier(nn.Module):
     """BERT-style classifier (SST-2 workload, BASELINE config 4)."""
 
     config: TransformerConfig
+
+    def train_gauges(self, row_shape) -> dict:
+        return _attention_gauges(self.config, row_shape)
 
     @nn.compact
     def __call__(self, ids, example_w=None):
@@ -690,6 +818,9 @@ class CausalLM(nn.Module):
     training workload for ring attention)."""
 
     config: TransformerConfig
+
+    def train_gauges(self, row_shape) -> dict:
+        return _attention_gauges(self.config, row_shape)
 
     def setup(self):
         cfg = dataclasses.replace(self.config, causal=True)

@@ -338,6 +338,8 @@ def _attn_half(cfg: TransformerConfig, lp, h):
 
         out = ring_attention(q, k, v, axis_name=AXIS_SP, causal=cfg.causal)
     else:
+        # 'dense', and 'auto': the stages' attention is picked by name
+        # here, not by models.transformer.pick_attention
         out = dense_attention(q, k, v, causal=cfg.causal)
     proj_k = lp["attn"]["proj"]["kernel"].astype(dt)   # (h_loc, hd, d)
     proj_b = lp["attn"]["proj"]["bias"].astype(dt)     # (d,) replicated

@@ -45,6 +45,7 @@ from sparktorch_tpu.train.step import (
     make_train_epoch,
     make_train_epoch_fused,
     make_train_step,
+    shard_map_compat,
 )
 from sparktorch_tpu.utils.data import DataBatch, handle_features, pad_to_multiple
 from sparktorch_tpu.utils.early_stopper import EarlyStopping
@@ -183,10 +184,26 @@ def _note_grad_allreduce(tele, params, mesh: Mesh) -> None:
     tele.gauge("train.grad_allreduce.bytes", nbytes)
 
 
-def _note_model_gauges(tele, module) -> None:
-    """What a model says of its own structure (``train_gauges``: the
-    sparse attention's top-k, the experts held and routed), on the bus."""
-    for name, value in getattr(module, "train_gauges", dict)().items():
+def _note_model_gauges(tele, module, row_shape, mesh: Mesh) -> None:
+    """What a model says of its own structure and of the step built for
+    rows of ``row_shape`` (``train_gauges``: the sparse attention's
+    top-k, the experts held and routed, the layers whose attention is a
+    fused kernel), on the bus. The model is asked where the step's
+    trace asks it, in the body of a ``shard_map`` over ``mesh`` (an
+    abstract evaluation: nothing runs), so a choice it makes from the
+    mesh in sight is the one the step was built with."""
+    gauges = getattr(module, "train_gauges", None)
+    if gauges is None:
+        return
+    said = {}
+
+    def body():
+        said.update(gauges(row_shape))
+        return jnp.zeros(())
+
+    jax.eval_shape(shard_map_compat(body, mesh, in_specs=(),
+                                    out_specs=jax.sharding.PartitionSpec()))
+    for name, value in said.items():
         tele.gauge(name, value)
 
 
@@ -231,6 +248,16 @@ def _result(spec, loop: "_ChunkLoop", obs: "_RunObservers") -> TrainResult:
                        summary=obs.recorder.summary())
 
 
+def _jit_init(spec, mesh: Mesh, rng, sample_x, tx):
+    """The compiled init: the state replicated over ``mesh`` by the
+    ``jit``'s own ``out_shardings`` (no mesh is in sight of the trace:
+    a module that picks a path by the mesh sees the process's device
+    count, :func:`sparktorch_tpu.models.transformer.pick_attention`)."""
+    return jax.jit(
+        lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx),
+        out_shardings=replicated(mesh))
+
+
 def _init_state(tele, spec, mesh: Mesh, rng, sample_x, tx) -> TrainState:
     """Initialize UNDER jit with replicated out_shardings: every process
     runs the same compiled init, so this works on multi-process
@@ -241,10 +268,7 @@ def _init_state(tele, spec, mesh: Mesh, rng, sample_x, tx) -> TrainState:
     the goodput ledger's compile bucket takes it."""
     with tele.span("train/init"), _goodput.span(
             "compile", {"site": "train_init"}), mesh:
-        return jax.jit(
-            lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx),
-            out_shardings=replicated(mesh),
-        )()
+        return _jit_init(spec, mesh, rng, sample_x, tx)()
 
 
 class _RunObservers:
@@ -646,7 +670,8 @@ def train_distributed(
         eval_step = (make_eval_step(module.apply, loss_fn, mesh)
                      if val_batch is not None and not fused_signals else None)
         _note_grad_allreduce(tele, state.params, mesh)
-        _note_model_gauges(tele, module)
+        _note_model_gauges(tele, module,
+                           tuple(train_batch.x.shape[1:]), mesh)
 
     from sparktorch_tpu.utils.tracing import profile_run
 
@@ -912,7 +937,7 @@ def train_distributed_streaming(
     loss_fn = spec.loss_fn()
     step_fn = _make_step(module, loss_fn, tx, mesh, steps, mini_batch)
     _note_grad_allreduce(tele, state.params, mesh)
-    _note_model_gauges(tele, module)
+    _note_model_gauges(tele, module, tuple(x.shape[1:]), mesh)
 
     sharding = batch_sharding(mesh)
 

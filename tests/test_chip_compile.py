@@ -206,6 +206,130 @@ def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # on 8,192 rows
 
 
+@pytest.mark.parametrize("rows,seq,calls", [(32, 512, 1), (128, 128, 0)])
+def test_encoder_layer_gradient_picks_its_attention_for_v5e(
+        one_chip, as_tpu, monkeypatch, rows, seq, calls):
+    """One layer of BERT-base's classifier under the default
+    ``attn_impl='auto'``, its whole gradient through the TPU compiler at
+    the two benchmark shapes of 16,384 tokens: at 32 rows of 512 the
+    layer's attention is one ``flash_fwd``, one ``flash_bwd_dq`` and one
+    ``flash_bwd_dkv`` and no array of all (query, key) pairs is left in
+    the program; at 128 rows of 128 it is XLA's dense fusions and no
+    custom call."""
+    from sparktorch_tpu.models.transformer import bert_base
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    monkeypatch.setattr(jax, "device_count", lambda: 1)  # a one-chip host
+    module = bert_base(n_layers=1, max_len=seq)
+    ids = jnp.zeros((rows, seq), jnp.float32)
+    labels = jnp.zeros((rows,), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy")
+    text = jax.jit(jax.grad(lambda p, x, y: loss_fn(
+        module.apply({"params": p}, x), y).sum())).lower(
+            jax.tree.map(S, shapes), S(ids), S(labels)).compile().as_text()
+    counts = kernel_call_counts(text)
+    assert counts == {"flash_fwd": calls, "flash_bwd_dq": calls,
+                      "flash_bwd_dkv": calls, "fused_ce_fwd": 0,
+                      "fused_ce_bwd": 0}, counts
+    assert module.train_gauges((seq,)) == {
+        "train.attention.kernel_layers": calls}
+    all_pairs = f"[{rows},12,{seq},{seq}]"
+    assert (all_pairs in text) is (calls == 0)
+
+
+def _encoder_over_2x2(topo, **overrides):
+    """One BERT-base layer at rows of 512, its parameters' shapes, and a
+    dp=4 mesh of the described chips."""
+    from sparktorch_tpu.models.transformer import bert_base
+    from sparktorch_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    module = bert_base(n_layers=1, max_len=512, **overrides)
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.key(0), jnp.zeros((1, 512), jnp.float32)))["params"]
+    return module, shapes, build_mesh(MeshConfig(dp=4), topo.devices)
+
+
+def test_predictor_over_a_mesh_stays_dense_for_v5e_2x2(topo, as_tpu,
+                                                       monkeypatch):
+    """``BatchPredictor(mesh=...)``'s forward at rows of 512 on a 2x2
+    host: the ``jit``'s own shardings hand the program to the
+    partitioner with no mesh in sight of the trace, so the rule keeps a
+    process of four devices dense and the program compiles with no
+    custom call. Named, the kernel is what the TPU compiler refuses
+    there: what the default must never ask for."""
+    from sparktorch_tpu.inference import _jit_forward
+
+    monkeypatch.setattr(jax, "device_count", lambda: len(topo.devices))
+    x = jax.ShapeDtypeStruct((16, 512), jnp.float32)
+    module, shapes, mesh = _encoder_over_2x2(topo)
+    text = _jit_forward(module, mesh, shapes, {}).lower(
+        shapes, {}, x).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert "all-gather" not in text and "all-reduce" not in text
+    named, _, _ = _encoder_over_2x2(topo, attn_impl="flash")
+    with pytest.raises(Exception, match="cannot be automatically partitioned"):
+        _jit_forward(named, mesh, shapes, {}).lower(shapes, {}, x).compile()
+
+
+def test_train_init_over_dp4_compiles_for_v5e_2x2(topo, as_tpu, monkeypatch):
+    """The sync trainer's init for BERT-base at rows of 512 over dp=4
+    (a ``jit`` with ``out_shardings`` under the legacy ``with mesh:``,
+    no mesh in sight of the trace either): no kernel is traced into it,
+    and the step's own gauge, asked inside the step's ``shard_map``,
+    reads the kernels."""
+    from sparktorch_tpu.obs import Telemetry
+    from sparktorch_tpu.train.sync import _jit_init, _note_model_gauges
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    monkeypatch.setattr(jax, "device_count", lambda: len(topo.devices))
+    module, _, mesh = _encoder_over_2x2(topo)
+    spec = ModelSpec(module=module, loss="cross_entropy", optimizer="adam",
+                     optimizer_params={"lr": 2e-5}, input_shape=(512,))
+    with mesh:
+        lowered = _jit_init(spec, mesh, jax.random.key(0),
+                            jnp.zeros((1, 512), jnp.float32),
+                            spec.make_optimizer()).lower()
+    assert "tpu_custom_call" not in lowered.as_text()
+    lowered.compile()
+    tele = Telemetry(run_id="init-dp4")
+    _note_model_gauges(tele, module, (512,), mesh)
+    assert tele.gauge_value("train.attention.kernel_layers") == 1
+
+
+def test_dp4_step_at_long_rows_holds_the_kernels_for_v5e_2x2(topo, as_tpu):
+    """The sync trainer's dp=4 step at 8 rows of 512 a chip: inside the
+    step's ``shard_map`` every axis is Manual, each chip's program holds
+    its own three kernels a layer, and the TPU compiler takes it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from sparktorch_tpu.train import step as step_mod
+    from sparktorch_tpu.utils.data import DataBatch
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    module, _, mesh = _encoder_over_2x2(topo)
+    spec = ModelSpec(module=module, loss="cross_entropy", optimizer="adam",
+                     optimizer_params={"lr": 2e-5}, input_shape=(512,))
+    tx = spec.make_optimizer()
+    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(("dp", "fsdp")))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep),
+        jax.eval_shape(lambda: step_mod.create_train_state(
+            spec, jax.random.key(0),
+            sample_x=jnp.zeros((1, 512), jnp.float32), tx=tx)))
+    batch = DataBatch(
+        x=jax.ShapeDtypeStruct((32, 512), jnp.float32, sharding=rows),
+        y=jax.ShapeDtypeStruct((32,), jnp.float32, sharding=rows),
+        w=jax.ShapeDtypeStruct((32,), jnp.float32, sharding=rows))
+    step = step_mod.make_train_step(module.apply, spec.loss_fn(), tx, mesh)
+    counts = kernel_call_counts(step.lower(state, batch).compile().as_text())
+    assert counts == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                      "fused_ce_fwd": 0, "fused_ce_bwd": 0}, counts
+
+
 def test_untileable_shape_raises_on_tpu_backend(one_chip, as_tpu):
     """A caller who asked for the kernel by name gets an error naming
     the shape and the rule on a TPU backend — never a dense program

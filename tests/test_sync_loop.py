@@ -137,7 +137,7 @@ class CountingNet(nn.Module):
         hidden = nn.relu(_Counted(20)(x))
         return _Counted(1)(hidden)
 
-    def train_gauges(self):
+    def train_gauges(self, row_shape):
         return {"train.toy.layers": 2}
 
     def train_counters(self, sown, drop_fraction):
