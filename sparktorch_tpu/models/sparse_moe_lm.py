@@ -1,22 +1,32 @@
 """Decoder LM over grouped-query heads with a dropless mixture of
-experts that is told which experts it holds (Qwen3-MoE's block), under
-one of two attentions, by configuration:
+experts that is told which experts it holds (Qwen3-MoE's block). What a
+layer is belongs to the LAYER (:class:`LayerKind`: its attention, its
+query heads, its rotary table, experts or a dense MLP after it), and a
+model is its list of layers (:class:`SparseMoEConfig` ``layers``). Three
+models are built on it:
 
-- ``attention="learned_sparse"``: DeepSeek-V3.2's lightning indexer in
+- every layer ``learned_sparse``: DeepSeek-V3.2's lightning indexer in
   front of a causal attention over the keys it selects (the language
   model of Keye-VL-2.0-30B-A3B, :func:`keye_vl2_lm`);
-- ``attention="block_diffusion"``: every key a static block mask allows,
+- every layer ``block_diffusion``: every key a static block mask allows,
   for a model trained by masked block diffusion (SDAR-30B-A3B-Chat,
-  :func:`sdar_moe_lm`; "Masked block diffusion" below).
+  :func:`sdar_moe_lm`; "Masked block diffusion" below);
+- ``full`` and ``window`` layers mixed 1:3 with different head counts and
+  rotary tables, a gated attention output, a leading dense layer and a
+  shared expert beside sigmoid-routed ones (Laguna-XS.2,
+  :func:`laguna_lm`; "Layers of several kinds" below).
 
-Shared by both, written once: the projections, q/k norm and rotary step
+Shared by all, written once: the projections, q/k norm and rotary step
 around the attention kernel (``_GroupedQueryProjections``), the expert
-layer (``HeldExperts``, ``held_experts_sum``), ``rms_norm``, the layers'
-remat with the attention kernel's output and row statistics kept, the
+layer (``HeldExperts``, ``held_experts_sum``), ``rms_norm``, a layer's
+remat with its attention kernel's output and row statistics kept, the
 head's padding to the fused cross entropy's tile, the counters and
-gauges. What differs is the attention module (``_ATTENTION``) and, under
-block diffusion, what the model does before its first layer and hands
-back after its last.
+gauges. What differs is the attention module (``_ATTENTION``, by the
+layer's kind) and, under block diffusion, what the model does before its
+first layer and hands back after its last. A model whose layers are all
+alike says its one kind by four fields (``attention``, ``n_heads``,
+``rope_theta``, ``mrope_section``) and builds what it built before
+layers had kinds: the same parameter tree, the same lowered step.
 
 One layer, for the tokens ``x`` of a row, in the published order:
 
@@ -24,6 +34,8 @@ One layer, for the tokens ``x`` of a row, in the published order:
   and ``v`` with fewer heads than ``q``); RMSNorm over each head of
   ``q`` and ``k``; M-RoPE on ``q`` and ``k``: three position ids a
   token, the frequency pairs cut among them in contiguous sections.
+  (The first two models' layer; the third's is under "Layers of several
+  kinds".)
 - Indexer, on ``stop_gradient(h)``: ``qI = h WIq`` (``idx_heads`` of
   ``idx_dim``), ``kI = LayerNorm(h WIk)`` (one key head), ``w = h
   WIw``, rotary by the temporal id on the first ``idx_rope_dims``;
@@ -63,8 +75,9 @@ rematerialised in the backward pass, but for three arrays the forward
 pass keeps: its selected sets, and the attention kernel's output and
 row statistics (``ops.sparse_attention.SAVED_NAMES``: one activation of
 ``[rows, T, heads, head_dim]`` in the compute dtype and 4 bytes a row a
-head, for each layer), so the selection and the attention's forward
-kernel run once a layer a step.
+head, for each layer; each kind of attention names its own, and a
+layer's remat lists its kind's), so the selection and the attention's
+forward kernel run once a layer a step.
 
 Masked block diffusion. The forward pass of the second model is its
 TRAINING forward: a row ``x_0`` of ``L`` ids is noised (one level a row,
@@ -90,21 +103,67 @@ convention), no shift, the loss normalised by the row's length. All
 ``[MASK]`` tokens share one embedding row, so a layer's router sends
 them alike. Generation by denoising is not here.
 
+Layers of several kinds. Layer ``l`` of kind ``a(l)`` in {``full``,
+``window``} with ``H(l)`` query heads (Laguna-XS.2: 48 / 64), 8
+key/value heads, ``d`` = 128; "assumed" marks what the source's config
+names without a shape or a formula (the benchmark's configuration file
+lists each with its reason):
+
+- ``h = RMSNorm(x)``; ``q = h Wq`` (``H(l)`` heads), ``k = h Wk``, ``v =
+  h Wv`` (8 heads), no biases. Assumed: RMSNorm (gain 1 at init) over
+  each head of ``q`` and ``k`` before the rotary step, as above.
+- Rotary by halves on the first ``r(a)`` dims of ``q`` and ``k``, the
+  rest passed through (:class:`Rotary`). ``window``: ``r`` = 128,
+  ``inv_freq_i = 1e4^(-2i / 128)``, ``i < 64``. ``full``: ``r`` = 64
+  (``partial_rotary_factor`` 0.5) and YaRN over ``D`` = 64 dims, ``i <
+  32``: ``e_i = 5e5^(-2i / D)``, ``n_i = e_i / 64``, ``c(b) = D ln(4096 /
+  (2 pi b)) / (2 ln 5e5)``, ``low = max(floor(c(64)), 0)``, ``high =
+  min(ceil(c(1)), D - 1)``, ``ramp_i = clip((i - low) / (high - low), 0,
+  1)``, ``inv_freq_i = n_i ramp_i + e_i (1 - ramp_i)``
+  (:func:`_yarn_inv_freq`); ``cos`` and ``sin`` are multiplied by
+  ``attention_factor`` 1.4158883 (= 0.1 ln 64 + 1), so the rotated dims
+  of ``q`` and ``k`` carry it and the passed dims do not. Assumed: this
+  is Hugging Face's ``rope_type: yarn`` computation.
+- Keys query ``i`` attends: ``full``: ``j <= i``. ``window``: ``j <= i``
+  and ``i - j < 512`` (512 keys with its own). Softmax over them of ``q_i
+  . k_j / sqrt(128)``, times ``v``, by ``ops/rule_attention.py`` under
+  the kind's name (``causal`` / ``window``: :func:`layer_rule`).
+- Output gate (``attn_gate``; the source's ``gating: true``). Assumed:
+  one gate a head a token, ``o_{t,i} <- sigmoid(h_t Wg)_i o_{t,i}`` with
+  ``Wg`` ``[2048, H(l)]`` (N(0, 0.02), no bias), on the normed input
+  ``h``, before ``Wo``.
+- ``x = x + o Wo``; ``g = RMSNorm(x)``.
+- A ``dense`` layer (layer 0): ``x = x + Wd (silu(Wg g) * Wu g)``, width
+  8,192 (:class:`SwiGLU`, scope ``dense_mlp``).
+- An ``experts`` layer: ``s = sigmoid(g Wr)`` over ALL 256 experts
+  (``scoring``); the 8 largest; gates ``2.5 s_e / sum_chosen s``
+  (``routed_scale``); ``x = x + sum_{chosen e held here} gate_e E_e(g) +
+  S(g)``, ``E_e`` and ``S`` SwiGLU of width 512, ``S`` the shared expert
+  (scope ``shared_expert``), added to every token ungated, on every chip
+  alike: it is no share of anything and goes through no router. Assumed:
+  sigmoid scores renormalised over the chosen 8 (the convention the
+  scaling factor comes with); no group limit; the selection bias that
+  convention carries is a buffer, zero here, and is left out; ties to the
+  lower index.
+
 Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
 (rows computed by each held expert), ``row_chunks`` (the chunks the
 loop ran, and the chunks that all chosen pairs would take), ``routed``
 (chosen pairs whose expert is held) and ``dropped`` (those of them that
 the grouped products, chunk by chunk, did not multiply by their own
-expert's weights: 0); under block diffusion also ``masked_tokens`` and
-``tokens`` of the step and, by layer, ``attn_tiles`` (the tiles the
-attention's forward kernel visits, of the whole square's).
+expert's weights: 0), by each layer that holds experts (a dense layer
+sows none); under block diffusion also ``masked_tokens`` and ``tokens``
+of the step and, by layer, ``attn_tiles`` (the tiles the attention's
+forward kernel visits, of the whole square's); by each ``full`` and
+``window`` layer the same count as ``attn_tiles_full`` /
+``attn_tiles_window``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -114,7 +173,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from sparktorch_tpu.ops.block_diffusion_attention import (
     SAVED_NAMES as BLOCKDIFF_SAVED_NAMES, BlockDiffusionMask,
-    block_diffusion_attention, tiles_visited)
+    block_diffusion_attention)
+from sparktorch_tpu.ops.rule_attention import (
+    Causal, CausalWindow, rule_attention, saved_names, tiles_visited)
 from sparktorch_tpu.ops.sparse_attention import SAVED_NAMES, sparse_attention
 from sparktorch_tpu.utils.losses import TokenWeighted
 
@@ -135,44 +196,112 @@ _CE_BLOCK_V = 512
 
 
 @dataclasses.dataclass(frozen=True)
+class Rotary:
+    """One rotary table. The ``n = sum(sections)`` frequency pairs
+    ``theta^(-i / n)`` turn the first ``2 n`` dims of a head by halves
+    (the rest pass through), pair ``i`` with the position id of its
+    section (one section: plain rotary by the token's index). ``yarn``
+    is ``(factor, original_max_positions, beta_fast, beta_slow)``: the
+    frequencies blended as :func:`_yarn_inv_freq` says; ``cos`` and
+    ``sin`` are multiplied by ``attention_factor``."""
+
+    theta: float
+    sections: Tuple[int, ...]
+    yarn: Optional[Tuple[float, float, float, float]] = None
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is: its attention (a key of ``_ATTENTION``), its
+    query heads, its rotary table, and ``"experts"`` or ``"dense"`` after
+    the attention."""
+
+    attention: str
+    n_heads: int
+    rotary: Rotary
+    mlp: str = "experts"
+
+
+@dataclasses.dataclass(frozen=True)
 class SparseMoEConfig:
     vocab_size: int = 151_936
     d_model: int = 2_048
     n_layers: int = 48
-    n_heads: int = 32
     n_kv_heads: int = 4
     head_dim: int = 128
+    rms_eps: float = 1e-6
+    # A model whose layers are all alike says its one kind by these four;
+    # ``layers``, where given, says each layer's and these are not read.
+    # "learned_sparse" (the indexer's selected keys, causal),
+    # "block_diffusion" (the module docstring's second model), "full"
+    # (every causal key) or "window" (the last ``window`` causal keys)
+    attention: str = "learned_sparse"
+    n_heads: int = 32
     rope_theta: float = 1e7
     mrope_section: Tuple[int, ...] = (16, 24, 24)
-    rms_eps: float = 1e-6
+    layers: Tuple[LayerKind, ...] = ()
     idx_heads: int = 16
     idx_dim: int = 64
     idx_rope_dims: int = 32
     topk: int = 2_048
+    block_length: int = 4
+    mask_token_id: int = 151_669
+    noise_eps: float = 1e-3
+    window: int = 512
+    # "full" and "window" layers: one sigmoid gate a head a token on the
+    # attention's output, from the layer's normed input
+    attn_gate: bool = False
     n_routed_experts: int = 128
     experts_held: Tuple[int, ...] = tuple(range(128))
     experts_per_token: int = 8
     expert_width: int = 768
+    # the chosen experts' gates: "softmax" over all routed experts or
+    # "sigmoid" of each, renormalised over the chosen, times routed_scale
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # an expert every token goes through, beside the routed ones (0: none)
+    shared_expert_width: int = 0
+    dense_width: int = 0   # of a "dense" layer's MLP
     compute_dtype: jnp.dtype = jnp.bfloat16
-    # "learned_sparse" (the indexer's selected keys, causal) or
-    # "block_diffusion" (the module docstring's second model)
-    attention: str = "learned_sparse"
-    block_length: int = 4
-    mask_token_id: int = 151_669
-    noise_eps: float = 1e-3
 
     def __post_init__(self):
-        if self.attention not in _ATTENTION:
-            raise ValueError(f"attention {self.attention!r} is none of "
-                             f"{sorted(_ATTENTION)}")
-        if (self.attention == "block_diffusion"
-                and not 0 <= self.mask_token_id < self.vocab_size):
+        if not self.layers:
+            object.__setattr__(self, "layers", (LayerKind(
+                self.attention, self.n_heads,
+                Rotary(self.rope_theta, tuple(self.mrope_section))),)
+                * self.n_layers)
+        if len(self.layers) != self.n_layers:
+            raise ValueError(f"{len(self.layers)} layers are described, "
+                             f"n_layers is {self.n_layers}")
+        for kind in self.layers:
+            if kind.attention not in _ATTENTION:
+                raise ValueError(f"attention {kind.attention!r} is none of "
+                                 f"{sorted(_ATTENTION)}")
+            if not 0 < 2 * sum(kind.rotary.sections) <= self.head_dim:
+                raise ValueError(
+                    f"rotary sections {kind.rotary.sections} do not cut the "
+                    f"{self.head_dim // 2} frequency pairs of a head, or a "
+                    f"leading part of them")
+            if kind.n_heads % self.n_kv_heads:
+                raise ValueError(f"{kind.n_heads} query heads are not a "
+                                 f"multiple of {self.n_kv_heads} key/value "
+                                 f"heads")
+            if kind.mlp not in ("experts", "dense"):
+                raise ValueError(f"mlp {kind.mlp!r} is neither experts nor "
+                                 f"dense")
+        if 0 < self.layers_of("block_diffusion") < self.n_layers:
+            raise ValueError("block diffusion doubles the row for every "
+                             "layer: it is every layer's attention or none's")
+        if not self.dense_width and any(k.mlp == "dense"
+                                        for k in self.layers):
+            raise ValueError("a dense layer's MLP needs dense_width")
+        if self.diffusion and not 0 <= self.mask_token_id < self.vocab_size:
             raise ValueError(f"mask_token_id {self.mask_token_id} is no row "
                              f"of a vocabulary of {self.vocab_size}")
-        if sum(self.mrope_section) * 2 != self.head_dim:
-            raise ValueError(
-                f"mrope_section {self.mrope_section} does not cut the "
-                f"{self.head_dim // 2} frequency pairs of a head")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r} is neither softmax "
+                             f"nor sigmoid")
         held = tuple(self.experts_held)
         if (len(set(held)) != len(held) or not held
                 or not all(0 <= e < self.n_routed_experts for e in held)):
@@ -180,6 +309,14 @@ class SparseMoEConfig:
                              f"{self.n_routed_experts}")
         if self.experts_per_token > self.n_routed_experts:
             raise ValueError("more experts a token than routed experts")
+
+    def layers_of(self, attention: str) -> int:
+        return sum(k.attention == attention for k in self.layers)
+
+    @property
+    def diffusion(self) -> bool:
+        """Whether the model trains by masked block diffusion."""
+        return self.layers_of("block_diffusion") == self.n_layers
 
 
 def _normal(stddev=0.02):
@@ -194,21 +331,50 @@ def rms_norm(x, gain, eps):
 
 def _rotate(x, cos, sin):
     """Rotary by halves (the pair of frequency ``i`` is dims ``i`` and
-    ``i + n/2``) on the last axis; ``cos``/``sin`` broadcast over heads."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    ``i + n/2``) on the first ``n = 2 * cos.shape[-1]`` dims of the last
+    axis, the rest passed through; ``cos``/``sin`` broadcast over heads."""
+    half = cos.shape[-1]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if 2 * half < x.shape[-1]:
+        parts.append(x[..., 2 * half:])
+    return jnp.concatenate(parts, -1)
 
 
 def _inv_freq(n_pairs: int, theta: float):
     return theta ** (-jnp.arange(n_pairs, dtype=jnp.float32) / n_pairs)
 
 
-def mrope_angles(position_ids, cfg: SparseMoEConfig):
-    """``[b, T, head_dim / 2]`` angles: frequency pair ``i`` turns with
-    the position id of its section (temporal, height, width)."""
-    inv = _inv_freq(cfg.head_dim // 2, cfg.rope_theta)
-    section = np.repeat(np.arange(len(cfg.mrope_section)), cfg.mrope_section)
+def _yarn_inv_freq(n_pairs: int, theta: float, factor: float,
+                   original_max: float, beta_fast: float, beta_slow: float):
+    """YaRN's frequencies over ``D = 2 n_pairs`` dims (Hugging Face's
+    ``rope_type: yarn``, ``truncate`` as default): ``e_i = theta^(-2i /
+    D)`` extrapolates, ``e_i / factor`` interpolates, and pair ``i`` takes
+    ``ramp_i`` of the second, where ``c(b) = D ln(original_max / (2 pi b))
+    / (2 ln theta)`` is the pair that turns ``b`` times over the original
+    context, ``low = max(floor(c(beta_fast)), 0)``, ``high =
+    min(ceil(c(beta_slow)), D - 1)`` and ``ramp_i = clip((i - low) / (high
+    - low), 0, 1)``. Static, float64 on the host, rounded once."""
+    dims = 2 * n_pairs
+    pair = np.arange(n_pairs, dtype=np.float64)
+    extrapolated = theta ** (-2.0 * pair / dims)
+    turns = lambda b: (dims * np.log(original_max / (2 * np.pi * b))
+                       / (2 * np.log(theta)))
+    low = max(np.floor(turns(beta_fast)), 0.0)
+    high = min(np.ceil(turns(beta_slow)), dims - 1.0)
+    ramp = np.clip((pair - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(extrapolated / factor * ramp
+                       + extrapolated * (1.0 - ramp), jnp.float32)
+
+
+def rotary_angles(position_ids, rotary: Rotary):
+    """``[b, T, pairs]`` angles of ``rotary``'s table: frequency pair
+    ``i`` turns with the position id of its section (M-RoPE's temporal,
+    height, width; one section: the first id alone)."""
+    n_pairs = sum(rotary.sections)
+    inv = (_inv_freq(n_pairs, rotary.theta) if rotary.yarn is None
+           else _yarn_inv_freq(n_pairs, rotary.theta, *rotary.yarn))
+    section = np.repeat(np.arange(len(rotary.sections)), rotary.sections)
     pos = position_ids.astype(jnp.float32)[section]
     return jnp.moveaxis(pos, 0, -1) * inv  # [3->pairs, b, T] -> [b, T, pairs]
 
@@ -315,11 +481,13 @@ def selected_keys(q_idx, k_idx, w, topk: int, chunk: int):
 
 
 class _GroupedQueryProjections(nn.Module):
-    """What both attentions do around their kernels: ``q``, ``k``, ``v``
-    without biases, RMSNorm over each head of ``q`` and ``k``, the rotary
-    step; and the output projection."""
+    """What every attention does around its kernels: ``q`` (the layer's
+    own number of heads), ``k``, ``v`` without biases, RMSNorm over each
+    head of ``q`` and ``k``, the rotary step of the layer's table; and
+    the output projection."""
 
     config: SparseMoEConfig
+    kind: LayerKind
 
     def _dense(self, name, shape):
         return self.param(name, _normal(), shape)
@@ -331,20 +499,23 @@ class _GroupedQueryProjections(nn.Module):
 
     def _qkv(self, h, angles):
         cfg, d, hd = self.config, h.shape[-1], self.config.head_dim
-        q = self._proj(h, self._dense("wq", (d, cfg.n_heads, hd)))
+        q = self._proj(h, self._dense("wq", (d, self.kind.n_heads, hd)))
         k = self._proj(h, self._dense("wk", (d, cfg.n_kv_heads, hd)))
         v = self._proj(h, self._dense("wv", (d, cfg.n_kv_heads, hd)))
         ones = nn.initializers.ones
         q = rms_norm(q, self.param("q_norm", ones, (hd,)), cfg.rms_eps)
         k = rms_norm(k, self.param("k_norm", ones, (hd,)), cfg.rms_eps)
         cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
+        factor = self.kind.rotary.attention_factor
+        if factor != 1.0:  # on the rotated dims alone
+            cos, sin = factor * cos, factor * sin
         return _rotate(q, cos, sin), _rotate(k, cos, sin), v
 
     def _out(self, o, d):
         cfg, dt = self.config, self.config.compute_dtype
         return jnp.einsum(
             "bthk,hkd->btd", o,
-            self._dense("wo", (cfg.n_heads, cfg.head_dim, d)).astype(dt),
+            self._dense("wo", (self.kind.n_heads, cfg.head_dim, d)).astype(dt),
             preferred_element_type=jnp.float32)
 
 
@@ -367,7 +538,7 @@ class SparseAttention(_GroupedQueryProjections):
                 cfg.idx_heads ** -0.5 * cfg.idx_dim ** -0.5)
             r = cfg.idx_rope_dims
             ang = temporal.astype(jnp.float32)[..., None] * _inv_freq(
-                r // 2, cfg.rope_theta)
+                r // 2, self.kind.rotary.theta)
             c_i, s_i = jnp.cos(ang), jnp.sin(ang)
             q_i = jnp.concatenate(
                 [_rotate(q_i[..., :r], c_i[:, :, None], s_i[:, :, None]),
@@ -404,17 +575,61 @@ class BlockDiffusionAttention(_GroupedQueryProjections):
         return self._out(o, d)
 
 
-# attention by configuration: the module, and what its forward pass
-# names for the layers' remat policy to keep
+# a kind of layer whose attention is a static causal rule -> what its
+# kernels, its saved arrays and its scope are called in a trace
+_RULE_NAMES = {"full": "causal", "window": "window"}
+
+
+def layer_rule(cfg: SparseMoEConfig, kind: LayerKind):
+    """The static rule of a ``full`` or ``window`` layer's attention."""
+    return Causal() if kind.attention == "full" else CausalWindow(cfg.window)
+
+
+class RuleAttention(_GroupedQueryProjections):
+    """Every key a static causal rule keeps (``full``: all of them,
+    ``window``: the last ``window``), by the kernels of
+    ``ops/rule_attention.py`` under the kind's name, and where the
+    configuration says so a gate on the output: one sigmoid a head a
+    token, from the layer's normed input, before ``Wo``."""
+
+    @nn.compact
+    def __call__(self, h, angles, temporal):
+        cfg, dt = self.config, self.config.compute_dtype
+        b, t, d = h.shape
+        del temporal
+        name = _RULE_NAMES[self.kind.attention]
+        q, k, v = self._qkv(h, angles)
+        rule = layer_rule(cfg, self.kind)
+        visited, total = tiles_visited(rule, t)
+        self.sow("moe_metrics", f"attn_tiles_{self.kind.attention}",
+                 b * cfg.n_kv_heads
+                 * jnp.asarray([visited, total], jnp.float32))
+        with jax.named_scope(f"{name}_attention"):
+            o = rule_attention(q.astype(dt), k.astype(dt), v.astype(dt),
+                               rule, name)
+        if cfg.attn_gate:
+            gate = jax.nn.sigmoid(self._proj(
+                h, self._dense("wg", (d, self.kind.n_heads))))
+            o = (o * gate[..., None]).astype(dt)
+        return self._out(o, d)
+
+
+# attention by kind of layer: the module, and what its forward pass
+# names for the layer's remat policy to keep
 _ATTENTION = {
     "learned_sparse": (SparseAttention, (_MASK_NAME, *SAVED_NAMES)),
     "block_diffusion": (BlockDiffusionAttention, BLOCKDIFF_SAVED_NAMES),
+    **{kind: (RuleAttention, saved_names(name))
+       for kind, name in _RULE_NAMES.items()},
 }
 
 
 class HeldExperts(nn.Module):
     """The experts of ``experts_held`` out of a router over all of
-    ``n_routed_experts``: this chip's part of the layer's result."""
+    ``n_routed_experts``: this chip's part of the layer's result. A
+    token's scores are the configuration's (``scoring``: the softmax
+    over all routed experts, or each expert's own sigmoid); its gates are
+    the chosen scores renormalised to sum 1, times ``routed_scale``."""
 
     config: SparseMoEConfig
 
@@ -433,13 +648,16 @@ class HeldExperts(nn.Module):
                 self.param("router", _normal(),
                            (d, cfg.n_routed_experts)).astype(dt),
                 preferred_element_type=jnp.float32)
-            probs = jax.nn.softmax(logits, -1)
+            probs = (jax.nn.softmax(logits, -1) if cfg.scoring == "softmax"
+                     else jax.nn.sigmoid(logits))
             top_e = jax.lax.top_k(jax.lax.stop_gradient(probs), k)[1]
             # the chosen probabilities by a one-hot product, whose
             # transpose is dense (top_k's own is a batched scatter)
             top_p = jnp.einsum("nke,ne->nk", jax.nn.one_hot(
                 top_e, cfg.n_routed_experts, dtype=probs.dtype), probs)
             gates = top_p / jnp.sum(top_p, -1, keepdims=True)
+            if cfg.routed_scale != 1.0:
+                gates = cfg.routed_scale * gates
             # local id of each chosen expert, n_held for one held elsewhere
             local = np.full((cfg.n_routed_experts,), n_held, np.int32)
             local[list(held)] = np.arange(n_held)
@@ -641,19 +859,51 @@ def pairs_covered(sorted_expert, group_sizes):
                    dtype=jnp.float32)
 
 
+class SwiGLU(nn.Module):
+    """``Wd (silu(Wg g) * Wu g)`` for every token, under a scope of its
+    own: a dense layer's MLP, or the expert every token goes through."""
+
+    config: SparseMoEConfig
+    width: int
+    traced_as: str   # the scope's name
+
+    @nn.compact
+    def __call__(self, g):
+        dt, d = self.config.compute_dtype, g.shape[-1]
+        w = lambda name, shape: self.param(name, _normal(), shape).astype(dt)
+        with jax.named_scope(self.traced_as):
+            x = g.astype(dt)
+            a, b = (jnp.einsum("btd,df->btf", x, w(name, (d, self.width)),
+                               preferred_element_type=jnp.float32)
+                    for name in ("w_gate", "w_up"))
+            return jnp.einsum("btf,fd->btd", (jax.nn.silu(a) * b).astype(dt),
+                              w("w_down", (self.width, d)),
+                              preferred_element_type=jnp.float32)
+
+
 class DecoderLayer(nn.Module):
     config: SparseMoEConfig
+    kind: LayerKind
 
     @nn.compact
     def __call__(self, x, angles, temporal):
-        cfg = self.config
+        cfg, kind = self.config, self.kind
         ones = nn.initializers.ones
         d = x.shape[-1]
         h = rms_norm(x, self.param("attn_norm", ones, (d,)), cfg.rms_eps)
-        attention, _ = _ATTENTION[cfg.attention]
-        x = x + attention(cfg, name="attn")(h, angles, temporal)
+        attention, _ = _ATTENTION[kind.attention]
+        x = x + attention(cfg, kind, name="attn")(h, angles, temporal)
+        if kind.mlp == "dense":
+            g = rms_norm(x, self.param("mlp_norm", ones, (d,)), cfg.rms_eps)
+            return x + SwiGLU(cfg, cfg.dense_width, "dense_mlp",
+                              name="mlp")(g)
         g = rms_norm(x, self.param("moe_norm", ones, (d,)), cfg.rms_eps)
-        return x + HeldExperts(cfg, name="moe")(g)
+        x = x + HeldExperts(cfg, name="moe")(g)
+        if cfg.shared_expert_width:
+            # on every chip alike: no share of it, no gate
+            x = x + SwiGLU(cfg, cfg.shared_expert_width, "shared_expert",
+                           name="shared")(g)
+        return x
 
 
 class SparseMoELM(nn.Module):
@@ -678,19 +928,27 @@ class SparseMoELM(nn.Module):
         """The flax streams the training forward draws from, for the
         step that hands them over (``train/step.py``): block diffusion's
         noise, and nothing for the deterministic model."""
-        return ((NOISE_STREAM,) if self.config.attention == "block_diffusion"
-                else ())
+        return (NOISE_STREAM,) if self.config.diffusion else ()
 
     def train_gauges(self, row_shape) -> dict:
         """What the trainers put on the bus when they build a step (for
-        rows of ``row_shape``, which changes nothing here)."""
+        rows of ``row_shape``, which changes nothing here): the experts
+        held and routed, and what each kind of attention among the
+        layers says of itself."""
         cfg = self.config
         gauges = {"train.moe.experts_held": len(cfg.experts_held),
                   "train.moe.experts_routed": cfg.n_routed_experts}
-        if cfg.attention == "block_diffusion":
-            return {"train.diffusion.block_length": cfg.block_length,
-                    **gauges}
-        return {"train.sparse_attn.topk": cfg.topk, **gauges}
+        if cfg.shared_expert_width:
+            gauges["train.moe.shared_width"] = cfg.shared_expert_width
+        if cfg.layers_of("learned_sparse"):
+            gauges["train.sparse_attn.topk"] = cfg.topk
+        if cfg.diffusion:
+            gauges["train.diffusion.block_length"] = cfg.block_length
+        if cfg.layers_of("window"):
+            gauges["train.attention.window"] = cfg.window
+        gauges.update({f"train.attention.layers_{kind}": cfg.layers_of(kind)
+                       for kind in _RULE_NAMES if cfg.layers_of(kind)})
+        return gauges
 
     def train_counters(self, sown: dict, drop_fraction) -> tuple:
         """What the counters sown in one step mean, to the trainer that
@@ -706,7 +964,10 @@ class SparseMoELM(nn.Module):
         step's ``masked_tokens`` of its ``tokens`` (the rows' own, not
         the doubled sequence's), and from ``attn_tiles``, ``[layers,
         2]``: the tiles the attention's forward kernel visited of the
-        tiles of the whole square, over rows and key/value heads."""
+        tiles of the whole square, over rows and key/value heads; from
+        ``attn_tiles_<kind>``, ``[layers of that kind, 2]``, the same by
+        kind of layer. A layer sows what it has: a dense layer no expert
+        counter, so no array here has a row a layer of the model."""
         by_expert, chunks = sown["expert_rows"], sown["row_chunks"]
         rows, f = float(by_expert.sum()), float(drop_fraction or 0.0)
         fields = dict(
@@ -731,6 +992,12 @@ class SparseMoELM(nn.Module):
             gauges.update({
                 "train.diffusion.attn_tiles_visited": float(tiles[0]),
                 "train.diffusion.attn_tiles_total": float(tiles[1])})
+        for kind in _RULE_NAMES:
+            if f"attn_tiles_{kind}" in sown:
+                tiles = sown[f"attn_tiles_{kind}"].sum(0)
+                gauges.update({
+                    f"train.attention.{kind}_tiles_visited": float(tiles[0]),
+                    f"train.attention.{kind}_tiles_total": float(tiles[1])})
         return fields, counters, gauges
 
     def _noise_key(self):
@@ -760,7 +1027,7 @@ class SparseMoELM(nn.Module):
         b, t = ids.shape
         if position_ids is None:
             position_ids = jnp.broadcast_to(jnp.arange(t), (3, b, t))
-        diffusion = cfg.attention == "block_diffusion"
+        diffusion = cfg.diffusion
         if diffusion:
             with jax.named_scope("diffusion_noise"):
                 level, masked = diffusion_noise(
@@ -771,15 +1038,22 @@ class SparseMoELM(nn.Module):
             self.sow("moe_metrics", "masked_tokens",
                      jnp.sum(masked, dtype=jnp.float32))
             self.sow("moe_metrics", "tokens", jnp.float32(b * t))
-        angles, temporal = mrope_angles(position_ids, cfg), position_ids[0]
+        # one table of angles a rotary table among the layers, and one
+        # rematerialised layer class a set of names an attention keeps
+        angles = {rotary: rotary_angles(position_ids, rotary)
+                  for rotary in dict.fromkeys(k.rotary for k in cfg.layers)}
+        temporal = position_ids[0]
         x = self.param("embed", _normal(),
                        (cfg.vocab_size, cfg.d_model))[ids]
-        layer = nn.remat(
+        remat = {kept: nn.remat(
             DecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *_ATTENTION[cfg.attention][1]))
-        for i in range(cfg.n_layers):
-            x = layer(cfg, name=f"layer_{i}")(x, angles, temporal)
+            policy=jax.checkpoint_policies.save_only_these_names(*kept))
+            for kept in dict.fromkeys(
+                _ATTENTION[k.attention][1] for k in cfg.layers)}
+        for i, kind in enumerate(cfg.layers):
+            layer = remat[_ATTENTION[kind.attention][1]]
+            x = layer(cfg, kind, name=f"layer_{i}")(
+                x, angles[kind.rotary], temporal)
         if diffusion:
             x = x[:, t:]  # the clean half's last output enters nothing
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
@@ -812,13 +1086,28 @@ def diffusion_noise(key, rows: int, seq_len: int, eps: float):
     return level, jax.random.uniform(k_mask, (rows, seq_len)) < level
 
 
+def _layer_kind(kind) -> LayerKind:
+    """A :class:`LayerKind`, or its fields as a configuration file's
+    object (``rotary`` an object of :class:`Rotary`'s fields)."""
+    if isinstance(kind, LayerKind):
+        return kind
+    rotary = dict(kind["rotary"])
+    for key in ("sections", "yarn"):
+        if rotary.get(key) is not None:
+            rotary[key] = tuple(rotary[key])
+    return LayerKind(**{**kind, "rotary": Rotary(**rotary)})
+
+
 def _coerced(overrides: dict) -> dict:
-    """A configuration file's lists and dtype names as the fields' types."""
+    """A configuration file's lists, objects and dtype names as the
+    fields' types."""
     for key in ("mrope_section", "experts_held"):
         if key in overrides:
             overrides[key] = tuple(overrides[key])
     if isinstance(overrides.get("compute_dtype"), str):
         overrides["compute_dtype"] = jnp.dtype(overrides["compute_dtype"])
+    if "layers" in overrides:
+        overrides["layers"] = tuple(map(_layer_kind, overrides["layers"]))
     return overrides
 
 
@@ -838,3 +1127,31 @@ def sdar_moe_lm(**overrides) -> SparseMoELM:
     return SparseMoELM(SparseMoEConfig(**{
         "attention": "block_diffusion", "rope_theta": 1e6,
         "mrope_section": (64,), **_coerced(overrides)}))
+
+
+def laguna_lm(**overrides) -> SparseMoELM:
+    """Laguna-XS.2 at its published sizes: 40 layers of hidden 2,048 over
+    8 key/value heads of 128; every fourth layer from the first attends
+    every causal key with 48 query heads (rotary on 64 of the 128 dims,
+    theta 5e5, YaRN by 64 over 4,096 positions), the others a window of
+    512 with 64 (plain rotary, theta 1e4, all dims); a gate on every
+    attention's output; the first layer's MLP dense (8,192), the others'
+    256 experts of 512, 8 a token by sigmoid scores renormalised and
+    scaled by 2.5, beside one shared expert of 512; vocabulary 100,352.
+    ``overrides`` as for :func:`keye_vl2_lm`; ``layers`` (each a
+    :class:`LayerKind` or its fields as a dict), where given, replaces
+    the published pattern, which is otherwise cut to ``n_layers``."""
+    overrides = _coerced(overrides)
+    full = Rotary(5e5, (32,), (64.0, 4_096.0, 64.0, 1.0), 1.4158883083359672)
+    window = Rotary(1e4, (64,))
+    n_layers = overrides.get("n_layers", 40)
+    return SparseMoELM(SparseMoEConfig(**{
+        "vocab_size": 100_352, "n_layers": n_layers, "n_kv_heads": 8,
+        "layers": tuple(
+            LayerKind("window", 64, window) if i % 4 else
+            LayerKind("full", 48, full, "experts" if i else "dense")
+            for i in range(n_layers)),
+        "window": 512, "attn_gate": True, "n_routed_experts": 256,
+        "experts_held": tuple(range(256)), "expert_width": 512,
+        "scoring": "sigmoid", "routed_scale": 2.5,
+        "shared_expert_width": 512, "dense_width": 8_192, **overrides}))

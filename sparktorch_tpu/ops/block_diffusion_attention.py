@@ -1,5 +1,5 @@
-"""Attention under a mask that is a static rule of the two indices, in
-Pallas for TPU — forward and backward, grouped-query heads.
+"""The mask of masked block diffusion, as a rule for the kernels of
+``ops/rule_attention.py``.
 
 Masked block diffusion trains on a row that holds a sequence twice: the
 ``L`` clean tokens ``x_0`` and then their noised copy ``x_t``, ``T = 2L``
@@ -12,59 +12,29 @@ tokens, ``blk(i) = (i mod L) // b`` and ``noisy(i) = i >= L``, query
   strictly before its block), or
 - both are noisy and ``blk(j) == blk(i)`` (its own block, both ways).
 
-A clean query attends no noisy key. The mask is the same for every row,
-head and layer, and is not causal: a query sees up to ``b - 1`` keys
-after itself. As an array it would be ``T x T`` bytes a row (268 MB at
-16,384 tokens); here it is never an array. The kernels evaluate the rule
-on a tile's own indices (a few integer operations on a column and a row
-of indices, one comparison a pair), and they visit only the tiles in
-which the rule keeps a pair: the rule is evaluated once on the host, tile
-by tile, into two static tables of ``(Q tile, K tile)`` visits, in Q-major
-order for the forward and dq kernels and in K-major order for dkv. The
-grid's last axis runs over a table, which reaches the kernel and its
-block index maps by scalar prefetch; a tile the rule leaves empty costs
-nothing, not even a skipped grid step (at ``L`` = 8,192 and ``b`` = 4, 576
-of 2,048 tiles of 256 x 512 are visited and 89% of the pairs in them are
-kept). Any hashable callable with :class:`BlockDiffusionMask`'s contract
-will do as the rule: a sliding window or document boundaries are rules
-of a line or two.
+A clean query attends no noisy key. The mask is not causal: a query sees
+up to ``b - 1`` keys after itself. It is the third of the three rules
+the kernels have (``rule_attention.Causal`` and ``CausalWindow`` are the
+others): at ``L`` = 8,192 and ``b`` = 4, 576 of 2,048 tiles of 256 x 512
+are visited and 89% of the pairs in them are kept.
 
-Shared with ``ops/sparse_attention.py``, which holds them: the layout
-(``q`` as ``[b, kv_heads, G, T, d]``, one grid step serving the ``G``
-query heads of a key/value head from one K and one V tile), the tile
-sizes, the streaming-softmax body and both backward bodies (``fwd_tile``,
-``dq_tile``, ``dkv_tile``: the mask comes from a tile of an array there
-and from the rule here), the ``_NEG`` convention (a masked score is a
-large negative finite number and a masked probability exactly 0), the
-row statistics the backward kernels read, and the names the forward rule
-gives its output and row statistics for a caller's remat policy
-(:data:`SAVED_NAMES`, as ``sparse_attention.SAVED_NAMES``), so that the
-forward kernel runs once a layer a step.
-
-``pallas_call`` names: ``blockdiff_attn_fwd``, ``blockdiff_attn_bwd_dq``,
-``blockdiff_attn_bwd_dkv``. Off the TPU they run in interpret mode. A
-shape that does not tile, or a rule that leaves a whole row of tiles
-empty, is an error everywhere: there is no dense path.
+:func:`block_diffusion_attention` is ``rule_attention`` under the name
+``blockdiff``: its kernels are ``blockdiff_attn_fwd``,
+``blockdiff_attn_bwd_dq`` and ``blockdiff_attn_bwd_dkv`` in a trace, and
+its forward rule names :data:`SAVED_NAMES` for a caller's remat policy.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.ad_checkpoint import checkpoint_name
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from sparktorch_tpu.ops.sparse_attention import (
-    _LANES, _blocks, _heads_first, _heads_last, _interpret, dkv_tile,
-    dq_tile, fwd_finalize, fwd_init, fwd_tile, row_statistics)
+from sparktorch_tpu.ops.rule_attention import rule_attention, saved_names
 
+_NAME = "blockdiff"
 # what the forward rule names for a caller's remat policy
-SAVED_NAMES = ("blockdiff_attn_out", "blockdiff_attn_lse")
+SAVED_NAMES = saved_names(_NAME)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,227 +60,9 @@ class BlockDiffusionMask:
                 | (n_i & n_j & (b_j == b_i)))
 
 
-@functools.lru_cache(maxsize=None)
-def visited_tiles(rule, t: int, block_q: int, block_k: int):
-    """The tiles of ``[t, t]`` in which ``rule`` keeps a pair, as int32
-    arrays ``(q tile, k tile)`` of equal length: in Q-major order, and
-    the same visits in K-major order."""
-    n_q, n_k = t // block_q, t // block_k
-    cols = np.arange(t, dtype=np.int32)[None, :]
-    kept = np.stack([
-        rule(qi * block_q + np.arange(block_q, dtype=np.int32)[:, None], cols)
-        .reshape(block_q, n_k, block_k).any((0, 2)) for qi in range(n_q)])
-    if not (kept.any(0).all() and kept.any(1).all()):
-        raise ValueError(f"{rule} leaves a whole row of {block_q} x "
-                         f"{block_k} tiles empty at {t} tokens")
-    q_major = np.argwhere(kept).astype(np.int32)
-    k_major = q_major[np.lexsort((q_major[:, 0], q_major[:, 1]))]
-    return (q_major[:, 0], q_major[:, 1]), (k_major[:, 0], k_major[:, 1])
-
-
-def tiles_visited(rule, t: int) -> tuple:
-    """``(tiles the kernels visit, tiles of the whole square)`` at ``t``
-    tokens."""
-    block_q, block_k = _blocks(t)
-    (qt, _), _ = visited_tiles(rule, t, block_q, block_k)
-    return len(qt), (t // block_q) * (t // block_k)
-
-
-def _visit(major_ref, v, n_visits):
-    """Whether visit ``v`` is the first and the last of its run in the
-    table's major column."""
-    here = major_ref[v]
-    first = (v == 0) | (major_ref[jnp.maximum(v - 1, 0)] != here)
-    last = (v == n_visits - 1) | (
-        major_ref[jnp.minimum(v + 1, n_visits - 1)] != here)
-    return first, last
-
-
-def _keep(rule, qi, ki, block_q, block_k):
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, 1), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    return rule(rows, cols)
-
-
-def _fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, rule, scale, block_q, block_k,
-                n_visits, groups):
-    v = pl.program_id(2)
-    first, last = _visit(qt_ref, v, n_visits)
-
-    @pl.when(first)
-    def _init():
-        fwd_init(acc_ref, m_ref, l_ref)
-
-    keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k)
-    fwd_tile(q_ref, k_ref[...], v_ref[...], keep, acc_ref, m_ref, l_ref,
-             scale, groups)
-
-    @pl.when(last)
-    def _finalize():
-        fwd_finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups)
-
-
-def _bwd_dq_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   d_ref, dq_ref, dq_acc, *, rule, scale, block_q, block_k,
-                   n_visits, groups):
-    v = pl.program_id(2)
-    first, last = _visit(qt_ref, v, n_visits)
-
-    @pl.when(first)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k)
-    dq_tile(q_ref, k_ref[...], v_ref[...], keep, do_ref, lse_ref, d_ref,
-            dq_acc, scale, groups)
-
-    @pl.when(last)
-    def _finalize():
-        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, rule, scale,
-                    block_q, block_k, n_visits, groups):
-    v = pl.program_id(2)
-    first, last = _visit(kt_ref, v, n_visits)
-
-    @pl.when(first)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k)
-    dkv_tile(q_ref, k_ref[...], v_ref[...], keep, do_ref, lse_ref, d_ref,
-             dk_acc, dv_acc, scale, groups)
-
-    @pl.when(last)
-    def _finalize():
-        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _specs(groups, d, block_q, block_k):
-    """Block specs of the operands every kernel shares, on the grid
-    ``(b, kv head, visit)`` with the table prefetched."""
-    q_spec = pl.BlockSpec(
-        (None, None, groups, block_q, d),
-        lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, block_k, d), lambda b, h, v, qt, kt: (b, h, kt[v], 0))
-    row_spec = pl.BlockSpec(
-        (None, None, groups, block_q, _LANES),
-        lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
-    return q_spec, kv_spec, row_spec
-
-
-def _call(kernel, name, table, shape, out_shape, in_specs, out_specs,
-          scratch_shapes, operands, **static):
-    """One kernel over ``table``'s visits for every row and key/value
-    head."""
-    b, hkv, groups, t, d = shape
-    block_q, block_k = _blocks(t)
-    qt, kt = table
-    return pl.pallas_call(
-        functools.partial(kernel, scale=d ** -0.5, block_q=block_q,
-                          block_k=block_k, n_visits=len(qt), groups=groups,
-                          **static),
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, hkv, len(qt)),
-            in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=scratch_shapes),
-        interpret=_interpret(),
-        name=name,
-    )(jnp.asarray(qt), jnp.asarray(kt), *operands)
-
-
-def _fwd(rule, q5, k4, v4):
-    b, hkv, groups, t, d = q5.shape
-    block_q, block_k = _blocks(t)
-    q_major, _ = visited_tiles(rule, t, block_q, block_k)
-    q_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k)
-    return _call(
-        _fwd_kernel, "blockdiff_attn_fwd", q_major, q5.shape,
-        [jax.ShapeDtypeStruct(q5.shape, q5.dtype),
-         jax.ShapeDtypeStruct((b, hkv, groups, t, _LANES), jnp.float32)],
-        [q_spec, kv_spec, kv_spec], [q_spec, row_spec],
-        [pltpu.VMEM((groups, block_q, d), jnp.float32),
-         pltpu.VMEM((groups, block_q, _LANES), jnp.float32),
-         pltpu.VMEM((groups, block_q, _LANES), jnp.float32)],
-        (q5, k4, v4), rule=rule)
-
-
-def _bwd(rule, q5, k4, v4, o5, lse, do5):
-    b, hkv, groups, t, d = q5.shape
-    block_q, block_k = _blocks(t)
-    q_major, k_major = visited_tiles(rule, t, block_q, block_k)
-    lse, di = row_statistics(o5, lse, do5)
-    q_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k)
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
-    operands = (q5, k4, v4, do5, lse, di)
-    dq5 = _call(
-        _bwd_dq_kernel, "blockdiff_attn_bwd_dq", q_major, q5.shape,
-        jax.ShapeDtypeStruct(q5.shape, q5.dtype), in_specs, q_spec,
-        [pltpu.VMEM((groups, block_q, d), jnp.float32)], operands,
-        rule=rule)
-    dk4, dv4 = _call(
-        _bwd_dkv_kernel, "blockdiff_attn_bwd_dkv", k_major, q5.shape,
-        [jax.ShapeDtypeStruct(k4.shape, k4.dtype),
-         jax.ShapeDtypeStruct(v4.shape, v4.dtype)], in_specs,
-        [kv_spec, kv_spec],
-        [pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)], operands, rule=rule)
-    return dq5, dk4, dv4
-
-
-def _check(q, k, v):
-    b, t, hq, d = q.shape
-    hkv = k.shape[2]
-    if k.shape != v.shape or k.shape[:2] != (b, t) or k.shape[3] != d:
-        raise ValueError(f"block_diffusion_attention: q {q.shape}, k "
-                         f"{k.shape}, v {v.shape} do not go together")
-    if hq % hkv:
-        raise ValueError(f"block_diffusion_attention: {hq} query heads are "
-                         f"not a multiple of {hkv} key/value heads")
-    if d % _LANES or t % _LANES:
-        raise ValueError(
-            f"block_diffusion_attention: seq {t} x head_dim {d} cannot be "
-            f"tiled: both must be multiples of {_LANES}")
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def block_diffusion_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               rule) -> jax.Array:
     """``softmax`` attention of each query over the keys ``rule`` keeps
-    for it. ``q`` is ``[b, T, heads, d]``, ``k`` and ``v`` are ``[b, T,
-    kv_heads, d]`` (query head ``i`` reads key/value head ``i // (heads
-    // kv_heads)``); ``rule`` is static, a :class:`BlockDiffusionMask`
-    for ``T`` tokens or a callable like it."""
-    return _forward(q, k, v, rule)[0]
-
-
-def _forward(q, k, v, rule):
-    _check(q, k, v)
-    hkv = k.shape[2]
-    q5 = _heads_first(q, hkv)
-    k4, v4 = (jnp.swapaxes(x, 1, 2) for x in (k, v))
-    o5, lse = _fwd(rule, q5, k4, v4)
-    o5 = checkpoint_name(o5, SAVED_NAMES[0])
-    # one lane of the row statistics, as ``sparse_attention`` keeps them
-    lse = checkpoint_name(lse[..., 0], SAVED_NAMES[1])
-    return _heads_last(o5), (q5, k4, v4, o5, lse)
-
-
-def _bwd_rule(rule, res, g):
-    q5, k4, v4, o5, lse = res
-    dq5, dk4, dv4 = _bwd(rule, q5, k4, v4, o5, lse,
-                         _heads_first(g.astype(q5.dtype), k4.shape[1]))
-    return (_heads_last(dq5), jnp.swapaxes(dk4, 1, 2),
-            jnp.swapaxes(dv4, 1, 2))
-
-
-block_diffusion_attention.defvjp(_forward, _bwd_rule)
+    for it (``rule_attention``'s shapes); ``rule`` is static, a
+    :class:`BlockDiffusionMask` for ``T`` tokens or a callable like it."""
+    return rule_attention(q, k, v, rule, _NAME)

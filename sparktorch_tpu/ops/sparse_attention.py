@@ -90,7 +90,7 @@ def fwd_tile(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale, groups):
     """One K/V tile into the running maximum, sum and output of the
     ``groups`` query heads of a Q tile. ``keep`` is the tile's mask,
     from wherever the kernel has it: this body and the two below are
-    shared with ``ops/block_diffusion_attention.py``."""
+    shared with ``ops/rule_attention.py``."""
     for g in range(groups):
         s = _scores(q_ref[g], k, keep, scale)
         m_prev, l_prev = m_ref[g][:, :1], l_ref[g][:, :1]

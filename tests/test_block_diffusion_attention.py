@@ -1,4 +1,5 @@
-"""``ops/block_diffusion_attention.py`` in interpret mode against dense
+"""``ops/block_diffusion_attention.py``'s rule through the kernels of
+``ops/rule_attention.py`` in interpret mode against dense
 masked float32 attention: forward and the three gradients, one and
 eight query heads a key/value head, rows of one, two and three tiles,
 blocks of 4 tokens and of a whole tile; the static tile tables against
@@ -10,7 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from sparktorch_tpu.ops import block_diffusion_attention as mod
+from sparktorch_tpu.ops import rule_attention as mod
 from sparktorch_tpu.ops.block_diffusion_attention import (
     SAVED_NAMES, BlockDiffusionMask, block_diffusion_attention)
 from test_sparse_attention import pallas_calls
@@ -136,7 +137,7 @@ def test_in_bfloat16_it_is_dense_attention_to_bfloat16s_precision():
                                  "empty_row"])
 def test_a_shape_that_cannot_be_tiled_is_an_error(bad):
     q, k, v = make_qkv(256, 2)
-    rule, match = BlockDiffusionMask(128, 4), "block_diffusion_attention"
+    rule, match = BlockDiffusionMask(128, 4), "blockdiff_attn"
     if bad == "head_dim":
         q, k, v = (x[..., :64] for x in (q, k, v))
     elif bad == "heads":

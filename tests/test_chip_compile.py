@@ -206,6 +206,43 @@ def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # on 8,192 rows
 
 
+def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
+    """The whole model of the mixed-attention cell at its configuration
+    file's sizes and the cell's rows (2 rows of 8,192 tokens; a full
+    layer with a dense MLP, three window layers of 64 heads and a full
+    layer of 48 with experts, 16 of 256 held; 12,544 rows of vocabulary):
+    the two causal rules lower in Mosaic at 6 and 8 query heads a
+    key/value head, each kind's kernels run once a layer (the remat keeps
+    each kind's output and row statistics under its own names), and the
+    gradient's scratch leaves room beside 7.84 GB of state."""
+    import json
+
+    from sparktorch_tpu.models.sparse_moe_lm import laguna_lm
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "laguna-xs.2-ep16.json")) as f:
+        module = laguna_lm(**json.load(f)["constructor_kwargs"])
+    ids = jnp.zeros((2, 8192), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 490_298_624
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy")
+    compiled = jax.jit(jax.grad(lambda p, x, y: loss_fn(
+        module.apply({"params": p}, x), y).sum())).lower(
+            jax.tree.map(S, shapes), S(ids), S(ids)).compile()
+    text = compiled.as_text()
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert _pallas_calls(text, f"window_attn_{kernel}") == 3
+        assert _pallas_calls(text, f"causal_attn_{kernel}") == 2
+    assert _pallas_calls(text, "blockdiff_attn_fwd") == 0
+    assert _pallas_calls(text, "fused_ce_fwd") == 1
+    # weights, gradients and Adam's moments are 7.84 GB of the 15.75
+    assert compiled.memory_analysis().temp_size_in_bytes < 5_500_000_000
+
+
 @pytest.mark.parametrize("rows,seq,calls", [(32, 512, 1), (128, 128, 0)])
 def test_encoder_layer_gradient_picks_its_attention_for_v5e(
         one_chip, as_tpu, monkeypatch, rows, seq, calls):

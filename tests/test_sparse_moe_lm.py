@@ -206,30 +206,50 @@ def _seeded_layer(held=(2, 3)):
 
 def _uncut_layer(model):
     """The reference, its configuration holding all 16 experts, and the
-    program's expert layer by the experts held, for either model built
-    on the block."""
+    program's expert layer by the experts held, for each model built on
+    the block."""
     if model == "keye":
         cfg, _ = sizes(held=tuple(range(16)), layers=1)
         return REF, cfg, _expert_layer
-    import test_block_diffusion_lm as D
+    if model == "sdar":
+        import test_block_diffusion_lm as D
+    else:
+        import test_mixed_attention_lm as D
 
-    cfg, _ = D.sizes(held=tuple(range(16)), layers=1)
-    return D.REF, cfg, lambda held: M.HeldExperts(
-        D.sizes(held=held, layers=1)[1].config)
+    cfg, _ = D.sizes(held=tuple(range(16)))
+    return D.REF, cfg, lambda held: M.HeldExperts(D.sizes(held=held)[1].config)
 
 
-@pytest.mark.parametrize("n_shares", [8, 4])
-@pytest.mark.parametrize("model", ["keye", "sdar"])
+@pytest.mark.parametrize("model,n_shares", [
+    ("keye", 8), ("keye", 4), ("sdar", 8), ("sdar", 4), ("laguna", 16),
+    ("laguna", 4)])
 def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(model,
                                                                n_shares):
     """Each share routes over all 16 experts and computes its own; the
-    shares' outputs summed are the reference's layer with every expert."""
+    shares' outputs summed are the reference's layer with every expert.
+    Where the layer has a shared expert (the third model: sigmoid scores,
+    gates scaled by 2.5), every chip computes it alike and it counts
+    ONCE: the shares' routed parts and one shared expert are the uncut
+    layer, and a shared expert a share is not."""
     ref, cfg, layer_holding = _uncut_layer(model)
-    whole = ref.init(jax.random.key(4), cfg)["params"]["layer_0"]["moe"]
+    # the last layer holds experts in every model
+    last = f"layer_{cfg['num_hidden_layers'] - 1}"
+    whole_layer = ref.init(jax.random.key(4), cfg)["params"][last]
+    whole = whole_layer["moe"]
     g = jax.random.normal(jax.random.key(5), (ROWS, T, 64), jnp.float32)
     ein = lambda eq, a, b: jnp.einsum(eq, a, b, precision="highest")
     want = jnp.stack([ref._experts_row(whole, row, ref._sizes(cfg), ein, None)
                       for row in g])
+    shared = 0.0
+    if "shared" in whole_layer:
+        config = layer_holding((0,)).config
+        assert (config.scoring, config.routed_scale) == ("sigmoid", 2.5)
+        shared = M.SwiGLU(config, config.shared_expert_width,
+                          "shared_expert").apply(
+            {"params": whole_layer["shared"]}, g)
+        want = want + jnp.stack([ref._swiglu(whole_layer["shared"], row, ein)
+                                 for row in g])
+        assert rel(shared, want) > 1e-2
     per = 16 // n_shares
     total = 0.0
     for share in range(n_shares):
@@ -240,7 +260,9 @@ def test_the_shares_of_the_expert_layer_sum_to_the_uncut_layer(model,
         out = layer_holding(held).apply({"params": params}, g)
         total = total + out
         assert rel(out, want) > 1e-2  # one share alone is not the layer
-    assert rel(total, want) < 1e-5
+    assert rel(total + shared, want) < 1e-5
+    if "shared" in whole_layer:
+        assert rel(total + n_shares * shared, want) > 1e-2
 
 
 @pytest.mark.parametrize("forced", [(2,), (2, 3)])
@@ -508,8 +530,12 @@ def test_a_planted_fault_changes_the_references_logits(fault):
 def test_a_bad_configuration_is_refused():
     with pytest.raises(ValueError, match="experts_held"):
         M.keye_vl2_lm(experts_held=(0, 0))
-    with pytest.raises(ValueError, match="mrope_section"):
-        M.keye_vl2_lm(mrope_section=(16, 16, 16))
+    # 72 frequency pairs where a head of 128 has 64; (16, 16, 16) turns
+    # the first 96 dims and passes the rest: partial rotary
+    with pytest.raises(ValueError, match="rotary sections"):
+        M.keye_vl2_lm(mrope_section=(16, 24, 32))
+    assert M.keye_vl2_lm(mrope_section=(16, 16, 16)).config.layers[0] \
+        .rotary.sections == (16, 16, 16)
 
 
 # -- through the trainers ------------------------------------------------
