@@ -32,11 +32,19 @@ causal; it lives with the model that needs it and calls these kernels.
 Shared with ``ops/sparse_attention.py``, which holds them: the layout
 (``q`` as ``[b, kv_heads, G, T, d]``, one grid step serving the ``G``
 query heads of a key/value head from one K and one V tile), the tile
-sizes, the streaming-softmax body and both backward bodies (``fwd_tile``,
+sizes, the streaming-softmax body with its scratch and both backward
+bodies (``fwd_tile`` / ``fwd_init`` / ``fwd_finalize`` / ``fwd_scratch``,
 ``dq_tile``, ``dkv_tile``: the mask comes from a tile of an array there
 and from the rule here), the ``_NEG`` convention (a masked score is a
 large negative finite number and a masked probability exactly 0) and the
-row statistics the backward kernels read.
+row statistics the backward kernels read. The forward body works on the
+tile turned (keys down the sublanes, queries along the lanes, so a
+head's running maximum and sum are rows; that file's docstring says
+why), and its log-sum-exp leaves one number a row, ``[b, kv_heads, G,
+T]`` float32. A rule is element-wise on its broadcast indices, so the
+forward kernel calls it on a row of queries and a column of keys and
+gets the turned mask with nothing transposed (:func:`_keep`); dq and
+dkv keep the tile queries down.
 
 A call carries a static ``name``: its kernels are the ``pallas_call``s
 ``<name>_attn_fwd``, ``<name>_attn_bwd_dq`` and ``<name>_attn_bwd_dkv``,
@@ -64,7 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from sparktorch_tpu.ops.sparse_attention import (
     _LANES, _blocks, _heads_first, _heads_last, _interpret, dkv_tile,
-    dq_tile, fwd_finalize, fwd_init, fwd_tile, row_statistics)
+    dq_tile, fwd_finalize, fwd_init, fwd_scratch, fwd_tile, row_statistics)
 
 
 def saved_names(name: str) -> tuple:
@@ -133,12 +141,19 @@ def _visit(major_ref, v, n_visits):
     return first, last
 
 
-def _keep(rule, qi, ki, block_q, block_k):
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, 1), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    return rule(rows, cols)
+def _keep(rule, qi, ki, block_q, block_k, keys_down: bool = False):
+    """The rule on a tile's own indices: queries down and keys across,
+    ``[block_q, block_k]``, or (``keys_down``, the forward kernel's
+    tile) keys down and queries across. A rule is element-wise on its
+    broadcast indices, so the turned tile is the rule on a row of
+    queries and a column of keys: nothing is transposed."""
+    q_shape, k_shape = ((1, block_q), (block_k, 1)) if keys_down else (
+        (block_q, 1), (1, block_k))
+    queries = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, q_shape, int(keys_down))
+    keys = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, k_shape, int(not keys_down))
+    return rule(queries, keys)
 
 
 def _fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -151,7 +166,8 @@ def _fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _init():
         fwd_init(acc_ref, m_ref, l_ref)
 
-    keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k)
+    keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k,
+                 keys_down=True)
     fwd_tile(q_ref, k_ref[...], v_ref[...], keep, acc_ref, m_ref, l_ref,
              scale, groups)
 
@@ -239,16 +255,16 @@ def _fwd(rule, name, q5, k4, v4):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     q_major, _ = visited_tiles(rule, t, block_q, block_k)
-    q_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k)
+    q_spec, kv_spec, _ = _specs(groups, d, block_q, block_k)
+    # the log-sum-exp, one number a row with the sequence along the lanes
+    lse_spec = pl.BlockSpec((None, None, groups, block_q),
+                            lambda b, h, v, qt, kt: (b, h, 0, qt[v]))
     return _call(
         _fwd_kernel, f"{name}_attn_fwd", q_major, q5.shape,
         [jax.ShapeDtypeStruct(q5.shape, q5.dtype),
-         jax.ShapeDtypeStruct((b, hkv, groups, t, _LANES), jnp.float32)],
-        [q_spec, kv_spec, kv_spec], [q_spec, row_spec],
-        [pltpu.VMEM((groups, block_q, d), jnp.float32),
-         pltpu.VMEM((groups, block_q, _LANES), jnp.float32),
-         pltpu.VMEM((groups, block_q, _LANES), jnp.float32)],
-        (q5, k4, v4), rule=rule)
+         jax.ShapeDtypeStruct((b, hkv, groups, t), jnp.float32)],
+        [q_spec, kv_spec, kv_spec], [q_spec, lse_spec],
+        fwd_scratch(groups, d, block_q), (q5, k4, v4), rule=rule)
 
 
 def _bwd(rule, name, q5, k4, v4, o5, lse, do5):
@@ -308,8 +324,7 @@ def _forward(q, k, v, rule, name):
     o5, lse = _fwd(rule, name, q5, k4, v4)
     out_name, lse_name = saved_names(name)
     o5 = checkpoint_name(o5, out_name)
-    # one lane of the row statistics, as ``sparse_attention`` keeps them
-    lse = checkpoint_name(lse[..., 0], lse_name)
+    lse = checkpoint_name(lse, lse_name)
     return _heads_last(o5), (q5, k4, v4, o5, lse)
 
 

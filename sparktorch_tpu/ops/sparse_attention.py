@@ -25,17 +25,36 @@ masked ones away: at 8,192 tokens and 2,048 selected keys that is about
 gathering the selected keys, is a later optimisation; the roofline
 share the benchmark reports counts selected pairs only and says so.
 
+The forward kernel works on the tile TURNED, as ``flash_attention``'s
+streaming branch does: keys down the sublanes, queries along the lanes
+(``[block_k, block_q]`` = ``[512, 256]`` a head of the group at 8,192
+tokens; scores ``K_tile @ Q_g^T``, the PV product ``V_tile^T @ p``). A
+head's running maximum and sum are then ROWS ``[1, block_q]``: they
+reduce down the registers with no cross-lane reduction, live in VMEM as
+``[G, 8, block_q]`` float32 (two registers a head at 256 queries) and
+rescale the running output ``[G, d, block_q]`` spread down the
+sublanes; as ``[block_q, 1]`` columns they cost as much as the tile's
+own passes (PERF.md section 6, PR 33 and 36). The output is turned back
+once a Q tile and leaves in the layout it always had; the log-sum-exp
+leaves one number a row, ``[b, kv_heads, G, T]`` float32 with T along
+the lanes. The int8 mask lies queries down in HBM (whoever selected the
+keys wrote it so, and dq and dkv read it so): the forward kernel turns
+its tile once a grid step, after the cast, for the heads that share it.
+The two backward kernels keep the tile queries down; they read finished
+statistics and reduce nothing.
+
 The backward kernels need two arrays only the forward kernel can make:
 its output in its own layout (``[b, kv_heads, G, T, d]``, the inputs'
-dtype) and the row statistics (log-sum-exp, ``[b, kv_heads, G, T]``
-float32). The forward rule names both (``checkpoint_name``,
+dtype) and the row statistics (the log-sum-exp above, as the kernel
+wrote it). The forward rule names both (``checkpoint_name``,
 :data:`SAVED_NAMES`), so a caller who rematerialises around the
 attention can list them in its policy
 (``save_only_these_names(*SAVED_NAMES)``) and the backward pass reads
 what the forward pass left, for one more activation of ``q``'s size a
 call, and does not launch ``sparse_attn_fwd`` a second time. Outside a
 ``jax.checkpoint``, or under a policy that lists neither, the names are
-identities.
+identities. On their way into dq and dkv the statistics and ``sum(o *
+do)`` are spread over 128 lanes by XLA (:func:`row_statistics`).
 
 ``pallas_call`` names: ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``,
 ``sparse_attn_bwd_dkv``. Off the TPU they run in interpret mode. A
@@ -51,6 +70,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from sparktorch_tpu.ops.flash_attention import _nt, _tn
 
 _LANES = 128
 _NEG = -1e30         # a masked score: finite, so NEG - NEG is 0, not NaN
@@ -70,8 +91,11 @@ def _first_q(ki, block_q: int, block_k: int):
     return (ki * block_k) // block_q
 
 
-def _keep(mask_ref):
-    return mask_ref[...].astype(jnp.int32) != 0
+def _keep(mask_ref, keys_down: bool = False):
+    """The mask tile as booleans, queries down as it lies in HBM, or
+    (``keys_down``) turned once for the heads that share it."""
+    mask = mask_ref[...].astype(jnp.int32)
+    return (mask.T if keys_down else mask) != 0
 
 
 def _scores(q, k, keep, scale):
@@ -88,30 +112,32 @@ def fwd_init(acc_ref, m_ref, l_ref):
 
 def fwd_tile(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale, groups):
     """One K/V tile into the running maximum, sum and output of the
-    ``groups`` query heads of a Q tile. ``keep`` is the tile's mask,
-    from wherever the kernel has it: this body and the two below are
-    shared with ``ops/rule_attention.py``."""
+    ``groups`` query heads of a Q tile, on the tile TURNED: keys down,
+    queries across, ``keep`` ``[block_k, block_q]`` from wherever the
+    kernel has it. A head's maximum and sum are then rows ``[1,
+    block_q]``: they reduce down the registers, not across the lanes,
+    and rescale the running output ``[d, block_q]`` spread down the
+    sublanes. This body and the two around it are shared with
+    ``ops/rule_attention.py``."""
     for g in range(groups):
-        s = _scores(q_ref[g], k, keep, scale)
-        m_prev, l_prev = m_ref[g][:, :1], l_ref[g][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        s = jnp.where(keep, _nt(k, q_ref[g]) * scale, _NEG)
+        m_prev, l_prev = m_ref[g][:1], l_ref[g][:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[g] = acc_ref[g] * alpha + pv
+        l_new = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+        acc_ref[g] = acc_ref[g] * alpha + _tn(v, p.astype(v.dtype))
         m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
         l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
 
 def fwd_finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups):
+    """The running output turned back once a Q tile, and the log-sum-exp
+    one number a row, ``[groups, block_q]``."""
     for g in range(groups):
-        l = jnp.maximum(l_ref[g][:, :1], 1e-20)
-        o_ref[g] = (acc_ref[g] / l).astype(o_ref.dtype)
-        lse_ref[g] = jnp.broadcast_to(m_ref[g][:, :1] + jnp.log(l),
-                                      lse_ref.shape[1:])
+        l = jnp.maximum(l_ref[g][:1], 1e-20)
+        o_ref[g] = (acc_ref[g] / l).T.astype(o_ref.dtype)
+        lse_ref[pl.ds(g, 1), :] = m_ref[g][:1] + jnp.log(l)
 
 
 def dq_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dq_acc, scale,
@@ -156,8 +182,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
 
     @pl.when(ki <= _last_k(qi, block_q, block_k))
     def _body():
-        k, v, keep = k_ref[...], v_ref[...], _keep(mask_ref)
-        fwd_tile(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale, groups)
+        # the mask lies queries down in HBM: turned once a grid step
+        # for the heads that share it
+        keep = _keep(mask_ref, keys_down=True)
+        fwd_tile(q_ref, k_ref[...], v_ref[...], keep, acc_ref, m_ref, l_ref,
+                 scale, groups)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -274,6 +303,14 @@ def _specs(groups, d, block_q, block_k, q_major: bool):
     return q_spec, kv_spec, mask_spec, row_spec
 
 
+def fwd_scratch(groups, d, block_q):
+    """The forward kernel's VMEM: the running output ``[d, block_q]`` a
+    head, and its running maximum and sum as rows, one register high."""
+    return [pltpu.VMEM((groups, d, block_q), jnp.float32),
+            pltpu.VMEM((groups, 8, block_q), jnp.float32),
+            pltpu.VMEM((groups, 8, block_q), jnp.float32)]
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -282,20 +319,20 @@ def _fwd(q5, k4, v4, mask):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     n_q, n_k = t // block_q, t // block_k
-    q_spec, kv_spec, mask_spec, row_spec = _specs(
+    q_spec, kv_spec, mask_spec, _ = _specs(
         groups, d, block_q, block_k, q_major=True)
+    # the log-sum-exp, one number a row with the sequence along the lanes
+    lse_spec = pl.BlockSpec((None, None, groups, block_q),
+                            lambda b, h, qi, ki: (b, h, 0, qi))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=d ** -0.5, block_q=block_q,
                           block_k=block_k, n_k=n_k, groups=groups),
         out_shape=[jax.ShapeDtypeStruct(q5.shape, q5.dtype),
-                   jax.ShapeDtypeStruct((b, hkv, groups, t, _LANES),
-                                        jnp.float32)],
+                   jax.ShapeDtypeStruct((b, hkv, groups, t), jnp.float32)],
         grid=(b, hkv, n_q, n_k),
         in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, row_spec],
-        scratch_shapes=[pltpu.VMEM((groups, block_q, d), jnp.float32),
-                        pltpu.VMEM((groups, block_q, _LANES), jnp.float32),
-                        pltpu.VMEM((groups, block_q, _LANES), jnp.float32)],
+        out_specs=[q_spec, lse_spec],
+        scratch_shapes=fwd_scratch(groups, d, block_q),
         interpret=_interpret(),
         name="sparse_attn_fwd",
     )(q5, k4, v4, mask)
@@ -370,9 +407,7 @@ def _forward(q, k, v, mask):
     k4, v4 = (jnp.swapaxes(x, 1, 2) for x in (k, v))
     o5, lse = _fwd(q5, k4, v4, mask)
     o5 = checkpoint_name(o5, SAVED_NAMES[0])
-    # One lane of the row statistics is kept for the backward pass, with
-    # no trailing axis of one: the TPU would pad that axis to 128 lanes.
-    lse = checkpoint_name(lse[..., 0], SAVED_NAMES[1])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
     return _heads_last(o5), (q5, k4, v4, mask, o5, lse)
 
 

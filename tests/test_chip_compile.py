@@ -100,10 +100,25 @@ def test_fused_ce_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+def _kernel_lines(text, kernel):
+    """The compiled text's custom calls of the Pallas kernel ``kernel``."""
+    call = re.compile(
+        rf'custom_call_target="tpu_custom_call".*op_name="[^"]*\b{kernel}\b')
+    return [line for line in text.splitlines() if call.search(line)]
+
+
 def _pallas_calls(text, kernel):
-    return len(re.findall(
-        rf'custom_call_target="tpu_custom_call".*op_name="[^"]*\b{kernel}\b',
-        text))
+    return len(_kernel_lines(text, kernel))
+
+
+def _forward_statistics(text, kernel):
+    """The float32 results of the compiled forward kernels ``kernel``,
+    as their dims: the row statistics, one number a row (rank 4, the
+    sequence along the lanes), not spread over 128 lanes (rank 5)."""
+    return [tuple(map(int, dims.split(",")))
+            for line in _kernel_lines(text, kernel)
+            for dims in re.findall(r"f32\[([\d,]+)\]",
+                                   line.split(" custom-call(")[0])]
 
 
 def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
@@ -122,6 +137,7 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     for kernel in ("sparse_attn_fwd", "sparse_attn_bwd_dq",
                    "sparse_attn_bwd_dkv"):
         assert _pallas_calls(text, kernel) == 1, kernel
+    assert _forward_statistics(text, "sparse_attn_fwd") == [(1, 4, 8, 4096)]
 
 
 def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
@@ -200,6 +216,8 @@ def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
                      rngs={NOISE_STREAM: jax.random.key(1)}), x).sum())).lower(
             jax.tree.map(S, shapes), S(ids)).compile().as_text()
     assert _pallas_calls(text, "blockdiff_attn_fwd") == 1
+    assert _forward_statistics(text, "blockdiff_attn_fwd") == [
+        (1, 4, 8, 16384)]
     assert _pallas_calls(text, "blockdiff_attn_bwd_dq") == 1
     assert _pallas_calls(text, "blockdiff_attn_bwd_dkv") == 1
     assert _pallas_calls(text, "sparse_attn_fwd") == 0
@@ -237,6 +255,11 @@ def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
         assert _pallas_calls(text, f"window_attn_{kernel}") == 3
         assert _pallas_calls(text, f"causal_attn_{kernel}") == 2
+    # 64 and 48 query heads on 8 key/value heads
+    assert _forward_statistics(text, "window_attn_fwd") == 3 * [
+        (2, 8, 8, 8192)]
+    assert _forward_statistics(text, "causal_attn_fwd") == 2 * [
+        (2, 8, 6, 8192)]
     assert _pallas_calls(text, "blockdiff_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1
     # weights, gradients and Adam's moments are 7.84 GB of the 15.75

@@ -4,7 +4,12 @@ rule against its dense mask pair by pair, its tile tables against the
 closed form, forward and the three gradients against dense masked
 float32 attention at six and eight query heads a key/value head, the
 exact leak test of the window, and the names a call gives its kernels
-and its saved arrays."""
+and its saved arrays. And, over the four forward kernels that share one
+tile body (``blockdiff``, ``causal``, ``window``, ``sparse``): the row
+statistics as the kernel writes them and as the forward rule saves
+them, and the turned tile's mask."""
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -12,7 +17,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import test_sparse_attention as sparse_case
 from sparktorch_tpu.ops import rule_attention as mod
+from sparktorch_tpu.ops import sparse_attention as sparse
+from sparktorch_tpu.ops.block_diffusion_attention import BlockDiffusionMask
 from sparktorch_tpu.ops.rule_attention import (
     Causal, CausalWindow, rule_attention, saved_names)
 from test_block_diffusion_attention import D, _grads, dense
@@ -161,3 +169,117 @@ def test_a_call_names_its_kernels_and_what_a_remat_may_keep(name):
         counts[kept] = [pallas_calls(jaxpr, k) for k in kernels]
         assert pallas_calls(jaxpr, f"{other}_attn_fwd") == 0
     assert counts == {name: [1, 1, 1], other: [2, 1, 1]}
+
+
+@dataclasses.dataclass(frozen=True)
+class Without:
+    """``rule`` with one query's row emptied: a query that keeps
+    nothing, as a learned selection may leave one."""
+
+    rule: object
+    query: int
+
+    def __call__(self, i, j):
+        return self.rule(i, j) & (i != self.query)
+
+
+T, EMPTY = 384, 200
+FORWARD_KERNELS = {
+    # kernel name: (the rule, query heads a key/value head)
+    "blockdiff": (BlockDiffusionMask(T // 2, 4), 2),
+    "causal": (Causal(), 6),
+    "window": (CausalWindow(160), 8),
+    "sparse": (None, 2),
+}
+
+
+def forward_call(name, dtype, empty_row: bool = False):
+    """``(forward rule of the kernel called name, q, k, v, its dense
+    mask [rows, T, T])``: the rule kernels under their own names, the
+    selected-key kernel on a random causal int8 mask; ``empty_row``:
+    query ``EMPTY`` keeps no key."""
+    rule, groups = FORWARD_KERNELS[name]
+    if name == "sparse":
+        mask = np.array(sparse_case.make_mask("random", T))
+        if empty_row:
+            mask[:, EMPTY] = 0
+        mask = jnp.asarray(mask)
+        return (lambda q, k, v: sparse._forward(q, k, v, mask),
+                *sparse_case.make_qkv(T, dtype), np.asarray(mask) != 0)
+    if empty_row:
+        rule = Without(rule, EMPTY)
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    q, k, v = (x.astype(dtype) for x in make_qkv(T, groups, kv=2))
+    return (lambda q, k, v: mod._forward(q, k, v, rule, name), q, k, v,
+            np.broadcast_to(rule(i, j), (1, T, T)))
+
+
+@pytest.mark.parametrize("name", list(FORWARD_KERNELS))
+def test_the_forward_kernel_writes_one_statistic_a_row(name):
+    """Its second result is ``[b, kv_heads, G, T]`` float32, T along the
+    lanes, and that array itself is what the forward rule names for a
+    remat: no ``[.., T, 128]`` float32 array of statistics spread over
+    the lanes, and no slice of one, anywhere in a forward call (the
+    operands are bfloat16, so any float32 array 128 wide would be
+    one)."""
+    forward, q, k, v, _ = forward_call(name, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(forward)(q, k, v).jaxpr
+    (kernel,) = [eqn for eqn in jaxpr.eqns if eqn.primitive.name ==
+                 "pallas_call" and eqn.params["name"] == f"{name}_attn_fwd"]
+    out, lse = (var.aval for var in kernel.outvars)
+    b, hkv, groups = q.shape[0], k.shape[2], q.shape[2] // k.shape[2]
+    assert (out.shape, out.dtype) == ((b, hkv, groups, T, D), jnp.bfloat16)
+    assert (lse.shape, lse.dtype) == ((b, hkv, groups, T), jnp.float32)
+    named = {eqn.params["name"]: eqn.invars[0] for eqn in jaxpr.eqns
+             if eqn.primitive.name == "name"}
+    assert saved_names("sparse") == sparse.SAVED_NAMES
+    assert named[saved_names(name)[1]] is kernel.outvars[1]
+    spread = [var.aval for eqn in jaxpr.eqns for var in eqn.outvars
+              if var.aval.dtype == jnp.float32 and var.aval.shape[-1:] == (
+                  128,)]
+    assert not spread
+
+
+@pytest.mark.parametrize("name", list(FORWARD_KERNELS))
+def test_the_saved_statistics_are_the_dense_log_sum_exp(name):
+    """A row, in float32, against the masked scores' log-sum-exp written
+    out; a query that keeps nothing gets zeros and the statistics the
+    kernel started from (the masked score and the floor of the sum)."""
+    forward, q, k, v, mask = forward_call(name, jnp.float32, empty_row=True)
+    out, res = forward(q, k, v)
+    lse = res[-1]
+    groups = q.shape[2] // k.shape[2]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, groups, axis=2),
+                        precision="highest") * D ** -0.5
+    kept = jnp.asarray(mask)[:, None]
+    want = jnp.where(
+        kept.any(-1),
+        jax.nn.logsumexp(jnp.where(kept, scores, -jnp.inf), axis=-1),
+        sparse._NEG + np.log(1e-20))
+    assert not mask[:, EMPTY].any() and mask.any(-1).sum() == mask.shape[
+        0] * (T - 1)
+    np.testing.assert_allclose(lse, want.reshape(lse.shape), atol=1e-5,
+                               rtol=1e-6)
+    assert np.all(np.asarray(out[:, EMPTY]) == 0)
+    assert np.all(np.isfinite(np.asarray(out)))
+
+
+@pytest.mark.parametrize("rule", [
+    Causal(), CausalWindow(512), BlockDiffusionMask(512, 4)], ids=repr)
+def test_a_rule_on_a_row_of_queries_is_its_transpose(rule):
+    """What the forward kernel's turned tile rests on: a rule is
+    element-wise on its broadcast indices, so a row of queries against a
+    column of keys gives keys down, queries across, and the kernel's
+    mask of a tile is the same pairs either way up."""
+    t = 1_024
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    down = rule(i, j)
+    assert down.shape == (t, t) and down.any() and not down.all()
+    assert np.array_equal(rule(i.T, j.T), down.T)
+    for qi, ki in ((0, 0), (3, 1), (7, 3), (5, 0)):
+        tile = mod._keep(rule, qi, ki, 128, 256)
+        turned = mod._keep(rule, qi, ki, 128, 256, keys_down=True)
+        assert turned.shape == (256, 128)
+        assert np.array_equal(turned, np.asarray(tile).T)
+        assert np.array_equal(
+            tile, down[qi * 128:(qi + 1) * 128, ki * 256:(ki + 1) * 256])
