@@ -499,24 +499,30 @@ class _GroupedQueryProjections(nn.Module):
 
     def _qkv(self, h, angles):
         cfg, d, hd = self.config, h.shape[-1], self.config.head_dim
-        q = self._proj(h, self._dense("wq", (d, self.kind.n_heads, hd)))
-        k = self._proj(h, self._dense("wk", (d, cfg.n_kv_heads, hd)))
-        v = self._proj(h, self._dense("wv", (d, cfg.n_kv_heads, hd)))
+        with jax.named_scope("attn_qkv"):  # the three products
+            q = self._proj(h, self._dense("wq", (d, self.kind.n_heads, hd)))
+            k = self._proj(h, self._dense("wk", (d, cfg.n_kv_heads, hd)))
+            v = self._proj(h, self._dense("wv", (d, cfg.n_kv_heads, hd)))
         ones = nn.initializers.ones
-        q = rms_norm(q, self.param("q_norm", ones, (hd,)), cfg.rms_eps)
-        k = rms_norm(k, self.param("k_norm", ones, (hd,)), cfg.rms_eps)
-        cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
-        factor = self.kind.rotary.attention_factor
-        if factor != 1.0:  # on the rotated dims alone
-            cos, sin = factor * cos, factor * sin
-        return _rotate(q, cos, sin), _rotate(k, cos, sin), v
+        # the float32 passes a head of q and k: norm, table, rotation
+        with jax.named_scope("attn_qk_rope"):
+            q = rms_norm(q, self.param("q_norm", ones, (hd,)), cfg.rms_eps)
+            k = rms_norm(k, self.param("k_norm", ones, (hd,)), cfg.rms_eps)
+            cos, sin = (jnp.cos(angles)[:, :, None],
+                        jnp.sin(angles)[:, :, None])
+            factor = self.kind.rotary.attention_factor
+            if factor != 1.0:  # on the rotated dims alone
+                cos, sin = factor * cos, factor * sin
+            return _rotate(q, cos, sin), _rotate(k, cos, sin), v
 
     def _out(self, o, d):
         cfg, dt = self.config, self.config.compute_dtype
-        return jnp.einsum(
-            "bthk,hkd->btd", o,
-            self._dense("wo", (self.kind.n_heads, cfg.head_dim, d)).astype(dt),
-            preferred_element_type=jnp.float32)
+        with jax.named_scope("attn_out"):
+            return jnp.einsum(
+                "bthk,hkd->btd", o,
+                self._dense("wo",
+                            (self.kind.n_heads, cfg.head_dim, d)).astype(dt),
+                preferred_element_type=jnp.float32)
 
 
 class SparseAttention(_GroupedQueryProjections):
@@ -608,9 +614,10 @@ class RuleAttention(_GroupedQueryProjections):
             o = rule_attention(q.astype(dt), k.astype(dt), v.astype(dt),
                                rule, name)
         if cfg.attn_gate:
-            gate = jax.nn.sigmoid(self._proj(
-                h, self._dense("wg", (d, self.kind.n_heads))))
-            o = (o * gate[..., None]).astype(dt)
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(self._proj(
+                    h, self._dense("wg", (d, self.kind.n_heads))))
+                o = (o * gate[..., None]).astype(dt)
         return self._out(o, d)
 
 
@@ -890,14 +897,15 @@ class DecoderLayer(nn.Module):
         cfg, kind = self.config, self.kind
         ones = nn.initializers.ones
         d = x.shape[-1]
-        h = rms_norm(x, self.param("attn_norm", ones, (d,)), cfg.rms_eps)
+        norm = jax.named_scope("block_norm")(rms_norm)
+        h = norm(x, self.param("attn_norm", ones, (d,)), cfg.rms_eps)
         attention, _ = _ATTENTION[kind.attention]
         x = x + attention(cfg, kind, name="attn")(h, angles, temporal)
         if kind.mlp == "dense":
-            g = rms_norm(x, self.param("mlp_norm", ones, (d,)), cfg.rms_eps)
+            g = norm(x, self.param("mlp_norm", ones, (d,)), cfg.rms_eps)
             return x + SwiGLU(cfg, cfg.dense_width, "dense_mlp",
                               name="mlp")(g)
-        g = rms_norm(x, self.param("moe_norm", ones, (d,)), cfg.rms_eps)
+        g = norm(x, self.param("moe_norm", ones, (d,)), cfg.rms_eps)
         x = x + HeldExperts(cfg, name="moe")(g)
         if cfg.shared_expert_width:
             # on every chip alike: no share of it, no gate
@@ -1040,11 +1048,14 @@ class SparseMoELM(nn.Module):
             self.sow("moe_metrics", "tokens", jnp.float32(b * t))
         # one table of angles a rotary table among the layers, and one
         # rematerialised layer class a set of names an attention keeps
-        angles = {rotary: rotary_angles(position_ids, rotary)
-                  for rotary in dict.fromkeys(k.rotary for k in cfg.layers)}
+        with jax.named_scope("attn_qk_rope"):
+            angles = {rotary: rotary_angles(position_ids, rotary)
+                      for rotary in dict.fromkeys(
+                          k.rotary for k in cfg.layers)}
         temporal = position_ids[0]
-        x = self.param("embed", _normal(),
-                       (cfg.vocab_size, cfg.d_model))[ids]
+        with jax.named_scope("embed"):  # its gradient: the scatter-add
+            x = self.param("embed", _normal(),
+                           (cfg.vocab_size, cfg.d_model))[ids]
         remat = {kept: nn.remat(
             DecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(*kept))
@@ -1056,8 +1067,9 @@ class SparseMoELM(nn.Module):
                 x, angles[kind.rotary], temporal)
         if diffusion:
             x = x[:, t:]  # the clean half's last output enters nothing
-        x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
-                                   (cfg.d_model,)), cfg.rms_eps)
+        with jax.named_scope("block_norm"):
+            x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
+                                       (cfg.d_model,)), cfg.rms_eps)
         with jax.named_scope("lm_head"):
             head = self.param("head", _normal(),
                               (cfg.d_model, cfg.vocab_size)).astype(dt)

@@ -210,11 +210,13 @@ class Span:
     region's output arrays to fold device completion into the timing —
     JAX dispatch is async, so without a sync a span around a compiled
     call measures enqueue time, not compute (the ROUND4 "honest
-    timing" lesson).
+    timing" lesson). ``wait_s`` is the part of the span spent inside
+    :meth:`sync` (0.0 for a span that never synced): what the host
+    took to enqueue is ``duration_s - wait_s``.
     """
 
     __slots__ = ("name", "path", "labels", "depth", "t0", "duration_s",
-                 "synced")
+                 "synced", "wait_s")
 
     def __init__(self, name: str, path: str, labels: Dict[str, Any],
                  depth: int):
@@ -225,13 +227,20 @@ class Span:
         self.t0 = time.perf_counter()
         self.duration_s: Optional[float] = None
         self.synced = False
+        self.wait_s = 0.0
 
     def sync(self, *arrays: Any) -> None:
         """Block until the given device values are materialized, so the
-        span's duration covers their compute. No-op on host values."""
+        span's duration covers their compute. No-op on host values.
+        The wait is timed (``wait_s``) and is a host event of its own
+        in a trace, ``<path>/wait``: an annotation, not a span, so the
+        thread's compile events stay filed under the span itself."""
         import jax
 
-        jax.block_until_ready(arrays)
+        t0 = time.perf_counter()
+        with (_TRACE_ANNOTATION or _jax_hooks())(f"{self.path}/wait"):
+            jax.block_until_ready(arrays)
+        self.wait_s += time.perf_counter() - t0
         self.synced = True
 
 
@@ -249,9 +258,11 @@ class Telemetry:
         self._gauges: Dict[MetricKey, float] = {}
         self._hists: Dict[MetricKey, _Hist] = {}
         self._spans: Dict[MetricKey, _Hist] = {}
-        # each span sample's start (perf_counter), beside its duration
-        # in ``_spans[key].ring`` and bounded like it
+        # each span sample's start (perf_counter) and the seconds it
+        # spent in ``Span.sync``, beside its duration in
+        # ``_spans[key].ring`` and bounded like it
         self._span_starts: Dict[MetricKey, "collections.deque[float]"] = {}
+        self._span_waits: Dict[MetricKey, "collections.deque[float]"] = {}
         self._info: Dict[MetricKey, str] = {}
         self._sections: Dict[str, Any] = {}
         self._sinks: List[Callable[[Dict[str, Any]], None]] = []
@@ -363,11 +374,14 @@ class Telemetry:
                 if starts is None:
                     starts = self._span_starts[k] = collections.deque(
                         maxlen=self._ring_size)
+                    self._span_waits[k] = collections.deque(
+                        maxlen=self._ring_size)
                 hist.observe(span.duration_s)
                 starts.append(span.t0)
+                self._span_waits[k].append(span.wait_s)
             self.event("span", name=path, dur_s=span.duration_s,
                        depth=span.depth, synced=span.synced,
-                       **span.labels)
+                       wait_s=span.wait_s, **span.labels)
 
     def event(self, kind: str, **fields: Any) -> None:
         """Emit one structured event to every attached sink."""
@@ -450,6 +464,16 @@ class Telemetry:
             # starts were never kept: the newest samples have both
             return list(zip(starts, list(hist.ring)[-len(starts):]))
 
+    def span_waits(self, path: str,
+                   labels: Optional[Dict[str, Any]] = None) -> List[float]:
+        """The seconds each retained sample of one span spent in
+        :meth:`Span.sync`, oldest first and one for one with
+        :meth:`span_samples` (whose pairs keep their shape: readers
+        unpack them): 0.0 for a sample that never synced, never more
+        than its duration."""
+        with self._lock:
+            return list(self._span_waits.get(_key(path, labels), ()))
+
     def snapshot(self) -> Dict[str, Any]:
         """One coherent view of every metric: counters and gauges as
         flat ``name{labels}`` -> value dicts, histograms and spans as
@@ -501,6 +525,7 @@ class Telemetry:
             self._hists.clear()
             self._spans.clear()
             self._span_starts.clear()
+            self._span_waits.clear()
             self._info.clear()
             self._sections.clear()
 
@@ -522,6 +547,7 @@ class Telemetry:
                 "_hists": dict(self._hists),
                 "_spans": dict(self._spans),
                 "_span_starts": dict(self._span_starts),
+                "_span_waits": dict(self._span_waits),
                 "_info": dict(self._info),
                 "_sections": dict(self._sections),
             }
@@ -531,6 +557,10 @@ class Telemetry:
         self.__dict__.setdefault("_info", {})  # pre-info pickles
         self.__dict__.setdefault("_sections", {})  # pre-section pickles
         self.__dict__.setdefault("_span_starts", {})  # pre-start pickles
+        if "_span_waits" not in state:  # pre-wait pickles: none synced
+            self._span_waits = {
+                k: collections.deque([0.0] * len(v), maxlen=v.maxlen)
+                for k, v in self._span_starts.items()}
         self._lock = threading.Lock()
         self._sinks = []
         self._tls = threading.local()

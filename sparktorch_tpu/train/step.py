@@ -461,13 +461,14 @@ def _dp_body(apply_fn, loss_fn, tx, axis_names, per_shard_mb,
             apply_fn, params, state.model_state, mb.x, train=True,
             example_w=mb.w, rngs=_forward_rngs(apply_fn, sample_key),
         )
-        per = loss_fn(preds, mb.y)
-        den = jnp.sum(mb.w)
-        # Sown aux objectives (per-shard means, pre-weighted at the
-        # sow site) scale by den so the global psum(num)/psum(den)
-        # is the task mean plus the example-weighted mean aux —
-        # matching the sharded trainer's objective.
-        num = jnp.sum(per * mb.w) + _sown_total(sown, per.dtype) * den
+        with jax.named_scope("loss"):  # the criterion and its sums
+            per = loss_fn(preds, mb.y)
+            den = jnp.sum(mb.w)
+            # Sown aux objectives (per-shard means, pre-weighted at the
+            # sow site) scale by den so the global psum(num)/psum(den)
+            # is the task mean plus the example-weighted mean aux —
+            # matching the sharded trainer's objective.
+            num = jnp.sum(per * mb.w) + _sown_total(sown, per.dtype) * den
         return num, (den, new_model_state, _moe_drop_counts(sown_metrics),
                      _moe_sown_by_layer(sown_metrics))
 

@@ -122,6 +122,123 @@ def test_samples_survive_a_pickle_and_an_older_pickle_still_reads():
     assert old.span_rollup("a")["count"] == 2
 
 
+def test_a_synced_spans_wait_is_recorded_beside_its_sample():
+    """``Span.sync`` times its own block: the wait is part of the span's
+    duration, one number a sample in ``span_waits`` (one for one with
+    ``span_samples``, whose pairs keep their shape), 0.0 for a span that
+    never synced, bounded and reset like the starts."""
+    tele = Telemetry(ring_size=3)
+    program = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((256, 256))
+    program(x).block_until_ready()
+    with tele.span("work") as span:
+        out = program(x)
+        span.sync(out)
+    assert span.synced and 0.0 < span.wait_s <= span.duration_s
+    with tele.span("work") as twice:
+        twice.sync(program(x))
+        first = twice.wait_s
+        twice.sync(program(x))
+    assert first < twice.wait_s <= twice.duration_s  # the waits add up
+    with tele.span("work") as never:
+        time.sleep(0.001)
+    assert never.wait_s == 0.0 and not never.synced
+    with tele.span("other", {"rank": 1}) as host:
+        host.sync(3.0, np.ones(2))  # host values: nothing to wait for
+    assert 0.0 <= host.wait_s <= host.duration_s
+    waits, samples = tele.span_waits("work"), tele.span_samples("work")
+    assert waits == [span.wait_s, twice.wait_s, 0.0]
+    assert all(len(pair) == 2 for pair in samples)
+    assert all(w <= d for w, (_t0, d) in zip(waits, samples))
+    assert tele.span_waits("other", {"rank": 1}) == [host.wait_s]
+    assert tele.span_waits("never") == []
+    with tele.span("work"):
+        pass
+    assert tele.span_waits("work") == [twice.wait_s, 0.0, 0.0]  # the ring's
+    assert len(tele.span_samples("work")) == 3
+    tele.reset()
+    assert tele.span_waits("work") == [] and tele._span_waits == {}
+
+
+def test_the_waits_travel_with_a_pickled_bus_and_an_older_pickle_has_none():
+    tele = Telemetry()
+    with tele.span("a") as a:
+        a.sync(jnp.ones(3) + 1)
+    with tele.span("a"):
+        pass
+    clone = pickle.loads(pickle.dumps(tele))
+    assert clone.span_waits("a") == tele.span_waits("a") == [a.wait_s, 0.0]
+    state = tele.__getstate__()
+    del state["_span_waits"]  # a bus pickled before waits were kept
+    old = Telemetry.__new__(Telemetry)
+    old.__setstate__(state)
+    assert old.span_waits("a") == [0.0, 0.0]
+    with old.span("a") as later:
+        later.sync(jnp.ones(3) * 2)
+    assert old.span_waits("a") == [0.0, 0.0, later.wait_s]
+    assert len(old.span_samples("a")) == 3
+
+
+def test_the_wait_is_a_host_event_of_its_own_and_the_sink_gets_it(tmp_path):
+    tele = Telemetry()
+    seen = []
+    tele.add_sink(seen.append)
+    program = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((128, 128))
+    program(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tele.span("train"):
+            with tele.span("step_chunk") as span:
+                span.sync(program(x))
+            with tele.span("chunk_records"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    assert events.get("train/step_chunk") == 1
+    assert events.get("train/step_chunk/wait") == 1
+    assert "train/chunk_records/wait" not in events
+    # no character at which the benchmark's gap names are cut
+    assert not set("#([:") & set("train/step_chunk/wait")
+    # an annotation, not a span: the bus has no sample of that path
+    assert "train/step_chunk/wait" not in tele.snapshot()["spans"]
+    by_name = {e["name"]: e for e in seen if e["kind"] == "span"}
+    assert by_name["train/step_chunk"]["wait_s"] == span.wait_s > 0.0
+    assert by_name["train/chunk_records"]["wait_s"] == 0.0
+
+
+def test_compiles_inside_a_synced_span_stay_filed_under_the_span():
+    """The wait opens no span: a fused fit's first chunk traces, lowers
+    and compiles inside ``train/step_chunk``, and every one of those
+    events is still that path's; the ring the benchmark reaches into is
+    the durations, as it was."""
+    tele = Telemetry()
+    x, y = _rows()
+    train_distributed(_payload(), x, labels=y, iters=8, steps_per_call=4,
+                      mini_batch=8, telemetry=tele)
+    filed = _jit_sums(tele, "train/step_chunk")
+    assert filed["jit.trace_s"]["sum"] > 0 and filed["jit.lower_s"]["sum"] > 0
+    assert filed["jit.compile_s"]["count"] + filed[
+        "jit.cache_load_s"]["count"] >= 1
+    assert all(h["count"] == 0 for h in _jit_sums(
+        tele, "train/step_chunk/wait").values())
+    assert not any("wait" in k for k in tele.snapshot()["spans"])
+    samples = tele.span_samples("train/step_chunk")
+    waits = tele.span_waits("train/step_chunk")
+    assert len(samples) == len(waits) == 2
+    assert all(0.0 < w <= d for w, (_t0, d) in zip(waits, samples))
+    # the first chunk's enqueue holds its trace and compile; later ones
+    # enqueue in a fraction of what they wait
+    assert samples[0][1] - waits[0] > sum(
+        h["sum"] for h in filed.values()) * 0.5
+    ring = list(tele._spans[("train/step_chunk", ())].ring)
+    assert ring == [d for _t0, d in samples]
+    assert all(isinstance(d, float) for d in ring)
+
+
 def test_compile_events_go_to_the_innermost_open_span_and_nowhere_else():
     mine, other = Telemetry(), Telemetry()
 
