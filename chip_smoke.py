@@ -5,7 +5,7 @@ points a user calls; data is made from ``--seed`` and nothing is
 fetched. It fails before any phase unless JAX's first device is a TPU
 — it never sets a platform itself.
 
-With no arguments (one chip), four phases:
+With no arguments (one chip), five phases:
 
 - ``trainer_sync``   the README flow at BERT-base width:
   ``serialize_torch_obj(bert_base())`` -> ``SparkTorch(mode=
@@ -19,6 +19,15 @@ With no arguments (one chip), four phases:
   ``train_distributed`` drives it; the COMPILED step's text must hold
   each of the five Pallas kernels as a ``tpu_custom_call``; at seq
   2048 flash-vs-dense and fused-vs-dense losses and gradients agree.
+- ``qk_norm_rope``   the decoder's fused q/k norm, rotary step, cast
+  and turn heads first (``ops/qk_norm_rope.py``) against its plain
+  spelling (``rms_norm`` + ``_rotate`` + cast + ``heads_first``) at one
+  window layer's shape (2 rows of 8,192 tokens, 64 query heads on 8
+  key/value heads of 128, all dims rotated) and at a full layer's
+  rotary (48 heads, 64 of the 128 dims rotated, the attention factor)
+  on shorter rows: values and the five gradients. Mosaic's lane rolls
+  and the blocks' index maps are checked here, where interpret mode
+  cannot.
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -60,6 +69,11 @@ TOL_PREDICT_ABS = 2e-2       # predictor logits vs plain apply (logits O(1))
 TOL_PARITY_LOSS_REL = 1e-3   # kernel-vs-dense loss at seq 2048
 TOL_PARITY_GRAD_REL = 2e-2   # ||g_kernel - g_dense|| / ||g_dense||
 TOL_DP_LOSS_REL = 5e-3       # dp=4 vs one chip, per-step loss
+# the fused q/k pass against its plain spelling, ||a - b|| / ||b||: both
+# compute in float32 and round once, so values differ by a bf16 step on
+# a rounding boundary here and there, gradients by float32's order of sums
+TOL_FUSED_VALUE_REL = 2e-3
+TOL_FUSED_GRAD_REL = 1e-4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +100,13 @@ class Sizes:
     lm_batch: int = 2
     lm_steps: int = 4
     parity_seq: int = 2048
+    # qk_norm_rope: (rows, tokens, query heads, rotated pairs, attention
+    # factor) of Laguna-XS.2's window layer at the cell's rows, and of
+    # its full layer on shorter rows; 8 key/value heads of 128
+    fused_cases: tuple = ((2, 8192, 64, 64, 1.0),
+                          (1, 2048, 48, 32, 1.4158883083359672))
+    fused_kv_heads: int = 8
+    fused_head_dim: int = 128
     # trainer_hogwild: bench resnet18_hogwild
     hog_rows: int = 1024
     hog_mini_batch: int = 256
@@ -326,6 +347,74 @@ def phase_kernels(sz: Sizes, seed: int, ctx: dict) -> str:
     return (f"compile_s={compile_s:.2f} steps={sz.lm_steps} run_s={run_s:.3f} "
             f"seq={sz.lm_seq} losses={[round(float(v), 4) for v in losses]} "
             f"tpu_custom_calls={json.dumps(counts)} | {parity}")
+
+
+def phase_qk_norm_rope(sz: Sizes, seed: int, ctx: dict) -> str:
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.models.sparse_moe_lm import _rotate, rms_norm
+    from sparktorch_tpu.ops.qk_norm_rope import qk_norm_rope, tables
+    from sparktorch_tpu.ops.sparse_attention import heads_first
+
+    hkv, d, eps, dt = sz.fused_kv_heads, sz.fused_head_dim, 1e-6, jnp.bfloat16
+
+    def plain(xq, xk, xv, gq, gk, angles, factor):
+        b, t = angles.shape[:2]
+        cos = factor * jnp.cos(angles)[:, :, None]
+        sin = factor * jnp.sin(angles)[:, :, None]
+        heads = lambda x: x.reshape(b, t, -1, d)
+        q = _rotate(rms_norm(heads(xq), gq, eps), cos, sin)
+        k = _rotate(rms_norm(heads(xk), gk, eps), cos, sin)
+        return heads_first(q.astype(dt), k.astype(dt), heads(xv).astype(dt),
+                           "plain")
+
+    def rel(a, b):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+    report = []
+    for b, t, heads, half, factor in sz.fused_cases:
+        keys = jax.random.split(jax.random.key(seed), 10)
+        xq, xk, xv = (jax.random.normal(k, (b, t, h * d)) * jnp.exp(
+            jax.random.normal(keys[3], (b, t, 1)))
+            for k, h in zip(keys[:3], (heads, hkv, hkv)))
+        gq, gk = (1.0 + 0.2 * jax.random.normal(k, (d,)) for k in keys[4:6])
+        angles = jax.random.uniform(keys[6], (b, t, half)) * 100.0
+        weights = [jax.random.normal(k, s).astype(dt) for k, s in zip(
+            keys[7:], ((b, hkv, heads // hkv, t, d), (b, hkv, t, d),
+                       (b, hkv, t, d)))]
+
+        def fused(xq, xk, xv, gq, gk):
+            return qk_norm_rope(xq, xk, xv, gq, gk,
+                                *tables(angles, d, factor), eps, half, dt)
+
+        def both(fn):
+            def loss(*a):
+                return sum(jnp.sum(o.astype(jnp.float32) * w) for o, w in
+                           zip(fn(*a), weights))
+            return jax.jit(lambda *a: (fn(*a), jax.grad(
+                loss, argnums=(0, 1, 2, 3, 4))(*a)))
+
+        operands = (xq, xk, xv, gq, gk)
+        run = both(fused)
+        got = jax.block_until_ready(run(*operands))
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*operands))
+        fused_s = time.perf_counter() - t0
+        want = jax.block_until_ready(both(
+            lambda *a: plain(*a, angles, factor))(*operands))
+        value_rel = max(map(rel, got[0], want[0]))
+        grad_rel = max(map(rel, got[1], want[1]))
+        report.append(f"{heads}x{t}x{b} half={half} value_rel="
+                      f"{value_rel:.2e} grad_rel={grad_rel:.2e} "
+                      f"fwd_and_grad_s={fused_s:.4f}")
+        if not (value_rel <= TOL_FUSED_VALUE_REL          # NaN fails too
+                and grad_rel <= TOL_FUSED_GRAD_REL):
+            raise AssertionError(
+                f"fused q/k pass vs plain spelling: {report[-1]} (limits "
+                f"{TOL_FUSED_VALUE_REL}, {TOL_FUSED_GRAD_REL})")
+    return " | ".join(report)
 
 
 def phase_trainer_hogwild(sz: Sizes, seed: int, ctx: dict) -> str:
@@ -591,6 +680,7 @@ def phase_moe_ep4(sz: Sizes, seed: int, ctx: dict) -> str:
 ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("predictor", phase_predictor),
             ("kernels", phase_kernels),
+            ("qk_norm_rope", phase_qk_norm_rope),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),

@@ -17,7 +17,8 @@ models are built on it:
   :func:`laguna_lm`; "Layers of several kinds" below).
 
 Shared by all, written once: the projections, q/k norm and rotary step
-around the attention kernel (``_GroupedQueryProjections``), the expert
+around the attention kernel (``_GroupedQueryProjections``; who lays out
+what is under "Layouts around the attention kernels" below), the expert
 layer (``HeldExperts``, ``held_experts_sum``), ``rms_norm``, a layer's
 remat with its attention kernel's output and row statistics kept, the
 head's padding to the fused cross entropy's tile, the counters and
@@ -78,6 +79,27 @@ row statistics (``ops.sparse_attention.SAVED_NAMES``: one activation of
 head, for each layer; each kind of attention names its own, and a
 layer's remat lists its kind's), so the selection and the attention's
 forward kernel run once a layer a step.
+
+Layouts around the attention kernels. The three products leave their
+einsums float32 ``[b, T, heads * head_dim]`` (``_heads``: the weights
+enter flat, a head is a block of lanes). ``ops/qk_norm_rope.py`` reads
+them so and writes ``q5 [b, kv_heads, G, T, head_dim]``, ``k4`` and
+``v4 [b, kv_heads, T, head_dim]`` in the compute dtype: the q/k norm,
+the rotary step of the layer's table (:func:`rotary_table`, built once a
+table a forward pass), the cast and the turn heads first are ONE kernel
+forward and one backward, under the scope ``attn_qk_rope``. Every
+attention module hands those to its op's heads-first entry
+(``sparse_attention_heads_first``, ``rule_attention_heads_first``,
+``block_diffusion_attention_heads_first``), which returns ``o5`` like
+``q5`` and, backward, the three cotangents as its kernels write them,
+which the fused op's backward kernel reads so. The one turn a module
+still makes with an XLA transpose is the output's: ``heads_last(o5)``
+to ``[b, T, heads, head_dim]`` for the gate and ``Wo``, and its
+transpose on the cotangent. The ``[b, T, h, d]`` entries of the three
+ops (``sparse_attention``, ``rule_attention``,
+``block_diffusion_attention``) are thin wrappers for other callers and
+the tests; ``rms_norm`` and ``_rotate`` serve the block norms and the
+indexer, and are the plain spelling the tests hold the fused op to.
 
 Masked block diffusion. The forward pass of the second model is its
 TRAINING forward: a row ``x_0`` of ``L`` ids is noised (one level a row,
@@ -171,12 +193,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from sparktorch_tpu.ops import qk_norm_rope as fused
 from sparktorch_tpu.ops.block_diffusion_attention import (
     SAVED_NAMES as BLOCKDIFF_SAVED_NAMES, BlockDiffusionMask,
-    block_diffusion_attention)
+    block_diffusion_attention_heads_first)
 from sparktorch_tpu.ops.rule_attention import (
-    Causal, CausalWindow, rule_attention, saved_names, tiles_visited)
-from sparktorch_tpu.ops.sparse_attention import SAVED_NAMES, sparse_attention
+    Causal, CausalWindow, rule_attention_heads_first, saved_names,
+    tiles_visited)
+from sparktorch_tpu.ops.sparse_attention import (
+    SAVED_NAMES, heads_last, sparse_attention_heads_first)
 from sparktorch_tpu.utils.losses import TokenWeighted
 
 _MASK_NAME = "sparse_attn_mask"
@@ -379,6 +404,15 @@ def rotary_angles(position_ids, rotary: Rotary):
     return jnp.moveaxis(pos, 0, -1) * inv  # [3->pairs, b, T] -> [b, T, pairs]
 
 
+def rotary_table(position_ids, rotary: Rotary, head_dim: int):
+    """``(cos, sin)`` float32 ``[b, T, head_dim]`` of ``rotary`` as
+    ``ops/qk_norm_rope.py`` reads a table (``tables`` there): whole
+    heads, signed, the ``attention_factor`` on the rotated dims alone;
+    built once a table a forward pass and shared by its layers."""
+    return fused.tables(rotary_angles(position_ids, rotary), head_dim,
+                        rotary.attention_factor)
+
+
 # -- selection ---------------------------------------------------------------
 
 
@@ -482,9 +516,10 @@ def selected_keys(q_idx, k_idx, w, topk: int, chunk: int):
 
 class _GroupedQueryProjections(nn.Module):
     """What every attention does around its kernels: ``q`` (the layer's
-    own number of heads), ``k``, ``v`` without biases, RMSNorm over each
-    head of ``q`` and ``k``, the rotary step of the layer's table; and
-    the output projection."""
+    own number of heads), ``k``, ``v`` without biases; RMSNorm over each
+    head of ``q`` and ``k``, the rotary step of the layer's table, the
+    cast and the turn into the kernels' layout as one fused op
+    (``ops/qk_norm_rope.py``); and the output projection."""
 
     config: SparseMoEConfig
     kind: LayerKind
@@ -497,23 +532,37 @@ class _GroupedQueryProjections(nn.Module):
         return jnp.einsum("btd,d...->bt...", x.astype(dt), w.astype(dt),
                           preferred_element_type=jnp.float32)
 
-    def _qkv(self, h, angles):
-        cfg, d, hd = self.config, h.shape[-1], self.config.head_dim
-        with jax.named_scope("attn_qkv"):  # the three products
-            q = self._proj(h, self._dense("wq", (d, self.kind.n_heads, hd)))
-            k = self._proj(h, self._dense("wk", (d, cfg.n_kv_heads, hd)))
-            v = self._proj(h, self._dense("wv", (d, cfg.n_kv_heads, hd)))
+    def _heads(self, x, name, heads):
+        """``x W`` for the ``heads`` heads of the parameter ``name`` ``[d,
+        heads, head_dim]``, float32 ``[b, T, heads * head_dim]``: a head
+        is a block of lanes. The weights enter the product FLAT (a free
+        reshape), so it leaves tokens down and lanes across, the layout
+        the fused op reads; from ``btd,dhk->bthk`` the compiler writes it
+        tokens minor and a transposing copy of the whole float32 product
+        follows (PERF.md section 6, PR 38)."""
+        dt, d = self.config.compute_dtype, x.shape[-1]
+        w = self._dense(name, (d, heads, self.config.head_dim))
+        return jnp.einsum("btd,df->btf", x.astype(dt),
+                          w.reshape(d, -1).astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    def _qkv(self, h, table):
+        """``q5 [b, kv_heads, G, T, head_dim]``, ``k4`` and ``v4 [b,
+        kv_heads, T, head_dim]`` in the compute dtype, as the attention
+        kernels' heads-first entries read them; ``table`` is the layer's
+        ``(cos, sin)`` of :func:`rotary_table`."""
+        cfg, hd = self.config, self.config.head_dim
+        with jax.named_scope("attn_qkv"):  # the three products, float32
+            q = self._heads(h, "wq", self.kind.n_heads)
+            k = self._heads(h, "wk", cfg.n_kv_heads)
+            v = self._heads(h, "wv", cfg.n_kv_heads)
         ones = nn.initializers.ones
-        # the float32 passes a head of q and k: norm, table, rotation
+        # norm, rotation, cast and the turn heads first: one kernel
         with jax.named_scope("attn_qk_rope"):
-            q = rms_norm(q, self.param("q_norm", ones, (hd,)), cfg.rms_eps)
-            k = rms_norm(k, self.param("k_norm", ones, (hd,)), cfg.rms_eps)
-            cos, sin = (jnp.cos(angles)[:, :, None],
-                        jnp.sin(angles)[:, :, None])
-            factor = self.kind.rotary.attention_factor
-            if factor != 1.0:  # on the rotated dims alone
-                cos, sin = factor * cos, factor * sin
-            return _rotate(q, cos, sin), _rotate(k, cos, sin), v
+            return fused.qk_norm_rope(
+                q, k, v, self.param("q_norm", ones, (hd,)),
+                self.param("k_norm", ones, (hd,)), *table, cfg.rms_eps,
+                sum(self.kind.rotary.sections), cfg.compute_dtype)
 
     def _out(self, o, d):
         cfg, dt = self.config, self.config.compute_dtype
@@ -528,11 +577,11 @@ class _GroupedQueryProjections(nn.Module):
 class SparseAttention(_GroupedQueryProjections):
 
     @nn.compact
-    def __call__(self, h, angles, temporal):
+    def __call__(self, h, table, temporal):
         cfg, dt = self.config, self.config.compute_dtype
         d = h.shape[-1]
         dense, proj = self._dense, self._proj
-        q, k, v = self._qkv(h, angles)
+        q5, k4, v4 = self._qkv(h, table)
 
         with jax.named_scope("indexer"):
             hi = jax.lax.stop_gradient(h)
@@ -556,8 +605,7 @@ class SparseAttention(_GroupedQueryProjections):
             _MASK_NAME)
         self.sow("intermediates", "selected", mask)  # for whoever asks
         with jax.named_scope("sparse_attention"):
-            o = sparse_attention(q.astype(dt), k.astype(dt), v.astype(dt),
-                                 mask)
+            o = heads_last(sparse_attention_heads_first(q5, k4, v4, mask))
         return self._out(o, d)
 
 
@@ -566,18 +614,18 @@ class BlockDiffusionAttention(_GroupedQueryProjections):
     of a clean row and its noised copy: no indexer, nothing selected."""
 
     @nn.compact
-    def __call__(self, h, angles, temporal):
-        cfg, dt = self.config, self.config.compute_dtype
+    def __call__(self, h, table, temporal):
+        cfg = self.config
         b, t, d = h.shape
         del temporal
-        q, k, v = self._qkv(h, angles)
+        q5, k4, v4 = self._qkv(h, table)
         rule = BlockDiffusionMask(t // 2, cfg.block_length)
         visited, total = tiles_visited(rule, t)
         self.sow("moe_metrics", "attn_tiles", b * cfg.n_kv_heads
                  * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope("block_diffusion_attention"):
-            o = block_diffusion_attention(q.astype(dt), k.astype(dt),
-                                          v.astype(dt), rule)
+            o = heads_last(block_diffusion_attention_heads_first(
+                q5, k4, v4, rule))
         return self._out(o, d)
 
 
@@ -599,20 +647,20 @@ class RuleAttention(_GroupedQueryProjections):
     token, from the layer's normed input, before ``Wo``."""
 
     @nn.compact
-    def __call__(self, h, angles, temporal):
+    def __call__(self, h, table, temporal):
         cfg, dt = self.config, self.config.compute_dtype
         b, t, d = h.shape
         del temporal
         name = _RULE_NAMES[self.kind.attention]
-        q, k, v = self._qkv(h, angles)
+        q5, k4, v4 = self._qkv(h, table)
         rule = layer_rule(cfg, self.kind)
         visited, total = tiles_visited(rule, t)
         self.sow("moe_metrics", f"attn_tiles_{self.kind.attention}",
                  b * cfg.n_kv_heads
                  * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope(f"{name}_attention"):
-            o = rule_attention(q.astype(dt), k.astype(dt), v.astype(dt),
-                               rule, name)
+            o = heads_last(rule_attention_heads_first(q5, k4, v4, rule,
+                                                      name))
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 gate = jax.nn.sigmoid(self._proj(
@@ -893,14 +941,14 @@ class DecoderLayer(nn.Module):
     kind: LayerKind
 
     @nn.compact
-    def __call__(self, x, angles, temporal):
+    def __call__(self, x, table, temporal):
         cfg, kind = self.config, self.kind
         ones = nn.initializers.ones
         d = x.shape[-1]
         norm = jax.named_scope("block_norm")(rms_norm)
         h = norm(x, self.param("attn_norm", ones, (d,)), cfg.rms_eps)
         attention, _ = _ATTENTION[kind.attention]
-        x = x + attention(cfg, kind, name="attn")(h, angles, temporal)
+        x = x + attention(cfg, kind, name="attn")(h, table, temporal)
         if kind.mlp == "dense":
             g = norm(x, self.param("mlp_norm", ones, (d,)), cfg.rms_eps)
             return x + SwiGLU(cfg, cfg.dense_width, "dense_mlp",
@@ -1046,10 +1094,11 @@ class SparseMoELM(nn.Module):
             self.sow("moe_metrics", "masked_tokens",
                      jnp.sum(masked, dtype=jnp.float32))
             self.sow("moe_metrics", "tokens", jnp.float32(b * t))
-        # one table of angles a rotary table among the layers, and one
+        # one (cos, sin) a rotary table among the layers, and one
         # rematerialised layer class a set of names an attention keeps
         with jax.named_scope("attn_qk_rope"):
-            angles = {rotary: rotary_angles(position_ids, rotary)
+            tables = {rotary: rotary_table(position_ids, rotary,
+                                           cfg.head_dim)
                       for rotary in dict.fromkeys(
                           k.rotary for k in cfg.layers)}
         temporal = position_ids[0]
@@ -1064,7 +1113,7 @@ class SparseMoELM(nn.Module):
         for i, kind in enumerate(cfg.layers):
             layer = remat[_ATTENTION[kind.attention][1]]
             x = layer(cfg, kind, name=f"layer_{i}")(
-                x, angles[kind.rotary], temporal)
+                x, tables[kind.rotary], temporal)
         if diffusion:
             x = x[:, t:]  # the clean half's last output enters nothing
         with jax.named_scope("block_norm"):

@@ -22,6 +22,11 @@ are visited and 89% of the pairs in them are kept.
 ``blockdiff``: its kernels are ``blockdiff_attn_fwd``,
 ``blockdiff_attn_bwd_dq`` and ``blockdiff_attn_bwd_dkv`` in a trace, and
 its forward rule names :data:`SAVED_NAMES` for a caller's remat policy.
+It takes ``[b, T, h, d]`` operands and turns them itself;
+:func:`block_diffusion_attention_heads_first` is
+``rule_attention_heads_first`` under the same name, operands and result
+in the kernels' layout with nothing transposed, which the decoder calls
+behind ``ops/qk_norm_rope.py``.
 """
 
 from __future__ import annotations
@@ -30,7 +35,8 @@ import dataclasses
 
 import jax
 
-from sparktorch_tpu.ops.rule_attention import rule_attention, saved_names
+from sparktorch_tpu.ops.rule_attention import (
+    rule_attention, rule_attention_heads_first, saved_names)
 
 _NAME = "blockdiff"
 # what the forward rule names for a caller's remat policy
@@ -66,3 +72,11 @@ def block_diffusion_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     for it (``rule_attention``'s shapes); ``rule`` is static, a
     :class:`BlockDiffusionMask` for ``T`` tokens or a callable like it."""
     return rule_attention(q, k, v, rule, _NAME)
+
+
+def block_diffusion_attention_heads_first(q5: jax.Array, k4: jax.Array,
+                                          v4: jax.Array, rule) -> jax.Array:
+    """:func:`block_diffusion_attention` on operands in the kernels'
+    layout (``rule_attention_heads_first``'s shapes): ``q5, k4, v4 ->
+    o5``, nothing transposed on either side."""
+    return rule_attention_heads_first(q5, k4, v4, rule, _NAME)

@@ -46,6 +46,15 @@ forward kernel calls it on a row of queries and a column of keys and
 gets the turned mask with nothing transposed (:func:`_keep`); dq and
 dkv keep the tile queries down.
 
+Two entries, as ``ops/sparse_attention.py`` has them and says more of:
+:func:`rule_attention_heads_first` takes and returns the kernels' layout
+(``q5``, ``k4``, ``v4`` -> ``o5``, the cotangents back as the kernels
+write them) and carries the ``custom_vjp``; the decoder calls it, with
+``ops/qk_norm_rope.py`` writing ``q5``, ``k4`` and ``v4`` in that layout
+and the module turning ``o5`` back. :func:`rule_attention` takes ``[b,
+T, h, d]`` operands and is a thin wrapper that makes the turns itself
+with XLA transposes.
+
 A call carries a static ``name``: its kernels are the ``pallas_call``s
 ``<name>_attn_fwd``, ``<name>_attn_bwd_dq`` and ``<name>_attn_bwd_dkv``,
 so that two kinds of layer in one step separate in a device trace, and
@@ -71,8 +80,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from sparktorch_tpu.ops.sparse_attention import (
-    _LANES, _blocks, _heads_first, _heads_last, _interpret, dkv_tile,
-    dq_tile, fwd_finalize, fwd_init, fwd_scratch, fwd_tile, row_statistics)
+    _LANES, _blocks, _interpret, dkv_tile, dq_tile, fwd_finalize, fwd_init,
+    fwd_scratch, fwd_tile, heads_first, heads_last, row_statistics)
 
 
 def saved_names(name: str) -> tuple:
@@ -290,15 +299,11 @@ def _bwd(rule, name, q5, k4, v4, o5, lse, do5):
     return dq5, dk4, dv4
 
 
-def _check(q, k, v, name):
-    b, t, hq, d = q.shape
-    hkv = k.shape[2]
-    if k.shape != v.shape or k.shape[:2] != (b, t) or k.shape[3] != d:
-        raise ValueError(f"{name}_attn: q {q.shape}, k {k.shape}, v "
-                         f"{v.shape} do not go together")
-    if hq % hkv:
-        raise ValueError(f"{name}_attn: {hq} query heads are not a multiple "
-                         f"of {hkv} key/value heads")
+def _check(q5, k4, v4, name):
+    b, hkv, _, t, d = q5.shape
+    if k4.shape != v4.shape or k4.shape != (b, hkv, t, d):
+        raise ValueError(f"{name}_attn: q {q5.shape}, k {k4.shape}, v "
+                         f"{v4.shape} do not go together")
     if d % _LANES or t % _LANES:
         raise ValueError(
             f"{name}_attn: seq {t} x head_dim {d} cannot be tiled: both "
@@ -306,34 +311,41 @@ def _check(q, k, v, name):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def rule_attention_heads_first(q5: jax.Array, k4: jax.Array, v4: jax.Array,
+                               rule, name: str) -> jax.Array:
+    """:func:`rule_attention` on operands in the kernels' layout: ``q5
+    [b, kv_heads, G, T, d]``, ``k4`` and ``v4 [b, kv_heads, T, d]`` ->
+    ``o5`` like ``q5``; the cotangents of ``q5``, ``k4`` and ``v4`` come
+    back as the backward kernels write them. Nothing is transposed on
+    either side."""
+    return _forward(q5, k4, v4, rule, name)[0]
+
+
+def _forward(q5, k4, v4, rule, name):
+    _check(q5, k4, v4, name)
+    o5, lse = _fwd(rule, name, q5, k4, v4)
+    out_name, lse_name = saved_names(name)
+    o5 = checkpoint_name(o5, out_name)
+    lse = checkpoint_name(lse, lse_name)
+    return o5, (q5, k4, v4, o5, lse)
+
+
+def _bwd_rule(rule, name, res, do5):
+    q5, k4, v4, o5, lse = res
+    return _bwd(rule, name, q5, k4, v4, o5, lse, do5.astype(q5.dtype))
+
+
+rule_attention_heads_first.defvjp(_forward, _bwd_rule)
+
+
 def rule_attention(q: jax.Array, k: jax.Array, v: jax.Array, rule,
                    name: str) -> jax.Array:
     """``softmax`` attention of each query over the keys ``rule`` keeps
     for it. ``q`` is ``[b, T, heads, d]``, ``k`` and ``v`` are ``[b, T,
     kv_heads, d]`` (query head ``i`` reads key/value head ``i // (heads
     // kv_heads)``); ``rule`` and ``name`` are static: a rule for ``T``
-    tokens, and what the call's kernels and saved arrays are called."""
-    return _forward(q, k, v, rule, name)[0]
-
-
-def _forward(q, k, v, rule, name):
-    _check(q, k, v, name)
-    hkv = k.shape[2]
-    q5 = _heads_first(q, hkv)
-    k4, v4 = (jnp.swapaxes(x, 1, 2) for x in (k, v))
-    o5, lse = _fwd(rule, name, q5, k4, v4)
-    out_name, lse_name = saved_names(name)
-    o5 = checkpoint_name(o5, out_name)
-    lse = checkpoint_name(lse, lse_name)
-    return _heads_last(o5), (q5, k4, v4, o5, lse)
-
-
-def _bwd_rule(rule, name, res, g):
-    q5, k4, v4, o5, lse = res
-    dq5, dk4, dv4 = _bwd(rule, name, q5, k4, v4, o5, lse,
-                         _heads_first(g.astype(q5.dtype), k4.shape[1]))
-    return (_heads_last(dq5), jnp.swapaxes(dk4, 1, 2),
-            jnp.swapaxes(dv4, 1, 2))
-
-
-rule_attention.defvjp(_forward, _bwd_rule)
+    tokens, and what the call's kernels and saved arrays are called. A
+    thin wrapper: it turns its operands heads first, calls
+    :func:`rule_attention_heads_first` and turns the result back."""
+    return heads_last(rule_attention_heads_first(
+        *heads_first(q, k, v, f"{name}_attn"), rule, name))

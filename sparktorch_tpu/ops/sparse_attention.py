@@ -56,6 +56,20 @@ call, and does not launch ``sparse_attn_fwd`` a second time. Outside a
 identities. On their way into dq and dkv the statistics and ``sum(o *
 do)`` are spread over 128 lanes by XLA (:func:`row_statistics`).
 
+Two entries, one set of kernels. :func:`sparse_attention_heads_first`
+takes and returns the kernels' own layout (``q5 [b, kv_heads, G, T,
+d]``, ``k4`` and ``v4 [b, kv_heads, T, d]`` -> ``o5``; backward the
+cotangents ``dq5``, ``dk4``, ``dv4`` as the kernels write them) and
+carries the ``custom_vjp``: nothing is transposed on either side. The
+decoder calls it (``models/sparse_moe_lm.py``): there the turn INTO the
+layout is made by ``ops/qk_norm_rope.py``'s kernels, whose output
+blocks' index map it is, and the turn of ``o5`` back to ``[b, T, h,
+d]`` (:func:`heads_last`, and its transpose on the output's cotangent)
+by the module, an XLA transpose still. :func:`sparse_attention` takes
+``[b, T, h, d]`` operands: a thin wrapper that makes both turns itself
+with XLA transposes (:func:`heads_first`, :func:`heads_last`), for the
+tests and any caller whose operands lie tokens first.
+
 ``pallas_call`` names: ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``,
 ``sparse_attn_bwd_dkv``. Off the TPU they run in interpret mode. A
 shape that does not tile is an error everywhere: there is no dense
@@ -247,15 +261,11 @@ def _blocks(seq: int) -> tuple:
     return largest(256), largest(512)
 
 
-def _check(q, k, v, mask):
-    b, t, hq, d = q.shape
-    hkv = k.shape[2]
-    if k.shape != v.shape or k.shape[:2] != (b, t) or k.shape[3] != d:
-        raise ValueError(f"sparse_attention: q {q.shape}, k {k.shape}, "
-                         f"v {v.shape} do not go together")
-    if hq % hkv:
-        raise ValueError(f"sparse_attention: {hq} query heads are not a "
-                         f"multiple of {hkv} key/value heads")
+def _check(q5, k4, v4, mask):
+    b, hkv, _, t, d = q5.shape
+    if k4.shape != v4.shape or k4.shape != (b, hkv, t, d):
+        raise ValueError(f"sparse_attention: q {q5.shape}, k {k4.shape}, "
+                         f"v {v4.shape} do not go together")
     if mask.shape != (b, t, t) or mask.dtype != jnp.int8:
         raise ValueError(f"sparse_attention: the mask is {mask.dtype}"
                          f"{mask.shape}, not int8{(b, t, t)}")
@@ -265,13 +275,22 @@ def _check(q, k, v, mask):
             f"both must be multiples of {_LANES}")
 
 
-def _heads_first(x, hkv):
-    """``[b, T, h, d]`` -> ``[b, hkv, h // hkv, T, d]``."""
-    b, t, h, d = x.shape
-    return jnp.transpose(x.reshape(b, t, hkv, h // hkv, d), (0, 2, 3, 1, 4))
+def heads_first(q, k, v, name: str):
+    """``q [b, T, h, d]``, ``k`` and ``v [b, T, hkv, d]`` as the kernels
+    read them: ``q5 [b, hkv, h // hkv, T, d]``, ``k4`` and ``v4 [b, hkv,
+    T, d]``, by transposing copies (the decoder's fused
+    ``ops/qk_norm_rope.py`` writes this layout itself)."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv:
+        raise ValueError(f"{name}: {h} query heads are not a multiple of "
+                         f"{hkv} key/value heads")
+    return (jnp.transpose(q.reshape(b, t, hkv, h // hkv, d), (0, 2, 3, 1, 4)),
+            jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
 
 
-def _heads_last(x5):
+def heads_last(x5):
+    """``[b, hkv, G, T, d]`` -> ``[b, T, hkv * G, d]``."""
     b, hkv, g, t, d = x5.shape
     return jnp.transpose(x5, (0, 3, 1, 2, 4)).reshape(b, t, hkv * g, d)
 
@@ -389,6 +408,32 @@ def _bwd(q5, k4, v4, mask, o5, lse, do5):
 
 
 @jax.custom_vjp
+def sparse_attention_heads_first(q5: jax.Array, k4: jax.Array,
+                                 v4: jax.Array, mask: jax.Array) -> jax.Array:
+    """:func:`sparse_attention` on operands in the kernels' layout:
+    ``q5 [b, kv_heads, G, T, d]``, ``k4`` and ``v4 [b, kv_heads, T,
+    d]`` -> ``o5`` like ``q5``; the cotangents of ``q5``, ``k4`` and
+    ``v4`` come back as the backward kernels write them. Nothing is
+    transposed on either side."""
+    return _forward(q5, k4, v4, mask)[0]
+
+
+def _forward(q5, k4, v4, mask):
+    _check(q5, k4, v4, mask)
+    o5, lse = _fwd(q5, k4, v4, mask)
+    o5 = checkpoint_name(o5, SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
+    return o5, (q5, k4, v4, mask, o5, lse)
+
+
+def _bwd_rule(res, do5):
+    q5, k4, v4, mask, o5, lse = res
+    return (*_bwd(q5, k4, v4, mask, o5, lse, do5.astype(q5.dtype)), None)
+
+
+sparse_attention_heads_first.defvjp(_forward, _bwd_rule)
+
+
 def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      mask: jax.Array) -> jax.Array:
     """``softmax`` attention of each query over the keys its mask row
@@ -396,27 +441,8 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     T, kv_heads, d]`` (query head ``i`` reads key/value head ``i //
     (heads // kv_heads)``), ``mask`` is int8 ``[b, T, T]``, shared by
     all heads and zero above the diagonal. A query that selects nothing
-    gets zeros. No gradient reaches the mask."""
-    return _forward(q, k, v, mask)[0]
-
-
-def _forward(q, k, v, mask):
-    _check(q, k, v, mask)
-    hkv = k.shape[2]
-    q5 = _heads_first(q, hkv)
-    k4, v4 = (jnp.swapaxes(x, 1, 2) for x in (k, v))
-    o5, lse = _fwd(q5, k4, v4, mask)
-    o5 = checkpoint_name(o5, SAVED_NAMES[0])
-    lse = checkpoint_name(lse, SAVED_NAMES[1])
-    return _heads_last(o5), (q5, k4, v4, mask, o5, lse)
-
-
-def _bwd_rule(res, g):
-    q5, k4, v4, mask, o5, lse = res
-    dq5, dk4, dv4 = _bwd(q5, k4, v4, mask, o5, lse,
-                         _heads_first(g.astype(q5.dtype), k4.shape[1]))
-    return (_heads_last(dq5), jnp.swapaxes(dk4, 1, 2),
-            jnp.swapaxes(dv4, 1, 2), None)
-
-
-sparse_attention.defvjp(_forward, _bwd_rule)
+    gets zeros. No gradient reaches the mask. A thin wrapper: it turns
+    its operands heads first, calls
+    :func:`sparse_attention_heads_first` and turns the result back."""
+    return heads_last(sparse_attention_heads_first(
+        *heads_first(q, k, v, "sparse_attention"), mask))

@@ -174,6 +174,9 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "sparse_attn_fwd") == 1  # kept, not recomputed
     assert _pallas_calls(text, "sparse_attn_bwd_dq") == 1
     assert _pallas_calls(text, "sparse_attn_bwd_dkv") == 1
+    # the fused q/k pass: forward, the remat's forward, one backward
+    assert _pallas_calls(text, "qk_norm_rope_fwd") == 2
+    assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # 4,000 padded to 4,096
 
     grouped = re.compile(r"%ragged-dot-none[.\d]* = ")
@@ -220,6 +223,8 @@ def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
         (1, 4, 8, 16384)]
     assert _pallas_calls(text, "blockdiff_attn_bwd_dq") == 1
     assert _pallas_calls(text, "blockdiff_attn_bwd_dkv") == 1
+    assert _pallas_calls(text, "qk_norm_rope_fwd") == 2  # at 16,384 tokens
+    assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
     assert _pallas_calls(text, "sparse_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # on 8,192 rows
 
@@ -260,6 +265,13 @@ def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
         (2, 8, 8, 8192)]
     assert _forward_statistics(text, "causal_attn_fwd") == 2 * [
         (2, 8, 6, 8192)]
+    # the fused q/k pass at 6 and 8 heads a group, half and all of the
+    # dims rotated: twice forward (the remat's) and once backward a layer
+    assert _pallas_calls(text, "qk_norm_rope_fwd") == 10
+    assert _pallas_calls(text, "qk_norm_rope_bwd") == 5
+    # and the products reach it as they leave: no transposing copy of a
+    # float32 product (tokens minor from an einsum over [d, heads, 128])
+    assert not re.search(r"f32\[2,8192,(6144|8192)\]\S* copy\(", text)
     assert _pallas_calls(text, "blockdiff_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1
     # weights, gradients and Adam's moments are 7.84 GB of the 15.75

@@ -20,9 +20,12 @@ import jax.numpy as jnp
 import test_sparse_attention as sparse_case
 from sparktorch_tpu.ops import rule_attention as mod
 from sparktorch_tpu.ops import sparse_attention as sparse
-from sparktorch_tpu.ops.block_diffusion_attention import BlockDiffusionMask
+from sparktorch_tpu.ops.block_diffusion_attention import (
+    BlockDiffusionMask, block_diffusion_attention,
+    block_diffusion_attention_heads_first)
 from sparktorch_tpu.ops.rule_attention import (
-    Causal, CausalWindow, rule_attention, saved_names)
+    Causal, CausalWindow, rule_attention, rule_attention_heads_first,
+    saved_names)
 from test_block_diffusion_attention import D, _grads, dense
 from test_sparse_attention import pallas_calls
 
@@ -110,6 +113,35 @@ def test_forward_and_gradients_match_dense_masked_attention(
                                    err_msg=f"d{name}")
 
 
+HEADS_FIRST = {
+    # the [b, T, h, d] wrapper, and its heads-first entry
+    "window": (lambda *a: rule_attention(*a, CausalWindow(160), "window"),
+               lambda *a: rule_attention_heads_first(
+                   *a, CausalWindow(160), "window")),
+    "blockdiff": (
+        lambda *a: block_diffusion_attention(*a, BlockDiffusionMask(192, 4)),
+        lambda *a: block_diffusion_attention_heads_first(
+            *a, BlockDiffusionMask(192, 4))),
+}
+
+
+@pytest.mark.parametrize("name", list(HEADS_FIRST))
+def test_the_heads_first_entry_is_the_op_without_its_turns(name):
+    """What the decoder calls: operands as the kernels read them give
+    ``o5`` and, backward, the cotangents as the kernels write them, bit
+    for bit what the ``[b, T, h, d]`` wrapper turns in and out."""
+    wrapper, entry = HEADS_FIRST[name]
+    qkv = make_qkv(384, 6, kv=2)
+    q5, k4, v4 = sparse.heads_first(*qkv, "test")
+    assert q5.shape == (1, 2, 6, 384, D) and k4.shape == (1, 2, 384, D)
+    np.testing.assert_array_equal(sparse.heads_last(entry(q5, k4, v4)),
+                                  wrapper(*qkv))
+    got = _grads(entry, (q5, k4, v4))
+    want = sparse.heads_first(*_grads(wrapper, qkv), "test")
+    for a, b, which in zip(got, want, "qkv"):
+        np.testing.assert_array_equal(a, b, err_msg=f"d{which}")
+
+
 def test_a_key_512_back_changes_nothing_and_a_key_511_back_does():
     """The exact leak test, at the published window and the cell's tiles
     (1,024 tokens: 256 x 512): a change to key and value ``j`` leaves the
@@ -194,23 +226,25 @@ FORWARD_KERNELS = {
 
 
 def forward_call(name, dtype, empty_row: bool = False):
-    """``(forward rule of the kernel called name, q, k, v, its dense
-    mask [rows, T, T])``: the rule kernels under their own names, the
-    selected-key kernel on a random causal int8 mask; ``empty_row``:
-    query ``EMPTY`` keeps no key."""
+    """``(forward rule of the kernel called name on ``q, k, v`` turned
+    heads first, q, k, v, its dense mask [rows, T, T])``: the rule
+    kernels under their own names, the selected-key kernel on a random
+    causal int8 mask; ``empty_row``: query ``EMPTY`` keeps no key."""
     rule, groups = FORWARD_KERNELS[name]
     if name == "sparse":
         mask = np.array(sparse_case.make_mask("random", T))
         if empty_row:
             mask[:, EMPTY] = 0
         mask = jnp.asarray(mask)
-        return (lambda q, k, v: sparse._forward(q, k, v, mask),
+        return (lambda q, k, v: sparse._forward(
+                    *sparse.heads_first(q, k, v, name), mask),
                 *sparse_case.make_qkv(T, dtype), np.asarray(mask) != 0)
     if empty_row:
         rule = Without(rule, EMPTY)
     i, j = np.arange(T)[:, None], np.arange(T)[None, :]
     q, k, v = (x.astype(dtype) for x in make_qkv(T, groups, kv=2))
-    return (lambda q, k, v: mod._forward(q, k, v, rule, name), q, k, v,
+    return (lambda q, k, v: mod._forward(
+                *sparse.heads_first(q, k, v, name), rule, name), q, k, v,
             np.broadcast_to(rule(i, j), (1, T, T)))
 
 
@@ -260,7 +294,8 @@ def test_the_saved_statistics_are_the_dense_log_sum_exp(name):
         0] * (T - 1)
     np.testing.assert_allclose(lse, want.reshape(lse.shape), atol=1e-5,
                                rtol=1e-6)
-    assert np.all(np.asarray(out[:, EMPTY]) == 0)
+    assert out.shape == (q.shape[0], k.shape[2], groups, T, D)
+    assert np.all(np.asarray(out[..., EMPTY, :]) == 0)
     assert np.all(np.isfinite(np.asarray(out)))
 
 

@@ -9,7 +9,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from sparktorch_tpu.ops.sparse_attention import SAVED_NAMES, sparse_attention
+from sparktorch_tpu.ops.sparse_attention import (
+    SAVED_NAMES, heads_first, heads_last, sparse_attention,
+    sparse_attention_heads_first)
 
 B, HQ, HKV, D = 2, 4, 2, 128
 # sequence lengths and the tiles the kernels cut them into
@@ -92,6 +94,28 @@ def test_in_bfloat16_it_is_dense_attention_to_bfloat16s_precision():
     assert got.dtype == jnp.bfloat16
     want = dense(*(x.astype(jnp.float32) for x in qkv), mask)
     np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2)
+
+
+def test_the_heads_first_entry_is_the_op_without_its_turns(qkv):
+    """What the decoder calls: operands as the kernels read them (``q5
+    [b, kv_heads, G, T, d]``, ``k4``, ``v4``) give ``o5`` and, backward,
+    the cotangents as the kernels write them, bit for bit what the ``[b,
+    T, h, d]`` wrapper turns in and out."""
+    mask = make_mask("random", 384)
+    q5, k4, v4 = heads_first(*qkv, "test")
+    assert q5.shape == (B, HKV, HQ // HKV, 384, D)
+    assert k4.shape == v4.shape == (B, HKV, 384, D)
+    o5 = sparse_attention_heads_first(q5, k4, v4, mask)
+    np.testing.assert_array_equal(heads_last(o5),
+                                  sparse_attention(*qkv, mask))
+    weight = jnp.cos(jnp.arange(D, dtype=jnp.float32))
+    got = jax.grad(lambda *a: jnp.sum(
+        sparse_attention_heads_first(*a, mask) * weight),
+        argnums=(0, 1, 2))(q5, k4, v4)
+    want = heads_first(*_grads(lambda *a: sparse_attention(*a, mask), qkv),
+                       "test")
+    for a, b, name in zip(got, want, "qkv"):
+        np.testing.assert_array_equal(a, b, err_msg=f"d{name}")
 
 
 @pytest.mark.parametrize("bad", ["mask_dtype", "head_dim", "heads", "seq"])

@@ -449,6 +449,101 @@ def test_keeping_the_attentions_results_changes_no_gradient_leaf(
                for w in ("wq", "wk", "wv"))
 
 
+# -- the fused q/k pass (``ops/qk_norm_rope.py``) in the three models ---------
+
+
+def _tiny_loss(model):
+    """``(loss of the parameters, the parameters)`` of the tiny model of
+    that name: this file's, ``test_block_diffusion_lm``'s or
+    ``test_mixed_attention_lm``'s, on its own rows. A function of its
+    own each call: a trace is cached by function."""
+    if model == "keye":
+        (cfg, module), (ids, labels) = sizes(), rows()
+        loss_fn, ref, more = resolve_loss("cross_entropy"), REF, {}
+    elif model == "sdar":
+        import test_block_diffusion_lm as case
+        (cfg, module), ids = case.sizes(), case.rows()
+        loss_fn, ref, labels, more = case.LOSS, case.REF, ids, {
+            "rngs": case.STREAM}
+    else:
+        import test_mixed_attention_lm as case
+        (cfg, module), (ids, labels) = case.sizes(), case.rows()
+        loss_fn, ref, more = case.LOSS, case.REF, {}
+    params = ref.init(jax.random.key(0), cfg)["params"]
+    return (lambda p: jnp.sum(loss_fn(
+        module.apply({"params": p}, ids, **more), labels))), params
+
+
+def _transposes(jaxpr):
+    """``(permutation, operand's shape)`` of every ``transpose`` of the
+    jaxpr and of every jaxpr inside it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "transpose":
+            found.append((tuple(eqn.params["permutation"]),
+                          eqn.invars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _transposes(sub)
+    return found
+
+
+@pytest.fixture(scope="module", params=["keye", "sdar", "laguna"])
+def fused_and_plain(request):
+    """The tiny model's loss and gradient with the fused op and with the
+    op's plain spelling (``test_qk_norm_rope.plain``: ``rms_norm`` +
+    ``_rotate`` + cast + ``heads_first``) in its place, and the jaxpr of
+    the loss and gradient as the model builds them."""
+    from test_qk_norm_rope import plain
+
+    loss, params = _tiny_loss(request.param)
+    # one trace gives the jaxpr and, compiled, the numbers
+    traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+    fused = traced.lower().compile()(params)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(M.fused, "qk_norm_rope", plain)
+    spelled = jax.jit(jax.value_and_grad(_tiny_loss(request.param)[0]))(
+        params)
+    patch.undo()
+    layers = sum(name.startswith("layer_") for name in params)
+    return dict(fused=fused, plain=spelled, jaxpr=traced.jaxpr.jaxpr,
+                layers=layers)
+
+
+def test_the_fused_q_k_pass_is_its_plain_spelling_in_the_model(
+        fused_and_plain):
+    (loss, grads), (p_loss, p_grads) = (fused_and_plain[k]
+                                        for k in ("fused", "plain"))
+    assert abs(float(loss - p_loss)) < 1e-5 * abs(float(p_loss))
+    errs = jax.tree.map(rel, grads, p_grads)
+    assert max(jax.tree.leaves(errs)) < 1e-5, errs
+    assert all(float(jnp.linalg.norm(layer["attn"][w])) > 0
+               for name, layer in grads.items() if name.startswith("layer_")
+               for w in ("q_norm", "k_norm", "wq", "wk", "wv"))
+
+
+def test_the_fused_kernels_run_twice_forward_and_once_backward_a_layer(
+        fused_and_plain):
+    """Forward, the remat's forward again (its results are the
+    attention's operands, which no policy keeps), and one backward."""
+    jaxpr, n = fused_and_plain["jaxpr"], fused_and_plain["layers"]
+    assert pallas_calls(jaxpr, "qk_norm_rope_fwd") == 2 * n
+    assert pallas_calls(jaxpr, "qk_norm_rope_bwd") == n
+
+
+def test_nothing_is_turned_into_an_attention_kernel_but_its_outputs_cotangent(
+        fused_and_plain):
+    """q, k and v reach the kernels as the fused op writes them and
+    their cotangents leave as the kernels write them: of the turns
+    between ``[b, T, heads, 128]`` and heads first a layer keeps the
+    output's (forward and the remat's) and its cotangent's; no key or
+    value array is swapped either way."""
+    jaxpr, n = fused_and_plain["jaxpr"], fused_and_plain["layers"]
+    turns = [perm for perm, shape in _transposes(jaxpr) if shape[-1] == 128]
+    assert turns.count((0, 2, 3, 1, 4)) == n       # heads_first(do)
+    assert turns.count((0, 3, 1, 2, 4)) == 2 * n   # heads_last(o5)
+    assert (0, 2, 1, 3) not in turns
+
+
 def _float_arrays_by_pair(jaxpr, n_pairs):
     """Every float array with a row for each chosen pair (rank 2 or
     more, ``n_pairs`` or more rows) that an equation of the jaxpr, or of
