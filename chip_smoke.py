@@ -5,7 +5,7 @@ points a user calls; data is made from ``--seed`` and nothing is
 fetched. It fails before any phase unless JAX's first device is a TPU
 — it never sets a platform itself.
 
-With no arguments (one chip), five phases:
+With no arguments (one chip), six phases:
 
 - ``trainer_sync``   the README flow at BERT-base width:
   ``serialize_torch_obj(bert_base())`` -> ``SparkTorch(mode=
@@ -28,6 +28,15 @@ With no arguments (one chip), five phases:
   on shorter rows: values and the five gradients. Mosaic's lane rolls
   and the blocks' index maps are checked here, where interpret mode
   cannot.
+- ``latent_attention`` latent attention's two ops at JoyAI-LLM-Flash's
+  heads (32 of 192 / 128): ``ops/latent_rope.py`` (the rotary step on 64
+  of 192 dims with the weights' columns de-interleaved, the cast, the
+  turn heads first, the shared rotary key written a head) against the
+  plain interleaved rotation, scores, values and the three products'
+  gradients; and ``ops/latent_attention.py``'s kernels on what it wrote
+  against dense causal attention at the true widths, output and the
+  three gradients. The 256-lane padding, the lane rolls and the index
+  maps are checked here, where interpret mode cannot.
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -74,6 +83,10 @@ TOL_DP_LOSS_REL = 5e-3       # dp=4 vs one chip, per-step loss
 # a rounding boundary here and there, gradients by float32's order of sums
 TOL_FUSED_VALUE_REL = 2e-3
 TOL_FUSED_GRAD_REL = 1e-4
+# latent attention's kernels (bf16 operands, float32 sums) against dense
+# float32 attention on the same bf16 operands
+TOL_LATENT_OUT_REL = 1e-2
+TOL_LATENT_GRAD_REL = 2e-2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,6 +120,9 @@ class Sizes:
                           (1, 2048, 48, 32, 1.4158883083359672))
     fused_kv_heads: int = 8
     fused_head_dim: int = 128
+    # latent_attention: (rows, tokens, heads) of nope 128 + rope 64 over
+    # values of 128
+    latent_case: tuple = (1, 2048, 32)
     # trainer_hogwild: bench resnet18_hogwild
     hog_rows: int = 1024
     hog_mini_batch: int = 256
@@ -417,6 +433,85 @@ def phase_qk_norm_rope(sz: Sizes, seed: int, ctx: dict) -> str:
     return " | ".join(report)
 
 
+def phase_latent_attention(sz: Sizes, seed: int, ctx: dict) -> str:
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.ops.latent_attention import (
+        latent_attention_heads_first)
+    from sparktorch_tpu.ops.latent_rope import latent_rope
+    from sparktorch_tpu.ops.qk_norm_rope import tables
+
+    b, t, heads = sz.latent_case
+    nope, rope, dv, slot, dt = 128, 64, 128, 128, jnp.bfloat16
+    half, scale = rope // 2, (nope + rope) ** -0.5
+    order = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    keys = jax.random.split(jax.random.key(seed), 5)
+    q = jax.random.normal(keys[0], (b, t, heads, nope + rope))
+    kv = jax.random.normal(keys[1], (b, t, heads, nope + dv))
+    kr = jax.random.normal(keys[2], (b, t, rope))
+    angles = jnp.broadcast_to(
+        jnp.arange(t, dtype=jnp.float32)[None, :, None]
+        * 3.2e7 ** (-jnp.arange(half) / half), (b, t, half))
+    w_out = jax.random.normal(keys[3], (b, t, heads, dv))
+
+    def turned(x, ang):  # pair j is dims (2j, 2j + 1)
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         -1).reshape(x.shape)
+
+    def plain(q, kv, kr):
+        """Dense causal attention by the published equations, float32
+        on operands rounded to bf16 where the ops round them."""
+        r = lambda x: x.astype(dt).astype(jnp.float32)
+        qq = r(jnp.concatenate(
+            [q[..., :nope], turned(q[..., nope:], angles[:, :, None])], -1))
+        k_rope = jnp.broadcast_to(turned(kr, angles)[:, :, None],
+                                  (b, t, heads, rope))
+        kk = r(jnp.concatenate([kv[..., :nope], k_rope], -1))
+        s = jnp.einsum("bqhd,bkhd->bhqk", qq, kk,
+                       precision="highest") * scale
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                          r(kv[..., nope:]), precision="highest")
+
+    def mine(q, kv, kr):
+        slot_of = lambda x: jnp.pad(
+            x[..., order], ((0, 0),) * (x.ndim - 1) + ((0, slot - rope),))
+        xq = jnp.concatenate([q[..., :nope], slot_of(q[..., nope:])],
+                             -1).reshape(b, t, -1)
+        q5, k4, v4 = latent_rope(xq, kv.reshape(b, t, -1), slot_of(kr),
+                                 *tables(angles, slot), half, nope, dt)
+        o5 = latent_attention_heads_first(q5, k4, v4, scale)
+        return jnp.swapaxes(o5[:, :, 0], 1, 2).astype(jnp.float32)
+
+    def both(fn):
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *a: jnp.sum(fn(*a) * w_out), argnums=(0, 1, 2))(*a)))
+
+    def rel(a, b):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+    run = both(mine)
+    got = jax.block_until_ready(run(q, kv, kr))
+    t0 = time.perf_counter()
+    jax.block_until_ready(run(q, kv, kr))
+    mine_s = time.perf_counter() - t0
+    want = jax.block_until_ready(both(plain)(q, kv, kr))
+    out_rel = rel(got[0], want[0])
+    grad_rel = max(map(rel, got[1], want[1]))
+    report = (f"{heads}x{t}x{b} out_rel={out_rel:.2e} grad_rel="
+              f"{grad_rel:.2e} fwd_and_grad_s={mine_s:.4f}")
+    if not (out_rel <= TOL_LATENT_OUT_REL            # NaN fails too
+            and grad_rel <= TOL_LATENT_GRAD_REL):
+        raise AssertionError(
+            f"latent attention vs the plain equations: {report} (limits "
+            f"{TOL_LATENT_OUT_REL}, {TOL_LATENT_GRAD_REL})")
+    return report
+
+
 def phase_trainer_hogwild(sz: Sizes, seed: int, ctx: dict) -> str:
     from sparktorch_tpu import SparkTorch, serialize_torch_obj
     from sparktorch_tpu.models.resnet import resnet18
@@ -681,6 +776,7 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("predictor", phase_predictor),
             ("kernels", phase_kernels),
             ("qk_norm_rope", phase_qk_norm_rope),
+            ("latent_attention", phase_latent_attention),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),

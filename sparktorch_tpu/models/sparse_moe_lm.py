@@ -2,7 +2,7 @@
 experts that is told which experts it holds (Qwen3-MoE's block). What a
 layer is belongs to the LAYER (:class:`LayerKind`: its attention, its
 query heads, its rotary table, experts or a dense MLP after it), and a
-model is its list of layers (:class:`SparseMoEConfig` ``layers``). Three
+model is its list of layers (:class:`SparseMoEConfig` ``layers``). Four
 models are built on it:
 
 - every layer ``learned_sparse``: DeepSeek-V3.2's lightning indexer in
@@ -14,12 +14,17 @@ models are built on it:
 - ``full`` and ``window`` layers mixed 1:3 with different head counts and
   rotary tables, a gated attention output, a leading dense layer and a
   shared expert beside sigmoid-routed ones (Laguna-XS.2,
-  :func:`laguna_lm`; "Layers of several kinds" below).
+  :func:`laguna_lm`; "Layers of several kinds" below);
+- every layer ``latent``: queries, keys and values from two low-rank
+  latents, keys wider than values, a selection bias on the router and a
+  multi-token prediction module after the last layer (JoyAI-LLM-Flash,
+  :func:`joyai_flash_lm`; "Latent attention" and "Multi-token
+  prediction" below).
 
 Shared by all, written once: the projections, q/k norm and rotary step
-around the attention kernel (``_GroupedQueryProjections``; who lays out
-what is under "Layouts around the attention kernels" below), the expert
-layer (``HeldExperts``, ``held_experts_sum``), ``rms_norm``, a layer's
+around the attention kernel of the grouped-query kinds
+(``_GroupedQueryProjections``; who lays out what is under "Layouts
+around the attention kernels" below), the expert layer (``HeldExperts``, ``held_experts_sum``), ``rms_norm``, a layer's
 remat with its attention kernel's output and row statistics kept, the
 head's padding to the fused cross entropy's tile, the counters and
 gauges. What differs is the attention module (``_ATTENTION``, by the
@@ -168,6 +173,67 @@ lists each with its reason):
   convention carries is a buffer, zero here, and is left out; ties to the
   lower index.
 
+Latent attention (DeepSeek-V3's multi-head latent attention in its
+uncompressed, training form; :class:`LatentAttention`). For the tokens
+``x [T, d]`` of a row and ``H`` heads (JoyAI-LLM-Flash: 32), with
+``q_lora_rank`` 1,536, ``kv_lora_rank`` 512, ``qk_nope_dim`` 128,
+``qk_rope_dim`` 64, ``v_dim`` 128:
+
+- ``h = RMSNorm(x)``; ``c_q = RMSNorm(h W_dq)`` (``W_dq [d, 1536]``, a
+  gain a latent dim); ``q = c_q W_uq`` (``[1536, H x 192]``); each head
+  ``q_i = [q_i^nope (128) ; q_i^rope (64)]`` (scope ``latent_q`` inside
+  ``attn_qkv``).
+- ``[c_kv (512) ; k^rope (64)] = h W_dkv``; ``c_kv <- RMSNorm(c_kv)``;
+  ``[k_i^nope (128) ; v_i (128)] = c_kv W_ukv`` for each head (``[512, H x
+  256]``); ``k^rope`` is ONE 64-wide key a token, shared by all heads,
+  and is not normed (scope ``latent_kv``).
+- Rotary on ``q_i^rope`` and ``k^rope`` only, ``theta`` 3.2e7, 32 pairs
+  ``theta^(-2j / 64)``, by the token's index, INTERLEAVED: pair ``j`` is
+  dims ``(2j, 2j + 1)``. The module permutes the rotary COLUMNS of
+  ``W_uq`` and ``W_dkv`` (even dims, then odd: a pass over the weights)
+  and rotates by halves, queries and keys alike, so every score is the
+  interleaved rotation's; the same pass pads a head's query to 256
+  lanes, so the products leave their einsums in whole registers and
+  ``ops/latent_rope.py`` turns, casts and lays them out heads first in
+  one kernel each way (scope ``latent_rope`` inside ``attn_qk_rope``).
+- ``k_i = [k_i^nope ; k^rope]``; ``s_ij = q_i . k_j / sqrt(192)`` over
+  causal keys ``j <= i``; softmax; ``o_i = sum_j p_ij v_j`` (128 wide),
+  by ``ops/latent_attention.py`` (scope ``latent_attention``; its
+  docstring says how the 192 is laid out and why); ``x = x +
+  concat_i(o_i) W_o`` (``[H x 128, d]``, scope ``attn_out``). No biases,
+  no per-head q/k norm, no output gate.
+- The expert layer after it is the third model's, with a selection bias
+  (``selection_bias``; DeepSeek-V3's ``noaux_tc``): ``s = sigmoid(g
+  W_r)`` over ALL 256 experts; the 8 experts of largest ``s + b`` (``b
+  [256]`` a leaf of the router that takes no gradient; no group limit;
+  ties to the lower index); gates ``2.5 s_e / sum_chosen s`` from ``s``
+  WITHOUT ``b``. Who sets ``b`` is not here (the rule that moves it
+  against the experts' loads is left out): a configuration without one
+  builds no leaf for it.
+
+Multi-token prediction (DeepSeek-V3 section 2.2, depth 1;
+:class:`MultiTokenPredictor`, ``mtp_depth`` 1). With ``x^L`` the stream
+after the last layer (before the final norm), ``E`` the model's own
+embedding and the job's ``ids[i] = t_i``: for ``i < T - 1``, ``u_i =
+[RMSNorm_e(E[t_{i+1}]) ; RMSNorm_h(x^L_i)] W_eh`` (``W_eh [2d, d]``, the
+embedding's half first); ONE layer of the last layer's kind with its own
+weights (its own router, bias, held experts and shared expert) over
+positions ``0 .. T - 2``; ``logits^mtp_i = RMSNorm_s(.) W_head`` with the
+SAME head and the same embedding as the main path: each is ONE leaf of
+the tree, used twice, and its gradient is the sum of the two paths'.
+``logits^mtp_i`` predicts ``t_{i+2}``, the label ``y[i + 1]``. The
+kernels tile whole rows, so the module runs ``T`` positions: position
+``T - 1`` takes a stand-in embedding (the row's first), no earlier
+position attends it, its tokens are sent to no expert and counted by no
+counter (``HeldExperts``' ``live``), and the loss gives it no weight. The
+model returns ``utils.losses.MultiTokenLogits(logits, mtp_logits,
+mtp_weight)`` and the loss ``cross_entropy_multi_token`` is the row's
+``mean_i CE(logits_i, y_i) + mtp_weight / (T - 1) sum_{i < T - 1}
+CE(logits^mtp_i, y_{i+1})``, both heads through the fused cross entropy
+at the padded width. Everything the module adds runs under the scope
+``mtp``, outside its layer's own scopes. Decoding with the module as a
+drafter is not here.
+
 Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
 (rows computed by each held expert), ``row_chunks`` (the chunks the
 loop ran, and the chunks that all chosen pairs would take), ``routed``
@@ -176,9 +242,14 @@ the grouped products, chunk by chunk, did not multiply by their own
 expert's weights: 0), by each layer that holds experts (a dense layer
 sows none); under block diffusion also ``masked_tokens`` and ``tokens``
 of the step and, by layer, ``attn_tiles`` (the tiles the attention's
-forward kernel visits, of the whole square's); by each ``full`` and
-``window`` layer the same count as ``attn_tiles_full`` /
-``attn_tiles_window``.
+forward kernel visits, of the whole square's); by each ``full``,
+``window`` and ``latent`` layer the same count as ``attn_tiles_full`` /
+``attn_tiles_window`` / ``attn_tiles_latent``; by the multi-token
+prediction module ``mtp_loss`` (the sum of its cross entropy over the
+positions whose label the row itself holds, ``ids[i + 2]`` for ``i < T -
+2``: a forward pass of the loss's kernel on its logits, no gradient) and
+``mtp_tokens`` (those positions, counted from the mask the sum took);
+its layer sows the expert counters with the other layers'.
 """
 
 from __future__ import annotations
@@ -194,6 +265,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from sparktorch_tpu.ops import qk_norm_rope as fused
+from sparktorch_tpu.ops import latent_attention as latent
 from sparktorch_tpu.ops.block_diffusion_attention import (
     SAVED_NAMES as BLOCKDIFF_SAVED_NAMES, BlockDiffusionMask,
     block_diffusion_attention_heads_first)
@@ -202,7 +274,9 @@ from sparktorch_tpu.ops.rule_attention import (
     tiles_visited)
 from sparktorch_tpu.ops.sparse_attention import (
     SAVED_NAMES, heads_last, sparse_attention_heads_first)
-from sparktorch_tpu.utils.losses import TokenWeighted
+from sparktorch_tpu.ops.latent_rope import latent_rope
+from sparktorch_tpu.utils.losses import (MultiTokenLogits, TokenWeighted,
+                                         token_cross_entropy)
 
 _MASK_NAME = "sparse_attn_mask"
 # Queries a block of index scores. The source's q_chunk_size is 512;
@@ -277,6 +351,14 @@ class SparseMoEConfig:
     # "full" and "window" layers: one sigmoid gate a head a token on the
     # attention's output, from the layer's normed input
     attn_gate: bool = False
+    # "latent" layers: the ranks of the two latents, a head's dims that
+    # pass the rotary step and that take it (a query and a key are both,
+    # the rotary part of the key one for all heads), and a value's dims
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_dim: int = 0
     n_routed_experts: int = 128
     experts_held: Tuple[int, ...] = tuple(range(128))
     experts_per_token: int = 8
@@ -285,9 +367,16 @@ class SparseMoEConfig:
     # "sigmoid" of each, renormalised over the chosen, times routed_scale
     scoring: str = "softmax"
     routed_scale: float = 1.0
+    # a bias an expert, added to the scores where the experts are chosen
+    # and nowhere else: a leaf the gradient does not reach
+    selection_bias: bool = False
     # an expert every token goes through, beside the routed ones (0: none)
     shared_expert_width: int = 0
     dense_width: int = 0   # of a "dense" layer's MLP
+    # multi-token prediction modules after the last layer (0 or 1), and
+    # the weight of their loss beside the next token's
+    mtp_depth: int = 0
+    mtp_weight: float = 0.0
     compute_dtype: jnp.dtype = jnp.bfloat16
 
     def __post_init__(self):
@@ -303,18 +392,29 @@ class SparseMoEConfig:
             if kind.attention not in _ATTENTION:
                 raise ValueError(f"attention {kind.attention!r} is none of "
                                  f"{sorted(_ATTENTION)}")
-            if not 0 < 2 * sum(kind.rotary.sections) <= self.head_dim:
+            if kind.mlp not in ("experts", "dense"):
+                raise ValueError(f"mlp {kind.mlp!r} is neither experts nor "
+                                 f"dense")
+            pairs = sum(kind.rotary.sections)
+            if kind.attention == "latent":
+                # a head a key/value head; the widths are the five fields'
+                if 2 * pairs != self.qk_rope_dim or not (
+                        self.q_lora_rank and self.kv_lora_rank
+                        and self.qk_nope_dim and self.v_dim):
+                    raise ValueError(
+                        f"a latent layer needs q_lora_rank, kv_lora_rank, "
+                        f"qk_nope_dim, v_dim and rotary sections "
+                        f"{kind.rotary.sections} that cut the "
+                        f"{self.qk_rope_dim // 2} pairs of qk_rope_dim")
+            elif not 0 < 2 * pairs <= self.head_dim:
                 raise ValueError(
                     f"rotary sections {kind.rotary.sections} do not cut the "
                     f"{self.head_dim // 2} frequency pairs of a head, or a "
                     f"leading part of them")
-            if kind.n_heads % self.n_kv_heads:
+            elif kind.n_heads % self.n_kv_heads:
                 raise ValueError(f"{kind.n_heads} query heads are not a "
                                  f"multiple of {self.n_kv_heads} key/value "
                                  f"heads")
-            if kind.mlp not in ("experts", "dense"):
-                raise ValueError(f"mlp {kind.mlp!r} is neither experts nor "
-                                 f"dense")
         if 0 < self.layers_of("block_diffusion") < self.n_layers:
             raise ValueError("block diffusion doubles the row for every "
                              "layer: it is every layer's attention or none's")
@@ -334,6 +434,14 @@ class SparseMoEConfig:
                              f"{self.n_routed_experts}")
         if self.experts_per_token > self.n_routed_experts:
             raise ValueError("more experts a token than routed experts")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one multi-token "
+                             f"prediction module is built, or none")
+        if self.mtp_depth and (self.diffusion
+                               or self.layers[-1].mlp != "experts"):
+            raise ValueError("the multi-token prediction module is a layer "
+                             "of the last layer's kind, which holds experts, "
+                             "after a causal model")
 
     def layers_of(self, attention: str) -> int:
         return sum(k.attention == attention for k in self.layers)
@@ -669,6 +777,82 @@ class RuleAttention(_GroupedQueryProjections):
         return self._out(o, d)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention in its uncompressed (training) form
+    ("Latent attention" in the module docstring): queries and keys from
+    two normed latents, a query and a key ``[nope ; rope]`` with the
+    rotary part of the key one for all heads, values narrower than keys,
+    one key/value head a query head, every causal key
+    (``ops/latent_attention.py``). No per-head norm, no gate, no bias."""
+
+    config: SparseMoEConfig
+    kind: LayerKind
+
+    def _dense(self, name, shape):
+        return self.param(name, _normal(), shape)
+
+    def _product(self, x, w):
+        """``x w`` float32 for a weight already laid out, lanes across."""
+        dt = self.config.compute_dtype
+        return jnp.einsum("btd,df->btf", x.astype(dt), w.astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    def _slot(self, w):
+        """The rotary columns of a weight (its last axis) as the fused op
+        reads their product: DE-INTERLEAVED (even dims, then odd, so the
+        pair ``(2j, 2j + 1)`` lies half a slot apart and the rotation is
+        by halves; queries and keys alike, so no score moves) and padded
+        with zero columns to whole registers. A pass over the weight,
+        not over the tokens."""
+        rope = self.config.qk_rope_dim
+        order = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+        return jnp.pad(w[..., order], ((0, 0),) * (w.ndim - 1) + (
+            (0, latent.padded_width(rope) - rope),))
+
+    @nn.compact
+    def __call__(self, h, table, temporal):
+        cfg, dt = self.config, self.config.compute_dtype
+        b, t, d = h.shape
+        del temporal
+        heads, nope, d_v = self.kind.n_heads, cfg.qk_nope_dim, cfg.v_dim
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        ones = nn.initializers.ones
+        # the products before the kernels, each latent's under its name
+        with jax.named_scope("attn_qkv"):
+            with jax.named_scope("latent_q"):  # down, norm, up
+                c_q = rms_norm(self._product(h, self._dense("w_dq", (d, rq))),
+                               self.param("q_norm", ones, (rq,)), cfg.rms_eps)
+                w_uq = self._dense("w_uq", (rq, heads, nope + cfg.qk_rope_dim))
+                xq = self._product(c_q, jnp.concatenate(
+                    [w_uq[..., :nope], self._slot(w_uq[..., nope:])],
+                    -1).reshape(rq, -1))
+            with jax.named_scope("latent_kv"):
+                w_dkv = self._dense("w_dkv", (d, rkv + cfg.qk_rope_dim))
+                down = self._product(h, jnp.concatenate(
+                    [w_dkv[:, :rkv], self._slot(w_dkv[:, rkv:])], -1))
+                c_kv = rms_norm(down[..., :rkv],
+                                self.param("kv_norm", ones, (rkv,)),
+                                cfg.rms_eps)
+                xkv = self._product(c_kv, self._dense(
+                    "w_ukv", (rkv, heads, nope + d_v)).reshape(rkv, -1))
+        # rotation, cast and the turn heads first: one kernel
+        with jax.named_scope("attn_qk_rope"), jax.named_scope("latent_rope"):
+            q5, k4, v4 = latent_rope(
+                xq, xkv, down[..., rkv:], *table,
+                sum(self.kind.rotary.sections), nope, dt)
+        visited, total = latent.tiles_visited(t)
+        self.sow("moe_metrics", "attn_tiles_latent",
+                 b * heads * jnp.asarray([visited, total], jnp.float32))
+        with jax.named_scope("latent_attention"):
+            o = heads_last(latent.latent_attention_heads_first(
+                q5, k4, v4, (nope + cfg.qk_rope_dim) ** -0.5))
+        with jax.named_scope("attn_out"):
+            return jnp.einsum(
+                "bthk,hkd->btd", o,
+                self._dense("wo", (heads, d_v, d)).astype(dt),
+                preferred_element_type=jnp.float32)
+
+
 # attention by kind of layer: the module, and what its forward pass
 # names for the layer's remat policy to keep
 _ATTENTION = {
@@ -676,7 +860,18 @@ _ATTENTION = {
     "block_diffusion": (BlockDiffusionAttention, BLOCKDIFF_SAVED_NAMES),
     **{kind: (RuleAttention, saved_names(name))
        for kind, name in _RULE_NAMES.items()},
+    "latent": (LatentAttention, latent.SAVED_NAMES),
 }
+# the kinds whose forward kernel's tiles a layer counts under its name
+_TILES_BY_KIND = (*_RULE_NAMES, "latent")
+
+
+def _table_width(cfg: SparseMoEConfig, kind: LayerKind) -> int:
+    """The lanes of a layer's rotary table: a whole head, or a latent
+    layer's rotary slot."""
+    if kind.attention == "latent":
+        return latent.padded_width(cfg.qk_rope_dim)
+    return cfg.head_dim
 
 
 class HeldExperts(nn.Module):
@@ -684,12 +879,18 @@ class HeldExperts(nn.Module):
     ``n_routed_experts``: this chip's part of the layer's result. A
     token's scores are the configuration's (``scoring``: the softmax
     over all routed experts, or each expert's own sigmoid); its gates are
-    the chosen scores renormalised to sum 1, times ``routed_scale``."""
+    the chosen scores renormalised to sum 1, times ``routed_scale``.
+    Where the configuration carries a ``selection_bias``, the experts are
+    chosen by ``score + bias`` and gated by the score alone: the bias is
+    a leaf no gradient reaches (whoever balances the experts sets it; no
+    rule for that is here). ``live [b, t]``, where given, marks the
+    tokens that exist: the others (a row's padding) are sent to no held
+    expert and count in no counter."""
 
     config: SparseMoEConfig
 
     @nn.compact
-    def __call__(self, g):
+    def __call__(self, g, live=None):
         cfg, dt = self.config, self.config.compute_dtype
         b, t, d = g.shape
         n, k = b * t, cfg.experts_per_token
@@ -705,7 +906,13 @@ class HeldExperts(nn.Module):
                 preferred_element_type=jnp.float32)
             probs = (jax.nn.softmax(logits, -1) if cfg.scoring == "softmax"
                      else jax.nn.sigmoid(logits))
-            top_e = jax.lax.top_k(jax.lax.stop_gradient(probs), k)[1]
+            chosen_by = jax.lax.stop_gradient(probs)
+            if cfg.selection_bias:
+                with jax.named_scope("selection_bias"):
+                    chosen_by = chosen_by + jax.lax.stop_gradient(self.param(
+                        "selection_bias", nn.initializers.zeros,
+                        (cfg.n_routed_experts,)))
+            top_e = jax.lax.top_k(chosen_by, k)[1]
             # the chosen probabilities by a one-hot product, whose
             # transpose is dense (top_k's own is a batched scatter)
             top_p = jnp.einsum("nke,ne->nk", jax.nn.one_hot(
@@ -716,7 +923,10 @@ class HeldExperts(nn.Module):
             # local id of each chosen expert, n_held for one held elsewhere
             local = np.full((cfg.n_routed_experts,), n_held, np.int32)
             local[list(held)] = np.arange(n_held)
-            pair_local = jnp.asarray(local)[top_e].reshape(n * k)
+            pair_local = jnp.asarray(local)[top_e]
+            if live is not None:
+                pair_local = jnp.where(live.reshape(n, 1), pair_local, n_held)
+            pair_local = pair_local.reshape(n * k)
             # held pairs first, by expert; pairs of experts held elsewhere
             # last
             order = jnp.argsort(pair_local, stable=True)
@@ -941,7 +1151,9 @@ class DecoderLayer(nn.Module):
     kind: LayerKind
 
     @nn.compact
-    def __call__(self, x, table, temporal):
+    def __call__(self, x, table, temporal, live=None):
+        """``live [b, t]``, where given: the tokens that exist, for the
+        expert layer (:class:`HeldExperts`)."""
         cfg, kind = self.config, self.kind
         ones = nn.initializers.ones
         d = x.shape[-1]
@@ -954,7 +1166,7 @@ class DecoderLayer(nn.Module):
             return x + SwiGLU(cfg, cfg.dense_width, "dense_mlp",
                               name="mlp")(g)
         g = norm(x, self.param("moe_norm", ones, (d,)), cfg.rms_eps)
-        x = x + HeldExperts(cfg, name="moe")(g)
+        x = x + HeldExperts(cfg, name="moe")(g, live)
         if cfg.shared_expert_width:
             # on every chip alike: no share of it, no gate
             x = x + SwiGLU(cfg, cfg.shared_expert_width, "shared_expert",
@@ -962,12 +1174,59 @@ class DecoderLayer(nn.Module):
         return x
 
 
+def _remat_layer(kept: tuple):
+    """:class:`DecoderLayer` rematerialised but for the arrays its
+    attention names (``kept``)."""
+    return nn.remat(
+        DecoderLayer,
+        policy=jax.checkpoint_policies.save_only_these_names(*kept))
+
+
+class MultiTokenPredictor(nn.Module):
+    """One multi-token prediction module ("Multi-token prediction" in the
+    module docstring): from the stream after the last layer ``x [b, T,
+    d]`` and the row's embeddings ``emb``, position ``i``'s hidden state
+    for the token after the next: ``[RMSNorm(emb[i + 1]) ; RMSNorm(x[i])]
+    W_eh``, one layer of the last layer's kind with its own weights, and
+    a norm; the caller's head makes the logits. Position ``T - 1`` has no
+    next embedding: it is computed on a stand-in (the row's first), no
+    earlier position attends it, its tokens reach no expert (``live``)
+    and the loss gives it no weight."""
+
+    config: SparseMoEConfig
+
+    @nn.compact
+    def __call__(self, x, emb, table, temporal):
+        cfg, dt = self.config, self.config.compute_dtype
+        b, t, d = x.shape
+        ones = nn.initializers.ones
+        norm = jax.named_scope("block_norm")(rms_norm)
+        gain = lambda name: self.param(name, ones, (d,))
+        halves = jnp.concatenate(
+            [norm(jnp.roll(emb, -1, 1), gain("embed_norm"), cfg.rms_eps),
+             norm(x, gain("hidden_norm"), cfg.rms_eps)], -1)
+        with jax.named_scope("mtp_proj"):
+            u = jnp.einsum(
+                "bte,ed->btd", halves.astype(dt),
+                self.param("proj", _normal(), (2 * d, d)).astype(dt),
+                preferred_element_type=jnp.float32)
+        kind = cfg.layers[-1]
+        live = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t))
+        u = _remat_layer(_ATTENTION[kind.attention][1])(
+            cfg, kind, name="layer")(u, table, temporal, live)
+        return norm(u, gain("final_norm"), cfg.rms_eps)
+
+
 class SparseMoELM(nn.Module):
     """Token ids ``[b, T]`` (ints, or the estimator's float columns) ->
     float32 logits ``[b, T, vocab_size rounded up to the fused cross
     entropy's tile]`` (-1e30 past ``vocab_size``). ``position_ids`` is ``[3, b,
     T]`` (temporal, height, width) and defaults to the token's index in
-    all three, which is plain rotary."""
+    all three, which is plain rotary. Under block diffusion the logits
+    come with their tokens' weights (``TokenWeighted``), with a
+    multi-token prediction module with the module's logits and its
+    loss's weight (``MultiTokenLogits``): what the configuration's loss
+    takes as ``preds``."""
 
     config: SparseMoEConfig
 
@@ -1003,7 +1262,15 @@ class SparseMoELM(nn.Module):
         if cfg.layers_of("window"):
             gauges["train.attention.window"] = cfg.window
         gauges.update({f"train.attention.layers_{kind}": cfg.layers_of(kind)
-                       for kind in _RULE_NAMES if cfg.layers_of(kind)})
+                       for kind in _TILES_BY_KIND if cfg.layers_of(kind)})
+        if cfg.layers_of("latent"):
+            gauges["train.attention.latent_q_rank"] = cfg.q_lora_rank
+            gauges["train.attention.latent_kv_rank"] = cfg.kv_lora_rank
+        if cfg.selection_bias:
+            gauges["train.moe.selection_bias"] = 1
+        if cfg.mtp_depth:
+            gauges["train.mtp.depth"] = cfg.mtp_depth
+            gauges["train.mtp.weight"] = cfg.mtp_weight
         return gauges
 
     def train_counters(self, sown: dict, drop_fraction) -> tuple:
@@ -1048,7 +1315,15 @@ class SparseMoELM(nn.Module):
             gauges.update({
                 "train.diffusion.attn_tiles_visited": float(tiles[0]),
                 "train.diffusion.attn_tiles_total": float(tiles[1])})
-        for kind in _RULE_NAMES:
+        if "mtp_loss" in sown:
+            # the module's own cross entropy, over the positions whose
+            # label the row itself holds (all but its last two)
+            tokens = float(sown["mtp_tokens"].sum())
+            fields.update(mtp_loss=float(sown["mtp_loss"].sum()) / tokens,
+                          mtp_tokens=tokens)
+            counters["train.mtp.tokens"] = tokens
+            gauges["train.mtp.loss"] = fields["mtp_loss"]
+        for kind in _TILES_BY_KIND:
             if f"attn_tiles_{kind}" in sown:
                 tiles = sown[f"attn_tiles_{kind}"].sum(0)
                 gauges.update({
@@ -1097,40 +1372,66 @@ class SparseMoELM(nn.Module):
         # one (cos, sin) a rotary table among the layers, and one
         # rematerialised layer class a set of names an attention keeps
         with jax.named_scope("attn_qk_rope"):
-            tables = {rotary: rotary_table(position_ids, rotary,
-                                           cfg.head_dim)
-                      for rotary in dict.fromkeys(
-                          k.rotary for k in cfg.layers)}
+            tables = {key: rotary_table(position_ids, *key)
+                      for key in dict.fromkeys(
+                          (k.rotary, _table_width(cfg, k))
+                          for k in cfg.layers)}
         temporal = position_ids[0]
         with jax.named_scope("embed"):  # its gradient: the scatter-add
-            x = self.param("embed", _normal(),
-                           (cfg.vocab_size, cfg.d_model))[ids]
-        remat = {kept: nn.remat(
-            DecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(*kept))
-            for kept in dict.fromkeys(
-                _ATTENTION[k.attention][1] for k in cfg.layers)}
+            emb = self.param("embed", _normal(),
+                             (cfg.vocab_size, cfg.d_model))[ids]
+        x = emb
+        remat = {kept: _remat_layer(kept) for kept in dict.fromkeys(
+            _ATTENTION[k.attention][1] for k in cfg.layers)}
         for i, kind in enumerate(cfg.layers):
             layer = remat[_ATTENTION[kind.attention][1]]
             x = layer(cfg, kind, name=f"layer_{i}")(
-                x, tables[kind.rotary], temporal)
+                x, tables[kind.rotary, _table_width(cfg, kind)], temporal)
         if diffusion:
             x = x[:, t:]  # the clean half's last output enters nothing
         with jax.named_scope("block_norm"):
-            x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
-                                       (cfg.d_model,)), cfg.rms_eps)
+            normed = rms_norm(x, self.param("final_norm",
+                                            nn.initializers.ones,
+                                            (cfg.d_model,)), cfg.rms_eps)
         with jax.named_scope("lm_head"):
             head = self.param("head", _normal(),
                               (cfg.d_model, cfg.vocab_size)).astype(dt)
             pad = -cfg.vocab_size % min(_CE_BLOCK_V, cfg.vocab_size)
-            logits = jnp.einsum("btd,dv->btv", x.astype(dt),
-                                jnp.pad(head, ((0, 0), (0, pad))),
-                                preferred_element_type=jnp.float32)
-            logits = logits + jnp.where(
-                jnp.arange(cfg.vocab_size + pad) < cfg.vocab_size, 0.0, -1e30)
+
+            def to_logits(h):
+                logits = jnp.einsum("btd,dv->btv", h.astype(dt),
+                                    jnp.pad(head, ((0, 0), (0, pad))),
+                                    preferred_element_type=jnp.float32)
+                return logits + jnp.where(
+                    jnp.arange(cfg.vocab_size + pad) < cfg.vocab_size, 0.0,
+                    -1e30)
+
+            logits = to_logits(normed)
         if diffusion:
             return TokenWeighted(logits, masked / level)
-        return logits
+        if not cfg.mtp_depth:
+            return logits
+        # the module, its pass through the SAME head and embedding (their
+        # gradients add), and its own loss for the counters: all of it
+        # under ``mtp``, outside its layer's own scopes
+        with jax.named_scope("mtp"):
+            last = cfg.layers[-1]
+            hidden = MultiTokenPredictor(cfg, name="mtp")(
+                x, emb, tables[last.rotary, _table_width(cfg, last)],
+                temporal)
+            with jax.named_scope("lm_head"):
+                mtp_logits = to_logits(hidden)
+            if self.is_mutable_collection("moe_metrics"):
+                # position i is held to the token two on, ids[i + 2]: the
+                # row holds that label for all but its last two positions
+                seen = jnp.broadcast_to(jnp.arange(t) < t - 2, (b, t))
+                per_token = token_cross_entropy(
+                    jax.lax.stop_gradient(mtp_logits), jnp.roll(ids, -2, 1))
+                self.sow("moe_metrics", "mtp_loss",
+                         jnp.sum(per_token * seen))
+                self.sow("moe_metrics", "mtp_tokens",
+                         jnp.sum(seen, dtype=jnp.float32))
+        return MultiTokenLogits(logits, mtp_logits, cfg.mtp_weight)
 
 
 # the flax stream block diffusion's noise is drawn from
@@ -1216,3 +1517,28 @@ def laguna_lm(**overrides) -> SparseMoELM:
         "experts_held": tuple(range(256)), "expert_width": 512,
         "scoring": "sigmoid", "routed_scale": 2.5,
         "shared_expert_width": 512, "dense_width": 8_192, **overrides}))
+
+
+def joyai_flash_lm(**overrides) -> SparseMoELM:
+    """JoyAI-LLM-Flash at its published sizes: 40 layers of hidden 2,048,
+    every one latent attention (32 heads, queries and keys of 128 + 64
+    over values of 128, from latents of rank 1,536 and 512; interleaved
+    rotary of theta 3.2e7 on the 64); the first layer's MLP dense
+    (7,168), the others' 256 experts of 768, 8 a token by sigmoid scores
+    plus a selection bias, renormalised and scaled by 2.5, beside one
+    shared expert of 768; one multi-token prediction module weighed 0.3;
+    vocabulary 129,280. ``overrides`` as for :func:`keye_vl2_lm`."""
+    overrides = _coerced(overrides)
+    n_layers = overrides.get("n_layers", 40)
+    rotary = Rotary(3.2e7, (32,))
+    return SparseMoELM(SparseMoEConfig(**{
+        "vocab_size": 129_280, "n_layers": n_layers, "n_kv_heads": 32,
+        "layers": tuple(
+            LayerKind("latent", 32, rotary, "experts" if i else "dense")
+            for i in range(n_layers)),
+        "q_lora_rank": 1_536, "kv_lora_rank": 512, "qk_nope_dim": 128,
+        "qk_rope_dim": 64, "v_dim": 128, "n_routed_experts": 256,
+        "experts_held": tuple(range(256)), "expert_width": 768,
+        "scoring": "sigmoid", "routed_scale": 2.5, "selection_bias": True,
+        "shared_expert_width": 768, "dense_width": 7_168, "mtp_depth": 1,
+        "mtp_weight": 0.3, **overrides}))

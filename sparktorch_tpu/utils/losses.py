@@ -37,6 +37,19 @@ class TokenWeighted(NamedTuple):
     weights: jax.Array
 
 
+class MultiTokenLogits(NamedTuple):
+    """What the training forward of a model with a multi-token
+    prediction module hands :func:`multi_token_cross_entropy_loss`:
+    ``logits [batch, seq, vocab]`` of the next token, ``mtp_logits`` of
+    the same shape, whose position ``i`` predicts the token after the
+    next (its last position predicts nothing and counts nothing), and
+    the ``weight`` of the second loss beside the first."""
+
+    logits: jax.Array
+    mtp_logits: jax.Array
+    weight: float
+
+
 def _flatten_per_example(x: jax.Array) -> jax.Array:
     """Mean over all non-batch dims -> shape (batch,)."""
     if x.ndim <= 1:
@@ -160,6 +173,14 @@ def weighted_cross_entropy_loss(preds: TokenWeighted,
     entropy goes through the fused Pallas kernel where
     :func:`cross_entropy_auto` would pick it, else the dense path."""
     logits, weights = preds
+    return (token_cross_entropy(logits, targets) * weights).mean(-1)
+
+
+def token_cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Cross entropy of every token, ``[batch, seq]``, from ``logits
+    [batch, seq, vocab]`` and integer ``targets [batch, seq]``: through
+    the fused Pallas kernel where :func:`cross_entropy_auto` would pick
+    it, else the dense path."""
     b, s, v = logits.shape
     labels = targets.astype(jnp.int32).reshape(b * s)
     flat = logits.reshape(b * s, v)
@@ -170,7 +191,29 @@ def weighted_cross_entropy_loss(preds: TokenWeighted,
     else:
         per_token = jax.nn.logsumexp(flat, axis=-1) - jnp.take_along_axis(
             flat, labels[:, None], axis=-1)[:, 0]
-    return (per_token.reshape(b, s) * weights).mean(-1)
+    return per_token.reshape(b, s)
+
+
+def multi_token_cross_entropy_loss(preds: MultiTokenLogits,
+                                   targets: jax.Array) -> jax.Array:
+    """``cross_entropy_multi_token`` registry entry: the row's mean
+    next-token cross entropy plus ``weight`` times the mean, over the
+    ``seq - 1`` positions that have one, of the cross entropy of the
+    multi-token prediction module's logits against the label one further
+    on (``targets[i]`` is the token after position ``i``, so position
+    ``i`` of the module is held to ``targets[i + 1]``; the last position
+    has no such label). The two heads reach the loss as TokenWeighted's
+    weights do, WITH the logits (:class:`MultiTokenLogits`), and each
+    goes through :func:`token_cross_entropy`; the module's part runs
+    under the scope ``mtp``, as the module itself does."""
+    logits, mtp_logits, weight = preds
+    seq = logits.shape[1]
+    labels = targets.astype(jnp.int32).reshape(logits.shape[:2])
+    per = token_cross_entropy(logits, labels).mean(-1)
+    with jax.named_scope("mtp"):
+        further = token_cross_entropy(mtp_logits, jnp.roll(labels, -1, 1))
+        held = jnp.arange(seq) < seq - 1
+        return per + weight * jnp.sum(further * held, -1) / (seq - 1)
 
 
 def nll_loss(preds: jax.Array, targets: jax.Array) -> jax.Array:
@@ -199,6 +242,7 @@ LOSS_REGISTRY: dict[str, LossFn] = {
     "cross_entropy_dense": cross_entropy_loss,
     "cross_entropy_fused": fused_cross_entropy_loss,
     "cross_entropy_weighted": weighted_cross_entropy_loss,
+    "cross_entropy_multi_token": multi_token_cross_entropy_loss,
     "nll": nll_loss,
     "bce_with_logits": bce_with_logits_loss,
     # torch.nn criterion-class spellings, so reference users can pass the

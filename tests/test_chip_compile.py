@@ -278,6 +278,57 @@ def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 5_500_000_000
 
 
+def test_latent_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
+    """The whole model of the latent-attention cell at its configuration
+    file's sizes and the cell's row (1 row of 8,192 tokens; a dense layer,
+    four expert layers and the multi-token prediction module's, 32 heads
+    of 192 / 128, 16 of 256 experts held, 16,160 rows of vocabulary)
+    under its two-headed loss: keys of 256 lanes over values of 128 at one
+    query head a grid step and tiles of 1,024 lower in Mosaic, each
+    attention kernel runs once a layer (six layers: the remat keeps the
+    output and row statistics), the layout op twice forward and once
+    backward, both heads and the module's own loss go through the loss's
+    kernel, and the gradient's scratch leaves room beside 8.17 GB of
+    weights and Adam's moments."""
+    import json
+
+    from sparktorch_tpu.models.sparse_moe_lm import joyai_flash_lm
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "joyai-llm-flash-ep16.json")) as f:
+        module = joyai_flash_lm(**json.load(f)["constructor_kwargs"])
+    ids = jnp.zeros((1, 8192), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 680_441_088
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy_multi_token")
+
+    def loss(p, x, y):
+        out, sown = module.apply({"params": p}, x, mutable=["moe_metrics"])
+        return loss_fn(out, y).sum(), sown
+
+    compiled = jax.jit(jax.grad(loss, has_aux=True)).lower(
+        jax.tree.map(S, shapes), S(ids), S(ids)).compile()
+    text = compiled.as_text()
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert _pallas_calls(text, f"latent_attn_{kernel}") == 6
+    assert _forward_statistics(text, "latent_attn_fwd") == 6 * [
+        (1, 32, 1, 8192)]
+    assert _pallas_calls(text, "latent_rope_fwd") == 12
+    assert _pallas_calls(text, "latent_rope_bwd") == 6
+    # two heads forward and backward, the module's own loss forward only
+    assert _pallas_calls(text, "fused_ce_fwd") == 3
+    assert _pallas_calls(text, "fused_ce_bwd") == 2
+    assert _pallas_calls(text, "causal_attn_fwd") == 0
+    assert _pallas_calls(text, "qk_norm_rope_fwd") == 0
+    # no transposing copy of a float32 product on its way to the layout op
+    assert not re.search(r"f32\[1,8192,8192\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 7_000_000_000
+
+
 @pytest.mark.parametrize("rows,seq,calls", [(32, 512, 1), (128, 128, 0)])
 def test_encoder_layer_gradient_picks_its_attention_for_v5e(
         one_chip, as_tpu, monkeypatch, rows, seq, calls):
