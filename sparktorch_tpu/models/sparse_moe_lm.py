@@ -80,7 +80,7 @@ over the padded width is the loss over ``vocab_size``. Every layer is
 rematerialised in the backward pass, but for three arrays the forward
 pass keeps: its selected sets, and the attention kernel's output and
 row statistics (``ops.sparse_attention.SAVED_NAMES``: one activation of
-``[rows, T, heads, head_dim]`` in the compute dtype and 4 bytes a row a
+``[rows, T, heads * head_dim]`` in the compute dtype and 4 bytes a row a
 head, for each layer; each kind of attention names its own, and a
 layer's remat lists its kind's), so the selection and the attention's
 forward kernel run once a layer a step.
@@ -93,15 +93,22 @@ them so and writes ``q5 [b, kv_heads, G, T, head_dim]``, ``k4`` and
 the rotary step of the layer's table (:func:`rotary_table`, built once a
 table a forward pass), the cast and the turn heads first are ONE kernel
 forward and one backward, under the scope ``attn_qk_rope``. Every
-attention module hands those to its op's heads-first entry
-(``sparse_attention_heads_first``, ``rule_attention_heads_first``,
-``block_diffusion_attention_heads_first``), which returns ``o5`` like
-``q5`` and, backward, the three cotangents as its kernels write them,
-which the fused op's backward kernel reads so. The one turn a module
-still makes with an XLA transpose is the output's: ``heads_last(o5)``
-to ``[b, T, heads, head_dim]`` for the gate and ``Wo``, and its
-transpose on the cotangent. The ``[b, T, h, d]`` entries of the three
-ops (``sparse_attention``, ``rule_attention``,
+grouped-query attention module hands those to its op's heads-first
+entry (``sparse_attention_heads_first``,
+``rule_attention_heads_first``,
+``block_diffusion_attention_heads_first``), which returns ``o`` FLAT,
+``[b, T, heads * head_dim]`` with a head a block of lanes (the kernels'
+output blocks' index map makes the turn), and, backward, takes its
+cotangent so and hands back the three cotangents as its kernels write
+them, which the fused op's backward kernel reads so. ``Wo`` enters its product flat too
+(``[heads * head_dim, d]``, a free reshape of the parameter), and the
+output gate multiplies ``o`` through ``ops.sparse_attention.by_head``
+(the tokens split by 8, so that the heads become an axis and nothing
+moves): none of these modules transposes anything or lays an array of
+``o``'s size out ``[b, T, heads, head_dim]``. (Latent attention, one
+head a grid step, keeps ``o5`` heads first and its module's turn:
+``ops/latent_attention.py`` says why.) The ``[b, T, h, d]`` entries of
+the three ops (``sparse_attention``, ``rule_attention``,
 ``block_diffusion_attention``) are thin wrappers for other callers and
 the tests; ``rms_norm`` and ``_rotate`` serve the block norms and the
 indexer, and are the plain spelling the tests hold the fused op to.
@@ -273,7 +280,7 @@ from sparktorch_tpu.ops.rule_attention import (
     Causal, CausalWindow, rule_attention_heads_first, saved_names,
     tiles_visited)
 from sparktorch_tpu.ops.sparse_attention import (
-    SAVED_NAMES, heads_last, sparse_attention_heads_first)
+    SAVED_NAMES, by_head, sparse_attention_heads_first)
 from sparktorch_tpu.ops.latent_rope import latent_rope
 from sparktorch_tpu.utils.losses import (MultiTokenLogits, TokenWeighted,
                                          token_cross_entropy)
@@ -673,13 +680,15 @@ class _GroupedQueryProjections(nn.Module):
                 sum(self.kind.rotary.sections), cfg.compute_dtype)
 
     def _out(self, o, d):
+        """``o Wo`` float32 for the flat ``o [b, T, heads * head_dim]``
+        the attention kernels write: the parameter ``wo [heads,
+        head_dim, d]`` enters the product flat too (a free reshape), as
+        ``_heads`` hands its weights and for its reason."""
         cfg, dt = self.config, self.config.compute_dtype
+        wo = self._dense("wo", (self.kind.n_heads, cfg.head_dim, d))
         with jax.named_scope("attn_out"):
-            return jnp.einsum(
-                "bthk,hkd->btd", o,
-                self._dense("wo",
-                            (self.kind.n_heads, cfg.head_dim, d)).astype(dt),
-                preferred_element_type=jnp.float32)
+            return jnp.einsum("btf,fd->btd", o, wo.reshape(-1, d).astype(dt),
+                              preferred_element_type=jnp.float32)
 
 
 class SparseAttention(_GroupedQueryProjections):
@@ -713,7 +722,7 @@ class SparseAttention(_GroupedQueryProjections):
             _MASK_NAME)
         self.sow("intermediates", "selected", mask)  # for whoever asks
         with jax.named_scope("sparse_attention"):
-            o = heads_last(sparse_attention_heads_first(q5, k4, v4, mask))
+            o = sparse_attention_heads_first(q5, k4, v4, mask)
         return self._out(o, d)
 
 
@@ -732,8 +741,7 @@ class BlockDiffusionAttention(_GroupedQueryProjections):
         self.sow("moe_metrics", "attn_tiles", b * cfg.n_kv_heads
                  * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope("block_diffusion_attention"):
-            o = heads_last(block_diffusion_attention_heads_first(
-                q5, k4, v4, rule))
+            o = block_diffusion_attention_heads_first(q5, k4, v4, rule)
         return self._out(o, d)
 
 
@@ -767,13 +775,14 @@ class RuleAttention(_GroupedQueryProjections):
                  b * cfg.n_kv_heads
                  * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope(f"{name}_attention"):
-            o = heads_last(rule_attention_heads_first(q5, k4, v4, rule,
-                                                      name))
+            o = rule_attention_heads_first(q5, k4, v4, rule, name)
         if cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 gate = jax.nn.sigmoid(self._proj(
                     h, self._dense("wg", (d, self.kind.n_heads))))
-                o = (o * gate[..., None]).astype(dt)
+                # a head is head_dim lanes of a token's row: no axis moves
+                o = (by_head(o, cfg.head_dim)
+                     * by_head(gate, 1)).astype(dt).reshape(o.shape)
         return self._out(o, d)
 
 
@@ -844,7 +853,9 @@ class LatentAttention(nn.Module):
         self.sow("moe_metrics", "attn_tiles_latent",
                  b * heads * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope("latent_attention"):
-            o = heads_last(latent.latent_attention_heads_first(
+            # one head a grid step: the kernels keep o heads first and
+            # the turn is XLA's (ops/latent_attention.py says why)
+            o = latent.heads_last(latent.latent_attention_heads_first(
                 q5, k4, v4, (nope + cfg.qk_rope_dim) ** -0.5))
         with jax.named_scope("attn_out"):
             return jnp.einsum(

@@ -24,9 +24,9 @@ are visited and 89% of the pairs in them are kept.
 its forward rule names :data:`SAVED_NAMES` for a caller's remat policy.
 It takes ``[b, T, h, d]`` operands and turns them itself;
 :func:`block_diffusion_attention_heads_first` is
-``rule_attention_heads_first`` under the same name, operands and result
-in the kernels' layout with nothing transposed, which the decoder calls
-behind ``ops/qk_norm_rope.py``.
+``rule_attention_heads_first`` under the same name, operands in the
+kernels' layout and the result flat with nothing transposed, which the
+decoder calls behind ``ops/qk_norm_rope.py``.
 """
 
 from __future__ import annotations
@@ -78,5 +78,5 @@ def block_diffusion_attention_heads_first(q5: jax.Array, k4: jax.Array,
                                           v4: jax.Array, rule) -> jax.Array:
     """:func:`block_diffusion_attention` on operands in the kernels'
     layout (``rule_attention_heads_first``'s shapes): ``q5, k4, v4 ->
-    o5``, nothing transposed on either side."""
+    o [b, T, heads * d]``, nothing transposed on either side."""
     return rule_attention_heads_first(q5, k4, v4, rule, _NAME)

@@ -36,9 +36,16 @@ grouped-query step's at the same tiles: the tiles here are 1,024 x
 :func:`latent_attention_heads_first` takes and returns the kernels'
 layout and carries the ``custom_vjp`` (the decoder calls it, with
 ``ops/latent_rope.py`` writing ``q5``, ``k4`` and ``v4``; the tests pad
-and turn ``[b, T, h, d]`` operands themselves). ``pallas_call`` names ``latent_attn_fwd``, ``latent_attn_bwd_dq``,
-``latent_attn_bwd_dkv``; the forward rule names its output and row
-statistics for a caller's remat policy (:data:`SAVED_NAMES`). Off the
+and turn ``[b, T, h, d]`` operands themselves). Its output stays heads
+first, ``o5 [b, heads, 1, T, d_v]``, which the module turns with an XLA
+transpose (:func:`heads_last`), where the grouped-query kernels write
+``o`` flat, ``[b, T, heads * d]``: with one head a grid step a flat
+block is ``[1024, 128]`` in rows of 256 B at a stride of 8 KB, and on
+the chip dq and dkv lost more reading such a cotangent than the turns
+cost (PERF.md section 6, PR 41: the two readings). ``pallas_call`` names
+``latent_attn_fwd``, ``latent_attn_bwd_dq``, ``latent_attn_bwd_dkv``;
+the forward rule names its output and row statistics for a caller's
+remat policy (:data:`SAVED_NAMES`). Off the
 TPU the kernels run in interpret mode; a shape that does not tile is an
 error everywhere.
 """
@@ -57,7 +64,7 @@ from sparktorch_tpu.ops.rule_attention import (
     Causal, _bwd_dkv_kernel, _bwd_dq_kernel, _fwd_kernel, saved_names,
     visited_tiles)
 from sparktorch_tpu.ops.sparse_attention import (
-    _LANES, _interpret, fwd_scratch, row_statistics)
+    _LANES, _interpret, fwd_scratch, spread)
 
 _NAME = "latent"
 SAVED_NAMES = saved_names(_NAME)
@@ -92,16 +99,27 @@ def padded_width(d: int) -> int:
     return -(-d // _LANES) * _LANES
 
 
+def heads_last(o5):
+    """``o5 [b, heads, 1, T, d_v]`` -> ``[b, T, heads, d_v]``."""
+    b, heads, _, t, d = o5.shape
+    return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, t, heads, d)
+
+
 def _specs(d_qk: int, d_v: int, block_q: int, block_k: int):
     """Block specs on the grid ``(b, head, visit)`` with the table
     prefetched: a Q tile of queries (or their cotangent), of outputs (or
-    theirs), a K tile, a V tile, and a Q tile's row statistics."""
+    theirs: the one head's ``[block_q, d_v]``, as the tile bodies read a
+    head of a flat tile), a K tile, a V tile, and a Q tile's row
+    statistics."""
     q_tile = lambda d: pl.BlockSpec(
         (None, None, 1, block_q, d),
         lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
+    o_tile = pl.BlockSpec(
+        (None, None, None, block_q, d_v),
+        lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
     k_tile = lambda d: pl.BlockSpec(
         (None, None, block_k, d), lambda b, h, v, qt, kt: (b, h, kt[v], 0))
-    return q_tile(d_qk), q_tile(d_v), k_tile(d_qk), k_tile(d_v), q_tile(_LANES)
+    return q_tile(d_qk), o_tile, k_tile(d_qk), k_tile(d_v), q_tile(_LANES)
 
 
 def _call(kernel, name, table, q5, scale, out_shape, in_specs, out_specs,
@@ -145,7 +163,8 @@ def _bwd(q5, k4, v4, o5, lse, do5, scale):
     d_v = v4.shape[-1]
     block_q, block_k = _blocks(t)
     q_major, k_major = visited_tiles(_RULE, t, block_q, block_k)
-    lse, di = row_statistics(o5, lse, do5)
+    lse, di = spread(lse), spread(jnp.sum(
+        o5.astype(jnp.float32) * do5.astype(jnp.float32), axis=-1))
     q_spec, o_spec, k_spec, v_spec, row_spec = _specs(
         d_qk, d_v, block_q, block_k)
     in_specs = [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec]

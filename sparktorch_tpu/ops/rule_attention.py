@@ -31,7 +31,9 @@ causal; it lives with the model that needs it and calls these kernels.
 
 Shared with ``ops/sparse_attention.py``, which holds them: the layout
 (``q`` as ``[b, kv_heads, G, T, d]``, one grid step serving the ``G``
-query heads of a key/value head from one K and one V tile), the tile
+query heads of a key/value head from one K and one V tile; ``o`` and its
+cotangent flat, ``[b, T, kv_heads * G * d]``, a step's block the ``G *
+d`` lanes of its key/value head), the tile
 sizes, the streaming-softmax body with its scratch and both backward
 bodies (``fwd_tile`` / ``fwd_init`` / ``fwd_finalize`` / ``fwd_scratch``,
 ``dq_tile``, ``dkv_tile``: the mask comes from a tile of an array there
@@ -47,13 +49,14 @@ gets the turned mask with nothing transposed (:func:`_keep`); dq and
 dkv keep the tile queries down.
 
 Two entries, as ``ops/sparse_attention.py`` has them and says more of:
-:func:`rule_attention_heads_first` takes and returns the kernels' layout
-(``q5``, ``k4``, ``v4`` -> ``o5``, the cotangents back as the kernels
-write them) and carries the ``custom_vjp``; the decoder calls it, with
-``ops/qk_norm_rope.py`` writing ``q5``, ``k4`` and ``v4`` in that layout
-and the module turning ``o5`` back. :func:`rule_attention` takes ``[b,
-T, h, d]`` operands and is a thin wrapper that makes the turns itself
-with XLA transposes.
+:func:`rule_attention_heads_first` takes the kernels' layout (``q5``,
+``k4``, ``v4``), returns the flat ``o`` (backward: takes the flat
+``do``, the cotangents back as the kernels write them) and carries the
+``custom_vjp``; the decoder calls it, with ``ops/qk_norm_rope.py``
+writing ``q5``, ``k4`` and ``v4`` in that layout and the module's gate
+and ``Wo`` reading ``o`` as it lies. :func:`rule_attention` takes ``[b,
+T, h, d]`` operands and is a thin wrapper that turns them with XLA
+transposes and reshapes the flat result.
 
 A call carries a static ``name``: its kernels are the ``pallas_call``s
 ``<name>_attn_fwd``, ``<name>_attn_bwd_dq`` and ``<name>_attn_bwd_dkv``,
@@ -81,13 +84,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from sparktorch_tpu.ops.sparse_attention import (
     _LANES, _blocks, _interpret, dkv_tile, dq_tile, fwd_finalize, fwd_init,
-    fwd_scratch, fwd_tile, heads_first, heads_last, row_statistics)
+    fwd_scratch, fwd_tile, heads_first, row_statistics)
 
 
 def saved_names(name: str) -> tuple:
     """What the forward rule of a call named ``name`` names for a
-    caller's remat policy: its output in the kernels' layout, and the
-    row statistics."""
+    caller's remat policy: its output (flat, ``[b, T, heads * d]``) and
+    the row statistics."""
     return (f"{name}_attn_out", f"{name}_attn_lse")
 
 
@@ -231,12 +234,16 @@ def _specs(groups, d, block_q, block_k):
     q_spec = pl.BlockSpec(
         (None, None, groups, block_q, d),
         lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
+    # o and its cotangent, [b, T, kv_heads * groups * d]: a key/value
+    # head's group is a block of lanes
+    o_spec = pl.BlockSpec(
+        (None, block_q, groups * d), lambda b, h, v, qt, kt: (b, qt[v], h))
     kv_spec = pl.BlockSpec(
         (None, None, block_k, d), lambda b, h, v, qt, kt: (b, h, kt[v], 0))
     row_spec = pl.BlockSpec(
         (None, None, groups, block_q, _LANES),
         lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
-    return q_spec, kv_spec, row_spec
+    return q_spec, o_spec, kv_spec, row_spec
 
 
 def _call(kernel, name, table, shape, out_shape, in_specs, out_specs,
@@ -264,26 +271,26 @@ def _fwd(rule, name, q5, k4, v4):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     q_major, _ = visited_tiles(rule, t, block_q, block_k)
-    q_spec, kv_spec, _ = _specs(groups, d, block_q, block_k)
+    q_spec, o_spec, kv_spec, _ = _specs(groups, d, block_q, block_k)
     # the log-sum-exp, one number a row with the sequence along the lanes
     lse_spec = pl.BlockSpec((None, None, groups, block_q),
                             lambda b, h, v, qt, kt: (b, h, 0, qt[v]))
     return _call(
         _fwd_kernel, f"{name}_attn_fwd", q_major, q5.shape,
-        [jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        [jax.ShapeDtypeStruct((b, t, hkv * groups * d), q5.dtype),
          jax.ShapeDtypeStruct((b, hkv, groups, t), jnp.float32)],
-        [q_spec, kv_spec, kv_spec], [q_spec, lse_spec],
+        [q_spec, kv_spec, kv_spec], [o_spec, lse_spec],
         fwd_scratch(groups, d, block_q), (q5, k4, v4), rule=rule)
 
 
-def _bwd(rule, name, q5, k4, v4, o5, lse, do5):
+def _bwd(rule, name, q5, k4, v4, o, lse, do):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     q_major, k_major = visited_tiles(rule, t, block_q, block_k)
-    lse, di = row_statistics(o5, lse, do5)
-    q_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k)
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
-    operands = (q5, k4, v4, do5, lse, di)
+    lse, di = row_statistics(o, lse, do)
+    q_spec, o_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k)
+    in_specs = [q_spec, kv_spec, kv_spec, o_spec, row_spec, row_spec]
+    operands = (q5, k4, v4, do, lse, di)
     dq5 = _call(
         _bwd_dq_kernel, f"{name}_attn_bwd_dq", q_major, q5.shape,
         jax.ShapeDtypeStruct(q5.shape, q5.dtype), in_specs, q_spec,
@@ -315,24 +322,25 @@ def rule_attention_heads_first(q5: jax.Array, k4: jax.Array, v4: jax.Array,
                                rule, name: str) -> jax.Array:
     """:func:`rule_attention` on operands in the kernels' layout: ``q5
     [b, kv_heads, G, T, d]``, ``k4`` and ``v4 [b, kv_heads, T, d]`` ->
-    ``o5`` like ``q5``; the cotangents of ``q5``, ``k4`` and ``v4`` come
-    back as the backward kernels write them. Nothing is transposed on
-    either side."""
+    ``o [b, T, kv_heads * G * d]``, head ``i`` in lanes ``[i * d, (i +
+    1) * d)``; the cotangents of ``q5``, ``k4`` and ``v4`` come back as
+    the backward kernels write them. Nothing is transposed on either
+    side."""
     return _forward(q5, k4, v4, rule, name)[0]
 
 
 def _forward(q5, k4, v4, rule, name):
     _check(q5, k4, v4, name)
-    o5, lse = _fwd(rule, name, q5, k4, v4)
+    o, lse = _fwd(rule, name, q5, k4, v4)
     out_name, lse_name = saved_names(name)
-    o5 = checkpoint_name(o5, out_name)
+    o = checkpoint_name(o, out_name)
     lse = checkpoint_name(lse, lse_name)
-    return o5, (q5, k4, v4, o5, lse)
+    return o, (q5, k4, v4, o, lse)
 
 
-def _bwd_rule(rule, name, res, do5):
-    q5, k4, v4, o5, lse = res
-    return _bwd(rule, name, q5, k4, v4, o5, lse, do5.astype(q5.dtype))
+def _bwd_rule(rule, name, res, do):
+    q5, k4, v4, o, lse = res
+    return _bwd(rule, name, q5, k4, v4, o, lse, do.astype(q5.dtype))
 
 
 rule_attention_heads_first.defvjp(_forward, _bwd_rule)
@@ -345,7 +353,8 @@ def rule_attention(q: jax.Array, k: jax.Array, v: jax.Array, rule,
     kv_heads, d]`` (query head ``i`` reads key/value head ``i // (heads
     // kv_heads)``); ``rule`` and ``name`` are static: a rule for ``T``
     tokens, and what the call's kernels and saved arrays are called. A
-    thin wrapper: it turns its operands heads first, calls
-    :func:`rule_attention_heads_first` and turns the result back."""
-    return heads_last(rule_attention_heads_first(
-        *heads_first(q, k, v, f"{name}_attn"), rule, name))
+    thin wrapper: it turns its operands heads first and calls
+    :func:`rule_attention_heads_first`, whose flat result reshapes to
+    ``q``'s shape with no element moved."""
+    return rule_attention_heads_first(
+        *heads_first(q, k, v, f"{name}_attn"), rule, name).reshape(q.shape)

@@ -35,20 +35,32 @@ reduce down the registers with no cross-lane reduction, live in VMEM as
 rescale the running output ``[G, d, block_q]`` spread down the
 sublanes; as ``[block_q, 1]`` columns they cost as much as the tile's
 own passes (PERF.md section 6, PR 33 and 36). The output is turned back
-once a Q tile and leaves in the layout it always had; the log-sum-exp
-leaves one number a row, ``[b, kv_heads, G, T]`` float32 with T along
-the lanes. The int8 mask lies queries down in HBM (whoever selected the
-keys wrote it so, and dq and dkv read it so): the forward kernel turns
-its tile once a grid step, after the cast, for the heads that share it.
+once a Q tile, each head to its own lanes of the output's tile (below);
+the log-sum-exp leaves one number a row, ``[b, kv_heads, G, T]`` float32
+with T along the lanes. The int8 mask lies queries down in HBM (whoever
+selected the keys wrote it so, and dq and dkv read it so): the forward
+kernel turns its tile once a grid step, after the cast, for the heads
+that share it.
 The two backward kernels keep the tile queries down; they read finished
 statistics and reduce nothing.
 
+The output is FLAT, ``o [b, T, kv_heads * G * d]`` with head ``i`` in
+lanes ``[i * d, (i + 1) * d)``: the layout in which an output projection
+``[heads * d, hidden]`` reads it as it lies, and the encoder's kernels'
+(``ops/flash_attention.py``). A kernel's output block is ``[block_q, G *
+d]`` at ``(b, Q tile, key/value head)``: the turn from heads first is
+the block's index map and a lane slice a head in the tile
+(:func:`_head`), and the backward kernels read the cotangent ``do``
+through the same spec. Nothing outside a kernel reshapes the lane axis
+of an ``o``-sized array into heads by ``[b, T, heads, d]``, which on the
+TPU is a copy of the whole array: a sum or a product a head goes through
+:func:`by_head`.
+
 The backward kernels need two arrays only the forward kernel can make:
-its output in its own layout (``[b, kv_heads, G, T, d]``, the inputs'
-dtype) and the row statistics (the log-sum-exp above, as the kernel
-wrote it). The forward rule names both (``checkpoint_name``,
-:data:`SAVED_NAMES`), so a caller who rematerialises around the
-attention can list them in its policy
+its output (flat, the inputs' dtype) and the row statistics (the
+log-sum-exp above, as the kernel wrote it). The forward rule names both
+(``checkpoint_name``, :data:`SAVED_NAMES`), so a caller who
+rematerialises around the attention can list them in its policy
 (``save_only_these_names(*SAVED_NAMES)``) and the backward pass reads
 what the forward pass left, for one more activation of ``q``'s size a
 call, and does not launch ``sparse_attn_fwd`` a second time. Outside a
@@ -57,18 +69,17 @@ identities. On their way into dq and dkv the statistics and ``sum(o *
 do)`` are spread over 128 lanes by XLA (:func:`row_statistics`).
 
 Two entries, one set of kernels. :func:`sparse_attention_heads_first`
-takes and returns the kernels' own layout (``q5 [b, kv_heads, G, T,
-d]``, ``k4`` and ``v4 [b, kv_heads, T, d]`` -> ``o5``; backward the
-cotangents ``dq5``, ``dk4``, ``dv4`` as the kernels write them) and
-carries the ``custom_vjp``: nothing is transposed on either side. The
-decoder calls it (``models/sparse_moe_lm.py``): there the turn INTO the
-layout is made by ``ops/qk_norm_rope.py``'s kernels, whose output
-blocks' index map it is, and the turn of ``o5`` back to ``[b, T, h,
-d]`` (:func:`heads_last`, and its transpose on the output's cotangent)
-by the module, an XLA transpose still. :func:`sparse_attention` takes
-``[b, T, h, d]`` operands: a thin wrapper that makes both turns itself
-with XLA transposes (:func:`heads_first`, :func:`heads_last`), for the
-tests and any caller whose operands lie tokens first.
+takes the kernels' own layout (``q5 [b, kv_heads, G, T, d]``, ``k4`` and
+``v4 [b, kv_heads, T, d]``) and returns the flat ``o``; backward it
+takes the flat ``do`` and hands back ``dq5``, ``dk4``, ``dv4`` as the
+kernels write them. It carries the ``custom_vjp``: nothing is transposed
+on either side. The decoder calls it (``models/sparse_moe_lm.py``):
+there the turn INTO the layout is made by ``ops/qk_norm_rope.py``'s
+kernels, whose output blocks' index map it is, and ``Wo`` reads ``o`` as
+it lies. :func:`sparse_attention` takes ``[b, T, h, d]`` operands: a
+thin wrapper that turns them with XLA transposes (:func:`heads_first`)
+and reshapes the flat result, for the tests and any caller whose
+operands lie tokens first.
 
 ``pallas_call`` names: ``sparse_attn_fwd``, ``sparse_attn_bwd_dq``,
 ``sparse_attn_bwd_dkv``. Off the TPU they run in interpret mode. A
@@ -88,10 +99,11 @@ from jax.experimental.pallas import tpu as pltpu
 from sparktorch_tpu.ops.flash_attention import _nt, _tn
 
 _LANES = 128
+_SUBLANES = 8
 _NEG = -1e30         # a masked score: finite, so NEG - NEG is 0, not NaN
 
 # what the forward rule names for a caller's remat policy: the kernel's
-# output in its own layout, and the row statistics
+# output (flat) and the row statistics
 SAVED_NAMES = ("sparse_attn_out", "sparse_attn_lse")
 
 
@@ -145,12 +157,20 @@ def fwd_tile(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale, groups):
         l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
 
+def _head(g, d):
+    """Where head ``g`` lies in a ``[block_q, groups * d]`` tile of ``o``
+    or of its cotangent: ``d`` whole registers of lanes."""
+    return slice(None), pl.ds(g * d, d)
+
+
 def fwd_finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups):
-    """The running output turned back once a Q tile, and the log-sum-exp
+    """The running output turned back once a Q tile, each head to its
+    own lanes of the ``[block_q, groups * d]`` tile, and the log-sum-exp
     one number a row, ``[groups, block_q]``."""
+    d = acc_ref.shape[1]
     for g in range(groups):
         l = jnp.maximum(l_ref[g][:1], 1e-20)
-        o_ref[g] = (acc_ref[g] / l).T.astype(o_ref.dtype)
+        o_ref[_head(g, d)] = (acc_ref[g] / l).T.astype(o_ref.dtype)
         lse_ref[pl.ds(g, 1), :] = m_ref[g][:1] + jnp.log(l)
 
 
@@ -160,7 +180,7 @@ def dq_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dq_acc, scale,
         s = _scores(q_ref[g], k, keep, scale)
         p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
         dp = jax.lax.dot_general(
-            do_ref[g], v, (((1,), (1,)), ((), ())),
+            do_ref[_head(g, v.shape[-1])], v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - d_ref[g][:, :1])
         dq_acc[g] = dq_acc[g] + jax.lax.dot_general(
@@ -171,7 +191,7 @@ def dq_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dq_acc, scale,
 def dkv_tile(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dk_acc, dv_acc,
              scale, groups):
     for g in range(groups):
-        q, do = q_ref[g], do_ref[g]
+        q, do = q_ref[g], do_ref[_head(g, v.shape[-1])]
         s = _scores(q, k, keep, scale)
         p = jnp.where(keep, jnp.exp(s - lse_ref[g][:, :1]), 0.0)
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
@@ -289,10 +309,17 @@ def heads_first(q, k, v, name: str):
             jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
 
 
-def heads_last(x5):
-    """``[b, hkv, G, T, d]`` -> ``[b, T, hkv * G, d]``."""
-    b, hkv, g, t, d = x5.shape
-    return jnp.transpose(x5, (0, 3, 1, 2, 4)).reshape(b, t, hkv * g, d)
+def by_head(x, d: int):
+    """The flat ``x [b, T, heads * d]`` with its heads as an axis: ``[b,
+    T // 8, 8, heads, d]``, the tokens split by the 8 sublanes of a
+    register. Elementwise work and sums over a head's ``d`` lanes go
+    through this shape: the TPU compiler lays the flat array out in
+    tiles of 8 tokens x 128 lanes, so this reshape moves nothing (a
+    bitcast: the 8 stays under the heads), where ``[b, T, heads, d]``
+    puts the heads on the sublanes and costs a copy of the whole array
+    (PERF.md section 6, PR 41)."""
+    b, t, f = x.shape
+    return x.reshape(b, t // _SUBLANES, _SUBLANES, f // d, d)
 
 
 def _specs(groups, d, block_q, block_k, q_major: bool):
@@ -313,13 +340,17 @@ def _specs(groups, d, block_q, block_k, q_major: bool):
 
     q_spec = pl.BlockSpec((None, None, groups, block_q, d), order(
         lambda b, h, qi, ki: (b, h, 0, qq(qi, ki), 0)))
+    # o and its cotangent, [b, T, kv_heads * groups * d]: a key/value
+    # head's group is a block of lanes
+    o_spec = pl.BlockSpec((None, block_q, groups * d), order(
+        lambda b, h, qi, ki: (b, qq(qi, ki), h)))
     kv_spec = pl.BlockSpec((None, None, block_k, d), order(
         lambda b, h, qi, ki: (b, h, kk(qi, ki), 0)))
     mask_spec = pl.BlockSpec((None, block_q, block_k), order(
         lambda b, h, qi, ki: (b, qq(qi, ki), kk(qi, ki))))
     row_spec = pl.BlockSpec((None, None, groups, block_q, _LANES), order(
         lambda b, h, qi, ki: (b, h, 0, qq(qi, ki), 0)))
-    return q_spec, kv_spec, mask_spec, row_spec
+    return q_spec, o_spec, kv_spec, mask_spec, row_spec
 
 
 def fwd_scratch(groups, d, block_q):
@@ -338,7 +369,7 @@ def _fwd(q5, k4, v4, mask):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     n_q, n_k = t // block_q, t // block_k
-    q_spec, kv_spec, mask_spec, _ = _specs(
+    q_spec, o_spec, kv_spec, mask_spec, _ = _specs(
         groups, d, block_q, block_k, q_major=True)
     # the log-sum-exp, one number a row with the sequence along the lanes
     lse_spec = pl.BlockSpec((None, None, groups, block_q),
@@ -346,64 +377,72 @@ def _fwd(q5, k4, v4, mask):
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=d ** -0.5, block_q=block_q,
                           block_k=block_k, n_k=n_k, groups=groups),
-        out_shape=[jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, t, hkv * groups * d), q5.dtype),
                    jax.ShapeDtypeStruct((b, hkv, groups, t), jnp.float32)],
         grid=(b, hkv, n_q, n_k),
         in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, lse_spec],
+        out_specs=[o_spec, lse_spec],
         scratch_shapes=fwd_scratch(groups, d, block_q),
         interpret=_interpret(),
         name="sparse_attn_fwd",
     )(q5, k4, v4, mask)
 
 
-def row_statistics(o5, lse, do5):
-    """What the backward kernels read a row: the log-sum-exp and ``sum(o
-    * do)``, each spread over a tile's lanes."""
-    rows = (*o5.shape[:-1], _LANES)
-    di = jnp.sum(o5.astype(jnp.float32) * do5.astype(jnp.float32), axis=-1,
-                 keepdims=True)
-    di = jnp.broadcast_to(di, rows)
-    return jnp.broadcast_to(lse[..., None], rows), di
+def spread(rows):
+    """One float a row, ``[b, kv_heads, G, T]``, spread over a tile's
+    lanes: how the backward kernels read a row statistic."""
+    return jnp.broadcast_to(rows[..., None], (*rows.shape, _LANES))
 
 
-def _bwd(q5, k4, v4, mask, o5, lse, do5):
+def row_statistics(o, lse, do):
+    """What the backward kernels read a row: the log-sum-exp ``[b,
+    kv_heads, G, T]`` and ``sum(o * do)`` over each head's lanes of the
+    flat ``o`` and ``do`` (``[b, T, heads]``, turned to the log-sum-exp's
+    layout: one float a row), each spread over a tile's lanes."""
+    b, hkv, groups, t = lse.shape
+    di = jnp.sum(by_head(o.astype(jnp.float32) * do.astype(jnp.float32),
+                         o.shape[-1] // (hkv * groups)), axis=-1)
+    di = jnp.transpose(di.reshape(b, t, hkv, groups), (0, 2, 3, 1))
+    return spread(lse), spread(di)
+
+
+def _bwd(q5, k4, v4, mask, o, lse, do):
     b, hkv, groups, t, d = q5.shape
     block_q, block_k = _blocks(t)
     n_q, n_k = t // block_q, t // block_k
     kw = dict(scale=d ** -0.5, block_q=block_q, block_k=block_k,
               groups=groups)
-    lse, di = row_statistics(o5, lse, do5)
+    lse, di = row_statistics(o, lse, do)
 
-    q_spec, kv_spec, mask_spec, row_spec = _specs(
+    q_spec, o_spec, kv_spec, mask_spec, row_spec = _specs(
         groups, d, block_q, block_k, q_major=True)
     dq5 = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, n_k=n_k, **kw),
         out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
         grid=(b, hkv, n_q, n_k),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, row_spec,
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, o_spec, row_spec,
                   row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((groups, block_q, d), jnp.float32)],
         interpret=_interpret(),
         name="sparse_attn_bwd_dq",
-    )(q5, k4, v4, mask, do5, lse, di)
+    )(q5, k4, v4, mask, do, lse, di)
 
-    q_spec, kv_spec, mask_spec, row_spec = _specs(
+    q_spec, o_spec, kv_spec, mask_spec, row_spec = _specs(
         groups, d, block_q, block_k, q_major=False)
     dk4, dv4 = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, n_q=n_q, **kw),
         out_shape=[jax.ShapeDtypeStruct(k4.shape, k4.dtype),
                    jax.ShapeDtypeStruct(v4.shape, v4.dtype)],
         grid=(b, hkv, n_k, n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, row_spec,
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, o_spec, row_spec,
                   row_spec],
         out_specs=[kv_spec, kv_spec],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
         name="sparse_attn_bwd_dkv",
-    )(q5, k4, v4, mask, do5, lse, di)
+    )(q5, k4, v4, mask, do, lse, di)
     return dq5, dk4, dv4
 
 
@@ -412,23 +451,24 @@ def sparse_attention_heads_first(q5: jax.Array, k4: jax.Array,
                                  v4: jax.Array, mask: jax.Array) -> jax.Array:
     """:func:`sparse_attention` on operands in the kernels' layout:
     ``q5 [b, kv_heads, G, T, d]``, ``k4`` and ``v4 [b, kv_heads, T,
-    d]`` -> ``o5`` like ``q5``; the cotangents of ``q5``, ``k4`` and
-    ``v4`` come back as the backward kernels write them. Nothing is
-    transposed on either side."""
+    d]`` -> ``o [b, T, kv_heads * G * d]``, head ``i`` in lanes ``[i *
+    d, (i + 1) * d)``; the cotangents of ``q5``, ``k4`` and ``v4`` come
+    back as the backward kernels write them. Nothing is transposed on
+    either side."""
     return _forward(q5, k4, v4, mask)[0]
 
 
 def _forward(q5, k4, v4, mask):
     _check(q5, k4, v4, mask)
-    o5, lse = _fwd(q5, k4, v4, mask)
-    o5 = checkpoint_name(o5, SAVED_NAMES[0])
+    o, lse = _fwd(q5, k4, v4, mask)
+    o = checkpoint_name(o, SAVED_NAMES[0])
     lse = checkpoint_name(lse, SAVED_NAMES[1])
-    return o5, (q5, k4, v4, mask, o5, lse)
+    return o, (q5, k4, v4, mask, o, lse)
 
 
-def _bwd_rule(res, do5):
-    q5, k4, v4, mask, o5, lse = res
-    return (*_bwd(q5, k4, v4, mask, o5, lse, do5.astype(q5.dtype)), None)
+def _bwd_rule(res, do):
+    q5, k4, v4, mask, o, lse = res
+    return (*_bwd(q5, k4, v4, mask, o, lse, do.astype(q5.dtype)), None)
 
 
 sparse_attention_heads_first.defvjp(_forward, _bwd_rule)
@@ -442,7 +482,8 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (heads // kv_heads)``), ``mask`` is int8 ``[b, T, T]``, shared by
     all heads and zero above the diagonal. A query that selects nothing
     gets zeros. No gradient reaches the mask. A thin wrapper: it turns
-    its operands heads first, calls
-    :func:`sparse_attention_heads_first` and turns the result back."""
-    return heads_last(sparse_attention_heads_first(
-        *heads_first(q, k, v, "sparse_attention"), mask))
+    its operands heads first and calls
+    :func:`sparse_attention_heads_first`, whose flat result reshapes to
+    ``q``'s shape with no element moved."""
+    return sparse_attention_heads_first(
+        *heads_first(q, k, v, "sparse_attention"), mask).reshape(q.shape)

@@ -21,6 +21,7 @@ import os
 import re
 import sys
 
+import numpy as np
 import pytest
 
 import jax
@@ -121,6 +122,16 @@ def _forward_statistics(text, kernel):
                                    line.split(" custom-call(")[0])]
 
 
+def _o_sized_copies(text, tokens):
+    """The compiled text's ``copy`` and ``transpose`` instructions whose
+    result is ``o`` by head, ``[b, tokens, heads, 128]`` or heads first
+    ``[b, kv_heads, G, tokens, 128]``, in any dtype and layout: what a
+    module that does not read ``o`` as the kernels write it costs."""
+    by_head = rf"\w+\[\d+,{tokens},\d+,128\]|\w+\[\d+,\d+,\d+,{tokens},128\]"
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= ({by_head})\S* (copy|transpose)\(", line)]
+
+
 def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     """The selected-key kernels at Keye-VL-2.0's heads (32 query heads
     on 4 key/value heads of 128), one row of 4,096 tokens."""
@@ -178,6 +189,7 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "qk_norm_rope_fwd") == 2
     assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # 4,000 padded to 4,096
+    assert not _o_sized_copies(text, 8192)  # o leaves flat and is read so
 
     grouped = re.compile(r"%ragged-dot-none[.\d]* = ")
     comps = _computations(text)
@@ -227,6 +239,7 @@ def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
     assert _pallas_calls(text, "sparse_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # on 8,192 rows
+    assert not _o_sized_copies(text, 16384)
 
 
 def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
@@ -272,6 +285,19 @@ def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     # and the products reach it as they leave: no transposing copy of a
     # float32 product (tokens minor from an einsum over [d, heads, 128])
     assert not re.search(r"f32\[2,8192,(6144|8192)\]\S* copy\(", text)
+    # and o leaves the kernels as the gate and Wo read it: nothing copies
+    # or transposes it by head, and outside a fusion's own computation
+    # (so: to HBM) no instruction under the gate's scope writes a
+    # float32 array of o's size
+    assert not _o_sized_copies(text, 8192)
+    o_sized = {2 * 8192 * heads * 128 for heads in (48, 64)}
+    gate_f32 = [
+        line.strip()[:160] for name, lines in _computations(text).items()
+        if "fused_computation" not in name for line in lines
+        if "attn_gate" in line for dims in re.findall(
+            r"f32\[([\d,]+)\]", line.split(" = ")[-1].split("(")[0])
+        if int(np.prod([int(d) for d in dims.split(",")])) in o_sized]
+    assert not gate_f32, gate_f32
     assert _pallas_calls(text, "blockdiff_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1
     # weights, gradients and Adam's moments are 7.84 GB of the 15.75
@@ -511,7 +537,6 @@ def test_dp4_step_runs_its_allreduces_under_the_backward_pass(
     options of ``train/step.py`` the same lowered program compiles to
     no asynchronous all-reduce at all. Over one chip the step is a
     plain ``jax.jit``."""
-    import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from sparktorch_tpu.models import SequenceClassifier, tiny_transformer

@@ -307,12 +307,14 @@ def test_the_published_model_and_what_a_configuration_may_not_say():
 
 # sha256 (first 16) of each older model's parameter tree (paths, shapes,
 # dtypes) and of the lowered text of the gradient of its loss with its
-# counters, at the sizes below, read on the parent commit (6539c26) by
-# the same lines. A PR that means to change one of these models' steps
-# reads them anew.
-PARENT = {"keye": ("96e560cdd12a17e7", "1202bebaf07418f9"),
-          "sdar": ("b60f6b0d23c7320d", "514c8ff762c43718"),
-          "laguna": ("0ce980a58c390130", "86eb2cc5066edd81")}
+# counters, at the sizes below. The trees are the ones read on commit
+# 6539c26 (PR 38) by the same lines: no PR since has moved a leaf. The
+# steps were read anew at PR 41, which meant to change them (``o``
+# leaves the attention kernels flat and ``Wo`` reads it so). A PR that
+# means to change one of these models' steps reads them anew.
+PARENT = {"keye": ("96e560cdd12a17e7", "ea4bc228b53e2164"),
+          "sdar": ("b60f6b0d23c7320d", "de7733f5cfb06b03"),
+          "laguna": ("0ce980a58c390130", "ccdf118bcc6d0167")}
 
 
 def older_model(name):

@@ -128,15 +128,17 @@ HEADS_FIRST = {
 @pytest.mark.parametrize("name", list(HEADS_FIRST))
 def test_the_heads_first_entry_is_the_op_without_its_turns(name):
     """What the decoder calls: operands as the kernels read them give
-    ``o5`` and, backward, the cotangents as the kernels write them, bit
-    for bit what the ``[b, T, h, d]`` wrapper turns in and out."""
+    the flat ``o [b, T, heads * d]`` and, backward, the cotangents as
+    the kernels write them, bit for bit what the ``[b, T, h, d]``
+    wrapper turns in and reads by head."""
     wrapper, entry = HEADS_FIRST[name]
     qkv = make_qkv(384, 6, kv=2)
     q5, k4, v4 = sparse.heads_first(*qkv, "test")
     assert q5.shape == (1, 2, 6, 384, D) and k4.shape == (1, 2, 384, D)
-    np.testing.assert_array_equal(sparse.heads_last(entry(q5, k4, v4)),
-                                  wrapper(*qkv))
-    got = _grads(entry, (q5, k4, v4))
+    by_head = lambda *a: entry(*a).reshape(1, 384, 12, D)
+    assert entry(q5, k4, v4).shape == (1, 384, 12 * D)
+    np.testing.assert_array_equal(by_head(q5, k4, v4), wrapper(*qkv))
+    got = _grads(by_head, (q5, k4, v4))
     want = sparse.heads_first(*_grads(wrapper, qkv), "test")
     for a, b, which in zip(got, want, "qkv"):
         np.testing.assert_array_equal(a, b, err_msg=f"d{which}")
@@ -262,7 +264,7 @@ def test_the_forward_kernel_writes_one_statistic_a_row(name):
                  "pallas_call" and eqn.params["name"] == f"{name}_attn_fwd"]
     out, lse = (var.aval for var in kernel.outvars)
     b, hkv, groups = q.shape[0], k.shape[2], q.shape[2] // k.shape[2]
-    assert (out.shape, out.dtype) == ((b, hkv, groups, T, D), jnp.bfloat16)
+    assert (out.shape, out.dtype) == ((b, T, hkv * groups * D), jnp.bfloat16)
     assert (lse.shape, lse.dtype) == ((b, hkv, groups, T), jnp.float32)
     named = {eqn.params["name"]: eqn.invars[0] for eqn in jaxpr.eqns
              if eqn.primitive.name == "name"}
@@ -294,8 +296,8 @@ def test_the_saved_statistics_are_the_dense_log_sum_exp(name):
         0] * (T - 1)
     np.testing.assert_allclose(lse, want.reshape(lse.shape), atol=1e-5,
                                rtol=1e-6)
-    assert out.shape == (q.shape[0], k.shape[2], groups, T, D)
-    assert np.all(np.asarray(out[..., EMPTY, :]) == 0)
+    assert out.shape == (q.shape[0], T, q.shape[2] * D)
+    assert np.all(np.asarray(out[:, EMPTY]) == 0)
     assert np.all(np.isfinite(np.asarray(out)))
 
 

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from sparktorch_tpu.ops.sparse_attention import (
-    SAVED_NAMES, heads_first, heads_last, sparse_attention,
+    SAVED_NAMES, heads_first, sparse_attention,
     sparse_attention_heads_first)
 
 B, HQ, HKV, D = 2, 4, 2, 128
@@ -98,17 +98,19 @@ def test_in_bfloat16_it_is_dense_attention_to_bfloat16s_precision():
 
 def test_the_heads_first_entry_is_the_op_without_its_turns(qkv):
     """What the decoder calls: operands as the kernels read them (``q5
-    [b, kv_heads, G, T, d]``, ``k4``, ``v4``) give ``o5`` and, backward,
-    the cotangents as the kernels write them, bit for bit what the ``[b,
-    T, h, d]`` wrapper turns in and out."""
+    [b, kv_heads, G, T, d]``, ``k4``, ``v4``) give the flat ``o [b, T,
+    heads * d]``, head ``i`` in lanes ``[i * d, (i + 1) * d)``, and,
+    backward, the cotangents as the kernels write them, bit for bit what
+    the ``[b, T, h, d]`` wrapper turns in and reads by head."""
     mask = make_mask("random", 384)
     q5, k4, v4 = heads_first(*qkv, "test")
     assert q5.shape == (B, HKV, HQ // HKV, 384, D)
     assert k4.shape == v4.shape == (B, HKV, 384, D)
-    o5 = sparse_attention_heads_first(q5, k4, v4, mask)
-    np.testing.assert_array_equal(heads_last(o5),
+    o = sparse_attention_heads_first(q5, k4, v4, mask)
+    assert o.shape == (B, 384, HQ * D)
+    np.testing.assert_array_equal(o.reshape(B, 384, HQ, D),
                                   sparse_attention(*qkv, mask))
-    weight = jnp.cos(jnp.arange(D, dtype=jnp.float32))
+    weight = jnp.tile(jnp.cos(jnp.arange(D, dtype=jnp.float32)), HQ)
     got = jax.grad(lambda *a: jnp.sum(
         sparse_attention_heads_first(*a, mask) * weight),
         argnums=(0, 1, 2))(q5, k4, v4)
@@ -187,6 +189,5 @@ def test_the_row_statistics_are_kept_without_an_axis_of_one():
              if eqn.primitive.name == "name"}
     assert set(named) == set(SAVED_NAMES)
     out, lse = (named[n] for n in SAVED_NAMES)
-    assert (out.shape, out.dtype) == ((B, HKV, HQ // HKV, 384, D),
-                                      jnp.bfloat16)
+    assert (out.shape, out.dtype) == ((B, 384, HQ * D), jnp.bfloat16)
     assert (lse.shape, lse.dtype) == ((B, HKV, HQ // HKV, 384), jnp.float32)

@@ -454,8 +454,9 @@ def test_keeping_the_attentions_results_changes_no_gradient_leaf(
 
 def _tiny_loss(model):
     """``(loss of the parameters, the parameters)`` of the tiny model of
-    that name: this file's, ``test_block_diffusion_lm``'s or
-    ``test_mixed_attention_lm``'s, on its own rows. A function of its
+    that name: this file's, ``test_block_diffusion_lm``'s,
+    ``test_mixed_attention_lm``'s (gated) or
+    ``test_latent_attention_lm``'s, on its own rows. A function of its
     own each call: a trace is cached by function."""
     if model == "keye":
         (cfg, module), (ids, labels) = sizes(), rows()
@@ -466,7 +467,10 @@ def _tiny_loss(model):
         loss_fn, ref, labels, more = case.LOSS, case.REF, ids, {
             "rngs": case.STREAM}
     else:
-        import test_mixed_attention_lm as case
+        if model == "laguna":
+            import test_mixed_attention_lm as case
+        else:
+            import test_latent_attention_lm as case
         (cfg, module), (ids, labels) = case.sizes(), case.rows()
         loss_fn, ref, more = case.LOSS, case.REF, {}
     params = ref.init(jax.random.key(0), cfg)["params"]
@@ -530,18 +534,63 @@ def test_the_fused_kernels_run_twice_forward_and_once_backward_a_layer(
     assert pallas_calls(jaxpr, "qk_norm_rope_bwd") == n
 
 
-def test_nothing_is_turned_into_an_attention_kernel_but_its_outputs_cotangent(
-        fused_and_plain):
-    """q, k and v reach the kernels as the fused op writes them and
-    their cotangents leave as the kernels write them: of the turns
-    between ``[b, T, heads, 128]`` and heads first a layer keeps the
-    output's (forward and the remat's) and its cotangent's; no key or
-    value array is swapped either way."""
-    jaxpr, n = fused_and_plain["jaxpr"], fused_and_plain["layers"]
+def _shapes(jaxpr):
+    """The shape of every array an equation of the jaxpr, or of a jaxpr
+    inside it, produces."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [v.aval.shape for v in eqn.outvars
+                  if hasattr(v.aval, "shape")]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _shapes(sub)
+    return found
+
+
+# the turns between [b, T, heads, 128] and heads first, either way, and
+# the swap of a key or value array's token and head axes
+_TURNS = {(0, 2, 3, 1, 4), (0, 3, 1, 2, 4), (0, 2, 1, 3)}
+
+
+@pytest.mark.parametrize("model", ["keye", "sdar", "laguna", "joyai"])
+def test_nothing_is_turned_on_either_side_of_an_attention_kernel(model):
+    """q, k and v reach the kernels as the fused op writes them, their
+    cotangents leave as the kernels write them, and ``o`` leaves flat,
+    ``[b, T, heads * 128]``, for the gate and ``Wo`` to read as it lies
+    (its cotangent comes back so): the traced loss and gradient of each
+    tiny grouped-query model (a gated one among them) transposes no
+    array of 128 lanes between tokens first and heads first, and holds
+    no array ``[b, T, heads, 128]`` at all, which on the TPU is a copy
+    of the whole array away from the flat one (the gate's product and
+    the row statistics' sum go through ``by_head``). The latent one
+    keeps its output's turn and nothing else."""
+    loss, params = _tiny_loss(model)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss))(params).jaxpr
     turns = [perm for perm, shape in _transposes(jaxpr) if shape[-1] == 128]
-    assert turns.count((0, 2, 3, 1, 4)) == n       # heads_first(do)
-    assert turns.count((0, 3, 1, 2, 4)) == 2 * n   # heads_last(o5)
-    assert (0, 2, 1, 3) not in turns
+    if model == "joyai":
+        # one head a grid step: latent attention keeps o5 heads first,
+        # and its module's turn (forward, the remat's, the cotangent's)
+        # a layer is all that is left (ops/latent_attention.py)
+        layers = sum(name.startswith("layer_") for name in params) + 1
+        assert sorted(set(turns) & _TURNS) == [(0, 2, 3, 1, 4),
+                                               (0, 3, 1, 2, 4)]
+        assert turns.count((0, 3, 1, 2, 4)) == 2 * layers
+        assert turns.count((0, 2, 3, 1, 4)) == layers
+        return
+    assert not set(turns) & _TURNS, turns
+    rows = {shape[:2] for shape in _shapes(jaxpr)
+            if len(shape) == 3 and shape[-1] % 128 == 0 and shape[-1] > 128}
+    assert rows  # the flat arrays are there: [b, T, heads * 128]
+    by_heads = [shape for shape in _shapes(jaxpr)
+                if len(shape) == 4 and shape[-1] == 128
+                and shape[:2] in rows]
+    assert not by_heads, by_heads
+    # the probe sees both where they are: the parent's spelling
+    b, t = next(iter(rows))
+    turned = jax.make_jaxpr(lambda o5: jnp.transpose(
+        o5, (0, 3, 1, 2, 4)).reshape(b, t, 2, 128))(
+            jnp.zeros((b, 1, 2, t, 128))).jaxpr
+    assert {perm for perm, _ in _transposes(turned)} & _TURNS
+    assert (b, t, 2, 128) in _shapes(turned)
 
 
 def _float_arrays_by_pair(jaxpr, n_pairs):
