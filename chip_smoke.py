@@ -5,7 +5,7 @@ points a user calls; data is made from ``--seed`` and nothing is
 fetched. It fails before any phase unless JAX's first device is a TPU
 — it never sets a platform itself.
 
-With no arguments (one chip), six phases:
+With no arguments (one chip), seven phases:
 
 - ``trainer_sync``   the README flow at BERT-base width:
   ``serialize_torch_obj(bert_base())`` -> ``SparkTorch(mode=
@@ -37,6 +37,14 @@ With no arguments (one chip), six phases:
   against dense causal attention at the true widths, output and the
   three gradients. The 256-lane padding, the lane rolls and the index
   maps are checked here, where interpret mode cannot.
+- ``gated_delta``    ``ops/gated_delta_rule.py`` (the chunked gated
+  delta rule, chunks of 64) at Qwen3-Next's width (16 key and 32 value
+  heads of 128) on 2,048 tokens against the token-by-token recurrence
+  in float32: the output and all five gradients (``q``, ``k``, ``v``,
+  the log-decays, ``beta``). The triangular inverse's products, the
+  identity products that turn a token's scalars and the state carried
+  across the grid's sequential axis are checked here, where interpret
+  mode cannot.
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -87,6 +95,11 @@ TOL_FUSED_GRAD_REL = 1e-4
 # float32 attention on the same bf16 operands
 TOL_LATENT_OUT_REL = 1e-2
 TOL_LATENT_GRAD_REL = 2e-2
+# the chunked gated delta rule (bf16 operands, float32 sums and state)
+# against the token-by-token recurrence in float32 on the same bf16
+# operands: the chunk's T, W and V' enter their products rounded to bf16
+TOL_GDN_OUT_REL = 1e-2
+TOL_GDN_GRAD_REL = 2e-2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,6 +136,8 @@ class Sizes:
     # latent_attention: (rows, tokens, heads) of nope 128 + rope 64 over
     # values of 128
     latent_case: tuple = (1, 2048, 32)
+    # gated_delta: (rows, tokens, key heads, value heads) of 128
+    gdn_case: tuple = (1, 2048, 16, 32)
     # trainer_hogwild: bench resnet18_hogwild
     hog_rows: int = 1024
     hog_mini_batch: int = 256
@@ -512,6 +527,81 @@ def phase_latent_attention(sz: Sizes, seed: int, ctx: dict) -> str:
     return report
 
 
+def phase_gated_delta(sz: Sizes, seed: int, ctx: dict) -> str:
+    """``ops/gated_delta_rule.py`` (chunks of 64, the WY form) against
+    the token-by-token recurrence at Qwen3-Next's width: the output and
+    all five gradients, decays from a few tokens' half-life to some
+    hundreds'."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    b, t, hk, hv = sz.gdn_case
+    d, dt = 128, jnp.bfloat16
+    keys = jax.random.split(jax.random.key(seed), 6)
+    unit = lambda x: (x / jnp.linalg.norm(x, axis=-1, keepdims=True))
+    q = (unit(jax.random.normal(keys[0], (b, t, hk, d))) * d ** -0.5).astype(dt)
+    k = unit(jax.random.normal(keys[1], (b, t, hk, d))).astype(dt)
+    v = jax.random.normal(keys[2], (b, t, hv, d)).astype(dt)
+    g = -jnp.exp(jax.random.uniform(keys[3], (b, t, hv), minval=np.log(1e-3),
+                                    maxval=np.log(0.5)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, hv)))
+    w_out = jax.random.normal(keys[5], (b, t, hv * d))
+
+    def recurrence(q, k, v, g, beta):
+        f32 = lambda x, heads: jnp.repeat(
+            x.astype(jnp.float32), hv // heads, 2)
+
+        def step(state, x):
+            q, k, v, g, beta = x
+            state = jnp.exp(g)[..., None, None] * state
+            u = beta[..., None] * (v - jnp.einsum(
+                "bhkv,bhk->bhv", state, k, precision="highest"))
+            state = state + k[..., :, None] * u[..., None, :]
+            return state, jnp.einsum("bhkv,bhk->bhv", state, q,
+                                     precision="highest")
+
+        _, o = jax.lax.scan(
+            step, jnp.zeros((b, hv, d, d), jnp.float32),
+            tuple(jnp.moveaxis(a, 1, 0) for a in (
+                f32(q, hk), f32(k, hk), f32(v, hv), g, beta)))
+        return jnp.moveaxis(o, 0, 1).reshape(b, t, hv * d)
+
+    def mine(q, k, v, g, beta):
+        flat = lambda x: x.reshape(b, t, -1)
+        return gated_delta_rule(flat(q), flat(k), flat(v), g,
+                                beta).astype(jnp.float32)
+
+    def both(fn):
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *a: jnp.sum(fn(*a) * w_out),
+            argnums=(0, 1, 2, 3, 4))(*a)))
+
+    def rel(a, b):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+    run = both(mine)
+    got = jax.block_until_ready(run(q, k, v, g, beta))
+    t0 = time.perf_counter()
+    jax.block_until_ready(run(q, k, v, g, beta))
+    mine_s = time.perf_counter() - t0
+    want = jax.block_until_ready(both(recurrence)(q, k, v, g, beta))
+    out_rel = rel(got[0], want[0])
+    grads = {n: rel(a, b) for n, a, b in zip(
+        ("q", "k", "v", "g", "beta"), got[1], want[1])}
+    report = (f"{hk}/{hv}x{t}x{b} out_rel={out_rel:.2e} grad_rel="
+              f"{ {n: float(f'{r:.2e}') for n, r in grads.items()} } "
+              f"fwd_and_grad_s={mine_s:.4f}")
+    if not (out_rel <= TOL_GDN_OUT_REL                  # NaN fails too
+            and max(grads.values()) <= TOL_GDN_GRAD_REL):
+        raise AssertionError(
+            f"the chunked gated delta rule vs the recurrence: {report} "
+            f"(limits {TOL_GDN_OUT_REL}, {TOL_GDN_GRAD_REL})")
+    return report
+
+
 def phase_trainer_hogwild(sz: Sizes, seed: int, ctx: dict) -> str:
     from sparktorch_tpu import SparkTorch, serialize_torch_obj
     from sparktorch_tpu.models.resnet import resnet18
@@ -777,6 +867,7 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("kernels", phase_kernels),
             ("qk_norm_rope", phase_qk_norm_rope),
             ("latent_attention", phase_latent_attention),
+            ("gated_delta", phase_gated_delta),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),

@@ -2,7 +2,7 @@
 experts that is told which experts it holds (Qwen3-MoE's block). What a
 layer is belongs to the LAYER (:class:`LayerKind`: its attention, its
 query heads, its rotary table, experts or a dense MLP after it), and a
-model is its list of layers (:class:`SparseMoEConfig` ``layers``). Four
+model is its list of layers (:class:`SparseMoEConfig` ``layers``). Five
 models are built on it:
 
 - every layer ``learned_sparse``: DeepSeek-V3.2's lightning indexer in
@@ -19,7 +19,12 @@ models are built on it:
   latents, keys wider than values, a selection bias on the router and a
   multi-token prediction module after the last layer (JoyAI-LLM-Flash,
   :func:`joyai_flash_lm`; "Latent attention" and "Multi-token
-  prediction" below).
+  prediction" below);
+- ``gated_delta`` (Gated DeltaNet linear attention: no keys and values,
+  a recurrent state a head) and ``full`` layers mixed 3:1, the full
+  layers at 256-wide heads under an element-wise output gate, ten of
+  512 softmax-routed experts beside a GATED shared expert
+  (Qwen3-Next-80B-A3B, :func:`qwen3_next_lm`; "Gated delta rule" below).
 
 Shared by all, written once: the projections, q/k norm and rotary step
 around the attention kernel of the grouped-query kinds
@@ -28,7 +33,9 @@ around the attention kernels" below), the expert layer (``HeldExperts``, ``held_
 remat with its attention kernel's output and row statistics kept, the
 head's padding to the fused cross entropy's tile, the counters and
 gauges. What differs is the attention module (``_ATTENTION``, by the
-layer's kind) and, under block diffusion, what the model does before its
+layer's kind: the grouped-query kinds share ``_GroupedQueryProjections``,
+``latent`` and ``gated_delta`` are modules beside it that share no
+projection with them) and, under block diffusion, what the model does before its
 first layer and hands back after its last. A model whose layers are all
 alike says its one kind by four fields (``attention``, ``n_heads``,
 ``rope_theta``, ``mrope_section``) and builds what it built before
@@ -218,6 +225,58 @@ uncompressed, training form; :class:`LatentAttention`). For the tokens
   against the experts' loads is left out): a configuration without one
   builds no leaf for it.
 
+Gated delta rule (Gated DeltaNet, the linear-attention layer of
+Qwen3-Next; :class:`GatedDeltaNet`). Layer ``l`` is linear attention
+when ``(l + 1) % 4 != 0`` and full attention otherwise. For the tokens
+``x [T, d]`` of a row, ``n_k`` = 16 key heads and ``n_v`` = 32 value
+heads of 128, value head ``j`` reading key head ``j // 2``:
+
+- ``h = RMSNorm(x)``; ``[q ; k ; v ; z] = h W_qkvz`` (``[d, 2 n_k 128 +
+  2 n_v 128]``: ``q`` and ``k`` 2,048 wide, ``v`` and ``z`` 4,096),
+  ``[b ; a] = h W_ba`` (``[d, 2 n_v]``), no biases, the columns in that
+  order and heads in order (scope ``gdn_in_proj`` inside ``attn_qkv``).
+- ``u = [q ; k ; v]`` (8,192 channels) through a causal depthwise
+  convolution over time of 4 taps, no bias, then SiLU: ``u~[t, c] =
+  silu(sum_{i < 4} w[i, c] u[t - 3 + i, c])``, ``u[t < 0] = 0``: four
+  shifted multiply-adds over the channels as they lie. ``z``, ``a`` and
+  ``b`` do not pass it (scope ``gdn_conv``).
+- A value head ``j`` and a token ``t``: ``beta = sigmoid(b)``, the
+  log-decay ``g = -exp(A_log_j) softplus(a + dt_bias_j)`` (float32),
+  ``alpha = exp(g)`` in (0, 1); ``q^ = q / sqrt(sum q^2 + 1e-6) /
+  sqrt(128)`` and ``k^ = k / sqrt(sum k^2 + 1e-6)`` over a key head's
+  128 dims (scope ``gdn_gates``).
+- The gated delta rule, a state ``S [128, 128]`` a value head from ``S_0
+  = 0``: ``S' = alpha_t S_{t-1}``; ``u_t = beta_t (v_t - S'^T k^_t)``;
+  ``S_t = S' + k^_t u_t^T``; ``o_t = S_t^T q^_t`` (so ``S_t = alpha_t (I
+  - beta_t k^ k^^T) S_{t-1} + beta_t k^ v^T``), computed in chunks of
+  64 tokens by ``ops/gated_delta_rule.py`` (its ``CHUNK``; its
+  docstring has the chunk's form, the layout and what is kept; scope
+  ``gated_delta``). A row that is not whole chunks is an error.
+- ``y_{t,j} = RMSNorm(o_{t,j}; gain [128]) * silu(z_{t,j})``, the norm
+  over a head's 128 dims (scope ``gdn_out_norm``); ``x = x + concat_j(y_j)
+  W_o`` (``[n_v 128, d]``, scope ``attn_out`` inside ``gdn_out_proj``).
+- ``A_log`` and ``dt_bias`` start on a ladder (``_DECAY_RATES``): head
+  ``j``'s rate ``exp(A_log_j)`` runs geometrically from 2e-3 to 0.25 and
+  ``softplus(dt_bias) = 1``, half-lives from a few tokens to some
+  hundreds; the convolution's taps N(0, 0.289).
+- The full layers are the third model's ``full`` kind at ``head_dim``
+  256 (16 query heads on 2 key/value heads, rotary by halves on 64 of
+  the 256 dims, theta 1e7, plain) with ``attn_gate_width`` ``"element"``:
+  the query projection is twice as wide, ``[q ; gate] = h W_q``, held as
+  two leaves of one shape (``wq`` and ``wq_gate``), and ``o <- o *
+  sigmoid(gate)`` element by element on the flat ``o`` before ``W_o``
+  (scope ``attn_gate``).
+- The expert layer after every mixer is the first model's (softmax over
+  ALL 512, the 10 largest, gates renormalised), and beside it the shared
+  expert under a gate (``shared_expert_gate``): ``x = x + sum_{chosen e
+  held here} gate_e E_e(g) + sigmoid(g w_s) S(g)``, ``w_s [d, 1]``
+  (``gate``, a leaf of the shared expert), one sigmoid a token, on every
+  chip alike (scope ``shared_expert``).
+
+Left out: decoding (the recurrent state and the convolution's last
+three tokens as a cache), state resets at document boundaries in a
+packed row, and the model's multi-token prediction module.
+
 Multi-token prediction (DeepSeek-V3 section 2.2, depth 1;
 :class:`MultiTokenPredictor`, ``mtp_depth`` 1). With ``x^L`` the stream
 after the last layer (before the final norm), ``E`` the model's own
@@ -251,7 +310,9 @@ sows none); under block diffusion also ``masked_tokens`` and ``tokens``
 of the step and, by layer, ``attn_tiles`` (the tiles the attention's
 forward kernel visits, of the whole square's); by each ``full``,
 ``window`` and ``latent`` layer the same count as ``attn_tiles_full`` /
-``attn_tiles_window`` / ``attn_tiles_latent``; by the multi-token
+``attn_tiles_window`` / ``attn_tiles_latent``; by each ``gated_delta``
+layer ``gdn_chunks`` (the chunks the rule's forward kernel runs: rows x
+value heads x ``T / 64``); by the multi-token
 prediction module ``mtp_loss`` (the sum of its cross entropy over the
 positions whose label the row itself holds, ``ids[i + 2]`` for ``i < T -
 2``: a forward pass of the loss's kernel on its logits, no gradient) and
@@ -271,6 +332,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from sparktorch_tpu.ops import gated_delta_rule as delta
 from sparktorch_tpu.ops import qk_norm_rope as fused
 from sparktorch_tpu.ops import latent_attention as latent
 from sparktorch_tpu.ops.block_diffusion_attention import (
@@ -320,12 +382,13 @@ class Rotary:
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer is: its attention (a key of ``_ATTENTION``), its
-    query heads, its rotary table, and ``"experts"`` or ``"dense"`` after
-    the attention."""
+    query heads (a ``gated_delta`` layer's value heads), its rotary table
+    (None for a layer that takes no rotary step: ``gated_delta``), and
+    ``"experts"`` or ``"dense"`` after the attention."""
 
     attention: str
     n_heads: int
-    rotary: Rotary
+    rotary: Optional[Rotary]
     mlp: str = "experts"
 
 
@@ -358,6 +421,16 @@ class SparseMoEConfig:
     # "full" and "window" layers: one sigmoid gate a head a token on the
     # attention's output, from the layer's normed input
     attn_gate: bool = False
+    # the gate's width: "head" (one a head a token, from a projection of
+    # its own, ``wg``) or "element" (one a dim of a head: the query
+    # projection's second half, ``wq_gate``)
+    attn_gate_width: str = "head"
+    # "gated_delta" layers: key heads (a layer's ``n_heads`` is its value
+    # heads, each reading key head ``j // (value / key)``; every head is
+    # ``ops/gated_delta_rule.py``'s ``HEAD_DIM`` wide and the rule runs in
+    # its ``CHUNK``) and the causal convolution's taps
+    linear_key_heads: int = 0
+    linear_conv_width: int = 4
     # "latent" layers: the ranks of the two latents, a head's dims that
     # pass the rotary step and that take it (a query and a key are both,
     # the rotary part of the key one for all heads), and a value's dims
@@ -379,6 +452,8 @@ class SparseMoEConfig:
     selection_bias: bool = False
     # an expert every token goes through, beside the routed ones (0: none)
     shared_expert_width: int = 0
+    # one sigmoid a token on the shared expert's output, from ``g``
+    shared_expert_gate: bool = False
     dense_width: int = 0   # of a "dense" layer's MLP
     # multi-token prediction modules after the last layer (0 or 1), and
     # the weight of their loss beside the next token's
@@ -390,6 +465,7 @@ class SparseMoEConfig:
         if not self.layers:
             object.__setattr__(self, "layers", (LayerKind(
                 self.attention, self.n_heads,
+                None if self.attention == "gated_delta" else
                 Rotary(self.rope_theta, tuple(self.mrope_section))),)
                 * self.n_layers)
         if len(self.layers) != self.n_layers:
@@ -402,6 +478,22 @@ class SparseMoEConfig:
             if kind.mlp not in ("experts", "dense"):
                 raise ValueError(f"mlp {kind.mlp!r} is neither experts nor "
                                  f"dense")
+            if kind.attention == "gated_delta":
+                if kind.rotary is not None:
+                    raise ValueError("a gated_delta layer takes no rotary "
+                                     "step: its rotary is None")
+                if (self.linear_key_heads < 1
+                        or kind.n_heads % self.linear_key_heads
+                        or self.linear_conv_width < 1):
+                    raise ValueError(
+                        f"a gated_delta layer needs linear_key_heads that "
+                        f"divide its {kind.n_heads} value heads and a "
+                        f"convolution of one tap or more; got "
+                        f"{self.linear_key_heads}, {self.linear_conv_width}")
+                continue
+            if kind.rotary is None:
+                raise ValueError(f"a {kind.attention} layer needs a rotary "
+                                 f"table")
             pairs = sum(kind.rotary.sections)
             if kind.attention == "latent":
                 # a head a key/value head; the widths are the five fields'
@@ -431,6 +523,9 @@ class SparseMoEConfig:
         if self.diffusion and not 0 <= self.mask_token_id < self.vocab_size:
             raise ValueError(f"mask_token_id {self.mask_token_id} is no row "
                              f"of a vocabulary of {self.vocab_size}")
+        if self.attn_gate_width not in ("head", "element"):
+            raise ValueError(f"attn_gate_width {self.attn_gate_width!r} is "
+                             f"neither head nor element")
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {self.scoring!r} is neither softmax "
                              f"nor sigmoid")
@@ -759,8 +854,11 @@ class RuleAttention(_GroupedQueryProjections):
     """Every key a static causal rule keeps (``full``: all of them,
     ``window``: the last ``window``), by the kernels of
     ``ops/rule_attention.py`` under the kind's name, and where the
-    configuration says so a gate on the output: one sigmoid a head a
-    token, from the layer's normed input, before ``Wo``."""
+    configuration says so a gate on the output, from the layer's normed
+    input, before ``Wo``: one sigmoid a head a token from a projection
+    of its own (``attn_gate_width`` ``"head"``), or one a dim of a head
+    from the second half of the query projection (``"element"``:
+    ``wq_gate``, of ``wq``'s shape)."""
 
     @nn.compact
     def __call__(self, h, table, temporal):
@@ -776,7 +874,12 @@ class RuleAttention(_GroupedQueryProjections):
                  * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope(f"{name}_attention"):
             o = rule_attention_heads_first(q5, k4, v4, rule, name)
-        if cfg.attn_gate:
+        if cfg.attn_gate and cfg.attn_gate_width == "element":
+            with jax.named_scope("attn_gate"):
+                # flat as o lies: a gate a lane
+                o = (o * jax.nn.sigmoid(self._heads(
+                    h, "wq_gate", self.kind.n_heads))).astype(dt)
+        elif cfg.attn_gate:
             with jax.named_scope("attn_gate"):
                 gate = jax.nn.sigmoid(self._proj(
                     h, self._dense("wg", (d, self.kind.n_heads))))
@@ -786,13 +889,9 @@ class RuleAttention(_GroupedQueryProjections):
         return self._out(o, d)
 
 
-class LatentAttention(nn.Module):
-    """Multi-head latent attention in its uncompressed (training) form
-    ("Latent attention" in the module docstring): queries and keys from
-    two normed latents, a query and a key ``[nope ; rope]`` with the
-    rotary part of the key one for all heads, values narrower than keys,
-    one key/value head a query head, every causal key
-    (``ops/latent_attention.py``). No per-head norm, no gate, no bias."""
+class _FlatProducts(nn.Module):
+    """What the mixers beside ``_GroupedQueryProjections`` share: their
+    weights' draw and a product with a weight already laid out."""
 
     config: SparseMoEConfig
     kind: LayerKind
@@ -805,6 +904,15 @@ class LatentAttention(nn.Module):
         dt = self.config.compute_dtype
         return jnp.einsum("btd,df->btf", x.astype(dt), w.astype(dt),
                           preferred_element_type=jnp.float32)
+
+
+class LatentAttention(_FlatProducts):
+    """Multi-head latent attention in its uncompressed (training) form
+    ("Latent attention" in the module docstring): queries and keys from
+    two normed latents, a query and a key ``[nope ; rope]`` with the
+    rotary part of the key one for all heads, values narrower than keys,
+    one key/value head a query head, every causal key
+    (``ops/latent_attention.py``). No per-head norm, no gate, no bias."""
 
     def _slot(self, w):
         """The rotary columns of a weight (its last axis) as the fused op
@@ -864,6 +972,93 @@ class LatentAttention(nn.Module):
                 preferred_element_type=jnp.float32)
 
 
+# A convolution's taps at init: the spread of U(-1/2, 1/2), what an
+# unset depthwise convolution of 4 taps gets where the model was written.
+_CONV_STD = 0.289
+# The decay rates exp(A_log) of a layer's value heads at init: a
+# geometric ladder, so that with softplus(dt_bias) = 1 a token's
+# log-decay lies between about -0.5 and -1e-3 and the heads' half-lives
+# run from a few tokens to some hundreds, as a trained model's do. (The
+# released initialisation draws the rate from U(0, 16): most heads then
+# forget within a token and the state carries nothing.)
+_DECAY_RATES = (2e-3, 0.25)
+
+
+def _decay_ladder(key, shape, dtype=jnp.float32):
+    del key
+    lo, hi = np.log(_DECAY_RATES)
+    return jnp.asarray(lo + (hi - lo) * np.arange(shape[0])
+                       / max(shape[0] - 1, 1), dtype)
+
+
+class GatedDeltaNet(_FlatProducts):
+    """A Gated DeltaNet linear-attention layer ("Gated delta rule" in the
+    module docstring): one product for ``q``, ``k``, ``v`` and the output
+    gate ``z`` and one for the two scalars a value head, a causal
+    depthwise convolution with SiLU over ``[q ; k ; v]``, ``beta`` and
+    the log-decay, L2-normed ``q`` and ``k``, the chunked gated delta
+    rule (``ops/gated_delta_rule.py``), a gated RMSNorm a head and the
+    output projection. No rotary step, no keys and values: what it
+    carries along a row is a state ``[128, 128]`` a value head, inside
+    the rule's kernels."""
+
+    @nn.compact
+    def __call__(self, h, table, temporal):
+        cfg, dt = self.config, self.config.compute_dtype
+        b, t, d = h.shape
+        del table, temporal
+        n_k, n_v = cfg.linear_key_heads, self.kind.n_heads
+        d_k = d_v = delta.HEAD_DIM
+        keys, values = n_k * d_k, n_v * d_v
+        taps = cfg.linear_conv_width
+        with jax.named_scope("attn_qkv"), jax.named_scope("gdn_in_proj"):
+            # columns [q ; k ; v ; z] and [b ; a], heads in order
+            qkvz = self._product(h, self._dense(
+                "w_qkvz", (d, 2 * keys + 2 * values)))
+            ba = self._product(h, self._dense("w_ba", (d, 2 * n_v)))
+        with jax.named_scope("gdn_conv"):
+            # u~[t] = silu(sum_i w[i] u[t - (taps - 1) + i]), u[t < 0] = 0:
+            # shifted multiply-adds over the channels as they lie
+            u, z = qkvz[..., :2 * keys + values], qkvz[..., 2 * keys + values:]
+            w = self.param("conv", _normal(_CONV_STD),
+                           (taps, 2 * keys + values))
+            padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+            u = jax.nn.silu(sum(w[i] * padded[:, i:i + t]
+                                for i in range(taps)))
+            q, k, v = (u[..., :keys], u[..., keys:2 * keys],
+                       u[..., 2 * keys:].astype(dt))
+        with jax.named_scope("gdn_gates"):
+            beta = jax.nn.sigmoid(ba[..., :n_v])
+            log_decay = -jnp.exp(self.param(
+                "A_log", _decay_ladder, (n_v,))) * jax.nn.softplus(
+                    ba[..., n_v:] + self.param(
+                        "dt_bias", nn.initializers.constant(
+                            np.log(np.e - 1.0)), (n_v,)))
+
+            def unit(x, scale):
+                x = by_head(x, d_k)  # a head's lanes as an axis, in place
+                return (x * (scale * jax.lax.rsqrt(jnp.sum(
+                    jnp.square(x), -1, keepdims=True) + 1e-6))).astype(
+                        dt).reshape(b, t, keys)
+
+            q, k = unit(q, d_k ** -0.5), unit(k, 1.0)
+        self.sow("moe_metrics", "gdn_chunks", jnp.float32(
+            delta.chunks_run(b, t, n_v)))
+        with jax.named_scope("gated_delta"):
+            o = delta.gated_delta_rule(q, k, v, log_decay, beta)
+        with jax.named_scope("gdn_out_norm"):
+            gain = self.param("out_norm", nn.initializers.ones, (d_v,))
+            y = (rms_norm(by_head(o, d_v), gain, cfg.rms_eps)
+                 * jax.nn.silu(by_head(z, d_v))).astype(dt).reshape(o.shape)
+        # ``attn_out`` as every mixer's; ``gdn_out_proj`` tells a linear
+        # layer's from a full layer's in a trace
+        with jax.named_scope("gdn_out_proj"), jax.named_scope("attn_out"):
+            return jnp.einsum(
+                "btf,fd->btd", y,
+                self._dense("wo", (n_v, d_v, d)).reshape(-1, d).astype(dt),
+                preferred_element_type=jnp.float32)
+
+
 # attention by kind of layer: the module, and what its forward pass
 # names for the layer's remat policy to keep
 _ATTENTION = {
@@ -872,17 +1067,22 @@ _ATTENTION = {
     **{kind: (RuleAttention, saved_names(name))
        for kind, name in _RULE_NAMES.items()},
     "latent": (LatentAttention, latent.SAVED_NAMES),
+    # the rule's output and the states entering each block of chunks
+    "gated_delta": (GatedDeltaNet, delta.SAVED_NAMES),
 }
 # the kinds whose forward kernel's tiles a layer counts under its name
 _TILES_BY_KIND = (*_RULE_NAMES, "latent")
 
 
-def _table_width(cfg: SparseMoEConfig, kind: LayerKind) -> int:
-    """The lanes of a layer's rotary table: a whole head, or a latent
-    layer's rotary slot."""
+def _table_key(cfg: SparseMoEConfig, kind: LayerKind):
+    """What names a layer's rotary table among the model's: the table
+    and its lanes (a whole head, or a latent layer's rotary slot); None
+    for a layer that takes no rotary step, for which none is built."""
+    if kind.rotary is None:
+        return None
     if kind.attention == "latent":
-        return latent.padded_width(cfg.qk_rope_dim)
-    return cfg.head_dim
+        return kind.rotary, latent.padded_width(cfg.qk_rope_dim)
+    return kind.rotary, cfg.head_dim
 
 
 class HeldExperts(nn.Module):
@@ -1137,11 +1337,14 @@ def pairs_covered(sorted_expert, group_sizes):
 
 class SwiGLU(nn.Module):
     """``Wd (silu(Wg g) * Wu g)`` for every token, under a scope of its
-    own: a dense layer's MLP, or the expert every token goes through."""
+    own: a dense layer's MLP, or the expert every token goes through;
+    ``gated``: times ``sigmoid(g w)``, one gate a token (``gate [d,
+    1]``)."""
 
     config: SparseMoEConfig
     width: int
     traced_as: str   # the scope's name
+    gated: bool = False
 
     @nn.compact
     def __call__(self, g):
@@ -1152,9 +1355,14 @@ class SwiGLU(nn.Module):
             a, b = (jnp.einsum("btd,df->btf", x, w(name, (d, self.width)),
                                preferred_element_type=jnp.float32)
                     for name in ("w_gate", "w_up"))
-            return jnp.einsum("btf,fd->btd", (jax.nn.silu(a) * b).astype(dt),
-                              w("w_down", (self.width, d)),
-                              preferred_element_type=jnp.float32)
+            out = jnp.einsum("btf,fd->btd", (jax.nn.silu(a) * b).astype(dt),
+                             w("w_down", (self.width, d)),
+                             preferred_element_type=jnp.float32)
+            if self.gated:
+                out = out * jax.nn.sigmoid(jnp.einsum(
+                    "btd,do->bto", x, w("gate", (d, 1)),
+                    preferred_element_type=jnp.float32))
+            return out
 
 
 class DecoderLayer(nn.Module):
@@ -1179,9 +1387,9 @@ class DecoderLayer(nn.Module):
         g = norm(x, self.param("moe_norm", ones, (d,)), cfg.rms_eps)
         x = x + HeldExperts(cfg, name="moe")(g, live)
         if cfg.shared_expert_width:
-            # on every chip alike: no share of it, no gate
+            # on every chip alike: no share of it
             x = x + SwiGLU(cfg, cfg.shared_expert_width, "shared_expert",
-                           name="shared")(g)
+                           cfg.shared_expert_gate, name="shared")(g)
         return x
 
 
@@ -1274,6 +1482,11 @@ class SparseMoELM(nn.Module):
             gauges["train.attention.window"] = cfg.window
         gauges.update({f"train.attention.layers_{kind}": cfg.layers_of(kind)
                        for kind in _TILES_BY_KIND if cfg.layers_of(kind)})
+        if cfg.layers_of("gated_delta"):
+            gauges["train.attention.layers_gated_delta"] = cfg.layers_of(
+                "gated_delta")
+        if cfg.shared_expert_gate:
+            gauges["train.moe.shared_gate"] = 1
         if cfg.layers_of("latent"):
             gauges["train.attention.latent_q_rank"] = cfg.q_lora_rank
             gauges["train.attention.latent_kv_rank"] = cfg.kv_lora_rank
@@ -1334,6 +1547,11 @@ class SparseMoELM(nn.Module):
                           mtp_tokens=tokens)
             counters["train.mtp.tokens"] = tokens
             gauges["train.mtp.loss"] = fields["mtp_loss"]
+        if "gdn_chunks" in sown:
+            # the chunks the rule's forward kernel ran, over the linear
+            # layers: rows x value heads x tokens / chunk each
+            fields["gdn_chunks"] = float(sown["gdn_chunks"].sum())
+            counters["train.attention.gdn_chunks"] = fields["gdn_chunks"]
         for kind in _TILES_BY_KIND:
             if f"attn_tiles_{kind}" in sown:
                 tiles = sown[f"attn_tiles_{kind}"].sum(0)
@@ -1383,10 +1601,9 @@ class SparseMoELM(nn.Module):
         # one (cos, sin) a rotary table among the layers, and one
         # rematerialised layer class a set of names an attention keeps
         with jax.named_scope("attn_qk_rope"):
-            tables = {key: rotary_table(position_ids, *key)
+            tables = {key: key and rotary_table(position_ids, *key)
                       for key in dict.fromkeys(
-                          (k.rotary, _table_width(cfg, k))
-                          for k in cfg.layers)}
+                          _table_key(cfg, k) for k in cfg.layers)}
         temporal = position_ids[0]
         with jax.named_scope("embed"):  # its gradient: the scatter-add
             emb = self.param("embed", _normal(),
@@ -1397,7 +1614,7 @@ class SparseMoELM(nn.Module):
         for i, kind in enumerate(cfg.layers):
             layer = remat[_ATTENTION[kind.attention][1]]
             x = layer(cfg, kind, name=f"layer_{i}")(
-                x, tables[kind.rotary, _table_width(cfg, kind)], temporal)
+                x, tables[_table_key(cfg, kind)], temporal)
         if diffusion:
             x = x[:, t:]  # the clean half's last output enters nothing
         with jax.named_scope("block_norm"):
@@ -1428,8 +1645,7 @@ class SparseMoELM(nn.Module):
         with jax.named_scope("mtp"):
             last = cfg.layers[-1]
             hidden = MultiTokenPredictor(cfg, name="mtp")(
-                x, emb, tables[last.rotary, _table_width(cfg, last)],
-                temporal)
+                x, emb, tables[_table_key(cfg, last)], temporal)
             with jax.named_scope("lm_head"):
                 mtp_logits = to_logits(hidden)
             if self.is_mutable_collection("moe_metrics"):
@@ -1464,6 +1680,8 @@ def _layer_kind(kind) -> LayerKind:
     object (``rotary`` an object of :class:`Rotary`'s fields)."""
     if isinstance(kind, LayerKind):
         return kind
+    if kind.get("rotary") is None:
+        return LayerKind(**{**kind, "rotary": None})
     rotary = dict(kind["rotary"])
     for key in ("sections", "yarn"):
         if rotary.get(key) is not None:
@@ -1553,3 +1771,32 @@ def joyai_flash_lm(**overrides) -> SparseMoELM:
         "scoring": "sigmoid", "routed_scale": 2.5, "selection_bias": True,
         "shared_expert_width": 768, "dense_width": 7_168, "mtp_depth": 1,
         "mtp_weight": 0.3, **overrides}))
+
+
+def qwen3_next_lm(**overrides) -> SparseMoELM:
+    """Qwen3-Next-80B-A3B at its published sizes: 48 layers of hidden
+    2,048; three of every four Gated DeltaNet linear attention (16 key
+    and 32 value heads of 128, a causal convolution of 4 taps, the gated
+    delta rule in chunks of 64), every fourth (layers 3, 7, ...) full
+    causal attention with 16 query over 2 key/value heads of 256, rotary
+    on 64 of the 256 dims (theta 1e7) and an element-wise output gate
+    from the query projection's second half; every layer 512 experts of
+    512, 10 a token by softmax scores renormalised, beside one shared
+    expert of 512 under a sigmoid gate a token; vocabulary 151,936.
+    ``overrides`` as for :func:`keye_vl2_lm`; ``layers``, where given,
+    replaces the published pattern, which is otherwise cut to
+    ``n_layers``."""
+    overrides = _coerced(overrides)
+    n_layers = overrides.get("n_layers", 48)
+    full = Rotary(1e7, (32,))
+    return SparseMoELM(SparseMoEConfig(**{
+        "n_layers": n_layers, "n_kv_heads": 2, "head_dim": 256,
+        "layers": tuple(
+            LayerKind("full", 16, full) if (i + 1) % 4 == 0 else
+            LayerKind("gated_delta", 32, None) for i in range(n_layers)),
+        "attn_gate": True, "attn_gate_width": "element",
+        "linear_key_heads": 16, "linear_conv_width": 4,
+        "n_routed_experts": 512, "experts_held": tuple(range(512)),
+        "experts_per_token": 10, "expert_width": 512,
+        "shared_expert_width": 512, "shared_expert_gate": True,
+        **overrides}))

@@ -355,6 +355,109 @@ def test_latent_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 7_000_000_000
 
 
+def test_gated_delta_rule_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
+    """``gdn_fwd`` and ``gdn_bwd`` at the linear-attention cell's shapes
+    (1 row of 16,384 tokens, 16 key and 32 value heads of 128, chunks of
+    64 in blocks of 8): the 64 x 64 float32 products of the triangular
+    inverse, the identity products that turn a token's scalars, the
+    state in scratch across the sequential axis and the backward
+    kernel's 1.2 MB of chunk caches lower in Mosaic."""
+    from sparktorch_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    t = 16_384
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        S((1, t, 2048), jnp.bfloat16), S((1, t, 2048), jnp.bfloat16),
+        S((1, t, 4096), jnp.bfloat16), S((1, t, 32), jnp.float32),
+        S((1, t, 32), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert _pallas_calls(text, "gdn_fwd") == 1
+    assert _pallas_calls(text, "gdn_bwd") == 1
+    # the states kept: one a value head a block of 512 tokens
+    assert re.search(r"f32\[1,32,32,128,128\]", text)
+
+
+def test_causal_kernels_and_qk_norm_rope_at_256_compile_for_v5e(one_chip,
+                                                                as_tpu):
+    """The full-attention layer of the linear-attention cell: heads of
+    256, 8 query heads a key/value head, 2 key/value heads, 64 of the 256
+    dims rotated, 1 row of 16,384 tokens. The shared tile bodies of
+    ``ops/sparse_attention.py`` and the fused q/k pass had been tiled,
+    compiled and timed at 128 alone: at 256 a Q tile's blocks are twice as
+    wide and still fit the kernels' fast memory at 256 x 512."""
+    from sparktorch_tpu.ops import qk_norm_rope as fused
+    from sparktorch_tpu.ops.rule_attention import (
+        Causal, rule_attention_heads_first)
+
+    b, t, d, heads, kv = 1, 16_384, 256, 16, 2
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+
+    def loss(xq, xk, xv, cos, sin, gq, gk):
+        q5, k4, v4 = fused.qk_norm_rope(xq, xk, xv, gq, gk, cos, sin, 1e-6,
+                                        32, jnp.bfloat16)
+        return rule_attention_heads_first(
+            q5, k4, v4, Causal(), "causal").astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 5, 6))).lower(
+        S(b, t, heads * d), S(b, t, kv * d), S(b, t, kv * d), S(b, t, d),
+        S(b, t, d), S(d), S(d)).compile().as_text()
+    for kernel in ("causal_attn_fwd", "causal_attn_bwd_dq",
+                   "causal_attn_bwd_dkv", "qk_norm_rope_fwd",
+                   "qk_norm_rope_bwd"):
+        assert _pallas_calls(text, kernel) == 1
+    assert _forward_statistics(text, "causal_attn_fwd") == [(1, 2, 8, t)]
+
+
+@pytest.mark.slow  # 100 s; the two tests above keep its kernels in tier 1
+def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
+    """The whole model of the linear-attention cell at its configuration
+    file's sizes and the cell's row (1 row of 16,384 tokens; three Gated
+    DeltaNet layers and a full layer at 256, 16 of 512 experts held, 10 a
+    token, 18,992 rows of vocabulary): the rule's kernels run once a
+    linear layer (the remat keeps its output and block states), the
+    causal kernels once, and the gradient's scratch leaves room beside
+    6.79 GB of state."""
+    import json
+
+    from sparktorch_tpu.models.sparse_moe_lm import qwen3_next_lm
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "qwen3-next-80b-a3b-ep32.json")) as f:
+        module = qwen3_next_lm(**json.load(f)["constructor_kwargs"])
+    ids = jnp.zeros((1, 16_384), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 424_340_544
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy")
+
+    def loss(p, x, y):
+        out, sown = module.apply({"params": p}, x, mutable=["moe_metrics"])
+        return loss_fn(out, y).sum(), sown
+
+    compiled = jax.jit(jax.grad(loss, has_aux=True)).lower(
+        jax.tree.map(S, shapes), S(ids), S(ids)).compile()
+    text = compiled.as_text()
+    assert _pallas_calls(text, "gdn_fwd") == 3
+    assert _pallas_calls(text, "gdn_bwd") == 3
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert _pallas_calls(text, f"causal_attn_{kernel}") == 1
+    assert _forward_statistics(text, "causal_attn_fwd") == [(1, 2, 8, 16_384)]
+    assert _pallas_calls(text, "qk_norm_rope_fwd") == 2
+    assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
+    assert _pallas_calls(text, "fused_ce_fwd") == 1
+    # 4.86 GB read at PR 42
+    assert compiled.memory_analysis().temp_size_in_bytes < 6_000_000_000
+
+
 @pytest.mark.parametrize("rows,seq,calls", [(32, 512, 1), (128, 128, 0)])
 def test_encoder_layer_gradient_picks_its_attention_for_v5e(
         one_chip, as_tpu, monkeypatch, rows, seq, calls):
