@@ -250,8 +250,14 @@ heads of 128, value head ``j`` reading key head ``j // 2``:
   ``S_t = S' + k^_t u_t^T``; ``o_t = S_t^T q^_t`` (so ``S_t = alpha_t (I
   - beta_t k^ k^^T) S_{t-1} + beta_t k^ v^T``), computed in chunks of
   64 tokens by ``ops/gated_delta_rule.py`` (its ``CHUNK``; its
-  docstring has the chunk's form, the layout and what is kept; scope
-  ``gated_delta``). A row that is not whole chunks is an error.
+  docstring has the chunk's form, the layout and the kernels' phases;
+  scope ``gated_delta``). A row that is not whole chunks is an error.
+  Kept for the backward pass under the layer's remat (the op's
+  ``SAVED_NAMES``): ``o``, the state entering each block of 8 chunks
+  (67 MB a layer at the cell's row) and, since PR 43, each chunk's
+  triangular inverse ``T`` in float32 (134 MB a layer): inverting is 60
+  of a chunk's 74 matrix-unit passes, and ``gdn_bwd`` did it again for
+  11.0 of its 32.3 ms a layer (TPU v5e, the kernels alone, PR 43).
 - ``y_{t,j} = RMSNorm(o_{t,j}; gain [128]) * silu(z_{t,j})``, the norm
   over a head's 128 dims (scope ``gdn_out_norm``); ``x = x + concat_j(y_j)
   W_o`` (``[n_v 128, d]``, scope ``attn_out`` inside ``gdn_out_proj``).
@@ -1067,7 +1073,8 @@ _ATTENTION = {
     **{kind: (RuleAttention, saved_names(name))
        for kind, name in _RULE_NAMES.items()},
     "latent": (LatentAttention, latent.SAVED_NAMES),
-    # the rule's output and the states entering each block of chunks
+    # the rule's output, the states entering each block of chunks and
+    # each chunk's inverse
     "gated_delta": (GatedDeltaNet, delta.SAVED_NAMES),
 }
 # the kinds whose forward kernel's tiles a layer counts under its name

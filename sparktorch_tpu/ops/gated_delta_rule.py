@@ -40,25 +40,50 @@ through its block's index map, so nothing is repeated or transposed.
 The two scalars a token come as ``gb [b, value heads, T / C, 8, C]``
 float32, tokens along the lanes: row 0 the cumulative log-decay ``G``,
 row 1 ``beta`` (six rows of padding make the block a register high);
-a kernel turns them to columns by a product with the identity, which at
-full precision is exact. A grid step is ``(row, value head, block of
-``_BLOCK_CHUNKS`` chunks)``: the last axis is sequential and the state
-``[128, 128]`` float32 lives in VMEM scratch across it; nothing of ``[T,
-T]`` and no per-token state reaches HBM.
+a kernel turns a block's to columns by ONE product with the identity,
+which at full precision is exact. A grid step is ``(row, value head,
+block of ``_BLOCK_CHUNKS`` chunks)``: the last axis is sequential and
+the state ``[128, 128]`` float32 lives in VMEM scratch across it;
+nothing of ``[T, T]`` and no per-token state reaches HBM.
 
-Kept for the backward pass: the operands, and the state ENTERING each
-block of 8 chunks (``[b, value heads, T / 512, 128, 128]`` float32, 67
-MB a layer at 16,384 tokens and 32 heads; a state a CHUNK would be 537
-MB). The forward rule names its output and those states
-(:data:`SAVED_NAMES`) for a caller's remat policy, so ``gdn_fwd`` runs
-once a layer a step. The backward kernel visits the blocks last to
-first with the state's cotangent in VMEM scratch; in a block it first
-runs the chunks forward from the kept state, leaving each chunk's ``T``,
-``W``, ``V'`` and entering state in VMEM (1.2 MB), then walks them
-backward. So the backward pass costs one more forward pass of the
-state, never a second of ``O``. The cotangents of ``q`` and ``k`` leave
-a value head each (``[b, T, value heads * 128]``); the wrapper sums the
-value heads of a key head.
+A grid step has state-free phases and serial loops. Of a chunk's
+products only ``W S``, ``Q exp(G) S``, ``P V'`` and the next state read
+the state; ``K K^T * Gam``, the ten products of the inverse, ``W``, ``U``
+and ``P = Q K^T * Gam`` depend on nothing a previous chunk made. Inside
+the loop that carries the state they were one chunk's dependent chain
+(the inverse is nine products deep) for four matrix units to wait on;
+so a kernel first makes them for ALL of the block's chunks, batched over
+the axis of chunks (:class:`_Chunks`), leaves what the loop reads in
+VMEM (0.8 MiB), and the loop is three products a chunk forward. The
+backward kernel has the same shape twice over: the state's cotangent
+chain is two products a chunk (``dV' = P^T dO + K exp(G_C - G) dS'``,
+then ``dS = (Q exp G)^T dO + exp(G_C) dS' - W^T dV'``), and the other
+sixteen and ``da = T^T dT T^T`` are made for all chunks after it.
+
+Kept for the backward pass: the operands, the state ENTERING each block
+of 8 chunks (``[b, value heads, T / 512, 128, 128]`` float32, 67 MB a
+layer at 16,384 tokens and 32 heads; a state a CHUNK would be 537 MB)
+and each chunk's ``T`` (``[b, value heads, T / C, 32, 128]`` float32: a
+chunk's upper 32 rows beside its lower 32, so HBM's (8, 128) tiles hold
+no padding; 134 MB a layer, 0.33 ms to write and read back). The
+inverse is 60 of a chunk's 74 matrix-unit passes forward, and the
+backward kernel, which needs ``T`` for ``W``, ``U`` and ``da``, inverted
+again until PR 43: 11.0 ms of its 32.3 a layer at the cell's shape (TPU
+v5e, the kernels alone). The forward rule names its output, those states
+and ``T`` (:data:`SAVED_NAMES`) for a caller's remat policy, so
+``gdn_fwd`` runs once a layer a step; a policy that keeps none of them
+runs it twice and makes ``T`` twice. The backward kernel visits the
+blocks last to first with the state's cotangent in VMEM scratch; in a
+block it makes ``W`` and ``U`` of every chunk from the ``T`` kept (no
+``K K^T`` and no inverse), runs the chunks forward from the kept state
+for each chunk's ``V'`` and entering state (2.6 MiB of VMEM with the
+cotangents), walks the cotangent's chain backward, and finishes all
+chunks at once. So the backward pass costs one more forward pass of the
+state, never a second of ``O`` or of ``T``. With both, at 1 x 16,384 x
+16 / 32 heads: ``gdn_fwd`` 17.6 -> 9.6 ms and ``gdn_bwd`` 32.3 -> 12.7
+(the kernels alone, PR 43), to the bit the same results. The cotangents
+of ``q`` and ``k`` leave a value head each (``[b, T, value heads *
+128]``); the wrapper sums the value heads of a key head.
 
 Matrix products take their operands in the inputs' dtype (bfloat16 in
 the model) and accumulate in float32; the state, ``T``, the decays and
@@ -94,15 +119,21 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 # what the forward rule names for a caller's remat policy: the rule's
-# output and the states entering each block of chunks
-SAVED_NAMES = ("gdn_out", "gdn_states")
+# output, the states entering each block of chunks and each chunk's T
+SAVED_NAMES = ("gdn_out", "gdn_states", "gdn_t")
 
 
 def _mm(a, b, dims, dt=jnp.float32):
     """A product with float32 accumulation, its operands in ``dt``; at
-    full precision where ``dt`` is float32."""
+    full precision where ``dt`` is float32. ``dims`` name the contracted
+    axes of one chunk's matrices; an axis of chunks before them is a
+    batch."""
+    lead = a.ndim - 2
+    (of_a,), (of_b,) = dims
+    batch = tuple(range(lead))
     return jax.lax.dot_general(
-        a.astype(dt), b.astype(dt), (dims, ((), ())),
+        a.astype(dt), b.astype(dt),
+        (((of_a + lead,), (of_b + lead,)), (batch, batch)),
         precision=_HIGHEST if dt == jnp.float32 else None,
         preferred_element_type=jnp.float32)
 
@@ -123,7 +154,7 @@ class _Tile:
         return (self.r >> shift) == (self.s >> shift)
 
     def columns(self, rows):
-        """``[8, C]`` rows as ``[C, 8]`` columns: a product with the
+        """``[n, C]`` rows as ``[C, n]`` columns: a product with the
         identity, exact at full precision."""
         return _mm(self.eye, rows, _NT)
 
@@ -146,143 +177,190 @@ def _inverse(a, tile: _Tile):
     return x
 
 
-class _Chunk:
-    """One chunk's operands and what both passes make of them first:
-    the decays' factors, ``K K^T * Gam`` and ``T``."""
+def _fold(chunk: int) -> int:
+    """The pieces of 8 rows or more a chunk's ``T`` is kept in, side by
+    side: ``[C / fold, C * fold]`` is 128 lanes wide at 32 and 64, which
+    HBM's (8, 128) tiles hold without padding."""
+    return min(_LANES // chunk, chunk // _ROWS)
 
-    def __init__(self, q, k, v, gb, tile: _Tile):
-        self.q, self.k, self.v, self.dt = q, k, v, q.dtype
-        cols = tile.columns(gb)
-        g_col, self.beta = cols[:, 0:1], cols[:, 1:2]
-        g_row = gb[0:1, :]
-        lane = jax.lax.broadcasted_iota(jnp.int32, g_row.shape, 1)
-        g_last = jnp.sum(jnp.where(lane == tile.chunk - 1, g_row, 0.0),
-                         axis=1, keepdims=True)
-        self.gam = jnp.exp(jnp.where(tile.incl, g_col - g_row, -1e30))
+
+def _folded(t):
+    fold = _fold(t.shape[-1])
+    rows = t.shape[-1] // fold
+    return jnp.concatenate(
+        [t[..., i * rows:(i + 1) * rows, :] for i in range(fold)], axis=-1)
+
+
+def _unfolded(kept, chunk: int):
+    return jnp.concatenate(
+        [kept[..., i * chunk:(i + 1) * chunk] for i in range(_fold(chunk))],
+        axis=-2)
+
+
+class _Chunks:
+    """A block's chunks at once, an axis of chunks before every array's
+    own: the operands, the decays' factors and the products no state
+    enters. The chunks are independent work, which is what fills the
+    matrix units; one product turns the scalars of them all."""
+
+    def __init__(self, q_ref, k_ref, v_ref, gb_ref, tile: _Tile):
+        n, chunk = gb_ref.shape[0], tile.chunk
+        by_chunk = lambda ref: ref[...].reshape(n, chunk, _LANES)
+        self.q, self.k, self.v = by_chunk(q_ref), by_chunk(k_ref), by_chunk(
+            v_ref)
+        self.dt, self.tile = self.q.dtype, tile
+        cols = tile.columns(gb_ref[...].reshape(n * _ROWS, chunk))
+        cols = jnp.stack([cols[:, c * _ROWS:(c + 1) * _ROWS]
+                          for c in range(n)])
+        self.g_col, self.beta = cols[..., 0:1], cols[..., 1:2]
+        self.g_row = gb_ref[:, 0:1, :]
+        lane = jax.lax.broadcasted_iota(jnp.int32, self.g_row.shape, 2)
+        g_last = jnp.sum(jnp.where(lane == chunk - 1, self.g_row, 0.0),
+                         axis=-1, keepdims=True)
+        self.e_g = jnp.exp(self.g_col)               # exp(G), a column
+        self.e_last = jnp.exp(g_last)                # exp(G_C), [n, 1, 1]
+        self.e_rest = jnp.exp(g_last - self.g_col)   # exp(G_C - G)
+        self.k_beta = self.beta * self.e_g * self.k  # diag(beta exp G) K
+        self.v_beta = self.beta * self.v
+        self.k_rest = self.e_rest * self.k           # K * exp(G_C - G)
+        self.gam = jnp.exp(jnp.where(tile.incl, self.g_col - self.g_row,
+                                     -1e30))
         self.gam_strict = jnp.where(tile.strict, self.gam, 0.0)
-        self.e_g = jnp.exp(g_col)               # exp(G), a column
-        self.e_last = jnp.exp(g_last)           # exp(G_C), [1, 1]
-        self.e_rest = jnp.exp(g_last - g_col)   # exp(G_C - G)
-        self.m = _mm(k, k, _NT, self.dt) * self.gam_strict
-        self.k_beta = self.beta * self.e_g * k  # diag(beta exp G) K
-        self.v_beta = self.beta * v
-        self.k_rest = self.e_rest * k           # K * exp(G_C - G)
 
-    def inverse(self, tile):
-        return _inverse(-self.beta * self.m, tile)
+    @functools.cached_property
+    def m(self):
+        """``K K^T * Gam`` strictly below the diagonal."""
+        return _mm(self.k, self.k, _NT, self.dt) * self.gam_strict
 
-    def new_values(self, t, state):
-        """``(W, V')`` from ``T`` and the entering state."""
-        w = _mm(t, self.k_beta, _NN, self.dt)
-        return w, _mm(t, self.v_beta, _NN, self.dt) - _mm(
-            w, state, _NN, self.dt)
+    def inverse(self):
+        return _inverse(-self.beta * self.m, self.tile)
 
     def attention(self):
-        """``Q K^T * Gam``, the diagonal kept."""
+        """``P = Q K^T * Gam``, the diagonal kept."""
         return _mm(self.q, self.k, _NT, self.dt) * self.gam
 
-    def next_state(self, v_new, state):
-        return self.e_last * state + _mm(self.k_rest, v_new, _TN, self.dt)
+    def for_the_loops(self, t, w_ref, u_ref, kr_ref, last_ref):
+        """Leaves in VMEM what both kernels' loops over the state read of
+        each chunk: ``W`` and ``U`` from ``T``, ``K exp(G_C - G)`` and
+        ``exp(G_C)``; what enters a product, in the operands' dtype."""
+        w_ref[...] = _mm(t, self.k_beta, _NN, self.dt).astype(self.dt)
+        u_ref[...] = _mm(t, self.v_beta, _NN, self.dt)
+        kr_ref[...] = self.k_rest.astype(self.dt)
+        last_ref[...] = jnp.broadcast_to(self.e_last, last_ref.shape)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, gb_ref, o_ref, kept_ref, state_ref, *,
-                chunk, n_chunks):
+def _fwd_kernel(q_ref, k_ref, v_ref, gb_ref, o_ref, kept_ref, t_ref, wq_ref,
+                u_ref, kr_ref, last_ref, p_ref, state_ref, *, chunk, n_chunks):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     kept_ref[...] = state_ref[...]
-    tile = _Tile(chunk)
+    ck = _Chunks(q_ref, k_ref, v_ref, gb_ref, _Tile(chunk))
+    t, dt = ck.inverse(), ck.dt
+    t_ref[...] = _folded(t)
+    # [W ; Q exp G], one operand of 2 C rows: W S and Q exp(G) S are one
+    # product
+    ck.for_the_loops(t, wq_ref.at[:, :chunk, :], u_ref, kr_ref, last_ref)
+    wq_ref[:, chunk:, :] = (ck.e_g * ck.q).astype(dt)
+    p_ref[...] = ck.attention().astype(dt)
 
     def one_chunk(c, carry):
-        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-        ck = _Chunk(q_ref[rows, :], k_ref[rows, :], v_ref[rows, :],
-                    gb_ref[c], tile)
         state = state_ref[...]
-        _, v_new = ck.new_values(ck.inverse(tile), state)
-        o = _mm(ck.e_g * ck.q, state, _NN, ck.dt) + _mm(
-            ck.attention(), v_new, _NN, ck.dt)
-        o_ref[rows, :] = o.astype(o_ref.dtype)
-        state_ref[...] = ck.next_state(v_new, state)
+        ws_qs = _mm(wq_ref[c], state, _NN, dt)          # [W S ; Q exp(G) S]
+        v_new = u_ref[c] - ws_qs[:chunk]
+        o = ws_qs[chunk:] + _mm(p_ref[c], v_new, _NN, dt)
+        o_ref[pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :] = o.astype(
+            o_ref.dtype)
+        state_ref[...] = last_ref[c] * state + _mm(kr_ref[c], v_new, _TN, dt)
         return carry
 
     jax.lax.fori_loop(0, n_chunks, one_chunk, 0)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, kept_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dgb_ref, t_ref, w_ref, vn_ref, s_ref,
-                dstate_ref, *, chunk, n_chunks):
+def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, kept_ref, t_ref, do_ref, dq_ref,
+                dk_ref, dv_ref, dgb_ref, w_ref, vn_ref, kr_ref, last_ref,
+                ptdo_ref, qtdo_ref, s_ref, dvn_ref, dn_ref, dstate_ref, *,
+                chunk, n_chunks):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
     tile = _Tile(chunk)
-    rows_of = lambda c: pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-    operands = lambda c: _Chunk(q_ref[rows_of(c), :], k_ref[rows_of(c), :],
-                                v_ref[rows_of(c), :], gb_ref[c], tile)
+    ck = _Chunks(q_ref, k_ref, v_ref, gb_ref, tile)
+    t, dt = _unfolded(t_ref[...], chunk), ck.dt
+    do = do_ref[...].reshape(n_chunks, chunk, _LANES)
+    p = ck.attention()
+    ck.for_the_loops(t, w_ref, vn_ref, kr_ref, last_ref)
+    # the terms of the cotangent's chain that no cotangent enters
+    ptdo_ref[...] = _mm(p, do, _TN, dt)
+    qtdo_ref[...] = _mm(ck.e_g * ck.q, do, _TN, dt)
 
+    # the chunks forward from the state kept: each chunk's V' (over its
+    # U) and entering state
     def forward(c, state):
-        ck = operands(c)
-        t = ck.inverse(tile)
-        w, v_new = ck.new_values(t, state)
-        t_ref[c], w_ref[c], vn_ref[c], s_ref[c] = t, w, v_new, state
-        return ck.next_state(v_new, state)
+        v_new = vn_ref[c] - _mm(w_ref[c], state, _NN, dt)
+        vn_ref[c], s_ref[c] = v_new, state
+        return last_ref[c] * state + _mm(kr_ref[c], v_new, _TN, dt)
 
     jax.lax.fori_loop(0, n_chunks, forward, kept_ref[...])
 
-    sub = jax.lax.broadcasted_iota(jnp.int32, (chunk, _ROWS), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, _ROWS), 1)
-    top = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, chunk), 0) == 0
-
-    def backward(i, carry):
+    # and backward, the state's cotangent alone: with O = (Q exp G) S +
+    # P V', V' = U - W S and S' = exp(G_C) S + (K exp(G_C - G))^T V', a
+    # chunk's dV' and the cotangent leaving it
+    def backward(i, d_next):
         c = n_chunks - 1 - i
-        ck, dt = operands(c), q_ref.dtype
-        t, w, v_new, state = t_ref[c], w_ref[c], vn_ref[c], s_ref[c]
-        do, d_next = do_ref[rows_of(c), :], dstate_ref[...]
-        p = ck.attention()
-        # O = (Q exp G) S + P V';  S' = exp(G_C) S + (K exp(G_C - G))^T V'
-        dv_new = _mm(p, do, _TN, dt) + _mm(ck.k_rest, d_next, _NN, dt)
-        dp = _mm(do, v_new, _NT, dt)
-        dp_gam = dp * ck.gam
-        dq_s = _mm(do, state, _NT, dt)                      # dO S^T
-        dk_rest = _mm(v_new, d_next, _NT, dt)               # V' dS'^T
-        from_rest = jnp.sum(dk_rest * ck.k_rest, axis=1, keepdims=True)
-        d_last = jnp.sum(from_rest, axis=0, keepdims=True) + ck.e_last * (
-            jnp.sum(jnp.sum(state * d_next, axis=1, keepdims=True),
-                    axis=0, keepdims=True))
-        dstate_ref[...] = (_mm(ck.e_g * ck.q, do, _TN, dt)
-                           + ck.e_last * d_next - _mm(w, dv_new, _TN, dt))
-        # V' = U - W S;  U = T (beta V);  W = T (beta exp(G) K)
-        dw = -_mm(dv_new, state, _NT, dt)
-        d_t = _mm(dv_new, ck.v_beta, _NT, dt) + _mm(dw, ck.k_beta, _NT, dt)
-        dv_beta = _mm(t, dv_new, _TN, dt)
-        dk_beta = _mm(t, dw, _TN, dt)
-        # T = (I - A)^-1;  A = -diag(beta) M;  M = K K^T * Gam (strict)
-        da = jnp.where(tile.strict, _mm(_mm(t, d_t, _TN), t, _NT), 0.0)
-        dm_gam = -ck.beta * da * ck.gam_strict
-        # Gam[r, s] = exp(G_r - G_s): what reaches G_r less what reaches G_s
-        e = dp * p - ck.beta * da * ck.m
-        dq_ref[rows_of(c), :] = (_mm(dp_gam, ck.k, _NN, dt)
-                                 + ck.e_g * dq_s).astype(dq_ref.dtype)
-        dk_ref[rows_of(c), :] = (
-            _mm(dp_gam, ck.q, _TN, dt) + ck.e_rest * dk_rest
-            + _mm(dm_gam, ck.k, _NN, dt) + _mm(dm_gam, ck.k, _TN, dt)
-            + ck.beta * ck.e_g * dk_beta).astype(dk_ref.dtype)
-        dv_ref[rows_of(c), :] = (ck.beta * dv_beta).astype(dv_ref.dtype)
-        d_g = (ck.e_g * jnp.sum(dq_s * ck.q, axis=1, keepdims=True)
-               - from_rest
-               + jnp.sum(dk_beta * ck.k_beta, axis=1, keepdims=True)
-               + jnp.sum(e, axis=1, keepdims=True)
-               + jnp.where(sub[:, 0:1] == chunk - 1, d_last, 0.0))
-        d_beta = (-jnp.sum(da * ck.m, axis=1, keepdims=True)
-                  + jnp.sum(dv_beta * ck.v, axis=1, keepdims=True)
-                  + ck.e_g * jnp.sum(dk_beta * ck.k, axis=1, keepdims=True))
-        columns = jnp.where(lane == 0, d_g, jnp.where(lane == 1, d_beta, 0.0))
-        dgb_ref[c] = tile.rows(columns) - jnp.where(
-            top, jnp.sum(e, axis=0, keepdims=True), 0.0)
-        return carry
+        dv_new = ptdo_ref[c] + _mm(kr_ref[c], d_next, _NN, dt)
+        dvn_ref[c], dn_ref[c] = dv_new, d_next
+        return (qtdo_ref[c] + last_ref[c] * d_next
+                - _mm(w_ref[c], dv_new, _TN, dt))
 
-    jax.lax.fori_loop(0, n_chunks, backward, 0)
+    dstate_ref[...] = jax.lax.fori_loop(0, n_chunks, backward,
+                                        dstate_ref[...])
+
+    # every chunk's cotangents from its dV' and dS', the chunks at once
+    v_new, state, dv_new, d_next = (vn_ref[...], s_ref[...], dvn_ref[...],
+                                    dn_ref[...])
+    total = lambda x: jnp.sum(x, axis=-1, keepdims=True)
+    dp = _mm(do, v_new, _NT, dt)
+    dp_gam = dp * ck.gam
+    dq_s = _mm(do, state, _NT, dt)                      # dO S^T
+    dk_rest = _mm(v_new, d_next, _NT, dt)               # V' dS'^T
+    from_rest = total(dk_rest * ck.k_rest)
+    d_last = jnp.sum(from_rest, axis=-2, keepdims=True) + ck.e_last * (
+        jnp.sum(total(state * d_next), axis=-2, keepdims=True))
+    # V' = U - W S;  U = T (beta V);  W = T (beta exp(G) K)
+    dw = -_mm(dv_new, state, _NT, dt)
+    d_t = _mm(dv_new, ck.v_beta, _NT, dt) + _mm(dw, ck.k_beta, _NT, dt)
+    dv_beta = _mm(t, dv_new, _TN, dt)
+    dk_beta = _mm(t, dw, _TN, dt)
+    # T = (I - A)^-1;  A = -diag(beta) M;  M = K K^T * Gam (strict)
+    da = jnp.where(tile.strict, _mm(_mm(t, d_t, _TN), t, _NT), 0.0)
+    dm_gam = -ck.beta * da * ck.gam_strict
+    # Gam[r, s] = exp(G_r - G_s): what reaches G_r less what reaches G_s
+    e = dp * p - ck.beta * da * ck.m
+    flat = lambda x, ref: x.astype(ref.dtype).reshape(ref.shape)
+    dq_ref[...] = flat(_mm(dp_gam, ck.k, _NN, dt) + ck.e_g * dq_s, dq_ref)
+    dk_ref[...] = flat(
+        _mm(dp_gam, ck.q, _TN, dt) + ck.e_rest * dk_rest
+        + _mm(dm_gam, ck.k, _NN, dt) + _mm(dm_gam, ck.k, _TN, dt)
+        + ck.beta * ck.e_g * dk_beta, dk_ref)
+    dv_ref[...] = flat(ck.beta * dv_beta, dv_ref)
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    d_g = (ck.e_g * total(dq_s * ck.q) - from_rest
+           + total(dk_beta * ck.k_beta) + total(e)
+           + jnp.where(last, d_last, 0.0))
+    d_beta = (-total(da * ck.m) + total(dv_beta * ck.v)
+              + ck.e_g * total(dk_beta * ck.k))
+    # a chunk's two columns and six of padding, every chunk's turned back
+    # to rows by one product
+    padding = jnp.zeros((chunk, _ROWS - 2), jnp.float32)
+    columns = jnp.concatenate(
+        [x for c in range(n_chunks) for x in (d_g[c], d_beta[c], padding)],
+        axis=1)
+    top = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, chunk), 0) == 0
+    dgb_ref[...] = tile.rows(columns).reshape(dgb_ref.shape) - jnp.where(
+        top, jnp.sum(e, axis=-2, keepdims=True), 0.0)
 
 
 def _block_chunks(t: int, chunk: int) -> int:
@@ -292,6 +370,11 @@ def _block_chunks(t: int, chunk: int) -> int:
     while n < _BLOCK_CHUNKS and (t // chunk) % (2 * n) == 0:
         n *= 2
     return n
+
+
+def _t_shape(chunk: int):
+    """A chunk's ``T`` as it is kept (:func:`_fold`)."""
+    return chunk // _fold(chunk), chunk * _fold(chunk)
 
 
 def _shapes(q, v, chunk):
@@ -315,7 +398,13 @@ def _specs(ratio, chunk, n_chunks, n_blocks, backward: bool):
                            lambda b, h, i: (b, h, at(i), 0, 0))
     kept = pl.BlockSpec((None, None, None, _LANES, _LANES),
                         lambda b, h, i: (b, h, at(i), 0, 0))
-    return key, value, scalars, kept
+    inverses = pl.BlockSpec((None, None, n_chunks, *_t_shape(chunk)),
+                            lambda b, h, i: (b, h, at(i), 0, 0))
+    return key, value, scalars, kept, inverses
+
+
+def _vmem(dtype, *shape):
+    return pltpu.VMEM(shape, dtype)
 
 
 _SEQUENTIAL = pltpu.CompilerParams(
@@ -324,25 +413,34 @@ _SEQUENTIAL = pltpu.CompilerParams(
 
 def _fwd(q, k, v, gb, chunk):
     b, t, heads, ratio, n_chunks, n_blocks = _shapes(q, v, chunk)
-    key, value, scalars, kept = _specs(ratio, chunk, n_chunks, n_blocks,
-                                       backward=False)
+    key, value, scalars, kept, inverses = _specs(
+        ratio, chunk, n_chunks, n_blocks, backward=False)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, n_chunks=n_chunks),
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, heads, n_blocks, _LANES, _LANES),
-                                        jnp.float32)],
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct(
+                       (b, heads, t // chunk, *_t_shape(chunk)), jnp.float32)],
         grid=(b, heads, n_blocks),
-        in_specs=[key, key, value, scalars], out_specs=[value, kept],
-        scratch_shapes=[pltpu.VMEM((_LANES, _LANES), jnp.float32)],
+        in_specs=[key, key, value, scalars],
+        out_specs=[value, kept, inverses],
+        scratch_shapes=[
+            _vmem(q.dtype, n_chunks, 2 * chunk, _LANES),    # [W ; Q exp G]
+            _vmem(jnp.float32, n_chunks, chunk, _LANES),    # U
+            _vmem(q.dtype, n_chunks, chunk, _LANES),        # K exp(G_C - G)
+            _vmem(jnp.float32, n_chunks, 1, _LANES),        # exp(G_C)
+            _vmem(q.dtype, n_chunks, chunk, chunk),         # P
+            _vmem(jnp.float32, _LANES, _LANES)],            # the state
         compiler_params=_SEQUENTIAL, interpret=_interpret(), name="gdn_fwd",
     )(q, k, v, gb)
 
 
-def _bwd(q, k, v, gb, states, do, chunk):
+def _bwd(q, k, v, gb, states, inverses, do, chunk):
     b, t, heads, ratio, n_chunks, n_blocks = _shapes(q, v, chunk)
-    key, value, scalars, kept = _specs(ratio, chunk, n_chunks, n_blocks,
-                                       backward=True)
-    f32 = lambda *shape: pltpu.VMEM(shape, jnp.float32)
+    key, value, scalars, kept, kept_t = _specs(
+        ratio, chunk, n_chunks, n_blocks, backward=True)
+    f32 = functools.partial(_vmem, jnp.float32)
     dq, dk, dv, dgb = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, n_chunks=n_chunks),
         out_shape=[jax.ShapeDtypeStruct(v.shape, q.dtype),
@@ -350,15 +448,21 @@ def _bwd(q, k, v, gb, states, do, chunk):
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(gb.shape, jnp.float32)],
         grid=(b, heads, n_blocks),
-        in_specs=[key, key, value, scalars, kept, value],
+        in_specs=[key, key, value, scalars, kept, kept_t, value],
         out_specs=[value, value, value, scalars],
-        scratch_shapes=[f32(n_chunks, chunk, chunk),
-                        f32(n_chunks, chunk, _LANES),
-                        f32(n_chunks, chunk, _LANES),
-                        f32(n_chunks, _LANES, _LANES),
-                        f32(_LANES, _LANES)],
+        scratch_shapes=[
+            _vmem(q.dtype, n_chunks, chunk, _LANES),    # W
+            f32(n_chunks, chunk, _LANES),               # U, then V'
+            _vmem(q.dtype, n_chunks, chunk, _LANES),    # K exp(G_C - G)
+            f32(n_chunks, 1, _LANES),                   # exp(G_C)
+            f32(n_chunks, chunk, _LANES),               # P^T dO
+            f32(n_chunks, _LANES, _LANES),              # (Q exp G)^T dO
+            f32(n_chunks, _LANES, _LANES),              # entering states
+            f32(n_chunks, chunk, _LANES),               # dV'
+            f32(n_chunks, _LANES, _LANES),              # dS' leaving a chunk
+            f32(_LANES, _LANES)],                       # the state's cotangent
         compiler_params=_SEQUENTIAL, interpret=_interpret(), name="gdn_bwd",
-    )(q, k, v, gb, states, do)
+    )(q, k, v, gb, states, inverses, do)
     # the value heads of a key head: adjacent blocks of 128 lanes
     by_key = lambda d: jnp.sum(d.astype(jnp.float32).reshape(
         b, t, heads // ratio, ratio, _LANES), 3).reshape(q.shape).astype(
@@ -372,15 +476,14 @@ def _rule(q, k, v, gb, chunk):
 
 
 def _forward(q, k, v, gb, chunk):
-    o, states = _fwd(q, k, v, gb, chunk)
-    o = checkpoint_name(o, SAVED_NAMES[0])
-    states = checkpoint_name(states, SAVED_NAMES[1])
-    return o, (q, k, v, gb, states)
+    o, states, inverses = (checkpoint_name(x, name) for x, name in zip(
+        _fwd(q, k, v, gb, chunk), SAVED_NAMES))
+    return o, (q, k, v, gb, states, inverses)
 
 
 def _backward(chunk, res, do):
-    q, k, v, gb, states = res
-    return _bwd(q, k, v, gb, states, do.astype(v.dtype), chunk)
+    *kept, do = *res, do.astype(res[2].dtype)
+    return _bwd(*kept, do, chunk)
 
 
 _rule.defvjp(_forward, _backward)

@@ -112,6 +112,35 @@ def _pallas_calls(text, kernel):
     return len(_kernel_lines(text, kernel))
 
 
+def _declared_vmem(jaxpr, found=None):
+    """``{kernel name: [bytes, ...]}``: what each ``pallas_call`` inside
+    ``jaxpr`` declares of VMEM: its blocks twice (the pipeline holds two
+    of each) and its scratch, every array padded to its dtype's (8 x 4 /
+    itemsize, 128) tile. What the compiler adds for values it spills is
+    not in it: the compile, which refuses a kernel over the chip's scoped
+    limit (16 MiB on the v5e), holds the sum."""
+    found = {} if found is None else found
+
+    def padded(aval):
+        *lead, rows, lanes = aval.shape
+        item = np.dtype(aval.dtype).itemsize
+        tile = 32 // item
+        return (int(np.prod(lead, dtype=np.int64)) * -(-rows // tile) * tile
+                * -(-lanes // 128) * 128 * item)
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            mapping, body = eqn.params["grid_mapping"], eqn.params["jaxpr"]
+            scratch = body.invars[len(body.invars)
+                                  - mapping.num_scratch_operands:]
+            found.setdefault(eqn.params["name"], []).append(
+                2 * sum(padded(b.block_aval) for b in mapping.block_mappings)
+                + sum(padded(v.aval) for v in scratch))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _declared_vmem(sub, found)
+    return found
+
+
 def _forward_statistics(text, kernel):
     """The float32 results of the compiled forward kernels ``kernel``,
     as their dims: the row statistics, one number a row (rank 4, the
@@ -359,9 +388,11 @@ def test_gated_delta_rule_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     """``gdn_fwd`` and ``gdn_bwd`` at the linear-attention cell's shapes
     (1 row of 16,384 tokens, 16 key and 32 value heads of 128, chunks of
     64 in blocks of 8): the 64 x 64 float32 products of the triangular
-    inverse, the identity products that turn a token's scalars, the
-    state in scratch across the sequential axis and the backward
-    kernel's 1.2 MB of chunk caches lower in Mosaic."""
+    inverse batched over a block's chunks, the one identity product that
+    turns the block's scalars, ``T`` folded 128 lanes wide on its way out
+    and back, the state in scratch across the sequential axis and what
+    the state's loops read (0.8 MiB forward; 2.6 MiB backward with the
+    chunks' states and cotangents) lower in Mosaic and fit its VMEM."""
     from sparktorch_tpu.ops.gated_delta_rule import gated_delta_rule
 
     t = 16_384
@@ -371,15 +402,23 @@ def test_gated_delta_rule_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     def loss(q, k, v, g, beta):
         return gated_delta_rule(q, k, v, g, beta).astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    traced = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
         S((1, t, 2048), jnp.bfloat16), S((1, t, 2048), jnp.bfloat16),
         S((1, t, 4096), jnp.bfloat16), S((1, t, 32), jnp.float32),
-        S((1, t, 32), jnp.float32)).compile()
-    text = compiled.as_text()
+        S((1, t, 32), jnp.float32))
+    text = traced.lower().compile().as_text()
     assert _pallas_calls(text, "gdn_fwd") == 1
     assert _pallas_calls(text, "gdn_bwd") == 1
-    # the states kept: one a value head a block of 512 tokens
+    # the states kept: one a value head a block of 512 tokens; a chunk's
+    # T: 32 rows of 128 lanes, 134 MB a layer
     assert re.search(r"f32\[1,32,32,128,128\]", text)
+    assert re.search(r"f32\[1,32,256,32,128\]", text)
+    # 2.28 and 4.84 MiB read at PR 43, of a scoped limit of 16: the
+    # compiler has as much again for what the batched phases spill
+    vmem = _declared_vmem(traced.jaxpr.jaxpr)
+    assert sorted(vmem) == ["gdn_bwd", "gdn_fwd"]
+    assert max(vmem["gdn_fwd"]) < 3 * 2 ** 20
+    assert max(vmem["gdn_bwd"]) < 5.5 * 2 ** 20
 
 
 def test_causal_kernels_and_qk_norm_rope_at_256_compile_for_v5e(one_chip,
@@ -420,9 +459,10 @@ def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     file's sizes and the cell's row (1 row of 16,384 tokens; three Gated
     DeltaNet layers and a full layer at 256, 16 of 512 experts held, 10 a
     token, 18,992 rows of vocabulary): the rule's kernels run once a
-    linear layer (the remat keeps its output and block states), the
-    causal kernels once, and the gradient's scratch leaves room beside
-    6.79 GB of state."""
+    linear layer (the remat keeps its output, block states and each
+    chunk's ``T``) and declare the VMEM they declare alone, the causal
+    kernels once, and the gradient's scratch leaves room beside 6.79 GB
+    of state."""
     import json
 
     from sparktorch_tpu.models.sparse_moe_lm import qwen3_next_lm
@@ -443,11 +483,15 @@ def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
         out, sown = module.apply({"params": p}, x, mutable=["moe_metrics"])
         return loss_fn(out, y).sum(), sown
 
-    compiled = jax.jit(jax.grad(loss, has_aux=True)).lower(
-        jax.tree.map(S, shapes), S(ids), S(ids)).compile()
+    traced = jax.jit(jax.grad(loss, has_aux=True)).trace(
+        jax.tree.map(S, shapes), S(ids), S(ids))
+    compiled = traced.lower().compile()
     text = compiled.as_text()
     assert _pallas_calls(text, "gdn_fwd") == 3
     assert _pallas_calls(text, "gdn_bwd") == 3
+    vmem = _declared_vmem(traced.jaxpr.jaxpr)
+    assert len(vmem["gdn_fwd"]) == len(vmem["gdn_bwd"]) == 3
+    assert max(vmem["gdn_fwd"] + vmem["gdn_bwd"]) < 5.5 * 2 ** 20
     for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
         assert _pallas_calls(text, f"causal_attn_{kernel}") == 1
     assert _forward_statistics(text, "causal_attn_fwd") == [(1, 2, 8, 16_384)]

@@ -1,8 +1,9 @@
 """``ops/gated_delta_rule.py`` (the chunked WY form, Pallas, interpret
 mode here) against the token-by-token recurrence it restates: outputs
 and all five gradients, the state carried from chunk to chunk and from
-block to block, nothing leaking backwards in time, the shapes it refuses
-and what a remat policy keeps.
+block to block, nothing leaking backwards in time, the shapes it refuses,
+what a remat policy keeps (each chunk's ``T`` among it) and what the
+backward kernel no longer computes: the inverse.
 
 Tolerances. With float32 operands every product in the kernels is at
 full precision and the two derivations differ by float32's order of
@@ -202,34 +203,138 @@ def test_shapes_that_do_not_tile_are_errors():
         gated_delta_rule(q, k, v, g[..., :1], beta)
 
 
-def _kernel_calls(jaxpr, found=None):
+def _kernels(jaxpr, found=None):
+    """``{name: [pallas_call equations]}`` of a jaxpr and all inside it."""
     found = {} if found is None else found
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            name = eqn.params["name"]
-            found[name] = found.get(name, 0) + 1
+            found.setdefault(eqn.params["name"], []).append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _kernel_calls(sub, found)
+            _kernels(sub, found)
     return found
+
+
+def _kernel_calls(jaxpr):
+    return {name: len(eqns) for name, eqns in _kernels(jaxpr).items()}
+
+
+def _under_remat(kept, chunk=64):
+    """The rule as a layer calls it: under a remat policy that keeps the
+    names ``kept`` and nothing else."""
+    policy = jax.checkpoint_policies.save_only_these_names(*kept)
+    return jax.checkpoint(
+        lambda *a: gated_delta_rule(*a, chunk=chunk) * 2.0, policy=policy)
+
+
+def _gradient_jaxpr(kept, args, chunk=64):
+    layer = _under_remat(kept, chunk)
+    return jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(layer(*a)),
+                                   argnums=(0, 1, 2, 3, 4)))(*args).jaxpr
 
 
 @pytest.mark.parametrize("kept,fwd_calls", [(G.SAVED_NAMES, 1), ((), 2)])
 def test_a_remat_policy_that_keeps_the_names_runs_the_forward_kernel_once(
         kept, fwd_calls):
     args = operands(8, 1, 128, 1, 2, 0.01)[:5]
-    policy = jax.checkpoint_policies.save_only_these_names(*kept)
-    layer = jax.checkpoint(lambda *a: gated_delta_rule(*a) * 2.0,
-                           policy=policy)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(layer(*a)),
-                                    argnums=(0, 1, 2, 3, 4)))(*args)
-    assert _kernel_calls(jaxpr.jaxpr) == {"gdn_fwd": fwd_calls, "gdn_bwd": 1}
+    assert _kernel_calls(_gradient_jaxpr(kept, args)) == {
+        "gdn_fwd": fwd_calls, "gdn_bwd": 1}
+
+
+def _full_precision_tile_products(jaxpr, chunk):
+    """How many ``dot_general`` s in a kernel's body (its loops' bodies
+    too) multiply two ``chunk x chunk`` matrices, or a batch of them, at
+    full precision: the inverse's ten, and the two of ``da = T^T dT
+    T^T``. (The products with the identity that turn a token's scalars
+    have an operand of 8 rows a chunk; the cases below run 4 chunks a
+    block, so 32 rows, no tile.)"""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            highest = jax.lax.Precision.HIGHEST in jax.tree.leaves(
+                eqn.params["precision"])
+            n += highest and all(
+                v.aval.shape[-2:] == (chunk, chunk) for v in eqn.invars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _full_precision_tile_products(sub, chunk)
+    return n
+
+
+# the inverse's products: four for the 8 x 8 blocks and two a merge
+@pytest.mark.parametrize("tokens,chunk,inverse",
+                         [(256, 64, 10), (64, 16, 6)])
+def test_the_backward_kernel_reads_t_and_inverts_nothing(tokens, chunk,
+                                                         inverse):
+    """With the names kept the gradient is one ``gdn_fwd`` and one
+    ``gdn_bwd``; the forward kernel's body holds the inverse's
+    full-precision tile products once for its block's chunks, the
+    backward kernel's the two of ``da`` and none of the inverse, and ``T``
+    as the forward kernel wrote it is among its operands."""
+    args = operands(10, 1, tokens, 1, 2, 0.01)[:5]
+    kernels = _kernels(_gradient_jaxpr(G.SAVED_NAMES, args, chunk))
+    assert {k: len(v) for k, v in kernels.items()} == {"gdn_fwd": 1,
+                                                       "gdn_bwd": 1}
+    (fwd,), (bwd,) = kernels["gdn_fwd"], kernels["gdn_bwd"]
+    assert _full_precision_tile_products(fwd.params["jaxpr"],
+                                         chunk) == inverse
+    assert _full_precision_tile_products(bwd.params["jaxpr"], chunk) == 2
+    t_kept = fwd.outvars[2].aval
+    assert t_kept.shape == (1, 2, tokens // chunk, *G._t_shape(chunk))
+    assert [v.aval for v in bwd.invars].count(t_kept) == 1
+
+
+@pytest.mark.parametrize("tokens,chunk", [(1024, 64), (64, 16)])
+def test_the_t_kept_is_each_chunks_inverse(tokens, chunk):
+    """``T`` is kept ``[b, value heads, T / chunk, ...]`` float32 (a
+    chunk's rows folded side by side, 128 lanes wide at 64), unit lower
+    triangular a chunk, and ``(I - A) T = I`` for the chunk's ``A =
+    -diag(beta) (K K^T * Gam)`` strictly below the diagonal."""
+    q, k, v, g, beta, _ = operands(11, 1, tokens, 1, 2, 0.05)
+    n = tokens // chunk
+    by_chunk = lambda x: jnp.moveaxis(x, 1, 2).reshape(1, 2, n, 1, chunk)
+    gb = jnp.concatenate([jnp.cumsum(by_chunk(g), -1), by_chunk(beta),
+                          jnp.zeros((1, 2, n, 6, chunk))], 3)
+    _, (*_, kept) = G._forward(q, k, v, gb, chunk)
+    assert kept.dtype == jnp.float32
+    assert kept.shape == (1, 2, n, *G._t_shape(chunk))
+    assert kept.shape[-1] == (128 if chunk == 64 else 32)
+    t = np.asarray(G._unfolded(kept, chunk), np.float64)
+    assert t.shape == (1, 2, n, chunk, chunk)
+    np.testing.assert_array_equal(np.triu(t, 1), 0.0)
+    np.testing.assert_array_equal(np.diagonal(t, axis1=-2, axis2=-1), 1.0)
+    kc = np.asarray(k, np.float64).reshape(1, 1, n, chunk, D)
+    g_sum, b = (np.asarray(gb[..., i, :], np.float64) for i in (0, 1))
+    gam = np.exp(np.minimum(g_sum[..., :, None] - g_sum[..., None, :], 0.0))
+    a = np.tril(-b[..., :, None] * (kc @ np.swapaxes(kc, -1, -2)) * gam, -1)
+    assert np.abs((np.eye(chunk) - a) @ t - np.eye(chunk)).max() < 1e-5
+
+
+@functools.lru_cache(maxsize=None)
+def _remat_gradients(kept):
+    """The gradients of the rule under :func:`_under_remat`, two blocks
+    of eight chunks, weighed as :func:`both` weighs them."""
+    *args, w = operands(12, 1, 1024, 1, 2, 0.01)
+    layer = _under_remat(kept)
+    return jax.jit(jax.grad(lambda *a: jnp.sum(layer(*a) * w),
+                            argnums=(0, 1, 2, 3, 4)))(*args)
+
+
+@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("kept", [G.SAVED_NAMES, ()],
+                         ids=["names_kept", "nothing_kept"])
+def test_each_gradient_under_a_remat_policy_is_the_recurrences(kept, name):
+    """With the names kept the backward kernel reads the forward pass's
+    ``T`` and states; with nothing kept the forward kernel runs again
+    and makes them again. Either way the gradients are the rule's."""
+    want = both(12, 1, 1024, 1, 2, 0.01, 64)[1][1]
+    i = NAMES.index(name)
+    assert rel(_remat_gradients(kept)[i], 2.0 * want[i]) < 1e-4
 
 
 def test_the_states_kept_are_one_a_block_of_eight_chunks():
     q, k, v, g, beta, _ = operands(9, 1, 1024, 1, 2, 0.01)
     # G = 0 (no decay) in row 0 and beta = 0.5 in row 1 of the scalars
     gb = jnp.zeros((1, 2, 16, 8, 64)).at[..., 1, :].set(0.5)
-    _, (_, _, _, _, states) = G._forward(q, k, v, gb, 64)
+    _, (_, _, _, _, states, _) = G._forward(q, k, v, gb, 64)
     assert states.shape == (1, 2, 2, D, D)
     assert not np.any(np.asarray(states[:, :, 0]))   # a row starts from 0
     assert np.any(np.asarray(states[:, :, 1]))
