@@ -5,7 +5,7 @@ points a user calls; data is made from ``--seed`` and nothing is
 fetched. It fails before any phase unless JAX's first device is a TPU
 — it never sets a platform itself.
 
-With no arguments (one chip), seven phases:
+With no arguments (one chip), eight phases:
 
 - ``trainer_sync``   the README flow at BERT-base width:
   ``serialize_torch_obj(bert_base())`` -> ``SparkTorch(mode=
@@ -45,6 +45,15 @@ With no arguments (one chip), seven phases:
   identity products that turn a token's scalars and the state carried
   across the grid's sequential axis are checked here, where interpret
   mode cannot.
+- ``gdn_conv_gate`` ``ops/gdn_conv_gate.py`` (what a Gated DeltaNet
+  layer does around the rule: the causal convolution, SiLU, the q/k L2
+  norms and the cast out of the float32 product, and the gated norm a
+  head) at Qwen3-Next's width on 2,048 tokens against the plain
+  spelling it replaced: the four results and the gradients of the
+  product, the taps, the gain and ``o``. Mosaic's sublane rolls across
+  a tile's halo, the index maps that read a part's columns out of the
+  product and the one cotangent buffer the backward kernels alias are
+  checked here, where interpret mode cannot.
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -136,7 +145,8 @@ class Sizes:
     # latent_attention: (rows, tokens, heads) of nope 128 + rope 64 over
     # values of 128
     latent_case: tuple = (1, 2048, 32)
-    # gated_delta: (rows, tokens, key heads, value heads) of 128
+    # gated_delta and gdn_conv_gate: (rows, tokens, key heads, value
+    # heads) of 128
     gdn_case: tuple = (1, 2048, 16, 32)
     # trainer_hogwild: bench resnet18_hogwild
     hog_rows: int = 1024
@@ -602,6 +612,85 @@ def phase_gated_delta(sz: Sizes, seed: int, ctx: dict) -> str:
     return report
 
 
+def phase_gdn_conv_gate(sz: Sizes, seed: int, ctx: dict) -> str:
+    """``ops/gdn_conv_gate.py`` against the array operations it replaced
+    in ``GatedDeltaNet``, at Qwen3-Next's width, bfloat16 results out of
+    a float32 product: ``q``, ``k``, ``v``, the gated norm's ``y`` and
+    the four gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.models.sparse_moe_lm import rms_norm
+    from sparktorch_tpu.ops.gdn_conv_gate import gdn_conv, gdn_out_norm
+    from sparktorch_tpu.ops.sparse_attention import by_head
+
+    b, t, hk, hv = sz.gdn_case
+    d, taps, eps, dt = 128, 4, 1e-6, jnp.bfloat16
+    n_k, n_v = hk * d, hv * d
+    keys = jax.random.split(jax.random.key(seed), 9)
+    qkvz = jax.random.normal(keys[0], (b, t, 2 * n_k + 2 * n_v)) * jnp.exp(
+        jax.random.normal(keys[1], (b, t, 1)))
+    w = 0.289 * jax.random.normal(keys[2], (taps, 2 * n_k + n_v))
+    gain = 1.0 + 0.2 * jax.random.normal(keys[3], (d,))
+    o = jax.random.normal(keys[4], (b, t, n_v)).astype(dt)
+    weights = [jax.random.normal(k, (b, t, n)).astype(dt) for k, n in zip(
+        keys[5:], (n_k, n_k, n_v, n_v))]
+
+    def fused(qkvz, w, gain, o):
+        q, k, v, gate = gdn_conv(qkvz, w, n_k, dt)
+        return q, k, v, gdn_out_norm(o, gate, gain, eps)
+
+    def plain(qkvz, w, gain, o):
+        u, z = qkvz[..., :2 * n_k + n_v], qkvz[..., 2 * n_k + n_v:]
+        padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+        u = jax.nn.silu(sum(w[i] * padded[:, i:i + t] for i in range(taps)))
+
+        def unit(x, scale):
+            x = by_head(x, d)
+            return (x * (scale * jax.lax.rsqrt(jnp.sum(
+                jnp.square(x), -1, keepdims=True) + 1e-6))).astype(
+                    dt).reshape(b, t, n_k)
+
+        return (unit(u[..., :n_k], d ** -0.5), unit(u[..., n_k:2 * n_k], 1.0),
+                u[..., 2 * n_k:].astype(dt),
+                (rms_norm(by_head(o, d), gain, eps) * jax.nn.silu(
+                    by_head(z, d))).astype(dt).reshape(o.shape))
+
+    def both(fn):
+        def loss(*a):
+            return sum(jnp.sum(x.astype(jnp.float32) * w_)
+                       for x, w_ in zip(fn(*a), weights))
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            loss, argnums=(0, 1, 2, 3))(*a)))
+
+    def rel(a, b):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+    operands = (qkvz, w, gain, o)
+    run = both(fused)
+    got = jax.block_until_ready(run(*operands))
+    t0 = time.perf_counter()
+    jax.block_until_ready(run(*operands))
+    fused_s = time.perf_counter() - t0
+    want = jax.block_until_ready(both(plain)(*operands))
+    value_rel = max(map(rel, got[0], want[0]))
+    grads = {n: rel(a, b) for n, a, b in zip(
+        ("qkvz", "taps", "gain", "o"), got[1], want[1])}
+    report = (f"{hk}/{hv}x{t}x{b} value_rel={value_rel:.2e} grad_rel="
+              f"{ {n: float(f'{r:.2e}') for n, r in grads.items()} } "
+              f"fwd_and_grad_s={fused_s:.4f}")
+    # o's cotangent leaves in bfloat16, rounded once on either side
+    if not (value_rel <= TOL_FUSED_VALUE_REL            # NaN fails too
+            and max(grads["qkvz"], grads["taps"],
+                    grads["gain"]) <= TOL_FUSED_GRAD_REL
+            and grads["o"] <= TOL_FUSED_VALUE_REL):
+        raise AssertionError(
+            f"the linear layer's fused passes vs the plain spelling: "
+            f"{report} (limits {TOL_FUSED_VALUE_REL}, {TOL_FUSED_GRAD_REL})")
+    return report
+
+
 def phase_trainer_hogwild(sz: Sizes, seed: int, ctx: dict) -> str:
     from sparktorch_tpu import SparkTorch, serialize_torch_obj
     from sparktorch_tpu.models.resnet import resnet18
@@ -868,6 +957,7 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("qk_norm_rope", phase_qk_norm_rope),
             ("latent_attention", phase_latent_attention),
             ("gated_delta", phase_gated_delta),
+            ("gdn_conv_gate", phase_gdn_conv_gate),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),

@@ -237,14 +237,19 @@ heads of 128, value head ``j`` reading key head ``j // 2``:
   order and heads in order (scope ``gdn_in_proj`` inside ``attn_qkv``).
 - ``u = [q ; k ; v]`` (8,192 channels) through a causal depthwise
   convolution over time of 4 taps, no bias, then SiLU: ``u~[t, c] =
-  silu(sum_{i < 4} w[i, c] u[t - 3 + i, c])``, ``u[t < 0] = 0``: four
-  shifted multiply-adds over the channels as they lie. ``z``, ``a`` and
-  ``b`` do not pass it (scope ``gdn_conv``).
+  silu(sum_{i < 4} w[i, c] u[t - 3 + i, c])``, ``u[t < 0] = 0``. ``z``,
+  ``a`` and ``b`` do not pass it. Then ``q^ = q / sqrt(sum q^2 + 1e-6) /
+  sqrt(128)`` and ``k^ = k / sqrt(sum k^2 + 1e-6)`` over a key head's
+  128 dims, and the cast. All of it is ``ops/gdn_conv_gate.py``'s
+  ``gdn_conv`` (scope ``gdn_conv``): it reads the float32 product as it
+  lies, a part's columns through its blocks' index map and the three
+  tokens before a tile as a halo, and writes ``q^``, ``k^`` and ``v`` in
+  the compute dtype, one pass over HBM each way (kernels
+  ``gdn_conv_fwd`` / ``gdn_conv_bwd``, a call for each of the three).
 - A value head ``j`` and a token ``t``: ``beta = sigmoid(b)``, the
   log-decay ``g = -exp(A_log_j) softplus(a + dt_bias_j)`` (float32),
-  ``alpha = exp(g)`` in (0, 1); ``q^ = q / sqrt(sum q^2 + 1e-6) /
-  sqrt(128)`` and ``k^ = k / sqrt(sum k^2 + 1e-6)`` over a key head's
-  128 dims (scope ``gdn_gates``).
+  ``alpha = exp(g)`` in (0, 1): array operations on ``[T, 32]`` (scope
+  ``gdn_gates``).
 - The gated delta rule, a state ``S [128, 128]`` a value head from ``S_0
   = 0``: ``S' = alpha_t S_{t-1}``; ``u_t = beta_t (v_t - S'^T k^_t)``;
   ``S_t = S' + k^_t u_t^T``; ``o_t = S_t^T q^_t`` (so ``S_t = alpha_t (I
@@ -259,7 +264,12 @@ heads of 128, value head ``j`` reading key head ``j // 2``:
   of a chunk's 74 matrix-unit passes, and ``gdn_bwd`` did it again for
   11.0 of its 32.3 ms a layer (TPU v5e, the kernels alone, PR 43).
 - ``y_{t,j} = RMSNorm(o_{t,j}; gain [128]) * silu(z_{t,j})``, the norm
-  over a head's 128 dims (scope ``gdn_out_norm``); ``x = x + concat_j(y_j)
+  over a head's 128 dims: ``ops/gdn_conv_gate.py``'s ``gdn_out_norm``
+  (scope ``gdn_out_norm``; kernels ``gdn_out_norm_fwd`` / ``_bwd``),
+  which reads ``z`` out of the product's last columns through what
+  ``gdn_conv`` handed on as ``gate``, so that the product's cotangent is
+  ONE buffer: the norm's backward kernel writes ``z``'s columns of it
+  and the convolution's the rest, in place. ``x = x + concat_j(y_j)
   W_o`` (``[n_v 128, d]``, scope ``attn_out`` inside ``gdn_out_proj``).
 - ``A_log`` and ``dt_bias`` start on a ladder (``_DECAY_RATES``): head
   ``j``'s rate ``exp(A_log_j)`` runs geometrically from 2e-3 to 0.25 and
@@ -318,7 +328,8 @@ forward kernel visits, of the whole square's); by each ``full``,
 ``window`` and ``latent`` layer the same count as ``attn_tiles_full`` /
 ``attn_tiles_window`` / ``attn_tiles_latent``; by each ``gated_delta``
 layer ``gdn_chunks`` (the chunks the rule's forward kernel runs: rows x
-value heads x ``T / 64``); by the multi-token
+value heads x ``T / 64``) and ``gdn_fused_tokens`` (rows x ``T``: the
+tokens through the fused passes around the rule); by the multi-token
 prediction module ``mtp_loss`` (the sum of its cross entropy over the
 positions whose label the row itself holds, ``ids[i + 2]`` for ``i < T -
 2``: a forward pass of the loss's kernel on its logits, no gradient) and
@@ -339,6 +350,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from sparktorch_tpu.ops import gated_delta_rule as delta
+from sparktorch_tpu.ops import gdn_conv_gate as conv_gate
 from sparktorch_tpu.ops import qk_norm_rope as fused
 from sparktorch_tpu.ops import latent_attention as latent
 from sparktorch_tpu.ops.block_diffusion_attention import (
@@ -1001,10 +1013,11 @@ class GatedDeltaNet(_FlatProducts):
     """A Gated DeltaNet linear-attention layer ("Gated delta rule" in the
     module docstring): one product for ``q``, ``k``, ``v`` and the output
     gate ``z`` and one for the two scalars a value head, a causal
-    depthwise convolution with SiLU over ``[q ; k ; v]``, ``beta`` and
-    the log-decay, L2-normed ``q`` and ``k``, the chunked gated delta
-    rule (``ops/gated_delta_rule.py``), a gated RMSNorm a head and the
-    output projection. No rotary step, no keys and values: what it
+    depthwise convolution with SiLU over ``[q ; k ; v]`` and L2-normed
+    ``q`` and ``k`` (one fused pass, ``ops/gdn_conv_gate.py``), ``beta``
+    and the log-decay, the chunked gated delta rule
+    (``ops/gated_delta_rule.py``), a gated RMSNorm a head (the same
+    file's) and the output projection. No rotary step, no keys and values: what it
     carries along a row is a state ``[128, 128]`` a value head, inside
     the rule's kernels."""
 
@@ -1023,16 +1036,13 @@ class GatedDeltaNet(_FlatProducts):
                 "w_qkvz", (d, 2 * keys + 2 * values)))
             ba = self._product(h, self._dense("w_ba", (d, 2 * n_v)))
         with jax.named_scope("gdn_conv"):
-            # u~[t] = silu(sum_i w[i] u[t - (taps - 1) + i]), u[t < 0] = 0:
-            # shifted multiply-adds over the channels as they lie
-            u, z = qkvz[..., :2 * keys + values], qkvz[..., 2 * keys + values:]
-            w = self.param("conv", _normal(_CONV_STD),
-                           (taps, 2 * keys + values))
-            padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
-            u = jax.nn.silu(sum(w[i] * padded[:, i:i + t]
-                                for i in range(taps)))
-            q, k, v = (u[..., :keys], u[..., keys:2 * keys],
-                       u[..., 2 * keys:].astype(dt))
+            # u~[t] = silu(sum_i w[i] u[t - (taps - 1) + i]), u[t < 0] = 0
+            # over [q ; k ; v] as they lie in the product, q and k
+            # L2-normed a head, the cast: one pass (ops/gdn_conv_gate.py);
+            # ``gate`` is the product again, for the gated norm's z
+            q, k, v, gate = conv_gate.gdn_conv(
+                qkvz, self.param("conv", _normal(_CONV_STD),
+                                 (taps, 2 * keys + values)), keys, dt)
         with jax.named_scope("gdn_gates"):
             beta = jax.nn.sigmoid(ba[..., :n_v])
             log_decay = -jnp.exp(self.param(
@@ -1040,22 +1050,15 @@ class GatedDeltaNet(_FlatProducts):
                     ba[..., n_v:] + self.param(
                         "dt_bias", nn.initializers.constant(
                             np.log(np.e - 1.0)), (n_v,)))
-
-            def unit(x, scale):
-                x = by_head(x, d_k)  # a head's lanes as an axis, in place
-                return (x * (scale * jax.lax.rsqrt(jnp.sum(
-                    jnp.square(x), -1, keepdims=True) + 1e-6))).astype(
-                        dt).reshape(b, t, keys)
-
-            q, k = unit(q, d_k ** -0.5), unit(k, 1.0)
         self.sow("moe_metrics", "gdn_chunks", jnp.float32(
             delta.chunks_run(b, t, n_v)))
+        self.sow("moe_metrics", "gdn_fused_tokens", jnp.float32(b * t))
         with jax.named_scope("gated_delta"):
             o = delta.gated_delta_rule(q, k, v, log_decay, beta)
         with jax.named_scope("gdn_out_norm"):
-            gain = self.param("out_norm", nn.initializers.ones, (d_v,))
-            y = (rms_norm(by_head(o, d_v), gain, cfg.rms_eps)
-                 * jax.nn.silu(by_head(z, d_v))).astype(dt).reshape(o.shape)
+            y = conv_gate.gdn_out_norm(
+                o, gate, self.param("out_norm", nn.initializers.ones, (d_v,)),
+                cfg.rms_eps)
         # ``attn_out`` as every mixer's; ``gdn_out_proj`` tells a linear
         # layer's from a full layer's in a trace
         with jax.named_scope("gdn_out_proj"), jax.named_scope("attn_out"):
@@ -1559,6 +1562,12 @@ class SparseMoELM(nn.Module):
             # layers: rows x value heads x tokens / chunk each
             fields["gdn_chunks"] = float(sown["gdn_chunks"].sum())
             counters["train.attention.gdn_chunks"] = fields["gdn_chunks"]
+            # rows x tokens through the fused passes around the rule
+            # (ops/gdn_conv_gate.py), over the linear layers
+            fields["gdn_fused_tokens"] = float(
+                sown["gdn_fused_tokens"].sum())
+            counters["train.attention.gdn_fused_tokens"] = fields[
+                "gdn_fused_tokens"]
         for kind in _TILES_BY_KIND:
             if f"attn_tiles_{kind}" in sown:
                 tiles = sown[f"attn_tiles_{kind}"].sum(0)

@@ -26,6 +26,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.sharding import SingleDeviceSharding
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -116,7 +117,8 @@ def _declared_vmem(jaxpr, found=None):
     """``{kernel name: [bytes, ...]}``: what each ``pallas_call`` inside
     ``jaxpr`` declares of VMEM: its blocks twice (the pipeline holds two
     of each) and its scratch, every array padded to its dtype's (8 x 4 /
-    itemsize, 128) tile. What the compiler adds for values it spills is
+    itemsize, 128) tile; an operand left in HBM (``pl.ANY``) is no block.
+    What the compiler adds for values it spills is
     not in it: the compile, which refuses a kernel over the chip's scoped
     limit (16 MiB on the v5e), holds the sum."""
     found = {} if found is None else found
@@ -134,7 +136,9 @@ def _declared_vmem(jaxpr, found=None):
             scratch = body.invars[len(body.invars)
                                   - mapping.num_scratch_operands:]
             found.setdefault(eqn.params["name"], []).append(
-                2 * sum(padded(b.block_aval) for b in mapping.block_mappings)
+                2 * sum(padded(b.block_aval) for b in mapping.block_mappings
+                        if getattr(b.block_aval, "memory_space", None)
+                        is not pl.ANY)
                 + sum(padded(v.aval) for v in scratch))
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _declared_vmem(sub, found)
@@ -159,6 +163,25 @@ def _o_sized_copies(text, tokens):
     by_head = rf"\w+\[\d+,{tokens},\d+,128\]|\w+\[\d+,\d+,\d+,{tokens},128\]"
     return [line.strip()[:160] for line in text.splitlines()
             if re.search(rf"= ({by_head})\S* (copy|transpose)\(", line)]
+
+
+def _wide_float32_passes(text, tokens):
+    """The compiled text's top-level instructions with a float32 result
+    ``[b, tokens, >= 2048]`` under a linear layer's element-wise scopes
+    that are a ``pad``, ``copy``, ``concatenate`` or ``slice``, are a
+    fusion named after one or read one: what XLA moves through HBM
+    around the kernels of ``ops/gdn_conv_gate.py`` that the kernels
+    could have read or written in place."""
+    entry = text[text.index("\nENTRY "):]
+    found = []
+    for line in entry.splitlines():
+        m = re.search(r"= f32\[\d+,(\d+),(\d+)\]\S* ([\w-]+)\(", line)
+        if (m and int(m.group(1)) == tokens and int(m.group(2)) >= 2048
+                and re.search(r"gdn_conv|gdn_gates|gdn_out_norm", line)
+                and re.search(r"\b(pad|copy|concatenate|slice)\b",
+                              line.split("metadata=")[0])):
+            found.append(line.strip()[:160])
+    return found
 
 
 def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
@@ -462,7 +485,12 @@ def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     linear layer (the remat keeps its output, block states and each
     chunk's ``T``) and declare the VMEM they declare alone, the causal
     kernels once, and the gradient's scratch leaves room beside 6.79 GB
-    of state."""
+    of state. The passes around the rule are ``ops/gdn_conv_gate.py``'s
+    kernels, forward twice (the remat keeps none of their results) and
+    backward once a layer, three calls a pass of the convolution (``q``,
+    ``k``, ``v``); the product's cotangent is one buffer that the four
+    backward calls of a layer alias, and XLA is left no pass of its own
+    over a float32 ``[16384, >= 2048]`` array under their scopes."""
     import json
 
     from sparktorch_tpu.models.sparse_moe_lm import qwen3_next_lm
@@ -492,13 +520,30 @@ def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     vmem = _declared_vmem(traced.jaxpr.jaxpr)
     assert len(vmem["gdn_fwd"]) == len(vmem["gdn_bwd"]) == 3
     assert max(vmem["gdn_fwd"] + vmem["gdn_bwd"]) < 5.5 * 2 ** 20
+    layers, passes = 3, 3
+    for kernel, calls in (("gdn_conv_fwd", 2 * passes * layers),
+                          ("gdn_conv_bwd", passes * layers),
+                          ("gdn_out_norm_fwd", 2 * layers),
+                          ("gdn_out_norm_bwd", layers)):
+        assert _pallas_calls(text, kernel) == calls, kernel
+        # blocks of 8 MiB at the most, held twice, and the halos
+        assert len(vmem[kernel]) == calls
+        assert max(vmem[kernel]) < 9 * 2 ** 20, kernel
+    assert _wide_float32_passes(text, 16_384) == []
+    # the product's cotangent: written by ``gdn_out_norm_bwd`` and handed
+    # through the three ``gdn_conv_bwd`` calls in place
+    whole = r"f32\[1,16384,12288\]"
+    assert not re.search(rf"= {whole}\S* (copy|add|concatenate)\(", text)
+    assert len(re.findall(rf"= \({whole}\S*, [^=]*custom-call\(", text)) \
+        == passes * layers
     for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
         assert _pallas_calls(text, f"causal_attn_{kernel}") == 1
     assert _forward_statistics(text, "causal_attn_fwd") == [(1, 2, 8, 16_384)]
     assert _pallas_calls(text, "qk_norm_rope_fwd") == 2
     assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
     assert _pallas_calls(text, "fused_ce_fwd") == 1
-    # 4.86 GB read at PR 42
+    # 4.86 GB read at PR 42, 5.26 at PR 43, 5.74 at PR 44 (the product's
+    # whole cotangent is live across ``gdn_bwd``)
     assert compiled.memory_analysis().temp_size_in_bytes < 6_000_000_000
 
 

@@ -287,6 +287,12 @@ def test_each_kernel_runs_once_a_layer_in_the_gradient():
         "gdn_fwd", "gdn_bwd", "causal_attn_fwd", "causal_attn_bwd_dq",
         "causal_attn_bwd_dkv", "qk_norm_rope_fwd", "qk_norm_rope_bwd")}
     assert list(calls.values()) == [3, 3, 1, 1, 1, 2, 1]
+    # the passes around the rule keep nothing: forward twice a linear
+    # layer (a call for each of q, k and v), backward once
+    around = {k: pallas_calls(jaxpr, k) for k in (
+        "gdn_conv_fwd", "gdn_conv_bwd", "gdn_out_norm_fwd",
+        "gdn_out_norm_bwd")}
+    assert list(around.values()) == [18, 9, 6, 3]
 
 
 def _primitives(jaxpr, found=None):
@@ -450,6 +456,9 @@ def test_it_trains_through_train_distributed_and_its_counters_arrive(
         assert 0 < r["moe_rows"] < 4 * 4 * T * 10
         # three linear layers x 4 rows x 2 value heads x 2 chunks
         assert r["gdn_chunks"] == 3 * 4 * 2 * 2
+        # three linear layers x 4 rows x 128 tokens through the fused
+        # passes around the rule
+        assert r["gdn_fused_tokens"] == 3 * 4 * T
     assert tele.gauge_value("train.moe.experts_held") == 16
     assert tele.gauge_value("train.moe.experts_routed") == 512
     assert tele.gauge_value("train.moe.shared_width") == 32
@@ -457,6 +466,8 @@ def test_it_trains_through_train_distributed_and_its_counters_arrive(
     assert tele.gauge_value("train.attention.layers_gated_delta") == 3
     assert tele.gauge_value("train.attention.layers_full") == 1
     assert tele.counter_value("train.attention.gdn_chunks") == 2 * 48
+    assert tele.counter_value("train.attention.gdn_fused_tokens") == (
+        2 * 3 * 4 * T)
     # one tile of 128 x 128 a row a key/value head in the full layer
     assert (tele.gauge_value("train.attention.full_tiles_visited"),
             tele.gauge_value("train.attention.full_tiles_total")) == (4, 4)
