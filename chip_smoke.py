@@ -125,7 +125,7 @@ class Sizes:
     bert_mini_batch: int = 128
     bert_iters: int = 64
     predict_rows: int = 300
-    # kernels: bench long_context_lm
+    # kernels: a long-context language model's widths
     lm_vocab: int = 32768
     lm_d_model: int = 512
     lm_heads: int = 8
@@ -148,7 +148,7 @@ class Sizes:
     # gated_delta and gdn_conv_gate: (rows, tokens, key heads, value
     # heads) of 128
     gdn_case: tuple = (1, 2048, 16, 32)
-    # trainer_hogwild: bench resnet18_hogwild
+    # trainer_hogwild: ResNet-18 at CIFAR-10 shapes
     hog_rows: int = 1024
     hog_mini_batch: int = 256
     hog_iters: int = 16

@@ -3,7 +3,7 @@
 One executable shape for every process-level worker the control plane
 spawns — the ``run_shard_server``-shaped entry the ROADMAP filed for
 fleet shards, plus inference replicas, hogwild workers, and arbitrary
-dill-shipped callables (how the chaos benches ship their elastic work
+dill-shipped callables (how tests/test_ctl.py ships its elastic work
 loops). The parent writes a dill payload file; this entry:
 
 1. installs a SIGTERM handler that sets the **cancel event** — the

@@ -337,8 +337,8 @@ class Supervisor:
         self.telemetry.observe("ft_recovery_latency_s", latency,
                                labels=labels)
         # Same window, same number, into the goodput ledger's
-        # restart_downtime bucket — the reconciliation the bench gate
-        # checks.
+        # restart_downtime bucket (tests/test_goodput.py::
+        # test_restart_downtime_reconciles_with_recovery_latency).
         _goodput.add("restart_downtime", latency)
         self.telemetry.event("ft_restart", worker=w.name, attempt=attempt)
 

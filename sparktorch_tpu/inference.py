@@ -238,7 +238,7 @@ class BatchPredictor:
         instead of once per chunk.
         ``in_flight`` paces via ``block_until_ready`` as best-effort
         backpressure; callers needing a HARD bound must fence with a
-        readback themselves (see benchmarks/stream_inference_1m.py)."""
+        readback themselves."""
         n = x.shape[0]
         if n == 0:
             # Shape probe WITHOUT the readback predict() does — one
@@ -284,8 +284,8 @@ def write_rows_parquet(path: str, rows: Iterable[np.ndarray],
     a Parquet file as raw fixed-size binary — the columnar on-disk
     format the streaming inference path ingests. Returns rows written.
 
-    No compression: synthetic/pixel payloads barely compress and the
-    bench must measure the wire, not the codec.
+    No compression: synthetic/pixel payloads barely compress, and a
+    reader's rate should be the wire's, not the codec's.
     """
     import pyarrow as pa
     import pyarrow.parquet as pq

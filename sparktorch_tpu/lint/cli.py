@@ -3,8 +3,8 @@ obs/timeline.py — it is print-rule-exempt by path).
 
 Exit codes: 0 clean, 1 findings (or a --gate-wall breach), 2 usage
 error (unknown rule). --json emits the machine schema (version-
-stamped; golden-tested); --log appends one JSONL record per run so
-``benchmarks/`` retains the analyzer's wall-time trend, and
+stamped; golden-tested); --log appends one JSONL record per run (the
+analyzer's wall-time trend, wherever the caller keeps it), and
 --gate-wall FAILS the run when the analysis wall (parse+rules, not
 interpreter startup — the package import bill is jax's, not ours)
 exceeds the bound, so the lint step can never quietly become the
@@ -124,7 +124,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             "ok": bool(gate_ok and not findings),
         }
         os.makedirs(os.path.dirname(args.log) or ".", exist_ok=True)
-        with open(args.log, "a", encoding="utf-8") as f:  # lint-obs: ok (bench record retention, not telemetry)
+        with open(args.log, "a", encoding="utf-8") as f:  # lint-obs: ok (run record retention, not telemetry)
             f.write(json.dumps(record) + "\n")
 
     return 0 if (gate_ok and not findings) else 1

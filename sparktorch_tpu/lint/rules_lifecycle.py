@@ -1,8 +1,8 @@
 """Handle-lifecycle rule (SPK501): native-handle access after stop/kill.
 
-The shipped bug: PR 10's elastic bench read ``coord.generation`` after
+The shipped bug: PR 10's elastic run read ``coord.generation`` after
 the ``finally: coord.stop()`` had freed the native gang state — a
-use-after-free that segfaulted the whole bench process. The fix
+use-after-free that segfaulted the whole process. The fix
 snapshotted final state *before* the free; the rule keeps the class
 out: within one function scope, attribute access on a native handle
 (``GangCoordinator``, ``ProcessWorker``, anything from
@@ -58,7 +58,7 @@ class HandleLifecycleRule(Rule):
     id = "SPK501"
     slug = "handle-lifecycle"
     summary = "native handle used after .stop()/.kill() in the same scope"
-    why = ("PR 10's elastic bench segfaulted reading coord.generation "
+    why = ("PR 10's elastic run segfaulted reading coord.generation "
            "after the finally-stop freed the native gang state; "
            "snapshot before stop, or reassign the handle")
 

@@ -35,8 +35,8 @@ class ObsPrintRule(Rule):
 
     # CLIs whose stdout is their contract (same set the grep excluded,
     # plus the analyzer's own CLI).
-    EXEMPT = ("bench.py", "net/bench_wire.py", "obs/timeline.py",
-              "obs/replay.py", "parallel/tune.py", "lint/cli.py")
+    EXEMPT = ("obs/timeline.py", "obs/replay.py", "parallel/tune.py",
+              "lint/cli.py")
 
     def applies(self, rel: Optional[str]) -> bool:
         return rel not in self.EXEMPT
@@ -154,7 +154,7 @@ class ProfilerApiRule(Rule):
     summary = "interpreter profiling hook used outside obs/profile.py"
     why = ("sys.settrace/setprofile wreck jit dispatch for the whole "
            "process and a second sys._current_frames() walker "
-           "double-pays the <1%-overhead budget bench-profile gates; "
+           "double-pays the sampler's cost; "
            "stack sampling goes through obs.profile.StackProfiler, "
            "where rate, bounds, and bucket tagging stay audited")
 

@@ -97,8 +97,8 @@ class TransformerConfig:
     #               ep). In the GSPMD trainer: the layout is left to
     #               sharding constraints and the partitioner — which on
     #               jax 0.4.x lowers to all-gather + all-reduce (full
-    #               token replication); kept ONLY as the bench-moe
-    #               control leg and an escape hatch.
+    #               token replication); kept as 'auto''s fallback and
+    #               the tests' reference (test_pipeline_parallel.py).
     # 'auto'      — 'a2a' when the routing groups shard evenly, else
     #               'replicate'. Under the GSPMD trainer the group
     #               partition is mesh-anchored (see moe_group_partition)
@@ -549,8 +549,8 @@ class MoEFFN(nn.Module):
     independent, partitioner-proof. (Deriving the same movement from
     einsum operand shardings — ``moe_ep_dispatch='replicate'`` — is
     lowered by jax 0.4.x GSPMD to all-gather + all-reduce, O(world)
-    comm bytes and ~0.7% loss drift; kept only as the bench-moe
-    control leg.) The switch load-balance loss is sown (pre-weighted
+    comm bytes and ~0.7% loss drift; kept as ``'auto'``'s fallback and
+    a test reference.) The switch load-balance loss is sown (pre-weighted
     by ``moe_aux_weight``) into the ``losses`` collection; every
     trainer adds sown losses to the objective.
 

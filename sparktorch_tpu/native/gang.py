@@ -331,7 +331,7 @@ class GangCoordinator:
         self.world_size = world_size
         self.rejoin_grace_ms = rejoin_grace_ms
         # Last-observed native state, snapshotted by stop() BEFORE the
-        # handle is freed: callers (the elastic bench's summary, a
+        # handle is freed: callers (an elastic run's summary, a
         # supervisor's post-mortem) read .generation/.failed after the
         # run's finally-block stop, and passing the nulled handle into
         # the native calls is a use-after-free (observed segfault).

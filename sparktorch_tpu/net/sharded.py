@@ -120,7 +120,7 @@ class HashRing:
 
 class StaticFleetView:
     """A fixed shard map for clients of a fleet that never reshapes
-    (tests, single-host bench rigs)."""
+    (tests, single-host rigs)."""
 
     def __init__(self, shards: Mapping[Any, str],
                  replicas: int = _RING_REPLICAS):

@@ -463,7 +463,7 @@ def _int_header(headers: Dict[str, str], name: str) -> Optional[int]:
 def _tree_to_host(tree: Any):
     """Materialize device arrays to host numpy, preserving structure.
     Kept jax-optional: plain numpy trees pass through without
-    importing jax (bench_wire runs device-free)."""
+    importing jax (the wire codec runs device-free)."""
     try:
         import jax
 

@@ -1,7 +1,7 @@
 """sparktorch_tpu.obs — the unified telemetry subsystem.
 
 One bus (:class:`Telemetry`) shared by every trainer, the parameter
-server, inference, and the bench CLI: nestable timed spans, monotonic
+server and inference: nestable timed spans, monotonic
 counters, histogram metrics with p50/p95/p99 roll-ups, gauges. Sinks
 stream JSONL events; :func:`render_prometheus` serves the same state
 from the param server's ``/metrics`` route; gang heartbeats give

@@ -5,8 +5,8 @@ collector remember; this module lets it JUDGE: a fixed set of
 :class:`AlertRule` declarations is evaluated once per collector sweep
 against the history, producing **latched, episode-counted** alert
 events — the shape every downstream consumer (the elastic controller's
-scale signals, the bench drift gates, an operator tailing the sink
-with ``timeline --follow``) can act on without re-deriving trends.
+scale signals, an operator tailing the sink with
+``timeline --follow``) can act on without re-deriving trends.
 
 Three rule forms:
 

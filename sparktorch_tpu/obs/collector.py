@@ -258,7 +258,7 @@ class FleetCollector:
         # into bounded per-series rings (obs.history.MetricsHistory),
         # served back as derived queries on ``GET /history`` and as
         # the substrate the alert rules judge. ``history=False`` turns
-        # the tier off (the bench's overhead control leg); a
+        # the tier off (only tests pass it: ROADMAP D5); a
         # MetricsHistory instance is adopted as-is.
         from sparktorch_tpu.obs.history import DEFAULT_RETENTION, MetricsHistory
 

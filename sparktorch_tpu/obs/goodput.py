@@ -50,8 +50,8 @@ attribution, so a checkpoint inside a step chunk counts once, in
 ``checkpoint``. The one failure mode the invariant cannot derive away
 is OVER-attribution (attributed > wall — double-counted regions or
 spans on several threads): the ledger computes it explicitly
-(``overattributed_s``) and the ``make bench-goodput`` gate holds it
-near zero.
+(``overattributed_s``) and tests/test_goodput.py holds it at zero
+(``test_overattribution_is_detected_not_hidden``).
 
 The ledger publishes as the ``goodput`` telemetry section (riding
 every ``/telemetry`` scrape, the collector's last-good snapshots, and
@@ -101,9 +101,8 @@ _DIRECT_BUCKETS = ("compute", "exposed_comm", "compile", "checkpoint",
 PRODUCTIVE_BUCKETS = ("compute",)
 
 # Published per-chip peaks, keyed by jax's ``device_kind`` — the one
-# table every MFU and roofline field reads (the ledger's /goodput MFU
-# and bench.py's records alike). A device that is not in it gets no
-# such field: never another device's number.
+# table every MFU and roofline field reads. A device that is not in it
+# gets no such field: never another device's number.
 DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
     "TPU v5 lite": {
         "bf16_tflops": 197.0,
@@ -126,11 +125,9 @@ def device_peaks(device_kind: Optional[str] = None
 
 def mfu_honest(achieved_tflops_per_chip: float,
                peak_tflops: Optional[float]) -> Optional[float]:
-    """Model-FLOPs utilization from honest achieved TFLOPs/chip — the
-    exact division bench.py's headline configs report, shared so the
-    ledger's /goodput MFU and the bench can never disagree on the
-    formula. No peak (a device outside :data:`DEVICE_PEAKS`) means no
-    MFU: None."""
+    """Model-FLOPs utilization from honest achieved TFLOPs/chip: one
+    division for a rank's doc and the run-level merge. No peak (a
+    device outside :data:`DEVICE_PEAKS`) means no MFU: None."""
     if not peak_tflops:
         return None
     return achieved_tflops_per_chip / peak_tflops

@@ -11,8 +11,8 @@ stack-level drill-down). The reference had nothing here: its only
 signal was a per-partition loss callback to the driver.
 
 :class:`StackProfiler` is a wall-clock sampler: a daemon thread walks
-``sys._current_frames()`` at a configurable rate (default ~67Hz,
-gated <1% overhead by ``make bench-profile``) and tags **every
+``sys._current_frames()`` at a configurable rate (default ~67Hz;
+its cost on the chip is not measured) and tags **every
 sample with the ledger bucket open on that thread** via
 :func:`~sparktorch_tpu.obs.goodput.open_span_buckets` — the
 cross-thread registry the ledger maintains for exactly this reader.
@@ -188,7 +188,7 @@ class StackProfiler:
                 keys.reverse()  # root first
                 if len(keys) > self.max_depth:
                     # Keep the LEAF side: self-time attribution (the
-                    # bench/diff signal) must survive truncation, so
+                    # diff mode's signal) must survive truncation, so
                     # the sacrificed frames are the root boilerplate.
                     keys = keys[-self.max_depth:]
                     self._truncated += 1
@@ -404,7 +404,7 @@ def sections_from_snapshots(snapshots: Mapping[Any, Optional[Mapping]]
 
 def flatten_self(root: Mapping[str, Any]) -> Dict[str, int]:
     """Aggregate a trie into {frame key: self samples} — the flat
-    ranking the bench gate and the diff mode judge on."""
+    ranking ``top_frames`` and the diff mode judge on."""
     out: Dict[str, int] = {}
 
     def walk(node: Mapping[str, Any]) -> None:

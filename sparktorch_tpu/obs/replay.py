@@ -9,10 +9,10 @@ story the profiler can't give: *which batch* broke the run, not
 
 A bundle is a ``.json`` meta file plus a sibling ``.npz`` holding the
 pre-step state anchor and the offending batch, leaf by leaf. The
-bundle names a *builder* — ``"module:function"``, e.g. the bench's
-``sparktorch_tpu.bench:_health_replay_builder`` — that reconstructs
-the exact jitted step function and pytree templates in the replaying
-process; the replay then:
+bundle names a *builder* — ``"module:function"``, importable in the
+replaying process, returning ``{"step_fn", "state", "batch"}`` — that
+reconstructs the exact jitted step function and pytree templates
+there; the replay then:
 
 1. rebuilds ``(state, batch)`` from the npz leaves over the builder's
    tree structure,

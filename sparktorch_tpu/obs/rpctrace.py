@@ -238,7 +238,7 @@ class RpcTracer:
         if buffer_size is None:
             buffer_size = int(os.environ.get(BUFFER_ENV, DEFAULT_BUFFER))
         # sample_rate < 0 turns the tracer fully OFF (no root spans at
-        # all — the bench's untraced control leg); 0.0 keeps the SLO
+        # all; only tests pass it: ROADMAP D5); 0.0 keeps the SLO
         # escape hatch armed.
         self.sample_rate = float(sample_rate)
         self.slo_s = float(slo_s)
@@ -289,9 +289,9 @@ class RpcTracer:
             return list(self._ring)
 
     def resize(self, buffer_size: int) -> None:
-        """Grow/shrink the completed-span ring in place (a bench or a
-        soak that must hold every span of a bounded run resizes up
-        front instead of racing eviction)."""
+        """Grow/shrink the completed-span ring in place (a caller
+        that must hold every span of a bounded run resizes up front
+        instead of racing eviction)."""
         with self._lock:
             self._ring = deque(self._ring, maxlen=max(1, int(buffer_size)))
 

@@ -246,7 +246,7 @@ class Span:
 
 class Telemetry:
     """The event bus. One instance per run scope (a trainer invocation,
-    a parameter server, the bench CLI); a process-global default exists
+    a parameter server); a process-global default exists
     for code that doesn't thread one through (:func:`get_telemetry`)."""
 
     def __init__(self, run_id: Optional[str] = None,

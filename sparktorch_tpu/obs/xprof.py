@@ -126,8 +126,8 @@ _HLO_SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 
 def hlo_collective_bytes(hlo_text: str) -> Dict[str, Any]:
     """Static per-family collective RESULT bytes of a compiled HLO
-    module — the partitioner-independent ground truth the bench-moe
-    gate compares layouts with (profiled byte counters don't exist on
+    module — the partitioner-independent ground truth tests/test_moe.py
+    compares layouts with (profiled byte counters don't exist on
     the CPU backend, and wall time alone can't attribute a win to
     fewer bytes moved).
 

@@ -3,7 +3,7 @@ trace-guided mesh auto-tuner.
 
 Submodules import jax at module level (mesh/sharding_rules) or lazily
 (tune's measurement path); this package init re-exports only the
-names the trainers and benches reach for, without forcing the heavy
+names the trainers reach for, without forcing the heavy
 imports on ``import sparktorch_tpu.parallel`` alone.
 """
 

@@ -19,10 +19,9 @@ static cost model alone:
 3. **Measure** the survivors: compile every survivor once (outside
    any capture — a capture containing the multi-second XLA compile
    overflows the profiler buffer), then run INTERLEAVED rounds of a
-   few profiled steps per candidate — the same
-   medians-over-interleaved-repeats discipline the fleet bench uses,
-   because on a cpu-share rig whole measurement windows land in slow
-   scheduler epochs and back-to-back candidate timings swing 10x.
+   few profiled steps per candidate — medians over interleaved
+   repeats, because on a cpu-share rig whole measurement windows land
+   in slow scheduler epochs and back-to-back candidate timings swing.
    Each round's capture is analyzed offline
    (:class:`~sparktorch_tpu.obs.xprof.TraceAnalysis`); candidates are
    scored by the median step wall across all rounds with an
@@ -37,8 +36,8 @@ static cost model alone:
 The winner is a usable fast path, not a report:
 ``make_sharded_train_step(mesh="auto", spec=..., sample_batch=...)``
 runs this search and trains on the chosen mesh
-(:mod:`sparktorch_tpu.train.sharded`), and ``make bench-tune`` gates
-the tuner against an exhaustive measurement of the same space.
+(:mod:`sparktorch_tpu.train.sharded`). tests/test_autotune.py holds
+the search's decisions on scripted measurements; no chip has run it.
 
 CLI::
 
@@ -261,8 +260,8 @@ class WorkloadShape:
     dtype_bytes: int = 4
     # MoE capacity expansion: the dispatch/combine all-to-alls move
     # (tokens x capacity_factor x top_k) capacity slots, not raw
-    # tokens — the a2a byte term scales by both (validated against the
-    # explicit shard_map lowering by `make bench-moe`).
+    # tokens — the a2a byte term scales by both (compared with the
+    # compiled shard_map lowering's bytes in tests/test_moe.py).
     moe_capacity_factor: float = 1.0
     moe_top_k: int = 1
 
@@ -505,8 +504,8 @@ def predict_comm_bytes(config: MeshConfig, shape: WorkloadShape,
         # exchanges (G, e, cap, d) CAPACITY blocks — tokens expanded by
         # capacity_factor x top_k — with each member keeping its own
         # 1/ep slice resident, hence the (ep-1)/ep wire fraction.
-        # Grounded against HLO-measured collective bytes and step wall
-        # by `make bench-moe` (the bench_moe_a2a gates).
+        # Held within 4x of the compiled program's a2a bytes by
+        # tests/test_moe.py (..._fewer_bytes_than_replicate_and_grounds_tuner).
         "ep_all_to_all": (
             shape.n_moe_layers * 2 * ((ep - 1) / ep) * act_dev
             * shape.moe_capacity_factor * shape.moe_top_k
@@ -1382,7 +1381,8 @@ def autotune(
     exceeds ``noise_mult x`` the noise floor (cross-candidate max of
     p75-p25 wall spreads). ``exhaustive=True`` disables pruning and
     the early stop — every legal candidate is measured for all
-    rounds (the ``make bench-tune`` referee mode). ``measure_fn``
+    rounds (the CLI's ``--exhaustive``: a referee for the pruned
+    search). ``measure_fn``
     (same signature as :func:`prepare_candidate`) lets tests pin the
     decision logic without a backend. ``cache=True`` keys the result
     by a (workload dims, rig fingerprint, search space) hash and
@@ -1447,8 +1447,7 @@ def autotune(
             cached.compile_s_total = 0.0
             # Same per-RUN semantics for the wall: the entry stores
             # the original search's wall, but THIS process only paid
-            # the lookup — the bench's warm-vs-cold tune-wall gate
-            # reads exactly this number.
+            # the lookup.
             cached.wall_s = time.perf_counter() - t_start  # lint-obs: ok (artifact stat)
             cached.publish(telemetry)
             if artifact_path:

@@ -584,7 +584,7 @@ class InferenceTier:
                 params_version=dead.params_version)
             # Counted BEFORE the fresh replica is exposed: anything
             # that observes the recovered replica (a waiter polling
-            # alive(), the bench's kill gate) must also see the
+            # alive(), test_tier_chaos_kill_zero_drops) must also see the
             # restart counter — the reverse order races.
             self.telemetry.counter("serve.replica_restarts_total",
                                    labels={"replica": rid})

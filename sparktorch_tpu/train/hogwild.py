@@ -65,7 +65,7 @@ _HTTP_PULL_TIMEOUT = 180.0
 
 def _new_phase_stats() -> dict:
     """Per-transport phase accounting (seconds, bytes, counts) — the
-    raw material for the hogwild budget the bench publishes: where a
+    raw material for the hogwild budget the run summary carries: where a
     worker's wall time actually goes (pull wire, push materialize+wire,
     stop-poll), so ``async_efficiency`` decomposes instead of being one
     unexplained ratio."""
@@ -180,7 +180,7 @@ class HttpTransport:
             host_grads = jax.tree.map(lambda a: np.asarray(a), grads)
         # Serialization counts as materialize, not wire — the same
         # bucketing as BinaryTransport (which encodes before ITS t1),
-        # so the hogwild_wire bench compares like with like.
+        # so the two transports' phase stats compare like with like.
         payload = dill.dumps(host_grads)
         t1 = time.perf_counter()  # lint-obs: ok (phase stats pair)
         st["push_materialize_s"] += t1 - t0

@@ -312,7 +312,7 @@ class _RunObservers:
         # would cost a device sync a chunk, and one-shot kill configs
         # make the distinction irrelevant across resumes.
         _chaos.fire("worker.step", worker=self._rank, step=i)
-        # Poison-batch injection (bench-health drill): the poisoned copy
+        # Poison-batch injection (chaos ``poison_batch_at``): the poisoned copy
         # REPLACES the batch, so the health ledger's replay anchor
         # records exactly what dispatches.
         act = _chaos.fire("data.batch", worker=self._rank, step=i)

@@ -139,9 +139,8 @@ def arm_compile_cache(min_compile_time_s: float = 0.3) -> bool:
     ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses that directory
     and no directory is set in code; otherwise the cache is armed at
     :data:`DEFAULT_COMPILE_CACHE_DIR`. Every entry point that wants a
-    warm second run (``bench.main``, ``chip_smoke.py``, the
-    ``mesh='auto'`` path, the benchmark scripts) calls this and names
-    no path of its own."""
+    warm second run (``chip_smoke.py``, the ``mesh='auto'`` path,
+    ``chipbench``) calls this and names no path of its own."""
     return arm_persistent_cache(DEFAULT_COMPILE_CACHE_DIR,
                                 min_compile_time_s)
 

@@ -1,5 +1,5 @@
 """SPK501 true positive — the PR 10 shipped segfault, minimally: the
-elastic bench read `coord.generation` after the finally-stop had freed
+elastic run read `coord.generation` after the finally-stop had freed
 the native gang state (use-after-free through ctypes)."""
 
 from sparktorch_tpu.native.gang import GangCoordinator

@@ -152,7 +152,7 @@ def test_ep_a2a_byte_model_capacity_scaling():
     lowering: (G, e, cap, d) capacity blocks — tokens expanded by
     capacity_factor x top_k — with a (ep-1)/ep wire fraction. Linear
     in both expansion knobs, zero at ep=1, monotone in ep; grounded
-    against measured HLO bytes by the bench-moe gate."""
+    against the compiled program's bytes in tests/test_moe.py."""
     import dataclasses
 
     base = WorkloadShape(param_bytes=1e6, tp_param_bytes=1e6,
@@ -325,6 +325,43 @@ def test_autotune_prunes_measures_and_ranks():
     )
     # All rounds ran for every measured candidate.
     assert result.measured_steps_total() == 4 * 3 * 3
+
+
+def test_autotune_step_budget_counts_warmup_rounds():
+    """The search never runs more profiled steps than
+    ``measure_top_k x steps x (repeats + warmup_rounds)``, and the
+    count it reports INCLUDES the discarded warmup rounds (they
+    execute; discarding their scores refunds nothing) while the scored
+    total leaves them out."""
+    spec, batch = _fake_spec_and_batch()
+    labels = ["dp8", "fsdp8", "fsdp4xtp2", "dp2xfsdp4", "dp4xfsdp2",
+              "dp4xtp2", "dp2xtp4", "fsdp2xtp4", "dp2xfsdp2xtp2"]
+    walls = {label: (0.010 + 0.001 * i, 0.002)
+             for i, label in enumerate(labels)}
+    steps, repeats, top_k, warmup = 3, 2, 4, 2
+    ran = []
+    prepare = _fake_measure(walls)
+
+    def counting(*args, **kw):
+        runner = prepare(*args, **kw)
+
+        def counted(n):
+            ran.append(n)
+            return runner(n)
+
+        counted.compile_s = runner.compile_s
+        return counted
+
+    result = autotune(spec, batch, list(range(8)), steps=steps,
+                      repeats=repeats, measure_top_k=top_k,
+                      warmup_rounds=warmup, noise_mult=2.0,
+                      axes=GSPMD_AXES, measure_fn=counting,
+                      alpha_bytes=1 << 20)
+    assert result.warmup_rounds == warmup
+    assert result.executed_steps_total == sum(ran) \
+        == top_k * steps * (repeats + warmup)
+    assert result.measured_steps_total() == top_k * steps * repeats
+    assert all(c.measured is None for c in result.pruned())
 
 
 def test_autotune_early_stops_on_noise_floor():
@@ -580,17 +617,26 @@ def test_mesh_auto_end_to_end(tmp_path):
     spec = ModelSpec(module=module, loss="cross_entropy",
                      optimizer="adam", optimizer_params={"lr": 1e-3})
     artifact = str(tmp_path / "tune_result.json")
-    step = make_sharded_train_step(
-        module.apply, spec.loss_fn(), spec.make_optimizer(),
-        mesh="auto", spec=spec, sample_batch=batch,
-        # Pinned alpha: THIS test asserts the predicted ranking
-        # ("dp8 cheapest"), and a measured per-rig alpha must not
-        # decide a deterministic assertion. The probe path has its
-        # own tests below.
-        tune_kwargs={"measure_top_k": 1, "steps": 2, "repeats": 2,
-                     "artifact_path": artifact,
-                     "alpha_bytes": 1 << 20},
-    )
+    from sparktorch_tpu.obs import Telemetry
+    from sparktorch_tpu.obs import goodput as _goodput
+
+    tele = Telemetry(run_id="mesh_auto")
+    with _goodput.GoodputLedger(telemetry=tele).activate():
+        step = make_sharded_train_step(
+            module.apply, spec.loss_fn(), spec.make_optimizer(),
+            mesh="auto", spec=spec, sample_batch=batch,
+            # Pinned alpha: THIS test asserts the predicted ranking
+            # ("dp8 cheapest"), and a measured per-rig alpha must not
+            # decide a deterministic assertion. The probe path has its
+            # own tests below.
+            tune_kwargs={"measure_top_k": 1, "steps": 2, "repeats": 2,
+                         "artifact_path": artifact,
+                         "alpha_bytes": 1 << 20},
+        )
+    # The search's compiles land in an armed ledger, site-labeled.
+    assert tele.counter_value("goodput.compiles_total",
+                              labels={"site": "tune"}) >= 1
+    assert tele.get_section(_goodput.SECTION)["buckets"]["compile"] > 0
     # The auto path hands back the search and the initialized state.
     assert step.tune_result is not None and step.state is not None
     assert step.tune_result.best_label == "dp8"  # cheapest predicted
@@ -751,6 +797,9 @@ def test_tune_cache_hit_skips_search_and_stamps_artifact(
     assert result.cache_hit is True
     assert result.cache_key == key
     assert result.best_label == "dp8"
+    # This process compiled nothing for the search: the warm path's
+    # count starts below any cold search's.
+    assert result.compile_count == 0 and result.compile_s_total == 0.0
     with open(artifact) as f:
         doc = json.load(f)
     assert doc["cache_hit"] is True and doc["cache_key"] == key
@@ -761,8 +810,8 @@ def test_tune_cache_hit_skips_search_and_stamps_artifact(
 def test_scripted_and_exhaustive_searches_never_touch_cache(
         monkeypatch, tmp_path):
     """A measure_fn (scripted test) or exhaustive (referee) run must
-    neither read nor write the cache — a cache entry satisfying the
-    bench's referee would void the gate."""
+    neither read nor write the cache — a cache entry satisfying a
+    referee would void what it referees."""
     from sparktorch_tpu.parallel.tune import TUNE_CACHE_ENV
 
     monkeypatch.setenv(TUNE_CACHE_ENV, str(tmp_path))

@@ -335,7 +335,10 @@ def _elastic_rig(tmp_path, n_parts=12, crashy_ranks=(), sleep=0.04,
                     raise RuntimeError(f"rank{rank} boom")
                 if completed(p):
                     continue
-                tmp = os.path.join(out, p + ".tmp")
+                # A temp name per rank: the controller can hand one
+                # part to two live ranks (ROADMAP D7), and two writers
+                # of one temp name race on the rename.
+                tmp = os.path.join(out, f"{p}.{rank}.tmp")
                 with open(tmp, "w") as f:
                     f.write(f"{rank}:{generation}")
                 os.replace(tmp, os.path.join(out, p + ".done"))
@@ -418,6 +421,48 @@ def test_elastic_coordinator_resize_bumps_real_generation(tmp_path):
         assert coord.world_size == 2
     finally:
         coord.stop()
+
+
+def test_elastic_shrink_then_grow_redistributes_through_coordinator(
+        tmp_path):
+    """Shrink THEN grow in one world, through the native coordinator:
+    its generation and world size follow the controller's, every
+    controller event is generation-tagged, every partition has exactly
+    one output with no torn temp file, and partitions completed in
+    more than one generation — the resizes really moved work."""
+    coord = GangCoordinator(world_size=3, port=0,
+                            heartbeat_timeout_ms=5000)
+    try:
+        ctl, start_fn, completed, work, _ = _elastic_rig(
+            tmp_path, n_parts=24, crashy_ranks=(1,), min_world=1,
+            coordinator=coord)
+        for r in range(3):
+            ctl.add_rank(r, start_fn)
+
+        def grow_after_shrink():
+            while not ctl._stop.is_set():
+                if ctl._resizes["shrink"] >= 1:
+                    ctl.grow(3, start_fn)
+                    return
+                time.sleep(0.01)
+
+        threading.Thread(target=grow_after_shrink, daemon=True).start()
+        summary = ctl.run(poll_interval_s=0.02, deadline_s=60)
+        assert summary["resizes"] == {"shrink": 1, "grow": 1}
+        assert summary["removed"] == [1]
+        assert coord.generation == ctl.generation \
+            == summary["generation"] == 2
+        assert coord.world_size == 3  # ranks 0, 2 and the joined 3
+    finally:
+        coord.stop()
+    kinds = [h["kind"] for h in ctl.history]
+    assert kinds.index("shrink") < kinds.index("grow")
+    assert all("generation" in h for h in ctl.history)
+    out = str(tmp_path / "elastic")
+    assert sorted(os.listdir(out)) == sorted(p + ".done" for p in work)
+    generations = {open(os.path.join(out, p + ".done")).read().split(":")[1]
+                   for p in work}
+    assert len(generations) >= 2, generations
 
 
 def test_native_resize_releases_waiters_and_reregisters():

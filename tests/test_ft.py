@@ -633,20 +633,60 @@ def test_worker_loop_preemption_stops_slowed_worker():
     assert records == []
 
 
-@pytest.mark.slow
-def test_chaos_soak_multi_round_random_schedule():
-    """The chaos SOAK (`make bench-chaos-soak`, shrunk): a seeded
-    random kill/freeze/drop schedule over multiple supervised rounds
-    — every round completes, restart count == injected kills, stall
-    preemptions == injected freezes, record counts exact (no metric
-    double-counting)."""
-    from sparktorch_tpu.bench import bench_hogwild_chaos_soak
+def test_supervisor_preempts_frozen_heartbeat_exactly_once(tmp_path):
+    """A supervised rank whose first attempt goes silent mid-run (it
+    keeps running, its heartbeat stops): the barrier deadline preempts
+    it through its cancel event, the restarted attempt finishes, and
+    the books are exact — one stall preemption, one restart, every
+    rank completed once."""
+    from sparktorch_tpu.ft.policy import BarrierPolicy
+    from sparktorch_tpu.obs.heartbeat import HeartbeatEmitter
 
-    rec = bench_hogwild_chaos_soak(rounds=3, iters=8, freeze_rounds=1,
-                                   worker_steps=40)
-    assert rec["restarts"] == rec["kills"] + rec["freezes"]
-    assert rec["stall_preemptions"] == rec["freezes"]
-    assert rec["records_exact"] is True
+    hb_dir = str(tmp_path / "hb")
+    tele = Telemetry(run_id="freeze")
+    frozen_rank, freeze_at, steps = 1, 3, 40
+    done = {r: 0 for r in range(3)}
+    lock = threading.Lock()
+
+    def make_start(rank):
+        def start(attempt):
+            # Freshen the slot before the handle exists: the frozen
+            # file's age must not re-preempt the restarted attempt.
+            HeartbeatEmitter(hb_dir, rank).beat()
+
+            def target(cancel):
+                emitter = HeartbeatEmitter(hb_dir, rank)
+                frozen = attempt == 0 and rank == frozen_rank
+                for s in range(steps):
+                    if cancel.is_set():
+                        return
+                    if not (frozen and s >= freeze_at):
+                        emitter.notify_step(s)
+                    time.sleep(0.02)
+                with lock:
+                    done[rank] += 1
+                emitter.close()
+
+            return ThreadWorker(f"freeze{rank}", target, pass_cancel=True)
+
+        return start
+
+    policy = FtPolicy(restart=RestartPolicy(max_restarts=2,
+                                            backoff_base_s=0.05),
+                      barrier=BarrierPolicy(deadline_s=0.3), seed=0)
+    sup = Supervisor(policy=policy, telemetry=tele, heartbeat_dir=hb_dir,
+                     name="freeze")
+    for rank in range(3):
+        sup.add(str(rank), make_start(rank), rank=rank)
+    sup.run(deadline_s=60)
+    assert done == {0: 1, 1: 1, 2: 1}
+    counters = tele.snapshot()["counters"]
+    assert {k: v for k, v in counters.items()
+            if k.startswith("ft_stall_preemptions_total")} == {
+        f"ft_stall_preemptions_total{{worker={frozen_rank}}}": 1}
+    assert {k: v for k, v in counters.items()
+            if k.startswith("ft_restarts_total")} == {
+        f"ft_restarts_total{{worker={frozen_rank}}}": 1}
 
 
 def test_sync_chaos_kill_resumes_from_latest_checkpoint(tmp_path):

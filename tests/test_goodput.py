@@ -271,7 +271,10 @@ def _elastic_rig(tmp_path, crashy_ranks=(), n_parts=8):
                     raise RuntimeError(f"rank{rank} boom")
                 if completed(p):
                     continue
-                tmp = os.path.join(out, p + ".tmp")
+                # A temp name per rank: the controller can hand one
+                # part to two live ranks (ROADMAP D7), and two writers
+                # of one temp name race on the rename.
+                tmp = os.path.join(out, f"{p}.{rank}.tmp")
                 with open(tmp, "w") as f:
                     f.write(f"{rank}:{generation}")
                 os.replace(tmp, os.path.join(out, p + ".done"))
@@ -382,6 +385,42 @@ def test_sharded_run_compile_detection():
     counters = tele.snapshot()["counters"]
     assert counters.get(
         "goodput.compiles_total{site=train_sharded}") == doc["compiles"]
+
+
+def test_streaming_run_attributes_compile_checkpoint_and_data_wait(
+        tmp_path):
+    """A streaming run with checkpointing under an ambient ledger: the
+    trainer's own spans reach the compile, checkpoint and data_wait
+    buckets (counted, not timed), the steps are counted, nothing is
+    invented in the downtime buckets, and the ledger stays MECE within
+    its own tolerance."""
+    from sparktorch_tpu.models import MnistMLP
+    from sparktorch_tpu.train.sync import train_distributed_streaming
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(256, 16)).astype(np.float32)
+    y = rng.integers(0, 10, (256,)).astype(np.int32)
+    spec = ModelSpec(module=MnistMLP(), loss="cross_entropy",
+                     optimizer="sgd", optimizer_params={"lr": 1e-2},
+                     input_shape=(16,))
+    tele = Telemetry(run_id="gp_streaming")
+    led = gp.GoodputLedger(telemetry=tele, rank=0)
+    with led.activate():
+        train_distributed_streaming(
+            spec, (x, y), chunk_rows=128, epochs=2, mini_batch=32,
+            checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=4,
+            telemetry=tele)
+    doc = tele.get_section(gp.SECTION)
+    assert doc["compiles"] >= 1 and doc["n_steps"] > 0
+    for bucket in ("compile", "checkpoint", "data_wait"):
+        assert doc["counts"].get(bucket, 0) >= 1, (bucket, doc["counts"])
+        assert doc["buckets"][bucket] > 0.0
+    assert doc["buckets"]["restart_downtime"] == 0.0
+    assert doc["buckets"]["resize_downtime"] == 0.0
+    assert abs(sum(doc["buckets"].values()) - doc["wall_s"]) \
+        <= 0.02 * doc["wall_s"]
+    assert doc["overattributed_s"] <= 0.02 * doc["wall_s"]
 
 
 def test_checkpoint_manager_feeds_checkpoint_bucket(tmp_path):

@@ -194,62 +194,3 @@ def test_parquet_stream_skip_and_limit_windows(tmp_path, trained):
     parts = [window(0, 190), window(190, 190), window(380, None)]
     np.testing.assert_allclose(np.concatenate(parts), want, rtol=1e-5,
                                atol=1e-6)
-
-
-def test_stream_stall_watchdog_loop():
-    """The 1M-runner's stall watchdog (benchmarks/): fires on_stall
-    only when fenced progress freezes past the timeout WHILE
-    streaming; any progress or an inactive stream resets the timer."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "stream_1m", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "benchmarks", "stream_inference_1m.py",
-        ),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-
-    def run(fenced_seq, streaming_seq, timeout_s=30.0):
-        """Drive the loop with scripted fenced/streaming values, one
-        per 10s simulated tick; returns ticks-until-stall or None."""
-        t = [0.0]
-        i = [0]
-        fired = []
-
-        def clock():
-            return t[0]
-
-        def sleep(s):
-            t[0] += s
-            i[0] += 1
-            if i[0] >= len(fenced_seq):
-                raise StopIteration  # script exhausted, no stall
-
-        try:
-            mod.stall_watchdog_loop(
-                get_fenced=lambda: fenced_seq[min(i[0], len(fenced_seq) - 1)],
-                is_streaming=lambda: streaming_seq[
-                    min(i[0], len(streaming_seq) - 1)
-                ],
-                timeout_s=timeout_s,
-                on_stall=lambda: fired.append(t[0]),
-                sleep_s=10.0,
-                clock=clock,
-                sleep=sleep,
-            )
-        except StopIteration:
-            pass
-        return fired
-
-    # Frozen fence while streaming: fires once after the timeout.
-    assert run([5] * 8, [True] * 8) != []
-    # Progressing fence: never fires.
-    assert run(list(range(8)), [True] * 8) == []
-    # Frozen but NOT streaming (compile/dataset gen): never fires.
-    assert run([5] * 8, [False] * 8) == []
-    # Streaming resumes after an idle stretch: timer restarts from the
-    # resume point, so a short freeze doesn't fire.
-    assert run([5] * 8, [False] * 5 + [True] * 3) == []

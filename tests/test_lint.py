@@ -226,10 +226,10 @@ def test_rule_selectors():
 
 def test_full_tree_clean_and_under_wall_gate():
     """The merge contract: zero unexplained findings over the whole
-    package. The real <5s wall gate lives in `make bench-lint`
-    (--gate-wall 5, record retained in benchmarks/); here only a
-    generous pathological-regression backstop so a load spike on a
-    shared rig can't flake the unit suite."""
+    package. The wall bound here is only a generous
+    pathological-regression backstop (the CLI's --gate-wall is the
+    caller's to set), so a load spike on a shared rig can't flake the
+    unit suite."""
     t0 = time.perf_counter()
     findings, n_files = run_lint([PKG_DIR], ALL_RULES)
     wall = time.perf_counter() - t0

@@ -256,6 +256,34 @@ def test_moe_ep2_hlo_no_token_all_gather():
     assert stats["bytes"]["all_to_all"] > 0, stats
 
 
+def test_moe_a2a_moves_fewer_bytes_than_replicate_and_grounds_tuner():
+    """On one dp4 x ep2 mesh the compiled a2a step moves strictly
+    fewer collective bytes than the token-replication layout (the
+    ``auto`` fallback), and the tuner's ``ep_all_to_all`` term stays
+    within 4x of what the compiled a2a program ships: the model prices
+    the FORWARD dispatch + combine pair fleet-wide, the HLO is per
+    device and holds the backward pair too."""
+    from sparktorch_tpu.obs.xprof import hlo_collective_bytes
+    from sparktorch_tpu.parallel.tune import (
+        predict_comm_bytes,
+        transformer_workload,
+    )
+
+    rep = hlo_collective_bytes(_compiled_ep2_hlo(
+        moe_group_size=16, moe_ep_dispatch="replicate"))
+    a2a = hlo_collective_bytes(_compiled_ep2_hlo(
+        moe_group_size=16, moe_ep_dispatch="a2a"))
+    assert 0 < a2a["total_bytes"] < rep["total_bytes"], (a2a, rep)
+
+    n_dev = jax.device_count()
+    shape = transformer_workload(_moe_cfg(moe_group_size=16),
+                                 global_batch=8)
+    predicted = predict_comm_bytes(MeshConfig(ep=2), shape, n_dev)
+    shipped_fwd = a2a["bytes"]["all_to_all"] * n_dev / 2
+    assert 0.25 <= predicted["ep_all_to_all"] / shipped_fwd <= 4.0, (
+        predicted["ep_all_to_all"], shipped_fwd)
+
+
 def test_moe_drop_accounting_exact_across_ep():
     """Capacity-overflow drop accounting must be EXACT under expert
     parallelism: at a starving capacity factor, the global (dropped,

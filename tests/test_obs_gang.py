@@ -530,152 +530,59 @@ def test_sections_ride_snapshot_dump_and_pickle(tmp_path):
     assert "sections" not in tele.snapshot()
 
 
-def test_comm_drift_gate_fires_and_skips(monkeypatch):
-    """The armed comm-fraction drift gate: no prior record -> clean
-    skip; within tolerance -> checked record with deltas; a lost
-    overlap or grown comm fraction beyond tolerance -> AssertionError
-    (fails `make bench-trace`)."""
-    from sparktorch_tpu import bench as bench_mod
+def test_gang_obs_bench_gate_passes(tmp_path):
+    """Two rank exporters behind the collector, one rank's capture
+    truncated: the merged scrape reconciles with each rank's own
+    scrape series by series, the gang budget with the per-rank
+    analyses (families SUM, step walls MAX, skew = max - min), and the
+    truncation trips once, on the rank it happened to."""
+    run_id = mint_run_id("gang-obs")
+    n_ranks = 2
+    exps = [_rank_exporter(r, run_id, str(tmp_path / "hb"))
+            for r in range(n_ranks)]
+    # Rank 1's capture lost markers: 4 steps annotated, 2 in the trace.
+    trace_dir = tmp_path / "trace_r1"
+    trace_dir.mkdir()
+    with gzip.open(trace_dir / "host0.trace.json.gz", "wt") as f:
+        json.dump(_rank_trace(2.0), f)
+    analyze_and_publish(str(trace_dir), telemetry=exps[1].telemetry,
+                        expected_steps=4)
+    collector = FleetCollector({r: e.url for r, e in enumerate(exps)},
+                               run_id=run_id, poll_interval_s=0,
+                               ).start(poll_loop=False)
+    try:
+        collector.poll()
+        merged = parse_prometheus(scrape_text(collector.url + "/metrics"))
+        for r, exp in enumerate(exps):
+            own = parse_prometheus(scrape_text(exp.url + "/metrics"))
+            labels = f'{{host="127.0.0.1",rank="{r}"}}'
+            assert own["sparktorch_gangtest_ticks"] == float(r + 1)
+            assert merged["sparktorch_gangtest_ticks" + labels] == r + 1
+        # Every rank-originated series carries a rank label.
+        snap = scrape_json(collector.url + "/telemetry")
+        assert not [k for section in ("counters", "gauges", "histograms")
+                    for k in snap.get(section, {})
+                    if not k.startswith(("collector.", "xprof.gang_"))
+                    and "rank=" not in k]
+        trunc = {k: v for k, v in snap["counters"].items()
+                 if k.startswith("xprof.capture_truncated_total")}
+        assert list(trunc.values()) == [1.0]
+        assert "rank=1" in next(iter(trunc))
 
-    monkeypatch.setattr(bench_mod, "_prior_comm_budget",
-                        lambda cfg, **kw: None)
-    rec = bench_mod._check_comm_drift("sharded_trace", 0.5, 0.6)
-    assert rec["status"] == "no_prior_record"
-
-    prior = {"config": "sharded_trace", "comm_fraction": 0.5,
-             "overlap_fraction": 0.6, "ts": "2026-07-01T00:00:00"}
-    monkeypatch.setattr(bench_mod, "_prior_comm_budget",
-                        lambda cfg, **kw: prior)
-    rec = bench_mod._check_comm_drift("sharded_trace", 0.55, 0.5)
-    assert rec["status"] == "checked"
-    assert rec["comm_fraction_delta"] == pytest.approx(0.05)
-    assert rec["overlap_fraction_delta"] == pytest.approx(-0.1)
-    # Lost overlap beyond tolerance: the regression the gate exists for.
-    with pytest.raises(AssertionError, match="overlap_fraction"):
-        bench_mod._check_comm_drift("sharded_trace", 0.5, 0.3)
-    # Comm fraction growing past tolerance fails too.
-    with pytest.raises(AssertionError, match="comm_fraction"):
-        bench_mod._check_comm_drift("sharded_trace", 0.8, 0.6)
-    # Tolerance is operator-tunable via the env knob.
-    monkeypatch.setenv("SPARKTORCH_TPU_COMM_DRIFT_TOL", "0.5")
-    assert bench_mod._check_comm_drift(
-        "sharded_trace", 0.8, 0.3)["status"] == "checked"
-
-
-def test_gang_drift_gate_fires_and_skips(monkeypatch):
-    """The armed GANG-level drift gate (PR 5 follow-up): no prior gang
-    record -> clean skip; within tolerance -> checked record with
-    deltas; cross-rank step skew growing past the relative limit or
-    gang comm fraction past the absolute tolerance -> AssertionError
-    (fails `make bench-trace`, which runs the gang_obs config)."""
-    from sparktorch_tpu import bench as bench_mod
-
-    monkeypatch.setattr(bench_mod, "_prior_gang_budget", lambda cfg: None)
-    rec = bench_mod._check_gang_drift("gang_obs", 0.2, 0.5)
-    assert rec["status"] == "no_prior_record"
-
-    prior = {"config": "gang_obs", "gang_comm_fraction": 0.5,
-             "gang_step_skew_s": 0.2, "ts": "2026-07-01T00:00:00"}
-    monkeypatch.setattr(bench_mod, "_prior_gang_budget", lambda cfg: prior)
-    rec = bench_mod._check_gang_drift("gang_obs", 0.25, 0.55)
-    assert rec["status"] == "checked"
-    assert rec["gang_step_skew_delta_s"] == pytest.approx(0.05)
-    assert rec["gang_comm_fraction_delta"] == pytest.approx(0.05)
-    # A straggler: skew grows past prior * 1.5 + 50ms.
-    with pytest.raises(AssertionError, match="step skew"):
-        bench_mod._check_gang_drift("gang_obs", 0.40, 0.5)
-    # Gang comm fraction growing past tolerance fails too.
-    with pytest.raises(AssertionError, match="comm_fraction"):
-        bench_mod._check_gang_drift("gang_obs", 0.2, 0.8)
-    # Both tolerances are operator-tunable via env knobs.
-    monkeypatch.setenv("SPARKTORCH_TPU_GANG_SKEW_TOL", "2.0")
-    monkeypatch.setenv("SPARKTORCH_TPU_COMM_DRIFT_TOL", "0.5")
-    assert bench_mod._check_gang_drift(
-        "gang_obs", 0.40, 0.8)["status"] == "checked"
-    # Microsecond-scale synthetic skews ride inside the 50ms absolute
-    # floor — rounding jitter alone can never trip the gate.
-    monkeypatch.delenv("SPARKTORCH_TPU_GANG_SKEW_TOL", raising=False)
-    prior_tiny = {"config": "gang_obs", "gang_comm_fraction": 0.5,
-                  "gang_step_skew_s": 0.0005}
-    monkeypatch.setattr(bench_mod, "_prior_gang_budget",
-                        lambda cfg: prior_tiny)
-    assert bench_mod._check_gang_drift(
-        "gang_obs", 0.0012, 0.5)["status"] == "checked"
-
-
-def test_prior_gang_budget_scans_round_artifacts(tmp_path):
-    """_prior_gang_budget wants records carrying a MERGED gang budget
-    (gang_comm_fraction) — per-rank comm records don't count."""
-    from sparktorch_tpu import bench as bench_mod
-
-    root = tmp_path
-    (root / "benchmarks").mkdir()
-    (root / "benchmarks" / "log.jsonl").write_text(
-        json.dumps({"config": "gang_obs", "comm_fraction": 0.4}) + "\n"
-        + json.dumps({"config": "gang_obs", "gang_comm_fraction": 0.33,
-                      "gang_step_skew_s": 0.001,
-                      "ts": "2026-08-01T00:00:00"}) + "\n")
-    prior = bench_mod._prior_gang_budget("gang_obs", root=str(root))
-    assert prior is not None and prior["gang_comm_fraction"] == 0.33
-    # A per-rank record alone is not a gang prior.
-    assert bench_mod._prior_gang_budget("sharded_trace",
-                                        root=str(root)) is None
-
-
-def test_prior_comm_budget_scans_round_artifacts(tmp_path):
-    """_prior_comm_budget reads the retained round artifacts: BENCH
-    json (parsed dict or list) and benchmarks/*.jsonl, newest wins;
-    torn files never block the bench."""
-    from sparktorch_tpu import bench as bench_mod
-
-    root = tmp_path
-    (root / "benchmarks").mkdir()
-    (root / "BENCH_r01.json").write_text(json.dumps({
-        "parsed": [{"config": "moe_lm", "comm_fraction": 0.30,
-                    "overlap_fraction": 0.5}],
-    }))
-    (root / "BENCH_r02.json").write_text("{torn")
-    (root / "benchmarks" / "bench_r02_tpu.jsonl").write_text(
-        json.dumps({"config": "moe_lm", "comm_fraction": 0.42,
-                    "overlap_fraction": 0.6,
-                    "ts": "2026-08-01T00:00:00"}) + "\n"
-        + json.dumps({"config": "other", "comm_fraction": 0.9}) + "\n")
-    prior = bench_mod._prior_comm_budget("moe_lm", root=str(root))
-    assert prior is not None and prior["comm_fraction"] == 0.42
-    assert bench_mod._prior_comm_budget("sharded_trace",
-                                        root=str(root)) is None
-    # Recency is the record's TIMESTAMP (round number as tiebreak),
-    # never the filename: a newer record in an uppercase BENCH_r*.json
-    # must beat an older lowercase benchmarks/*.jsonl one.
-    (root / "BENCH_r03.json").write_text(json.dumps({
-        "parsed": {"config": "moe_lm", "comm_fraction": 0.55,
-                   "overlap_fraction": 0.7, "ts": "2026-08-02T00:00:00"},
-    }))
-    prior = bench_mod._prior_comm_budget("moe_lm", root=str(root))
-    assert prior["comm_fraction"] == 0.55
-    # mesh= restricts the scan to SAME-LAYOUT priors: the newest
-    # record under another mesh is skipped in favor of an older
-    # matching one; mesh-less (pre-knob) records always qualify.
-    (root / "benchmarks" / "meshed.jsonl").write_text(
-        json.dumps({"config": "moe_lm", "comm_fraction": 0.10,
-                    "mesh": "fsdp8",
-                    "ts": "2026-08-03T00:00:00"}) + "\n")
-    prior = bench_mod._prior_comm_budget("moe_lm", root=str(root),
-                                         mesh="dp4xtp2")
-    assert prior["comm_fraction"] == 0.55   # fsdp8 record skipped
-    prior = bench_mod._prior_comm_budget("moe_lm", root=str(root),
-                                         mesh="fsdp8")
-    assert prior["comm_fraction"] == 0.10   # matching mesh wins
-
-
-def test_gang_obs_bench_gate_passes():
-    """The `make bench-gang-obs` gate, run in-process (2 ranks to keep
-    it quick): merged-scrape reconciliation, gang-budget reconciliation,
-    and the seeded truncation trip are all asserted inside."""
-    from sparktorch_tpu.bench import bench_gang_obs
-
-    rec = bench_gang_obs(n_ranks=2)
-    assert rec["n_ranks"] == 2
-    assert rec["scrape_reconciled"] is True
-    assert rec["truncation_trips"] == 1
-    assert rec["gang_step_skew_s"] > 0
+        xp = scrape_json(collector.url + "/gang")["xprof"]
+        assert xp["n_ranks"] == n_ranks
+        analyses = [analyze_trace(_rank_trace(1.0 + r))
+                    for r in range(n_ranks)]
+        for fam in analyses[0].family_s():
+            assert xp["collective_s"][fam] == pytest.approx(
+                sum(a.family_s()[fam] for a in analyses), abs=1e-9)
+        for i, step in enumerate(xp["steps"]):
+            walls = [a.steps[i].wall_s for a in analyses]
+            assert step["wall_s"] == pytest.approx(max(walls), abs=1e-9)
+            assert step["skew_s"] == pytest.approx(
+                max(walls) - min(walls), abs=1e-9)
+        assert xp["step_skew_s"] > 0
+    finally:
+        collector.stop()
+        for e in exps:
+            e.stop()

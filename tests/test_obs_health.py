@@ -8,6 +8,7 @@ collector/timeline surfaces (``GET /health``, ``timeline --health``,
 """
 
 import json
+import os
 import sys
 import threading
 import types
@@ -356,10 +357,99 @@ def test_replay_bundle_roundtrip_is_bitwise(tmp_path, capsys):
     assert out["compared"]["loss"]["recorded_bits"] == \
         out["compared"]["loss"]["replayed_bits"]
 
-    # The CLI contract bench-health drills in a fresh process.
+    # The CLI contract: exit 0 and the verdict line.
     rc = replay_mod.main([meta_path])
     cap = capsys.readouterr().out
     assert rc == 0 and "bitwise reproduction" in cap
+
+
+def _install_sync_step_builder(n_features, rows):
+    """The replay builder of the drill below: the jitted step
+    ``train_distributed(steps_per_call=1)`` trains with (same spec,
+    mesh and optimizer) plus state / batch TEMPLATES, importable as
+    ``module:function`` the way a bundle's meta names it."""
+    mod = types.ModuleType("_sparktorch_health_sync")
+
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        from sparktorch_tpu.models import Net
+        from sparktorch_tpu.parallel.mesh import build_mesh
+        from sparktorch_tpu.train.step import (
+            create_train_state,
+            make_train_step,
+        )
+        from sparktorch_tpu.utils.data import DataBatch
+        from sparktorch_tpu.utils.serde import ModelSpec
+
+        spec = ModelSpec(module=Net(), loss="mse", optimizer="adam",
+                         optimizer_params={"lr": 1e-2},
+                         input_shape=(n_features,))
+        tx = spec.make_optimizer()
+        state = create_train_state(
+            spec, jax.random.key(0),
+            sample_x=jnp.zeros((1, n_features), jnp.float32), tx=tx)
+        step_fn = make_train_step(spec.make_module().apply,
+                                  spec.loss_fn(), tx, build_mesh())
+        batch = DataBatch(x=jnp.zeros((rows, n_features), jnp.float32),
+                          y=jnp.zeros((rows,), jnp.float32),
+                          w=jnp.ones((rows,), jnp.float32))
+        return {"step_fn": step_fn, "state": state, "batch": batch}
+
+    mod.build = build
+    sys.modules["_sparktorch_health_sync"] = mod
+    return "_sparktorch_health_sync:build"
+
+
+def test_sync_trainer_poison_drill_names_the_step_and_replays_bitwise(
+        tmp_path):
+    """A seeded poison batch on a real ``train_distributed`` run: the
+    first anomaly is ``nonfinite`` AT the poisoned step, seen within
+    two steps of the delayed fetch, with dotted parameter names in the
+    gradient table; the anchor re-armed on the poisoned batch, so the
+    bundle replays ONE step, bit for bit."""
+    from sparktorch_tpu.ft import ChaosConfig, inject
+    from sparktorch_tpu.models import Net
+    from sparktorch_tpu.obs import replay as replay_mod
+    from sparktorch_tpu.train.sync import train_distributed
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    n_features, rows, poison_step, iters = 10, 64, 4, 8
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(rows, n_features)).astype(np.float32)
+    y = rng.normal(size=(rows,)).astype(np.float32)
+    spec = ModelSpec(module=Net(), loss="mse", optimizer="adam",
+                     optimizer_params={"lr": 1e-2},
+                     input_shape=(n_features,))
+    tele = Telemetry(run_id="health-drill")
+    cfg = HealthConfig(
+        warmup_steps=3, replay_dir=str(tmp_path),
+        replay_builder=_install_sync_step_builder(n_features, rows))
+    prev = health_mod.install(None)
+    try:
+        hl = health_mod.ensure(tele, rank=0, config=cfg)
+        with inject(ChaosConfig(poison_batch_at={0: poison_step}),
+                    telemetry=tele):
+            train_distributed(spec, x, labels=y, iters=iters, seed=0,
+                              steps_per_call=1, telemetry=tele)
+        doc = hl.snapshot()
+    finally:
+        health_mod.install(prev)
+
+    first = doc["anomalies"][0]
+    assert (first["akind"], first["step"]) == ("nonfinite", poison_step)
+    assert 0 <= first["detect_lag"] - cfg.fetch_lag <= 2
+    assert any("." in str(k) for k, _ in doc["top_grad_leaves"])
+
+    (meta_path,) = [b for b in doc["replay"]["bundles"]
+                    if os.path.basename(b)
+                    == f"replay_step{poison_step:06d}_r0.json"]
+    with open(meta_path) as f:
+        meta = json.load(f)
+    assert meta["anchor_step"] == poison_step
+    out = replay_mod.replay_bundle(meta_path)
+    assert out["match"] is True and out["steps_run"] == 1
 
 
 def test_replay_checksum_guards_anchor_integrity(tmp_path):
@@ -403,7 +493,7 @@ def test_ensure_reuses_bus_scoped_ledger_and_env_gate(monkeypatch):
         tele = Telemetry(run_id="health-ensure")
         a = health_mod.ensure(tele, rank=0)
         b = health_mod.ensure(tele)
-        assert a is b  # same bus -> same ledger (bench installs, trainer reuses)
+        assert a is b  # same bus -> same ledger (a caller installs, the trainer reuses)
         other = health_mod.ensure(Telemetry(run_id="health-ensure-2"))
         assert other is not a  # new bus -> fresh EWMAs
         monkeypatch.setenv(health_mod.ENV_GATE, "0")
@@ -465,3 +555,29 @@ def test_collector_serves_health_and_timeline_renders(tmp_path):
     stop_ev.set()
     lines = list(timeline_mod.follow(sink, poll_s=0.0, stop=stop_ev))
     assert any("health.run" in ln for ln in lines)
+
+
+def test_postmortem_bundle_carries_health_at_death(tmp_path):
+    """A run that dies after a NaN: the postmortem bundle answers
+    "was the model healthy" beside "why did it die", rank-tagged."""
+    import contextlib
+    import io
+
+    from sparktorch_tpu.obs import timeline as timeline_mod
+    from sparktorch_tpu.obs.blackbox import collect_postmortem
+
+    tele = Telemetry(run_id="health-pm")
+    hl = _ledger(tele=tele, fetch_lag=0, warmup_steps=2)
+    for _ in range(3):
+        hl.note_step(host={"loss": 1.0, "grad_norm": 0.5})
+    hl.note_step(host={"loss": float("nan")})
+    hl.flush()
+    pm_path = collect_postmortem(str(tmp_path), "health test death",
+                                 telemetry=tele)
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        rc = timeline_mod.main(["--postmortem", pm_path])
+    out = buf.getvalue()
+    assert rc == 0
+    assert "model health at death" in out and "nonfinite" in out
+    assert "rank 0" in out

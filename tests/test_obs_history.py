@@ -329,6 +329,89 @@ def test_history_http_golden_against_hand_computed():
         exp.stop()
 
 
+def test_slow_shard_fires_hot_shard_rule_once_and_control_stays_silent():
+    """Against a live 2-shard fleet, a seeded slow shard breaches the
+    sustained p99 rule on the CLIENT hop series
+    (``sharded.shard_pull_latency_s``: the injected delay lands before
+    the serve handler's clock, so the server-side series cannot see
+    it): one episode, fired within the rule's window counted in
+    sweeps, shown in ``/gang``. The identical loop with no chaos fires
+    nothing."""
+    import contextlib
+
+    import jax
+    import numpy as np
+
+    from sparktorch_tpu import serialize_torch_obj
+    from sparktorch_tpu.ft import ChaosConfig, inject
+    from sparktorch_tpu.models import ClassificationNet
+    from sparktorch_tpu.net.sharded import ShardedTransport
+    from sparktorch_tpu.obs import scrape_json
+    from sparktorch_tpu.serve.fleet import ParamServerFleet
+
+    payload = serialize_torch_obj(
+        ClassificationNet(n_classes=2), criterion="cross_entropy",
+        optimizer="sgd", optimizer_params={"lr": 1e-2}, input_shape=(10,))
+    n_pulls, for_sweeps, delay_s = 4, 2, 0.3
+
+    def leg(chaos):
+        tele = Telemetry(run_id="hot_shard")
+        fleet = ParamServerFleet(payload, n_shards=2,
+                                 telemetry=tele).start()
+        slow = sorted(fleet.urls())[1]
+        rules = [AlertRule(name="hot_shard_p99",
+                           metric="sharded.shard_pull_latency_s",
+                           labels={"shard": str(slow)}, kind="sustained",
+                           field="p99", op=">", threshold=delay_s / 2,
+                           for_sweeps=for_sweeps)]
+        collector = FleetCollector.for_fleet(fleet, poll_interval_s=0,
+                                             alert_rules=rules)
+        collector.start(poll_loop=False)
+        first_breach = fired = None
+        try:
+            t = ShardedTransport(fleet, telemetry=tele)
+            zeros = jax.tree.map(lambda a: np.zeros_like(np.asarray(a)),
+                                 fleet.assemble())
+            have = -1
+            ctx = (inject(ChaosConfig(seed=7,
+                                      slow_shard_s={slow: delay_s}),
+                          telemetry=tele)
+                   if chaos else contextlib.nullcontext())
+            with ctx:
+                for sweep in range(n_pulls):
+                    t.push(zeros)
+                    fleet.drain()
+                    snap = t.pull(have)
+                    have = snap[0] if snap is not None else have
+                    collector.poll()
+                    state = collector.alerts.doc()["rules"]["hot_shard_p99"]
+                    if first_breach is None and state["streak"] > 0:
+                        first_breach = sweep
+                    if fired is None and state["state"] == "firing":
+                        fired = sweep
+            t.close()
+            gang = scrape_json(collector.url + "/gang")
+            rate = scrape_json(collector.url + "/history"
+                               "?name=collector.scrapes_total&query=rate")
+            return (collector.alerts.doc()["rules"]["hot_shard_p99"],
+                    gang["alerts"], first_breach, fired, rate)
+        finally:
+            collector.stop()
+            fleet.stop()
+
+    rule, gang_alerts, first_breach, fired, rate = leg(chaos=False)
+    assert rule["episodes"] == 0 and not gang_alerts.get("active")
+    assert first_breach is None and fired is None
+    assert rate["value"] is not None
+
+    rule, gang_alerts, first_breach, fired, rate = leg(chaos=True)
+    assert rule["episodes"] == 1 and rule["state"] == "firing"
+    assert first_breach is not None and fired is not None
+    assert fired - first_breach <= for_sweeps + 1
+    assert gang_alerts["active"] == ["hot_shard_p99"]
+    assert rate["value"] is not None
+
+
 def test_collector_fallback_serves_history_from_peer_sink(tmp_path):
     """HA tail mode for /history: a secondary that has NEVER scraped
     reconstructs windowed queries from the primary's JSONL sink —
@@ -723,6 +806,45 @@ def test_elastic_controller_consumes_alerts_as_scale_signals(tmp_path):
     assert "scale_signal_cleared" in [e["kind"] for e in ctl.history]
 
 
+def test_elastic_controller_death_bundle_holds_restart_transition(
+        tmp_path):
+    """A member that dies once: the controller writes ONE postmortem
+    bundle for the death, and the bundle's window holds the
+    controller's own ``restart_scheduled`` transition (a dead rank's
+    last spans reach a bundle through the collector's last-good ring,
+    ``test_postmortem_collects_dead_ranks_last_good_ring``)."""
+    from sparktorch_tpu.ctl import ElasticController
+    from sparktorch_tpu.ft import FtPolicy, RestartPolicy
+    from sparktorch_tpu.ft.supervisor import ThreadWorker
+
+    tele = Telemetry(run_id="ctl_pm")
+    done = []
+
+    def start_fn(rank, attempt, generation, assignment):
+        def run():
+            if attempt == 0:
+                raise RuntimeError("first attempt dies")
+            done.extend(assignment)
+
+        return ThreadWorker(f"rank{rank}-a{attempt}", run)
+
+    policy = FtPolicy(restart=RestartPolicy(max_restarts=2,
+                                            backoff_base_s=0.01,
+                                            backoff_max_s=0.05), seed=0)
+    ctl = ElasticController(["p0", "p1"], lambda p: p in done,
+                            policy=policy, telemetry=tele,
+                            postmortem_dir=str(tmp_path))
+    ctl.add_rank(0, start_fn)
+    summary = ctl.run(poll_interval_s=0.01, deadline_s=30)
+    assert summary["restarts"] == {"0": 1} and sorted(done) == ["p0", "p1"]
+    (bundle,) = [f for f in os.listdir(tmp_path)
+                 if f.startswith("postmortem_")]
+    doc = read_postmortem(str(tmp_path / bundle))
+    assert "first attempt dies" in doc["reason"]
+    kinds = {str(e.get("kind")) for e in doc["events"]}
+    assert kinds & {"ctl.restart_scheduled", "restart_scheduled"}, kinds
+
+
 def test_supervisor_writes_postmortem_on_death(tmp_path):
     from sparktorch_tpu.ft import FtPolicy, RestartPolicy
     from sparktorch_tpu.ft.supervisor import Supervisor, ThreadWorker
@@ -849,29 +971,9 @@ def test_collector_sink_carries_alert_records_for_follow(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# wall_ts + bench plumbing
+# wall_ts
 # ---------------------------------------------------------------------------
 
 
 def test_wall_ts_is_epoch_seconds():
     assert abs(wall_ts() - time.time()) < 5.0
-
-
-def test_prior_window_median_of_newest_k(tmp_path):
-    from sparktorch_tpu.bench import _prior_record, _prior_window
-
-    bench_dir = tmp_path / "benchmarks"
-    bench_dir.mkdir()
-    rows = [{"config": "obs_history", "sweep_on_ms": v,
-             "ts": f"2026-01-0{i + 1}T00:00:00"}
-            for i, v in enumerate([10.0, 30.0, 20.0, 40.0])]
-    with open(bench_dir / "bench_r09_obs.jsonl", "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-    root = str(tmp_path)
-    newest = _prior_record("obs_history", "sweep_on_ms", root=root)
-    assert newest["sweep_on_ms"] == 40.0
-    win = _prior_window("obs_history", "sweep_on_ms", k=3, root=root)
-    assert win["n"] == 3
-    assert win["median"] == 30.0  # median of the newest 3 (30, 20, 40)
-    assert _prior_window("nope", "sweep_on_ms", root=root) is None

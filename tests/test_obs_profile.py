@@ -103,7 +103,7 @@ def _hot_spin(release):
 
 
 def test_sample_once_names_hot_function_in_its_bucket():
-    """The bench-profile acceptance in unit form: a busy-loop inside a
+    """The sampler's acceptance in unit form: a busy-loop inside a
     compute LedgerSpan must surface as the top self-time frame of the
     compute bucket, with the overwhelming share of its samples."""
     release = threading.Event()

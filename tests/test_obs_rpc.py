@@ -299,7 +299,8 @@ def test_single_server_full_vertical(clf_payload):
         pull_names = names(trees["pull"]["root"], [])
         assert "serve" in pull_names and "render" in pull_names
         # Cross-pipeline sanity: the serve span and the request both
-        # happened (the bench gate pins the p50 reconciliation).
+        # happened (span and histogram COUNTS reconcile per shard in
+        # test_sharded_pulls_stitch_one_tree_each_...).
         assert trees["push"]["wall_s"] > 0
         t.close()
     finally:
@@ -426,6 +427,63 @@ def test_slow_shard_named_critical_and_collector_stitch(clf_payload):
     finally:
         if collector is not None:
             collector.stop()
+        fleet.stop()
+
+
+def test_sharded_pulls_stitch_one_tree_each_and_match_wire_histogram(
+        clf_payload):
+    """Every sampled sharded pull stitches to exactly ONE tree, every
+    shard's serve hop is in it, and the serve spans are the same
+    request population the per-shard ``wire_latency_s`` histogram
+    counted — two pipelines, one set of requests."""
+    from sparktorch_tpu.net.sharded import ShardedTransport
+    from sparktorch_tpu.serve.fleet import ParamServerFleet
+
+    n_shards, n_pulls = 2, 4
+    tele = Telemetry(run_id="rpc_reconcile")
+    tracer = rpctrace.tracer_for(tele)
+    tracer.sample_rate = 1.0
+    tracer.resize(4096)
+    fleet = ParamServerFleet(clf_payload, n_shards=n_shards,
+                             telemetry=tele).start()
+    try:
+        t = ShardedTransport(fleet, telemetry=tele, run_id=tele.run_id)
+        zeros = _zeros_like_params(fleet)
+        have = -1
+        for _ in range(n_pulls):
+            t.push(zeros)  # advances every leaf, so every shard serves
+            fleet.drain()
+            snap = t.pull(have)
+            assert snap is not None
+            have = snap[0]
+        t.close()
+        time.sleep(0.1)  # handler threads close their serve spans
+        spans = tracer.spans
+        pulls = [tr for tr in rpctrace.stitch_spans(spans)
+                 if tr["root"]["name"] == "pull"]
+        assert len(pulls) == n_pulls
+        assert {tr["root"]["status"] for tr in pulls} == {"ok"}
+
+        def shards_served(node, acc):
+            if node["name"] == "serve":
+                acc.add(str(node["ann"].get("shard")))
+            for c in node["children"]:
+                shards_served(c, acc)
+            return acc
+
+        shard_ids = {str(s) for s in fleet.urls()}
+        assert len(shard_ids) == n_shards
+        for tr in pulls:
+            assert shards_served(tr["root"], set()) == shard_ids
+        for sid in shard_ids:
+            serves = [s for s in spans if s["name"] == "serve"
+                      and s["ann"].get("route") == "/delta.bin"
+                      and str(s["ann"].get("shard")) == sid]
+            hist = tele.histogram("param_server.wire_latency_s",
+                                  labels={"route": "/delta.bin",
+                                          "shard": sid})
+            assert len(serves) == hist["count"] == n_pulls
+    finally:
         fleet.stop()
 
 

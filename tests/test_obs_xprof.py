@@ -165,7 +165,7 @@ def test_moe_a2a_golden_capture_classification():
 
 
 def test_hlo_collective_bytes_parser():
-    """The static HLO byte analyzer (the bench-moe gate's ground
+    """The static HLO byte analyzer (tests/test_moe.py's ground
     truth) reads shapes and families off real HLO spellings — incl.
     -start/-done async pairs counted ONCE and tuple-shaped results."""
     from sparktorch_tpu.obs.xprof import hlo_collective_bytes

@@ -7,6 +7,7 @@ eviction + re-admission, live weight updates over the wire's
 before the tier-1 timeout cutoff position.)
 """
 
+import threading
 import time
 
 import jax
@@ -238,6 +239,63 @@ def test_live_weight_swap_exactness_single_server():
     finally:
         puller.stop()
         rep.stop()
+        http.stop()
+        server.stop()
+
+
+def test_tier_pullers_land_push_on_every_replica_under_load():
+    """``InferenceTier.start_pullers``: a push made while the tier is
+    serving reaches EVERY replica, no request is lost across the swap,
+    and each replica then serves exactly the pushed parameters."""
+    tele = Telemetry(run_id="t_tier_push")
+    server = ParameterServer(_clf_payload(), telemetry=tele)
+    http = ParamServerHttp(server, port=0).start()
+    module = ClassificationNet(n_classes=2)
+    x = np.random.default_rng(2).normal(0, 1, (8, 10)).astype(np.float32)
+    _v0, params0 = server.slot.read()
+    tier = InferenceTier(module, params0, n_replicas=2, telemetry=tele,
+                         buckets=(1, 8), warm_input=x[:1],
+                         probe_interval_s=0.05)
+    tier.start_pullers(lambda: BinaryTransport(http.url, quant=None),
+                       poll_s=0.02)
+    stop_load = threading.Event()
+    served, errors = [], []
+
+    def load():
+        while not stop_load.is_set():
+            try:
+                served.append(tier.submit(x[:1], deadline_s=30.0))
+            except Exception as e:  # noqa: BLE001 - asserted empty below
+                errors.append(e)
+            time.sleep(0.005)
+
+    loader = threading.Thread(target=load, daemon=True)
+    loader.start()
+    try:
+        grads = jax.tree.map(lambda a: np.ones_like(np.asarray(a)),
+                             params0)
+        server.push_gradients(grads, wait=True)
+        pushed = server.slot.version
+        deadline = time.monotonic() + 15.0
+        while (any(r.params_version < pushed
+                   for r in tier.replicas.values())
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        stop_load.set()
+        loader.join(timeout=30)
+        assert {rid: r.params_version >= pushed
+                for rid, r in tier.replicas.items()} == {"0": True,
+                                                         "1": True}
+        assert not errors and served
+        assert all(out.shape[0] == 1 for out in served)
+        _v, server_params = server.slot.read()
+        ref = np.asarray(module.apply({"params": server_params}, x))
+        for replica in tier.replicas.values():
+            np.testing.assert_allclose(replica.infer(x), ref,
+                                       rtol=1e-5, atol=1e-6)
+    finally:
+        stop_load.set()
+        tier.stop()
         http.stop()
         server.stop()
 
