@@ -54,6 +54,24 @@ With no arguments (one chip), eight phases:
   a tile's halo, the index maps that read a part's columns out of the
   product and the one cotangent buffer the backward kernels alias are
   checked here, where interpret mode cannot.
+- ``short_conv_gate`` ``ops/short_conv_gate.py`` (LFM2's gated short
+  convolution between its two products: the two gates and the three
+  taps out of the float32 product, one pass each way) at the cell's
+  step, 4 rows of 4,096 tokens of 2,048 channels, against its plain
+  spelling: the value and the gradients of the product and the taps,
+  forward and gradient timed apart. The halo's sublane rolls, the three
+  column blocks read through the index map and the backward grid's last
+  axis that writes the product's cotangent block by block are checked
+  here, where interpret mode cannot.
+- ``causal_heads_64`` heads of 64, two to a register, at the same
+  cell's attention layer (32 query on 8 key/value heads, 4 rows of
+  4,096): ``ops/qk_norm_rope.py`` against its plain spelling, and the
+  ``causal`` kernels of ``ops/rule_attention.py`` on its results
+  against dense float32 attention on the same bfloat16 operands, the
+  output and three gradients; the compiled text holds the three
+  kernels. The masked sums a head, the lane rolls by 64 and the sublane
+  slices of the running output are checked here, where interpret mode
+  cannot.
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -148,6 +166,11 @@ class Sizes:
     # gated_delta and gdn_conv_gate: (rows, tokens, key heads, value
     # heads) of 128
     gdn_case: tuple = (1, 2048, 16, 32)
+    # short_conv_gate and causal_heads_64: (rows, tokens, channels) and
+    # (rows, tokens, query heads, key/value heads) of 64 of the
+    # convolution / attention cell's step
+    sconv_case: tuple = (4, 4096, 2048)
+    heads64_case: tuple = (4, 4096, 32, 8)
     # trainer_hogwild: ResNet-18 at CIFAR-10 shapes
     hog_rows: int = 1024
     hog_mini_batch: int = 256
@@ -691,6 +714,152 @@ def phase_gdn_conv_gate(sz: Sizes, seed: int, ctx: dict) -> str:
     return report
 
 
+def _rel(a, b):
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+
+def _timed(fn, *operands):
+    """``(the result, seconds of a second call)``."""
+    import jax
+
+    out = jax.block_until_ready(fn(*operands))
+    t0 = time.perf_counter()
+    jax.block_until_ready(fn(*operands))
+    return out, time.perf_counter() - t0
+
+
+def phase_short_conv_gate(sz: Sizes, seed: int, ctx: dict) -> str:
+    """``ops/short_conv_gate.py`` against its plain spelling at the
+    convolution cell's step, a bfloat16 result out of a float32 product:
+    the value and both gradients, forward and gradient timed apart."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.ops import short_conv_gate as op
+
+    b, t, d = sz.sconv_case
+    dt = jnp.bfloat16
+    keys = jax.random.split(jax.random.key(seed), 4)
+    bcu = jax.random.normal(keys[0], (b, t, 3 * d)) * jnp.exp(
+        jax.random.normal(keys[1], (b, t, 1)))
+    taps = 0.333 * jax.random.normal(keys[2], (op.TAPS, d))
+    weight = jax.random.normal(keys[3], (b, t, d)).astype(dt)
+
+    def grad_of(fn):
+        return jax.jit(jax.grad(lambda x, w: jnp.sum(
+            fn(x, w, dt).astype(jnp.float32) * weight), argnums=(0, 1)))
+
+    value, fwd_s = _timed(jax.jit(lambda x, w: op.short_conv_gate(x, w, dt)),
+                          bcu, taps)
+    grads, grad_s = _timed(grad_of(op.short_conv_gate), bcu, taps)
+    want = jax.jit(lambda x, w: op.plain(x, w, dt))(bcu, taps)
+    want_grads = grad_of(op.plain)(bcu, taps)
+    value_rel = _rel(value, want)
+    grad_rel = {n: _rel(a, w) for n, a, w in zip(
+        ("product", "taps"), grads, want_grads)}
+    report = (f"{d}x{t}x{b} value_rel={value_rel:.2e} grad_rel="
+              f"{ {n: float(f'{r:.2e}') for n, r in grad_rel.items()} } "
+              f"fwd_s={fwd_s:.5f} bwd_s={grad_s:.5f}")
+    if not (value_rel <= TOL_FUSED_VALUE_REL            # NaN fails too
+            and max(grad_rel.values()) <= TOL_FUSED_GRAD_REL):
+        raise AssertionError(
+            f"the fused convolution pass vs its plain spelling: {report} "
+            f"(limits {TOL_FUSED_VALUE_REL}, {TOL_FUSED_GRAD_REL})")
+    return report
+
+
+def phase_causal_heads_64(sz: Sizes, seed: int, ctx: dict) -> str:
+    """Heads of 64, two to a register, at the attention layer's shape of
+    the convolution cell: ``qk_norm_rope`` against its plain spelling,
+    and the ``causal`` kernels on its bfloat16 results against dense
+    float32 attention on the same operands, output and three gradients;
+    the compiled text holds the five kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.models.sparse_moe_lm import _rotate, rms_norm
+    from sparktorch_tpu.ops.qk_norm_rope import qk_norm_rope, tables
+    from sparktorch_tpu.ops.rule_attention import (
+        Causal, heads_in_registers, rule_attention_heads_first)
+
+    b, t, heads, hkv = sz.heads64_case
+    d, eps, dt = 64, 1e-5, jnp.bfloat16
+    keys = jax.random.split(jax.random.key(seed), 8)
+    xq, xk, xv = (jax.random.normal(k, (b, t, h * d)) * jnp.exp(
+        0.5 * jax.random.normal(keys[3], (b, t, 1)))
+        for k, h in zip(keys[:3], (heads, hkv, hkv)))
+    gq, gk = (1.0 + 0.2 * jax.random.normal(k, (d,)) for k in keys[4:6])
+    angles = jnp.arange(t, dtype=jnp.float32)[None, :, None] * (
+        1e6 ** (-jnp.arange(d // 2) / (d // 2)))
+    angles = jnp.broadcast_to(angles, (b, t, d // 2))
+    w_out = jax.random.normal(keys[6], (b, t, heads * d)).astype(dt)
+    table = tables(angles, d)
+
+    def plain_qkv(xq, xk, xv, gq, gk):
+        cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
+        by_head = lambda x: x.reshape(b, t, -1, d)
+        return heads_in_registers(
+            _rotate(rms_norm(by_head(xq), gq, eps), cos, sin).astype(dt),
+            _rotate(rms_norm(by_head(xk), gk, eps), cos, sin).astype(dt),
+            by_head(xv).astype(dt), "plain")
+
+    fused_qkv = lambda *a: qk_norm_rope(*a, *table, eps, d // 2, dt)
+    operands = (xq, xk, xv, gq, gk)
+    got, qk_s = _timed(jax.jit(fused_qkv), *operands)
+    qkv_rel = max(map(_rel, got, jax.jit(plain_qkv)(*operands)))
+    q5, k4, v4 = got
+
+    def kernels(q5, k4, v4):
+        return rule_attention_heads_first(q5, k4, v4, Causal(), "causal", d)
+
+    def dense(q5, k4, v4):
+        # registers -> heads: [b, pairs, G, T, 128] -> [b, heads, T, 64]
+        f32 = lambda x: x.astype(jnp.float32)
+        q = jnp.transpose(f32(q5), (0, 3, 1, 2, 4)).reshape(b, t, heads, d)
+        k, v = (jnp.swapaxes(f32(x), 1, 2).reshape(b, t, hkv, d)
+                for x in (k4, v4))
+        k, v = (jnp.repeat(x, heads // hkv, 2) for x in (k, v))
+        keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+
+        def one_head(q, k, v):   # [b, T, 64] each
+            s = jnp.einsum("bqd,bkd->bqk", q, k,
+                           precision="highest") * d ** -0.5
+            p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1)
+            return jnp.einsum("bqk,bkd->bqd", p, v, precision="highest")
+
+        out = jax.lax.map(lambda qkv: jax.checkpoint(one_head)(*qkv), tuple(
+            jnp.moveaxis(x, 2, 0) for x in (q, k, v)))
+        return jnp.moveaxis(out, 0, 2).reshape(b, t, heads * d)
+
+    def with_grads(fn):
+        return jax.jit(lambda *a: (fn(*a), jax.grad(lambda *a: jnp.sum(
+            fn(*a).astype(jnp.float32) * w_out), argnums=(0, 1, 2))(*a)))
+
+    run = with_grads(kernels)
+    (o, grads), attn_s = _timed(run, q5, k4, v4)
+    counts = {k: len(re.findall(
+        rf'custom_call_target="tpu_custom_call".*\b{k}\b',
+        run.lower(q5, k4, v4).compile().as_text()))
+        for k in ("causal_attn_fwd", "causal_attn_bwd_dq",
+                  "causal_attn_bwd_dkv")}
+    want_o, want_grads = with_grads(dense)(q5, k4, v4)
+    out_rel = _rel(o, want_o)
+    grad_rel = max(map(_rel, grads, want_grads))
+    report = (f"{heads}/{hkv}x64x{t}x{b} qkv_rel={qkv_rel:.2e} out_rel="
+              f"{out_rel:.2e} grad_rel={grad_rel:.2e} qk_norm_rope_s="
+              f"{qk_s:.5f} attn_fwd_and_grad_s={attn_s:.5f} {counts}")
+    if not (qkv_rel <= TOL_FUSED_VALUE_REL              # NaN fails too
+            and out_rel <= TOL_LATENT_OUT_REL
+            and grad_rel <= TOL_LATENT_GRAD_REL
+            and all(n >= 1 for n in counts.values())):
+        raise AssertionError(
+            f"heads of 64 through qk_norm_rope and the causal kernels: "
+            f"{report} (limits {TOL_FUSED_VALUE_REL}, {TOL_LATENT_OUT_REL}, "
+            f"{TOL_LATENT_GRAD_REL})")
+    return report
+
+
 def phase_trainer_hogwild(sz: Sizes, seed: int, ctx: dict) -> str:
     from sparktorch_tpu import SparkTorch, serialize_torch_obj
     from sparktorch_tpu.models.resnet import resnet18
@@ -958,6 +1127,8 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("latent_attention", phase_latent_attention),
             ("gated_delta", phase_gated_delta),
             ("gdn_conv_gate", phase_gdn_conv_gate),
+            ("short_conv_gate", phase_short_conv_gate),
+            ("causal_heads_64", phase_causal_heads_64),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),

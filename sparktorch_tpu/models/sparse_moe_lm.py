@@ -2,7 +2,7 @@
 experts that is told which experts it holds (Qwen3-MoE's block). What a
 layer is belongs to the LAYER (:class:`LayerKind`: its attention, its
 query heads, its rotary table, experts or a dense MLP after it), and a
-model is its list of layers (:class:`SparseMoEConfig` ``layers``). Five
+model is its list of layers (:class:`SparseMoEConfig` ``layers``). Six
 models are built on it:
 
 - every layer ``learned_sparse``: DeepSeek-V3.2's lightning indexer in
@@ -24,7 +24,13 @@ models are built on it:
   a recurrent state a head) and ``full`` layers mixed 3:1, the full
   layers at 256-wide heads under an element-wise output gate, ten of
   512 softmax-routed experts beside a GATED shared expert
-  (Qwen3-Next-80B-A3B, :func:`qwen3_next_lm`; "Gated delta rule" below).
+  (Qwen3-Next-80B-A3B, :func:`qwen3_next_lm`; "Gated delta rule" below);
+- ``short_conv`` (a gated short convolution: no softmax, no keys and
+  values, no state beyond two tokens) and ``full`` layers mixed 3:1, the
+  full layers at 64-wide heads, two leading dense layers, 4 of 32
+  sigmoid-routed experts chosen under an expert bias, and the head TIED
+  to the embedding (LFM2-8B-A1B, :func:`lfm2_moe_lm`; "Gated short
+  convolution" below).
 
 Shared by all, written once: the projections, q/k norm and rotary step
 around the attention kernel of the grouped-query kinds
@@ -34,8 +40,8 @@ remat with its attention kernel's output and row statistics kept, the
 head's padding to the fused cross entropy's tile, the counters and
 gauges. What differs is the attention module (``_ATTENTION``, by the
 layer's kind: the grouped-query kinds share ``_GroupedQueryProjections``,
-``latent`` and ``gated_delta`` are modules beside it that share no
-projection with them) and, under block diffusion, what the model does before its
+``latent``, ``gated_delta`` and ``short_conv`` are modules beside it
+that share no projection with them) and, under block diffusion, what the model does before its
 first layer and hands back after its last. A model whose layers are all
 alike says its one kind by four fields (``attention``, ``n_heads``,
 ``rope_theta``, ``mrope_section``) and builds what it built before
@@ -78,9 +84,13 @@ One layer, for the tokens ``x`` of a row, in the published order:
   the rows held, rounded up to a chunk, at any load: a holder of every
   expert runs every chunk. There is no capacity and nothing is dropped.
 
-After the last layer RMSNorm and an untied head over ``vocab_size``
-rows (a slice of the published vocabulary, when the configuration says
-so). The logits come out at a width the fused cross-entropy kernel
+After the last layer RMSNorm and a head over ``vocab_size`` rows (a
+slice of the published vocabulary, when the configuration says so):
+untied, a leaf ``head`` of its own, or (``tie_word_embeddings``) the
+embedding transposed, in which case ``embed`` is ONE leaf used twice, by
+the gather and by the head's product, its gradient the sum of the
+gather's scatter-add and the product's, and the tree has no leaf
+``head``. The logits come out at a width the fused cross-entropy kernel
 tiles (18,992 does not): the columns past ``vocab_size`` are no
 parameters, they read -1e30 and so never enter a softmax, and the loss
 over the padded width is the loss over ``vocab_size``. Every layer is
@@ -293,6 +303,47 @@ Left out: decoding (the recurrent state and the convolution's last
 three tokens as a cache), state resets at document boundaries in a
 packed row, and the model's multi-token prediction module.
 
+Gated short convolution (LFM2's mixer; :class:`ShortConv`). Layer ``l``
+of LFM2-8B-A1B is ``layer_types[l]``, ``conv`` (18 of 24) or
+``full_attention`` (layers 2, 6, 10, 14, 18, 21); RMSNorm with a plain
+gain and ``eps`` 1e-5, no bias anywhere. For the tokens ``x [T, d]`` of a
+row:
+
+- ``conv``: ``h = RMSNorm(x)``; ``[B ; C ; u] = h W_in`` (``W_in [d, 3
+  d]``, three equal column blocks in that order; scope ``sconv_in_proj``
+  inside ``attn_qkv``); ``s = B * u``; a causal depthwise convolution
+  over time of 3 taps a channel, ``c[t] = sum_{i < 3} w[i] s[t - 2 +
+  i]``, ``s[t < 0] = 0``; NO activation; ``y = C * c``. The two gates
+  and the taps are ``ops/short_conv_gate.py``'s ``short_conv_gate``
+  (scope ``sconv_gate``; kernels ``sconv_fwd`` / ``sconv_bwd``): it
+  reads the float32 product as it lies, the three blocks through its
+  blocks' index map and the two tokens before a tile as a halo, and
+  writes ``y`` in the compute dtype, one pass over HBM each way; the
+  taps' count is that op's constant (``TAPS``), not a field. ``x = x +
+  y W_out`` (``[d, d]``, scope ``attn_out`` inside ``sconv_out_proj``).
+  The taps start N(0, 0.333) (``_SCONV_STD``). The layer's remat keeps
+  nothing of it: the product and the pass are recomputed. A
+  ``short_conv`` layer has no heads (its ``LayerKind.n_heads`` holds 0)
+  and no rotary table.
+- ``full_attention``: the third model's ``full`` kind at ``head_dim`` 64
+  (32 query heads on 8 key/value heads, q/k RMSNorm a head, rotary by
+  halves on all 64 dims, theta 1e6, plain, no gate). Heads of 64 lie
+  TWO TO A REGISTER of 128 lanes from the products through
+  ``ops/qk_norm_rope.py`` and the ``causal`` kernels of
+  ``ops/rule_attention.py`` to the flat ``o`` (those files' docstrings
+  say how): nothing is padded to 128 in HBM.
+- Layers ``l < num_dense_layers`` (2): the dense SwiGLU at 7,168. The
+  others: ``s = sigmoid(g W_r)`` over ALL 32 experts; the 4 of largest
+  ``s + b`` (the fourth model's ``selection_bias``: the source's expert
+  bias); gates ``s_e / (sum_chosen s + 1e-6)`` (``routed_norm_eps``)
+  from ``s`` WITHOUT ``b``, times ``routed_scale`` 1; no shared expert.
+- After the last layer RMSNorm and the head ``logits = n E^T``, ``E``
+  the embedding (``tie_word_embeddings``).
+
+Left out: decoding (the convolution's last two tokens as a cache beside
+keys and values) and state resets at document boundaries in a packed
+row.
+
 Multi-token prediction (DeepSeek-V3 section 2.2, depth 1;
 :class:`MultiTokenPredictor`, ``mtp_depth`` 1). With ``x^L`` the stream
 after the last layer (before the final norm), ``E`` the model's own
@@ -326,7 +377,9 @@ sows none); under block diffusion also ``masked_tokens`` and ``tokens``
 of the step and, by layer, ``attn_tiles`` (the tiles the attention's
 forward kernel visits, of the whole square's); by each ``full``,
 ``window`` and ``latent`` layer the same count as ``attn_tiles_full`` /
-``attn_tiles_window`` / ``attn_tiles_latent``; by each ``gated_delta``
+``attn_tiles_window`` / ``attn_tiles_latent``; by each ``short_conv``
+layer ``sconv_tokens`` (rows x ``T``: the tokens through the fused
+pass); by each ``gated_delta``
 layer ``gdn_chunks`` (the chunks the rule's forward kernel runs: rows x
 value heads x ``T / 64``) and ``gdn_fused_tokens`` (rows x ``T``: the
 tokens through the fused passes around the rule); by the multi-token
@@ -352,6 +405,7 @@ from jax.ad_checkpoint import checkpoint_name
 from sparktorch_tpu.ops import gated_delta_rule as delta
 from sparktorch_tpu.ops import gdn_conv_gate as conv_gate
 from sparktorch_tpu.ops import qk_norm_rope as fused
+from sparktorch_tpu.ops import short_conv_gate as sconv
 from sparktorch_tpu.ops import latent_attention as latent
 from sparktorch_tpu.ops.block_diffusion_attention import (
     SAVED_NAMES as BLOCKDIFF_SAVED_NAMES, BlockDiffusionMask,
@@ -400,9 +454,11 @@ class Rotary:
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer is: its attention (a key of ``_ATTENTION``), its
-    query heads (a ``gated_delta`` layer's value heads), its rotary table
-    (None for a layer that takes no rotary step: ``gated_delta``), and
-    ``"experts"`` or ``"dense"`` after the attention."""
+    query heads (a ``gated_delta`` layer's value heads; a ``short_conv``
+    layer has no heads and the field holds 0), its rotary table (None
+    for a layer that takes no rotary step: ``gated_delta``,
+    ``short_conv``), and ``"experts"`` or ``"dense"`` after the
+    attention."""
 
     attention: str
     n_heads: int
@@ -436,6 +492,8 @@ class SparseMoEConfig:
     mask_token_id: int = 151_669
     noise_eps: float = 1e-3
     window: int = 512
+    # ("short_conv", a gated short convolution, and "gated_delta" are a
+    # model's ``layers``' to say: they take no rotary table)
     # "full" and "window" layers: one sigmoid gate a head a token on the
     # attention's output, from the layer's normed input
     attn_gate: bool = False
@@ -465,6 +523,8 @@ class SparseMoEConfig:
     # "sigmoid" of each, renormalised over the chosen, times routed_scale
     scoring: str = "softmax"
     routed_scale: float = 1.0
+    # added to the chosen scores' sum before it divides them
+    routed_norm_eps: float = 0.0
     # a bias an expert, added to the scores where the experts are chosen
     # and nowhere else: a leaf the gradient does not reach
     selection_bias: bool = False
@@ -477,13 +537,16 @@ class SparseMoEConfig:
     # the weight of their loss beside the next token's
     mtp_depth: int = 0
     mtp_weight: float = 0.0
+    # the head is the embedding, transposed: one leaf, ``embed``, used
+    # twice, and no leaf ``head``
+    tie_word_embeddings: bool = False
     compute_dtype: jnp.dtype = jnp.bfloat16
 
     def __post_init__(self):
         if not self.layers:
             object.__setattr__(self, "layers", (LayerKind(
                 self.attention, self.n_heads,
-                None if self.attention == "gated_delta" else
+                None if self.attention in _NO_ROTARY else
                 Rotary(self.rope_theta, tuple(self.mrope_section))),)
                 * self.n_layers)
         if len(self.layers) != self.n_layers:
@@ -496,10 +559,12 @@ class SparseMoEConfig:
             if kind.mlp not in ("experts", "dense"):
                 raise ValueError(f"mlp {kind.mlp!r} is neither experts nor "
                                  f"dense")
-            if kind.attention == "gated_delta":
+            if kind.attention in _NO_ROTARY:
                 if kind.rotary is not None:
-                    raise ValueError("a gated_delta layer takes no rotary "
-                                     "step: its rotary is None")
+                    raise ValueError(f"a {kind.attention} layer takes no "
+                                     f"rotary step: its rotary is None")
+                if kind.attention == "short_conv":
+                    continue
                 if (self.linear_key_heads < 1
                         or kind.n_heads % self.linear_key_heads
                         or self.linear_conv_width < 1):
@@ -570,6 +635,10 @@ class SparseMoEConfig:
     def diffusion(self) -> bool:
         """Whether the model trains by masked block diffusion."""
         return self.layers_of("block_diffusion") == self.n_layers
+
+
+# the kinds of layer that take no rotary step and name no table
+_NO_ROTARY = ("gated_delta", "short_conv")
 
 
 def _normal(stddev=0.02):
@@ -891,7 +960,8 @@ class RuleAttention(_GroupedQueryProjections):
                  b * cfg.n_kv_heads
                  * jnp.asarray([visited, total], jnp.float32))
         with jax.named_scope(f"{name}_attention"):
-            o = rule_attention_heads_first(q5, k4, v4, rule, name)
+            o = rule_attention_heads_first(q5, k4, v4, rule, name,
+                                           cfg.head_dim)
         if cfg.attn_gate and cfg.attn_gate_width == "element":
             with jax.named_scope("attn_gate"):
                 # flat as o lies: a gate a lane
@@ -1068,6 +1138,40 @@ class GatedDeltaNet(_FlatProducts):
                 preferred_element_type=jnp.float32)
 
 
+# A short convolution's taps at init: the spread of U(-1/sqrt 3, 1/sqrt
+# 3), what an unset depthwise convolution of 3 taps gets where the model
+# was written.
+_SCONV_STD = 0.333
+
+
+class ShortConv(_FlatProducts):
+    """A gated short convolution ("Gated short convolution" in the
+    module docstring): one product for the two gates and the input, ``[B
+    ; C ; u] = h W_in``; ``y = C * conv3(B * u)``, a causal depthwise
+    convolution of 3 taps between two element-wise gates, no activation
+    (one fused pass, ``ops/short_conv_gate.py``); the output projection.
+    No softmax, no rotary step, no heads; what it carries along a row is
+    the two tokens before, inside the pass."""
+
+    @nn.compact
+    def __call__(self, h, table, temporal):
+        dt = self.config.compute_dtype
+        b, t, d = h.shape
+        del table, temporal
+        with jax.named_scope("attn_qkv"), jax.named_scope("sconv_in_proj"):
+            # columns [B ; C ; u], three equal blocks
+            bcu = self._product(h, self._dense("w_in", (d, 3 * d)))
+        self.sow("moe_metrics", "sconv_tokens", jnp.float32(b * t))
+        with jax.named_scope("sconv_gate"):
+            y = sconv.short_conv_gate(
+                bcu, self.param("conv", _normal(_SCONV_STD),
+                                (sconv.TAPS, d)), dt)
+        # ``attn_out`` as every mixer's; ``sconv_out_proj`` tells a
+        # convolution layer's from a full layer's in a trace
+        with jax.named_scope("sconv_out_proj"), jax.named_scope("attn_out"):
+            return self._product(y, self._dense("wo", (d, d)))
+
+
 # attention by kind of layer: the module, and what its forward pass
 # names for the layer's remat policy to keep
 _ATTENTION = {
@@ -1079,6 +1183,8 @@ _ATTENTION = {
     # the rule's output, the states entering each block of chunks and
     # each chunk's inverse
     "gated_delta": (GatedDeltaNet, delta.SAVED_NAMES),
+    # nothing: the product and the pass are recomputed
+    "short_conv": (ShortConv, ()),
 }
 # the kinds whose forward kernel's tiles a layer counts under its name
 _TILES_BY_KIND = (*_RULE_NAMES, "latent")
@@ -1138,7 +1244,10 @@ class HeldExperts(nn.Module):
             # transpose is dense (top_k's own is a batched scatter)
             top_p = jnp.einsum("nke,ne->nk", jax.nn.one_hot(
                 top_e, cfg.n_routed_experts, dtype=probs.dtype), probs)
-            gates = top_p / jnp.sum(top_p, -1, keepdims=True)
+            chosen_sum = jnp.sum(top_p, -1, keepdims=True)
+            if cfg.routed_norm_eps:
+                chosen_sum = chosen_sum + cfg.routed_norm_eps
+            gates = top_p / chosen_sum
             if cfg.routed_scale != 1.0:
                 gates = cfg.routed_scale * gates
             # local id of each chosen expert, n_held for one held elsewhere
@@ -1492,9 +1601,10 @@ class SparseMoELM(nn.Module):
             gauges["train.attention.window"] = cfg.window
         gauges.update({f"train.attention.layers_{kind}": cfg.layers_of(kind)
                        for kind in _TILES_BY_KIND if cfg.layers_of(kind)})
-        if cfg.layers_of("gated_delta"):
-            gauges["train.attention.layers_gated_delta"] = cfg.layers_of(
-                "gated_delta")
+        for kind in _NO_ROTARY:
+            if cfg.layers_of(kind):
+                gauges[f"train.attention.layers_{kind}"] = cfg.layers_of(
+                    kind)
         if cfg.shared_expert_gate:
             gauges["train.moe.shared_gate"] = 1
         if cfg.layers_of("latent"):
@@ -1568,6 +1678,12 @@ class SparseMoELM(nn.Module):
                 sown["gdn_fused_tokens"].sum())
             counters["train.attention.gdn_fused_tokens"] = fields[
                 "gdn_fused_tokens"]
+        if "sconv_tokens" in sown:
+            # rows x tokens through the fused pass, over the convolution
+            # layers
+            fields["sconv_tokens"] = float(sown["sconv_tokens"].sum())
+            counters["train.attention.sconv_tokens"] = fields[
+                "sconv_tokens"]
         for kind in _TILES_BY_KIND:
             if f"attn_tiles_{kind}" in sown:
                 tiles = sown[f"attn_tiles_{kind}"].sum(0)
@@ -1622,8 +1738,9 @@ class SparseMoELM(nn.Module):
                           _table_key(cfg, k) for k in cfg.layers)}
         temporal = position_ids[0]
         with jax.named_scope("embed"):  # its gradient: the scatter-add
-            emb = self.param("embed", _normal(),
-                             (cfg.vocab_size, cfg.d_model))[ids]
+            embed = self.param("embed", _normal(),
+                               (cfg.vocab_size, cfg.d_model))
+            emb = embed[ids]
         x = emb
         remat = {kept: _remat_layer(kept) for kept in dict.fromkeys(
             _ATTENTION[k.attention][1] for k in cfg.layers)}
@@ -1638,8 +1755,10 @@ class SparseMoELM(nn.Module):
                                             nn.initializers.ones,
                                             (cfg.d_model,)), cfg.rms_eps)
         with jax.named_scope("lm_head"):
-            head = self.param("head", _normal(),
-                              (cfg.d_model, cfg.vocab_size)).astype(dt)
+            # tied: the embedding's leaf a second time (its gradient the
+            # sum of the gather's scatter-add and this product's)
+            head = (embed.T if cfg.tie_word_embeddings else self.param(
+                "head", _normal(), (cfg.d_model, cfg.vocab_size))).astype(dt)
             pad = -cfg.vocab_size % min(_CE_BLOCK_V, cfg.vocab_size)
 
             def to_logits(h):
@@ -1816,3 +1935,42 @@ def qwen3_next_lm(**overrides) -> SparseMoELM:
         "experts_per_token": 10, "expert_width": 512,
         "shared_expert_width": 512, "shared_expert_gate": True,
         **overrides}))
+
+
+# LFM2-8B-A1B's published ``layer_types``
+_LFM2_LAYER_TYPES = tuple(
+    "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
+    for i in range(24))
+
+
+def lfm2_moe_lm(**overrides) -> SparseMoELM:
+    """LFM2-8B-A1B at its published sizes: 24 layers of hidden 2,048, 18
+    gated short convolutions (3 taps) and 6 full causal attentions (32
+    query over 8 key/value heads of 64, q/k norm a head, rotary by
+    halves on the whole head, theta 1e6) as ``layer_types`` lists them;
+    the first ``num_dense_layers`` 2 layers' MLP dense (7,168), the
+    others' 32 experts of 1,792, 4 a token by sigmoid scores plus an
+    expert bias, renormalised (1e-6 in the sum), scale 1, no shared
+    expert; the head tied to the embedding; vocabulary 65,536, norm eps
+    1e-5. ``overrides`` as for :func:`keye_vl2_lm`, and the source's own
+    two keys for the pattern: ``layer_types`` (``"conv"`` /
+    ``"full_attention"`` a layer) and ``num_dense_layers``, from which
+    ``layers`` and ``n_layers`` are built (a cut gives its own list)."""
+    overrides = _coerced(overrides)
+    layer_types = tuple(overrides.pop("layer_types", _LFM2_LAYER_TYPES))
+    n_dense = overrides.pop("num_dense_layers", 2)
+    kinds = {"conv": ("short_conv", 0, None),
+             "full_attention": ("full", 32, Rotary(1e6, (32,)))}
+    if set(layer_types) - set(kinds):
+        raise ValueError(f"layer_types {layer_types} name a kind that is "
+                         f"none of {sorted(kinds)}")
+    return SparseMoELM(SparseMoEConfig(**{
+        "vocab_size": 65_536, "n_layers": len(layer_types), "n_kv_heads": 8,
+        "head_dim": 64, "rms_eps": 1e-5,
+        "layers": tuple(
+            LayerKind(*kinds[kind], "dense" if i < n_dense else "experts")
+            for i, kind in enumerate(layer_types)),
+        "n_routed_experts": 32, "experts_held": tuple(range(32)),
+        "experts_per_token": 4, "expert_width": 1_792, "scoring": "sigmoid",
+        "selection_bias": True, "routed_norm_eps": 1e-6,
+        "dense_width": 7_168, "tie_word_embeddings": True, **overrides}))

@@ -48,6 +48,31 @@ forward kernel calls it on a row of queries and a column of keys and
 gets the turned mask with nothing transposed (:func:`_keep`); dq and
 dkv keep the tile queries down.
 
+Heads of 64 (``head_dim`` 64 in an operand whose last axis is 128) lie
+TWO TO A REGISTER, in HBM as in the products they come from: nothing is
+padded to 128 and ``o`` is flat with head ``i`` in lanes ``[64 i, 64 (i
++ 1))``. ``q5 [b, pairs, G, T, 128]`` is then the flat ``q`` by
+registers (register ``r`` of a pair holds its query heads ``2 r`` and
+``2 r + 1``) and ``k4`` / ``v4 [b, pairs, T, 128]`` hold a PAIR of
+key/value heads, ``A`` in lanes 0-63 and ``B`` in lanes 64-127; ``G`` is
+still the query heads of one key/value head, so head ``h`` of the pair's
+``2 G`` reads ``A`` when ``h < G`` and ``B`` otherwise. A grid step
+serves a pair. The shared bodies want a whole register a head, so this
+file has the three bodies again for halves (``_fwd_tile_halves`` and the
+rest): a step makes, once for its ``2 G`` heads, the four copies of the
+K tile and of the V tile with ONE head's 64 dims at ONE half of the
+lanes and zeros at the other (a select, and a lane roll by 64 where the
+head moves), so that a head's scores are the product of its register of
+``q`` with the copy that has its key/value head at its own lanes (the
+other head's lanes meet zeros), ``p v`` lands at its own rows of the
+running output (a sublane slice) and ``dq`` at its own lanes; ``dk`` and
+``dv`` are summed with a key/value head's two lane positions apart and
+folded once a K tile. Every matrix product is a whole register deep or
+wide, so a 64-wide head costs the matrix unit what a 128-wide one does:
+the price of keeping HBM dense with no lane shuffles a head (PERF.md
+section 6, PR 46). The statistics are a row a HEAD, ``[b, pairs, 2 G,
+T]``.
+
 Two entries, as ``ops/sparse_attention.py`` has them and says more of:
 :func:`rule_attention_heads_first` takes the kernels' layout (``q5``,
 ``k4``, ``v4``), returns the flat ``o`` (backward: takes the flat
@@ -66,8 +91,9 @@ remat policy (:func:`saved_names`: ``<name>_attn_out``,
 ``<name>_attn_lse``, as ``sparse_attention.SAVED_NAMES``), so that the
 forward kernel runs once a layer a step and a policy can keep one kind's
 and not another's. Off the TPU the kernels run in interpret mode. A
-shape that does not tile, or a rule that leaves a whole row of tiles
-empty, is an error everywhere: there is no dense path.
+shape that does not tile (``T`` no multiple of 128; a ``head_dim`` that
+is neither a multiple of 128 nor 64), or a rule that leaves a whole row
+of tiles empty, is an error everywhere: there is no dense path.
 """
 
 from __future__ import annotations
@@ -82,9 +108,11 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from sparktorch_tpu.ops.flash_attention import _nt, _tn
 from sparktorch_tpu.ops.sparse_attention import (
-    _LANES, _blocks, _interpret, dkv_tile, dq_tile, fwd_finalize, fwd_init,
-    fwd_scratch, fwd_tile, heads_first, row_statistics)
+    _LANES, _NEG, _blocks, _head, _interpret, _scores, dkv_tile, dq_tile,
+    fwd_finalize, fwd_init, fwd_scratch, fwd_tile, heads_first,
+    row_statistics, spread)
 
 
 def saved_names(name: str) -> tuple:
@@ -168,9 +196,134 @@ def _keep(rule, qi, ki, block_q, block_k, keys_down: bool = False):
     return rule(queries, keys)
 
 
+# -- heads of 64, two to a register ------------------------------------------
+
+_HALF = 64
+
+
+def _low(shape):
+    """Which lanes are a register's first head's."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) < _HALF
+
+
+def _apart(x):
+    """``x [block_k, 128]`` (key/value head ``A`` in lanes 0-63, ``B`` in
+    64-127) as ``copies[kv head][half]``: that head's 64 dims at the
+    lanes of ``half`` and zeros at the others. The roll is made on 32-bit
+    lanes."""
+    wide = x.astype(jnp.float32)
+    low = _low(wide.shape)
+    a, b = jnp.where(low, wide, 0.0), jnp.where(low, 0.0, wide)
+    to = lambda y: y.astype(x.dtype)
+    return ((to(a), to(pltpu.roll(a, _HALF, 1))),
+            (to(pltpu.roll(b, _HALF, 1)), to(b)))
+
+
+def _halves(groups):
+    """``(register, half, head of the pair, its key/value head)`` for the
+    ``2 groups`` heads a step serves."""
+    return [(r, half, 2 * r + half, (2 * r + half) // groups)
+            for r in range(groups) for half in range(2)]
+
+
+def _fwd_tile_halves(q_ref, k, v, keep, acc_ref, m_ref, l_ref, scale,
+                     groups):
+    """``sparse_attention.fwd_tile`` for heads of 64: the running output
+    ``[groups, 128, block_q]`` holds a register's two heads one above
+    the other, the statistics are a row a head, ``[2 groups, 8,
+    block_q]``."""
+    ks, vs = _apart(k), _apart(v)
+    for r, half, h, kv in _halves(groups):
+        rows = pl.ds(half * _HALF, _HALF)
+        s = jnp.where(keep, _nt(ks[kv][half], q_ref[r]) * scale, _NEG)
+        m_prev, l_prev = m_ref[h][:1], l_ref[h][:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+        pv = _tn(vs[kv][half], p.astype(v.dtype))   # zeros off its rows
+        acc_ref[r, rows, :] = (acc_ref[r, rows, :] * alpha
+                               + pv[half * _HALF:(half + 1) * _HALF])
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def _fwd_finalize_halves(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups):
+    for r in range(groups):
+        ls = [jnp.maximum(l_ref[2 * r + half][:1], 1e-20)
+              for half in range(2)]
+        out = jnp.concatenate([acc_ref[r, :_HALF] / ls[0],
+                               acc_ref[r, _HALF:] / ls[1]], 0)
+        o_ref[_head(r, _LANES)] = out.T.astype(o_ref.dtype)
+        for half in range(2):
+            lse_ref[pl.ds(2 * r + half, 1), :] = (
+                m_ref[2 * r + half][:1] + jnp.log(ls[half]))
+
+
+def _dq_tile_halves(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dq_acc,
+                    scale, groups):
+    ks, vs = _apart(k), _apart(v)
+    for r, half, h, kv in _halves(groups):
+        s = _scores(q_ref[r], ks[kv][half], keep, scale)
+        p = jnp.where(keep, jnp.exp(s - lse_ref[h][:, :1]), 0.0)
+        dp = _nt(do_ref[_head(r, _LANES)], vs[kv][half])
+        ds = p * (dp - d_ref[h][:, :1])
+        # at the head's own lanes: the copy is zero at the others
+        dq_acc[r] = dq_acc[r] + jax.lax.dot_general(
+            ds.astype(k.dtype), ks[kv][half], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _dkv_tile_halves(q_ref, k, v, keep, do_ref, lse_ref, d_ref, dk_acc,
+                     dv_acc, scale, groups):
+    """``dk_acc`` and ``dv_acc [2, block_k, 128]``: a key/value head's
+    sums with what its query heads at a register's first half give in
+    lanes 0-63 and its others' in lanes 64-127 (:func:`_folded` adds the
+    two)."""
+    ks, vs = _apart(k), _apart(v)
+    low = _low((1, _LANES))
+    for r, half, h, kv in _halves(groups):
+        own = low if half == 0 else ~low
+        q, do = q_ref[r], do_ref[_head(r, _LANES)]
+        q_own = jnp.where(own, q, jnp.zeros_like(q))
+        do_own = jnp.where(own, do, jnp.zeros_like(do))
+        s = _scores(q, ks[kv][half], keep, scale)
+        p = jnp.where(keep, jnp.exp(s - lse_ref[h][:, :1]), 0.0)
+        dv_acc[kv] = dv_acc[kv] + _tn(p.astype(do.dtype), do_own)
+        dp = _nt(do, vs[kv][half])
+        ds = p * (dp - d_ref[h][:, :1])
+        dk_acc[kv] = dk_acc[kv] + _tn(ds.astype(q.dtype), q_own)
+
+
+def _folded(acc_ref):
+    """A pair's ``[block_k, 128]`` from its two heads' sums kept apart:
+    head ``A``'s two halves added into lanes 0-63, ``B``'s into
+    64-127."""
+    both = [acc_ref[kv] + pltpu.roll(acc_ref[kv], _HALF, 1)
+            for kv in range(2)]
+    return jnp.where(_low(both[0].shape), both[0], both[1])
+
+
+def _row_statistics_halves(o, lse, do):
+    """``sparse_attention.row_statistics`` for heads of 64: ``sum(o *
+    do)`` over a head's lanes as a product with the heads' indicator
+    ``[heads * 64, heads]`` (float32, exact to its sums), because a
+    reshape of the lanes into heads of 64 is a copy of the whole array
+    into registers half empty (compiled for the v5e, PR 46)."""
+    b, pairs, heads, t = lse.shape
+    n = pairs * heads
+    owner = (jnp.arange(n * _HALF)[:, None] // _HALF
+             == jnp.arange(n)[None, :]).astype(jnp.float32)
+    di = jnp.einsum("btf,fh->bth",
+                    o.astype(jnp.float32) * do.astype(jnp.float32), owner,
+                    precision=jax.lax.Precision.HIGHEST)
+    di = jnp.transpose(di.reshape(b, t, pairs, heads), (0, 2, 3, 1))
+    return spread(lse), spread(di)
+
+
 def _fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, rule, scale, block_q, block_k,
-                n_visits, groups):
+                n_visits, groups, halves=False):
     v = pl.program_id(2)
     first, last = _visit(qt_ref, v, n_visits)
 
@@ -180,17 +333,19 @@ def _fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k,
                  keys_down=True)
-    fwd_tile(q_ref, k_ref[...], v_ref[...], keep, acc_ref, m_ref, l_ref,
-             scale, groups)
+    (_fwd_tile_halves if halves else fwd_tile)(
+        q_ref, k_ref[...], v_ref[...], keep, acc_ref, m_ref, l_ref, scale,
+        groups)
 
     @pl.when(last)
     def _finalize():
-        fwd_finalize(o_ref, lse_ref, acc_ref, m_ref, l_ref, groups)
+        (_fwd_finalize_halves if halves else fwd_finalize)(
+            o_ref, lse_ref, acc_ref, m_ref, l_ref, groups)
 
 
 def _bwd_dq_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    d_ref, dq_ref, dq_acc, *, rule, scale, block_q, block_k,
-                   n_visits, groups):
+                   n_visits, groups, halves=False):
     v = pl.program_id(2)
     first, last = _visit(qt_ref, v, n_visits)
 
@@ -199,8 +354,9 @@ def _bwd_dq_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k)
-    dq_tile(q_ref, k_ref[...], v_ref[...], keep, do_ref, lse_ref, d_ref,
-            dq_acc, scale, groups)
+    (_dq_tile_halves if halves else dq_tile)(
+        q_ref, k_ref[...], v_ref[...], keep, do_ref, lse_ref, d_ref, dq_acc,
+        scale, groups)
 
     @pl.when(last)
     def _finalize():
@@ -209,7 +365,7 @@ def _bwd_dq_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, rule, scale,
-                    block_q, block_k, n_visits, groups):
+                    block_q, block_k, n_visits, groups, halves=False):
     v = pl.program_id(2)
     first, last = _visit(kt_ref, v, n_visits)
 
@@ -219,18 +375,23 @@ def _bwd_dkv_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     keep = _keep(rule, qt_ref[v], kt_ref[v], block_q, block_k)
-    dkv_tile(q_ref, k_ref[...], v_ref[...], keep, do_ref, lse_ref, d_ref,
-             dk_acc, dv_acc, scale, groups)
+    (_dkv_tile_halves if halves else dkv_tile)(
+        q_ref, k_ref[...], v_ref[...], keep, do_ref, lse_ref, d_ref, dk_acc,
+        dv_acc, scale, groups)
 
     @pl.when(last)
     def _finalize():
-        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        dk, dv = ((_folded(dk_acc), _folded(dv_acc)) if halves
+                  else (dk_acc[...], dv_acc[...]))
+        dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _specs(groups, d, block_q, block_k):
+def _specs(groups, d, block_q, block_k, heads):
     """Block specs of the operands every kernel shares, on the grid
-    ``(b, kv head, visit)`` with the table prefetched."""
+    ``(b, kv head, visit)`` with the table prefetched; ``heads``: the
+    rows of statistics a step reads (``groups``, or twice that for
+    heads of 64)."""
     q_spec = pl.BlockSpec(
         (None, None, groups, block_q, d),
         lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
@@ -241,22 +402,22 @@ def _specs(groups, d, block_q, block_k):
     kv_spec = pl.BlockSpec(
         (None, None, block_k, d), lambda b, h, v, qt, kt: (b, h, kt[v], 0))
     row_spec = pl.BlockSpec(
-        (None, None, groups, block_q, _LANES),
+        (None, None, heads, block_q, _LANES),
         lambda b, h, v, qt, kt: (b, h, 0, qt[v], 0))
     return q_spec, o_spec, kv_spec, row_spec
 
 
-def _call(kernel, name, table, shape, out_shape, in_specs, out_specs,
-          scratch_shapes, operands, **static):
+def _call(kernel, name, table, shape, head_dim, out_shape, in_specs,
+          out_specs, scratch_shapes, operands, **static):
     """One kernel over ``table``'s visits for every row and key/value
-    head."""
+    head (every pair of them, for heads of ``head_dim`` 64)."""
     b, hkv, groups, t, d = shape
     block_q, block_k = _blocks(t)
     qt, kt = table
     return pl.pallas_call(
-        functools.partial(kernel, scale=d ** -0.5, block_q=block_q,
+        functools.partial(kernel, scale=head_dim ** -0.5, block_q=block_q,
                           block_k=block_k, n_visits=len(qt), groups=groups,
-                          **static),
+                          halves=head_dim < d, **static),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, hkv, len(qt)),
@@ -267,80 +428,99 @@ def _call(kernel, name, table, shape, out_shape, in_specs, out_specs,
     )(jnp.asarray(qt), jnp.asarray(kt), *operands)
 
 
-def _fwd(rule, name, q5, k4, v4):
+def _fwd(rule, name, head_dim, q5, k4, v4):
     b, hkv, groups, t, d = q5.shape
+    heads = groups * (d // head_dim)   # of one grid step
     block_q, block_k = _blocks(t)
     q_major, _ = visited_tiles(rule, t, block_q, block_k)
-    q_spec, o_spec, kv_spec, _ = _specs(groups, d, block_q, block_k)
+    q_spec, o_spec, kv_spec, _ = _specs(groups, d, block_q, block_k, heads)
     # the log-sum-exp, one number a row with the sequence along the lanes
-    lse_spec = pl.BlockSpec((None, None, groups, block_q),
+    lse_spec = pl.BlockSpec((None, None, heads, block_q),
                             lambda b, h, v, qt, kt: (b, h, 0, qt[v]))
+    acc, m, l = fwd_scratch(groups, d, block_q)
+    if heads > groups:   # a row of statistics a head
+        m = l = pltpu.VMEM((heads, *m.shape[1:]), jnp.float32)
     return _call(
-        _fwd_kernel, f"{name}_attn_fwd", q_major, q5.shape,
+        _fwd_kernel, f"{name}_attn_fwd", q_major, q5.shape, head_dim,
         [jax.ShapeDtypeStruct((b, t, hkv * groups * d), q5.dtype),
-         jax.ShapeDtypeStruct((b, hkv, groups, t), jnp.float32)],
+         jax.ShapeDtypeStruct((b, hkv, heads, t), jnp.float32)],
         [q_spec, kv_spec, kv_spec], [o_spec, lse_spec],
-        fwd_scratch(groups, d, block_q), (q5, k4, v4), rule=rule)
+        [acc, m, l], (q5, k4, v4), rule=rule)
 
 
-def _bwd(rule, name, q5, k4, v4, o, lse, do):
+def _bwd(rule, name, head_dim, q5, k4, v4, o, lse, do):
     b, hkv, groups, t, d = q5.shape
+    heads = groups * (d // head_dim)
     block_q, block_k = _blocks(t)
     q_major, k_major = visited_tiles(rule, t, block_q, block_k)
-    lse, di = row_statistics(o, lse, do)
-    q_spec, o_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k)
+    lse, di = (row_statistics if heads == groups
+               else _row_statistics_halves)(o, lse, do)
+    q_spec, o_spec, kv_spec, row_spec = _specs(groups, d, block_q, block_k,
+                                               heads)
     in_specs = [q_spec, kv_spec, kv_spec, o_spec, row_spec, row_spec]
     operands = (q5, k4, v4, do, lse, di)
     dq5 = _call(
-        _bwd_dq_kernel, f"{name}_attn_bwd_dq", q_major, q5.shape,
+        _bwd_dq_kernel, f"{name}_attn_bwd_dq", q_major, q5.shape, head_dim,
         jax.ShapeDtypeStruct(q5.shape, q5.dtype), in_specs, q_spec,
         [pltpu.VMEM((groups, block_q, d), jnp.float32)], operands,
         rule=rule)
+    # heads of 64: a pair's two heads' sums apart (``_folded``)
+    sums = (block_k, d) if heads == groups else (2, block_k, d)
     dk4, dv4 = _call(
-        _bwd_dkv_kernel, f"{name}_attn_bwd_dkv", k_major, q5.shape,
+        _bwd_dkv_kernel, f"{name}_attn_bwd_dkv", k_major, q5.shape, head_dim,
         [jax.ShapeDtypeStruct(k4.shape, k4.dtype),
          jax.ShapeDtypeStruct(v4.shape, v4.dtype)], in_specs,
         [kv_spec, kv_spec],
-        [pltpu.VMEM((block_k, d), jnp.float32),
-         pltpu.VMEM((block_k, d), jnp.float32)], operands, rule=rule)
+        [pltpu.VMEM(sums, jnp.float32), pltpu.VMEM(sums, jnp.float32)],
+        operands, rule=rule)
     return dq5, dk4, dv4
 
 
-def _check(q5, k4, v4, name):
+def _check(q5, k4, v4, name, head_dim):
     b, hkv, _, t, d = q5.shape
     if k4.shape != v4.shape or k4.shape != (b, hkv, t, d):
         raise ValueError(f"{name}_attn: q {q5.shape}, k {k4.shape}, v "
                          f"{v4.shape} do not go together")
-    if d % _LANES or t % _LANES:
+    if d % _LANES or t % _LANES or head_dim not in (d, _HALF) or (
+            head_dim == _HALF and d != _LANES):
         raise ValueError(
-            f"{name}_attn: seq {t} x head_dim {d} cannot be tiled: both "
-            f"must be multiples of {_LANES}")
+            f"{name}_attn: seq {t} x head_dim {head_dim} in operands "
+            f"{d} wide cannot be tiled: seq must be a multiple of {_LANES} "
+            f"and head_dim one too, or {_HALF} with two heads to a register "
+            f"of {_LANES} lanes")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def rule_attention_heads_first(q5: jax.Array, k4: jax.Array, v4: jax.Array,
-                               rule, name: str) -> jax.Array:
+                               rule, name: str,
+                               head_dim: int = 0) -> jax.Array:
     """:func:`rule_attention` on operands in the kernels' layout: ``q5
     [b, kv_heads, G, T, d]``, ``k4`` and ``v4 [b, kv_heads, T, d]`` ->
     ``o [b, T, kv_heads * G * d]``, head ``i`` in lanes ``[i * d, (i +
     1) * d)``; the cotangents of ``q5``, ``k4`` and ``v4`` come back as
     the backward kernels write them. Nothing is transposed on either
-    side."""
-    return _forward(q5, k4, v4, rule, name)[0]
+    side. ``head_dim`` (0: ``d``) 64 with ``d`` 128 says that two heads
+    lie in each register: ``q5 [b, pairs, G, T, 128]``, ``k4`` and ``v4
+    [b, pairs, T, 128]`` as the module docstring lays them,
+    ``ops/qk_norm_rope.py`` writes them and :func:`heads_in_registers`
+    turns ``[b, T, h, 64]`` operands."""
+    return _forward(q5, k4, v4, rule, name, head_dim)[0]
 
 
-def _forward(q5, k4, v4, rule, name):
-    _check(q5, k4, v4, name)
-    o, lse = _fwd(rule, name, q5, k4, v4)
+def _forward(q5, k4, v4, rule, name, head_dim=0):
+    head_dim = head_dim or q5.shape[-1]
+    _check(q5, k4, v4, name, head_dim)
+    o, lse = _fwd(rule, name, head_dim, q5, k4, v4)
     out_name, lse_name = saved_names(name)
     o = checkpoint_name(o, out_name)
     lse = checkpoint_name(lse, lse_name)
     return o, (q5, k4, v4, o, lse)
 
 
-def _bwd_rule(rule, name, res, do):
+def _bwd_rule(rule, name, head_dim, res, do):
     q5, k4, v4, o, lse = res
-    return _bwd(rule, name, q5, k4, v4, o, lse, do.astype(q5.dtype))
+    return _bwd(rule, name, head_dim or q5.shape[-1], q5, k4, v4, o, lse,
+                do.astype(q5.dtype))
 
 
 rule_attention_heads_first.defvjp(_forward, _bwd_rule)
@@ -353,8 +533,31 @@ def rule_attention(q: jax.Array, k: jax.Array, v: jax.Array, rule,
     kv_heads, d]`` (query head ``i`` reads key/value head ``i // (heads
     // kv_heads)``); ``rule`` and ``name`` are static: a rule for ``T``
     tokens, and what the call's kernels and saved arrays are called. A
-    thin wrapper: it turns its operands heads first and calls
-    :func:`rule_attention_heads_first`, whose flat result reshapes to
-    ``q``'s shape with no element moved."""
+    thin wrapper: it turns its operands heads first (heads of 64 two to
+    a register) and calls :func:`rule_attention_heads_first`, whose flat
+    result reshapes to ``q``'s shape with no element moved."""
+    if q.shape[-1] == _HALF:
+        return rule_attention_heads_first(
+            *heads_in_registers(q, k, v, f"{name}_attn"), rule, name,
+            _HALF).reshape(q.shape)
     return rule_attention_heads_first(
         *heads_first(q, k, v, f"{name}_attn"), rule, name).reshape(q.shape)
+
+
+def heads_in_registers(q, k, v, name: str):
+    """``q [b, T, h, 64]``, ``k`` and ``v [b, T, hkv, 64]`` as the
+    kernels read heads of 64: ``q5 [b, hkv / 2, h / hkv, T, 128]``,
+    ``k4`` and ``v4 [b, hkv / 2, T, 128]``, the flat arrays' lanes by
+    registers, by transposing copies (the decoder's fused
+    ``ops/qk_norm_rope.py`` writes this layout itself)."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if d != _HALF or h % hkv or hkv % 2:
+        raise ValueError(
+            f"{name}: {h} query heads on {hkv} key/value heads of {d} do "
+            f"not lie two to a register, a pair of key/value heads a step")
+    pairs = hkv // 2
+    return (jnp.transpose(q.reshape(b, t, pairs, h // hkv, _LANES),
+                          (0, 2, 3, 1, 4)),
+            jnp.swapaxes(k.reshape(b, t, pairs, _LANES), 1, 2),
+            jnp.swapaxes(v.reshape(b, t, pairs, _LANES), 1, 2))

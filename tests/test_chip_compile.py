@@ -547,6 +547,110 @@ def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 6_000_000_000
 
 
+def test_short_conv_pass_and_heads_of_64_compile_for_v5e(one_chip, as_tpu):
+    """The two mixers of the convolution cell at its step (4 rows of
+    4,096 tokens): ``sconv_fwd`` / ``sconv_bwd`` on the float32 product
+    ``[4, 4096, 3 x 2048]`` (blocks of 512 lanes, the halo's sublane
+    rolls, the backward grid's last axis over the product's three column
+    blocks) and, for 32 query on 8 key/value heads of 64, two heads to a
+    register, ``qk_norm_rope`` and the ``causal`` kernels (masked sums a
+    head, lane rolls by 64, the statistics a row a head): they lower in
+    Mosaic, fit its VMEM, and nothing 64 wide reaches HBM."""
+    from sparktorch_tpu.ops import qk_norm_rope as fused
+    from sparktorch_tpu.ops import short_conv_gate as sconv
+    from sparktorch_tpu.ops.rule_attention import (
+        Causal, rule_attention_heads_first)
+
+    b, t, d, heads, kv, hd = 4, 4_096, 2_048, 32, 8, 64
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+
+    def conv_loss(bcu, taps):
+        y = sconv.short_conv_gate(bcu, taps, jnp.bfloat16)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    traced = jax.jit(jax.grad(conv_loss, argnums=(0, 1))).trace(
+        S(b, t, 3 * d), S(sconv.TAPS, d))
+    text = traced.lower().compile().as_text()
+    assert _pallas_calls(text, "sconv_fwd") == 1
+    assert _pallas_calls(text, "sconv_bwd") == 1
+    # the product's cotangent leaves the kernel whole: no pass of XLA's
+    # puts three blocks together
+    assert not re.search(rf"= f32\[{b},{t},{3 * d}\]\S* "
+                         rf"(copy|add|concatenate|pad)\(", text)
+    vmem = _declared_vmem(traced.jaxpr.jaxpr)
+    assert max(vmem["sconv_fwd"] + vmem["sconv_bwd"]) < 10 * 2 ** 20
+
+    def attn_loss(xq, xk, xv, cos, sin, gq, gk):
+        q5, k4, v4 = fused.qk_norm_rope(xq, xk, xv, gq, gk, cos, sin, 1e-5,
+                                        32, jnp.bfloat16)
+        return rule_attention_heads_first(
+            q5, k4, v4, Causal(), "causal", hd).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(attn_loss, argnums=(0, 1, 2, 5, 6))).lower(
+        S(b, t, heads * hd), S(b, t, kv * hd), S(b, t, kv * hd),
+        S(b, t, 128), S(b, t, 128), S(hd), S(hd)).compile().as_text()
+    for kernel in ("causal_attn_fwd", "causal_attn_bwd_dq",
+                   "causal_attn_bwd_dkv", "qk_norm_rope_fwd",
+                   "qk_norm_rope_bwd"):
+        assert _pallas_calls(text, kernel) == 1
+    # a row of statistics a head: 4 pairs of key/value heads, 8 heads each
+    assert _forward_statistics(text, "causal_attn_fwd") == [(b, 4, 8, t)]
+    # q, k and v by registers: no result in HBM by heads of 64 (rank 4 or more)
+    assert not re.search(r"= \w+\[4,\d+,\d+,[\d,]*64\]",
+                         text[text.index("\nENTRY "):])
+
+
+@pytest.mark.slow  # 75 s; the test above keeps its kernels in tier 1
+def test_convolution_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
+    """The whole model of the convolution cell at its configuration
+    file's sizes and the cell's step (4 rows of 4,096 tokens; a dense
+    convolution layer, an attention layer at 32 / 8 heads of 64 and
+    three convolution layers with 8 of 32 experts held, the head tied to
+    16,384 rows of embedding): ``sconv_fwd`` twice a convolution layer
+    (the remat keeps nothing of it) and ``sconv_bwd`` once, the causal
+    kernels once, no dense attention (no ``[.., 4096, 4096]`` array),
+    and the gradient's scratch leaves room beside 8.13 GB of state."""
+    import json
+
+    from sparktorch_tpu.models.sparse_moe_lm import lfm2_moe_lm
+    from sparktorch_tpu.utils.losses import resolve_loss
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "lfm2-8b-a1b-ep4.json")) as f:
+        module = lfm2_moe_lm(**json.load(f)["constructor_kwargs"])
+    ids = jnp.zeros((4, 4_096), jnp.float32)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.key(0), ids))["params"]
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 507_820_288
+    S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    loss_fn = resolve_loss("cross_entropy")
+
+    def loss(p, x, y):
+        out, sown = module.apply({"params": p}, x, mutable=["moe_metrics"])
+        return loss_fn(out, y).sum(), sown
+
+    compiled = jax.jit(jax.grad(loss, has_aux=True)).lower(
+        jax.tree.map(S, shapes), S(ids), S(ids)).compile()
+    text = compiled.as_text()
+    layers = 4
+    assert _pallas_calls(text, "sconv_fwd") == 2 * layers
+    assert _pallas_calls(text, "sconv_bwd") == layers
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert _pallas_calls(text, f"causal_attn_{kernel}") == 1
+    assert _forward_statistics(text, "causal_attn_fwd") == [(4, 4, 8, 4_096)]
+    assert _pallas_calls(text, "qk_norm_rope_fwd") == 2
+    assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
+    assert _pallas_calls(text, "fused_ce_fwd") == 1
+    assert not re.search(r"\[[\d,]*4096,4096\]", text)   # no dense scores
+    # no activation by heads of 64 (a weight's gradient may lie so)
+    assert not re.search(r"= \w+\[4,\d+,\d+,[\d,]*64\]",
+                         text[text.index("\nENTRY "):])
+    print(compiled.memory_analysis())
+    assert compiled.memory_analysis().temp_size_in_bytes < 6_000_000_000
+
+
 @pytest.mark.parametrize("rows,seq,calls", [(32, 512, 1), (128, 128, 0)])
 def test_encoder_layer_gradient_picks_its_attention_for_v5e(
         one_chip, as_tpu, monkeypatch, rows, seq, calls):
