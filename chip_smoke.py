@@ -72,6 +72,16 @@ With no arguments (one chip), eight phases:
   kernels. The masked sums a head, the lane rolls by 64 and the sublane
   slices of the running output are checked here, where interpret mode
   cannot.
+- ``grouped_mlp``    ``ops/grouped_mlp.py`` through the expert layer's
+  sum (``models.sparse_moe_lm.held_experts_sum``) at LFM2's layer: 16,384
+  tokens, 4 of 32 experts each, 8 held (chunks of 32,768 sorted pairs,
+  tiles of 512 rows, widths 2,048 / 1,792), against the same sum spelled
+  with ``jax.lax.ragged_dot`` on whole arrays, rows past the held pairs
+  zeroed: the output and all five gradients, forward and gradient timed
+  apart. The output tile that stays in VMEM over a shared tile's visits
+  and a visit's column blocks, the blocks no grid step writes, the
+  carried sums the weight-gradient kernels add to in place and the
+  transposed products are checked here, where interpret mode cannot.
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -122,6 +132,9 @@ TOL_FUSED_GRAD_REL = 1e-4
 # float32 attention on the same bf16 operands
 TOL_LATENT_OUT_REL = 1e-2
 TOL_LATENT_GRAD_REL = 2e-2
+# the grouped kernels against ``ragged_dot`` on the same bf16 operands:
+# both sum in float32, in another order, and round hidden rows once
+TOL_GROUPED_REL = 5e-3
 # the chunked gated delta rule (bf16 operands, float32 sums and state)
 # against the token-by-token recurrence in float32 on the same bf16
 # operands: the chunk's T, W and V' enter their products rounded to bf16
@@ -170,6 +183,8 @@ class Sizes:
     # (rows, tokens, query heads, key/value heads) of 64 of the
     # convolution / attention cell's step
     sconv_case: tuple = (4, 4096, 2048)
+    # tokens, experts a token, routed experts, held, d, f
+    grouped_case: tuple = (16_384, 4, 32, 8, 2_048, 1_792)
     heads64_case: tuple = (4, 4096, 32, 8)
     # trainer_hogwild: ResNet-18 at CIFAR-10 shapes
     hog_rows: int = 1024
@@ -769,6 +784,75 @@ def phase_short_conv_gate(sz: Sizes, seed: int, ctx: dict) -> str:
     return report
 
 
+def _ragged_experts_sum(x, token, gate, rows, w_gate, w_up, w_down):
+    """``held_experts_sum`` on whole arrays by ``jax.lax.ragged_dot``
+    (what the layer ran before its kernels): operands in ``x``'s dtype,
+    sums float32, the rows past the held pairs, which the TPU's
+    ``ragged_dot`` leaves undefined, zeroed on both sides of each
+    product."""
+    import jax
+    import jax.numpy as jnp
+
+    dt = x.dtype
+    live = (jnp.arange(token.size) < jnp.sum(rows))[:, None]
+    held = lambda a: jnp.where(live, a, 0.0).astype(a.dtype)
+    rdot = lambda a, m: held(jax.lax.ragged_dot(
+        a, m.astype(dt), rows, preferred_element_type=jnp.float32))
+    xs = held(x[token])
+    hidden = (jax.nn.silu(rdot(xs, w_gate)) * rdot(xs, w_up)).astype(dt)
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(
+        rdot(hidden, w_down) * gate[:, None])
+
+
+def phase_grouped_mlp(sz: Sizes, seed: int, ctx: dict) -> str:
+    """The expert layer's sum through ``ops/grouped_mlp.py``'s kernels
+    against its ``ragged_dot`` spelling at LFM2's layer: the output and
+    the five gradients, forward and gradient timed apart."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparktorch_tpu.models import sparse_moe_lm as M
+
+    n, k, routed, n_held, d, f = sz.grouped_case
+    dt = jnp.bfloat16
+    keys = jax.random.split(jax.random.key(seed), 8)
+    x = jax.random.normal(keys[0], (n, d)).astype(dt)
+    # each token's k experts, uneven, the held ones the first n_held
+    expert = jnp.argsort(jax.random.gumbel(keys[1], (n, routed))
+                         + jnp.linspace(0.0, 1.0, routed), -1)[:, :k]
+    local = jnp.where(expert < n_held, expert, n_held).reshape(n * k)
+    order = jnp.argsort(local, stable=True)
+    rows = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :], 0,
+                   dtype=jnp.int32)
+    token = order // k
+    gate = jax.random.uniform(keys[2], (n * k,), jnp.float32, 0.05, 0.5)
+    w_gate, w_up = (jax.random.normal(kk, (n_held, d, f)) * d ** -0.5
+                    for kk in keys[3:5])
+    w_down = jax.random.normal(keys[5], (n_held, f, d)) * f ** -0.5
+    weight = jax.random.normal(keys[6], (n, d))
+    chunk, _ = M._row_chunks(n * k, n_held, routed)
+    ours = lambda x, gate, *w: M.held_experts_sum(x, token, gate, rows, *w,
+                                                  chunk)
+    plain = lambda x, gate, *w: _ragged_experts_sum(x, token, gate, rows, *w)
+    grad_of = lambda fn: jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2, 3, 4)))
+    operands = (x, gate, w_gate, w_up, w_down)
+    value, fwd_s = _timed(jax.jit(ours), *operands)
+    grads, grad_s = _timed(grad_of(ours), *operands)
+    rels = {"out": _rel(value, jax.jit(plain)(*operands))}
+    rels.update({name: _rel(a, b) for name, a, b in zip(
+        ("x", "gate", "w_gate", "w_up", "w_down"), grads,
+        grad_of(plain)(*operands))})
+    report = (f"tokens={n} held_rows={int(rows.sum())} chunk={chunk} "
+              f"rel={ {n: float(f'{r:.2e}') for n, r in rels.items()} } "
+              f"fwd_s={fwd_s:.5f} grad_s={grad_s:.5f}")
+    if not max(rels.values()) <= TOL_GROUPED_REL:       # NaN fails too
+        raise AssertionError(
+            f"the grouped kernels vs the ragged_dot spelling: {report} "
+            f"(limit {TOL_GROUPED_REL})")
+    return report
+
+
 def phase_causal_heads_64(sz: Sizes, seed: int, ctx: dict) -> str:
     """Heads of 64, two to a register, at the attention layer's shape of
     the convolution cell: ``qk_norm_rope`` against its plain spelling,
@@ -1129,6 +1213,7 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("gdn_conv_gate", phase_gdn_conv_gate),
             ("short_conv_gate", phase_short_conv_gate),
             ("causal_heads_64", phase_causal_heads_64),
+            ("grouped_mlp", phase_grouped_mlp),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),

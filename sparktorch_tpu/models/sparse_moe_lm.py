@@ -76,13 +76,19 @@ One layer, for the tokens ``x`` of a row, in the published order:
   out (they live on other chips, whose exchange is not here). Every
   chosen (token, held expert) pair is computed: pairs are sorted by
   expert (those of experts held elsewhere last), their tokens' rows
-  gathered, multiplied (``jax.lax.ragged_dot``), gated and summed back
-  a chunk of the sorted pairs at a time (:func:`_row_chunks`: twice the
-  pairs the layer expects to hold), by a loop that runs as many chunks
-  as hold a pair of a held expert (:func:`held_experts_sum`, with a
-  backward pass of its own: the same loop again). So the rows moved are
-  the rows held, rounded up to a chunk, at any load: a holder of every
-  expert runs every chunk. There is no capacity and nothing is dropped.
+  gathered, multiplied, gated and summed back a chunk of the sorted
+  pairs at a time (:func:`_row_chunks`: twice the pairs the layer
+  expects to hold), by a loop that runs as many chunks as hold a pair of
+  a held expert (:func:`held_experts_sum`, with a backward pass of its
+  own: the same loop again). The products are ``ops/grouped_mlp.py``'s
+  Pallas kernels over the chunk's row tiles that hold a held pair, each
+  tile by the expert (or, one after the other, the experts) whose rows
+  lie in it: SwiGLU, the gate and the row masks are their epilogues,
+  and no float32 array of ``[chunk, expert_width]`` lies between two of
+  them. So the rows gathered and scattered are the rows held, rounded up
+  to a chunk, and the rows multiplied are the rows held, rounded up to a
+  tile an expert, at any load: a holder of every expert runs every
+  chunk. There is no capacity and nothing is dropped.
 
 After the last layer RMSNorm and a head over ``vocab_size`` rows (a
 slice of the published vocabulary, when the configuration says so):
@@ -369,7 +375,9 @@ drafter is not here.
 
 Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
 (rows computed by each held expert), ``row_chunks`` (the chunks the
-loop ran, and the chunks that all chosen pairs would take), ``routed``
+loop ran, and the chunks that all chosen pairs would take), ``row_tiles``
+(the row tiles the grouped kernels visited in those chunks, a tile once
+an expert with a row in it, and the row tiles those chunks hold), ``routed``
 (chosen pairs whose expert is held) and ``dropped`` (those of them that
 the grouped products, chunk by chunk, did not multiply by their own
 expert's weights: 0), by each layer that holds experts (a dense layer
@@ -404,6 +412,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from sparktorch_tpu.ops import gated_delta_rule as delta
 from sparktorch_tpu.ops import gdn_conv_gate as conv_gate
+from sparktorch_tpu.ops import grouped_mlp as grouped
 from sparktorch_tpu.ops import qk_norm_rope as fused
 from sparktorch_tpu.ops import short_conv_gate as sconv
 from sparktorch_tpu.ops import latent_attention as latent
@@ -424,12 +433,18 @@ _MASK_NAME = "sparse_attn_mask"
 # the selected sets do not depend on the block (tests shrink it).
 _IDX_Q_CHUNK = 1_024
 # A trip of the expert layer's loop takes the sorted pairs this many
-# times the layer's expected share of them (:func:`_row_chunks`). A trip
-# costs its chunk's rows whatever rows are live, and 4 ms before its
-# first row (its scatter-adds pass over all tokens' sums, its weight
-# gradients are added to all experts'), so a layer's usual load should
-# take ONE trip: a chunk of exactly the share ran one trip or two by the
-# step's rows, 13 ms apart (PERF.md section 6, PR 28 and PR 32).
+# times the layer's expected share of them (:func:`_row_chunks`). A
+# trip's products cost the rows held (``ops/grouped_mlp.py``'s kernels
+# skip a chunk's row tiles without a held pair and leave an expert
+# without a row alone: 3.3 ms forward and 9.7 backward for 16,384 held
+# rows of 32,768 at LFM2's widths, 4.8 and 14.3 for all 32,768; TPU v5e,
+# the kernels alone, PERF.md section 6, PR 47). What a trip still pays
+# by the CHUNK, or before its first row, is XLA's: three gathers of the
+# chunk's rows, two scatter-adds that pass over every token's float32
+# sum, the weights' casts and the kernels' table. So a layer's usual
+# load should still take ONE trip: a chunk of exactly the share ran one
+# trip or two by the step's rows (13 ms apart then; PERF.md section 6,
+# PR 28 and PR 32).
 _CHUNK_OVER_SHARE = 2
 # The vocabulary tile of ``ops/fused_ce.py``, which the head pads to.
 _CE_BLOCK_V = 512
@@ -1274,26 +1289,25 @@ class HeldExperts(nn.Module):
         n_pairs = jnp.sum(pair_local < n_held).astype(jnp.float32)
         # counted chunk by chunk against the group sizes the loop gives
         # its products (chunks it does not run hold no held pair)
+        sizes = jax.vmap(lambda c: chunk_rows(rows, c * chunk, chunk))(
+            jnp.arange(n_chunks))
         covered = jax.vmap(pairs_covered)(
             jnp.pad(pair_local[order], (0, n_chunks * chunk - n * k),
-                    constant_values=n_held).reshape(n_chunks, chunk),
-            jax.vmap(lambda c: chunk_rows(rows, c * chunk, chunk))(
-                jnp.arange(n_chunks)))
+                    constant_values=n_held).reshape(n_chunks, chunk), sizes)
+        tile, trips = _row_tile(chunk, n_held), _trips(rows, chunk)
         self.sow("moe_metrics", "expert_rows", rows)
         self.sow("moe_metrics", "row_chunks", jnp.stack(
-            [_trips(rows, chunk), jnp.int32(n_chunks)]))
+            [trips, jnp.int32(n_chunks)]))
+        # a chunk the loop does not run holds no held pair: no visit
+        self.sow("moe_metrics", "row_tiles", jnp.stack(
+            [jnp.sum(jax.vmap(lambda s: grouped.tiles_visited(s, tile))(
+                sizes)), trips * (chunk // tile)]))
         self.sow("moe_metrics", "routed", n_pairs)
         self.sow("moe_metrics", "dropped", n_pairs - jnp.sum(covered))
         return out.reshape(b, t, d)
 
 
 # -- the held experts' rows, a chunk of the sorted pairs at a time ------------
-
-# [m, a] x [m, b] over groups of the rows -> [groups, a, b]: the weight
-# gradient of a grouped product
-_BY_GROUP = jax.lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(([0], [0]), ([], [])),
-    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
 def _row_chunks(n_pairs: int, n_held: int, n_routed: int):
@@ -1321,38 +1335,32 @@ def chunk_rows(rows, start, chunk: int):
                        - jnp.maximum(ends - rows, start), 0)
 
 
+def _row_tile(chunk: int, n_held: int) -> int:
+    """Rows a tile of the grouped kernels for a layer's chunks: by the
+    rows one of its ``n_held`` experts expects in a chunk, which holds
+    ``_CHUNK_OVER_SHARE`` times the layer's expected share."""
+    return grouped.row_tile(chunk, chunk // (_CHUNK_OVER_SHARE * n_held))
+
+
 class _Chunk:
-    """Chunk ``c`` of the sorted pairs: its tokens, gates, group sizes
-    and gathered rows of ``x``, and the grouped product on them.
+    """Chunk ``c`` of the sorted pairs: its tokens, gates (a column) and
+    gathered rows of ``x``, and the grouped kernels' table of the row
+    tiles its held rows lie in (``ops/grouped_mlp.py``).
 
     Rows past the held pairs belong to no group (their experts are held
-    elsewhere). ``ragged_dot`` leaves such rows of its result undefined
-    (the TPU's does not write them: found on the chip, PR 27), so both
-    sides of every product are zero there: nothing undefined reaches a
-    sum in either pass."""
+    elsewhere): no kernel reads them, and what the loops sum over the
+    chunk's rows (the gated outputs, ``dx``'s rows, ``d_gate``) the
+    kernels write as zeros there. Nothing is left unwritten and read: a
+    grouped product that left such rows undefined put 25x gradients on
+    the chip (XLA's own, PR 27)."""
 
-    def __init__(self, c, x, token, gate, rows, chunk):
+    def __init__(self, c, x, token, gate, rows, chunk, tile):
         self.start = c * chunk
         self.token = jax.lax.dynamic_slice(token, (self.start,), (chunk,))
         self.gate = jax.lax.dynamic_slice(gate, (self.start,), (chunk,))[:, None]
-        self.sizes = chunk_rows(rows, self.start, chunk)
-        self.live = (self.start + jnp.arange(chunk) < jnp.sum(rows))[:, None]
-        self.xs = self.held(x[self.token])
-
-    def held(self, a):
-        return jnp.where(self.live, a, 0.0).astype(a.dtype)
-
-    def rdot(self, a, m):
-        """``a`` (zero past the held pairs) by each row's expert's ``m``."""
-        return self.held(jax.lax.ragged_dot(
-            a, m, self.sizes, preferred_element_type=jnp.float32))
-
-    def by_group(self, a, b):
-        """``a^T b`` over each expert's rows, float32. A group with no
-        row in this chunk reads 0 whatever the product wrote there."""
-        prod = jax.lax.ragged_dot_general(
-            a, b, self.sizes, _BY_GROUP, preferred_element_type=jnp.float32)
-        return jnp.where((self.sizes > 0)[:, None, None], prod, 0.0)
+        self.table = grouped.visit_table(
+            chunk_rows(rows, self.start, chunk), chunk, tile)
+        self.xs = x[self.token]
 
 
 def _padded_pairs(token, gate, chunk: int):
@@ -1368,19 +1376,20 @@ def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down, chunk):
     ``[tokens, d]``. ``token`` and ``gate`` are the chosen pairs' in
     sorted order (held pairs first, by expert), ``rows`` the pairs of
     each held expert. A loop over chunks of ``chunk`` sorted pairs (a
-    Python int: :func:`_row_chunks`), as many as hold a held pair:
-    products in ``x``'s dtype, sums in float32."""
-    dt = x.dtype
+    Python int: :func:`_row_chunks`), as many as hold a held pair: a
+    trip gathers its chunk's rows, runs ``ops/grouped_mlp.py``'s two
+    forward kernels on the row tiles that hold a held pair and
+    scatter-adds; products in ``x``'s dtype, sums in float32."""
+    dt, tile = x.dtype, _row_tile(chunk, rows.size)
     with jax.named_scope("moe_experts"):
-        token, gate = _padded_pairs(token, gate, chunk)
-        w_in = jnp.concatenate([w_gate, w_up], -1).astype(dt)
-        w_out = w_down.astype(dt)
+        token, gate = _padded_pairs(token, gate.astype(jnp.float32), chunk)
+        w_gate, w_up, w_down = (w.astype(dt) for w in (w_gate, w_up, w_down))
 
         def one_chunk(c, out):
-            ck = _Chunk(c, x, token, gate, rows, chunk)
-            a, b = jnp.split(ck.rdot(ck.xs, w_in), 2, -1)
-            ys = ck.rdot((jax.nn.silu(a) * b).astype(dt), w_out)
-            return out.at[ck.token].add(ys * ck.gate)
+            ck = _Chunk(c, x, token, gate, rows, chunk, tile)
+            hidden = grouped.gmm_in(ck.table, ck.xs, w_gate, w_up, tile=tile)
+            return out.at[ck.token].add(grouped.gmm_down(
+                ck.table, hidden, w_down, ck.gate, tile=tile))
 
         return jax.lax.fori_loop(0, _trips(rows, chunk), one_chunk,
                                  jnp.zeros(x.shape, jnp.float32))
@@ -1391,46 +1400,42 @@ def _held_experts_fwd(*args):
 
 
 def _held_experts_bwd(chunk, args, d_out):
-    """The same loop again, a chunk's hidden rows recomputed: what is
-    kept between the passes is the function's arguments, and what the
-    loop carries is the gradients' sums in float32."""
+    """The same loop again, a chunk's hidden rows recomputed in the
+    kernel that takes their cotangent: what is kept between the passes
+    is the function's arguments, and what the loop carries is the
+    gradients' sums in float32, the weights' added to in place by the
+    kernels, an expert without a row in the chunk left as it is."""
     x, token, gate, rows, w_gate, w_up, w_down = args
-    dt, n_pairs = x.dtype, token.size
+    dt, n_pairs, tile = x.dtype, token.size, _row_tile(chunk, rows.size)
     # a custom_vjp's backward rule carries no scope of the forward's
     with jax.named_scope("moe_experts"):
-        token, gate = _padded_pairs(token, gate, chunk)
-        w_in = jnp.concatenate([w_gate, w_up], -1).astype(dt)
-        w_in_t, w_out_t = (jnp.swapaxes(w_in, 1, 2),
-                           jnp.swapaxes(w_down.astype(dt), 1, 2))
+        token, gate_f = _padded_pairs(token, gate.astype(jnp.float32), chunk)
+        w_g, w_u, w_d = (w.astype(dt) for w in (w_gate, w_up, w_down))
+        d_out = d_out.astype(dt)
 
         def one_chunk(c, sums):
-            dx, d_gate, dw_in, dw_out = sums
-            ck = _Chunk(c, x, token, gate, rows, chunk)
-            a, b = jnp.split(ck.rdot(ck.xs, w_in), 2, -1)
-            sig = jax.nn.sigmoid(a)
-            hidden = a * sig * b
-            dy = ck.held(d_out[ck.token]).astype(dt)
-            # ys = hidden w_down, out += gate ys
-            d_hidden = ck.rdot(dy, w_out_t)  # before the gate
-            d_gate = jax.lax.dynamic_update_slice(
-                d_gate, jnp.sum(hidden.astype(dt) * d_hidden, -1),
-                (ck.start,))
-            d_hidden = d_hidden * ck.gate
-            d_ab = jnp.concatenate(
-                [d_hidden * b * sig * (1.0 + a * (1.0 - sig)),
-                 d_hidden * a * sig], -1).astype(dt)
-            return (dx.at[ck.token].add(ck.rdot(d_ab, w_in_t)), d_gate,
-                    dw_in + ck.by_group(ck.xs, d_ab),
-                    dw_out + ck.by_group((hidden * ck.gate).astype(dt), dy))
+            dx, d_gate, dw_gate, dw_up, dw_down = sums
+            ck = _Chunk(c, x, token, gate_f, rows, chunk, tile)
+            dy = d_out[ck.token]
+            d_a, d_b, gated, d_gate_c = grouped.gmm_bwd_hidden(
+                ck.table, ck.xs, dy, ck.gate, w_g, w_u, w_d, tile=tile)
+            dw_gate, dw_up = grouped.gmm_dw_in(
+                ck.table, ck.xs, d_a, d_b, dw_gate, dw_up, tile=tile)
+            return (dx.at[ck.token].add(grouped.gmm_dx(
+                        ck.table, d_a, d_b, w_g, w_u, tile=tile)),
+                    jax.lax.dynamic_update_slice(d_gate, d_gate_c[:, 0],
+                                                 (ck.start,)),
+                    dw_gate, dw_up,
+                    grouped.gmm_dw_down(ck.table, gated, dy, dw_down,
+                                        tile=tile))
 
         f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
-        dx, d_gate, dw_in, dw_out = jax.lax.fori_loop(
+        dx, d_gate, dw_gate, dw_up, dw_down = jax.lax.fori_loop(
             0, _trips(rows, chunk), one_chunk,
-            (f32(x), f32(gate), f32(w_in), f32(w_down)))
-        dw_gate, dw_up = jnp.split(dw_in, 2, -1)
+            (f32(x), f32(gate_f), f32(w_gate), f32(w_up), f32(w_down)))
     return (dx.astype(dt), None, d_gate[:n_pairs].astype(gate.dtype), None,
             dw_gate.astype(w_gate.dtype), dw_up.astype(w_up.dtype),
-            dw_out.astype(w_down.dtype))
+            dw_down.astype(w_down.dtype))
 
 
 held_experts_sum.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -1627,7 +1632,12 @@ class SparseMoELM(nn.Module):
         pairs that were not computed (routed is computed plus dropped).
         From ``row_chunks``, ``[layers, 2]``: the chunks the layers'
         loops ran, whose ratio to the chunks that all chosen pairs would
-        take is the share of them moved. Under block diffusion also the
+        take is the share of them moved. From ``row_tiles``, ``[layers,
+        2]``: the row tiles the grouped kernels visited (a tile once an
+        expert with a row in it) and the row tiles of the chunks the
+        loops ran: visited over ``moe_rows`` / the tile's rows is what
+        the tiles' padding costs, visited over the chunks' tiles what
+        the skipped tiles save. Under block diffusion also the
         step's ``masked_tokens`` of its ``tokens`` (the rows' own, not
         the doubled sequence's), and from ``attn_tiles``, ``[layers,
         2]``: the tiles the attention's forward kernel visited of the
@@ -1636,17 +1646,21 @@ class SparseMoELM(nn.Module):
         kind of layer. A layer sows what it has: a dense layer no expert
         counter, so no array here has a row a layer of the model."""
         by_expert, chunks = sown["expert_rows"], sown["row_chunks"]
+        tiles = sown["row_tiles"]
         rows, f = float(by_expert.sum()), float(drop_fraction or 0.0)
         fields = dict(
             moe_rows=rows, moe_rows_max=float(by_expert.max()),
             moe_rows_mean=rows / by_expert.size,
             moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows,
-            moe_row_chunks=float(chunks[:, 0].sum()))
+            moe_row_chunks=float(chunks[:, 0].sum()),
+            moe_row_tiles=float(tiles[:, 0].sum()))
         counters = {"train.moe.rows": rows,
                     "train.moe.pairs_dropped": fields["moe_pairs_dropped"],
                     "train.moe.row_chunks": fields["moe_row_chunks"]}
         gauges = {"train.moe.rows_max": fields["moe_rows_max"],
-                  "train.moe.row_chunks_possible": float(chunks[:, 1].sum())}
+                  "train.moe.row_chunks_possible": float(chunks[:, 1].sum()),
+                  "train.moe.row_tiles_visited": fields["moe_row_tiles"],
+                  "train.moe.row_tiles": float(tiles[:, 1].sum())}
         if "masked_tokens" in sown:
             fields.update(
                 diffusion_masked_tokens=float(sown["masked_tokens"].sum()),

@@ -1,0 +1,509 @@
+"""The held experts' products on a chunk of sorted pairs, as Pallas
+grouped-matmul kernels over the row tiles that hold a live row, for TPU.
+
+A chunk is ``chunk`` rows sorted by expert: the rows of group 0, then of
+group 1, ... (``sizes [groups]`` rows each), then rows of no group (pairs
+of experts held elsewhere). The kernels tile the rows by ``tile`` and
+walk a table of VISITS made from ``sizes`` on the device
+(:func:`visit_table`) that reaches them by scalar prefetch: a visit is
+one (row tile, group) pair with a row in common, in row order, so a tile
+that three experts share is visited three times, each time under the
+mask of that group's rows, and a tile with no live row is never read.
+The grid is static (``chunk / tile + groups - 1`` visits, the most a
+chunk can take): steps past the last visit run nothing and their blocks'
+index maps repeat the last visit's, so they move nothing either.
+
+Forward, two kernels a chunk:
+
+- ``moe_gmm_in``: ``xs [chunk, d] x (w_gate[e], w_up[e]) [d, f]``,
+  float32 sums in VMEM, epilogue ``silu(a) * b``: writes ``hidden
+  [chunk, f]`` in the compute dtype once;
+- ``moe_gmm_down``: ``hidden x w_down[e] [f, d]``, epilogue ``* gate``:
+  writes ``gate * ys [chunk, d]`` float32, what the caller scatter-adds.
+
+Backward, four:
+
+- ``moe_gmm_bwd_hidden``: recomputes ``a``, ``b``, takes ``d_hidden = dy
+  x w_down[e]^T`` for the same rows and writes ``d_a``, ``d_b`` and
+  ``hidden * gate`` ``[chunk, f]`` in the compute dtype and ``d_gate
+  [chunk, 1] = sum(hidden * d_hidden)`` float32;
+- ``moe_gmm_dx``: ``d_a x w_gate[e]^T + d_b x w_up[e]^T`` ``[chunk, d]``
+  float32, for the caller's scatter-add;
+- ``moe_gmm_dw_in`` and ``moe_gmm_dw_down``: ``xs^T d_a``, ``xs^T d_b``
+  and ``(hidden * gate)^T dy`` over each group's rows, ADDED in place
+  (``input_output_aliases``) to float32 sums ``[groups, d, f]`` /
+  ``[groups, f, d]`` that the caller carries; the block of a group with
+  no row in the chunk is never fetched nor written.
+
+What a caller may rely on: every row of the three outputs it sums
+(``gate * ys``, ``dx``'s rows, ``d_gate``) is WRITTEN, zeros where the
+row is of no group (tiles without a live row are visited for that alone,
+after the live ones: a store and no read); ``hidden``, ``d_a``, ``d_b``
+and ``hidden * gate`` are written in the visited tiles only (zeros in
+their rows of no group) and read by these kernels only, under the same
+table. Operands enter every product in the compute dtype, every sum and
+every epilogue is float32; ``tests/test_grouped_mlp.py`` holds each
+kernel to the same arithmetic in plain ``jax.numpy`` on whole arrays.
+
+Blocks. A kernel's grid is ``(visits, column blocks)``, the columns
+inside: a row tile's operands stay in VMEM over its column blocks, an
+expert's weights over its consecutive visits, and an output row tile is
+one block over all columns (a step stores its columns' slice), so that
+the visits of a shared tile are consecutive steps of one resident block.
+The weight gradients' grid is ``(row blocks, column blocks, visits)``,
+the visits inside, a group's product summed in VMEM and added to the
+carried sum at the group's last visit. Widths come from the shapes alone
+(:func:`row_tile`, ``_widest``). Off the TPU the kernels run in
+interpret mode; a chunk that ``tile`` does not divide is an error
+everywhere: there is no other path.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from sparktorch_tpu.ops.sparse_attention import _LANES, _interpret
+
+# Rows a tile where an expert expects that many or more; an expert that
+# expects fewer takes the power of two below its expectation, down to
+# ``_MIN_ROW_TILE`` (the MXU's side: a narrower tile only pads).
+_ROW_TILE = 512
+_MIN_ROW_TILE = 128
+# What a kernel's blocks (the pipeline's two copies of each) and the
+# float32 values it holds between them may take of VMEM, by the count
+# each kernel's wrapper makes from its shapes: a kernel takes the widest
+# column blocks that fit, the whole width where it can, because an
+# expert's matrix that is ONE block stays in VMEM over the expert's
+# visits and is fetched in whole rows (at LFM2's widths, 2,048 / 1,792,
+# ``moe_gmm_down`` takes 1.04 ms a chunk of 16,384 live rows whole and
+# 1.55 in four column blocks, ``moe_gmm_dx`` 1.76 and 2.53: TPU v5e,
+# PR 47). The limit is what the compiler is told (its own, 16 MiB, is
+# under one expert's blocks; the chip has 128 MiB).
+_VMEM_BUDGET = 56 << 20
+_VMEM_LIMIT = 100 << 20
+
+_TABLE = 6  # scalar-prefetch operands of every kernel: visit_table's
+
+
+def row_tile(chunk: int, expected_rows: int) -> int:
+    """Rows a tile for chunks of ``chunk`` rows of which an expert
+    expects ``expected_rows``: the largest power of two that divides
+    ``chunk``, is at most ``_ROW_TILE`` and, above ``_MIN_ROW_TILE``, at
+    most ``expected_rows``."""
+    tile = 1
+    while (chunk % (2 * tile) == 0 and 2 * tile <= _ROW_TILE
+           and 2 * tile <= max(expected_rows, _MIN_ROW_TILE)):
+        tile *= 2
+    return tile
+
+
+def _blocks_of(width: int, block) -> int:
+    """How many blocks of ``block`` columns ``width`` is: a width given
+    by hand that does not divide it is an error, no ragged last block."""
+    if width % block:
+        raise ValueError(f"blocks of {block} do not divide a width of "
+                         f"{width}")
+    return width // block
+
+
+def _widest(width: int, fits) -> int:
+    """The widest block of ``width`` columns, a multiple of 128 lanes
+    that divides it, whose kernel ``fits(columns)`` says fits VMEM; the
+    narrowest such block where none does, and ``width`` itself where 128
+    lanes do not divide it (the compiler then says what it takes)."""
+    blocks = [c for c in range(width, 0, -_LANES)
+              if width % c == 0 and c % _LANES == 0]
+    return next((c for c in blocks if fits(c)), blocks[-1] if blocks
+                else width)
+
+
+def _running(a):
+    """``cumsum`` of a short int32 vector by comparison, one fusion: a
+    scan, like a gather of scalars, is passes of its own on the TPU
+    (PERF.md section 6, PR 32)."""
+    upto = jnp.arange(a.size)[:, None] >= jnp.arange(a.size)[None, :]
+    return jnp.sum(jnp.where(upto, a[None, :], 0), -1, dtype=jnp.int32)
+
+
+def _tiles_taken(sizes, tile: int):
+    """``(ends, takes)``: the row each group ends at, and the row tiles
+    it has a row in (none for an empty group)."""
+    ends = _running(sizes)
+    return ends, jnp.where(
+        sizes > 0, (ends - 1) // tile - (ends - sizes) // tile + 1, 0)
+
+
+def tiles_visited(sizes, tile: int):
+    """The visits :func:`visit_table` gives the kernels for ``sizes``:
+    each group's tiles, a shared tile once a group."""
+    return jnp.sum(_tiles_taken(sizes, tile)[1])
+
+
+def visit_table(sizes, chunk: int, tile: int):
+    """The kernels' table for a chunk whose groups hold ``sizes`` rows
+    (int32 ``[groups]``, in row order from row 0): six int32 arrays,
+    ``group``, ``tile``, ``tile_out``, ``lo``, ``hi`` of ``[visits]``
+    and ``counts [2]``.
+
+    Visit ``v < counts[0]`` is row tile ``tile[v]`` under group
+    ``group[v]``, whose rows are ``[lo[v], hi[v])`` of the chunk; the
+    visits run by group, then by tile, so a tile's visits are
+    consecutive. Visits ``counts[0] <= v < counts[1]`` are the tiles
+    without a live row, ``tile_out[v]``, for the kernels that write
+    zeros there. Past its range each array repeats its last entry in
+    range (``tile`` and ``group`` the last live visit's, ``tile_out``
+    the last tile's), which is what keeps a block where it is."""
+    if chunk % tile:
+        raise ValueError(f"a row tile of {tile} does not divide the chunk "
+                         f"of {chunk} rows")
+    n_tiles, groups = chunk // tile, sizes.size
+    ends, takes = _tiles_taken(sizes, tile)
+    visit_ends = _running(takes)
+    n_live, live_tiles = visit_ends[-1], -(-ends[-1] // tile)
+    v = jnp.arange(n_tiles + groups - 1, dtype=jnp.int32)
+    at = jnp.minimum(v, jnp.maximum(n_live - 1, 0))
+    group = jnp.minimum(jnp.sum(at[:, None] >= visit_ends[None, :], -1,
+                                dtype=jnp.int32), groups - 1)
+    # the group's entry by a one-hot sum, no gather
+    hot = group[:, None] == jnp.arange(groups)[None, :]
+    of = lambda a: jnp.sum(jnp.where(hot, a[None, :], 0), -1, dtype=jnp.int32)
+    row_tile_of = of((ends - sizes) // tile) + at - of(visit_ends - takes)
+    live = v < n_live
+    return (group, row_tile_of,
+            jnp.where(live, row_tile_of, jnp.minimum(
+                live_tiles + v - n_live, n_tiles - 1)).astype(jnp.int32),
+            jnp.where(live, of(ends - sizes), 0), jnp.where(live, of(ends), 0),
+            jnp.stack([n_live, n_live + n_tiles - live_tiles]).astype(
+                jnp.int32))
+
+
+# -- what every kernel does with the table ------------------------------------
+
+
+class _Visit:
+    """The grid step's visit, read from the prefetched table: whether it
+    is a live visit, a tile of zeros or nothing, whether its tile (and
+    its group) is met for the first or the last time, whether the whole
+    tile is its group's, and the mask of its group's rows."""
+
+    def __init__(self, table, rows: int, axis: int = 0):
+        self.group, self.tile, self.tile_out, self.lo, self.hi, counts = table
+        self.rows = rows
+        v = self.v = pl.program_id(axis)
+        last = pl.num_programs(axis) - 1
+        before, after = jnp.maximum(v - 1, 0), jnp.minimum(v + 1, last)
+        self.live = v < counts[0]
+        self.zeros = (v >= counts[0]) & (v < counts[1])
+        self.new_tile = (v == 0) | (self.tile_out[v] != self.tile_out[before])
+        self.new_group = (v == 0) | (self.group[v] != self.group[before])
+        self.group_ends = self.live & ((v + 1 >= counts[0])
+                                       | (self.group[after] != self.group[v]))
+        self.whole = ((self.lo[v] <= self.tile[v] * rows)
+                      & (self.hi[v] >= (self.tile[v] + 1) * rows))
+
+    def mask(self, width: int):
+        row = self.tile[self.v] * self.rows + jax.lax.broadcasted_iota(
+            jnp.int32, (self.rows, width), 0)
+        return (row >= self.lo[self.v]) & (row < self.hi[self.v])
+
+
+def _store_columns(ref, j, cols: int, value, mask):
+    """``value`` (the step's ``cols`` columns of a row tile) into block
+    ``j`` of the columns of ``ref``, a whole row tile, on the rows of
+    ``mask``; the other rows keep what the tile's earlier visits (or
+    ``_clear``) left there. The slice is static under a ``pl.when``: a
+    lane offset the program computes is no store the compiler takes."""
+    for jj in range(ref.shape[-1] // cols):
+        @pl.when(j == jj)
+        def _store(at=slice(jj * cols, (jj + 1) * cols)):
+            ref[:, at] = jnp.where(mask, value, ref[:, at])
+
+
+def _clear(visit: _Visit, j, *refs, zero_tiles=False):
+    """A tile's blocks start as zeros, at its first visit's first step:
+    a live tile's, and where ``zero_tiles`` (blocks that follow
+    ``tile_out``) those of a tile without a live row, which stay so."""
+    met = visit.live | visit.zeros if zero_tiles else visit.live
+
+    @pl.when(visit.new_tile & (j == 0) & met)
+    def _zero():
+        for ref in refs:
+            ref[...] = jnp.zeros_like(ref)
+
+
+def _dot(a, b, contract=(1, 0)):
+    return jax.lax.dot_general(a, b, (((contract[0],), (contract[1],)),
+                                      ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _row_spec(rows: int, width: int, table_index: int):
+    """A row tile over all ``width`` columns, at the tile the table's
+    array ``table_index`` (1: read, 2: written with the zero tiles) names."""
+    return pl.BlockSpec((rows, width),
+                        lambda v, j, *table: (table[table_index][v], 0))
+
+
+def _weight_spec(block, where):
+    """A block of the visit's group's matrix: ``where(j)`` its index."""
+    return pl.BlockSpec((None, *block),
+                        lambda v, j, *table: (table[0][v], *where(j)))
+
+
+def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch=(),
+          aliases=None):
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=_TABLE, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=list(scratch)),
+        input_output_aliases=aliases or {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(), name=name)
+
+
+# -- forward ------------------------------------------------------------------
+
+
+def _in_kernel(*refs, cols):
+    table, (x_ref, wg_ref, wu_ref, h_ref) = refs[:_TABLE], refs[_TABLE:]
+    visit, j = _Visit(table, x_ref.shape[0]), pl.program_id(1)
+    _clear(visit, j, h_ref)
+
+    @pl.when(visit.live)
+    def _product():
+        x = x_ref[...]
+        a, b = _dot(x, wg_ref[...]), _dot(x, wu_ref[...])
+        _store_columns(h_ref, j, cols,
+                       (a * jax.nn.sigmoid(a) * b).astype(h_ref.dtype),
+                       visit.mask(cols))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "cols"))
+def gmm_in(table, xs, w_gate, w_up, *, tile, cols=None):
+    """``silu(xs w_gate[e]) * (xs w_up[e])`` in ``xs``'s dtype, ``[chunk,
+    f]``: written in the visited tiles."""
+    (chunk, d), f = xs.shape, w_gate.shape[-1]
+    item = xs.dtype.itemsize
+    # the row tile in and out, two weight blocks, a and b and their
+    # epilogue in float32
+    cols = cols or _widest(f, lambda c: (
+        2 * tile * (d + f) * item + 4 * d * c * item + 16 * tile * c
+        <= _VMEM_BUDGET))
+    w_spec = _weight_spec((d, cols), lambda j: (0, j))
+    return _call(
+        functools.partial(_in_kernel, cols=cols), "moe_gmm_in",
+        (table[0].size, _blocks_of(f, cols)),
+        [_row_spec(tile, d, 1), w_spec, w_spec], _row_spec(tile, f, 1),
+        jax.ShapeDtypeStruct((chunk, f), xs.dtype))(*table, xs, w_gate, w_up)
+
+
+def _out_kernel(*refs, cols, transposed, gated):
+    """``sum_i lhs_i x rhs_i[e]`` (``rhs`` transposed or not), times the
+    row's gate where there is one, float32, zeros in the rows of no
+    group: ``moe_gmm_down`` (one product, gated) and ``moe_gmm_dx`` (two
+    transposed ones)."""
+    table, refs = refs[:_TABLE], refs[_TABLE:]
+    n = (len(refs) - 1 - gated) // 2
+    lhs, rhs, out_ref = refs[:n], refs[n:2 * n], refs[-1]
+    visit, j = _Visit(table, out_ref.shape[0]), pl.program_id(1)
+    _clear(visit, j, out_ref, zero_tiles=True)
+
+    @pl.when(visit.live)
+    def _product():
+        y = sum(_dot(a[...], m[...], (1, 1) if transposed else (1, 0))
+                for a, m in zip(lhs, rhs))
+        if gated:
+            y = y * refs[2 * n][...]
+        _store_columns(out_ref, j, cols, y, visit.mask(cols))
+
+
+def _out_fits(tile, k, width, cols, n, item) -> bool:
+    """Whether ``_out_kernel`` fits at ``cols`` columns a block: ``n``
+    row tiles of ``k`` in and as many weight blocks, the float32 row
+    tile out, a product and its store in float32."""
+    return (2 * n * (tile + cols) * k * item + 8 * tile * width
+            + 8 * tile * cols <= _VMEM_BUDGET)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "cols"))
+def gmm_down(table, hidden, w_down, gate, *, tile, cols=None):
+    """``gate * (hidden w_down[e])`` float32 ``[chunk, d]``, every row
+    written. ``gate`` is ``[chunk, 1]`` float32."""
+    (chunk, f), d = hidden.shape, w_down.shape[-1]
+    cols = cols or _widest(d, lambda c: _out_fits(tile, f, d, c, 1,
+                                                   hidden.dtype.itemsize))
+    return _call(
+        functools.partial(_out_kernel, cols=cols, transposed=False,
+                          gated=True), "moe_gmm_down",
+        (table[0].size, _blocks_of(d, cols)),
+        [_row_spec(tile, f, 1), _weight_spec((f, cols), lambda j: (0, j)),
+         _row_spec(tile, 1, 1)], _row_spec(tile, d, 2),
+        jax.ShapeDtypeStruct((chunk, d), jnp.float32))(
+            *table, hidden, w_down, gate)
+
+
+# -- backward -----------------------------------------------------------------
+
+
+def _bwd_hidden_kernel(*refs, cols):
+    table, refs = refs[:_TABLE], refs[_TABLE:]
+    (x_ref, dy_ref, gate_ref, wg_ref, wu_ref, wd_ref,
+     da_ref, db_ref, hg_ref, dgate_ref) = refs
+    visit, j = _Visit(table, x_ref.shape[0]), pl.program_id(1)
+    _clear(visit, j, da_ref, db_ref, hg_ref)
+    _clear(visit, j, dgate_ref, zero_tiles=True)
+
+    @pl.when(visit.live)
+    def _product():
+        dt = da_ref.dtype
+        x, gate = x_ref[...], gate_ref[...]
+        a, b = _dot(x, wg_ref[...]), _dot(x, wu_ref[...])
+        sig = jax.nn.sigmoid(a)
+        hidden = a * sig * b
+        # ys = hidden w_down, out += gate ys
+        d_hidden = _dot(dy_ref[...], wd_ref[...], (1, 1))  # before the gate
+        d_gate = jnp.sum(hidden.astype(dt).astype(jnp.float32) * d_hidden,
+                         -1, keepdims=True)
+        dgate_ref[...] += jnp.where(visit.mask(1), d_gate, 0.0)
+        d_hidden = d_hidden * gate
+        mask = visit.mask(cols)
+        _store_columns(da_ref, j, cols, (
+            d_hidden * b * sig * (1.0 + a * (1.0 - sig))).astype(dt), mask)
+        _store_columns(db_ref, j, cols, (d_hidden * a * sig).astype(dt), mask)
+        _store_columns(hg_ref, j, cols, (hidden * gate).astype(dt), mask)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "cols"))
+def gmm_bwd_hidden(table, xs, dy, gate, w_gate, w_up, w_down, *, tile,
+                   cols=None):
+    """``(d_a, d_b, hidden * gate, d_gate)`` of a chunk: the cotangents
+    of ``a = xs w_gate[e]`` and ``b = xs w_up[e]`` and the gated hidden
+    rows in ``xs``'s dtype, ``[chunk, f]`` each (written in the visited
+    tiles), and ``d_gate [chunk, 1]`` float32, every row written."""
+    (chunk, d), f = xs.shape, w_gate.shape[-1]
+    item = xs.dtype.itemsize
+    # two row tiles in, three out, three weight blocks, and eight
+    # float32 values of a row tile's columns between them
+    cols = cols or _widest(f, lambda c: (
+        (4 * tile * d + 6 * tile * f + 6 * d * c) * item + 32 * tile * c
+        <= _VMEM_BUDGET))
+    rows_d, rows_f = _row_spec(tile, d, 1), _row_spec(tile, f, 1)
+    w_spec = _weight_spec((d, cols), lambda j: (0, j))
+    wide = jax.ShapeDtypeStruct((chunk, f), xs.dtype)
+    return _call(
+        functools.partial(_bwd_hidden_kernel, cols=cols),
+        "moe_gmm_bwd_hidden", (table[0].size, _blocks_of(f, cols)),
+        [rows_d, rows_d, _row_spec(tile, 1, 1), w_spec, w_spec,
+         _weight_spec((cols, d), lambda j: (j, 0))],
+        [rows_f, rows_f, rows_f, _row_spec(tile, 1, 2)],
+        [wide, wide, wide, jax.ShapeDtypeStruct((chunk, 1), jnp.float32)])(
+            *table, xs, dy, gate, w_gate, w_up, w_down)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "cols"))
+def gmm_dx(table, d_a, d_b, w_gate, w_up, *, tile, cols=None):
+    """``d_a w_gate[e]^T + d_b w_up[e]^T`` float32 ``[chunk, d]``, every
+    row written."""
+    (chunk, f), d = d_a.shape, w_gate.shape[1]
+    cols = cols or _widest(d, lambda c: _out_fits(tile, f, d, c, 2,
+                                                   d_a.dtype.itemsize))
+    rows_f = _row_spec(tile, f, 1)
+    w_spec = _weight_spec((cols, f), lambda j: (j, 0))
+    return _call(
+        functools.partial(_out_kernel, cols=cols, transposed=True,
+                          gated=False), "moe_gmm_dx",
+        (table[0].size, _blocks_of(d, cols)), [rows_f, rows_f, w_spec, w_spec],
+        _row_spec(tile, d, 2),
+        jax.ShapeDtypeStruct((chunk, d), jnp.float32))(
+            *table, d_a, d_b, w_gate, w_up)
+
+
+def _dw_kernel(*refs, n):
+    """``sums_i[e] += lhs[rows of e]^T rhs_i[rows of e]`` for ``n``
+    right-hand sides of one left-hand side."""
+    table, refs = refs[:_TABLE], refs[_TABLE:]
+    lhs_ref, rhs, sums = refs[0], refs[1:1 + n], refs[1 + n:1 + 2 * n]
+    outs, accs = refs[1 + 2 * n:1 + 3 * n], refs[1 + 3 * n:]
+    visit = _Visit(table, lhs_ref.shape[0], axis=2)
+
+    @pl.when(visit.new_group)
+    def _start():
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
+
+    def product(lhs):
+        for acc, b_ref in zip(accs, rhs):
+            acc[...] += _dot(lhs, b_ref[...], (0, 0))
+
+    # most tiles are one group's from end to end: the mask is a pass
+    # over the tile that only a group's first and last tile need
+    @pl.when(visit.live & visit.whole)
+    def _whole():
+        product(lhs_ref[...])
+
+    @pl.when(visit.live & jnp.logical_not(visit.whole))
+    def _part():
+        lhs = lhs_ref[...]
+        product(jnp.where(visit.mask(lhs.shape[1]), lhs,
+                          jnp.zeros_like(lhs)))
+
+    @pl.when(visit.group_ends)
+    def _add():
+        for out, base, acc in zip(outs, sums, accs):
+            out[...] = base[...] + acc[...]
+
+    # a chunk without a live row still brings group 0's block out: as it was
+    @pl.when((visit.v == 0) & jnp.logical_not(visit.live))
+    def _keep():
+        for out, base in zip(outs, sums):
+            out[...] = base[...]
+
+
+def _gmm_dw(name, table, lhs, rhs, sums, tile, block):
+    chunk, k = lhs.shape
+    n, width, item = len(rhs), rhs[0].shape[1], lhs.dtype.itemsize
+    # the sums' blocks in and out and the product's in VMEM, float32;
+    # the row tiles in, the left one turned. All the columns where they
+    # fit (the left tile is turned once a column block), then the rows
+    fits = lambda r, c: (20 * n * r * c + 3 * tile * r * item
+                         + 2 * n * tile * c * item <= _VMEM_BUDGET)
+    cols = block[1] if block else _widest(width, lambda c: fits(_LANES, c))
+    rows = block[0] if block else _widest(k, lambda r: fits(r, cols))
+    sum_spec = pl.BlockSpec(
+        (None, rows, cols), lambda i, j, v, *table: (table[0][v], i, j))
+    rhs_spec = pl.BlockSpec((tile, cols),
+                            lambda i, j, v, *table: (table[1][v], j))
+    return _call(
+        functools.partial(_dw_kernel, n=n), name,
+        (_blocks_of(k, rows), _blocks_of(width, cols), table[0].size),
+        [pl.BlockSpec((tile, rows), lambda i, j, v, *table: (table[1][v], i)),
+         *[rhs_spec] * n, *[sum_spec] * n], [sum_spec] * n,
+        [jax.ShapeDtypeStruct(s.shape, s.dtype) for s in sums],
+        scratch=[pltpu.VMEM((rows, cols), jnp.float32)] * n,
+        aliases={_TABLE + 1 + n + i: i for i in range(n)})(
+            *table, lhs, *rhs, *sums)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "block"))
+def gmm_dw_in(table, xs, d_a, d_b, dw_gate, dw_up, *, tile, block=None):
+    """``(dw_gate + xs^T d_a, dw_up + xs^T d_b)`` over each group's
+    rows, float32 ``[groups, d, f]``, in place: a group with no row in
+    the chunk keeps its sum as it is."""
+    return _gmm_dw("moe_gmm_dw_in", table, xs, (d_a, d_b), (dw_gate, dw_up),
+                   tile, block)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "block"))
+def gmm_dw_down(table, hg, dy, dw_down, *, tile, block=None):
+    """``dw_down + (hidden * gate)^T dy`` over each group's rows,
+    float32 ``[groups, f, d]``, in place."""
+    return _gmm_dw("moe_gmm_dw_down", table, hg, (dy,), (dw_down,), tile,
+                   block)[0]

@@ -213,7 +213,7 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     policy, which keeps the attention kernel's output and row
     statistics: the compiled program holds one ``sparse_attn_fwd`` a
     layer and none for the remat's second forward pass, so a compiler
-    that put the call back would show here. The grouped products of the
+    that put the call back would show here. The grouped kernels of the
     expert layer sit in the bodies of two loops over chunks of rows
     (forward and backward: the remat's second forward needs no result of
     the loop either and is gone), and the gradient's temporaries are
@@ -243,17 +243,22 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # 4,000 padded to 4,096
     assert not _o_sized_copies(text, 8192)  # o leaves flat and is read so
 
-    grouped = re.compile(r"%ragged-dot-none[.\d]* = ")
     comps = _computations(text)
     bodies = {m.group(1) for lines in comps.values() for line in lines
               if " while(" in line
               for m in [re.search(r"body=(%[\w.\-]+)", line)]}
-    calls = {name: sum(bool(grouped.search(line)) for line in lines)
-             for name, lines in comps.items()}
-    # gate|up and down; the same again recomputed, the cotangents of
-    # both left operands and the two weight gradients
-    assert sorted(calls[b] for b in bodies if calls[b]) == [2, 5]
-    assert sum(calls.values()) == 7  # none outside the loops
+    # the grouped kernels of ops/grouped_mlp.py: gate|up with SwiGLU and
+    # down with the gate forward; the hidden rows recomputed with their
+    # cotangent, dx and the two weight gradients backward
+    kernels = ("moe_gmm_in", "moe_gmm_down", "moe_gmm_bwd_hidden",
+               "moe_gmm_dx", "moe_gmm_dw_in", "moe_gmm_dw_down")
+    calls = {body: [_pallas_calls("\n".join(comps[body]), kernel)
+                    for kernel in kernels] for body in bodies}
+    assert sorted(c for c in calls.values() if any(c)) == [
+        [0, 0, 1, 1, 1, 1], [1, 1, 0, 0, 0, 0]]
+    for kernel in kernels:  # none outside the loops
+        assert _pallas_calls(text, kernel) == 1, kernel
+    assert "ragged-dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6_009_584_128 // 2
 
 

@@ -386,8 +386,8 @@ def test_the_cut_configuration_counts_424_million_parameters():
 # gradient of the loss with its counters); this PR means to change none
 # and has not touched those hashes. The fourth, JoyAI-LLM-Flash, by the
 # same lines at that file's sizes, read on the parent commit 565801a
-# (PR 41):
-JOYAI_PARENT = ("cbffab51f8326b72", "12cedd238ba604e3")
+# (PR 41), its step anew at PR 47 (the experts' kernels):
+JOYAI_PARENT = ("cbffab51f8326b72", "0e9bf84f1ce74921")
 
 
 def _joyai_hashes():

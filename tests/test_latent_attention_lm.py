@@ -310,11 +310,15 @@ def test_the_published_model_and_what_a_configuration_may_not_say():
 # counters, at the sizes below. The trees are the ones read on commit
 # 6539c26 (PR 38) by the same lines: no PR since has moved a leaf. The
 # steps were read anew at PR 41, which meant to change them (``o``
-# leaves the attention kernels flat and ``Wo`` reads it so). A PR that
-# means to change one of these models' steps reads them anew.
-PARENT = {"keye": ("96e560cdd12a17e7", "ea4bc228b53e2164"),
-          "sdar": ("b60f6b0d23c7320d", "de7733f5cfb06b03"),
-          "laguna": ("0ce980a58c390130", "ccdf118bcc6d0167")}
+# leaves the attention kernels flat and ``Wo`` reads it so), and at PR
+# 47, which meant to as well (the held experts' products are
+# ``ops/grouped_mlp.py``'s kernels, in all five older models and in
+# ``tests/test_gated_delta_lm.py``'s and ``tests/test_short_conv_lm.py``'s
+# hashes too). A PR that means to change one of these models' steps
+# reads them anew.
+PARENT = {"keye": ("96e560cdd12a17e7", "56fe5f74c72bced4"),
+          "sdar": ("b60f6b0d23c7320d", "3ad78a5fe49982a3"),
+          "laguna": ("0ce980a58c390130", "64ac99ff5d5c0381")}
 
 
 def older_model(name):

@@ -780,6 +780,43 @@ def test_row_chunks_in_the_records_are_the_chunks_the_rows_take(
     assert 0 < share < 0.5  # 2 of 16 experts held
 
 
+def test_row_tiles_in_the_records_are_the_tiles_the_kernels_visit(
+        monkeypatch):
+    """``row_tiles`` against a count on the host from ``expert_rows``:
+    every (row tile, expert) pair with a row in common, chunk by chunk
+    of 64 pairs in tiles of 16, of the tiles the chunks that ran hold;
+    and through the trainer the record's ``moe_row_tiles`` and the two
+    gauges on the bus."""
+    from sparktorch_tpu.ops import grouped_mlp as G
+
+    chunk, tile = 64, 16
+    chunks_of(monkeypatch, chunk)
+    monkeypatch.setattr(G, "_MIN_ROW_TILE", tile)
+    layer, params, g = _seeded_layer()
+    sown = layer.apply({"params": params}, g,
+                       mutable=["moe_metrics"])[1]["moe_metrics"]
+    rows = np.asarray(sown["expert_rows"][0])
+    ends = np.cumsum(rows)
+    trips = -(-ends[-1] // chunk)
+    visits = sum(
+        (min(hi, (c + 1) * chunk) - 1) // tile - max(lo, c * chunk) // tile + 1
+        for c in range(trips) for lo, hi in zip(ends - rows, ends)
+        if max(lo, c * chunk) < min(hi, (c + 1) * chunk))
+    assert np.array_equal(sown["row_tiles"][0],
+                          [visits, trips * (chunk // tile)])
+    assert -(-ends[-1] // tile) <= visits < trips * (chunk // tile)
+
+    records, _, tele = _train(1, iters=3, layers=1, steps_per_call=1)
+    for r in records:
+        whole = -(-r["moe_rows"] // tile)
+        # a tile more for each expert after the first, each trip
+        assert whole <= r["moe_row_tiles"] <= whole + r["moe_row_chunks"]
+    assert tele.gauge_value("train.moe.row_tiles_visited") \
+        == records[-1]["moe_row_tiles"]
+    assert tele.gauge_value("train.moe.row_tiles") \
+        == records[-1]["moe_row_chunks"] * (chunk // tile)
+
+
 def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
     import optax
 
@@ -806,37 +843,67 @@ def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
                           iters=1)
 
 
-def test_rows_ragged_dot_leaves_undefined_never_reach_a_sum(monkeypatch):
-    """On the TPU ``ragged_dot`` does not write the rows of its result
-    past the groups (the CPU's zero-fills them, which hid it: the chip's
-    gradients read 25x the reference's, PR 27), and the layer's backward
-    pass, its own, takes the cotangent of a product's left operand by
-    such a product too. Poison those rows of every grouped product, on
-    the tail of the last chunk (150 or so held rows in chunks of 64),
-    and the groups without a row of every weight gradient: the layer's
-    output and every gradient must be what they are without the poison."""
-    real, real_general = jax.lax.ragged_dot, jax.lax.ragged_dot_general
+def _poisoned_grouped_kernels(monkeypatch, met):
+    """``ops/grouped_mlp.py``'s kernels with NaN in every row tile they
+    do not visit (of each array they read) and every block they do not
+    write (of each array they return): what the chip leaves there is
+    whatever the memory held. ``met`` collects the arrays poisoned."""
+    from sparktorch_tpu.ops import grouped_mlp as G
 
-    def poisoned(a, m, sizes, preferred_element_type=None):
-        out = real(a, m, sizes, preferred_element_type=preferred_element_type)
-        past = jnp.arange(out.shape[0])[:, None] >= jnp.sum(sizes)
-        return jnp.where(past, jnp.nan, out)
+    def unvisited(table, a, tile):
+        # the table's tiles repeat the last live visit's past it
+        live_tiles = jnp.where(table[5][0] > 0, table[1][-1] + 1, 0)
+        past = (jnp.arange(a.shape[0]) // tile >= live_tiles)[:, None]
+        met.append(a.shape)
+        return jnp.where(past, jnp.nan, a).astype(a.dtype)
 
-    def poisoned_general(a, b, sizes, dims, preferred_element_type=None):
-        out = real_general(a, b, sizes, dims,
-                           preferred_element_type=preferred_element_type)
-        return jnp.where((sizes == 0)[:, None, None], jnp.nan, out)
+    def poisoned(kernel, rows_in, rows_out):
+        def call(table, *args, tile, **kw):
+            args = [unvisited(table, a, tile) if i in rows_in else a
+                    for i, a in enumerate(args)]
+            out = kernel(table, *args, tile=tile, **kw)
+            several = isinstance(out, (tuple, list))
+            outs = [unvisited(table, a, tile) if i in rows_out else a
+                    for i, a in enumerate(out if several else [out])]
+            return outs if several else outs[0]
+        return call
+
+    for name, rows_in, rows_out in (
+            ("gmm_in", (0,), (0,)), ("gmm_down", (0,), ()),
+            ("gmm_bwd_hidden", (0, 1), (0, 1, 2)), ("gmm_dx", (0, 1), ()),
+            ("gmm_dw_in", (0, 1, 2), ()), ("gmm_dw_down", (0, 1), ())):
+        monkeypatch.setattr(G, name, poisoned(getattr(G, name), rows_in,
+                                              rows_out))
+
+
+def test_rows_and_tiles_the_grouped_kernels_skip_never_reach_a_sum(
+        monkeypatch):
+    """On the TPU a kernel's output block that no grid step writes holds
+    what the memory held (``ragged_dot`` left the rows past its groups
+    so, which the CPU's zero-fill hid: the chip's gradients read 25x the
+    reference's, PR 27), and the grouped kernels visit only the row
+    tiles with a held pair. Poison (NaN) every tile they do not visit,
+    of everything they read and of every result they need not write, on
+    the tail of the last chunk (150 or so held rows in chunks of 64,
+    tiles of 16): the layer's output and every gradient must be what
+    they are without the poison, and finite."""
+    from sparktorch_tpu.ops import grouped_mlp as G
 
     chunks_of(monkeypatch, 64)
+    monkeypatch.setattr(G, "_MIN_ROW_TILE", 16)
     layer, params, g = _seeded_layer()
     loss = lambda p, g: jnp.sum(jnp.sin(layer.apply({"params": p}, g)))
     want = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
-    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
-    monkeypatch.setattr(jax.lax, "ragged_dot_general", poisoned_general)
+    met = []
+    _poisoned_grouped_kernels(monkeypatch, met)
     got = jax.value_and_grad(loss, argnums=(0, 1))(params, g)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-    # and the poison is there to be met: a product alone shows it
-    assert np.isnan(np.asarray(jax.lax.ragged_dot(
-        jnp.ones((4, 2)), jnp.ones((1, 2, 2)), jnp.asarray([3])))[3]).all()
+    # and the poison is there to be met: the arrays the six kernels of
+    # one gradient read by row tile, and the four they write so
+    assert len(met) == (1 + 1) + 1 + (2 + 3) + 2 + 3 + 2
+    sown = layer.apply({"params": params}, g,
+                       mutable=["moe_metrics"])[1]["moe_metrics"]
+    visited, held = np.asarray(sown["row_tiles"][0])
+    assert 0 < visited < held  # tiles of the last chunk are skipped
