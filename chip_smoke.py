@@ -804,10 +804,47 @@ def _ragged_experts_sum(x, token, gate, rows, w_gate, w_up, w_down):
         rdot(hidden, w_down) * gate[:, None])
 
 
+def _sums_back_ms(x, token, gate, rows, weights, d_out, chunk) -> list:
+    """Milliseconds of ``ops/grouped_mlp.py``'s ``sum_back`` alone on the
+    first chunk's rows as the layer's two passes give them (``gate *
+    ys`` forward, ``dx``'s rows backward): a loop of twenty calls each
+    into one carried sum, since ONE call reads its dispatch."""
+    import functools
+
+    import jax
+
+    from sparktorch_tpu.models import sparse_moe_lm as M
+    from sparktorch_tpu.ops import grouped_mlp as G
+
+    tile = M._row_tile(chunk, rows.size)
+    ck = M._Chunk(0, x, *M._padded_pairs(token, gate, chunk), rows, chunk,
+                  tile)
+    w_gate, w_up, w_down = (w.astype(x.dtype) for w in weights)
+    ys = G.gmm_down(ck.table, G.gmm_in(ck.table, ck.xs, w_gate, w_up,
+                                       tile=tile), w_down, ck.gate, tile=tile)
+    d_a, d_b, _, _ = G.gmm_bwd_hidden(
+        ck.table, ck.xs, d_out.astype(x.dtype)[ck.token], ck.gate, w_gate,
+        w_up, w_down, tile=tile)
+    dx = G.gmm_dx(ck.table, d_a, d_b, w_gate, w_up, tile=tile)
+    back = jax.jit(functools.partial(G.sum_back, tile=tile),
+                   donate_argnums=1)
+    out = []
+    for moved in (ys, dx):
+        sums = jax.block_until_ready(back(
+            ck.table, G.token_sums(*x.shape), moved, ck.token))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            sums = back(ck.table, sums, moved, ck.token)
+        jax.block_until_ready(sums)
+        out.append((time.perf_counter() - t0) / 20 * 1e3)
+    return out
+
+
 def phase_grouped_mlp(sz: Sizes, seed: int, ctx: dict) -> str:
     """The expert layer's sum through ``ops/grouped_mlp.py``'s kernels
     against its ``ragged_dot`` spelling at LFM2's layer: the output and
-    the five gradients, forward and gradient timed apart."""
+    the five gradients, forward and gradient timed apart, and the sums
+    back of a chunk's rows to their tokens alone."""
     import jax
     import jax.numpy as jnp
 
@@ -843,9 +880,13 @@ def phase_grouped_mlp(sz: Sizes, seed: int, ctx: dict) -> str:
     rels.update({name: _rel(a, b) for name, a, b in zip(
         ("x", "gate", "w_gate", "w_up", "w_down"), grads,
         grad_of(plain)(*operands))})
+    back_ms = _sums_back_ms(x, token, gate, rows, (w_gate, w_up, w_down),
+                            weight, chunk)
     report = (f"tokens={n} held_rows={int(rows.sum())} chunk={chunk} "
               f"rel={ {n: float(f'{r:.2e}') for n, r in rels.items()} } "
-              f"fwd_s={fwd_s:.5f} grad_s={grad_s:.5f}")
+              f"fwd_s={fwd_s:.5f} grad_s={grad_s:.5f} "
+              f"sum_back_fwd_ms={back_ms[0]:.3f} "
+              f"sum_back_bwd_ms={back_ms[1]:.3f}")
     if not max(rels.values()) <= TOL_GROUPED_REL:       # NaN fails too
         raise AssertionError(
             f"the grouped kernels vs the ragged_dot spelling: {report} "

@@ -85,10 +85,12 @@ One layer, for the tokens ``x`` of a row, in the published order:
   tile by the expert (or, one after the other, the experts) whose rows
   lie in it: SwiGLU, the gate and the row masks are their epilogues,
   and no float32 array of ``[chunk, expert_width]`` lies between two of
-  them. So the rows gathered and scattered are the rows held, rounded up
-  to a chunk, and the rows multiplied are the rows held, rounded up to a
-  tile an expert, at any load: a holder of every expert runs every
-  chunk. There is no capacity and nothing is dropped.
+  them. A tile's rows of an expert go back to their tokens' float32
+  sums by a DMA a row (``moe_sum_back``; no scatter-add). So the rows
+  gathered are the rows held, rounded up to a chunk, the rows multiplied
+  are the rows held, rounded up to a tile an expert, and the rows summed
+  back are the rows held, at any load: a holder of every expert runs
+  every chunk. There is no capacity and nothing is dropped.
 
 After the last layer RMSNorm and a head over ``vocab_size`` rows (a
 slice of the published vocabulary, when the configuration says so):
@@ -377,7 +379,9 @@ Counters sown into ``moe_metrics`` each forward pass: ``expert_rows``
 (rows computed by each held expert), ``row_chunks`` (the chunks the
 loop ran, and the chunks that all chosen pairs would take), ``row_tiles``
 (the row tiles the grouped kernels visited in those chunks, a tile once
-an expert with a row in it, and the row tiles those chunks hold), ``routed``
+an expert with a row in it, and the row tiles those chunks hold),
+``rows_summed`` (the rows the loop's sums back add to their tokens, by
+the table they walk, and the rows of those chunks), ``routed``
 (chosen pairs whose expert is held) and ``dropped`` (those of them that
 the grouped products, chunk by chunk, did not multiply by their own
 expert's weights: 0), by each layer that holds experts (a dense layer
@@ -438,13 +442,15 @@ _IDX_Q_CHUNK = 1_024
 # skip a chunk's row tiles without a held pair and leave an expert
 # without a row alone: 3.3 ms forward and 9.7 backward for 16,384 held
 # rows of 32,768 at LFM2's widths, 4.8 and 14.3 for all 32,768; TPU v5e,
-# the kernels alone, PERF.md section 6, PR 47). What a trip still pays
-# by the CHUNK, or before its first row, is XLA's: three gathers of the
-# chunk's rows, two scatter-adds that pass over every token's float32
-# sum, the weights' casts and the kernels' table. So a layer's usual
-# load should still take ONE trip: a chunk of exactly the share ran one
-# trip or two by the step's rows (13 ms apart then; PERF.md section 6,
-# PR 28 and PR 32).
+# the kernels alone, PERF.md section 6, PR 47), and so do the rows' sums
+# back to their tokens since PR 48 (``moe_sum_back``: two DMAs a held
+# row, 1.16 ms for 16,384 held rows of 32,768 where XLA's scatter-add of
+# the chunk took 3.25, 0.32 for 4,096 of 8,192; PERF.md section 6, PR
+# 48). What a trip still pays by the CHUNK, or before its first row, is
+# XLA's: three gathers of the chunk's rows, the weights' casts and the
+# kernels' table. So a layer's usual load should still take ONE trip: a
+# chunk of exactly the share ran one trip or two by the step's rows (13
+# ms apart then; PERF.md section 6, PR 28 and PR 32).
 _CHUNK_OVER_SHARE = 2
 # The vocabulary tile of ``ops/fused_ce.py``, which the head pads to.
 _CE_BLOCK_V = 512
@@ -1302,6 +1308,11 @@ class HeldExperts(nn.Module):
         self.sow("moe_metrics", "row_tiles", jnp.stack(
             [jnp.sum(jax.vmap(lambda s: grouped.tiles_visited(s, tile))(
                 sizes)), trips * (chunk // tile)]))
+        # from the table the sums back walk, not from ``rows``
+        self.sow("moe_metrics", "rows_summed", jnp.stack(
+            [jnp.sum(jax.vmap(lambda s: grouped.rows_summed(
+                grouped.visit_table(s, chunk, tile), tile))(sizes)),
+             trips * chunk]))
         self.sow("moe_metrics", "routed", n_pairs)
         self.sow("moe_metrics", "dropped", n_pairs - jnp.sum(covered))
         return out.reshape(b, t, d)
@@ -1348,11 +1359,16 @@ class _Chunk:
     tiles its held rows lie in (``ops/grouped_mlp.py``).
 
     Rows past the held pairs belong to no group (their experts are held
-    elsewhere): no kernel reads them, and what the loops sum over the
-    chunk's rows (the gated outputs, ``dx``'s rows, ``d_gate``) the
-    kernels write as zeros there. Nothing is left unwritten and read: a
+    elsewhere): no kernel reads them, the sums back to the tokens
+    (``grouped.sum_back``: the gated outputs, ``dx``'s rows) add the
+    rows of a group only, and ``d_gate``, which is copied whole, the
+    kernel writes as zeros there. Nothing is left unwritten and read: a
     grouped product that left such rows undefined put 25x gradients on
-    the chip (XLA's own, PR 27)."""
+    the chip (XLA's own, PR 27).
+
+    ``token`` is what ``sum_back`` rests on: the sort is stable over
+    pairs in token order, so one expert's rows ascend in token, and
+    ``top_k`` gives a token an expert at most once, so none repeats."""
 
     def __init__(self, c, x, token, gate, rows, chunk, tile):
         self.start = c * chunk
@@ -1378,8 +1394,11 @@ def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down, chunk):
     each held expert. A loop over chunks of ``chunk`` sorted pairs (a
     Python int: :func:`_row_chunks`), as many as hold a held pair: a
     trip gathers its chunk's rows, runs ``ops/grouped_mlp.py``'s two
-    forward kernels on the row tiles that hold a held pair and
-    scatter-adds; products in ``x``'s dtype, sums in float32."""
+    forward kernels on the row tiles that hold a held pair and adds
+    their rows of a group to the tokens' sums (``sum_back``: within one
+    expert's rows ``token`` has to ascend, no token twice); products in
+    ``x``'s dtype, sums in float32, a token's pairs in ascending order
+    of expert."""
     dt, tile = x.dtype, _row_tile(chunk, rows.size)
     with jax.named_scope("moe_experts"):
         token, gate = _padded_pairs(token, gate.astype(jnp.float32), chunk)
@@ -1388,11 +1407,12 @@ def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down, chunk):
         def one_chunk(c, out):
             ck = _Chunk(c, x, token, gate, rows, chunk, tile)
             hidden = grouped.gmm_in(ck.table, ck.xs, w_gate, w_up, tile=tile)
-            return out.at[ck.token].add(grouped.gmm_down(
-                ck.table, hidden, w_down, ck.gate, tile=tile))
+            return grouped.sum_back(ck.table, out, grouped.gmm_down(
+                ck.table, hidden, w_down, ck.gate, tile=tile), ck.token,
+                tile=tile)
 
         return jax.lax.fori_loop(0, _trips(rows, chunk), one_chunk,
-                                 jnp.zeros(x.shape, jnp.float32))
+                                 grouped.token_sums(*x.shape)).reshape(x.shape)
 
 
 def _held_experts_fwd(*args):
@@ -1421,8 +1441,9 @@ def _held_experts_bwd(chunk, args, d_out):
                 ck.table, ck.xs, dy, ck.gate, w_g, w_u, w_d, tile=tile)
             dw_gate, dw_up = grouped.gmm_dw_in(
                 ck.table, ck.xs, d_a, d_b, dw_gate, dw_up, tile=tile)
-            return (dx.at[ck.token].add(grouped.gmm_dx(
-                        ck.table, d_a, d_b, w_g, w_u, tile=tile)),
+            return (grouped.sum_back(ck.table, dx, grouped.gmm_dx(
+                        ck.table, d_a, d_b, w_g, w_u, tile=tile), ck.token,
+                        tile=tile),
                     jax.lax.dynamic_update_slice(d_gate, d_gate_c[:, 0],
                                                  (ck.start,)),
                     dw_gate, dw_up,
@@ -1432,8 +1453,9 @@ def _held_experts_bwd(chunk, args, d_out):
         f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
         dx, d_gate, dw_gate, dw_up, dw_down = jax.lax.fori_loop(
             0, _trips(rows, chunk), one_chunk,
-            (f32(x), f32(gate_f), f32(w_gate), f32(w_up), f32(w_down)))
-    return (dx.astype(dt), None, d_gate[:n_pairs].astype(gate.dtype), None,
+            (grouped.token_sums(*x.shape), f32(gate_f), f32(w_gate),
+             f32(w_up), f32(w_down)))
+    return (dx.reshape(x.shape).astype(dt), None, d_gate[:n_pairs].astype(gate.dtype), None,
             dw_gate.astype(w_gate.dtype), dw_up.astype(w_up.dtype),
             dw_down.astype(w_down.dtype))
 
@@ -1637,7 +1659,12 @@ class SparseMoELM(nn.Module):
         expert with a row in it) and the row tiles of the chunks the
         loops ran: visited over ``moe_rows`` / the tile's rows is what
         the tiles' padding costs, visited over the chunks' tiles what
-        the skipped tiles save. Under block diffusion also the
+        the skipped tiles save. From ``rows_summed``, ``[layers, 2]``:
+        the rows the sums back added to their tokens, counted from the
+        visits they walk (``moe_rows`` on every step, or a held pair's
+        row was lost between the products and its token), and the rows
+        of the chunks the loops ran, which a scatter-add of whole chunks
+        moved. Under block diffusion also the
         step's ``masked_tokens`` of its ``tokens`` (the rows' own, not
         the doubled sequence's), and from ``attn_tiles``, ``[layers,
         2]``: the tiles the attention's forward kernel visited of the
@@ -1646,21 +1673,24 @@ class SparseMoELM(nn.Module):
         kind of layer. A layer sows what it has: a dense layer no expert
         counter, so no array here has a row a layer of the model."""
         by_expert, chunks = sown["expert_rows"], sown["row_chunks"]
-        tiles = sown["row_tiles"]
+        tiles, summed = sown["row_tiles"], sown["rows_summed"]
         rows, f = float(by_expert.sum()), float(drop_fraction or 0.0)
         fields = dict(
             moe_rows=rows, moe_rows_max=float(by_expert.max()),
             moe_rows_mean=rows / by_expert.size,
             moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows,
             moe_row_chunks=float(chunks[:, 0].sum()),
-            moe_row_tiles=float(tiles[:, 0].sum()))
+            moe_row_tiles=float(tiles[:, 0].sum()),
+            moe_rows_summed=float(summed[:, 0].sum()))
         counters = {"train.moe.rows": rows,
                     "train.moe.pairs_dropped": fields["moe_pairs_dropped"],
                     "train.moe.row_chunks": fields["moe_row_chunks"]}
         gauges = {"train.moe.rows_max": fields["moe_rows_max"],
                   "train.moe.row_chunks_possible": float(chunks[:, 1].sum()),
                   "train.moe.row_tiles_visited": fields["moe_row_tiles"],
-                  "train.moe.row_tiles": float(tiles[:, 1].sum())}
+                  "train.moe.row_tiles": float(tiles[:, 1].sum()),
+                  "train.moe.rows_summed": fields["moe_rows_summed"],
+                  "train.moe.rows_moved": float(summed[:, 1].sum())}
         if "masked_tokens" in sown:
             fields.update(
                 diffusion_masked_tokens=float(sown["masked_tokens"].sum()),

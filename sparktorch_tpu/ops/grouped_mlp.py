@@ -19,7 +19,8 @@ Forward, two kernels a chunk:
   float32 sums in VMEM, epilogue ``silu(a) * b``: writes ``hidden
   [chunk, f]`` in the compute dtype once;
 - ``moe_gmm_down``: ``hidden x w_down[e] [f, d]``, epilogue ``* gate``:
-  writes ``gate * ys [chunk, d]`` float32, what the caller scatter-adds.
+  writes ``gate * ys [chunk, d]`` float32, which :func:`sum_back` adds
+  to the tokens' sums.
 
 Backward, four:
 
@@ -28,22 +29,36 @@ Backward, four:
   ``hidden * gate`` ``[chunk, f]`` in the compute dtype and ``d_gate
   [chunk, 1] = sum(hidden * d_hidden)`` float32;
 - ``moe_gmm_dx``: ``d_a x w_gate[e]^T + d_b x w_up[e]^T`` ``[chunk, d]``
-  float32, for the caller's scatter-add;
+  float32, for :func:`sum_back` too;
 - ``moe_gmm_dw_in`` and ``moe_gmm_dw_down``: ``xs^T d_a``, ``xs^T d_b``
   and ``(hidden * gate)^T dy`` over each group's rows, ADDED in place
   (``input_output_aliases``) to float32 sums ``[groups, d, f]`` /
   ``[groups, f, d]`` that the caller carries; the block of a group with
   no row in the chunk is never fetched nor written.
 
-What a caller may rely on: every row of the three outputs it sums
-(``gate * ys``, ``dx``'s rows, ``d_gate``) is WRITTEN, zeros where the
-row is of no group (tiles without a live row are visited for that alone,
-after the live ones: a store and no read); ``hidden``, ``d_a``, ``d_b``
-and ``hidden * gate`` are written in the visited tiles only (zeros in
-their rows of no group) and read by these kernels only, under the same
-table. Operands enter every product in the compute dtype, every sum and
-every epilogue is float32; ``tests/test_grouped_mlp.py`` holds each
-kernel to the same arithmetic in plain ``jax.numpy`` on whole arrays.
+The rows' way back to their tokens, :func:`sum_back` (``moe_sum_back``),
+walks the same table: the tokens' float32 sums stay in HBM, and a live
+visit fetches the sums of its rows of its group by one DMA a row into
+VMEM, adds the rows and writes them back by one DMA a row, all of a
+visit's in flight at once. They are independent because the layer's sort
+leaves one expert's rows ascending in token and ``top_k`` gives a token
+an expert once; two experts may hold the same token, so a visit's writes
+land before the next visit reads. No row of a tile without a live row is
+written, read or moved (XLA's scatter-add of the whole chunk, which this
+replaced, sorted the chunk's indices, gathered all its rows into that
+order, passed over every token's sum and added the rows one by one,
+zeros and all: PERF.md section 6, PR 48).
+
+What a caller may rely on: every row of ``d_gate`` is WRITTEN, zeros
+where the row is of no group (tiles without a live row are visited for
+that alone, after the live ones: a store and no read); ``gate * ys``,
+``dx``'s rows, ``hidden``, ``d_a``, ``d_b`` and ``hidden * gate`` are
+written in the visited tiles only (zeros in their rows of no group) and
+read under the same table only, by these kernels or by
+:func:`sum_back`. Operands enter every product in the compute dtype,
+every sum and every epilogue is float32; ``tests/test_grouped_mlp.py``
+holds each kernel and the sum back to the same arithmetic in plain
+``jax.numpy`` on whole arrays.
 
 Blocks. A kernel's grid is ``(visits, column blocks)``, the columns
 inside: a row tile's operands stay in VMEM over its column blocks, an
@@ -154,10 +169,11 @@ def visit_table(sizes, chunk: int, tile: int):
     ``group[v]``, whose rows are ``[lo[v], hi[v])`` of the chunk; the
     visits run by group, then by tile, so a tile's visits are
     consecutive. Visits ``counts[0] <= v < counts[1]`` are the tiles
-    without a live row, ``tile_out[v]``, for the kernels that write
-    zeros there. Past its range each array repeats its last entry in
-    range (``tile`` and ``group`` the last live visit's, ``tile_out``
-    the last tile's), which is what keeps a block where it is."""
+    without a live row, ``tile_out[v]``, for the kernel that writes
+    zeros there (``d_gate``'s). Past its range each array repeats its
+    last entry in range (``tile`` and ``group`` the last live visit's,
+    ``tile_out`` the last tile's), which is what keeps a block where it
+    is."""
     if chunk % tile:
         raise ValueError(f"a row tile of {tile} does not divide the chunk "
                          f"of {chunk} rows")
@@ -307,14 +323,14 @@ def gmm_in(table, xs, w_gate, w_up, *, tile, cols=None):
 
 def _out_kernel(*refs, cols, transposed, gated):
     """``sum_i lhs_i x rhs_i[e]`` (``rhs`` transposed or not), times the
-    row's gate where there is one, float32, zeros in the rows of no
-    group: ``moe_gmm_down`` (one product, gated) and ``moe_gmm_dx`` (two
-    transposed ones)."""
+    row's gate where there is one, float32, zeros in a visited tile's
+    rows of no group: ``moe_gmm_down`` (one product, gated) and
+    ``moe_gmm_dx`` (two transposed ones)."""
     table, refs = refs[:_TABLE], refs[_TABLE:]
     n = (len(refs) - 1 - gated) // 2
     lhs, rhs, out_ref = refs[:n], refs[n:2 * n], refs[-1]
     visit, j = _Visit(table, out_ref.shape[0]), pl.program_id(1)
-    _clear(visit, j, out_ref, zero_tiles=True)
+    _clear(visit, j, out_ref)
 
     @pl.when(visit.live)
     def _product():
@@ -335,8 +351,9 @@ def _out_fits(tile, k, width, cols, n, item) -> bool:
 
 @functools.partial(jax.jit, static_argnames=("tile", "cols"))
 def gmm_down(table, hidden, w_down, gate, *, tile, cols=None):
-    """``gate * (hidden w_down[e])`` float32 ``[chunk, d]``, every row
-    written. ``gate`` is ``[chunk, 1]`` float32."""
+    """``gate * (hidden w_down[e])`` float32 ``[chunk, d]``, written in
+    the visited tiles, for :func:`sum_back`. ``gate`` is ``[chunk, 1]``
+    float32."""
     (chunk, f), d = hidden.shape, w_down.shape[-1]
     cols = cols or _widest(d, lambda c: _out_fits(tile, f, d, c, 1,
                                                    hidden.dtype.itemsize))
@@ -345,9 +362,107 @@ def gmm_down(table, hidden, w_down, gate, *, tile, cols=None):
                           gated=True), "moe_gmm_down",
         (table[0].size, _blocks_of(d, cols)),
         [_row_spec(tile, f, 1), _weight_spec((f, cols), lambda j: (0, j)),
-         _row_spec(tile, 1, 1)], _row_spec(tile, d, 2),
+         _row_spec(tile, 1, 1)], _row_spec(tile, d, 1),
         jax.ShapeDtypeStruct((chunk, d), jnp.float32))(
             *table, hidden, w_down, gate)
+
+
+# -- the rows' sum back to their tokens ---------------------------------------
+
+
+def rows_summed(table, tile: int):
+    """The rows :func:`sum_back` adds under ``table``: each live visit's
+    rows of its group in its tile."""
+    _, at, _, lo, hi, counts = table
+    rows = jnp.minimum(hi, (at + 1) * tile) - jnp.maximum(lo, at * tile)
+    return jnp.sum(jnp.where(jnp.arange(at.size) < counts[0], rows, 0))
+
+
+def token_sums(tokens: int, d: int):
+    """Zeros for :func:`sum_back` to add to: the tokens' float32 sums as
+    ``[tokens, d / 128, 128]``, a token's row whole register tiles of its
+    own (``[tokens, 1, d]`` where 128 lanes do not divide ``d``). Mosaic
+    slices an array in HBM along a dimension its tiling does not cover,
+    and of ``[tokens, d]``, tiled ``(8, 128)``, one row is an eighth of
+    16 tiles ("Slice shape along dimension 0 must be aligned to tiling
+    (8)"). ``reshape(tokens, d)`` gives the sums as the model reads
+    them."""
+    lanes = _LANES if d % _LANES == 0 else d
+    return jnp.zeros((tokens, d // lanes, lanes), jnp.float32)
+
+
+# the tokens of a row tile reach the kernel as a block of SMEM, and XLA
+# lays a long int32 vector out in tiles of 1,024: the block is that wide
+_TOKEN_BLOCK = 1024
+
+
+def _sum_back_kernel(*refs):
+    table, refs = refs[:_TABLE], refs[_TABLE:]
+    rows_ref, token_ref, _, sums_ref, held, sems = refs
+    n = rows_ref.shape[0]
+    visit = _Visit(table, n)
+
+    @pl.when(visit.live)
+    def _add():
+        v = visit.v
+        start = visit.tile[v] * n
+        first = jnp.maximum(visit.lo[v], start) - start
+        last = jnp.minimum(visit.hi[v], start + n) - start
+        # where the tile's tokens lie in the block of SMEM
+        at = visit.tile[v] % (token_ref.shape[0] // n) * n
+
+        def fetch(r):
+            return pltpu.make_async_copy(sums_ref.at[token_ref[at + r]],
+                                         held.at[r], sems.at[0])
+
+        def put(r):
+            return pltpu.make_async_copy(
+                held.at[r], sums_ref.at[token_ref[at + r]], sems.at[1])
+
+        def each_row(do):
+            jax.lax.fori_loop(first, last, lambda r, _: do(r) or 0, 0)
+
+        # the visit's tokens are distinct: its rows' sums are fetched
+        # all at once, added to, and all written back before the next
+        # visit, which may hold one of the tokens again, reads
+        each_row(lambda r: fetch(r).start())
+        each_row(lambda r: fetch(r).wait())
+        held[...] += rows_ref[...].reshape(held.shape)
+        each_row(lambda r: put(r).start())
+        each_row(lambda r: put(r).wait())
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def sum_back(table, sums, rows, token, *, tile):
+    """``sums`` (:func:`token_sums`' layout, float32) with ``rows[r]``
+    added to token ``token[r]``'s sum for every row ``r`` of a group, in
+    place, a live visit of ``table`` at a time in the table's order (by
+    group, then by tile: a token's rows are added in ascending order of
+    group). ``rows`` is ``[chunk, d]`` float32, ``token`` ``[chunk]``.
+
+    The kernel ``moe_sum_back`` keeps the sums in HBM and moves a row by
+    a DMA of its own: a visit fetches the current sums of its rows of its
+    group into VMEM, adds the rows and writes them back. What makes a
+    visit's rows independent, and what the caller owes: within ONE
+    group's rows no token comes twice. Two groups may hold the same
+    token; their visits do not overlap. Rows of no group, and tiles
+    without a live row, are never read nor moved (``rows`` may hold
+    anything there), and a token without a row of a group keeps its sum
+    bit for bit."""
+    chunk, d = rows.shape
+    block = max(tile, _TOKEN_BLOCK)
+    token = jnp.pad(token, (0, -chunk % block))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return _call(
+        _sum_back_kernel, "moe_sum_back", (table[0].size,),
+        [pl.BlockSpec((tile, d), lambda v, *table: (table[1][v], 0)),
+         pl.BlockSpec((block,),
+                      lambda v, *table: (table[1][v] * tile // block,),
+                      memory_space=pltpu.SMEM), in_hbm], in_hbm,
+        jax.ShapeDtypeStruct(sums.shape, sums.dtype),
+        scratch=[pltpu.VMEM((tile, *sums.shape[1:]), jnp.float32),
+                 pltpu.SemaphoreType.DMA((2,))],
+        aliases={_TABLE + 2: 0})(*table, rows, token, sums)
 
 
 # -- backward -----------------------------------------------------------------
@@ -410,8 +525,8 @@ def gmm_bwd_hidden(table, xs, dy, gate, w_gate, w_up, w_down, *, tile,
 
 @functools.partial(jax.jit, static_argnames=("tile", "cols"))
 def gmm_dx(table, d_a, d_b, w_gate, w_up, *, tile, cols=None):
-    """``d_a w_gate[e]^T + d_b w_up[e]^T`` float32 ``[chunk, d]``, every
-    row written."""
+    """``d_a w_gate[e]^T + d_b w_up[e]^T`` float32 ``[chunk, d]``,
+    written in the visited tiles, for :func:`sum_back`."""
     (chunk, f), d = d_a.shape, w_gate.shape[1]
     cols = cols or _widest(d, lambda c: _out_fits(tile, f, d, c, 2,
                                                    d_a.dtype.itemsize))
@@ -421,7 +536,7 @@ def gmm_dx(table, d_a, d_b, w_gate, w_up, *, tile, cols=None):
         functools.partial(_out_kernel, cols=cols, transposed=True,
                           gated=False), "moe_gmm_dx",
         (table[0].size, _blocks_of(d, cols)), [rows_f, rows_f, w_spec, w_spec],
-        _row_spec(tile, d, 2),
+        _row_spec(tile, d, 1),
         jax.ShapeDtypeStruct((chunk, d), jnp.float32))(
             *table, d_a, d_b, w_gate, w_up)
 

@@ -26,7 +26,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -117,7 +117,8 @@ def _declared_vmem(jaxpr, found=None):
     """``{kernel name: [bytes, ...]}``: what each ``pallas_call`` inside
     ``jaxpr`` declares of VMEM: its blocks twice (the pipeline holds two
     of each) and its scratch, every array padded to its dtype's (8 x 4 /
-    itemsize, 128) tile; an operand left in HBM (``pl.ANY``) is no block.
+    itemsize, 128) tile; an operand left in HBM (``pl.ANY``) is no block,
+    nor is a block of SMEM or a semaphore VMEM's.
     What the compiler adds for values it spills is
     not in it: the compile, which refuses a kernel over the chip's scoped
     limit (16 MiB on the v5e), holds the sum."""
@@ -135,11 +136,12 @@ def _declared_vmem(jaxpr, found=None):
             mapping, body = eqn.params["grid_mapping"], eqn.params["jaxpr"]
             scratch = body.invars[len(body.invars)
                                   - mapping.num_scratch_operands:]
+            in_vmem = lambda aval: getattr(aval, "memory_space", None) in (
+                None, pltpu.VMEM)
             found.setdefault(eqn.params["name"], []).append(
                 2 * sum(padded(b.block_aval) for b in mapping.block_mappings
-                        if getattr(b.block_aval, "memory_space", None)
-                        is not pl.ANY)
-                + sum(padded(v.aval) for v in scratch))
+                        if in_vmem(b.block_aval))
+                + sum(padded(v.aval) for v in scratch if in_vmem(v.aval)))
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _declared_vmem(sub, found)
     return found
@@ -203,6 +205,20 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
     assert _forward_statistics(text, "sparse_attn_fwd") == [(1, 4, 8, 4096)]
 
 
+def _assert_sums_back_are_the_kernels(text, module):
+    """No XLA scatter is left under the expert layers' scope (the
+    scatter-add of a whole chunk, which the compiler gave a sort of the
+    chunk's indices and a gather of all its rows into that order): each
+    of their loops, one a layer and pass, calls ``moe_sum_back`` once."""
+    cfg = module.config
+    layers = sum(k.mlp == "experts" for k in cfg.layers) + cfg.mtp_depth
+    under = [line for lines in _computations(text).values() for line in lines
+             if "moe_experts" in line]
+    assert not [line for line in under
+                if " scatter(" in line or " sort(" in line]
+    assert _pallas_calls(text, "moe_sum_back") == 2 * layers
+
+
 def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     """One layer of the sparse-attention MoE LM at the published widths
     and the benchmark cell's rows (2 x 8,192 tokens, above the top-k of
@@ -249,16 +265,19 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
               for m in [re.search(r"body=(%[\w.\-]+)", line)]}
     # the grouped kernels of ops/grouped_mlp.py: gate|up with SwiGLU and
     # down with the gate forward; the hidden rows recomputed with their
-    # cotangent, dx and the two weight gradients backward
+    # cotangent, dx and the two weight gradients backward; the rows' sum
+    # back to their tokens in both
     kernels = ("moe_gmm_in", "moe_gmm_down", "moe_gmm_bwd_hidden",
-               "moe_gmm_dx", "moe_gmm_dw_in", "moe_gmm_dw_down")
+               "moe_gmm_dx", "moe_gmm_dw_in", "moe_gmm_dw_down",
+               "moe_sum_back")
     calls = {body: [_pallas_calls("\n".join(comps[body]), kernel)
                     for kernel in kernels] for body in bodies}
     assert sorted(c for c in calls.values() if any(c)) == [
-        [0, 0, 1, 1, 1, 1], [1, 1, 0, 0, 0, 0]]
-    for kernel in kernels:  # none outside the loops
+        [0, 0, 1, 1, 1, 1, 1], [1, 1, 0, 0, 0, 0, 1]]
+    for kernel in kernels[:-1]:  # none outside the loops
         assert _pallas_calls(text, kernel) == 1, kernel
     assert "ragged-dot" not in text
+    _assert_sums_back_are_the_kernels(text, module)
     assert compiled.memory_analysis().temp_size_in_bytes < 6_009_584_128 // 2
 
 
@@ -297,6 +316,7 @@ def test_block_diffusion_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "sparse_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1  # on 8,192 rows
     assert not _o_sized_copies(text, 16384)
+    _assert_sums_back_are_the_kernels(text, module)
 
 
 def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
@@ -357,6 +377,7 @@ def test_mixed_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert not gate_f32, gate_f32
     assert _pallas_calls(text, "blockdiff_attn_fwd") == 0
     assert _pallas_calls(text, "fused_ce_fwd") == 1
+    _assert_sums_back_are_the_kernels(text, module)
     # weights, gradients and Adam's moments are 7.84 GB of the 15.75
     assert compiled.memory_analysis().temp_size_in_bytes < 5_500_000_000
 
@@ -409,6 +430,7 @@ def test_latent_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "qk_norm_rope_fwd") == 0
     # no transposing copy of a float32 product on its way to the layout op
     assert not re.search(r"f32\[1,8192,8192\]\S* copy\(", text)
+    _assert_sums_back_are_the_kernels(text, module)
     assert compiled.memory_analysis().temp_size_in_bytes < 7_000_000_000
 
 
@@ -547,6 +569,7 @@ def test_linear_attention_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     assert _pallas_calls(text, "qk_norm_rope_fwd") == 2
     assert _pallas_calls(text, "qk_norm_rope_bwd") == 1
     assert _pallas_calls(text, "fused_ce_fwd") == 1
+    _assert_sums_back_are_the_kernels(text, module)
     # 4.86 GB read at PR 42, 5.26 at PR 43, 5.74 at PR 44 (the product's
     # whole cotangent is live across ``gdn_bwd``)
     assert compiled.memory_analysis().temp_size_in_bytes < 6_000_000_000
@@ -652,6 +675,7 @@ def test_convolution_cells_gradient_compiles_for_v5e(one_chip, as_tpu):
     # no activation by heads of 64 (a weight's gradient may lie so)
     assert not re.search(r"= \w+\[4,\d+,\d+,[\d,]*64\]",
                          text[text.index("\nENTRY "):])
+    _assert_sums_back_are_the_kernels(text, module)
     print(compiled.memory_analysis())
     assert compiled.memory_analysis().temp_size_in_bytes < 6_000_000_000
 

@@ -1,7 +1,7 @@
-"""``ops/grouped_mlp.py``: each kernel against the plain ``jax.numpy``
-spelling, on the CPU in interpret mode at tiny shapes, and
-``held_experts_sum`` (values and all five gradients) against a plain
-float32 reference of the layer's sum."""
+"""``ops/grouped_mlp.py``: each kernel and the rows' sum back against the
+plain ``jax.numpy`` spelling, on the CPU in interpret mode at tiny
+shapes, and ``held_experts_sum`` (values and all five gradients) against
+a plain float32 reference of the layer's sum."""
 
 import numpy as np
 import pytest
@@ -13,6 +13,7 @@ from sparktorch_tpu.models import sparse_moe_lm as M
 from sparktorch_tpu.ops import grouped_mlp as G
 
 CHUNK, TILE, D, F = 64, 16, 24, 32
+N_TOKENS, EVERYONES = 80, 3
 # rows of each of four experts in a chunk of 64, tiles of 16
 SIZES = {
     "an_empty_expert": [20, 0, 30, 5],
@@ -23,7 +24,23 @@ SIZES = {
     "no_live_row": [0, 0, 0, 0],
     "a_full_chunk": [10, 22, 0, 32],
     "one_expert_takes_all": [0, 64, 0, 0],
+    "second_half_dead": [10, 12, 0, 10],
 }
+
+
+def tokens_of(sizes, rng, n_tokens=N_TOKENS, pairs=CHUNK):
+    """The tokens of ``pairs`` sorted pairs as the layer sorts them:
+    within an expert's rows ascending, none twice; across experts they
+    meet, and token ``EVERYONES`` is under every expert with a row (two
+    to four of them: in ``a_tile_shared_by_four`` and
+    ``experts_under_a_tile`` its rows lie in ONE tile under several
+    experts, in ``an_empty_expert`` in three visits of three tiles).
+    The rows of no group point anywhere."""
+    others = np.delete(np.arange(n_tokens), EVERYONES)
+    held = [np.sort(np.append(rng.choice(others, s - 1, replace=False),
+                              EVERYONES)) for s in sizes if s]
+    return jnp.asarray(np.concatenate(
+        [*held, rng.integers(0, n_tokens, pairs - sum(sizes))]), jnp.int32)
 
 
 def operands(sizes, dt, seed=0):
@@ -32,6 +49,8 @@ def operands(sizes, dt, seed=0):
     normal = lambda k, *shape: jax.random.normal(k, shape, jnp.float32)
     return dict(
         sizes=jnp.asarray(sizes, jnp.int32),
+        token=tokens_of(sizes, np.random.default_rng(seed)),
+        sums=[normal(k, N_TOKENS, D) for k in jax.random.split(keys[6])],
         xs=normal(keys[0], CHUNK, D).astype(dt),
         dy=normal(keys[1], CHUNK, D).astype(dt),
         gate=jax.random.uniform(keys[2], (CHUNK, 1), jnp.float32, 0.1, 1.0),
@@ -52,7 +71,8 @@ def plain(o):
     """Every result of the six kernels by ``jax.numpy`` on whole arrays:
     each row by its own expert's matrices (a gather of them), operands
     in their dtype, sums and epilogues float32, zeros in the rows of no
-    group."""
+    group; and the carried sums with ``ys`` and ``dx`` scatter-added to
+    their tokens, whole arrays too."""
     dt, f32 = o["xs"].dtype, jnp.float32
     group, live = group_of_row(o["sizes"])
     dot = lambda a, m, eq: jnp.einsum(eq, a, m[group],
@@ -78,8 +98,16 @@ def plain(o):
         "rg,rk,rn->gkn", hot, l.astype(f32), r.astype(f32))
     return dict(hidden=held(hidden.astype(dt)), ys=ys, d_a=d_a, d_b=d_b,
                 hg=hg, d_gate=d_gate, dx=dx,
+                out_sum=o["sums"][0].at[o["token"]].add(ys),
+                dx_sum=o["sums"][1].at[o["token"]].add(dx),
                 dw_gate=by_group(o["xs"], d_a), dw_up=by_group(o["xs"], d_b),
                 dw_down=by_group(hg, o["dy"]))
+
+
+def sum_back(table, sums, rows, token, tile):
+    """``G.sum_back`` on sums that come and go as ``[tokens, d]``."""
+    return G.sum_back(table, sums.reshape(G.token_sums(*sums.shape).shape),
+                      rows, token, tile=tile).reshape(sums.shape)
 
 
 def kernels(o, sums=None, tile=TILE, **blocks):
@@ -108,11 +136,15 @@ def kernels(o, sums=None, tile=TILE, **blocks):
     visited = (jnp.arange(CHUNK) // tile
                < -(-jnp.sum(o["sizes"]) // tile))[:, None]
     return dict(hidden=hidden, ys=ys, d_a=d_a, d_b=d_b, hg=hg, d_gate=d_gate,
-                dx=dx, dw_gate=dw_gate, dw_up=dw_up, dw_down=dw_down), visited
+                dx=dx,
+                out_sum=sum_back(table, o["sums"][0], ys, o["token"], tile),
+                dx_sum=sum_back(table, o["sums"][1], dx, o["token"], tile),
+                dw_gate=dw_gate, dw_up=dw_up, dw_down=dw_down), visited
 
 
-# read by the kernels only, so written in the visited tiles only
-INNER = ("hidden", "d_a", "d_b", "hg")
+# read under the table only (by the kernels, or by ``sum_back``), so
+# written in the visited tiles only
+INNER = ("hidden", "d_a", "d_b", "hg", "ys", "dx")
 
 
 def close(got, want, dt, name):
@@ -175,6 +207,31 @@ def test_an_absent_experts_sum_is_left_as_it_is_bit_for_bit():
         assert np.asarray(got[name]).tobytes() == np.asarray(b).tobytes()
 
 
+@pytest.mark.parametrize("case", ["second_half_dead", "no_live_row",
+                                  "experts_under_a_tile"])
+def test_a_token_without_a_held_row_keeps_its_sum_bit_for_bit(case):
+    """The sum back touches only the tokens of the rows that hold a held
+    pair: a token that only rows of no group point at (in a visited
+    tile's tail and in the tiles without a live row, which no one reads:
+    NaNs planted there do not arrive) keeps the bits it came with, a NaN
+    planted there stays and stays alone; the others grow by their
+    rows."""
+    o = operands(SIZES[case], jnp.float32, seed=2)
+    n_live = int(o["sizes"].sum())
+    token = np.asarray(o["token"])
+    untouched = np.setdiff1d(np.arange(N_TOKENS), token[:n_live])
+    assert np.intersect1d(untouched, token[n_live:]).size  # pointed at
+    base = o["sums"][0].at[untouched, 0].set(jnp.nan)
+    table = G.visit_table(o["sizes"], CHUNK, TILE)
+    ys = jnp.where((jnp.arange(CHUNK) < n_live)[:, None], plain(o)["ys"],
+                   jnp.nan)
+    got = np.asarray(sum_back(table, base, ys, o["token"], TILE))
+    assert got[untouched].tobytes() == np.asarray(base)[untouched].tobytes()
+    touched = np.unique(token[:n_live])
+    close(got[touched], plain(o)["out_sum"][touched], jnp.float32, case)
+    assert int(G.rows_summed(table, TILE)) == n_live
+
+
 @pytest.mark.parametrize("case", list(SIZES))
 def test_the_table_visits_each_experts_tiles_once(case):
     """The table against a count on the host: every (tile, group) pair
@@ -192,6 +249,9 @@ def test_the_table_visits_each_experts_tiles_once(case):
     assert n_live == len(pairs) == int(G.tiles_visited(
         jnp.asarray(sizes, jnp.int32), TILE))
     assert list(zip(tile[:n_live], group[:n_live])) == pairs
+    # what the sums back add under it: every row of a group, once
+    assert int(G.rows_summed(G.visit_table(
+        jnp.asarray(sizes, jnp.int32), CHUNK, TILE), TILE)) == sizes.sum()
     assert np.array_equal(tile_out[:n_live], tile[:n_live])
     assert np.array_equal(lo[:n_live], (ends - sizes)[group[:n_live]])
     assert np.array_equal(hi[:n_live], ends[group[:n_live]])
@@ -251,11 +311,13 @@ def layer_reference(x, token, gate, rows, w_gate, w_up, w_down):
 ], ids=["two_trips", "one_trip", "all_held", "nothing_held"])
 def test_held_experts_sum_and_its_five_gradients(rows, chunk, monkeypatch):
     monkeypatch.setattr(G, "_MIN_ROW_TILE", 16)
-    n_tokens, k, d, f = 48, 4, 24, 32
+    n_tokens, k, d, f = 96, 2, 24, 32
     keys = jax.random.split(jax.random.key(7), 7)
+    # token EVERYONES is under every expert with a row: in two_trips
+    # under expert 0 (the first trip) and expert 3 (the second)
+    token = tokens_of(rows, np.random.default_rng(7), n_tokens, n_tokens * k)
     rows = jnp.asarray(rows, jnp.int32)
     x = jax.random.normal(keys[0], (n_tokens, d), jnp.float32)
-    token = jax.random.permutation(keys[1], n_tokens * k) // k
     gate = jax.random.uniform(keys[2], (n_tokens * k,), jnp.float32, 0.1, 1.0)
     w = [jax.random.normal(kk, (4, *s), jnp.float32) * s[0] ** -0.5
          for kk, s in zip(keys[3:6], ((d, f), (d, f), (f, d)))]
@@ -285,3 +347,4 @@ def test_the_chip_smokes_phase_rehearsed_at_a_small_size(monkeypatch):
     report = chip_smoke.phase_grouped_mlp(
         chip_smoke.Sizes(grouped_case=(64, 2, 8, 4, 32, 48)), 0, {})
     assert "held_rows=" in report and "chunk=128" in report
+    assert "sum_back_fwd_ms=" in report and "sum_back_bwd_ms=" in report

@@ -314,11 +314,13 @@ def test_the_published_model_and_what_a_configuration_may_not_say():
 # 47, which meant to as well (the held experts' products are
 # ``ops/grouped_mlp.py``'s kernels, in all five older models and in
 # ``tests/test_gated_delta_lm.py``'s and ``tests/test_short_conv_lm.py``'s
-# hashes too). A PR that means to change one of these models' steps
-# reads them anew.
-PARENT = {"keye": ("96e560cdd12a17e7", "56fe5f74c72bced4"),
-          "sdar": ("b60f6b0d23c7320d", "3ad78a5fe49982a3"),
-          "laguna": ("0ce980a58c390130", "64ac99ff5d5c0381")}
+# hashes too), and at PR 48, likewise in all five (the held
+# experts' sums back are ``grouped_mlp.sum_back``, no scatter-add of a
+# chunk). A PR that means to change one of these models' steps reads
+# them anew.
+PARENT = {"keye": ("96e560cdd12a17e7", "b278dd743f81aa7a"),
+          "sdar": ("b60f6b0d23c7320d", "97eac0d6d6622f50"),
+          "laguna": ("0ce980a58c390130", "4479302cfcf8ca62")}
 
 
 def older_model(name):

@@ -786,7 +786,8 @@ def test_row_tiles_in_the_records_are_the_tiles_the_kernels_visit(
     every (row tile, expert) pair with a row in common, chunk by chunk
     of 64 pairs in tiles of 16, of the tiles the chunks that ran hold;
     and through the trainer the record's ``moe_row_tiles`` and the two
-    gauges on the bus."""
+    gauges on the bus; ``rows_summed``, counted from the visits the sums
+    back walk, is ``moe_rows`` on every step."""
     from sparktorch_tpu.ops import grouped_mlp as G
 
     chunk, tile = 64, 16
@@ -805,12 +806,19 @@ def test_row_tiles_in_the_records_are_the_tiles_the_kernels_visit(
     assert np.array_equal(sown["row_tiles"][0],
                           [visits, trips * (chunk // tile)])
     assert -(-ends[-1] // tile) <= visits < trips * (chunk // tile)
+    # the sums back add every held pair's row, of the chunks' rows
+    assert np.array_equal(sown["rows_summed"][0], [ends[-1], trips * chunk])
 
     records, _, tele = _train(1, iters=3, layers=1, steps_per_call=1)
     for r in records:
         whole = -(-r["moe_rows"] // tile)
         # a tile more for each expert after the first, each trip
         assert whole <= r["moe_row_tiles"] <= whole + r["moe_row_chunks"]
+        assert r["moe_rows_summed"] == r["moe_rows"] > 0
+    assert tele.gauge_value("train.moe.rows_summed") \
+        == records[-1]["moe_rows_summed"]
+    assert tele.gauge_value("train.moe.rows_moved") \
+        == records[-1]["moe_row_chunks"] * chunk
     assert tele.gauge_value("train.moe.row_tiles_visited") \
         == records[-1]["moe_row_tiles"]
     assert tele.gauge_value("train.moe.row_tiles") \
@@ -844,10 +852,11 @@ def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
 
 
 def _poisoned_grouped_kernels(monkeypatch, met):
-    """``ops/grouped_mlp.py``'s kernels with NaN in every row tile they
-    do not visit (of each array they read) and every block they do not
-    write (of each array they return): what the chip leaves there is
-    whatever the memory held. ``met`` collects the arrays poisoned."""
+    """``ops/grouped_mlp.py``'s kernels and its sum back with NaN in
+    every row tile they do not visit (of each array they read) and every
+    block they do not write (of each array they return): what the chip
+    leaves there is whatever the memory held. ``met`` collects the arrays
+    poisoned."""
     from sparktorch_tpu.ops import grouped_mlp as G
 
     def unvisited(table, a, tile):
@@ -869,9 +878,10 @@ def _poisoned_grouped_kernels(monkeypatch, met):
         return call
 
     for name, rows_in, rows_out in (
-            ("gmm_in", (0,), (0,)), ("gmm_down", (0,), ()),
-            ("gmm_bwd_hidden", (0, 1), (0, 1, 2)), ("gmm_dx", (0, 1), ()),
-            ("gmm_dw_in", (0, 1, 2), ()), ("gmm_dw_down", (0, 1), ())):
+            ("gmm_in", (0,), (0,)), ("gmm_down", (0,), (0,)),
+            ("gmm_bwd_hidden", (0, 1), (0, 1, 2)), ("gmm_dx", (0, 1), (0,)),
+            ("gmm_dw_in", (0, 1, 2), ()), ("gmm_dw_down", (0, 1), ()),
+            ("sum_back", (1,), ())):
         monkeypatch.setattr(G, name, poisoned(getattr(G, name), rows_in,
                                               rows_out))
 
@@ -901,8 +911,9 @@ def test_rows_and_tiles_the_grouped_kernels_skip_never_reach_a_sum(
         assert np.all(np.isfinite(np.asarray(a)))
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
     # and the poison is there to be met: the arrays the six kernels of
-    # one gradient read by row tile, and the four they write so
-    assert len(met) == (1 + 1) + 1 + (2 + 3) + 2 + 3 + 2
+    # one gradient read by row tile and the six they write so, and the
+    # rows the two sums back read
+    assert len(met) == (1 + 1) + (1 + 1) + (2 + 3) + (2 + 1) + 3 + 2 + 2
     sown = layer.apply({"params": params}, g,
                        mutable=["moe_metrics"])[1]["moe_metrics"]
     visited, held = np.asarray(sown["row_tiles"][0])
