@@ -82,6 +82,9 @@ With no arguments (one chip), eight phases:
   and a visit's column blocks, the blocks no grid step writes, the
   carried sums the weight-gradient kernels add to in place and the
   transposed products are checked here, where interpret mode cannot.
+- ``grouped_mlp_ep_member`` the same check at what one member of
+  Mellum2's four-chip expert-parallel group computes in a layer: 32,768
+  gathered rows of 2,304, 8 of 64 experts each, 16 held (PR 49).
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -91,8 +94,11 @@ with: BERT-base ``train_distributed`` on dp=4 against the same run on
 one chip, the same at rows of 512 tokens (the default attention is the
 fused kernels there) against ``attn_impl="dense"`` and through the
 predictor over the mesh, one ``make_sharded_train_step`` step on
-dp=2 x fsdp=2, and one MoE step at ep=4 whose compiled text contains
-``all-to-all``.
+dp=2 x fsdp=2, one MoE step at ep=4 whose compiled text contains
+``all-to-all``, and (``expert_exchange``) one expert exchange of the
+decoder's ``HeldExperts`` at Mellum2's widths over ep=4, 16 of 64
+experts a chip, against a token's sum of its eight experts on one chip
+that holds all 64. ``--only a,b`` runs the named phases alone.
 
 Every phase is a function that raises on failure; a failure stops the
 run (later phases print as "not run"), the exit code is non-zero and
@@ -135,6 +141,10 @@ TOL_LATENT_GRAD_REL = 2e-2
 # the grouped kernels against ``ragged_dot`` on the same bf16 operands:
 # both sum in float32, in another order, and round hidden rows once
 TOL_GROUPED_REL = 5e-3
+# the expert layer over four chips against one chip that holds every
+# expert: the same kernels on the same bf16 rows, a token's eight float32
+# sums added chip by chip and not expert by expert
+TOL_EXCHANGE_REL = 1e-3
 # the chunked gated delta rule (bf16 operands, float32 sums and state)
 # against the token-by-token recurrence in float32 on the same bf16
 # operands: the chunk's T, W and V' enter their products rounded to bf16
@@ -185,6 +195,12 @@ class Sizes:
     sconv_case: tuple = (4, 4096, 2048)
     # tokens, experts a token, routed experts, held, d, f
     grouped_case: tuple = (16_384, 4, 32, 8, 2_048, 1_792)
+    # grouped_case's fields for one member of Mellum2's four after the
+    # exchange's way in
+    exchange_member_case: tuple = (32_768, 8, 64, 16, 2_304, 896)
+    # rows a chip, d, routed experts, experts a token, f of Mellum2's
+    # expert layer over four chips
+    exchange_case: tuple = (8_192, 2_304, 64, 8, 896)
     heads64_case: tuple = (4, 4096, 32, 8)
     # trainer_hogwild: ResNet-18 at CIFAR-10 shapes
     hog_rows: int = 1024
@@ -845,12 +861,25 @@ def phase_grouped_mlp(sz: Sizes, seed: int, ctx: dict) -> str:
     against its ``ragged_dot`` spelling at LFM2's layer: the output and
     the five gradients, forward and gradient timed apart, and the sums
     back of a chunk's rows to their tokens alone."""
+    return _grouped_mlp(sz.grouped_case, seed)
+
+
+def phase_grouped_mlp_ep_member(sz: Sizes, seed: int, ctx: dict) -> str:
+    """The same at what ONE member of Mellum2's four computes in a layer
+    after the exchange's way in: the four members' 32,768 rows of 2,304,
+    8 of 64 experts each, the member's 16 held (65,536 held pairs in
+    expectation, two a token; chunks of 131,072): the kernels' first
+    hidden size that is not 2,048."""
+    return _grouped_mlp(sz.exchange_member_case, seed)
+
+
+def _grouped_mlp(case: tuple, seed: int) -> str:
     import jax
     import jax.numpy as jnp
 
     from sparktorch_tpu.models import sparse_moe_lm as M
 
-    n, k, routed, n_held, d, f = sz.grouped_case
+    n, k, routed, n_held, d, f = case
     dt = jnp.bfloat16
     keys = jax.random.split(jax.random.key(seed), 8)
     x = jax.random.normal(keys[0], (n, d)).astype(dt)
@@ -1245,6 +1274,66 @@ def phase_moe_ep4(sz: Sizes, seed: int, ctx: dict) -> str:
             f"param_devices={n_dev}")
 
 
+def phase_expert_exchange(sz: Sizes, seed: int, ctx: dict) -> str:
+    """One expert exchange at Mellum2's widths over the four chips (8,192
+    rows of 2,304 a chip, 8 of 64 experts of 896, 16 held a chip: rows,
+    gates and chosen experts all-gathered over ``ep``, float32 sums
+    reduce-scattered back) against every token's sum of its eight
+    experts computed on ONE chip that holds all 64: the same layer with
+    no axis in sight, a member's rows at a time."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from sparktorch_tpu.models import sparse_moe_lm as M
+    from sparktorch_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    n, d, routed, k, f = sz.exchange_case
+    cfg = M.mellum2_lm(n_layers=4).config
+    assert (cfg.d_model, cfg.n_routed_experts, cfg.experts_per_token,
+            cfg.expert_width) == (d, routed, k, f)
+    layer = M.HeldExperts(cfg)
+    mesh = build_mesh(MeshConfig(dp=1, ep=4), jax.devices()[:4])
+    keys = jax.random.split(jax.random.key(seed), 3)
+    g = jax.random.normal(keys[0], (4, n, d))
+    shapes = jax.eval_shape(lambda: layer.init(keys[1], g[:1]))["params"]
+    specs = {name: P() if name == "router" else P("ep") for name in shapes}
+    place = lambda tree, spec: jax.tree.map(
+        lambda s: NamedSharding(mesh, s), spec)
+    # the router spread so that tokens choose unevenly
+    params = jax.jit(lambda: {**(p := layer.init(keys[1], g[:1])["params"]),
+                              "router": p["router"] * 8.0},
+                     out_shardings=place(shapes, specs))()
+    apply = lambda p, g: layer.apply({"params": p}, g,
+                                     mutable=["moe_metrics"])
+    cut = jax.jit(jax.shard_map(
+        lambda p, g: (lambda out, sown: (out, jax.tree.map(
+            lambda a: jax.lax.psum(a, "ep"), sown["moe_metrics"])))(
+                *apply(p, g)),
+        mesh=mesh, in_specs=(specs, P("ep")), out_specs=(P("ep"), P()),
+        check_vma=False))
+    g4 = jax.device_put(g, NamedSharding(mesh, P("ep")))
+    (out4, sown), wall = _timed(cut, params, g4)
+    one = jax.devices()[0]
+    whole = jax.device_put(params, one)
+    on_one = jax.jit(lambda p, g: apply(p, g)[0])
+    rels = [_rel(out4[m], on_one(whole, jax.device_put(g[m:m + 1], one))[0])
+            for m in range(4)]
+    rows = np.asarray(sown["expert_rows"][0])
+    report = (f"rows_a_chip={n} rel_by_member="
+              f"{[float(f'{r:.2e}') for r in rels]} fwd_s={wall:.5f} "
+              f"expert_rows min={rows.min()} max={rows.max()} "
+              f"sum={rows.sum()} dropped={float(sown['dropped'][0])} "
+              f"exchange_rows={float(sown['exchange_rows'][0]):.0f} "
+              f"exchange_bytes={float(sown['exchange_bytes'][0]):.0f}")
+    if (not max(rels) <= TOL_EXCHANGE_REL or rows.sum() != 4 * n * k
+            or float(sown["dropped"][0]) != 0.0):
+        raise AssertionError(f"the exchange over four chips vs one chip "
+                             f"holding every expert: {report} (limit "
+                             f"{TOL_EXCHANGE_REL})")
+    return report
+
+
 ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("predictor", phase_predictor),
             ("kernels", phase_kernels),
@@ -1255,11 +1344,13 @@ ONE_CHIP = (("trainer_sync", phase_trainer_sync),
             ("short_conv_gate", phase_short_conv_gate),
             ("causal_heads_64", phase_causal_heads_64),
             ("grouped_mlp", phase_grouped_mlp),
+            ("grouped_mlp_ep_member", phase_grouped_mlp_ep_member),
             ("trainer_hogwild", phase_trainer_hogwild))
 FOUR_CHIPS = (("dp4_vs_one_chip", phase_dp4_vs_one_chip),
               ("dp4_long_rows", phase_dp4_long_rows),
               ("sharded_dp2_fsdp2", phase_sharded_dp2_fsdp2),
-              ("moe_ep4", phase_moe_ep4))
+              ("moe_ep4", phase_moe_ep4),
+              ("expert_exchange", phase_expert_exchange))
 
 
 def run_phases(phases, sz: Sizes, seed: int) -> bool:
@@ -1292,6 +1383,9 @@ def main(argv=None) -> int:
                     help="4: only the four-chip path and what it is "
                     "compared with")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", default=None,
+                    help="comma-separated names of the phases to run, of "
+                    "those --chips selects")
     args = ap.parse_args(argv)
 
     import jax
@@ -1316,8 +1410,10 @@ def main(argv=None) -> int:
           f"compile_cache={jax.config.jax_compilation_cache_dir}",
           flush=True)
     t0 = time.perf_counter()
-    ok = run_phases(ONE_CHIP if args.chips == 1 else FOUR_CHIPS,
-                    Sizes(), args.seed)
+    phases = ONE_CHIP if args.chips == 1 else FOUR_CHIPS
+    if args.only:
+        phases = tuple(p for p in phases if p[0] in args.only.split(","))
+    ok = run_phases(phases, Sizes(), args.seed)
     print(f"chip_smoke: total_s={time.perf_counter() - t0:.1f}", flush=True)
     if not ok:
         return 1

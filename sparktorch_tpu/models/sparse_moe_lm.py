@@ -2,7 +2,7 @@
 experts that is told which experts it holds (Qwen3-MoE's block). What a
 layer is belongs to the LAYER (:class:`LayerKind`: its attention, its
 query heads, its rotary table, experts or a dense MLP after it), and a
-model is its list of layers (:class:`SparseMoEConfig` ``layers``). Six
+model is its list of layers (:class:`SparseMoEConfig` ``layers``). Seven
 models are built on it:
 
 - every layer ``learned_sparse``: DeepSeek-V3.2's lightning indexer in
@@ -30,7 +30,13 @@ models are built on it:
   full layers at 64-wide heads, two leading dense layers, 4 of 32
   sigmoid-routed experts chosen under an expert bias, and the head TIED
   to the embedding (LFM2-8B-A1B, :func:`lfm2_moe_lm`; "Gated short
-  convolution" below).
+  convolution" below);
+- ``window`` (1,024 keys) and ``full`` layers mixed 3:1 at hidden 2,304,
+  the full layers' table YaRN over the whole head, 8 of 64
+  softmax-routed experts and nothing beside them (Mellum2-12B-A2.5B,
+  :func:`mellum2_lm`): every equation is an earlier model's; what it
+  adds is that its 64 experts are ONE host's, held whole across an
+  ``ep`` axis ("The expert exchange" below).
 
 Shared by all, written once: the projections, q/k norm and rotary step
 around the attention kernel of the grouped-query kinds
@@ -91,6 +97,35 @@ One layer, for the tokens ``x`` of a row, in the published order:
   are the rows held, rounded up to a tile an expert, and the rows summed
   back are the rows held, at any load: a holder of every expert runs
   every chunk. There is no capacity and nothing is dropped.
+
+The expert exchange. Inside the sync DP step's ``shard_map`` over a mesh
+whose ``ep`` axis has ``m`` > 1 members (:func:`ep_members`), the layer
+is WHOLE across them: member ``i`` holds the block ``experts_held[i n /
+m : (i + 1) n / m]`` (its ``w_gate``, ``w_up``, ``w_down`` are that
+block of the leaves, which lie ``P("ep")`` on their first axis:
+``parallel/sharding_rules.py`` ``decoder_ep_axes``; outside the step, at
+``init``, they are ``[n, ...]`` whole) and trains on its own rows. It
+routes its own rows, then (:func:`exchange_in`) all-gathers over ``ep``
+the rows ``[b t, d]`` in the compute dtype, the gates and the chosen
+experts of every member, sorts and runs :func:`held_experts_sum` on ALL
+``m b t`` rows for its own block (the kernels as they are), and
+(:func:`exchange_out`) reduce-scatters the float32 partial sums, each
+member keeping its own rows' sums over every member's experts: the sum
+of all ``experts_per_token`` for every token, exact, static shapes,
+nothing dropped, no stand-in for a member. A token has an expert on
+most members when ``experts_per_token`` is near ``m`` or above (8 of 64
+over 4: 3.66 of 4 in expectation), which is when gathering every row
+costs little over sending each row where it is needed (3 copies a row
+for 2.75); for a group much wider than ``experts_per_token`` an
+all-to-all deduplicated by chip is the better exchange and is not here.
+Both collectives lie under the scope ``moe_exchange``, outside
+``moe_route``; their transposes are each other, so the backward pass is
+the same two, the rows' cotangent coming back summed in the compute
+dtype. The step (``train/step.py`` ``ep_rows``) sums an expert leaf's
+gradient over the batch axes alone, since every member's rows already
+reached it, and everything else over ``ep`` too; the counters below are
+summed over ``ep`` by the step, ``expert_rows`` laid out so that the sum
+holds all ``n`` experts once.
 
 After the last layer RMSNorm and a head over ``vocab_size`` rows (a
 slice of the published vocabulary, when the configuration says so):
@@ -385,7 +420,10 @@ the table they walk, and the rows of those chunks), ``routed``
 (chosen pairs whose expert is held) and ``dropped`` (those of them that
 the grouped products, chunk by chunk, did not multiply by their own
 expert's weights: 0), by each layer that holds experts (a dense layer
-sows none); under block diffusion also ``masked_tokens`` and ``tokens``
+sows none); over an ``ep`` axis also ``exchange_rows`` (the rows a
+member gets from the others) and ``exchange_bytes`` (theirs in, in the
+compute dtype, and their float32 sums back; a forward pass's); under
+block diffusion also ``masked_tokens`` and ``tokens``
 of the step and, by layer, ``attn_tiles`` (the tiles the attention's
 forward kernel visits, of the whole square's); by each ``full``,
 ``window`` and ``latent`` layer the same count as ``attn_tiles_full`` /
@@ -1222,9 +1260,64 @@ def _table_key(cfg: SparseMoEConfig, kind: LayerKind):
     return kind.rotary, cfg.head_dim
 
 
+_EP = "ep"   # the mesh axis an expert layer's weights may be cut over
+
+
+def ep_members() -> int:
+    """The members of the ``ep`` axis the trace is one of: the axis's
+    size inside a ``shard_map`` body that cuts it (the sync DP step over
+    a mesh with ``ep`` > 1), else 1 (``init``, a plain ``apply``, a mesh
+    whose ``ep`` has one member)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or _EP not in mesh.manual_axes:
+        return 1
+    return mesh.shape[_EP]
+
+
+def exchange_in(x, gates, pair_held, n_block: int, axis: str = _EP):
+    """The expert exchange's way in, on one member of ``axis``: the rows
+    ``x [n, d]``, gates ``[n, k]`` and chosen experts ``pair_held [n, k]``
+    (an id among ALL the experts the layer holds across ``ep``, or their
+    count for a pair no member computes) of every member, members in
+    order, and ``pair_held`` turned into an id in THIS member's block of
+    ``n_block`` experts (``n_block`` for a pair of another member's). An
+    all-gather each: static shapes, no capacity, nothing dropped; its
+    transpose hands each member the sum of the members' cotangents of
+    its own rows (a reduce-scatter)."""
+    with jax.named_scope("moe_exchange"):
+        x, gates, pair_held = (
+            jax.lax.all_gather(a, axis, axis=0, tiled=True)
+            for a in (x, gates, pair_held))
+    mine = pair_held - jax.lax.axis_index(axis) * n_block
+    return x, gates, jnp.where((mine >= 0) & (mine < n_block), mine, n_block)
+
+
+def exchange_out(out, rows, axis: str = _EP):
+    """The exchange's way back: of the float32 partial sums ``out [ep x
+    n, d]`` over this member's experts for every member's rows, each
+    member keeps its own ``n`` rows' sums over ALL members' experts (a
+    reduce-scatter, float32 on the wire; its transpose is an all-gather).
+    ``rows`` (the pairs of each of this member's experts) comes back in
+    its block of a vector over all the layer's experts, zeros elsewhere,
+    so that summed over ``ep`` it counts every expert once."""
+    members, block = jax.lax.axis_size(axis), rows.size
+    with jax.named_scope("moe_exchange"):
+        out = jax.lax.psum_scatter(out, axis, scatter_dimension=0, tiled=True)
+    return out, jax.lax.dynamic_update_slice(
+        jnp.zeros((members * block,), rows.dtype), rows,
+        (jax.lax.axis_index(axis) * block,))
+
+
 class HeldExperts(nn.Module):
     """The experts of ``experts_held`` out of a router over all of
-    ``n_routed_experts``: this chip's part of the layer's result. A
+    ``n_routed_experts``: this chip's part of the layer's result, or,
+    under an ``ep`` axis of more than one member (:func:`ep_members`),
+    the WHOLE result for this member's rows: the member holds the block
+    ``experts_held[m * len / ep : (m + 1) * len / ep]`` (its weights are
+    that block of the leaves, ``parallel/sharding_rules.py``
+    ``decoder_ep_axes``), computes it for the rows of every member
+    (:func:`exchange_in`) and gets back its own rows' sums over every
+    member's block (:func:`exchange_out`). A
     token's scores are the configuration's (``scoring``: the softmax
     over all routed experts, or each expert's own sigmoid); its gates are
     the chosen scores renormalised to sum 1, times ``routed_scale``.
@@ -1244,6 +1337,10 @@ class HeldExperts(nn.Module):
         n, k = b * t, cfg.experts_per_token
         held = tuple(cfg.experts_held)
         n_held, f = len(held), cfg.expert_width
+        ep = ep_members()
+        if n_held % ep:
+            raise ValueError(f"{n_held} held experts are not whole blocks "
+                             f"for the {ep} members of the ep axis")
         x = g.reshape(n, d).astype(dt)
 
         with jax.named_scope("moe_route"):
@@ -1277,6 +1374,14 @@ class HeldExperts(nn.Module):
             pair_local = jnp.asarray(local)[top_e]
             if live is not None:
                 pair_local = jnp.where(live.reshape(n, 1), pair_local, n_held)
+        if ep > 1:
+            # every member's rows, gates and chosen experts (under its own
+            # scope, ``moe_exchange``, outside ``moe_route``); a pair's id
+            # becomes its id in this member's block, or none
+            n_held //= ep
+            x, gates, pair_local = exchange_in(x, gates, pair_local, n_held)
+            n *= ep
+        with jax.named_scope("moe_route"):
             pair_local = pair_local.reshape(n * k)
             # held pairs first, by expert; pairs of experts held elsewhere
             # last
@@ -1301,6 +1406,15 @@ class HeldExperts(nn.Module):
             jnp.pad(pair_local[order], (0, n_chunks * chunk - n * k),
                     constant_values=n_held).reshape(n_chunks, chunk), sizes)
         tile, trips = _row_tile(chunk, n_held), _trips(rows, chunk)
+        if ep > 1:
+            out, rows = exchange_out(out, rows)
+            n //= ep
+            # the rows that crossed to another member and the bytes of
+            # them, in as ``x``'s dtype and back as float32 sums, forward
+            moved = jnp.float32((ep - 1) * n)
+            self.sow("moe_metrics", "exchange_rows", moved)
+            self.sow("moe_metrics", "exchange_bytes",
+                     moved * d * (jnp.dtype(dt).itemsize + 4))
         self.sow("moe_metrics", "expert_rows", rows)
         self.sow("moe_metrics", "row_chunks", jnp.stack(
             [trips, jnp.int32(n_chunks)]))
@@ -1597,11 +1711,11 @@ class SparseMoELM(nn.Module):
 
     # read by the trainers that were not taught this model (D1)
     sync_dp_only = ("its attention is a Pallas kernel that GSPMD cannot "
-                    "partition, and its expert layer computes one chip's "
-                    "share of the experts with no exchange, so no mesh axis "
-                    "may divide the model; and where its training forward "
-                    "draws (block diffusion's noise) only the sync DP step "
-                    "hands a forward pass a random stream")
+                    "partition, and its expert layer's exchange over ep is "
+                    "written for the sync DP step's shard_map alone, so no "
+                    "other mesh axis may divide the model; and where its "
+                    "training forward draws (block diffusion's noise) only "
+                    "the sync DP step hands a forward pass a random stream")
 
     @property
     def train_rngs(self) -> tuple:
@@ -1613,11 +1727,14 @@ class SparseMoELM(nn.Module):
     def train_gauges(self, row_shape) -> dict:
         """What the trainers put on the bus when they build a step (for
         rows of ``row_shape``, which changes nothing here): the experts
-        held and routed, and what each kind of attention among the
-        layers says of itself."""
-        cfg = self.config
-        gauges = {"train.moe.experts_held": len(cfg.experts_held),
+        held (by a member of the ``ep`` axis the step is built over) and
+        routed, and what each kind of attention among the layers says
+        of itself."""
+        cfg, ep = self.config, ep_members()
+        gauges = {"train.moe.experts_held": len(cfg.experts_held) // ep,
                   "train.moe.experts_routed": cfg.n_routed_experts}
+        if ep > 1:
+            gauges["train.moe.ep_members"] = ep
         if cfg.shared_expert_width:
             gauges["train.moe.shared_width"] = cfg.shared_expert_width
         if cfg.layers_of("learned_sparse"):
@@ -1691,6 +1808,15 @@ class SparseMoELM(nn.Module):
                   "train.moe.row_tiles": float(tiles[:, 1].sum()),
                   "train.moe.rows_summed": fields["moe_rows_summed"],
                   "train.moe.rows_moved": float(summed[:, 1].sum())}
+        if "exchange_rows" in sown:
+            # over an ep axis: the rows that crossed to another member,
+            # summed over members and layers, and their bytes in and back
+            # (a forward pass's; the backward pass moves as many)
+            fields["moe_exchange_rows"] = float(sown["exchange_rows"].sum())
+            counters.update({
+                "train.moe.exchange_rows": fields["moe_exchange_rows"],
+                "train.moe.exchange_bytes":
+                    float(sown["exchange_bytes"].sum())})
         if "masked_tokens" in sown:
             fields.update(
                 diffusion_masked_tokens=float(sown["masked_tokens"].sum()),
@@ -2018,3 +2144,30 @@ def lfm2_moe_lm(**overrides) -> SparseMoELM:
         "experts_per_token": 4, "expert_width": 1_792, "scoring": "sigmoid",
         "selection_bias": True, "routed_norm_eps": 1e-6,
         "dense_width": 7_168, "tie_word_embeddings": True, **overrides}))
+
+
+def mellum2_lm(**overrides) -> SparseMoELM:
+    """Mellum2-12B-A2.5B at its published sizes: 28 layers of hidden
+    2,304 over 32 query and 4 key/value heads of 128, three of every four
+    attending a window of 1,024 (plain rotary, theta 5e5) and every
+    fourth (layers 3, 7, ...) every causal key (YaRN by 16 over 8,192
+    positions on the same theta, ``beta_fast`` 32, ``beta_slow`` 1,
+    ``attention_factor`` 1.2772588722239782), rotary on the whole head;
+    every layer 64 experts of 896, 8 a token by softmax scores
+    renormalised, no shared expert, no dense layer; an untied head over
+    98,304. Its 64 experts are ONE four-chip host's: under the sync DP
+    trainer on a mesh with ``ep`` = 4 each chip holds 16 of every layer
+    (:class:`HeldExperts`). ``overrides`` as for :func:`keye_vl2_lm`;
+    ``layers``, where given, replaces the published pattern, which is
+    otherwise cut to ``n_layers``."""
+    overrides = _coerced(overrides)
+    n_layers = overrides.get("n_layers", 28)
+    full = Rotary(5e5, (64,), (16.0, 8_192.0, 32.0, 1.0), 1.2772588722239782)
+    window = Rotary(5e5, (64,))
+    return SparseMoELM(SparseMoEConfig(**{
+        "vocab_size": 98_304, "d_model": 2_304, "n_layers": n_layers,
+        "layers": tuple(
+            LayerKind("full", 32, full) if (i + 1) % 4 == 0 else
+            LayerKind("window", 32, window) for i in range(n_layers)),
+        "window": 1_024, "n_routed_experts": 64,
+        "experts_held": tuple(range(64)), "expert_width": 896, **overrides}))

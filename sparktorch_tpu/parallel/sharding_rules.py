@@ -82,6 +82,23 @@ _TRANSFORMER_RULES = [
 ]
 
 
+# The decoder (``models/sparse_moe_lm.py`` ``SparseMoELM``) under the sync
+# DP trainer: an expert layer's three weight leaves ``[experts, ...]`` lie
+# on ``ep`` along their first axis, a member holding a contiguous block
+# (``HeldExperts``); every other leaf is on every member whole. The same
+# rule reads a gradient's path and an optimizer state's, whose moments
+# keep the parameters' keys at the end of their own.
+_DECODER_EP_LEAF = re.compile(r"(^|.*/)moe/w_(gate|up|down)$")
+
+
+def decoder_ep_axes(path) -> tuple:
+    """The mesh axes the decoder's leaf at ``path`` (of the parameters,
+    their gradient or an optimizer state of theirs) is cut over, along
+    its first axis: ``("ep",)`` for an expert layer's weights, else
+    ``()``."""
+    return (AXIS_EP,) if _DECODER_EP_LEAF.match(_path_str(path)) else ()
+
+
 def _path_str(path) -> str:
     parts = []
     for key in path:

@@ -22,7 +22,7 @@ from typing import Any, Callable, NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from sparktorch_tpu.ft import chaos as _chaos
 from sparktorch_tpu.obs import get_logger, get_telemetry
@@ -31,7 +31,6 @@ from sparktorch_tpu.parallel.launch import check_gang, notify_gang_step
 from sparktorch_tpu.parallel.mesh import (
     AXIS_PP,
     BATCH_AXES,
-    batch_sharding,
     build_mesh,
     replicated,
 )
@@ -39,12 +38,15 @@ from sparktorch_tpu.train.step import (
     EsConfig,
     TrainState,
     create_train_state,
+    cut_specs,
+    ep_rows,
     grad_allreduce_plan,
     init_es_state,
     make_eval_step,
     make_train_epoch,
     make_train_epoch_fused,
     make_train_step,
+    refuse_ep_cut,
     shard_map_compat,
 )
 from sparktorch_tpu.utils.data import DataBatch, handle_features, pad_to_multiple
@@ -69,19 +71,26 @@ def _as_batch(data, labels=None, validation_pct=0.0, seed=0):
 
 
 def _n_shards(mesh: Mesh) -> int:
-    """How many shards the batch axes cut the rows into."""
-    return math.prod(mesh.shape[ax] for ax in BATCH_AXES)
+    """How many shards the row axes cut the rows into: the batch axes
+    and, where it has more than one member, ``ep``
+    (``train/step.py`` ``ep_rows``)."""
+    return math.prod(mesh.shape[ax] for ax in ep_rows(mesh)[0])
+
+
+def _row_sharding(mesh: Mesh) -> NamedSharding:
+    """Sharding for ``(rows, ...)`` arrays: rows split over the row axes."""
+    return NamedSharding(mesh, PartitionSpec(ep_rows(mesh)[0]))
 
 
 def prepare_sharded_batch(batch: DataBatch, mesh: Mesh) -> DataBatch:
-    """Pad to a multiple of the batch-axis size and place shards.
+    """Pad to a multiple of the row axes' size and place shards.
 
     The padding rows carry weight 0 — this is the empty-partition
     protocol (``distributed.py:46-63,131-133``) done with math instead
     of phantom collective participants.
     """
     padded = pad_to_multiple(batch, _n_shards(mesh))
-    sharding = batch_sharding(mesh)
+    sharding = _row_sharding(mesh)
     return DataBatch(*(jax.device_put(a, sharding) for a in padded))
 
 
@@ -97,7 +106,7 @@ def _shuffle_batch(batch: DataBatch, key: jax.Array, mesh: Mesh) -> DataBatch:
     reference's RDD re-shuffle (``distributed.py:267-273``), executed
     on-device (an all-to-all under the hood, riding ICI)."""
     perm = jax.random.permutation(key, batch.x.shape[0])
-    sharding = batch_sharding(mesh)
+    sharding = _row_sharding(mesh)
     out = jax.jit(
         lambda b, p: DataBatch(b.x[p], b.y[p], b.w[p]),
         out_shardings=DataBatch(sharding, sharding, sharding),
@@ -177,11 +186,16 @@ def _finalize_checkpoint(ckpt, state, completed: bool) -> None:
 
 def _note_grad_allreduce(tele, params, mesh: Mesh) -> None:
     """What the step's gradient all-reduce sums, on the bus: the
-    all-reduces a step issues, one a parameter array (0 where the batch
-    axes have one member and nothing is reduced), and their bytes."""
-    buckets, nbytes = grad_allreduce_plan(params, mesh)
+    all-reduces a step issues, one a parameter array (0 where the axes
+    a leaf is summed over have one member and nothing is reduced), their
+    bytes and, apart, a member's bytes of the leaves cut over ``ep``."""
+    buckets, nbytes, cut_bytes = grad_allreduce_plan(params, mesh)
     tele.gauge("train.grad_allreduce.buckets", buckets)
     tele.gauge("train.grad_allreduce.bytes", nbytes)
+    if cut_bytes:
+        # a member's block of the leaves cut over ep, which no member
+        # sums over ep
+        tele.gauge("train.grad_allreduce.bytes_ep_cut", cut_bytes)
 
 
 def _note_model_gauges(tele, module, row_shape, mesh: Mesh) -> None:
@@ -252,10 +266,17 @@ def _jit_init(spec, mesh: Mesh, rng, sample_x, tx):
     """The compiled init: the state replicated over ``mesh`` by the
     ``jit``'s own ``out_shardings`` (no mesh is in sight of the trace:
     a module that picks a path by the mesh sees the process's device
-    count, :func:`sparktorch_tpu.models.transformer.pick_attention`)."""
-    return jax.jit(
-        lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx),
-        out_shardings=replicated(mesh))
+    count, :func:`sparktorch_tpu.models.transformer.pick_attention`),
+    or, over an ``ep`` axis of more than one member, placed leaf by leaf
+    as the step takes it (``train/step.py`` ``ep_rows``): a cut leaf is
+    born in blocks on its members and no device holds it whole."""
+    make = lambda: create_train_state(spec, rng, sample_x=sample_x, tx=tx)
+    shardings, cut = replicated(mesh), ep_rows(mesh)[1]
+    if cut is not None:
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec),
+            cut_specs(jax.eval_shape(make), cut))
+    return jax.jit(make, out_shardings=shardings)
 
 
 def _init_state(tele, spec, mesh: Mesh, rng, sample_x, tx) -> TrainState:
@@ -604,6 +625,8 @@ def train_distributed(
             telemetry=telemetry,
         )
 
+    if checkpoint_dir:
+        refuse_ep_cut(mesh, "a checkpoint of the sync DP trainer")
     obs = _RunObservers(tele, mesh)
     if pre_sharded:
         # ``data`` is already a globally-sharded DataBatch (multi-host
@@ -738,6 +761,7 @@ def train_distributed_multihost(
     from jax.experimental import multihost_utils
 
     mesh = mesh or build_mesh()
+    refuse_ep_cut(mesh, "train_distributed_multihost")
     n_proc = jax.process_count()
 
     local_x = np.asarray(local_x)
@@ -857,7 +881,7 @@ def train_distributed_multihost(
         unit = need // math.gcd(n_proc, need)
         per_host = -(-per_host // unit) * unit
 
-    sharding = batch_sharding(mesh)
+    sharding = _row_sharding(mesh)
     global_batch = DataBatch(*(
         jax.make_array_from_process_local_data(sharding,
                                                _pad_rows(a, per_host))
@@ -906,6 +930,7 @@ def train_distributed_streaming(
     """
     spec = deserialize_model(torch_obj)
     mesh = mesh or build_mesh()
+    refuse_ep_cut(mesh, "train_distributed_streaming")
     tele = telemetry or get_telemetry()
     obs = _RunObservers(tele, mesh, prefix="train_streaming")
 
@@ -939,7 +964,7 @@ def train_distributed_streaming(
     _note_grad_allreduce(tele, state.params, mesh)
     _note_model_gauges(tele, module, tuple(x.shape[1:]), mesh)
 
-    sharding = batch_sharding(mesh)
+    sharding = _row_sharding(mesh)
 
     def put_chunk(lo: int, order: np.ndarray) -> DataBatch:
         idx = order[lo : lo + chunk_rows]

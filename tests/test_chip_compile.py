@@ -917,3 +917,54 @@ def test_dp4_step_runs_its_allreduces_under_the_backward_pass(
     assert len(starts) == n_large > 1
     assert sum(1 for line in body if " all-reduce(" in line) == 1
     assert backward_matmuls and starts[0] < backward_matmuls[-1]
+
+
+def test_ep4_step_of_mellum2_compiles_for_v5e_2x2(topo, as_tpu):
+    """The sync trainer's step over dp=1 x ep=4 for one window layer of
+    Mellum2 at its published widths (rows of 2,048 tokens): the carry
+    placed leaf by leaf, the expert exchange's all-gather and
+    reduce-scatter and the members' own kernels in the compiled text; the
+    options are the dp options less the one the TPU compiler refuses the
+    cell's step with (``train/step.py`` ``_KLOOP_OPTION``)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from sparktorch_tpu.models.sparse_moe_lm import mellum2_lm
+    from sparktorch_tpu.parallel.mesh import MeshConfig, build_mesh
+    from sparktorch_tpu.train import step as step_mod
+    from sparktorch_tpu.utils.data import DataBatch
+    from sparktorch_tpu.utils.serde import ModelSpec
+
+    seq = 2_048
+    mesh = build_mesh(MeshConfig(dp=1, ep=4), topo.devices)
+    rows_axes, cut = step_mod.ep_rows(mesh)
+    options = step_mod._dp_compiler_options(mesh, rows_axes)
+    assert set(step_mod._TPU_DP_OPTIONS) - set(options) == {
+        step_mod._KLOOP_OPTION}
+    assert step_mod._dp_compiler_options(
+        build_mesh(MeshConfig(dp=4), topo.devices),
+        ("dp", "fsdp")) == step_mod._TPU_DP_OPTIONS
+
+    spec = ModelSpec(module=mellum2_lm(n_layers=1, vocab_size=24_576),
+                     loss="cross_entropy", optimizer="adam",
+                     optimizer_params={"lr": 1e-5}, input_shape=(seq,))
+    tx = spec.make_optimizer()
+    shapes = jax.eval_shape(lambda: step_mod.create_train_state(
+        spec, jax.random.key(0), sample_x=jnp.zeros((1, seq), jnp.float32),
+        tx=tx))
+    state = jax.tree.map(
+        lambda s, p: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                          sharding=NamedSharding(mesh, p)),
+        shapes, step_mod.cut_specs(shapes, cut))
+    assert state.params["layer_0"]["moe"]["w_up"].sharding.spec == P("ep")
+    assert state.opt_state[0].mu["layer_0"]["moe"]["w_up"].sharding.spec \
+        == P("ep")
+    assert state.params["layer_0"]["moe"]["router"].sharding.spec == P()
+    rows = NamedSharding(mesh, P(rows_axes))
+    batch = DataBatch(*(jax.ShapeDtypeStruct(s, jnp.float32, sharding=rows)
+                        for s in ((8, seq), (8, seq), (8,))))
+    step = step_mod.make_train_step(spec.make_module().apply, spec.loss_fn(),
+                                    tx, mesh, mini_batch=1)
+    assert isinstance(step, step_mod._CompiledWithOptions)
+    text = step.lower(state, batch).compile().as_text()
+    assert "all-gather" in text and "reduce-scatter" in text
+    assert text.count("tpu_custom_call") >= 10
