@@ -82,9 +82,17 @@ With no arguments (one chip), eight phases:
   and a visit's column blocks, the blocks no grid step writes, the
   carried sums the weight-gradient kernels add to in place and the
   transposed products are checked here, where interpret mode cannot.
+  Then what a trip does around its products, each alone on the first
+  chunk in a loop of twenty calls: ``fetch_rows`` of the chunk's rows
+  (one source, as the forward pass calls it, and ``x`` with ``d_out``,
+  as the backward pass does) against XLA's gathers of the whole chunk on
+  the same operands, the fetched rows EQUAL to ``x[token]`` on every
+  tile with a held pair and timed with half the chunk live (PR 51);
+  ``fetch_source`` of ``x``; and the two sums back (PR 48).
 - ``grouped_mlp_ep_member`` the same check at what one member of
   Mellum2's four-chip expert-parallel group computes in a layer: 32,768
-  gathered rows of 2,304, 8 of 64 experts each, 16 held (PR 49).
+  gathered rows of 2,304 (18 sublanes a row, 24 in the fetch's source),
+  8 of 64 experts each, 16 held, chunks of 131,072 (PR 49).
 - ``trainer_hogwild`` ``SparkTorch(mode="hogwild")`` (->
   ``train_async``), ResNet-18 at CIFAR shapes, two local workers: the
   server's version advances, loss finite.
@@ -820,40 +828,77 @@ def _ragged_experts_sum(x, token, gate, rows, w_gate, w_up, w_down):
         rdot(hidden, w_down) * gate[:, None])
 
 
-def _sums_back_ms(x, token, gate, rows, weights, d_out, chunk) -> list:
-    """Milliseconds of ``ops/grouped_mlp.py``'s ``sum_back`` alone on the
-    first chunk's rows as the layer's two passes give them (``gate *
-    ys`` forward, ``dx``'s rows backward): a loop of twenty calls each
-    into one carried sum, since ONE call reads its dispatch."""
+def _loop_ms(call, carried=None) -> float:
+    """Milliseconds a call of ``call`` in a loop of twenty (ONE call
+    reads its dispatch): ``call(carried)`` gives the next ``carried``."""
+    import jax
+
+    carried = jax.block_until_ready(call(carried))
+    t0 = time.perf_counter()
+    for _ in range(20):
+        carried = call(carried)
+    jax.block_until_ready(carried)
+    return (time.perf_counter() - t0) / 20 * 1e3
+
+
+def _trip_parts_ms(x, token, gate, rows, weights, d_out, chunk) -> dict:
+    """Milliseconds of what a trip of the layer's loops does around its
+    products, alone, on the first chunk as the layer's two passes give
+    it: ``ops/grouped_mlp.py``'s ``fetch_rows`` of ``x``'s rows (the
+    forward pass's call) and of ``x``'s and ``d_out``'s (the backward
+    pass's) against XLA's gathers of the whole chunk, ``x[token]`` and
+    the pair, on the same operands, the fetched rows EQUAL to the
+    gathered ones bit for bit on every tile with a held pair, the fetch
+    timed with half the chunk live; the turn of ``x`` into what the
+    fetch reads (``fetch_source``); and ``sum_back`` of the chunk's
+    ``gate * ys`` and of ``dx``'s rows into one carried sum."""
     import functools
 
     import jax
+    import jax.numpy as jnp
+    import numpy as np
 
     from sparktorch_tpu.models import sparse_moe_lm as M
     from sparktorch_tpu.ops import grouped_mlp as G
 
-    tile = M._row_tile(chunk, rows.size)
-    ck = M._Chunk(0, x, *M._padded_pairs(token, gate, chunk), rows, chunk,
-                  tile)
+    tile, d = M._row_tile(chunk, rows.size), x.shape[1]
+    dy_all = d_out.astype(x.dtype)
+    sources = (G.fetch_source(x), G.fetch_source(dy_all))
+    ck = M._Chunk(0, *M._padded_pairs(token, gate, chunk), rows, chunk, tile,
+                  d, *sources)
+    # timed HALF live, as a cell's chunk is by construction (twice the
+    # expected share), whatever share of this draw's pairs is held
+    fetch = functools.partial(G.fetch_rows, jnp.int32(chunk // 2), ck.token,
+                              d=d, tile=tile)
+    gather = functools.partial(jax.jit(
+        lambda token, *arrays: tuple(a[token] for a in arrays)), ck.token)
+    fetched = int(G.rows_fetched(ck.live, tile))
+    for got, want in zip(ck.fetched, gather(x, dy_all)):
+        if not np.array_equal(np.asarray(got[:fetched].astype("float32")),
+                              np.asarray(want[:fetched].astype("float32"))):
+            raise AssertionError(
+                f"fetch_rows is not x[token] on the {fetched} rows of the "
+                f"tiles with a held pair (chunk {chunk}, tile {tile})")
+    xs, dy = ck.fetched
     w_gate, w_up, w_down = (w.astype(x.dtype) for w in weights)
-    ys = G.gmm_down(ck.table, G.gmm_in(ck.table, ck.xs, w_gate, w_up,
+    ys = G.gmm_down(ck.table, G.gmm_in(ck.table, xs, w_gate, w_up,
                                        tile=tile), w_down, ck.gate, tile=tile)
     d_a, d_b, _, _ = G.gmm_bwd_hidden(
-        ck.table, ck.xs, d_out.astype(x.dtype)[ck.token], ck.gate, w_gate,
-        w_up, w_down, tile=tile)
+        ck.table, xs, dy, ck.gate, w_gate, w_up, w_down, tile=tile)
     dx = G.gmm_dx(ck.table, d_a, d_b, w_gate, w_up, tile=tile)
     back = jax.jit(functools.partial(G.sum_back, tile=tile),
                    donate_argnums=1)
-    out = []
-    for moved in (ys, dx):
-        sums = jax.block_until_ready(back(
-            ck.table, G.token_sums(*x.shape), moved, ck.token))
-        t0 = time.perf_counter()
-        for _ in range(20):
-            sums = back(ck.table, sums, moved, ck.token)
-        jax.block_until_ready(sums)
-        out.append((time.perf_counter() - t0) / 20 * 1e3)
-    return out
+    sums_of = lambda moved: lambda sums: back(
+        ck.table, G.token_sums(*x.shape) if sums is None else sums, moved,
+        ck.token)
+    return dict(
+        fetch_fwd_ms=_loop_ms(lambda _: fetch(sources[0])),
+        fetch_bwd_ms=_loop_ms(lambda _: fetch(*sources)),
+        gather_fwd_ms=_loop_ms(lambda _: gather(x)),
+        gather_bwd_ms=_loop_ms(lambda _: gather(x, dy_all)),
+        fetch_source_ms=_loop_ms(lambda _: G.fetch_source(x)),
+        sum_back_fwd_ms=_loop_ms(sums_of(ys)),
+        sum_back_bwd_ms=_loop_ms(sums_of(dx)))
 
 
 def phase_grouped_mlp(sz: Sizes, seed: int, ctx: dict) -> str:
@@ -909,13 +954,12 @@ def _grouped_mlp(case: tuple, seed: int) -> str:
     rels.update({name: _rel(a, b) for name, a, b in zip(
         ("x", "gate", "w_gate", "w_up", "w_down"), grads,
         grad_of(plain)(*operands))})
-    back_ms = _sums_back_ms(x, token, gate, rows, (w_gate, w_up, w_down),
-                            weight, chunk)
+    parts = _trip_parts_ms(x, token, gate, rows, (w_gate, w_up, w_down),
+                           weight, chunk)
     report = (f"tokens={n} held_rows={int(rows.sum())} chunk={chunk} "
               f"rel={ {n: float(f'{r:.2e}') for n, r in rels.items()} } "
               f"fwd_s={fwd_s:.5f} grad_s={grad_s:.5f} "
-              f"sum_back_fwd_ms={back_ms[0]:.3f} "
-              f"sum_back_bwd_ms={back_ms[1]:.3f}")
+              + " ".join(f"{name}={ms:.3f}" for name, ms in parts.items()))
     if not max(rels.values()) <= TOL_GROUPED_REL:       # NaN fails too
         raise AssertionError(
             f"the grouped kernels vs the ragged_dot spelling: {report} "

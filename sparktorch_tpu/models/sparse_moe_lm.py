@@ -121,7 +121,9 @@ all-to-all deduplicated by chip is the better exchange and is not here.
 Both collectives lie under the scope ``moe_exchange``, outside
 ``moe_route``; their transposes are each other, so the backward pass is
 the same two, the rows' cotangent coming back summed in the compute
-dtype. The step (``train/step.py`` ``ep_rows``) sums an expert leaf's
+dtype, and each stands in one layout in either pass (the gathered rows
+row-major, as the experts' kernels take them; a reduce-scatter's operand
+with its tokens minor, as the TPU compiler keeps it a reduce-scatter). The step (``train/step.py`` ``ep_rows``) sums an expert leaf's
 gradient over the batch axes alone, since every member's rows already
 reached it, and everything else over ``ep`` too; the counters below are
 summed over ``ep`` by the step, ``expert_rows`` laid out so that the sum
@@ -416,7 +418,10 @@ loop ran, and the chunks that all chosen pairs would take), ``row_tiles``
 (the row tiles the grouped kernels visited in those chunks, a tile once
 an expert with a row in it, and the row tiles those chunks hold),
 ``rows_summed`` (the rows the loop's sums back add to their tokens, by
-the table they walk, and the rows of those chunks), ``routed``
+the table they walk, and the rows of those chunks), ``rows_fetched``
+(the rows the loop's fetches move, the tiles with a held pair whole, and
+the rows of those chunks: what a gather of whole chunks moved),
+``routed``
 (chosen pairs whose expert is held) and ``dropped`` (those of them that
 the grouped products, chunk by chunk, did not multiply by their own
 expert's weights: 0), by each layer that holds experts (a dense layer
@@ -451,6 +456,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from sparktorch_tpu.ops import gated_delta_rule as delta
 from sparktorch_tpu.ops import gdn_conv_gate as conv_gate
@@ -484,9 +490,11 @@ _IDX_Q_CHUNK = 1_024
 # back to their tokens since PR 48 (``moe_sum_back``: two DMAs a held
 # row, 1.16 ms for 16,384 held rows of 32,768 where XLA's scatter-add of
 # the chunk took 3.25, 0.32 for 4,096 of 8,192; PERF.md section 6, PR
-# 48). What a trip still pays by the CHUNK, or before its first row, is
-# XLA's: three gathers of the chunk's rows, the weights' casts and the
-# kernels' table. So a layer's usual load should still take ONE trip: a
+# 48), and the rows' way in since PR 51 (``moe_fetch_rows``: a DMA a row
+# of the tiles with a held pair, where XLA's gathers moved the whole
+# chunk; PERF.md section 6, PR 51). What a trip still pays by the CHUNK,
+# or before its first row, is XLA's: the weights' casts and the kernels'
+# table. So a layer's usual load should still take ONE trip: a
 # chunk of exactly the share ran one trip or two by the step's rows (13
 # ms apart then; PERF.md section 6, PR 28 and PR 32).
 _CHUNK_OVER_SHARE = 2
@@ -1274,6 +1282,48 @@ def ep_members() -> int:
     return mesh.shape[_EP]
 
 
+def _laid(a, *major_to_minor):
+    return with_layout_constraint(a, Layout(major_to_minor=major_to_minor))
+
+
+# The exchange's two collectives of ``[tokens, d]`` arrays, each the
+# other's transpose, spelled out so that each stands in ONE layout in
+# either pass. A token's row is contiguous on both sides of the
+# all-gather: what it hands on is what a Pallas kernel takes
+# (``grouped.fetch_source``), no transposing copy of the gathered rows
+# between them. The reduce-scatter's operand has its tokens MINOR: over
+# a minor dimension the TPU compiler keeps a reduce-scatter, over the
+# major one it rewrites it as an all-reduce and a slice (a fusion
+# ``all-reduce-scatter`` of twice the bytes on the wire: 6-9 ms a step
+# in Mellum2's cell, PERF.md section 6, PR 51).
+
+
+def _gather(a, axis):
+    return _laid(jax.lax.all_gather(_laid(a, 0, 1), axis, axis=0, tiled=True),
+                 0, 1)
+
+
+def _scatter(a, axis):
+    return jax.lax.psum_scatter(_laid(a, 1, 0), axis, scatter_dimension=0,
+                                tiled=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _gather_rows(a, axis):
+    return _gather(a, axis)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _scatter_sums(a, axis):
+    return _scatter(a, axis)
+
+
+_gather_rows.defvjp(lambda a, axis: (_gather(a, axis), None),
+                    lambda axis, _, ct: (_scatter(ct, axis),))
+_scatter_sums.defvjp(lambda a, axis: (_scatter(a, axis), None),
+                     lambda axis, _, ct: (_gather(ct, axis),))
+
+
 def exchange_in(x, gates, pair_held, n_block: int, axis: str = _EP):
     """The expert exchange's way in, on one member of ``axis``: the rows
     ``x [n, d]``, gates ``[n, k]`` and chosen experts ``pair_held [n, k]``
@@ -1285,9 +1335,9 @@ def exchange_in(x, gates, pair_held, n_block: int, axis: str = _EP):
     transpose hands each member the sum of the members' cotangents of
     its own rows (a reduce-scatter)."""
     with jax.named_scope("moe_exchange"):
-        x, gates, pair_held = (
-            jax.lax.all_gather(a, axis, axis=0, tiled=True)
-            for a in (x, gates, pair_held))
+        x = _gather_rows(x, axis)
+        gates, pair_held = (jax.lax.all_gather(a, axis, axis=0, tiled=True)
+                            for a in (gates, pair_held))
     mine = pair_held - jax.lax.axis_index(axis) * n_block
     return x, gates, jnp.where((mine >= 0) & (mine < n_block), mine, n_block)
 
@@ -1302,7 +1352,7 @@ def exchange_out(out, rows, axis: str = _EP):
     so that summed over ``ep`` it counts every expert once."""
     members, block = jax.lax.axis_size(axis), rows.size
     with jax.named_scope("moe_exchange"):
-        out = jax.lax.psum_scatter(out, axis, scatter_dimension=0, tiled=True)
+        out = _scatter_sums(out, axis)
     return out, jax.lax.dynamic_update_slice(
         jnp.zeros((members * block,), rows.dtype), rows,
         (jax.lax.axis_index(axis) * block,))
@@ -1427,6 +1477,10 @@ class HeldExperts(nn.Module):
             [jnp.sum(jax.vmap(lambda s: grouped.rows_summed(
                 grouped.visit_table(s, chunk, tile), tile))(sizes)),
              trips * chunk]))
+        # the live tiles the trips' fetches move whole, of the same rows
+        self.sow("moe_metrics", "rows_fetched", jnp.stack(
+            [jnp.sum(grouped.rows_fetched(jnp.sum(sizes, -1), tile)),
+             trips * chunk]))
         self.sow("moe_metrics", "routed", n_pairs)
         self.sow("moe_metrics", "dropped", n_pairs - jnp.sum(covered))
         return out.reshape(b, t, d)
@@ -1468,14 +1522,18 @@ def _row_tile(chunk: int, n_held: int) -> int:
 
 
 class _Chunk:
-    """Chunk ``c`` of the sorted pairs: its tokens, gates (a column) and
-    gathered rows of ``x``, and the grouped kernels' table of the row
-    tiles its held rows lie in (``ops/grouped_mlp.py``).
+    """Chunk ``c`` of the sorted pairs: its tokens, gates (a column), the
+    count of its held rows (``live``: they lie first), the grouped
+    kernels' table of the row tiles they lie in and the rows of
+    ``sources`` (``grouped.fetch_source``'s of arrays ``[tokens, d]``)
+    its tokens name, ``fetched``, on those tiles
+    (``ops/grouped_mlp.py``).
 
     Rows past the held pairs belong to no group (their experts are held
-    elsewhere): no kernel reads them, the sums back to the tokens
-    (``grouped.sum_back``: the gated outputs, ``dx``'s rows) add the
-    rows of a group only, and ``d_gate``, which is copied whole, the
+    elsewhere): ``grouped.fetch_rows`` fetches the tiles that hold a
+    held pair, whole, and no kernel reads another; the sums back to the
+    tokens (``grouped.sum_back``: the gated outputs, ``dx``'s rows) add
+    the rows of a group only, and ``d_gate``, which is copied whole, the
     kernel writes as zeros there. Nothing is left unwritten and read: a
     grouped product that left such rows undefined put 25x gradients on
     the chip (XLA's own, PR 27).
@@ -1484,13 +1542,15 @@ class _Chunk:
     pairs in token order, so one expert's rows ascend in token, and
     ``top_k`` gives a token an expert at most once, so none repeats."""
 
-    def __init__(self, c, x, token, gate, rows, chunk, tile):
+    def __init__(self, c, token, gate, rows, chunk, tile, d, *sources):
         self.start = c * chunk
         self.token = jax.lax.dynamic_slice(token, (self.start,), (chunk,))
         self.gate = jax.lax.dynamic_slice(gate, (self.start,), (chunk,))[:, None]
-        self.table = grouped.visit_table(
-            chunk_rows(rows, self.start, chunk), chunk, tile)
-        self.xs = x[self.token]
+        sizes = chunk_rows(rows, self.start, chunk)
+        self.live = jnp.sum(sizes)
+        self.table = grouped.visit_table(sizes, chunk, tile)
+        self.fetched = grouped.fetch_rows(self.live, self.token, *sources,
+                                          d=d, tile=tile)
 
 
 def _padded_pairs(token, gate, chunk: int):
@@ -1507,20 +1567,22 @@ def held_experts_sum(x, token, gate, rows, w_gate, w_up, w_down, chunk):
     sorted order (held pairs first, by expert), ``rows`` the pairs of
     each held expert. A loop over chunks of ``chunk`` sorted pairs (a
     Python int: :func:`_row_chunks`), as many as hold a held pair: a
-    trip gathers its chunk's rows, runs ``ops/grouped_mlp.py``'s two
-    forward kernels on the row tiles that hold a held pair and adds
-    their rows of a group to the tokens' sums (``sum_back``: within one
-    expert's rows ``token`` has to ascend, no token twice); products in
-    ``x``'s dtype, sums in float32, a token's pairs in ascending order
-    of expert."""
+    trip fetches its chunk's rows on the row tiles that hold a held pair
+    (``fetch_rows``), runs ``ops/grouped_mlp.py``'s two forward kernels
+    on those tiles and adds their rows of a group to the tokens' sums
+    (``sum_back``: within one expert's rows ``token`` has to ascend, no
+    token twice); products in ``x``'s dtype, sums in float32, a token's
+    pairs in ascending order of expert."""
     dt, tile = x.dtype, _row_tile(chunk, rows.size)
     with jax.named_scope("moe_experts"):
         token, gate = _padded_pairs(token, gate.astype(jnp.float32), chunk)
         w_gate, w_up, w_down = (w.astype(dt) for w in (w_gate, w_up, w_down))
+        x_rows = grouped.fetch_source(x)
 
         def one_chunk(c, out):
-            ck = _Chunk(c, x, token, gate, rows, chunk, tile)
-            hidden = grouped.gmm_in(ck.table, ck.xs, w_gate, w_up, tile=tile)
+            ck = _Chunk(c, token, gate, rows, chunk, tile, x.shape[1], x_rows)
+            xs, = ck.fetched
+            hidden = grouped.gmm_in(ck.table, xs, w_gate, w_up, tile=tile)
             return grouped.sum_back(ck.table, out, grouped.gmm_down(
                 ck.table, hidden, w_down, ck.gate, tile=tile), ck.token,
                 tile=tile)
@@ -1545,16 +1607,18 @@ def _held_experts_bwd(chunk, args, d_out):
     with jax.named_scope("moe_experts"):
         token, gate_f = _padded_pairs(token, gate.astype(jnp.float32), chunk)
         w_g, w_u, w_d = (w.astype(dt) for w in (w_gate, w_up, w_down))
-        d_out = d_out.astype(dt)
+        sources = (grouped.fetch_source(x),
+                   grouped.fetch_source(d_out.astype(dt)))
 
         def one_chunk(c, sums):
             dx, d_gate, dw_gate, dw_up, dw_down = sums
-            ck = _Chunk(c, x, token, gate_f, rows, chunk, tile)
-            dy = d_out[ck.token]
+            ck = _Chunk(c, token, gate_f, rows, chunk, tile, x.shape[1],
+                        *sources)
+            xs, dy = ck.fetched
             d_a, d_b, gated, d_gate_c = grouped.gmm_bwd_hidden(
-                ck.table, ck.xs, dy, ck.gate, w_g, w_u, w_d, tile=tile)
+                ck.table, xs, dy, ck.gate, w_g, w_u, w_d, tile=tile)
             dw_gate, dw_up = grouped.gmm_dw_in(
-                ck.table, ck.xs, d_a, d_b, dw_gate, dw_up, tile=tile)
+                ck.table, xs, d_a, d_b, dw_gate, dw_up, tile=tile)
             return (grouped.sum_back(ck.table, dx, grouped.gmm_dx(
                         ck.table, d_a, d_b, w_g, w_u, tile=tile), ck.token,
                         tile=tile),
@@ -1781,6 +1845,10 @@ class SparseMoELM(nn.Module):
         visits they walk (``moe_rows`` on every step, or a held pair's
         row was lost between the products and its token), and the rows
         of the chunks the loops ran, which a scatter-add of whole chunks
+        moved. From ``rows_fetched``, ``[layers, 2]``: the rows the
+        trips fetched for their products (``fetch_rows``: the tiles with
+        a held pair, whole, so ``moe_rows`` and under a tile more a
+        trip) of the same chunks' rows, which a gather of whole chunks
         moved. Under block diffusion also the
         step's ``masked_tokens`` of its ``tokens`` (the rows' own, not
         the doubled sequence's), and from ``attn_tiles``, ``[layers,
@@ -1791,6 +1859,7 @@ class SparseMoELM(nn.Module):
         counter, so no array here has a row a layer of the model."""
         by_expert, chunks = sown["expert_rows"], sown["row_chunks"]
         tiles, summed = sown["row_tiles"], sown["rows_summed"]
+        fetched = sown["rows_fetched"]
         rows, f = float(by_expert.sum()), float(drop_fraction or 0.0)
         fields = dict(
             moe_rows=rows, moe_rows_max=float(by_expert.max()),
@@ -1798,7 +1867,8 @@ class SparseMoELM(nn.Module):
             moe_pairs_dropped=rows * f / (1.0 - f) if f < 1.0 else rows,
             moe_row_chunks=float(chunks[:, 0].sum()),
             moe_row_tiles=float(tiles[:, 0].sum()),
-            moe_rows_summed=float(summed[:, 0].sum()))
+            moe_rows_summed=float(summed[:, 0].sum()),
+            moe_rows_fetched=float(fetched[:, 0].sum()))
         counters = {"train.moe.rows": rows,
                     "train.moe.pairs_dropped": fields["moe_pairs_dropped"],
                     "train.moe.row_chunks": fields["moe_row_chunks"]}
@@ -1807,6 +1877,7 @@ class SparseMoELM(nn.Module):
                   "train.moe.row_tiles_visited": fields["moe_row_tiles"],
                   "train.moe.row_tiles": float(tiles[:, 1].sum()),
                   "train.moe.rows_summed": fields["moe_rows_summed"],
+                  "train.moe.rows_fetched": fields["moe_rows_fetched"],
                   "train.moe.rows_moved": float(summed[:, 1].sum())}
         if "exchange_rows" in sown:
             # over an ep axis: the rows that crossed to another member,

@@ -49,7 +49,23 @@ replaced, sorted the chunk's indices, gathered all its rows into that
 order, passed over every token's sum and added the rows one by one,
 zeros and all: PERF.md section 6, PR 48).
 
-What a caller may rely on: every row of ``d_gate`` is WRITTEN, zeros
+The rows' way in, :func:`fetch_rows` (``moe_fetch_rows``), is its
+mirror and needs the live rows' count alone: its grid is the chunk's row
+tiles, the sources (``x``, and ``d_out`` beside it in the backward pass,
+as :func:`fetch_source` lays them out in one pass of a ninth kernel,
+``moe_fetch_source``: a token's row whole tiles of its own) stay in HBM,
+and a tile that holds a live row is fetched WHOLE by one DMA a row and
+source, all of a tile's in flight at once, and turned
+into the ``[tile, d]`` block the products read. A tile past the live
+rows is neither fetched nor written. XLA's gathers of the whole chunk,
+``x[token]`` forward and ``x[token]`` and ``d_out[token]`` backward,
+which this replaced, paid by the row for as many rows of experts held
+elsewhere as rows any kernel reads (PERF.md section 6, PR 51).
+
+What a caller may rely on: the fetched rows are ``x[token]`` bit for
+bit on every row of a tile with a live row (its rows of no group too,
+under a tile of them) and NOTHING on the other tiles, which the kernels
+below never read; every row of ``d_gate`` is WRITTEN, zeros
 where the row is of no group (tiles without a live row are visited for
 that alone, after the live ones: a store and no read); ``gate * ys``,
 ``dx``'s rows, ``hidden``, ``d_a``, ``d_b`` and ``hidden * gate`` are
@@ -58,7 +74,7 @@ read under the same table only, by these kernels or by
 :func:`sum_back`. Operands enter every product in the compute dtype,
 every sum and every epilogue is float32; ``tests/test_grouped_mlp.py``
 holds each kernel and the sum back to the same arithmetic in plain
-``jax.numpy`` on whole arrays.
+``jax.numpy`` on whole arrays, and the fetch to ``x[token]``'s bits.
 
 Blocks. A kernel's grid is ``(visits, column blocks)``, the columns
 inside: a row tile's operands stay in VMEM over its column blocks, an
@@ -76,6 +92,7 @@ everywhere: there is no other path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -272,17 +289,154 @@ def _weight_spec(block, where):
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch=(),
-          aliases=None):
+          aliases=None, prefetch=_TABLE):
     return pl.pallas_call(
         kernel, out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=_TABLE, grid=grid, in_specs=in_specs,
+            num_scalar_prefetch=prefetch, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=list(scratch)),
         input_output_aliases=aliases or {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(), name=name)
+
+
+# -- the rows' way in ----------------------------------------------------------
+
+# the tokens of a row tile reach a kernel as a block of SMEM, and XLA
+# lays a long int32 vector out in tiles of 1,024: the block is that wide
+_TOKEN_BLOCK = 1024
+_SUBLANES = 8
+_SOURCE_ROWS = 256  # tokens a block of ``moe_fetch_source`` (512 read alike)
+# Row DMAs a trip of ``moe_fetch_rows``' loop starts: the loop's own
+# scalar work is what a row costs (a DMA a trip issued in 32.5 ns, 8 a
+# trip in 19: 2.13 -> 1.25 ms for 65,536 rows of 2,304; TPU v5e, PR 51),
+# and Mosaic's ``fori_loop`` takes no partial ``unroll``
+_FETCH_UNROLL = 8
+
+
+def _token_block(token, tile: int, tile_of):
+    """``token [chunk]`` padded to whole blocks of SMEM, and the spec of
+    the block that holds the tokens of the row tile ``tile_of(*grid
+    indices and prefetched scalars)``; a kernel finds them at
+    :func:`_tokens_at`."""
+    block = max(tile, _TOKEN_BLOCK)
+    return jnp.pad(token, (0, -token.size % block)), pl.BlockSpec(
+        (block,), lambda *a: (tile_of(*a) * tile // block,),
+        memory_space=pltpu.SMEM)
+
+
+def _tokens_at(token_ref, row_tile, rows: int):
+    """Where row tile ``row_tile``'s tokens lie in its block of SMEM."""
+    return row_tile % (token_ref.shape[0] // rows) * rows
+
+
+def _source_kernel(x_ref, out_ref):
+    rows, padded, lanes = out_ref.shape
+    whole, rest = divmod(x_ref.shape[1], lanes)
+    if whole:
+        out_ref[:, :whole, :] = x_ref[:, :whole * lanes].reshape(
+            rows, whole, lanes)
+    if padded > whole:
+        out_ref[:, whole:, :] = jnp.zeros((rows, padded - whole, lanes),
+                                          out_ref.dtype)
+    if rest:  # a width 128 lanes do not divide (the tests' 64): the rest
+        out_ref[:, whole, :rest] = x_ref[:, whole * lanes:]
+
+
+@jax.jit
+def fetch_source(x):
+    """``x [tokens, d]`` as :func:`fetch_rows` reads it: ``[tokens, s,
+    128]`` with ``s`` the sublanes of 128 lanes a row takes, rounded up
+    to whole tiles of 8 (16 at a width of 2,048; 24, 18 of them the
+    row's, at 2,304; 8, the row half of the first, at the tests' 64;
+    zeros past the row), so that a token's row is whole tiles of its own
+    in either dtype, as :func:`token_sums` lays the sums out and for
+    Mosaic's same reason: of bfloat16 ``[tokens, 18, 128]`` it refuses a
+    row ("Slice shape along dimension 1 must be aligned to tiling (8),
+    but is 18"), of ``[tokens, 1, d]`` too (two rows to a sublane), and
+    of ``[tokens, d]`` in any dtype.
+
+    One pass of a kernel, ``moe_fetch_source``, at any width: a block of
+    rows in, turned and padded in VMEM, out (0.57 ms at 32,768 bfloat16
+    rows of 2,304, TPU v5e), where XLA spelled ``pad`` and ``reshape``
+    as two passes of their own (1.64 ms; PERF.md section 6, PR 51)."""
+    tokens, d = x.shape
+    rows = row_tile(tokens, _SOURCE_ROWS)
+    padded = -(-d // (_LANES * _SUBLANES)) * _SUBLANES
+    return _call(
+        _source_kernel, "moe_fetch_source", (tokens // rows,),
+        [pl.BlockSpec((rows, d), lambda i: (i, 0))],
+        pl.BlockSpec((rows, padded, _LANES), lambda i: (i, 0, 0)),
+        jax.ShapeDtypeStruct((tokens, padded, _LANES), x.dtype), prefetch=0)(x)
+
+
+def rows_fetched(live, tile: int):
+    """The rows :func:`fetch_rows` moves of a chunk whose first ``live``
+    rows are of a group: its live tiles', whole."""
+    return -(-live // tile) * tile
+
+
+def _fetch_kernel(live_ref, token_ref, *refs):
+    n = (len(refs) - 1) // 3
+    sources, outs, held, sems = (refs[:n], refs[n:2 * n], refs[2 * n:3 * n],
+                                 refs[-1])
+    i, rows = pl.program_id(0), outs[0].shape[0]
+    a_trip = math.gcd(rows, _FETCH_UNROLL)
+
+    @pl.when(i * rows < live_ref[0])
+    def _fetch():
+        at = _tokens_at(token_ref, i, rows)
+
+        def start(trip, _):
+            for r in range(a_trip):
+                r = trip * a_trip + r
+                for s in range(n):
+                    pltpu.make_async_copy(sources[s].at[token_ref[at + r]],
+                                          held[s].at[r], sems.at[s]).start()
+            return 0
+
+        # the whole tile's rows in flight at once, ONE wait a source for
+        # all its rows' bytes (a copy of the scratch's size that is
+        # never started), then the turn from a row of its own tiles to a
+        # row of the block's (strided loads)
+        jax.lax.fori_loop(0, rows // a_trip, start, 0)
+        for s in range(n):
+            pltpu.make_async_copy(held[s], held[s], sems.at[s]).wait()
+        for out, got in zip(outs, held):
+            out[...] = got[...].reshape(rows, -1)[:, :out.shape[1]]
+
+
+@functools.partial(jax.jit, static_argnames=("d", "tile"))
+def fetch_rows(live, token, *sources, d, tile):
+    """``tuple(x[token] for x in sources)`` on the row tiles that hold
+    one of the chunk's first ``live`` rows: ``[chunk, d]`` each, in the
+    sources' dtype. ``sources`` are :func:`fetch_source`'s of arrays
+    ``[tokens, d]``, ``token [chunk]`` the rows' tokens, ``live`` (int32,
+    on the device) the rows of a group, which lie first.
+
+    The kernel ``moe_fetch_rows``, the mirror of ``moe_sum_back``, keeps
+    the sources in HBM; its grid is the chunk's row tiles. A tile with a
+    live row is fetched WHOLE (so at most ``tile - 1`` rows that are of
+    no group), a DMA a row and source, all of a tile's in flight at
+    once, and written as one block; a tile past the live rows is neither
+    fetched nor written, and its step's blocks repeat the last live
+    tile's, so it moves nothing. A copy: the fetched rows are bit for
+    bit the sources'. Nothing may read a tile this did not write."""
+    chunk = token.size
+    live_tile = lambda i, live: jnp.minimum(
+        i, jnp.maximum(-(-live[0] // tile) - 1, 0))
+    token, token_spec = _token_block(token, tile, live_tile)
+    rows_out = pl.BlockSpec((tile, d), lambda i, live: (live_tile(i, live), 0))
+    n, dt = len(sources), sources[0].dtype
+    return _call(
+        _fetch_kernel, "moe_fetch_rows", (_blocks_of(chunk, tile),),
+        [token_spec, *[pl.BlockSpec(memory_space=pl.ANY)] * n],
+        [rows_out] * n, [jax.ShapeDtypeStruct((chunk, d), dt)] * n,
+        scratch=[*[pltpu.VMEM((tile, *sources[0].shape[1:]), dt)] * n,
+                 pltpu.SemaphoreType.DMA((n,))], prefetch=1)(
+            jnp.reshape(live, (1,)).astype(jnp.int32), token, *sources)
 
 
 # -- forward ------------------------------------------------------------------
@@ -391,11 +545,6 @@ def token_sums(tokens: int, d: int):
     return jnp.zeros((tokens, d // lanes, lanes), jnp.float32)
 
 
-# the tokens of a row tile reach the kernel as a block of SMEM, and XLA
-# lays a long int32 vector out in tiles of 1,024: the block is that wide
-_TOKEN_BLOCK = 1024
-
-
 def _sum_back_kernel(*refs):
     table, refs = refs[:_TABLE], refs[_TABLE:]
     rows_ref, token_ref, _, sums_ref, held, sems = refs
@@ -408,8 +557,7 @@ def _sum_back_kernel(*refs):
         start = visit.tile[v] * n
         first = jnp.maximum(visit.lo[v], start) - start
         last = jnp.minimum(visit.hi[v], start + n) - start
-        # where the tile's tokens lie in the block of SMEM
-        at = visit.tile[v] % (token_ref.shape[0] // n) * n
+        at = _tokens_at(token_ref, visit.tile[v], n)
 
         def fetch(r):
             return pltpu.make_async_copy(sums_ref.at[token_ref[at + r]],
@@ -449,16 +597,14 @@ def sum_back(table, sums, rows, token, *, tile):
     without a live row, are never read nor moved (``rows`` may hold
     anything there), and a token without a row of a group keeps its sum
     bit for bit."""
-    chunk, d = rows.shape
-    block = max(tile, _TOKEN_BLOCK)
-    token = jnp.pad(token, (0, -chunk % block))
+    d = rows.shape[1]
+    token, token_spec = _token_block(token, tile,
+                                     lambda v, *table: table[1][v])
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     return _call(
         _sum_back_kernel, "moe_sum_back", (table[0].size,),
         [pl.BlockSpec((tile, d), lambda v, *table: (table[1][v], 0)),
-         pl.BlockSpec((block,),
-                      lambda v, *table: (table[1][v] * tile // block,),
-                      memory_space=pltpu.SMEM), in_hbm], in_hbm,
+         token_spec, in_hbm], in_hbm,
         jax.ShapeDtypeStruct(sums.shape, sums.dtype),
         scratch=[pltpu.VMEM((tile, *sums.shape[1:]), jnp.float32),
                  pltpu.SemaphoreType.DMA((2,))],

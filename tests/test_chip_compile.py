@@ -208,15 +208,50 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, as_tpu):
 def _assert_sums_back_are_the_kernels(text, module):
     """No XLA scatter is left under the expert layers' scope (the
     scatter-add of a whole chunk, which the compiler gave a sort of the
-    chunk's indices and a gather of all its rows into that order): each
-    of their loops, one a layer and pass, calls ``moe_sum_back`` once."""
+    chunk's indices and a gather of all its rows into that order), and
+    no gather (of a whole chunk's rows of ``x`` or of ``d_out``, three a
+    layer until PR 51): each of their loops, one a layer and pass, calls
+    ``moe_sum_back`` and ``moe_fetch_rows`` once, on sources that
+    ``moe_fetch_source`` lays out, one forward and two backward."""
     cfg = module.config
     layers = sum(k.mlp == "experts" for k in cfg.layers) + cfg.mtp_depth
     under = [line for lines in _computations(text).values() for line in lines
              if "moe_experts" in line]
     assert not [line for line in under
-                if " scatter(" in line or " sort(" in line]
+                if " scatter(" in line or " sort(" in line
+                or " gather(" in line]
     assert _pallas_calls(text, "moe_sum_back") == 2 * layers
+    assert _pallas_calls(text, "moe_fetch_rows") == 2 * layers
+    assert _pallas_calls(text, "moe_fetch_source") == 3 * layers
+
+
+@pytest.mark.parametrize("tokens,chunk,d", [
+    (16_384, 32_768, 2_048),    # LFM2's layer: 16 sublanes a row
+    (32_768, 131_072, 2_304),   # a member of Mellum2's: 18, 24 in the source
+], ids=["2048", "2304"])
+def test_the_rows_fetch_compiles_for_v5e_at_both_widths(one_chip, as_tpu,
+                                                        tokens, chunk, d):
+    """``moe_fetch_source`` and ``moe_fetch_rows`` (one source, as the
+    forward pass calls it, and two) at the two widths the cells have:
+    Mosaic takes a bfloat16 row as a DMA's end only as whole tiles of 8
+    sublanes (it refused ``[tokens, 18, 128]`` and ``[tokens, 1, d]``),
+    and the turn of the fetched rows into the products' block is a
+    ``reshape`` of a value in the kernel. Interpret mode checks none of
+    it."""
+    from sparktorch_tpu.ops import grouped_mlp as G
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = G.fetch_source.lower(
+        S((tokens, d), jnp.bfloat16)).compile().as_text()
+    assert _pallas_calls(text, "moe_fetch_source") == 1
+    source = jax.eval_shape(G.fetch_source, S((tokens, d), jnp.bfloat16))
+    assert source.shape == (tokens, -(-d // 1024) * 8, 128)
+    source = S(source.shape, source.dtype)
+    for n in (1, 2):
+        text = G.fetch_rows.lower(
+            S((), jnp.int32), S((chunk,), jnp.int32), *[source] * n, d=d,
+            tile=512).compile().as_text()
+        assert _pallas_calls(text, "moe_fetch_rows") == 1
 
 
 def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
@@ -265,16 +300,16 @@ def test_sparse_moe_layer_gradient_compiles_for_v5e(one_chip, as_tpu):
               for m in [re.search(r"body=(%[\w.\-]+)", line)]}
     # the grouped kernels of ops/grouped_mlp.py: gate|up with SwiGLU and
     # down with the gate forward; the hidden rows recomputed with their
-    # cotangent, dx and the two weight gradients backward; the rows' sum
-    # back to their tokens in both
+    # cotangent, dx and the two weight gradients backward; the rows'
+    # fetch and their sum back to their tokens in both
     kernels = ("moe_gmm_in", "moe_gmm_down", "moe_gmm_bwd_hidden",
                "moe_gmm_dx", "moe_gmm_dw_in", "moe_gmm_dw_down",
-               "moe_sum_back")
+               "moe_fetch_rows", "moe_sum_back")
     calls = {body: [_pallas_calls("\n".join(comps[body]), kernel)
                     for kernel in kernels] for body in bodies}
     assert sorted(c for c in calls.values() if any(c)) == [
-        [0, 0, 1, 1, 1, 1, 1], [1, 1, 0, 0, 0, 0, 1]]
-    for kernel in kernels[:-1]:  # none outside the loops
+        [0, 0, 1, 1, 1, 1, 1, 1], [1, 1, 0, 0, 0, 0, 1, 1]]
+    for kernel in kernels[:-2]:  # none outside the loops
         assert _pallas_calls(text, kernel) == 1, kernel
     assert "ragged-dot" not in text
     _assert_sums_back_are_the_kernels(text, module)
@@ -966,5 +1001,22 @@ def test_ep4_step_of_mellum2_compiles_for_v5e_2x2(topo, as_tpu):
                                     tx, mesh, mini_batch=1)
     assert isinstance(step, step_mod._CompiledWithOptions)
     text = step.lower(state, batch).compile().as_text()
-    assert "all-gather" in text and "reduce-scatter" in text
     assert text.count("tpu_custom_call") >= 10
+    # The layer's rows' sums forward and their cotangent backward go
+    # back by the OPCODE ``reduce-scatter`` under the exchange's scope,
+    # as ``moe_exchange_ms``'s reader knows them, their tokens minor: a
+    # tokens-major operand the compiler rewrites as an all-reduce and a
+    # slice in a fusion ``all-reduce-scatter`` with no ``op_name`` (PR
+    # 51; the gates' ``[tokens, 8]`` take that form at 8,192 tokens) ...
+    scatters = [line.split(" reduce-scatter(")[0] for line in text.splitlines()
+                if " reduce-scatter(" in line and "moe_exchange/" in line]
+    scatters = [s for s in scatters if ",2304]" in s]
+    assert len(scatters) == 2 and all("{0,1:" in s for s in scatters)
+    assert not re.search(r"%all-reduce-scatter\S* \(\S+ \w+\[\d+,2304\]", text)
+    # ... and the gathered rows reach ``moe_fetch_source`` row-major as
+    # the all-gather left them: no transposing copy of them between
+    gathers = [line.split(" all-gather(")[0] for line in text.splitlines()
+               if " all-gather(" in line and "moe_exchange/" in line
+               and ",2304]" in line.split(" all-gather(")[0]]
+    assert gathers and all("{1,0:" in g or "{2,1,0:" in g for g in gathers)
+    assert not re.search(r"= \w+\[%d,2304\]\S* copy\(" % (4 * seq), text)

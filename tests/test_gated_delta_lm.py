@@ -386,9 +386,9 @@ def test_the_cut_configuration_counts_424_million_parameters():
 # gradient of the loss with its counters); this PR means to change none
 # and has not touched those hashes. The fourth, JoyAI-LLM-Flash, by the
 # same lines at that file's sizes, read on the parent commit 565801a
-# (PR 41), its step anew at PR 47 (the experts' kernels) and at PR 48
-# (their sums back):
-JOYAI_PARENT = ("cbffab51f8326b72", "618e538533f4fa04")
+# (PR 41), its step anew at PR 47 (the experts' kernels), at PR 48
+# (their sums back) and at PR 51 (their rows' fetch):
+JOYAI_PARENT = ("cbffab51f8326b72", "dcc62556a33aa6b0")
 
 
 def _joyai_hashes():

@@ -1,7 +1,8 @@
-"""``ops/grouped_mlp.py``: each kernel and the rows' sum back against the
-plain ``jax.numpy`` spelling, on the CPU in interpret mode at tiny
-shapes, and ``held_experts_sum`` (values and all five gradients) against
-a plain float32 reference of the layer's sum."""
+"""``ops/grouped_mlp.py``: each kernel, the rows' fetch and their sum
+back against the plain ``jax.numpy`` spelling, on the CPU in interpret
+mode at tiny shapes, and ``held_experts_sum`` (values and all five
+gradients) against a plain float32 reference of the layer's sum, and bit
+for bit against itself on rows gathered as ``x[token]``."""
 
 import numpy as np
 import pytest
@@ -283,6 +284,47 @@ def test_row_tiles_follow_the_expected_rows_an_expert():
                  o["w_gate"], o["w_up"], tile=TILE, cols=24)
 
 
+# -- the rows' way in ---------------------------------------------------------
+
+
+def bits(a):
+    return np.asarray(a).tobytes()
+
+
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("d", [16 * 128, 18 * 128, 24],
+                         ids=["16x128", "18x128", "24"])
+@pytest.mark.parametrize("live", [0, 64, 37, 32],
+                         ids=["none_held", "all_held", "partial_last_tile",
+                              "second_half_dead"])
+def test_fetched_rows_are_the_gathered_rows_bit_for_bit(live, d, dt):
+    """``fetch_rows`` of two sources in one call against ``x[token]``:
+    EQUAL on every row of a tile with a live row (the last live tile
+    whole, its rows of no group too), whatever the width's sublanes (16
+    are whole bfloat16 tiles, 18 are not, 24 columns have no lane tile
+    at all) and the dtype; the source's padding never arrives."""
+    keys = jax.random.split(jax.random.key(live + d), 3)
+    x, dy = (jax.random.normal(k, (N_TOKENS, d), jnp.float32).astype(dt)
+             for k in keys[:2])
+    token = jax.random.randint(keys[2], (CHUNK,), 0, N_TOKENS)
+    sources = [G.fetch_source(a) for a in (x, dy)]
+    assert sources[0].shape[0] == N_TOKENS and sources[0].dtype == dt
+    # a row is whole tiles of its own at any width: itself, then zeros
+    row = sources[0].reshape(N_TOKENS, -1)
+    assert row.shape[1] % (8 * 128) == 0
+    assert bits(row[:, :d]) == bits(x) and not np.any(row[:, d:])
+    xs, dys = G.fetch_rows(jnp.int32(live), token, *sources, d=d, tile=TILE)
+    assert xs.shape == dys.shape == (CHUNK, d) and xs.dtype == dt
+    fetched = int(G.rows_fetched(live, TILE))
+    assert live <= fetched < live + TILE and fetched % TILE == 0
+    assert bits(xs[:fetched]) == bits(x[token][:fetched])
+    assert bits(dys[:fetched]) == bits(dy[token][:fetched])
+    # one source alone, as the forward pass calls it
+    alone, = G.fetch_rows(jnp.int32(live), token, sources[1], d=d, tile=TILE)
+    assert bits(alone[:fetched]) == bits(dys[:fetched])
+
+
 # -- the layer's sum through them ---------------------------------------------
 
 
@@ -330,9 +372,34 @@ def test_held_experts_sum_and_its_five_gradients(rows, chunk, monkeypatch):
     want = jax.value_and_grad(loss(
         lambda x, gate, *w: layer_reference(x, token, gate, rows, *w)),
         argnums=(0, 1, 2, 3, 4))(x, gate, *w)
-    for a, b, name in zip(jax.tree.leaves(got), jax.tree.leaves(want),
-                          ("loss", "x", "gate", "w_gate", "w_up", "w_down")):
+    names = ("loss", "x", "gate", "w_gate", "w_up", "w_down")
+    for a, b, name in zip(jax.tree.leaves(got), jax.tree.leaves(want), names):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+
+    # a gather is a copy: on rows gathered as ``x[token]``, and on
+    # fetched rows whose tiles without a held pair (which the fetch does
+    # not write and nothing may read) hold NaN, the sum and its five
+    # gradients keep every bit
+    fetch = G.fetch_rows
+    d = x.shape[1]
+
+    def gathered(live, token, *sources, d, tile):
+        return tuple(a.reshape(a.shape[0], -1)[:, :d][token] for a in sources)
+
+    def poisoned(live, token, *sources, d, tile):
+        dead = (jnp.arange(token.size) >= G.rows_fetched(live, tile))[:, None]
+        return tuple(jnp.where(dead, jnp.nan, rows) for rows in fetch(
+            live, token, *sources, d=d, tile=tile))
+
+    for spelling in (gathered, poisoned):
+        monkeypatch.setattr(G, "fetch_rows", spelling)
+        again = jax.value_and_grad(loss(
+            lambda x, gate, *w: M.held_experts_sum(x, token, gate, rows, *w,
+                                                   chunk)),
+            argnums=(0, 1, 2, 3, 4))(x, gate, *w)
+        for a, b, name in zip(jax.tree.leaves(got), jax.tree.leaves(again),
+                              names):
+            assert bits(a) == bits(b), (spelling.__name__, name)
 
 
 def test_the_chip_smokes_phase_rehearsed_at_a_small_size(monkeypatch):
@@ -348,3 +415,4 @@ def test_the_chip_smokes_phase_rehearsed_at_a_small_size(monkeypatch):
         chip_smoke.Sizes(grouped_case=(64, 2, 8, 4, 32, 48)), 0, {})
     assert "held_rows=" in report and "chunk=128" in report
     assert "sum_back_fwd_ms=" in report and "sum_back_bwd_ms=" in report
+    assert "fetch_bwd_ms=" in report and "gather_bwd_ms=" in report

@@ -316,11 +316,14 @@ def test_the_published_model_and_what_a_configuration_may_not_say():
 # ``tests/test_gated_delta_lm.py``'s and ``tests/test_short_conv_lm.py``'s
 # hashes too), and at PR 48, likewise in all five (the held
 # experts' sums back are ``grouped_mlp.sum_back``, no scatter-add of a
-# chunk). A PR that means to change one of these models' steps reads
-# them anew.
-PARENT = {"keye": ("96e560cdd12a17e7", "b278dd743f81aa7a"),
-          "sdar": ("b60f6b0d23c7320d", "97eac0d6d6622f50"),
-          "laguna": ("0ce980a58c390130", "4479302cfcf8ca62")}
+# chunk), and at PR 51, likewise in all five (the held experts fetch a
+# chunk's rows by ``grouped_mlp.fetch_rows``, no gather of a chunk; loss
+# and every gradient leaf read bit-equal to the parent's on seeded rows
+# in all five models before the hashes were read). A PR that means to
+# change one of these models' steps reads them anew.
+PARENT = {"keye": ("96e560cdd12a17e7", "6e0a8e2287eec3e6"),
+          "sdar": ("b60f6b0d23c7320d", "d52f4bc8b427cb9c"),
+          "laguna": ("0ce980a58c390130", "c79c1f5b0ab261dd")}
 
 
 def older_model(name):

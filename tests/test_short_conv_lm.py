@@ -437,9 +437,9 @@ def test_the_cut_configuration_counts_508_million_parameters():
 # through ``qk_norm_rope`` and the ``causal`` kernels, whose files this PR
 # edits, beside ``ops/gdn_conv_gate.py``, whose helpers it imports), by
 # the same lines at that file's sizes, read on the parent commit 5b33aff
-# (PR 45), its step anew at PR 47 (the experts' kernels) and at PR 48
-# (their sums back):
-QWEN3_NEXT_PARENT = ("d25fa4aa23141640", "61a71f59849f696f")
+# (PR 45), its step anew at PR 47 (the experts' kernels), at PR 48
+# (their sums back) and at PR 51 (their rows' fetch):
+QWEN3_NEXT_PARENT = ("d25fa4aa23141640", "199f33a6e184528b")
 
 
 def _qwen3_next_hashes():

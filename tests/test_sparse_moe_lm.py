@@ -825,6 +825,40 @@ def test_row_tiles_in_the_records_are_the_tiles_the_kernels_visit(
         == records[-1]["moe_row_chunks"] * (chunk // tile)
 
 
+def test_rows_fetched_in_the_records_are_the_live_tiles_rows(monkeypatch):
+    """``rows_fetched`` against a count on the host from ``expert_rows``:
+    chunk by chunk of 64 pairs in tiles of 16, the tiles that hold a
+    held pair, whole, of the rows of the chunks that ran; and through
+    the trainer the record's ``moe_rows_fetched`` (at least
+    ``moe_rows``, under a tile more a trip) and the gauge on the bus
+    beside ``train.moe.rows_moved``."""
+    from sparktorch_tpu.ops import grouped_mlp as G
+
+    chunk, tile = 64, 16
+    chunks_of(monkeypatch, chunk)
+    monkeypatch.setattr(G, "_MIN_ROW_TILE", tile)
+    layer, params, g = _seeded_layer()
+    sown = layer.apply({"params": params}, g,
+                       mutable=["moe_metrics"])[1]["moe_metrics"]
+    held = int(np.asarray(sown["expert_rows"][0]).sum())
+    trips = -(-held // chunk)
+    assert held % chunk % tile  # the last live tile is partial
+    fetched = (trips - 1) * chunk + -(-(held - (trips - 1) * chunk) // tile
+                                      ) * tile
+    assert np.array_equal(sown["rows_fetched"][0], [fetched, trips * chunk])
+    assert held < fetched < held + tile
+
+    records, _, tele = _train(1, iters=3, layers=1, steps_per_call=1)
+    for r in records:
+        assert 0 < r["moe_rows"] <= r["moe_rows_fetched"] \
+            < r["moe_rows"] + tile * r["moe_row_chunks"]
+        assert r["moe_rows_fetched"] % tile == 0
+    assert tele.gauge_value("train.moe.rows_fetched") \
+        == records[-1]["moe_rows_fetched"]
+    assert tele.gauge_value("train.moe.rows_fetched") \
+        <= tele.gauge_value("train.moe.rows_moved")
+
+
 def test_the_gspmd_and_pipeline_trainers_refuse_the_model():
     import optax
 
